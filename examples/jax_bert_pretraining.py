@@ -22,6 +22,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 
 import horovod_tpu as hvd
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 from horovod_tpu import models
 
 
@@ -37,6 +38,7 @@ def main():
                     help="Pallas flash kernel per ring-attention hop")
     args = ap.parse_args()
 
+    enable_compile_cache()
     hvd.init()
     devices = jax.devices()
     n_dev = len(devices)

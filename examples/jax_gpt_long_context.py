@@ -24,6 +24,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 
 import horovod_tpu as hvd
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 from horovod_tpu import models
 
 
@@ -40,6 +41,7 @@ def main():
                          "(linear memory in the per-device chunk)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     hvd.init()
     devices = jax.devices()
     sp = args.sp if len(devices) % args.sp == 0 else 1

@@ -9,6 +9,7 @@ library is built on demand with `make` the first time it is needed.
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import json
 import os
 import subprocess
@@ -42,15 +43,22 @@ def _load_library() -> ctypes.CDLL:
         if _lib is not None:
             return _lib
         # Always invoke make: it's incremental (no-op when up to date) and
-        # guarantees source edits are never shadowed by a stale .so.
-        try:
-            subprocess.run(["make", "-s"], cwd=_CPP_DIR, check=True,
-                           capture_output=True)
-        except (subprocess.CalledProcessError, OSError) as exc:
-            if not os.path.exists(_LIB_PATH):
-                raise
-            log.warning("native core rebuild failed (%s); using existing "
-                        "library", exc)
+        # guarantees source edits are never shadowed by a stale .so.  A
+        # failed build is an error even when an older library is lying
+        # around: that library is not what the sources say.  One build at a
+        # time across processes: the ranks of a job start together, and on
+        # a fresh checkout concurrent makes in one directory hand some rank
+        # a half-linked library.
+        with open(os.path.join(_CPP_DIR, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            try:
+                subprocess.run(
+                    ["make", "-s", f"-j{min(8, os.cpu_count() or 1)}"],
+                    cwd=_CPP_DIR, check=True, capture_output=True)
+            except subprocess.CalledProcessError as exc:
+                raise RuntimeError(
+                    f"native core build failed in {_CPP_DIR}: "
+                    f"{exc.stderr.decode(errors='replace')[-2000:]}") from exc
         lib = ctypes.CDLL(_LIB_PATH)
         _declare(lib)
         _lib = lib

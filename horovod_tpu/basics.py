@@ -12,8 +12,9 @@ from __future__ import annotations
 import os
 from typing import List, Optional, Sequence
 
+import numpy as np
+
 from .context import HorovodContext
-from .exceptions import HorovodInternalError
 from .utils.env import Config, get_bool
 from .utils.logging import get_logger
 from .parallel import mesh as _mesh
@@ -82,34 +83,10 @@ def init(comm=None, process_sets: Optional[Sequence] = None,
                     log.warning("jax.distributed shutdown failed: %s", exc)
                 _jax_distributed_up = False
                 # Cleared backends let initialize() pass its
-                # backends_are_initialized() guard.  Try the public API
-                # first; the private impl is a fallback for jax versions
-                # where the alias was removed.
-                cleared = False
-                public = getattr(jax, "clear_backends", None)
-                if public is not None:
-                    try:
-                        public()
-                        cleared = True
-                    except Exception as exc:
-                        log.warning("jax.clear_backends failed: %s", exc)
-                if not cleared:
-                    try:
-                        from jax._src import api as _jax_api
+                # backends_are_initialized() guard.
+                from jax._src import api as _jax_api
 
-                        _jax_api.clear_backends()
-                        cleared = True
-                    except Exception as exc:
-                        log.warning("clear_backends failed: %s", exc)
-                if not cleared:
-                    # Proceeding would hit initialize()'s backends-already-
-                    # initialized error anyway — degrade explicitly with a
-                    # named, actionable failure instead (ADVICE r2).
-                    raise HorovodInternalError(
-                        "elastic re-initialization could not clear jax "
-                        "backends on this jax version; this process cannot "
-                        "rejoin the new generation in-place and must be "
-                        "restarted (the elastic driver respawns it)")
+                _jax_api.clear_backends()
             jax.distributed.initialize(
                 coordinator_address=params[0],
                 num_processes=cfg.size,
@@ -117,18 +94,17 @@ def init(comm=None, process_sets: Optional[Sequence] = None,
             )
             _jax_distributed_up = True
             _jax_dist_params = params
+        # Which rank is each jax process?  Asked of the runtime, because on
+        # a TPU the process index follows the chips, not process_id.
+        from jax.experimental import multihost_utils
+
+        pairs = np.asarray(multihost_utils.process_allgather(
+            np.asarray([jax.process_index(), cfg.rank], np.int32)))
+        _mesh.set_process_ranks(
+            {int(p): int(r) for p, r in pairs.reshape(-1, 2)})
 
     if build_mesh:
-        try:
-            _mesh.build_global_mesh()
-        except Exception as exc:
-            # Under a multi-host runtime the mesh IS the data plane; hiding a
-            # build failure would desync the pod silently, so fail hard.
-            if get_bool("HOROVOD_JAX_DISTRIBUTED", False):
-                raise RuntimeError(
-                    f"global mesh build failed under jax.distributed: {exc}"
-                ) from exc
-            log.warning("global mesh not built: %s", exc)
+        _mesh.build_global_mesh()
 
     if process_sets:
         from .process_sets import add_process_set

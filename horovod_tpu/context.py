@@ -113,31 +113,30 @@ class _FusionBuffer:
 
 
 def _select_backend(cfg: Config) -> CoreBackend:
-    """Pick the native C++ core when available, pure-Python otherwise.
+    """The native C++ core, or the pure-Python local core when asked for.
 
     Selection mirrors the reference's controller choice in
-    InitializeHorovodOnce (operations.cc): env overrides first —
-    HOROVOD_CONTROLLER=python or HVD_TPU_PURE_PY=1 force the pure-Python
-    local core; any other value (auto/local/socket) prefers the native core.
+    InitializeHorovodOnce (operations.cc): HOROVOD_CONTROLLER=python or
+    HVD_TPU_PURE_PY=1 select the pure-Python local core; any other value
+    (auto/local/socket) means the native core, and a native core that does
+    not build or load is an error, not a reason to run something else.
     """
-    force_python = cfg.force_pure_python or cfg.controller == "python"
-    if not force_python:
-        try:
-            from ._core import NativeCore
+    if cfg.force_pure_python or cfg.controller == "python":
+        if cfg.size > 1:
+            raise HorovodInternalError(
+                "pure-Python core only supports single-process mode"
+            )
+        return PyLocalCore()
+    try:
+        from ._core import NativeCore
 
-            return NativeCore()
-        except Exception as exc:  # pragma: no cover - build-environment dependent
-            if cfg.size > 1 or cfg.controller == "socket":
-                raise HorovodInternalError(
-                    f"native core required for size={cfg.size} "
-                    f"(controller={cfg.controller}) but unavailable: {exc}"
-                ) from exc
-            log.debug("native core unavailable (%s); using pure-Python local core", exc)
-    if cfg.size > 1:
+        return NativeCore()
+    except Exception as exc:
         raise HorovodInternalError(
-            "pure-Python core only supports single-process mode"
-        )
-    return PyLocalCore()
+            f"native core unavailable (size={cfg.size}, "
+            f"controller={cfg.controller}): {exc}; set HVD_TPU_PURE_PY=1 "
+            "to run the single-process pure-Python core instead"
+        ) from exc
 
 
 class _ExecutorLane:

@@ -27,35 +27,10 @@ AxisName = Union[str, Sequence[str]]
 
 
 def axis_size(axis_name: AxisName) -> int:
-    try:
-        return lax.axis_size(axis_name)
-    except AttributeError:  # jax < 0.5: psum of a literal 1 is the idiom
-        return lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)
 
 
-def shard_map(*args, **kwargs):
-    """Version-portable ``jax.shard_map``.
-
-    Bridges two renames: the import moved from
-    ``jax.experimental.shard_map`` to top-level ``jax``, and the
-    replication-check kwarg flipped ``check_rep`` -> ``check_vma``.
-    Callers may pass either kwarg; whichever the installed jax rejects is
-    translated to the one it accepts.
-    """
-    try:
-        from jax import shard_map as sm
-    except ImportError:  # pre-top-level layout
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(*args, **kwargs)
-    except TypeError:
-        swaps = {"check_vma": "check_rep", "check_rep": "check_vma"}
-        for old, new in swaps.items():
-            if old in kwargs and new not in kwargs:
-                kwargs = dict(kwargs)
-                kwargs[new] = kwargs.pop(old)
-                return sm(*args, **kwargs)
-        raise
+shard_map = jax.shard_map
 
 
 def _axes_tuple(axis_name: AxisName):

@@ -37,6 +37,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..exceptions import HorovodInternalError
+from ..parallel import mesh as _mesh
+from ..utils.env import get_bool
 from ..utils.logging import get_logger
 from ..wire import DataType, OpType, ReduceOp, validate_alltoall_splits
 
@@ -45,14 +47,6 @@ log = get_logger()
 AXIS = "hvdev"
 
 _MIN_BUCKET = 1024
-
-
-def _shard_map():
-    try:
-        from jax import shard_map
-    except ImportError:  # pre-0.4.x layout
-        from jax.experimental.shard_map import shard_map
-    return shard_map
 
 
 _SUPPORTED_REDUCE = (ReduceOp.SUM, ReduceOp.AVERAGE, ReduceOp.MIN,
@@ -216,23 +210,34 @@ class DevicePlane:
         from jax.sharding import Mesh
 
         result = None
+        why = "this rank's jax process owns no addressable device"
         try:
             ranks = self._core.process_set_ranks(psid)
-            by_proc: Dict[int, Any] = {}
+            by_rank: Dict[int, Any] = {}
             for d in jax.devices():
-                by_proc.setdefault(d.process_index, d)
-            devs = [by_proc[r] for r in ranks]
-            my = by_proc.get(self._core.rank())
-            # hvd rank <-> jax process mapping comes from
-            # jax.distributed.initialize(process_id=cfg.rank) (basics.init);
-            # if the runtime was wired differently, "my" device may not be
-            # addressable — then the plane cannot place local shards.
+                by_rank.setdefault(_mesh.rank_of_process(d.process_index), d)
+            devs = [by_rank[r] for r in ranks]
+            my = by_rank.get(self._core.rank())
+            # hvd rank <-> jax process mapping is learned in basics.init
+            # (process_allgather of the ranks); if the runtime was wired
+            # differently, "my" device may not be addressable — then the
+            # plane cannot place local shards.
             if my is not None and my in jax.local_devices():
                 mesh = Mesh(np.asarray(devs), (AXIS,))
                 result = (mesh, list(ranks), my)
         except Exception as exc:  # noqa: BLE001 - capability probe
-            log.debug("device plane unavailable for set %d: %s", psid, exc)
-            result = None
+            why = f"{type(exc).__name__}: {exc}"
+        if result is None:
+            msg = (f"device plane unavailable for set {psid}: the jax "
+                   f"runtime ({jax.process_count()} process(es), "
+                   f"{jax.device_count()} device(s)) does not span its "
+                   f"ranks ({why})")
+            if get_bool("HOROVOD_JAX_DISTRIBUTED", False):
+                # The launcher was told to join the ranks into one jax
+                # runtime; a runtime that does not span them is a broken
+                # launch, not a reason to ship tensors over host TCP.
+                raise HorovodInternalError(msg)
+            log.debug(msg)
         if result is not None:
             # Cache successes only: a transient probe failure (e.g. the
             # jax distributed runtime still connecting at first enqueue)
@@ -290,7 +295,7 @@ class DevicePlane:
             import jax
             from jax import lax
             from jax.sharding import PartitionSpec as P
-            shard_map = _shard_map()
+            from jax import shard_map
 
             from .collectives import ensure_varying
 
@@ -339,7 +344,7 @@ class DevicePlane:
             import jax.numpy as jnp
             from jax import lax
             from jax.sharding import PartitionSpec as P
-            shard_map = _shard_map()
+            from jax import shard_map
 
             from .collectives import ensure_varying
 
@@ -372,7 +377,7 @@ class DevicePlane:
             import jax.numpy as jnp
             from jax import lax
             from jax.sharding import PartitionSpec as P
-            shard_map = _shard_map()
+            from jax import shard_map
 
             from .collectives import ensure_varying
 
@@ -402,7 +407,7 @@ class DevicePlane:
             import jax.numpy as jnp
             from jax import lax
             from jax.sharding import PartitionSpec as P
-            shard_map = _shard_map()
+            from jax import shard_map
 
             from .collectives import ensure_varying
 
@@ -439,7 +444,7 @@ class DevicePlane:
             import jax.numpy as jnp
             from jax import lax
             from jax.sharding import PartitionSpec as P
-            shard_map = _shard_map()
+            from jax import shard_map
 
             from .collectives import ensure_varying
 

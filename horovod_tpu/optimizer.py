@@ -31,11 +31,14 @@ communicates (and applies the inner optimizer) every k-th call, built with
 
 from __future__ import annotations
 
+import contextvars
 from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import optax
+from jax._src import config as _jax_config
+from jax._src.lax.parallel import all_gather_invariant
 
 from .compression import Compression
 from .mpi_ops import allreduce_async, synchronize, _is_traced
@@ -46,9 +49,27 @@ from .process_sets import ProcessSet, _resolve_psid
 from .wire import ReduceOp
 
 
+# The mesh axis a shard_optimizer_states chunk is split over, set while that
+# optimizer traces its inner transform (None elsewhere).  The chunk is
+# already summed over every other axis, and clip_by_global_norm must know.
+_inner_shard_axis = contextvars.ContextVar("hvd_inner_shard_axis",
+                                           default=None)
+
+
 def _resolve_axes(axis_name):
     ax = axis_name if axis_name is not None else _mesh.mesh_axis_name()
     return (ax,) if isinstance(ax, str) else tuple(ax)
+
+
+def _vma_tracked() -> bool:
+    """Whether the current trace types values by the mesh axes they vary
+    over (shard_map's ``check_vma``, on by default).  With it on, an empty
+    vma means "invariant: already reduced" — the gradient of replicated
+    parameters arrives that way, psummed by autodiff.  With it off every
+    value reports an empty vma and gradients are still per-shard.  Asked of
+    the trace itself: guessing it from the leaves (all invariant => off)
+    reduced the plain data-parallel step's gradients twice."""
+    return bool(_jax_config._check_vma.value)
 
 
 def _leaf_vma(leaf):
@@ -121,11 +142,7 @@ def _tree_allreduce(grads, op: ReduceOp, compression,
         return grads
     if _is_traced(leaves[0]):
         axes = _resolve_axes(axis_name)
-        # vma tracking is per-trace: with check_vma=False every leaf reports
-        # an empty vma, indistinguishable per-leaf from "fully pre-reduced".
-        # Gradients of any real model vary over the data axis, so if no leaf
-        # in the whole tree is marked varying, tracking must be off.
-        vma_tracked = any((_leaf_vma(l) or ()) for l in leaves)
+        vma_tracked = _vma_tracked()
         out = []
         for leaf in leaves:
             comp, ctx = compression.compress(leaf)
@@ -370,7 +387,7 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         for a in axes:
             world *= _jit_ops.axis_size(a)
         min_bytes = _jit_ops._device_codec_defaults()[1]
-        vma_tracked = any((_leaf_vma(l) or ()) for l in leaves)
+        vma_tracked = _vma_tracked()
         out, new_res = [], []
         for leaf, res in zip(leaves, rleaves):
             vma = _leaf_vma(leaf)
@@ -564,7 +581,7 @@ def _sharded_distributed_optimizer(optimizer: optax.GradientTransformation,
                 "(the rank's parameter shard feeds the inner optimizer)")
         leaves = jax.tree_util.tree_leaves(grads)
         axes = _axes()
-        vma_tracked = any((_leaf_vma(l) or ()) for l in leaves)
+        vma_tracked = _vma_tracked()
 
         def normalize(leaf):
             # A leaf invariant over some reduction axes was already summed
@@ -599,23 +616,21 @@ def _sharded_distributed_optimizer(optimizer: optax.GradientTransformation,
             for a in axes:
                 total_ranks *= _jit_ops.axis_size(a)
             gshard = gshard / total_ranks
-        upd_shard, new_inner = optimizer.update(gshard, state.inner_state,
-                                                state.master)
+        token = _inner_shard_axis.set(shard_ax)
+        try:
+            upd_shard, new_inner = optimizer.update(
+                gshard, state.inner_state, state.master)
+        finally:
+            _inner_shard_axis.reset(token)
         # fp32 master weights: the update lands on the master shard, and
         # the pytree update emitted is cast(new master) - current param, so
         # params track the master exactly (no bf16 sub-ulp loss).
         new_master = state.master + upd_shard
         # Varying -> Invariant gather: every rank assembles the identical
         # full master vector, and its type says so (out_specs expecting
-        # replicated params keep working).  Falls back to the plain
-        # (varying) all_gather on jax versions without the invariant form.
-        try:
-            from jax._src.lax.parallel import all_gather_invariant
-            master_vec = all_gather_invariant(new_master, shard_ax,
-                                              tiled=True)[:total]
-        except ImportError:  # pragma: no cover - older jax
-            master_vec = lax.all_gather(new_master, shard_ax,
-                                        tiled=True)[:total]
+        # replicated params keep working).
+        master_vec = all_gather_invariant(new_master, shard_ax,
+                                          tiled=True)[:total]
         updates = []
         offset = 0
         for leaf in pleaves:
@@ -657,20 +672,14 @@ def clip_by_global_norm(max_norm: float, axis_name=None
         local = sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
                     for l in leaves)
         if axis_name is not None:
-            # Only psum over axes the squared norm actually VARIES over.
-            # Inside shard_optimizer_states the chunk was already psummed
-            # over every non-shard axis (it is invariant there), so a blind
-            # psum over all resolved axes would inflate the norm by
-            # prod(size(non-shard axes)) and over-clip.  With check_vma
-            # off every leaf reports an EMPTY vma, indistinguishable from
-            # all-invariant — the vma_tracked guard (same idiom as the
-            # reduce paths above) falls back to psumming all axes then,
-            # matching the previous behavior.
+            # As the inner transform of shard_optimizer_states the chunk
+            # is already summed over every non-shard axis: psumming the
+            # squared norm there too would inflate it by their sizes and
+            # over-clip, so only the shard axis counts.
             axes = _resolve_axes(axis_name)
-            vma_tracked = any((_leaf_vma(l) or ()) for l in leaves)
-            if vma_tracked:
-                vma = _leaf_vma(local) or ()
-                axes = tuple(a for a in axes if a in vma)
+            shard_ax = _inner_shard_axis.get()
+            if shard_ax is not None:
+                axes = tuple(a for a in axes if a == shard_ax)
             if axes:
                 local = lax.psum(local, axes)
         norm = jnp.sqrt(local)

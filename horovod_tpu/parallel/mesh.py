@@ -20,6 +20,13 @@ HVD_AXIS = "hvd"
 
 _global_mesh = None
 
+# jax process index -> hvd rank, learned at hvd.init() under jax.distributed.
+# On a TPU a process's index follows its chips' place in the topology, not
+# the process_id it gave the coordinator, so the two need not coincide (four
+# one-chip workers on a v5e 2x2: ranks 0,1,2,3 are processes 0,2,3,1).
+# Empty means identity.
+_process_ranks: dict = {}
+
 
 def build_global_mesh(axis_name: str = HVD_AXIS, devices=None):
     """Build (and remember) the 1-D global mesh over all visible devices."""
@@ -66,6 +73,17 @@ def set_global_mesh(mesh) -> None:
 def reset() -> None:
     global _global_mesh
     _global_mesh = None
+    _process_ranks.clear()
+
+
+def set_process_ranks(mapping: dict) -> None:
+    _process_ranks.clear()
+    _process_ranks.update(mapping)
+
+
+def rank_of_process(process_index: int) -> int:
+    """The hvd rank of the jax process that owns a device."""
+    return _process_ranks.get(process_index, process_index)
 
 
 def mesh_axis_name() -> str:
@@ -84,7 +102,8 @@ def sub_mesh(ranks: Sequence[int], axis_name: Optional[str] = None):
     from jax.sharding import Mesh
 
     axis_name = axis_name or mesh_axis_name()
-    devices = [d for d in jax.devices() if getattr(d, "process_index", 0) in ranks]
+    devices = [d for d in jax.devices()
+               if rank_of_process(d.process_index) in ranks]
     if not devices:
         # Single-process simulation: treat local device i as "rank i"'s device.
         all_devices = jax.devices()

@@ -25,7 +25,7 @@ from typing import Dict, List, Optional
 
 from .util import (assign_ranks, find_free_port, forwardable_env,
                    local_hostnames, parse_hosts, pin_tpu_chip,
-                   ssh_command)
+                   ssh_command, tpu_chips_on_host)
 
 
 def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
@@ -321,7 +321,8 @@ class WorkerProcesses:
     def launch(self, assignments, command: List[str], base_env: Dict[str, str],
                rendezvous_addr: str, rendezvous_port: int,
                ssh_port: Optional[int] = None, verbose: bool = False,
-               stream_prefix: bool = True):
+               stream_prefix: bool = True,
+               tpu_process_ports: Optional[List[int]] = None):
         threads = []
         for a in assignments:
             env = dict(base_env)
@@ -336,7 +337,8 @@ class WorkerProcesses:
                 "HOROVOD_GLOO_RENDEZVOUS_ADDR": rendezvous_addr,
                 "HOROVOD_GLOO_RENDEZVOUS_PORT": str(rendezvous_port),
             })
-            pin_tpu_chip(env, a["local_rank"], a["local_size"])
+            pin_tpu_chip(env, a["local_rank"], a["local_size"],
+                         process_ports=tpu_process_ports)
             if a["hostname"] in local_hostnames():
                 proc = subprocess.Popen(
                     command, env=env, stdout=subprocess.PIPE,
@@ -479,9 +481,17 @@ def _run(args: argparse.Namespace) -> int:
         base_env["HOROVOD_JAX_COORDINATOR"] = \
             f"{rendezvous_addr}:{coord_port}"
 
+    tpu_process_ports = None
+    if (args.jax_distributed and len(assignments) > 1 and tpu_chips_on_host()
+            and all(a["hostname"] in local_hostnames() for a in assignments)):
+        # Several workers, one chip each, on this TPU host, in ONE jax
+        # runtime: libtpu needs the process grid as well as the chip pin.
+        tpu_process_ports = [find_free_port() for _ in assignments]
+
     workers = WorkerProcesses()
     workers.launch(assignments, command, base_env, rendezvous_addr,
-                   rendezvous_port, args.ssh_port, args.verbose)
+                   rendezvous_port, args.ssh_port, args.verbose,
+                   tpu_process_ports=tpu_process_ports)
     try:
         return workers.wait()
     except KeyboardInterrupt:

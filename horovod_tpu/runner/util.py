@@ -6,7 +6,7 @@ import dataclasses
 import os
 import shlex
 import socket
-from typing import List
+from typing import List, Optional, Sequence
 
 
 @dataclasses.dataclass
@@ -180,8 +180,25 @@ def ssh_command(ssh_port=None, connect_timeout=None) -> List[str]:
     return cmd
 
 
+def tpu_chips_on_host() -> int:
+    """How many TPU chips this host exposes, read from the device nodes
+    libtpu itself enumerates (``/dev/accel*``, or ``/dev/vfio/<n>`` on newer
+    hosts) — without loading libtpu, which would claim them."""
+    import glob
+
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+# libtpu's process grid (TPU_PROCESS_BOUNDS) over one host's chips when each
+# co-located worker owns one chip.  Only what has run on hardware is listed:
+# four workers on a v5e 2x2 host.
+_HOST_PROCESS_BOUNDS = {4: "2,2,1"}
+
+
 def pin_tpu_chip(env: dict, local_rank: int, local_size: int,
-                 force: bool = False) -> None:
+                 force: bool = False,
+                 process_ports: Optional[Sequence[int]] = None) -> None:
     """Pin a co-located worker to its own TPU chip (libtpu is single-owner
     per chip — the GPU analog is the local-rank device pinning the
     reference's launcher relies on).
@@ -193,6 +210,15 @@ def pin_tpu_chip(env: dict, local_rank: int, local_size: int,
     With several co-located workers a single inherited ``TPU_VISIBLE_CHIPS``
     would hand every worker the same chip and crash all but the first
     claim, so it is overridden per worker.
+
+    The visible chip and the one-chip-per-process bounds alone give each
+    worker a one-chip world of its own: fine for workers that only meet on
+    the host plane.  ``process_ports`` (one free local port per co-located
+    worker, the same list for all of them) asks for ONE runtime across the
+    workers instead, which ``--jax-distributed`` needs: libtpu then also
+    reads the process grid, every process's address, this process's port
+    and its task id, and the workers see ``local_size`` devices, one of
+    them local, joined over ICI.
     """
     if local_size <= 1 and not force:
         # A lone worker keeps all chips; its explicit pin (if any) is
@@ -209,3 +235,18 @@ def pin_tpu_chip(env: dict, local_rank: int, local_size: int,
         env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
     env["TPU_VISIBLE_CHIPS"] = str(local_rank)
     env.setdefault("TPU_CHIPS_PER_PROCESS_BOUNDS", "1,1,1")
+    if process_ports is None:
+        return
+    if local_size not in _HOST_PROCESS_BOUNDS:
+        raise ValueError(
+            f"no known TPU process grid for {local_size} co-located workers "
+            f"in one jax runtime (known: {sorted(_HOST_PROCESS_BOUNDS)}); "
+            "run one worker per host, or as many as the host has chips")
+    if len(process_ports) != local_size:
+        raise ValueError(f"need {local_size} process ports, got "
+                         f"{len(process_ports)}")
+    env["TPU_PROCESS_BOUNDS"] = _HOST_PROCESS_BOUNDS[local_size]
+    env["TPU_PROCESS_ADDRESSES"] = ",".join(
+        f"localhost:{p}" for p in process_ports)
+    env["TPU_PROCESS_PORT"] = str(process_ports[local_rank])
+    env["CLOUD_TPU_TASK_ID"] = str(local_rank)

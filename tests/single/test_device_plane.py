@@ -398,22 +398,31 @@ def test_np1_allgather_alltoall_device_identity(hvd_single, transfer_guard):
     np.testing.assert_allclose(np.asarray(recv), [3])
 
 
-def test_shard_map_import_shim():
-    """_shard_map() tolerates both jax layouts: the top-level jax.shard_map
-    (0.4.35+) and the jax.experimental.shard_map fallback — whichever this
-    jax exposes, the shim must return a callable that actually binds a
-    mesh axis (PR 17 satellite: the gspmd plane discriminates conventions
-    on exactly that binding)."""
-    from horovod_tpu.ops.device_plane import _shard_map
+def test_public_shard_map_is_jax_shard_map():
+    """One installation, one name: the package's public shard_map is
+    jax.shard_map itself and binds a mesh axis (the gspmd plane
+    discriminates calling conventions on exactly that binding)."""
+    from horovod_tpu.ops.collectives import shard_map as sm
 
-    sm = _shard_map()
-    assert callable(sm)
+    assert sm is jax.shard_map
     mesh = Mesh(np.asarray(jax.devices()[:4]), (AXIS,))
-    try:
-        fn = sm(lambda x: jax.lax.psum(x, AXIS), mesh=mesh,
-                in_specs=P(AXIS), out_specs=P(AXIS), check_rep=False)
-    except TypeError:  # newer jax renamed the kwarg
-        fn = sm(lambda x: jax.lax.psum(x, AXIS), mesh=mesh,
-                in_specs=P(AXIS), out_specs=P(AXIS), check_vma=False)
+    fn = sm(lambda x: jax.lax.psum(x, AXIS), mesh=mesh,
+            in_specs=P(AXIS), out_specs=P(AXIS), check_vma=False)
     x = jnp.ones((4, 2), jnp.float32)
     np.testing.assert_allclose(np.asarray(fn(x)), np.full((4, 2), 4.0))
+
+
+def test_rank_of_process_follows_the_learned_mapping():
+    """On a TPU a jax process's index follows its chips, not the process_id
+    it gave the coordinator (four one-chip workers on a v5e 2x2: ranks
+    0,1,2,3 are processes 0,2,3,1).  hvd.init() learns the mapping; without
+    one the two coincide."""
+    from horovod_tpu.parallel import mesh as pm
+
+    assert [pm.rank_of_process(p) for p in range(4)] == [0, 1, 2, 3]
+    pm.set_process_ranks({0: 0, 2: 1, 3: 2, 1: 3})
+    try:
+        assert [pm.rank_of_process(p) for p in range(4)] == [0, 3, 1, 2]
+    finally:
+        pm.reset()
+    assert pm.rank_of_process(2) == 2

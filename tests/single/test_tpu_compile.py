@@ -1,0 +1,121 @@
+"""Compile the main path's Pallas kernels for the TPU v5e with the chip's
+own compiler, on a described (not attached) ``v5e:2x2`` topology.
+
+Interpret mode cannot show what Mosaic refuses: a block not aligned to the
+tiling, more VMEM than a kernel may use, a kernel that cannot be
+partitioned.  Nothing runs here, so these say nothing about results or
+times; chip_smoke.py does.  This is the only file that describes the chip:
+the topology is described inside a fixture (never at import, not autouse,
+not in conftest.py) and every compile happens in the test's own process,
+because one process at a time may load the TPU's library.
+"""
+
+import functools
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from horovod_tpu.ops import quantize as qz
+from horovod_tpu.ops.flash_attention import (
+    flash_attention, flash_attention_with_lse)
+from horovod_tpu.parallel.ring_attention import ring_attention
+
+# [batch, seq, heads, head_dim]: the long-context shape bench.py times, and
+# GPT-2-small's attention at the batch chip_smoke.py trains.
+SHAPES = {"4x2048x8x128": (4, 2048, 8, 128), "8x1024x12x64": (8, 1024, 12, 64)}
+CODEC_ELEMS = 1 << 22
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _qkv(shape, sharding):
+    return (jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding),) * 3
+
+
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
+def test_flash_forward_compiles(one_chip, shape):
+    text = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False),
+        *_qkv(shape, one_chip))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
+def test_flash_backward_compiles(one_chip, shape):
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       interpret=False).astype(jnp.float32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          *_qkv(shape, one_chip))
+    # forward (recomputed for the residuals), dq, and dk/dv kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
+def test_flash_out_lse_pair_compiles(one_chip, shape):
+    """The variant the ring hop differentiates through: a cotangent on lse."""
+    def loss(q, k, v):
+        out, lse = flash_attention_with_lse(q, k, v, causal=True,
+                                            interpret=False)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          *_qkv(shape, one_chip))
+    assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("codec", ["int8", "int4", "int8g"])
+def test_codec_encode_decode_compiles(one_chip, codec):
+    def roundtrip(flat):
+        codes, scales = qz.quantize(flat, codec, interpret=False)
+        return qz.dequantize(codes, scales, CODEC_ELEMS, codec,
+                             interpret=False)
+
+    text = _compiled_text(roundtrip, jax.ShapeDtypeStruct(
+        (CODEC_ELEMS,), jnp.float32, sharding=one_chip))
+    assert text.count("tpu_custom_call") >= 2     # encode and decode
+
+
+def test_flash_ring_attention_compiles_on_four_chips(topo, monkeypatch):
+    """ring_attention(use_flash=True) under shard_map over the four
+    described chips: Pallas inside lax.switch inside fori_loop, with the
+    K/V rotation between hops."""
+    # ring_attention leaves interpret=None, and the dispatch then asks which
+    # backend is attached; here that is the CPU, so steer it in the test.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("sp",))
+    spec = P(None, "sp")
+    fn = jax.shard_map(
+        functools.partial(ring_attention, axis_name="sp", causal=True,
+                          use_flash=True),
+        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False)
+    text = _compiled_text(fn, *_qkv((2, 4 * 1024, 12, 64),
+                                    NamedSharding(mesh, spec)))
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text

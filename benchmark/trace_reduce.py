@@ -1,0 +1,277 @@
+"""From a profiler trace to per-layer metrics.
+
+The core is pure Python over ``Event`` tuples ``(name, start_ns, dur_ns,
+line)`` so that tests feed it hand-made events; ``read_xplane`` is the thin
+adapter that fills a ``Trace`` from an ``.xplane.pb`` with
+``jax.profiler.ProfileData`` (nothing but JAX is needed to read one).
+
+The readers at the bottom are what ``layer_metrics/<name>.json`` files name
+as ``"reducer": "trace_reduce.<function>"``.  A reader takes the ``Trace``,
+the run's context (``run.py:reader_context``: the traced run, the cell, its
+configuration and traffic, the chip's peaks) and the file's ``params``; it
+returns a number, or None when there is nothing to read (the harness then
+leaves the metric out).
+"""
+
+from __future__ import annotations
+
+import re
+from typing import Iterable, NamedTuple, Optional
+
+
+class Event(NamedTuple):
+    name: str
+    start_ns: float
+    dur_ns: float
+    line: str
+
+    @property
+    def end_ns(self) -> float:
+        return self.start_ns + self.dur_ns
+
+
+SYNC_LINE = "XLA Ops"          # what the core executes, one op at a time
+ASYNC_LINE = "Async XLA Ops"   # copies and collectives in flight beside it
+
+
+class Trace(NamedTuple):
+    """One traced window: per-device op events of both lines, the
+    benchmark's own host spans (``bench_*`` TraceAnnotations), the window
+    and its step count."""
+
+    devices: dict          # device index -> [Event] (any order)
+    host: list             # [Event] of the benchmark's host spans
+    window: tuple          # (start_ns, end_ns)
+    steps: int
+
+
+WINDOW_SPAN = "bench_window"
+
+# ---------------------------------------------------------------------------
+# Core: intervals
+# ---------------------------------------------------------------------------
+
+
+def merge(intervals: Iterable[tuple]) -> list:
+    """Sorted, disjoint intervals covering the same points."""
+    out = []
+    for start, end in sorted(i for i in intervals if i[1] > i[0]):
+        if out and start <= out[-1][1]:
+            if end > out[-1][1]:
+                out[-1] = (out[-1][0], end)
+        else:
+            out.append((start, end))
+    return out
+
+
+def clip(events: Iterable[Event], window: tuple) -> list:
+    """The events' intervals cut to ``window``."""
+    lo, hi = window
+    return [(max(e.start_ns, lo), min(e.end_ns, hi)) for e in events
+            if e.end_ns > lo and e.start_ns < hi]
+
+
+def length(merged: list) -> float:
+    return sum(end - start for start, end in merged)
+
+
+def subtract(merged: list, cover: list) -> list:
+    """The parts of ``merged`` that ``cover`` (merged too) does not touch."""
+    out, j = [], 0
+    for start, end in merged:
+        while j < len(cover) and cover[j][1] <= start:
+            j += 1
+        k, at = j, start
+        while k < len(cover) and cover[k][0] < end:
+            if cover[k][0] > at:
+                out.append((at, cover[k][0]))
+            at = max(at, cover[k][1])
+            k += 1
+        if at < end:
+            out.append((at, end))
+    return out
+
+
+def classify(events: Iterable[Event], pattern: str) -> tuple:
+    """(matching, others) by a regular expression searched in the name."""
+    rx = re.compile(pattern)
+    hit, miss = [], []
+    for e in events:
+        (hit if rx.search(e.name) else miss).append(e)
+    return hit, miss
+
+
+def sync_ops(events: Iterable[Event]) -> list:
+    """The ops the core itself executes (not the async line beside them)."""
+    return [e for e in events if e.line != ASYNC_LINE]
+
+
+def busy_ns(events: Iterable[Event], window: tuple) -> float:
+    """Time in ``window`` during which at least one event runs."""
+    return length(merge(clip(events, window)))
+
+
+def idle_share(events: Iterable[Event], window: tuple) -> float:
+    """Share of ``window`` in which the core executes no op."""
+    return 1.0 - busy_ns(sync_ops(events), window) / (window[1] - window[0])
+
+
+def exposed_ns(events: Iterable[Event], pattern: str, window: tuple) -> float:
+    """Time during which an op matching ``pattern`` runs (on either line)
+    and the core executes no other op: the part of a collective that compute
+    does not hide."""
+    hit, miss = classify(events, pattern)
+    return length(subtract(merge(clip(hit, window)),
+                           merge(clip(sync_ops(miss), window))))
+
+
+def per_step(total_ns: float, steps: int) -> float:
+    """Nanoseconds over a window of ``steps`` steps -> milliseconds a step."""
+    return total_ns / steps / 1e6
+
+
+def short_name(name: str) -> str:
+    """``%fusion.45 = (f32[256]{...}, ...) fusion(...)`` -> ``fusion.45``:
+    the trace names a TPU op by its whole HLO instruction."""
+    return name.split(" = ", 1)[0].lstrip("%")
+
+
+def op_group(name: str) -> str:
+    """``fusion.45`` -> ``fusion``, ``attn.99`` -> ``attn``,
+    ``fusion.4106.remat`` -> ``fusion``: the instruction's name without its
+    index.  XLA names a fusion for what it computes and a kernel call for
+    the module scope that made it, so the groups mean something; single
+    instructions (3,669 a step in ResNet-50) do not fit ten rows."""
+    return re.sub(r"(\.\d+|\.remat\d*|\.clone)+$", "", short_name(name))
+
+
+def top_ops(events: Iterable[Event], window: tuple, n: int = 10) -> list:
+    """``[[group, seconds], ...]``: the groups of ops (``op_group``) the
+    core spent most time in."""
+    total = {}
+    for e in sync_ops(events):
+        for start, end in clip([e], window):
+            name = op_group(e.name)
+            total[name] = total.get(name, 0.0) + (end - start)
+    ranked = sorted(total.items(), key=lambda kv: -kv[1])[:n]
+    return [[name, ns / 1e9] for name, ns in ranked]
+
+
+def idle_gaps(events: Iterable[Event], host: Iterable[Event], window: tuple,
+              n: int = 10) -> list:
+    """``[[what the host was doing, seconds], ...]``: the longest stretches
+    of ``window`` with no device op, each named by the benchmark host span
+    that overlaps it most (``(no host span)`` if none does)."""
+    gaps = subtract([tuple(window)], merge(clip(sync_ops(events), window)))
+    gaps = sorted(gaps, key=lambda g: g[0] - g[1])[:n]
+    host = [h for h in host if h.name != WINDOW_SPAN]
+    out = []
+    for start, end in gaps:
+        best, best_overlap = "(no host span)", 0.0
+        for h in host:
+            overlap = min(end, h.end_ns) - max(start, h.start_ns)
+            if overlap > best_overlap:
+                best, best_overlap = h.name, overlap
+        out.append([best, (end - start) / 1e9])
+    return out
+
+
+def mean_over_devices(trace: Trace, fn) -> Optional[float]:
+    values = [fn(events) for _, events in sorted(trace.devices.items())]
+    return sum(values) / len(values) if values else None
+
+
+# ---------------------------------------------------------------------------
+# Adapter: .xplane.pb -> Trace
+# ---------------------------------------------------------------------------
+
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+HOST_SPAN_PREFIX = "bench_"
+
+
+def read_xplane(path: str, steps: int) -> Trace:
+    """Op events (both lines) of every device plane, the benchmark's host
+    spans, and the window the ``bench_window`` span marks (or, without one,
+    first device op start to last device op end)."""
+    from jax.profiler import ProfileData
+
+    if path.endswith(".gz"):  # the recorded trace of the tests
+        import gzip
+
+        with gzip.open(path, "rb") as f:
+            data = ProfileData.from_serialized_xspace(f.read())
+    else:
+        data = ProfileData.from_file(path)
+    devices, host = {}, []
+    for plane in data.planes:
+        m = DEVICE_PLANE.match(plane.name)
+        if m:
+            ops = devices.setdefault(int(m.group(1)), [])
+            for line in plane.lines:
+                if line.name in (SYNC_LINE, ASYNC_LINE):
+                    ops.extend(Event(e.name, e.start_ns, e.duration_ns,
+                                     line.name) for e in line.events)
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                host.extend(Event(e.name, e.start_ns, e.duration_ns,
+                                  line.name) for e in line.events
+                            if e.name.startswith(HOST_SPAN_PREFIX))
+    devices = {d: ops for d, ops in devices.items() if ops}
+    marks = [h for h in host if h.name == WINDOW_SPAN]
+    if marks:
+        window = (marks[0].start_ns, marks[0].end_ns)
+    elif devices:
+        every = [e for ops in devices.values() for e in ops]
+        window = (min(e.start_ns for e in every), max(e.end_ns for e in every))
+    else:
+        window = (0.0, 0.0)
+    return Trace(devices, host, window, steps)
+
+
+# ---------------------------------------------------------------------------
+# Readers named by layer_metrics/*.json
+# ---------------------------------------------------------------------------
+
+
+def host_dispatch_ms(trace: Trace, ctx: dict, **_) -> Optional[float]:
+    """Host-clock time inside the ``step(...)`` call, mean per step of the
+    traced window (the loop's own ``perf_counter`` stamps, not the trace)."""
+    spans = (ctx.get("run") or {}).get("dispatch_s") or []
+    return sum(spans) / len(spans) * 1e3 if spans else None
+
+
+def device_idle_pct(trace: Trace, ctx: dict, **_) -> Optional[float]:
+    share = mean_over_devices(trace, lambda ev: idle_share(ev, trace.window))
+    return None if share is None else 100.0 * share
+
+
+def op_time_ms(trace: Trace, ctx: dict, pattern: str, exposed: bool = False,
+               **_) -> Optional[float]:
+    """Device time a step spends in ops whose name matches ``pattern``, on
+    either line (union of their intervals, so an async collective and its
+    start/done ops count once); with ``exposed``, only the part during which
+    the core executes no other op.  None when no op of any device matches."""
+    if not any(classify(ev, pattern)[0] for ev in trace.devices.values()):
+        return None
+
+    def one(events):
+        if exposed:
+            return exposed_ns(events, pattern, trace.window)
+        return busy_ns(classify(events, pattern)[0], trace.window)
+
+    return per_step(mean_over_devices(trace, one), trace.steps)
+
+
+def roofline_pct(trace: Trace, ctx: dict, pattern: str, least: str,
+                 **_) -> Optional[float]:
+    """Least time the chip could take for a kernel group in one step over
+    the device time of the ops matching ``pattern``.  ``least`` names the
+    function (``<module>.<function>`` under ``benchmark/``) that computes the
+    least time from the context's shapes and peaks: it returns a dict with
+    ``seconds``, and lives beside the operations and bytes it counts."""
+    from benchmark import common
+
+    took = op_time_ms(trace, ctx, pattern)
+    if not took:
+        return None
+    return 100.0 * common.load_function(least)(ctx)["seconds"] * 1e3 / took

@@ -1,0 +1,588 @@
+"""The benchmark's own files: every name resolves, the arithmetic of the
+yardstick (FLOPs, trace reduction, end-to-end statistics) against hand
+numbers, and ``run.py --rehearse`` end to end at tiny sizes on the CPU.
+
+Nothing here measures anything: a CPU run says what the program counts and
+that the control flow is right.  No TPU topology is described anywhere in
+this file."""
+
+import glob
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+BENCH = os.path.join(REPO, "benchmark")
+
+from benchmark import common, flops, run, trace_reduce  # noqa: E402
+from benchmark import traffic as traffic_gen  # noqa: E402
+from benchmark.trace_reduce import Event, Trace  # noqa: E402
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+DATA_DIRS = ("configs", "traffic", "layer_metrics")
+DATA_FILES = sorted(
+    os.path.relpath(p, BENCH) for d in DATA_DIRS
+    for p in glob.glob(os.path.join(BENCH, d, "*.json")))
+
+
+def _spec():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def _load(rel):
+    with open(os.path.join(BENCH, rel)) as f:
+        return json.load(f)
+
+
+# ---------------------------------------------------------------------------
+# Files and names
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("rel", DATA_FILES)
+def test_data_file_loads_and_is_named_for_its_content(rel):
+    data = _load(rel)
+    assert data["name"] == os.path.splitext(os.path.basename(rel))[0]
+    assert NAME.match(data["name"])
+    kind = os.path.dirname(rel)
+    if kind == "configs":
+        family = run.load_family(data["family"])
+        for fn in ("setup", "inputs", "model_flops", "reference", "build",
+                   "checks", "units"):
+            assert callable(getattr(family, fn)), (data["family"], fn)
+        assert data["source"].startswith("http")
+        assert isinstance(data["reduced"], list)
+        assert common.make_optimizer(data["optimizer"]).update
+    elif kind == "traffic":
+        for sizes in (traffic_gen.resolve(data, False),
+                      traffic_gen.resolve(data, True)):
+            assert sizes["batch_per_chip"] > 0
+            assert sizes["distinct_batches"] >= 1
+            assert sizes["warmup_steps"] >= 1 and sizes["trace_steps"] >= 2
+            assert "rehearse" not in sizes
+    else:
+        assert callable(common.load_function(data["reducer"]))
+        # What BENCHMARK.json states of a metric is stated there alone.
+        assert set(data) <= {"name", "reducer", "params", "what",
+                             "pattern_note"}
+
+
+@pytest.mark.parametrize("group,key,folder", [
+    ("configs", "name", "configs"), ("workloads", "traffic", "traffic"),
+    ("per_layer", "name", "layer_metrics")])
+def test_every_name_resolves_to_a_file_and_back(group, key, folder):
+    """What makes the harness data-driven: what an entry of BENCHMARK.json
+    names is a file, and every file is named by an entry."""
+    named = {e[key] for e in _spec()[group]}
+    files = {os.path.splitext(os.path.basename(p))[0]
+             for p in DATA_FILES if p.startswith(folder + "/")}
+    assert named == files
+    for entry in _spec()["configs"] if group == "configs" else ():
+        data = _load(f"configs/{entry['name']}.json")
+        assert entry["file"] == f"benchmark/configs/{entry['name']}.json"
+        assert (entry["source"], entry["reduced"]) == (data["source"],
+                                                       data["reduced"])
+    for w in _spec()["workloads"]:
+        assert run.cell_entry(_spec(), w["name"]) == w
+        assert w["config"] in {c["name"] for c in _spec()["configs"]}
+
+
+def test_benchmark_json_keeps_the_contract():
+    spec = _spec()
+    assert set(spec) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert spec["command"] == ["python", "benchmark/run.py"]
+    assert 1 <= spec["run_seconds"] <= 51
+    e2e = {m["name"]: m for m in spec["end_to_end"]}
+    assert "setup_s" in e2e and len(e2e) >= 2
+    for m in spec["end_to_end"]:
+        assert set(m) == {"name", "unit", "better", "bound", "source"}
+        assert 0.01 <= m["bound"] <= 0.1
+        assert m["source"] in ("host_clock", "device_trace")
+    cells = {w["name"] for w in spec["workloads"]}
+    for m in spec["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
+                                          "layer", "moves"}
+        assert m["moves"] in e2e
+        assert set(m.get("workloads", [])) <= cells
+    names = [x["name"] for g in ("configs", "workloads", "end_to_end",
+                                 "per_layer") for x in spec[g]]
+    assert all(NAME.match(n) for n in names)
+    pairs = [(w["config"], w["traffic"]) for w in spec["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+    four = sum(w["chips"] == 4 for w in spec["workloads"])
+    assert four <= max(1, len(cells) // 4)
+    for w in spec["workloads"]:  # every cell reports a per-layer metric
+        assert run.metrics_of(spec, "per_layer", w["name"])
+
+
+def test_unknown_device_kind_is_an_error_and_v5e_is_on_record():
+    table = _load("peaks.json")["peaks"]
+    v5e = flops.chip_peaks("TPU v5 lite", table)
+    assert v5e["bf16_flops_per_s"] == 197e12
+    assert v5e["hbm_bytes_per_s"] == 819e9
+    assert flops.chip_peaks("TPU v5p", table)["bf16_flops_per_s"] == 459e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        flops.chip_peaks("TPU v9 imaginary", table)
+
+
+# ---------------------------------------------------------------------------
+# flops.py against hand numbers
+# ---------------------------------------------------------------------------
+
+
+def _shapes(fn):
+    import jax
+
+    return jax.eval_shape(fn)
+
+
+def test_flops_of_one_convolution():
+    import jax
+    import jax.numpy as jnp
+
+    x = jax.ShapeDtypeStruct((2, 16, 16, 8), jnp.float32)
+    w = jax.ShapeDtypeStruct((3, 3, 8, 32), jnp.float32)
+
+    def conv(x, w):
+        return jax.lax.conv_general_dilated(
+            x, w, (2, 2), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+    # 2 x 8 x 8 x 32 outputs, each a 3 x 3 x 8 window.
+    assert flops.forward_macs(conv, x, w) == 2 * 8 * 8 * 32 * 72
+    assert flops.train_flops(100.0) == 600.0
+
+
+def test_flops_of_one_gpt_block():
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import models
+    from horovod_tpu.models.gpt import GPTBlock
+
+    cfg = dataclasses.replace(models.GPT_TINY, hidden_size=64, num_heads=4)
+    block = GPTBlock(cfg)
+    s, h = 32, 64
+    x = jax.ShapeDtypeStruct((1, s, h), jnp.float32)
+    params = _shapes(lambda: block.init(jax.random.key(0),
+                                        jnp.zeros((1, s, h))))
+    dense = 12 * h * h * s           # qkv 3h^2, out h^2, mlp 8h^2 per token
+    attention = 2 * s * s * h        # QK^T and PV over all heads
+    assert flops.forward_macs(block.apply, params, x) == dense + attention
+    assert flops.forward_macs(block.apply, params, x,
+                              batched_scale=0.5) == dense + attention / 2
+
+
+@pytest.mark.parametrize("model,gmacs", [
+    ("resnet50", 4.0892), ("gpt2-medium", 413.525)])
+def test_flops_of_the_published_models(model, gmacs):
+    """ResNet-50 at 224^2: 4.089 GMACs forward per image (24.5 GFLOP forward
+    + backward).  GPT-2-medium at 1024 tokens: 413.5 GMACs forward per
+    sequence with full attention, 2.272 GFLOP per token forward + backward
+    with causal attention halved."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import models
+
+    if model == "resnet50":
+        m = models.ResNet50(num_classes=1000, dtype=jnp.bfloat16)
+        v = _shapes(lambda: m.init(jax.random.key(0),
+                                   jnp.zeros((2, 224, 224, 3)), train=False))
+        x = jax.ShapeDtypeStruct((1, 224, 224, 3), jnp.bfloat16)
+        macs = flops.forward_macs(lambda v, x: m.apply(v, x, train=False),
+                                  v, x)
+        assert flops.train_flops(macs) / 1e9 == pytest.approx(24.535, abs=1e-3)
+    else:
+        m = models.GPT(models.GPTConfig(hidden_size=1024, num_layers=24,
+                                        num_heads=16, use_flash=False))
+        v = _shapes(lambda: m.init(jax.random.key(0),
+                                   jnp.zeros((1, 32), jnp.int32)))
+        assert sum(x.size for x in jax.tree_util.tree_leaves(v)) == 406382592
+        ids = jax.ShapeDtypeStruct((1, 1024), jnp.int32)
+        macs = flops.forward_macs(m.apply, v, ids)
+        causal = flops.forward_macs(m.apply, v, ids, batched_scale=0.5)
+        assert flops.train_flops(causal) / 1024 / 1e9 == pytest.approx(
+            2.272, abs=1e-3)
+    assert macs / 1e9 == pytest.approx(gmacs, abs=1e-3)
+
+
+def test_flash_least_seconds_by_hand():
+    # One layer, one head, one sequence of 1024 x 64 in bf16, causal:
+    # an S x S x D matmul is 2 * 1024 * 1024 * 64 = 134.2 MFLOP, halved.
+    one = 2 * 1024 * 1024 * 64 * 0.5
+    array = 1024 * 64 * 2
+    got = flops.flash_least_seconds(1, 1, 1024, 64, 1, True, 2,
+                                    peak_flops=1e12, peak_bytes_per_s=1e9)
+    k = got["kernels"]
+    assert [k[n]["flops"] for n in ("fwd", "dq", "dkv")] == [
+        2 * one, 3 * one, 4 * one]
+    assert k["fwd"]["bytes"] == 4 * array + 1024 * 4
+    assert k["dkv"]["bytes"] == 6 * array + 2 * 1024 * 4
+    # At 1 TFLOP/s against 1 GB/s every kernel is bound by its bytes.
+    assert {v["bound"] for v in k.values()} == {"bytes"}
+    assert got["seconds"] == pytest.approx(got["bytes"] / 1e9)
+    fast = flops.flash_least_seconds(8, 16, 1024, 64, 24, True, 2,
+                                     197e12, 819e9)
+    assert {v["bound"] for v in fast["kernels"].values()} == {"flops"}
+    assert fast["seconds"] == pytest.approx(9 * one * 128 * 24 / 197e12)
+
+
+# ---------------------------------------------------------------------------
+# The reduction's core on hand-made events
+# ---------------------------------------------------------------------------
+
+# One device, window 0..1000 ns, two steps.  fusion.1 and all-reduce.1
+# overlap for 100 ns; all-reduce.2 runs alone.
+EVENTS = [
+    Event("fusion.1", 0, 300, "XLA Ops"),
+    Event("all-reduce.1", 200, 200, "XLA Ops"),
+    Event("_mha_kernel", 500, 100, "XLA Ops"),
+    Event("all-reduce.2", 700, 100, "XLA Ops"),
+    Event("fusion.1", 900, 200, "XLA Ops"),     # runs past the window
+]
+HOST = [Event("bench_dispatch", 390, 20, "python"),
+        Event("bench_wait", 410, 500, "python")]
+WINDOW = (0.0, 1000.0)
+TRACE = Trace({0: EVENTS}, HOST, WINDOW, 2)
+
+
+def _in_ns(rows):
+    return [[name, round(seconds * 1e9)] for name, seconds in rows]
+
+
+@pytest.mark.parametrize("case,got,want", [
+    ("merge", lambda: trace_reduce.merge([(5, 7), (0, 2), (1, 3), (7, 8),
+                                          (4, 4)]), [(0, 3), (5, 8)]),
+    ("subtract", lambda: trace_reduce.subtract([(0, 10), (20, 30)],
+                                               [(2, 4), (8, 22), (25, 26)]),
+     [(0, 2), (4, 8), (22, 25), (26, 30)]),
+    ("busy", lambda: trace_reduce.busy_ns(EVENTS, WINDOW), 700.0),
+    ("idle_share", lambda: trace_reduce.idle_share(EVENTS, WINDOW), 0.3),
+    ("classify", lambda: [e.name for e in trace_reduce.classify(
+        EVENTS, "all-reduce|all-gather")[0]],
+     ["all-reduce.1", "all-reduce.2"]),
+    ("exposed", lambda: trace_reduce.exposed_ns(EVENTS, "all-reduce", WINDOW),
+     200.0),
+    ("per_step", lambda: trace_reduce.per_step(3e6, 2), 1.5),
+    ("top_ops", lambda: _in_ns(trace_reduce.top_ops(EVENTS, WINDOW, n=2)),
+     [["fusion", 400], ["all-reduce", 300]]),
+    ("idle_gaps", lambda: _in_ns(trace_reduce.idle_gaps(
+        EVENTS, HOST, WINDOW, n=2)),
+     [["bench_wait", 100], ["bench_wait", 100]]),
+])
+def test_reduction_core(case, got, want):
+    got = got()
+    assert got == want or got == pytest.approx(want)
+
+
+def _least_25ns(ctx):
+    return {"seconds": 25e-9}
+
+
+@pytest.mark.parametrize("reader,params,ctx,want", [
+    ("device_idle_pct", {}, {}, 30.0),
+    ("op_time_ms", {"pattern": "all-reduce"}, {}, 300 / 2 / 1e6),
+    ("op_time_ms", {"pattern": "all-reduce", "exposed": True}, {},
+     200 / 2 / 1e6),
+    ("op_time_ms", {"pattern": "no-such-op"}, {}, None),
+    ("roofline_pct", {"pattern": "_mha_", "least": "least_25ns.fn"}, {},
+     50.0),
+    ("roofline_pct", {"pattern": "no-such-op", "least": "least_25ns.fn"}, {},
+     None),
+    ("host_dispatch_ms", {}, {"run": {"dispatch_s": [0.001, 0.003]}}, 2.0),
+    ("host_dispatch_ms", {}, {}, None),
+])
+def test_layer_metric_readers(reader, params, ctx, want, monkeypatch):
+    # A metric's file names the function that computes its least time.
+    monkeypatch.setattr(common, "load_function", lambda dotted: {
+        "least_25ns.fn": _least_25ns}[dotted])
+    got = getattr(trace_reduce, reader)(TRACE, ctx, **params)
+    assert got == (want if want is None else pytest.approx(want))
+
+
+def test_flash_least_time_comes_from_the_cells_own_files():
+    spec = _spec()
+    entry = run.cell_entry(spec, "gpt2m-1chip")
+    up = {"cfg": _load(f"configs/{entry['config']}.json"), "cell": {},
+          "traffic": traffic_gen.resolve(
+              _load(f"traffic/{entry['traffic']}.json"), False)}
+    table = _load("peaks.json")["peaks"]
+    ctx = run.reader_context(up, {}, flops.chip_peaks("TPU v5 lite", table))
+    direct = flops.flash_least_seconds(8, 16, 1024, 64, 24, True, 2,
+                                       197e12, 819e9)
+    assert flops.flash_step_least(ctx) == direct
+    assert direct["seconds"] == pytest.approx(9.42e-3, rel=1e-3)
+    with pytest.raises(ValueError, match="no HBM peak"):
+        flops.flash_step_least(run.reader_context(
+            up, {}, flops.chip_peaks("TPU v4", table)))
+
+
+def test_an_async_collective_counts_once_and_is_not_the_core_being_busy():
+    """The async line shows a collective in flight beside the core's ops:
+    it is collective time, hidden while the core computes, exposed where it
+    does not, and by itself it does not make the device busy."""
+    events = [Event("%fusion.1 = f32[8] fusion(...)", 0, 400, "XLA Ops"),
+              Event("%all-reduce-start.1 = ...", 100, 10, "XLA Ops"),
+              Event("%all-reduce-start.1 = ...", 100, 500, "Async XLA Ops"),
+              Event("%all-reduce-done.1 = ...", 590, 10, "XLA Ops")]
+    trace = Trace({0: events}, [], WINDOW, 1)
+    assert trace_reduce.op_time_ms(trace, {}, pattern="all-reduce") == \
+        pytest.approx(500 / 1e6)
+    assert trace_reduce.op_time_ms(trace, {}, pattern="all-reduce",
+                                   exposed=True) == pytest.approx(200 / 1e6)
+    assert trace_reduce.device_idle_pct(trace, {}) == pytest.approx(59.0)
+    assert trace_reduce.top_ops(events, WINDOW, n=1) == [["fusion", 400e-9]]
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in _spec()["workloads"]])
+def test_per_layer_goes_through_each_metric_file_of_the_cell(cell,
+                                                             monkeypatch):
+    """The harness finds each reader by the name in BENCHMARK.json; what a
+    reader cannot find in the trace is left out of the line."""
+    collective = ("%psum.1 = f32[8] all-reduce(f32[8] %x), channel_id=1")
+    kernel = '%attn.1 = bf16[8] custom-call(), custom_call_target="tpu_custom_call"'
+    events = [Event("%fusion.1 = f32[8] fusion()", 0, 300, "XLA Ops"),
+              Event(collective, 300, 100, "XLA Ops"),
+              Event(kernel, 400, 200, "XLA Ops")]
+    ctx = {"run": {"dispatch_s": [0.002]}}
+    monkeypatch.setattr(flops, "flash_step_least",
+                        lambda ctx: {"seconds": 50e-9})
+    got = run.per_layer(_spec(), cell, Trace({0: events}, [], WINDOW, 2), ctx)
+    want = {m["name"] for m in run.metrics_of(_spec(), "per_layer", cell)}
+    assert set(got) == want
+    assert all(set(v) == {"value", "unit"} for v in got.values())
+    assert got["device_idle_pct"]["value"] == pytest.approx(40.0)
+    if "flash_roofline" in want:
+        assert got["flash_roofline"]["value"] == pytest.approx(50.0)
+    if "collective_exposed_ms" in want:
+        assert got["collective_exposed_ms"]["value"] == pytest.approx(50 / 1e6)
+    # Nothing to read: the metric is left out, the others stay.
+    bare = run.per_layer(_spec(), cell, Trace({0: events[:1]}, [], WINDOW, 2),
+                         {})
+    assert set(bare) == {"device_idle_pct"}
+
+
+def test_collective_pattern_matches_the_opcode_not_an_operand():
+    pattern = _load("layer_metrics/collective_ms.json")["params"]["pattern"]
+    assert pattern == _load(
+        "layer_metrics/collective_exposed_ms.json")["params"]["pattern"]
+    op = ("%psum_invariant.2347 = f32[1024,50304]{1,0:T(8,128)} all-reduce("
+          "f32[1024,50304]{1,0:T(8,128)} %fusion.14), channel_id=1")
+    start = "%ar.1 = (f32[8], f32[8]) all-reduce-start(f32[8] %x)"
+    user = "%fusion.7 = f32[8] fusion(f32[8] %all-reduce.3), kind=kLoop"
+    events = [Event(n, 0, 10, "XLA Ops") for n in (op, start, user)]
+    assert [e.name for e in trace_reduce.classify(events, pattern)[0]] == [
+        op, start]
+    flash = _load("layer_metrics/flash_kernel_ms.json")["params"]["pattern"]
+    kernel = ('%attn.72 = (bf16[128,1024,64]{2,1,0}) custom-call(bf16[128,'
+              '1024,64]{2,1,0} %b), custom_call_target="tpu_custom_call"')
+    concat = ('%custom-call.29 = f32[3,3,256,256] custom-call(f32[1,3,256,'
+              '256] %s), custom_call_target="ConcatBitcast"')
+    codec = ('%quantize.3 = s8[4096]{0} custom-call(f32[4096]{0} %x), '
+             'custom_call_target="tpu_custom_call"')
+    user = ('%fusion.9 = bf16[8] fusion(bf16[8] %attn.72), kind=kLoop, '
+            'calls=%fused_computation.9')
+    events = [Event(n, 0, 10, "XLA Ops")
+              for n in (kernel, concat, codec, user)]
+    assert [e.name for e in trace_reduce.classify(events, flash)[0]] == [
+        kernel]
+    assert flash == _load("layer_metrics/flash_roofline.json")["params"][
+        "pattern"]
+
+
+def test_readers_average_over_the_chips_of_the_cell():
+    busy_all = [Event("fusion.1", 0, 1000, "XLA Ops")]
+    two = Trace({0: EVENTS, 1: busy_all}, [], WINDOW, 2)
+    assert trace_reduce.device_idle_pct(two, {}) == pytest.approx(15.0)
+
+
+def _stamps(step_s, stall_every=None, stall_s=0.0, steps=100):
+    stamps, t = [0.0], 0.0
+    for i in range(steps):
+        t += step_s + (stall_s if stall_every and i % stall_every ==
+                       stall_every - 1 else 0.0)
+        stamps.append(t)
+    return {"stamps": stamps}
+
+
+def test_end_to_end_statistics():
+    # 100 steps of 10 ms, ten of them 20 ms: the whole window over all its
+    # steps is 11 ms, the tail lies on the edge, and the utilization counts
+    # every stall.
+    got = run.end_to_end(_stamps(0.010, 10, 0.010), flops_per_step=1e9,
+                         chips=2, peak_flops=1e12)
+    assert got["step_ms"] == pytest.approx(11.0)
+    assert 10.0 <= got["step_ms_p90"] <= 20.0
+    assert got["mfu_pct"] == pytest.approx(100 * 100 * 1e9 / (1.1 * 2 * 1e12))
+
+
+def test_a_stall_every_twentieth_step_moves_step_ms():
+    """The case a median of the gaps would not see (REVIEW of PR 23): five
+    steps in a hundred wait 40 ms for the host.  The tail does not see it
+    either (5 % of the samples), so the time per step has to."""
+    kw = dict(flops_per_step=1e9, chips=1, peak_flops=1e12)
+    smooth = run.end_to_end(_stamps(0.010), **kw)
+    stalled = run.end_to_end(_stamps(0.010, 20, 0.040), **kw)
+    assert smooth["step_ms"] == pytest.approx(10.0)
+    assert stalled["step_ms"] == pytest.approx(12.0)
+    assert stalled["step_ms_p90"] == pytest.approx(smooth["step_ms_p90"])
+    assert stalled["mfu_pct"] == pytest.approx(smooth["mfu_pct"] / 1.2)
+
+
+def test_timed_metrics_are_the_cells_end_to_end_metrics(capsys):
+    class Family:
+        @staticmethod
+        def units(cell):
+            return "tokens", 8192
+
+    run_ = {**_stamps(0.010, 20, 0.040), "losses": [1.0] * 100,
+            "dispatch_s": [0.002] * 100,
+            "gc": {"collections": 0, "seconds": 0.0, "longest_s": 0.0}}
+    up = {"family": Family, "cell": {}, "device": {"count": 1},
+          "flops_per_step": 1e9, "setup_s": 30.0, "first_loss": 2.0}
+    got = run.timed_metrics(
+        type("Args", (), {"workload": "gpt2m-1chip"}), _spec(), up, run_,
+        {"bf16_flops_per_s": 1e12})
+    assert set(got) == {m["name"] for m in _spec()["end_to_end"]}
+    assert got["step_ms"] == {"value": pytest.approx(12.0), "unit": "ms"}
+    assert got["setup_s"]["value"] == 30.0
+    shown = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert shown["note"] == "throughput" and shown["steps"] == 100
+    assert shown["step_ms_median"] == pytest.approx(10.0)
+    # Where the window stood still: the five longest steps, by index.
+    assert {i for i, _ in shown["longest_steps_ms"]} == {19, 39, 59, 79, 99}
+    assert shown["longest_steps_ms"][0][1] == pytest.approx(50.0)
+
+
+def test_gc_watch_times_the_collections_it_is_told_of():
+    watch = run.GcWatch()
+    watch("stop", {"generation": 0})           # a stop with no start: ignored
+    for _ in range(3):
+        watch("start", {"generation": 2})
+        watch("stop", {"generation": 2})
+    got = watch.summary()
+    assert got["collections"] == 3
+    assert 0.0 <= got["longest_s"] <= got["seconds"] < 1.0
+
+
+def test_the_loop_takes_the_batches_in_turn():
+    import jax.numpy as jnp
+
+    seen = []
+
+    def step(total, x):
+        seen.append(int(x))
+        return total + x, total + x
+
+    got = run.run_steps(step, (jnp.float32(0.0),),
+                        [(jnp.float32(1.0),), (jnp.float32(10.0),)],
+                        seconds=60.0, max_steps=5)
+    assert seen == [1, 10, 1, 10, 1]
+    assert got["losses"] == [1.0, 11.0, 12.0, 22.0, 23.0]
+    assert len(got["stamps"]) == 6 and len(got["dispatch_s"]) == 5
+    assert got["error"] is None
+
+
+@pytest.mark.parametrize("name", sorted(
+    os.path.splitext(os.path.basename(p))[0]
+    for p in DATA_FILES if p.startswith("traffic/")))
+def test_traffic_generator_reads_the_file_and_the_seed(name):
+    """One generator, any traffic file: shapes from the file and the
+    family's inputs, values from the seed alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sizes = {**traffic_gen.resolve(_load(f"traffic/{name}.json"), True),
+             "distinct_batches": 2}
+    inputs = [traffic_gen.Input((sizes.get("seq_len", 4),), jnp.int32,
+                                "randint", 50),
+              traffic_gen.Input((3, 3), jnp.float32, "normal")]
+    mesh = common.hvd_mesh(jax.devices()[:1])
+    a = traffic_gen.make_batches(sizes, inputs, mesh, 3000000019)
+    b = traffic_gen.make_batches(sizes, inputs, mesh, 3000000019)
+    c = traffic_gen.make_batches(sizes, inputs, mesh, 5)
+    assert len(a) == 2 and len(a[0]) == 2
+    n = sizes["batch_per_chip"]
+    assert a[0][0].shape == (n, sizes.get("seq_len", 4))
+    assert a[1][1].shape == (n, 3, 3)
+    assert int(a[0][0].min()) >= 0 and int(a[0][0].max()) < 50
+    for x, y in zip(jax.tree_util.tree_leaves(a),
+                    jax.tree_util.tree_leaves(b)):
+        np.testing.assert_array_equal(x, y)
+    assert not np.array_equal(a[0][0], a[1][0])     # distinct batches
+    assert not np.array_equal(a[0][0], c[0][0])     # another seed
+
+
+def test_recorded_chip_trace_has_a_device_plane_and_busy_time():
+    found = glob.glob(os.path.join(BENCH, "testdata", "*.xplane.pb*"))
+    if not found:
+        pytest.skip("no recorded chip trace under benchmark/testdata")
+    trace = trace_reduce.read_xplane(found[0], steps=2)
+    assert trace.devices, "no /device:TPU:<n> plane with an XLA Ops line"
+    assert trace.window[1] > trace.window[0]
+    busy = trace_reduce.mean_over_devices(
+        trace, lambda ev: trace_reduce.busy_ns(ev, trace.window))
+    assert 0 < busy <= trace.window[1] - trace.window[0]
+    assert any(h.name == "bench_window" for h in trace.host)
+    assert trace_reduce.top_ops(trace.devices[min(trace.devices)],
+                                trace.window)
+
+
+# ---------------------------------------------------------------------------
+# run.py end to end
+# ---------------------------------------------------------------------------
+
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+def _run(args, tmp_path, devices=1):
+    env = {k: v for k, v in os.environ.items() if k != "BENCH_RUN"}
+    env.update(JAX_PLATFORMS="cpu", BENCH_RUN="7",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
+               # one thread for the arithmetic: the suite's other workers
+               # run multi-process tests that time out on a starved machine
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices} "
+                         "--xla_cpu_multi_thread_eigen=false "
+                         "intra_op_parallelism_threads=1")
+    return subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), *args], env=env,
+        cwd=REPO, capture_output=True, text=True, timeout=240)
+
+
+@pytest.mark.parametrize("cell,trace,devices", [
+    ("resnet50-1chip", 0, 1), ("gpt2m-1chip", 1, 1), ("gpt2m-dp4", 0, 4)])
+def test_rehearsal_prints_the_contract_keys_and_no_metric(cell, trace,
+                                                          devices, tmp_path):
+    done = _run(["--workload", cell, "--seed", "3000000019", "--seconds", "1",
+                 "--trace", str(trace), "--rehearse"], tmp_path, devices)
+    assert done.returncode == 0, done.stderr[-2000:]
+    result = json.loads(done.stdout.strip().splitlines()[-1])
+    assert set(result) == RESULT_KEYS
+    assert result["correct"] is True, done.stdout[-3000:]
+    assert result["attempted"] >= 2 and result["failed"] == 0
+    # A CPU number is never written under a device metric's name.
+    assert result["metrics"] == {}
+    assert result["device"]["platform"] == "cpu"
+    assert result["device"]["count"] == devices
+
+
+@pytest.mark.parametrize("args,message", [
+    (["--workload", "resnet50-1chip"], "no TPU"),
+    (["--workload", "no-such-cell"], "not found"),
+])
+def test_run_refuses_loudly_and_prints_no_result(args, message, tmp_path):
+    done = _run([*args, "--seed", "1", "--seconds", "1", "--trace", "0"],
+                tmp_path)
+    assert done.returncode != 0
+    assert message in done.stderr
+    assert '"correct"' not in done.stdout

@@ -168,6 +168,51 @@ class GcWatch:
                 "longest_s": max(self.pauses_s, default=0.0)}
 
 
+class CompileWatch:
+    """What jax itself records of compiling while this is entered: each
+    ``/jax/core/compile/*`` duration (a trace, a lowering, a backend compile
+    or load) and each ``/jax/compilation_cache/*`` event (a read, a hit, a
+    miss and the write that follows it).  A warm window has none; a note
+    beside the longest steps, so that a stall in a timed window can be told
+    from a program that compiled there.  Nothing runs per step."""
+
+    PREFIXES = ("/jax/core/compile/", "/jax/compilation_cache/")
+
+    def __init__(self):
+        self.seen = {}
+
+    def __call__(self, event: str, seconds: float = 0.0, **_) -> None:
+        if event.startswith(self.PREFIXES):
+            count, total = self.seen.get(event, (0, 0.0))
+            self.seen[event] = (count + 1, total + seconds)
+
+    def __enter__(self):
+        from jax import monitoring
+
+        monitoring.register_event_listener(self)
+        monitoring.register_event_duration_secs_listener(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        from jax import monitoring
+
+        monitoring.unregister_event_listener(self)
+        monitoring.unregister_event_duration_listener(self)
+
+    def summary(self) -> dict:
+        def count(event):
+            return self.seen.get(event, (0, 0.0))[0]
+
+        return {"compilations": count(
+                    "/jax/core/compile/backend_compile_duration"),
+                "cache_reads": count(
+                    "/jax/compilation_cache/compile_requests_use_cache"),
+                "cache_writes": count("/jax/compilation_cache/cache_misses"),
+                "seconds": sum((s for _, s in self.seen.values()), 0.0),
+                "events": {e.rsplit("/", 1)[1]: n
+                           for e, (n, _) in sorted(self.seen.items())}}
+
+
 def end_to_end(run: dict, flops_per_step: float, chips: int,
                peak_flops: float) -> dict:
     """From the completion stamps of one window: ``step_ms`` and ``mfu_pct``
@@ -340,14 +385,18 @@ def set_up(args, spec: dict, phases: Phases) -> dict:
             "setup_s": setup_s, "flops_per_step": flops_per_step}
 
 
-def reader_context(up: dict, run: dict, peaks: dict) -> dict:
+def reader_context(up: dict, run: dict, peaks: dict, xplane: str = None,
+                   workload: str = None) -> dict:
     """What a per-layer reader may read besides the trace: the traced run
     (stamps, host time inside each ``step(...)`` call, losses), the cell as
     its family built it, the configuration with its ``assumed`` keys folded
-    in, the traffic's parameters and the chip's row of peaks.json."""
+    in, the traffic's parameters, the chip's row of peaks.json, the path of
+    the ``.xplane.pb`` (for a reader that wants more of the file than
+    ``Trace`` holds) and the cell's name."""
     cfg = {**up["cfg"].get("assumed", {}), **up["cfg"]}
     return {"run": run, "cell": up["cell"], "cfg": cfg,
-            "traffic": up["traffic"], "peaks": peaks}
+            "traffic": up["traffic"], "peaks": peaks, "xplane": xplane,
+            "workload": workload}
 
 
 def traced_metrics(args, spec: dict, up: dict, run: dict, xplane: str,
@@ -356,9 +405,11 @@ def traced_metrics(args, spec: dict, up: dict, run: dict, xplane: str,
     traced window."""
     from benchmark import trace_reduce
 
+    started = time.perf_counter()
     trace = trace_reduce.read_xplane(xplane, steps=len(run["losses"]))
+    read_s = time.perf_counter() - started
     metrics = per_layer(spec, args.workload, trace,
-                        reader_context(up, run, peaks))
+                        reader_context(up, run, peaks, xplane, args.workload))
     window = trace.window
     seen = {"window_s": (window[1] - window[0]) / 1e9,
             "busy_s": trace_reduce.mean_over_devices(
@@ -369,7 +420,9 @@ def traced_metrics(args, spec: dict, up: dict, run: dict, xplane: str,
         "device_ops": trace_reduce.top_ops(first, window),
         "idle_gaps": trace_reduce.idle_gaps(first, trace.host, window)}
     note("trace", xplane=os.path.relpath(xplane, ROOT), steps=trace.steps,
-         devices=sorted(trace.devices))
+         devices=sorted(trace.devices), read_xplane_s=read_s,
+         ops_with_scope=sum(bool(e.scope) for e in first), ops=len(first),
+         host_spans=len(trace.host))
     return metrics, seen, breakdown
 
 
@@ -382,13 +435,21 @@ def timed_metrics(args, spec: dict, up: dict, run: dict, peaks: dict) -> dict:
     what, per_step = up["family"].units(up["cell"])
     steps = len(run["losses"])
     window_s = run["stamps"][-1] - run["stamps"][0]
+    samples, dispatch_s = step_samples_ms(run), run["dispatch_s"]
+    longest = sorted(enumerate(samples), key=lambda kv: -kv[1])[:5]
     note("throughput", **{
         f"{what}_per_s_per_chip": per_step * steps / window_s / chips,
         "steps": steps, "window_s": window_s,
-        "step_ms_median": statistics.median(step_samples_ms(run)),
-        "longest_steps_ms": sorted(enumerate(step_samples_ms(run)),
-                                   key=lambda kv: -kv[1])[:5],
+        "step_ms_median": statistics.median(samples),
+        "longest_steps_ms": longest,
+        # Between the stamps of steps i and i+1 the loop dispatches step i+1
+        # and waits for step i: a long step whose dispatch is short stood
+        # still in the wait (the device, the runtime or a descheduled host).
+        "dispatch_ms_in_longest_steps": [
+            1e3 * dispatch_s[i + 1] if i + 1 < len(dispatch_s) else None
+            for i, _ in longest],
         "gc_in_window": run["gc"],
+        "compiles_in_window": run["compiles"],
         "host_dispatch_ms_mean": 1e3 * statistics.mean(run["dispatch_s"]),
         "first_loss": up["first_loss"], "last_loss": run["losses"][-1]})
     return {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
@@ -415,10 +476,11 @@ def main(argv=None) -> int:
     else:
         watch = GcWatch()
         gc.callbacks.append(watch)
-        run = run_steps(up["step"], up["state"], up["cell"]["batches"],
-                        args.seconds)
+        with CompileWatch() as compiles:
+            run = run_steps(up["step"], up["state"], up["cell"]["batches"],
+                            args.seconds)
         gc.callbacks.remove(watch)
-        run["gc"] = watch.summary()
+        run["gc"], run["compiles"] = watch.summary(), compiles.summary()
     hvd.shutdown()
 
     losses, first_loss, checks = run["losses"], up["first_loss"], up["checks"]

@@ -1,9 +1,10 @@
 """From a profiler trace to per-layer metrics.
 
 The core is pure Python over ``Event`` tuples ``(name, start_ns, dur_ns,
-line)`` so that tests feed it hand-made events; ``read_xplane`` is the thin
-adapter that fills a ``Trace`` from an ``.xplane.pb`` with
-``jax.profiler.ProfileData`` (nothing but JAX is needed to read one).
+line, scope)`` so that tests feed it hand-made events; ``read_xplane`` is the
+thin adapter that fills a ``Trace`` from an ``.xplane.pb``: names and times
+with ``jax.profiler.ProfileData``, each op's scope with ``xplane_raw`` (nothing
+but JAX and the standard library is needed to read one).
 
 The readers at the bottom are what ``layer_metrics/<name>.json`` files name
 as ``"reducer": "trace_reduce.<function>"``.  A reader takes the ``Trace``,
@@ -20,10 +21,16 @@ from typing import Iterable, NamedTuple, Optional
 
 
 class Event(NamedTuple):
+    """``name``: a device op's whole HLO instruction, a host span's name.
+    ``scope``: JAX's ``op_name`` of a device op, the scopes it was traced
+    under (``jit(step)/transpose(jvp(GPT))/h_0/attn/dot_general:``).  A
+    fusion has one, its root's; copies and layout changes have none."""
+
     name: str
     start_ns: float
     dur_ns: float
     line: str
+    scope: str = ""
 
     @property
     def end_ns(self) -> float:
@@ -35,17 +42,34 @@ ASYNC_LINE = "Async XLA Ops"   # copies and collectives in flight beside it
 
 
 class Trace(NamedTuple):
-    """One traced window: per-device op events of both lines, the
-    benchmark's own host spans (``bench_*`` TraceAnnotations), the window
-    and its step count."""
+    """One traced window: per-device op events of both lines, the host
+    spans of the benchmark (``bench_*`` TraceAnnotations) and of the program
+    (``hvd_*``), the window and its step count."""
 
     devices: dict          # device index -> [Event] (any order)
-    host: list             # [Event] of the benchmark's host spans
+    host: list             # [Event] of the bench_* and hvd_* host spans
     window: tuple          # (start_ns, end_ns)
     steps: int
 
 
 WINDOW_SPAN = "bench_window"
+
+
+def from_example(example: dict, shift_ns: float = 0.0) -> Trace:
+    """The ``Trace`` a metric file's ``example`` (or its ``nothing`` case)
+    describes: ``events`` are one device's ops, ``[name, start_ns, dur_ns,
+    line]`` or with a scope five, ``host`` the spans alike; ``window`` and
+    ``steps`` as they are.  ``shift_ns`` moves all of it in time, so that
+    several examples fit one trace."""
+    def events(rows):
+        return [Event(*row)._replace(start_ns=row[1] + shift_ns)
+                for row in rows]
+
+    ops = events(example.get("events", []))
+    lo, hi = example["window"]
+    return Trace({0: ops} if ops else {}, events(example.get("host", [])),
+                 (lo + shift_ns, hi + shift_ns), example["steps"])
+
 
 # ---------------------------------------------------------------------------
 # Core: intervals
@@ -92,12 +116,25 @@ def subtract(merged: list, cover: list) -> list:
     return out
 
 
-def classify(events: Iterable[Event], pattern: str) -> tuple:
-    """(matching, others) by a regular expression searched in the name."""
-    rx = re.compile(pattern)
+def classify(events: Iterable[Event], pattern: str = "",
+             scope: Optional[str] = None, scope_not: Optional[str] = None,
+             line: Optional[str] = None) -> tuple:
+    """(matching, others).  An event matches when every condition given
+    agrees: ``pattern`` is found in its name, ``scope`` is found in its
+    scope, ``scope_not`` is not (an op without a scope passes), and with
+    ``line="sync"`` it is one of the ops the core itself executes."""
+    if line not in (None, "sync"):
+        raise ValueError(f"line={line!r}: \"sync\" or nothing")
+    name_rx = re.compile(pattern)
+    scope_rx = re.compile(scope) if scope is not None else None
+    not_rx = re.compile(scope_not) if scope_not is not None else None
     hit, miss = [], []
     for e in events:
-        (hit if rx.search(e.name) else miss).append(e)
+        ok = (name_rx.search(e.name)
+              and (scope_rx is None or scope_rx.search(e.scope))
+              and (not_rx is None or not not_rx.search(e.scope))
+              and (line is None or e.line != ASYNC_LINE))
+        (hit if ok else miss).append(e)
     return hit, miss
 
 
@@ -116,13 +153,20 @@ def idle_share(events: Iterable[Event], window: tuple) -> float:
     return 1.0 - busy_ns(sync_ops(events), window) / (window[1] - window[0])
 
 
-def exposed_ns(events: Iterable[Event], pattern: str, window: tuple) -> float:
-    """Time during which an op matching ``pattern`` runs (on either line)
-    and the core executes no other op: the part of a collective that compute
-    does not hide."""
-    hit, miss = classify(events, pattern)
+def exposed_length(hit: Iterable[Event], miss: Iterable[Event],
+                   window: tuple) -> float:
+    """Time during which an op of ``hit`` runs (on either line) and the core
+    executes no op of ``miss``."""
     return length(subtract(merge(clip(hit, window)),
                            merge(clip(sync_ops(miss), window))))
+
+
+def exposed_ns(events: Iterable[Event], pattern: str, window: tuple,
+               **select) -> float:
+    """Time during which an op matching ``pattern`` (and ``select``, as
+    ``classify`` takes it) runs (on either line) and the core executes no
+    other op: the part of a collective that compute does not hide."""
+    return exposed_length(*classify(events, pattern, **select), window)
 
 
 def per_step(total_ns: float, steps: int) -> float:
@@ -160,19 +204,25 @@ def top_ops(events: Iterable[Event], window: tuple, n: int = 10) -> list:
 def idle_gaps(events: Iterable[Event], host: Iterable[Event], window: tuple,
               n: int = 10) -> list:
     """``[[what the host was doing, seconds], ...]``: the longest stretches
-    of ``window`` with no device op, each named by the benchmark host span
-    that overlaps it most (``(no host span)`` if none does)."""
+    of ``window`` with no device op, each named by the innermost host span
+    that covers most of it (the shortest of those overlapping more than half
+    of it, so a program's ``hvd_*`` span inside ``bench_dispatch`` is what is
+    named); where none does, by the span that overlaps it most
+    (``(no host span)`` if none does)."""
     gaps = subtract([tuple(window)], merge(clip(sync_ops(events), window)))
     gaps = sorted(gaps, key=lambda g: g[0] - g[1])[:n]
     host = [h for h in host if h.name != WINDOW_SPAN]
     out = []
     for start, end in gaps:
-        best, best_overlap = "(no host span)", 0.0
+        best, best_overlap, inner = "(no host span)", 0.0, None
         for h in host:
             overlap = min(end, h.end_ns) - max(start, h.start_ns)
             if overlap > best_overlap:
                 best, best_overlap = h.name, overlap
-        out.append([best, (end - start) / 1e9])
+            if 2 * overlap > end - start and (inner is None
+                                              or h.dur_ns < inner.dur_ns):
+                inner = h
+        out.append([inner.name if inner else best, (end - start) / 1e9])
     return out
 
 
@@ -186,36 +236,44 @@ def mean_over_devices(trace: Trace, fn) -> Optional[float]:
 # ---------------------------------------------------------------------------
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
-HOST_SPAN_PREFIX = "bench_"
+# The benchmark's own spans, and the program's: a TraceAnnotation inside
+# horovod_tpu/ is named hvd_<what>.
+HOST_SPAN_PREFIXES = ("bench_", "hvd_")
 
 
 def read_xplane(path: str, steps: int) -> Trace:
-    """Op events (both lines) of every device plane, the benchmark's host
-    spans, and the window the ``bench_window`` span marks (or, without one,
-    first device op start to last device op end)."""
+    """Op events (both lines) of every device plane, each with its scope
+    (``xplane_raw.op_scopes``: by the op's name, from the file's own
+    metadata), the ``bench_*`` and ``hvd_*`` host spans, and the window the
+    ``bench_window`` span marks (or, without one, first device op start to
+    last device op end)."""
+    import gzip
+
     from jax.profiler import ProfileData
 
-    if path.endswith(".gz"):  # the recorded trace of the tests
-        import gzip
+    from benchmark import xplane_raw
 
-        with gzip.open(path, "rb") as f:
-            data = ProfileData.from_serialized_xspace(f.read())
-    else:
-        data = ProfileData.from_file(path)
+    # .gz: the recorded traces of the tests
+    with (gzip.open if path.endswith(".gz") else open)(path, "rb") as f:
+        raw = f.read()
+    data = ProfileData.from_serialized_xspace(raw)
+    scopes = xplane_raw.op_scopes(raw, DEVICE_PLANE.pattern)
     devices, host = {}, []
     for plane in data.planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
             ops = devices.setdefault(int(m.group(1)), [])
+            scope_of = scopes.get(plane.name, {})
             for line in plane.lines:
                 if line.name in (SYNC_LINE, ASYNC_LINE):
                     ops.extend(Event(e.name, e.start_ns, e.duration_ns,
-                                     line.name) for e in line.events)
+                                     line.name, scope_of.get(e.name, ""))
+                               for e in line.events)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 host.extend(Event(e.name, e.start_ns, e.duration_ns,
                                   line.name) for e in line.events
-                            if e.name.startswith(HOST_SPAN_PREFIX))
+                            if e.name.startswith(HOST_SPAN_PREFIXES))
     devices = {d: ops for d, ops in devices.items() if ops}
     marks = [h for h in host if h.name == WINDOW_SPAN]
     if marks:
@@ -245,33 +303,63 @@ def device_idle_pct(trace: Trace, ctx: dict, **_) -> Optional[float]:
     return None if share is None else 100.0 * share
 
 
-def op_time_ms(trace: Trace, ctx: dict, pattern: str, exposed: bool = False,
-               **_) -> Optional[float]:
-    """Device time a step spends in ops whose name matches ``pattern``, on
-    either line (union of their intervals, so an async collective and its
+def op_time_ms(trace: Trace, ctx: dict, pattern: str = "",
+               exposed: bool = False, **select) -> Optional[float]:
+    """Device time a step spends in ops whose name matches ``pattern`` and
+    which ``select`` admits (``scope``, ``scope_not``: regular expressions on
+    the op's scope; ``line="sync"``: the core's own line only, so that
+    disjoint classes add up to ``busy_s``; see ``classify``), on either line
+    otherwise (union of their intervals, so an async collective and its
     start/done ops count once); with ``exposed``, only the part during which
     the core executes no other op.  None when no op of any device matches."""
-    if not any(classify(ev, pattern)[0] for ev in trace.devices.values()):
+    split = [classify(events, pattern, **select)
+             for _, events in sorted(trace.devices.items())]
+    if not any(hit for hit, _ in split):
         return None
-
-    def one(events):
-        if exposed:
-            return exposed_ns(events, pattern, trace.window)
-        return busy_ns(classify(events, pattern)[0], trace.window)
-
-    return per_step(mean_over_devices(trace, one), trace.steps)
+    total = sum(exposed_length(hit, miss, trace.window) if exposed
+                else busy_ns(hit, trace.window) for hit, miss in split)
+    return per_step(total / len(split), trace.steps)
 
 
 def roofline_pct(trace: Trace, ctx: dict, pattern: str, least: str,
-                 **_) -> Optional[float]:
+                 least_key: Optional[str] = None,
+                 **select) -> Optional[float]:
     """Least time the chip could take for a kernel group in one step over
-    the device time of the ops matching ``pattern``.  ``least`` names the
-    function (``<module>.<function>`` under ``benchmark/``) that computes the
-    least time from the context's shapes and peaks: it returns a dict with
-    ``seconds``, and lives beside the operations and bytes it counts."""
+    the device time of the ops matching ``pattern`` (and ``select``, as
+    ``op_time_ms`` takes it).  ``least`` names the function
+    (``<module>.<function>`` under ``benchmark/``) that computes the least
+    time from the context's shapes and peaks: it returns a dict with
+    ``seconds``, and lives beside the operations and bytes it counts.
+    ``least_key`` (``"kernels.dq"``) is the path to the one kernel's dict
+    inside it, for a function that returns several."""
     from benchmark import common
 
-    took = op_time_ms(trace, ctx, pattern)
+    took = op_time_ms(trace, ctx, pattern, **select)
     if not took:
         return None
-    return 100.0 * common.load_function(least)(ctx)["seconds"] * 1e3 / took
+    bound = common.load_function(least)(ctx)
+    for key in least_key.split(".") if least_key else ():
+        bound = bound[key]
+    return 100.0 * bound["seconds"] * 1e3 / took
+
+
+def host_span_ms(trace: Trace, ctx: dict, pattern: str,
+                 self_time: bool = False, **_) -> Optional[float]:
+    """Host time a step spends inside the ``bench_*`` / ``hvd_*`` spans
+    whose name matches ``pattern``, within the window: the union of their
+    intervals, or with ``self_time`` each span's own time, without what the
+    spans nested in it on its thread cover.  None when no span matches."""
+    rx = re.compile(pattern)
+    spans = [h for h in trace.host if rx.search(h.name)]
+    if not spans:
+        return None
+    if not self_time:
+        return per_step(busy_ns(spans, trace.window), trace.steps)
+    total = 0.0
+    for s in spans:
+        children = [h for h in trace.host if h is not s and h.line == s.line
+                    and s.start_ns <= h.start_ns and h.end_ns <= s.end_ns
+                    and h.dur_ns < s.dur_ns]
+        total += length(subtract(merge(clip([s], trace.window)),
+                                 merge(clip(children, trace.window))))
+    return per_step(total, trace.steps)

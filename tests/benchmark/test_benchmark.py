@@ -19,7 +19,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 BENCH = os.path.join(REPO, "benchmark")
 
-from benchmark import common, flops, run, trace_reduce  # noqa: E402
+from benchmark import common, flops, run, trace_reduce, xplane_raw  # noqa: E402
 from benchmark import traffic as traffic_gen  # noqa: E402
 from benchmark.trace_reduce import Event, Trace  # noqa: E402
 
@@ -70,7 +70,18 @@ def test_data_file_loads_and_is_named_for_its_content(rel):
         assert callable(common.load_function(data["reducer"]))
         # What BENCHMARK.json states of a metric is stated there alone.
         assert set(data) <= {"name", "reducer", "params", "what",
-                             "pattern_note"}
+                             "pattern_note", "example"}
+        # A metric proves itself: what its reader returns for a few events,
+        # and a case in which there is nothing for it to read.
+        assert "example" in data, (
+            f"{rel} has no \"example\" (benchmark/README.md, \"A per-layer "
+            "metric\")")
+        example = data["example"]
+        assert {"window", "steps", "value", "nothing"} <= set(example) <= {
+            "what", "events", "host", "context", "window", "steps", "value",
+            "nothing"}
+        assert isinstance(example["value"], (int, float))
+        assert set(example["nothing"]) <= {"events", "host", "context"}
 
 
 @pytest.mark.parametrize("group,key,folder", [
@@ -253,6 +264,27 @@ HOST = [Event("bench_dispatch", 390, 20, "python"),
         Event("bench_wait", 410, 500, "python")]
 WINDOW = (0.0, 1000.0)
 TRACE = Trace({0: EVENTS}, HOST, WINDOW, 2)
+# The same host loop with the program's own spans inside it: hvd_launch
+# covers most of the gap 400..500 from inside bench_wait, hvd_enqueue only
+# a fifth of it.
+NESTED = HOST + [Event("hvd_launch", 420, 75, "python"),
+                 Event("hvd_enqueue", 495, 25, "python"),
+                 Event("hvd_poll", 100, 50, "worker")]
+# Scopes as JAX writes them: forward, backward, optimizer, none.
+SCOPED = [
+    Event("%fusion.1 = f32[8] fusion()", 0, 300, "XLA Ops",
+          "jit(step)/jvp(GPT)/h_0/attn/dot_general:"),
+    Event("%attn.1 = bf16[8] custom-call()", 300, 100, "XLA Ops",
+          "jit(step)/jvp(GPT)/h_0/attn/pallas_call:"),
+    Event("%attn.2 = bf16[8] custom-call()", 400, 200, "XLA Ops",
+          "jit(step)/transpose(jvp(GPT))/h_0/attn/pallas_call:"),
+    Event("%fusion.2 = f32[8] fusion()", 600, 100, "XLA Ops",
+          "jit(step)/add:"),
+    Event("%copy.1 = f32[8] copy()", 700, 50, "XLA Ops"),
+    Event("%copy-start.1 = f32[8] copy-start()", 0, 900, "Async XLA Ops",
+          "jit(step)/jvp(GPT)/wte/gather:"),
+]
+SCOPED_TRACE = Trace({0: SCOPED}, NESTED, WINDOW, 2)
 
 
 def _in_ns(rows):
@@ -278,6 +310,23 @@ def _in_ns(rows):
     ("idle_gaps", lambda: _in_ns(trace_reduce.idle_gaps(
         EVENTS, HOST, WINDOW, n=2)),
      [["bench_wait", 100], ["bench_wait", 100]]),
+    # The innermost span that covers most of a gap names it; a span that
+    # covers less than half does not, however deep it lies.
+    ("idle_gaps_innermost", lambda: _in_ns(trace_reduce.idle_gaps(
+        EVENTS, NESTED, WINDOW, n=3)),
+     [["hvd_launch", 100], ["bench_wait", 100], ["bench_wait", 100]]),
+    ("classify_scope", lambda: [e.name[:7] for e in trace_reduce.classify(
+        SCOPED, "", scope=r"jvp\(", scope_not=r"transpose\(")[0]],
+     ["%fusion", "%attn.1", "%copy-s"]),
+    ("classify_sync_line", lambda: [e.name[:7] for e in trace_reduce.classify(
+        SCOPED, "", scope_not=r"jvp\(|transpose\(", line="sync")[0]],
+     ["%fusion", "%copy.1"]),
+    ("from_example", lambda: trace_reduce.from_example(
+        {"events": [["op", 5, 10, "XLA Ops", "a/b:"]],
+         "host": [["hvd_x", 0, 4, "python"]], "window": [0, 20], "steps": 3},
+        shift_ns=100),
+     Trace({0: [Event("op", 105, 10, "XLA Ops", "a/b:")]},
+           [Event("hvd_x", 100, 4, "python")], (100, 120), 3)),
 ])
 def test_reduction_core(case, got, want):
     got = got()
@@ -309,6 +358,54 @@ def test_layer_metric_readers(reader, params, ctx, want, monkeypatch):
     assert got == (want if want is None else pytest.approx(want))
 
 
+def _least_by_kernel(ctx):
+    return {"seconds": 90e-9,
+            "kernels": {"fwd": {"seconds": 25e-9}, "dq": {"seconds": 30e-9}}}
+
+
+@pytest.mark.parametrize("reader,params,want", [
+    # The three classes of a step: disjoint, on the core's own line, and
+    # together all the time the core is busy (750 ns over 2 steps).
+    ("op_time_ms", {"scope": r"jvp\(", "scope_not": r"transpose\(",
+                    "line": "sync"}, 400 / 2 / 1e6),
+    ("op_time_ms", {"scope": r"transpose\(", "line": "sync"}, 200 / 2 / 1e6),
+    ("op_time_ms", {"scope_not": r"jvp\(|transpose\(", "line": "sync"},
+     150 / 2 / 1e6),
+    # Without ``line`` the async line counts, as it always did.
+    ("op_time_ms", {"scope": r"jvp\(", "scope_not": r"transpose\("},
+     900 / 2 / 1e6),
+    # Name and scope together: the kernel calls of one pass.
+    ("op_time_ms", {"pattern": "^%attn", "scope_not": r"transpose\("},
+     100 / 2 / 1e6),
+    ("op_time_ms", {"pattern": "^%attn", "scope": r"transpose\("},
+     200 / 2 / 1e6),
+    ("op_time_ms", {"pattern": "^%attn", "scope": "no-such-scope"}, None),
+    ("roofline_pct", {"pattern": "^%attn", "least": "by_kernel.fn"},
+     100 * 90 / 150),
+    ("roofline_pct", {"pattern": "^%attn", "least": "by_kernel.fn",
+                      "least_key": "kernels.fwd",
+                      "scope_not": r"transpose\("}, 100 * 25 / 50),
+    # Host spans, the benchmark's and the program's: the union of those
+    # that match, or each one's own time without its children's.
+    ("host_span_ms", {"pattern": "^bench_wait$"}, 500 / 2 / 1e6),
+    ("host_span_ms", {"pattern": "^bench_wait$", "self_time": True},
+     400 / 2 / 1e6),
+    ("host_span_ms", {"pattern": "^hvd_"}, 150 / 2 / 1e6),
+    ("host_span_ms", {"pattern": "^hvd_", "self_time": True}, 150 / 2 / 1e6),
+    ("host_span_ms", {"pattern": "^(bench_wait|hvd_launch)$",
+                      "self_time": True}, 475 / 2 / 1e6),
+    ("host_span_ms", {"pattern": "^hvd_no_such_span$"}, None),
+])
+def test_readers_by_scope_line_kernel_and_host_span(reader, params, want,
+                                                    monkeypatch):
+    monkeypatch.setattr(common, "load_function", lambda dotted: {
+        "by_kernel.fn": _least_by_kernel}[dotted])
+    got = getattr(trace_reduce, reader)(SCOPED_TRACE, {}, **params)
+    assert got == (want if want is None else pytest.approx(want))
+    with pytest.raises(ValueError, match="sync"):
+        trace_reduce.classify(SCOPED, "", line="async")
+
+
 def test_flash_least_time_comes_from_the_cells_own_files():
     spec = _spec()
     entry = run.cell_entry(spec, "gpt2m-1chip")
@@ -316,7 +413,13 @@ def test_flash_least_time_comes_from_the_cells_own_files():
           "traffic": traffic_gen.resolve(
               _load(f"traffic/{entry['traffic']}.json"), False)}
     table = _load("peaks.json")["peaks"]
-    ctx = run.reader_context(up, {}, flops.chip_peaks("TPU v5 lite", table))
+    ctx = run.reader_context(up, {}, flops.chip_peaks("TPU v5 lite", table),
+                             xplane="benchmark/_trace/x.xplane.pb",
+                             workload="gpt2m-1chip")
+    assert set(ctx) == {"run", "cell", "cfg", "traffic", "peaks", "xplane",
+                        "workload"}
+    assert (ctx["xplane"], ctx["workload"]) == (
+        "benchmark/_trace/x.xplane.pb", "gpt2m-1chip")
     direct = flops.flash_least_seconds(8, 16, 1024, 64, 24, True, 2,
                                        197e12, 819e9)
     assert flops.flash_step_least(ctx) == direct
@@ -343,32 +446,147 @@ def test_an_async_collective_counts_once_and_is_not_the_core_being_busy():
     assert trace_reduce.top_ops(events, WINDOW, n=1) == [["fusion", 400e-9]]
 
 
+METRIC_FILES = sorted(os.path.splitext(os.path.basename(p))[0]
+                      for p in DATA_FILES if p.startswith("layer_metrics/"))
+
+
+def _example(name):
+    return _load(f"layer_metrics/{name}.json")["example"]
+
+
+def _nothing(example):
+    """The example's case with nothing to read, at its window and steps."""
+    return {"window": example["window"], "steps": example["steps"],
+            **example["nothing"]}
+
+
+def _merged(a: dict, b: dict, where: str) -> dict:
+    """``a`` and ``b`` as one context; a key both state must agree."""
+    out = dict(a)
+    for key, value in b.items():
+        if isinstance(value, dict) and isinstance(out.get(key), dict):
+            out[key] = _merged(out[key], value, f"{where}.{key}")
+        else:
+            assert out.setdefault(key, value) == value, (
+                f"examples disagree on context{where}.{key}: give it the "
+                "value the other metric files' examples use")
+    return out
+
+
+def _all_examples_in_one_trace():
+    """Every metric file's example, one after the other in time on the same
+    devices, and their contexts merged: a trace in which every reader finds
+    what it reads."""
+    devices, host, ctx, at, steps = {}, [], {}, 0.0, 0
+    for name in METRIC_FILES:
+        example = _example(name)
+        part = trace_reduce.from_example(example,
+                                         shift_ns=at - example["window"][0])
+        for d, events in part.devices.items():
+            devices.setdefault(d, []).extend(events)
+        host.extend(part.host)
+        ctx = _merged(ctx, example.get("context", {}), "")
+        at, steps = part.window[1], max(steps, part.steps)
+    return Trace(devices, host, (0.0, at), steps), ctx
+
+
+@pytest.mark.parametrize("name", METRIC_FILES)
+def test_metric_file_reads_its_own_example(name):
+    """``reducer``, ``params`` and ``example`` of one file held together,
+    through the harness's own lookup: the reader returns the value the file
+    states for the file's events, and nothing for its ``nothing`` case.  A
+    new metric brings its file and its entry; no test is edited for it."""
+    spec = _spec()
+    entry = next(m for m in spec["per_layer"] if m["name"] == name)
+    alone = {**spec, "per_layer": [entry]}
+    cell = (entry.get("workloads") or [spec["workloads"][0]["name"]])[0]
+    example = _example(name)
+    got = run.per_layer(alone, cell, trace_reduce.from_example(example),
+                        example.get("context", {}))
+    assert got == {name: {"value": pytest.approx(example["value"],
+                                                 rel=1e-9),
+                          "unit": entry["unit"]}}
+    nothing = _nothing(example)
+    assert run.per_layer(alone, cell, trace_reduce.from_example(nothing),
+                         nothing.get("context", {})) == {}
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in _spec()["workloads"]])
-def test_per_layer_goes_through_each_metric_file_of_the_cell(cell,
-                                                             monkeypatch):
-    """The harness finds each reader by the name in BENCHMARK.json; what a
-    reader cannot find in the trace is left out of the line."""
-    collective = ("%psum.1 = f32[8] all-reduce(f32[8] %x), channel_id=1")
-    kernel = '%attn.1 = bf16[8] custom-call(), custom_call_target="tpu_custom_call"'
-    events = [Event("%fusion.1 = f32[8] fusion()", 0, 300, "XLA Ops"),
-              Event(collective, 300, 100, "XLA Ops"),
-              Event(kernel, 400, 200, "XLA Ops")]
-    ctx = {"run": {"dispatch_s": [0.002]}}
-    monkeypatch.setattr(flops, "flash_step_least",
-                        lambda ctx: {"seconds": 50e-9})
-    got = run.per_layer(_spec(), cell, Trace({0: events}, [], WINDOW, 2), ctx)
+def test_per_layer_goes_through_each_metric_file_of_the_cell(cell):
+    """The harness finds each reader by the name in BENCHMARK.json.  From a
+    trace made of every metric file's example, a cell reports exactly the
+    metrics BENCHMARK.json lists for it (what other cells' metrics could
+    read is there too, and is not reported); what a reader cannot find is
+    left out of the line."""
+    trace, ctx = _all_examples_in_one_trace()
+    got = run.per_layer(_spec(), cell, trace, ctx)
     want = {m["name"] for m in run.metrics_of(_spec(), "per_layer", cell)}
     assert set(got) == want
     assert all(set(v) == {"value", "unit"} for v in got.values())
-    assert got["device_idle_pct"]["value"] == pytest.approx(40.0)
-    if "flash_roofline" in want:
-        assert got["flash_roofline"]["value"] == pytest.approx(50.0)
-    if "collective_exposed_ms" in want:
-        assert got["collective_exposed_ms"]["value"] == pytest.approx(50 / 1e6)
-    # Nothing to read: the metric is left out, the others stay.
-    bare = run.per_layer(_spec(), cell, Trace({0: events[:1]}, [], WINDOW, 2),
-                         {})
-    assert set(bare) == {"device_idle_pct"}
+    # Nothing to read: every metric is left out (each file's own ``nothing``
+    # case goes through its reader in the test above).
+    assert run.per_layer(_spec(), cell, Trace({}, [], WINDOW, 2), {}) == {}
+
+
+def test_a_new_metric_is_one_file_and_one_entry(tmp_path):
+    """A later PR adds ``layer_metrics/<metric>.json`` and an entry under
+    ``per_layer`` and edits nothing: in a copy of the spec and the
+    benchmark's files with a seventh metric added that way, the tests of
+    files, names and examples pass as they stand; with the file's example
+    taken out they fail and say what is missing."""
+    import shutil
+
+    for rel in ("BENCHMARK.json", "benchmark", os.path.join("tests",
+                                                            "benchmark")):
+        src, dst = os.path.join(REPO, rel), tmp_path / rel
+        if os.path.isdir(src):
+            shutil.copytree(src, dst, ignore=shutil.ignore_patterns(
+                "_trace", "__pycache__"))
+        else:
+            shutil.copy(src, dst)
+    metric = {
+        "name": "matmul_fusion_ms", "reducer": "trace_reduce.op_time_ms",
+        "params": {"pattern": "^%fusion", "scope": "dot_general:$",
+                   "line": "sync"},
+        "what": "device time per step of the fusions rooted in a matmul",
+        "example": {
+            "events": [["%fusion.1 = f32[8] fusion()", 0, 300, "XLA Ops",
+                        "jit(step)/jvp(GPT)/h_0/mlp_in/dot_general:"],
+                       ["%fusion.2 = f32[8] fusion()", 300, 100, "XLA Ops",
+                        "jit(step)/add:"]],
+            "window": [0, 1000], "steps": 2, "value": 300 / 2 / 1e6,
+            "nothing": {"events": [["%copy.1 = f32[8] copy()", 0, 9,
+                                    "XLA Ops"]]}}}
+    spec = _spec()
+    spec["per_layer"].append({
+        "name": metric["name"], "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "model (forward / backward)",
+        "moves": "step_ms", "workloads": ["gpt2m-1chip", "gpt2m-dp4"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    file = tmp_path / "benchmark" / "layer_metrics" / "matmul_fusion_ms.json"
+
+    def tests_of_the_copy():
+        env = {**os.environ, "JAX_PLATFORMS": "cpu",
+               "PYTHONPATH": os.pathsep.join([str(tmp_path), REPO])}
+        return subprocess.run(
+            [sys.executable, "-m", "pytest", "tests/benchmark", "-v",
+             "-p", "no:cacheprovider", "-k",
+             "data_file_loads or resolves_to_a_file or benchmark_json_keeps "
+             "or own_example or per_layer_goes_through"],
+            env=env, cwd=tmp_path, capture_output=True, text=True,
+            timeout=240)
+
+    file.write_text(json.dumps(metric))
+    done = tests_of_the_copy()
+    assert done.returncode == 0, done.stdout[-3000:]
+    assert re.search(r"own_example\[matmul_fusion_ms\] PASSED", done.stdout)
+    assert re.search(r"data_file_loads\w+\[layer_metrics/matmul_fusion_ms"
+                     r"\.json\] PASSED", done.stdout)
+    del metric["example"]
+    file.write_text(json.dumps(metric))
+    done = tests_of_the_copy()
+    assert done.returncode != 0
+    assert 'matmul_fusion_ms.json has no "example"' in done.stdout
 
 
 def test_collective_pattern_matches_the_opcode_not_an_operand():
@@ -446,7 +664,8 @@ def test_timed_metrics_are_the_cells_end_to_end_metrics(capsys):
 
     run_ = {**_stamps(0.010, 20, 0.040), "losses": [1.0] * 100,
             "dispatch_s": [0.002] * 100,
-            "gc": {"collections": 0, "seconds": 0.0, "longest_s": 0.0}}
+            "gc": {"collections": 0, "seconds": 0.0, "longest_s": 0.0},
+            "compiles": run.CompileWatch().summary()}
     up = {"family": Family, "cell": {}, "device": {"count": 1},
           "flops_per_step": 1e9, "setup_s": 30.0, "first_loss": 2.0}
     got = run.timed_metrics(
@@ -461,6 +680,15 @@ def test_timed_metrics_are_the_cells_end_to_end_metrics(capsys):
     # Where the window stood still: the five longest steps, by index.
     assert {i for i, _ in shown["longest_steps_ms"]} == {19, 39, 59, 79, 99}
     assert shown["longest_steps_ms"][0][1] == pytest.approx(50.0)
+    # ... and how much of each was the dispatch of the next step (the last
+    # step has none after it).
+    by_step = dict(zip((i for i, _ in shown["longest_steps_ms"]),
+                       shown["dispatch_ms_in_longest_steps"]))
+    assert by_step.pop(99) is None
+    assert list(by_step.values()) == [pytest.approx(2.0)] * 4
+    assert shown["compiles_in_window"] == {
+        "compilations": 0, "cache_reads": 0, "cache_writes": 0,
+        "seconds": 0.0, "events": {}}
 
 
 def test_gc_watch_times_the_collections_it_is_told_of():
@@ -472,6 +700,29 @@ def test_gc_watch_times_the_collections_it_is_told_of():
     got = watch.summary()
     assert got["collections"] == 3
     assert 0.0 <= got["longest_s"] <= got["seconds"] < 1.0
+
+
+def test_compile_watch_counts_what_jax_compiles_while_it_is_entered():
+    import jax
+    import jax.numpy as jnp
+    from jax._src import monitoring
+
+    before = (len(monitoring.get_event_listeners()),
+              len(monitoring.get_event_duration_listeners()))
+    x = jnp.arange(7.0)
+    jax.block_until_ready(x)
+    with run.CompileWatch() as cold:
+        fn = jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.0)
+        jax.block_until_ready(fn(x))
+    with run.CompileWatch() as warm:     # the same shapes: nothing compiles
+        jax.block_until_ready(fn(x))
+    assert cold.summary()["compilations"] >= 1
+    assert cold.summary()["seconds"] > 0.0
+    assert "backend_compile_duration" in cold.summary()["events"]
+    assert warm.summary() == {"compilations": 0, "cache_reads": 0,
+                              "cache_writes": 0, "seconds": 0.0, "events": {}}
+    assert before == (len(monitoring.get_event_listeners()),
+                      len(monitoring.get_event_duration_listeners()))
 
 
 def test_the_loop_takes_the_batches_in_turn():
@@ -523,11 +774,23 @@ def test_traffic_generator_reads_the_file_and_the_seed(name):
     assert not np.array_equal(a[0][0], c[0][0])     # another seed
 
 
-def test_recorded_chip_trace_has_a_device_plane_and_busy_time():
-    found = glob.glob(os.path.join(BENCH, "testdata", "*.xplane.pb*"))
-    if not found:
-        pytest.skip("no recorded chip trace under benchmark/testdata")
-    trace = trace_reduce.read_xplane(found[0], steps=2)
+# Recorded on a TPU v5 lite by run.py's traced window (two steps each of a
+# tiny GPT): the rehearsal sizes with dense attention (PR 23), and 256 wide
+# with the flash kernels on (PR 24).
+DENSE_TRACE = os.path.join(BENCH, "testdata", "gpt_tiny_2steps_v5e.xplane.pb.gz")
+FLASH_TRACE = os.path.join(BENCH, "testdata",
+                           "gpt_tiny_flash_2steps_v5e.xplane.pb.gz")
+
+
+def _busy_ms_per_step(trace):
+    return trace_reduce.per_step(trace_reduce.mean_over_devices(
+        trace, lambda ev: trace_reduce.busy_ns(trace_reduce.sync_ops(ev),
+                                               trace.window)), trace.steps)
+
+
+@pytest.mark.parametrize("path", [DENSE_TRACE, FLASH_TRACE])
+def test_recorded_chip_trace_has_a_device_plane_and_busy_time(path):
+    trace = trace_reduce.read_xplane(path, steps=2)
     assert trace.devices, "no /device:TPU:<n> plane with an XLA Ops line"
     assert trace.window[1] > trace.window[0]
     busy = trace_reduce.mean_over_devices(
@@ -536,6 +799,167 @@ def test_recorded_chip_trace_has_a_device_plane_and_busy_time():
     assert any(h.name == "bench_window" for h in trace.host)
     assert trace_reduce.top_ops(trace.devices[min(trace.devices)],
                                 trace.window)
+
+
+def test_recorded_trace_reads_as_it_did_before_events_had_a_scope():
+    """The values PR 23's reader gave for this file (read with the parent
+    commit's trace_reduce.py): the scope, the ``hvd_`` prefix and the
+    innermost-span rule change none of them."""
+    trace = trace_reduce.read_xplane(DENSE_TRACE, steps=2)
+    assert trace.window == (42861587.0, 46098696.0)
+    assert len(trace.devices[0]) == 1288 and len(trace.host) == 5
+    ctx = {"run": {"dispatch_s": [0.001, 0.003]}}
+    got = run.per_layer(_spec(), "resnet50-1chip", trace, ctx)
+    assert got["host_dispatch_ms"]["value"] == 2.0
+    assert got["device_idle_pct"]["value"] == 96.26388854993762
+    assert _busy_ms_per_step(trace) * 2 / 1e3 == 0.000120942   # busy_s
+    first = trace.devices[0]
+    assert trace_reduce.top_ops(first, trace.window)[:4] == [
+        ["fusion", 8.8045e-05], ["multiply_reduce_fusion", 1.1386e-05],
+        ["convolution_add_fusion", 7.062e-06], ["copy-done", 3.774e-06]]
+    assert trace_reduce.idle_gaps(first, trace.host, trace.window)[:4] == [
+        ["bench_dispatch", 0.00190976], ["bench_dispatch", 0.000609184],
+        ["bench_dispatch", 0.000594514], ["bench_dispatch", 4.58e-07]]
+
+
+@pytest.mark.parametrize("path", [DENSE_TRACE, FLASH_TRACE])
+def test_every_device_op_gets_the_scope_the_file_gives_it(path):
+    """``ProfileData`` names an event by its metadata's name, so the raw
+    file's ``{name: tf_op}`` is the scope of every event; not every op has
+    one (copies, layout changes), and a host plane has none at all."""
+    import gzip
+
+    with gzip.open(path, "rb") as f:
+        raw = f.read()
+    scopes = xplane_raw.op_scopes(raw, r"^/device:TPU:\d+$")
+    stats = xplane_raw.event_stats(raw)
+    trace = trace_reduce.read_xplane(path, steps=2)
+    assert set(scopes) == {f"/device:TPU:{d}" for d in trace.devices}
+    for d, events in trace.devices.items():
+        names = stats[f"/device:TPU:{d}"]
+        assert all(e.name in names for e in events)
+        assert all(e.scope == scopes[f"/device:TPU:{d}"].get(e.name, "")
+                   for e in events)
+        with_scope = [e for e in events if e.scope]
+        assert with_scope and all(e.scope.endswith(":") for e in with_scope)
+        assert any(not e.scope for e in events)
+    assert all("tf_op" not in s for s in stats["/host:CPU"].values())
+    assert not any(h.scope for h in trace.host)
+    op = next(e for e in trace.devices[0] if "dot_general" in e.scope)
+    assert names[op.name]["flops"] > 0 and names[op.name]["bytes_accessed"] > 0
+
+
+def test_recorded_dense_trace_splits_into_forward_backward_and_the_rest():
+    """JAX writes ``jvp(`` and ``transpose(`` into every op's scope with no
+    change to the program.  Of this trace's 938 sync-line ops 708 carry no
+    scope; by time the classes are 38.4 / 54.4 / 7.3 % (ISSUE 24 read 38.2 /
+    54.2 / 7.6 from the file's picoseconds; ``ProfileData`` gives whole
+    nanoseconds, which costs ops of a few ns up to one each)."""
+    trace = trace_reduce.read_xplane(DENSE_TRACE, steps=2)
+    sync = trace_reduce.sync_ops(trace.devices[0])
+    assert (len(sync), sum(not e.scope for e in sync)) == (938, 708)
+    busy = _busy_ms_per_step(trace)
+    parts = [trace_reduce.op_time_ms(
+        trace, {}, **_load(f"layer_metrics/{name}.json")["params"])
+        for name in ("forward_ms", "backward_ms", "outside_model_ms")]
+    assert sum(parts) == pytest.approx(busy, rel=1e-9)
+    assert [100 * p / busy for p in parts] == pytest.approx(
+        [38.36, 54.37, 7.27], abs=0.01)
+    assert [100 * p / busy for p in parts] == pytest.approx(
+        [38.2, 54.2, 7.6], abs=0.4)
+
+
+def test_recorded_flash_trace_parts_the_kernel_calls_by_pass():
+    """The flash kernels carry no name of their own; the scope tells the
+    forward call from the two backward calls (a ``custom_vjp``'s backward
+    is traced under ``transpose(jvp(...))``).  Two layers, two steps: four
+    forward and eight backward calls."""
+    trace = trace_reduce.read_xplane(FLASH_TRACE, steps=2)
+    params = {n: _load(f"layer_metrics/{n}.json")["params"]
+              for n in ("flash_kernel_ms", "flash_fwd_ms", "flash_bwd_ms",
+                        "forward_ms", "backward_ms", "outside_model_ms")}
+    got = {n: trace_reduce.op_time_ms(trace, {}, **p)
+           for n, p in params.items()}
+    assert got["flash_kernel_ms"] == pytest.approx(0.0348855, rel=1e-9)
+    assert got["flash_fwd_ms"] == pytest.approx(0.0143085, rel=1e-9)
+    assert got["flash_bwd_ms"] == pytest.approx(0.020577, rel=1e-9)
+    assert got["flash_fwd_ms"] + got["flash_bwd_ms"] == pytest.approx(
+        got["flash_kernel_ms"], rel=1e-9)
+    assert (got["forward_ms"] + got["backward_ms"] + got["outside_model_ms"]
+            == pytest.approx(_busy_ms_per_step(trace), rel=1e-9))
+    calls = trace_reduce.classify(trace.devices[0],
+                                  params["flash_kernel_ms"]["pattern"])[0]
+    backward = [e for e in calls if "transpose(" in e.scope]
+    assert (len(calls), len(backward)) == (12, 8)
+    assert all(e.scope.endswith("/attn/pallas_call:") for e in calls)
+
+
+def test_the_raw_decoder_needs_the_standard_library_only():
+    code = ("import sys; before = set(sys.modules); "
+            "import benchmark.xplane_raw; "
+            "print(sorted({m.split('.')[0] for m in set(sys.modules) - before}"
+            " - set(sys.stdlib_module_names) - {'benchmark'}))")
+    done = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                          capture_output=True, text=True, timeout=60)
+    assert done.stdout.strip() == "[]", done.stdout + done.stderr
+
+
+def _varint(n):
+    out = bytearray()
+    while True:
+        out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+        n >>= 7
+        if not n:
+            return bytes(out)
+
+
+def _field(number, value):
+    """One protobuf field: a varint for an int, length-delimited bytes."""
+    if isinstance(value, int):
+        return _varint(number << 3) + _varint(value)
+    value = value.encode() if isinstance(value, str) else value
+    return _varint(number << 3 | 2) + _varint(len(value)) + value
+
+
+def test_raw_decoder_on_a_hand_made_xspace():
+    """Every kind of XStat value, a stat that refers to a name, two metadata
+    entries of one name, a line that is skipped, a plane that is not asked
+    for and a plane without stats."""
+    import struct
+
+    def entry(key, message):
+        return _field(1, key) + _field(2, message)
+
+    stat_names = b"".join(_field(5, entry(i, _field(1, i) + _field(2, n)))
+                          for i, n in enumerate(
+                              ["tf_op", "flops", "delta", "share", "blob",
+                               "jit(f)/jvp(M)/dot_general:"], start=1))
+    fixed64 = _varint(2 << 3 | 1) + struct.pack("<d", 0.25)
+    op = (_field(1, 7) + _field(2, "%fusion.1 = f32[8] fusion()")
+          + _field(5, _field(1, 1) + _field(7, 6))            # ref_value
+          + _field(5, _field(1, 2) + _field(3, 1 << 40))      # uint64
+          + _field(5, _field(1, 3) + _field(4, (1 << 64) - 5))  # int64 -5
+          + _field(5, _field(1, 4) + fixed64)                 # double
+          + _field(5, _field(1, 5) + _field(6, b"\x00\x01")))  # bytes
+    twin = (_field(1, 8) + _field(2, "%fusion.1 = f32[8] fusion()")
+            + _field(5, _field(1, 9) + _field(5, "by-value")))
+    copy = _field(1, 9) + _field(2, "%copy.1 = f32[8] copy()")
+    line = _field(2, "XLA Ops") + _field(4, _field(1, 7) + _field(3, 1000))
+    device = (_field(2, "/device:TPU:0") + _field(3, line) + stat_names
+              + b"".join(_field(4, entry(k, m))
+                         for k, m in ((7, op), (8, twin), (9, copy))))
+    host = _field(2, "/host:CPU") + _field(4, entry(1, _field(2, "bench_wait")))
+    space = _field(1, device) + _field(1, host) + _field(4, "a hostname")
+    stats = xplane_raw.event_stats(space)
+    assert stats["/device:TPU:0"]["%fusion.1 = f32[8] fusion()"] == {
+        "tf_op": "jit(f)/jvp(M)/dot_general:", "flops": 1 << 40, "delta": -5,
+        "share": 0.25, "blob": b"\x00\x01", "9": "by-value"}
+    assert stats["/host:CPU"] == {"bench_wait": {}}
+    assert xplane_raw.op_scopes(space, "^/device:") == {"/device:TPU:0": {
+        "%fusion.1 = f32[8] fusion()": "jit(f)/jvp(M)/dot_general:"}}
+    assert xplane_raw.op_scopes(b"") == {}
+    with pytest.raises(ValueError, match="wire type"):
+        list(xplane_raw.fields(_varint(1 << 3 | 3)))
 
 
 # ---------------------------------------------------------------------------

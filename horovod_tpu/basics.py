@@ -255,7 +255,9 @@ def start_device_trace(logdir: str) -> None:
     """Start the XLA profiler (TensorBoard trace) — the on-device half of
     observability: the host timeline covers NEGOTIATE/data-plane phases,
     this covers the compiled XLA programs on the chip (SURVEY.md §5:
-    timeline hand-off into jax.profiler)."""
+    timeline hand-off into jax.profiler).  The eager spine's ``hvd_*`` spans
+    and a compiled step's ``hvd_*`` scopes land in the same trace, on its
+    clock (docs/observability.md, "Names on the profiler's clock")."""
     import jax
 
     jax.profiler.start_trace(logdir)

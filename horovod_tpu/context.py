@@ -25,6 +25,7 @@ import threading
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .exceptions import HorovodInternalError
 from .ops.device_plane import DevicePlane
@@ -292,64 +293,68 @@ class HorovodContext:
         group_key: str = "",
         group_size: int = 0,
     ) -> int:
-        # Device-plane capability first: a device-resident jax.Array whose
-        # op the plane serves never touches the host — the entry carries a
-        # zero-memory shape/dtype proxy for negotiation metadata only, and
-        # the announced device bit tells the coordinator this rank can
-        # dispatch the jitted collective.
-        dev_arr = self.device_plane.adopt(array, op, reduce_op, process_set_id)
-        if dev_arr is not None:
-            np_arr = np.broadcast_to(
-                np.zeros((), numpy_dtype(wire_dtype(dev_arr.dtype))),
-                tuple(dev_arr.shape))
-            was_jax, orig_dtype = True, dev_arr.dtype
-        else:
-            np_arr, was_jax, orig_dtype = _to_host(array)
-        dtype = wire_dtype(np_arr.dtype if orig_dtype is None else orig_dtype)
-        if name is None:
-            name = f"{op.name.lower()}.noname.{next(self._noname_counter)}"
-        if dtype in _INT_TYPES:
-            if reduce_op == ReduceOp.AVERAGE and op in (
-                    OpType.ALLREDUCE, OpType.REDUCESCATTER):
-                raise ValueError(
-                    "hvd.Average is not supported for integer tensors; use hvd.Sum"
-                )
-            if prescale_factor != 1.0 or postscale_factor != 1.0:
-                raise ValueError("pre/postscale not supported for integer tensors")
-        if splits is not None:
-            splits = np.ascontiguousarray(np.asarray(splits, dtype=np.int64))
+        # A span on the caller's thread, on the profiler's clock (a flag test
+        # outside a profiling session): adopt, entry, hand-over to the core.
+        with TraceAnnotation("hvd_enqueue") as span:
+            # Device-plane capability first: a device-resident jax.Array whose
+            # op the plane serves never touches the host — the entry carries a
+            # zero-memory shape/dtype proxy for negotiation metadata only, and
+            # the announced device bit tells the coordinator this rank can
+            # dispatch the jitted collective.
+            dev_arr = self.device_plane.adopt(array, op, reduce_op, process_set_id)
+            if dev_arr is not None:
+                np_arr = np.broadcast_to(
+                    np.zeros((), numpy_dtype(wire_dtype(dev_arr.dtype))),
+                    tuple(dev_arr.shape))
+                was_jax, orig_dtype = True, dev_arr.dtype
+            else:
+                np_arr, was_jax, orig_dtype = _to_host(array)
+            dtype = wire_dtype(np_arr.dtype if orig_dtype is None else orig_dtype)
+            if name is None:
+                name = f"{op.name.lower()}.noname.{next(self._noname_counter)}"
+            if dtype in _INT_TYPES:
+                if reduce_op == ReduceOp.AVERAGE and op in (
+                        OpType.ALLREDUCE, OpType.REDUCESCATTER):
+                    raise ValueError(
+                        "hvd.Average is not supported for integer tensors; use hvd.Sum"
+                    )
+                if prescale_factor != 1.0 or postscale_factor != 1.0:
+                    raise ValueError("pre/postscale not supported for integer tensors")
+            if splits is not None:
+                splits = np.ascontiguousarray(np.asarray(splits, dtype=np.int64))
 
-        handle = next(self._handle_counter)
-        entry = TensorEntry(
-            handle=handle,
-            name=name,
-            op=op,
-            array=np_arr,
-            dtype=dtype,
-            reduce_op=reduce_op,
-            root_rank=root_rank,
-            splits=splits,
-            process_set_id=process_set_id,
-            prescale_factor=prescale_factor,
-            postscale_factor=postscale_factor,
-            was_jax=was_jax,
-            orig_dtype=orig_dtype,
-            group_key=group_key,
-            group_size=group_size,
-            device_array=dev_arr,
-        )
-        with self._entries_lock:
-            self._entries[handle] = entry
-            if name in self._inflight_names:
-                # Reference semantics: a second op with an in-flight name
-                # queues behind the first (the negotiation layer keys by
-                # name, so it is submitted once the first completes — safe
-                # because every rank orders instances the same way).
-                self._deferred.setdefault(name, []).append(entry)
-                return handle
-            self._inflight_names.add(name)
-        self.core.enqueue(entry)
-        return handle
+            handle = next(self._handle_counter)
+            span.set_metadata(handle=handle)
+            entry = TensorEntry(
+                handle=handle,
+                name=name,
+                op=op,
+                array=np_arr,
+                dtype=dtype,
+                reduce_op=reduce_op,
+                root_rank=root_rank,
+                splits=splits,
+                process_set_id=process_set_id,
+                prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor,
+                was_jax=was_jax,
+                orig_dtype=orig_dtype,
+                group_key=group_key,
+                group_size=group_size,
+                device_array=dev_arr,
+            )
+            with self._entries_lock:
+                self._entries[handle] = entry
+                if name in self._inflight_names:
+                    # Reference semantics: a second op with an in-flight name
+                    # queues behind the first (the negotiation layer keys by
+                    # name, so it is submitted once the first completes — safe
+                    # because every rank orders instances the same way).
+                    self._deferred.setdefault(name, []).append(entry)
+                    return handle
+                self._inflight_names.add(name)
+            self.core.enqueue(entry)
+            return handle
 
     def group_key_for(self, name: Optional[str]) -> str:
         """Negotiation key for one grouped_* call (group_table.cc analog).
@@ -372,7 +377,9 @@ class HorovodContext:
             entry = self._entries.get(handle)
         if entry is None:
             raise ValueError(f"unknown handle {handle}")
-        entry.done.wait()
+        # How long the caller's loop stands still for negotiation + execution.
+        with TraceAnnotation("hvd_wait", handle=handle):
+            entry.done.wait()
         with self._entries_lock:
             self._entries.pop(handle, None)
         if entry.error is not None:
@@ -460,7 +467,10 @@ class HorovodContext:
         try:
             if resp.error:
                 raise HorovodInternalError(resp.error)
-            self._execute(resp, entries)
+            # One fused response through the data plane, on this thread.
+            with TraceAnnotation("hvd_execute", seq=resp.seq,
+                                 tensors=len(entries)):
+                self._execute(resp, entries)
             for e in entries:
                 e.done.set()
         except Exception as exc:  # noqa: BLE001 - propagate via handle

@@ -35,6 +35,7 @@ import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..exceptions import HorovodInternalError
 from ..parallel import mesh as _mesh
@@ -644,21 +645,26 @@ class DevicePlane:
                 self.stats["identity"] += len(entries)
             return
 
+        # The three dispatches of a fused device all-reduce, each a host
+        # span on this lane's thread (on the profiler's clock).
         mesh, ranks, my_dev = self._mesh_for(psid)
-        arrays = [jax.device_put(e.device_array, my_dev) for e in entries]
-        dtype = arrays[0].dtype
-        total = int(sum(a.size for a in arrays))
-        length = bucket_len(total)
-        packed = jax.device_put(
-            self._pack()(tuple(arrays), float(pre), length), my_dev)
-        garr = self._to_global(mesh, [packed])
-        codec = self._device_codec(rop, dtype, length, len(ranks))
-        schedule = self._device_schedule(len(ranks))
-        out = self._collective(psid, mesh, rop, dtype, length, codec,
-                               schedule)(garr)
-        row = self._shard_on(out, my_dev)
-        shapes = tuple(tuple(e.device_array.shape) for e in entries)
-        results = self._unpack()(row, float(post), shapes)
+        with TraceAnnotation("hvd_pack"):
+            arrays = [jax.device_put(e.device_array, my_dev) for e in entries]
+            dtype = arrays[0].dtype
+            total = int(sum(a.size for a in arrays))
+            length = bucket_len(total)
+            packed = jax.device_put(
+                self._pack()(tuple(arrays), float(pre), length), my_dev)
+            garr = self._to_global(mesh, [packed])
+        with TraceAnnotation("hvd_collective"):
+            codec = self._device_codec(rop, dtype, length, len(ranks))
+            schedule = self._device_schedule(len(ranks))
+            out = self._collective(psid, mesh, rop, dtype, length, codec,
+                                   schedule)(garr)
+        with TraceAnnotation("hvd_unpack"):
+            row = self._shard_on(out, my_dev)
+            shapes = tuple(tuple(e.device_array.shape) for e in entries)
+            results = self._unpack()(row, float(post), shapes)
         for e, r in zip(entries, results):
             e.result = r
         if codec != "none":

@@ -144,24 +144,26 @@ def _tree_allreduce(grads, op: ReduceOp, compression,
         axes = _resolve_axes(axis_name)
         vma_tracked = _vma_tracked()
         out = []
-        for leaf in leaves:
-            comp, ctx = compression.compress(leaf)
-            red = _reduce_grad_leaf(comp, axes, op, prescale_factor,
-                                    postscale_factor, vma_tracked)
-            out.append(compression.decompress(red, ctx))
+        with jax.named_scope("hvd_exchange"):
+            for leaf in leaves:
+                comp, ctx = compression.compress(leaf)
+                red = _reduce_grad_leaf(comp, axes, op, prescale_factor,
+                                        postscale_factor, vma_tracked)
+                out.append(compression.decompress(red, ctx))
         return jax.tree_util.tree_unflatten(treedef, out)
     # Eager: enqueue everything first (negotiation fuses the bucket), then wait.
     handles, ctxs = [], []
-    for i, leaf in enumerate(leaves):
-        comp, ctx = compression.compress(leaf)
-        ctxs.append(ctx)
-        handles.append(
-            allreduce_async(comp, name=f"{name_prefix}.{i}", op=op,
-                            prescale_factor=prescale_factor,
-                            postscale_factor=postscale_factor,
-                            process_set=process_set))
-    out = [compression.decompress(synchronize(h), ctx)
-           for h, ctx in zip(handles, ctxs)]
+    with jax.profiler.TraceAnnotation("hvd_exchange"):
+        for i, leaf in enumerate(leaves):
+            comp, ctx = compression.compress(leaf)
+            ctxs.append(ctx)
+            handles.append(
+                allreduce_async(comp, name=f"{name_prefix}.{i}", op=op,
+                                prescale_factor=prescale_factor,
+                                postscale_factor=postscale_factor,
+                                process_set=process_set))
+        out = [compression.decompress(synchronize(h), ctx)
+               for h, ctx in zip(handles, ctxs)]
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -254,6 +256,11 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
     predivide) demote deterministically to eager with a counter
     recording why (``ops.gspmd_plane.plane_counters()``) — demotion is
     bit-identical, since the annotations never change the math.
+
+    In a profiler trace the gradient exchange is named ``hvd_exchange`` and
+    the inner update ``hvd_update``: ``jax.named_scope``s in a compiled step,
+    host spans in the eager loop (docs/observability.md, "Names on the
+    profiler's clock").
     """
     if backward_passes_per_step < 1:
         raise ValueError("backward_passes_per_step must be >= 1")
@@ -389,22 +396,23 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         min_bytes = _jit_ops._device_codec_defaults()[1]
         vma_tracked = _vma_tracked()
         out, new_res = [], []
-        for leaf, res in zip(leaves, rleaves):
-            vma = _leaf_vma(leaf)
-            varying = (vma is None or not vma_tracked
-                       or all(a in vma for a in axes))
-            if (len(axes) == 1 and varying
-                    and _jit_ops.quantized_allreduce_eligible(
-                        leaf, world, min_bytes)):
-                corrected = leaf + res
-                out.append(_jit_ops.quantized_allreduce(
-                    corrected, axes[0], op=op, codec=dev_codec))
-                new_res.append(
-                    corrected - _qz.fake_quantize(corrected, dev_codec))
-            else:
-                out.append(_reduce_grad_leaf(leaf, axes, op, 1.0, 1.0,
-                                             vma_tracked))
-                new_res.append(res)
+        with jax.named_scope("hvd_exchange"):
+            for leaf, res in zip(leaves, rleaves):
+                vma = _leaf_vma(leaf)
+                varying = (vma is None or not vma_tracked
+                           or all(a in vma for a in axes))
+                if (len(axes) == 1 and varying
+                        and _jit_ops.quantized_allreduce_eligible(
+                            leaf, world, min_bytes)):
+                    corrected = leaf + res
+                    out.append(_jit_ops.quantized_allreduce(
+                        corrected, axes[0], op=op, codec=dev_codec))
+                    new_res.append(
+                        corrected - _qz.fake_quantize(corrected, dev_codec))
+                else:
+                    out.append(_reduce_grad_leaf(leaf, axes, op, 1.0, 1.0,
+                                                 vma_tracked))
+                    new_res.append(res)
         return (jax.tree_util.tree_unflatten(treedef, out),
                 jax.tree_util.tree_unflatten(treedef, new_res))
 
@@ -429,9 +437,19 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         # traced paths, memo-deduplicated for the eager per-step path.
         # The gspmd branch below overrides the tag within its trace.
         _hlo.mark_plane("eager")
+        leaves = jax.tree_util.tree_leaves(grads)
+        traced = bool(leaves) and _is_traced(leaves[0])
+
+        def inner_update(reduced, inner_state):
+            # The update's name in whichever trace sees it: a scope in the
+            # op_name of a compiled step's ops, a host span on the profiler's
+            # clock around the eager dispatches.
+            with (jax.named_scope if traced
+                  else jax.profiler.TraceAnnotation)("hvd_update"):
+                return optimizer.update(reduced, inner_state, params)
+
         if backward_passes_per_step == 1:
-            leaves = jax.tree_util.tree_leaves(grads)
-            if (gspmd_mesh is not None and leaves and _is_traced(leaves[0])
+            if (gspmd_mesh is not None and traced
                     and not _axes_bound(axis_name)):
                 # GSPMD plane: no explicit collective.  The grads of a
                 # batch-sharded global-mean loss arrive globally reduced
@@ -439,19 +457,18 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
                 # them replicated so GSPMD schedules that reduce where it
                 # overlaps the optimizer math below.
                 _hlo.mark_plane("gspmd")
-                reduced = _gspmd.constrain_grads(grads, gspmd_mesh)
-                updates, inner = optimizer.update(reduced,
-                                                  state.inner_state, params)
+                with jax.named_scope("hvd_exchange"):
+                    reduced = _gspmd.constrain_grads(grads, gspmd_mesh)
+                updates, inner = inner_update(reduced, state.inner_state)
                 return updates, DistributedOptState(inner, state.accum,
                                                     state.counter,
                                                     state.residual)
-            if (ef_active and state.residual is not None and leaves
-                    and _is_traced(leaves[0])):
+            if ef_active and state.residual is not None and traced:
                 reduced, residual = reduce_grads_ef(grads, state.residual)
             else:
                 reduced = reduce_grads(grads, 1)
                 residual = state.residual
-            updates, inner = optimizer.update(reduced, state.inner_state, params)
+            updates, inner = inner_update(reduced, state.inner_state)
             return updates, DistributedOptState(inner, state.accum,
                                                 state.counter, residual)
 
@@ -459,7 +476,7 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         counter = state.counter + 1
         k = backward_passes_per_step
 
-        if _is_traced(jax.tree_util.tree_leaves(grads)[0]):
+        if traced:
             ax = axis_name if axis_name is not None else _mesh.mesh_axis_name()
 
             def _vary(tree):
@@ -472,7 +489,7 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
             def communicate(acc_inner):
                 acc, inner_state = acc_inner
                 reduced = reduce_grads(acc, k)
-                updates, inner = optimizer.update(reduced, inner_state, params)
+                updates, inner = inner_update(reduced, inner_state)
                 zeros = jax.tree_util.tree_map(jnp.zeros_like, acc)
                 return _vary((updates, zeros, inner))
 
@@ -490,7 +507,7 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         # Eager: plain Python control flow.
         if int(counter) % k == 0:
             reduced = reduce_grads(accum, k)
-            updates, inner = optimizer.update(reduced, state.inner_state, params)
+            updates, inner = inner_update(reduced, state.inner_state)
             zeros = jax.tree_util.tree_map(jnp.zeros_like, accum)
             return updates, DistributedOptState(inner, zeros,
                                                 jnp.zeros((), jnp.int32),
@@ -607,19 +624,21 @@ def _sharded_distributed_optimizer(optimizer: optax.GradientTransformation,
         # Reduce over the non-shard axes in one combined psum, then
         # reduce-SCATTER over the shard axis: each rank ends with the
         # fully-summed gradient for its chunk.
-        if len(axes) > 1:
-            gvec = lax.psum(gvec, tuple(axes[1:]))
-        gshard = lax.psum_scatter(gvec, shard_ax, scatter_dimension=0,
-                                  tiled=True)
-        if op == ReduceOp.AVERAGE:
-            total_ranks = 1
-            for a in axes:
-                total_ranks *= _jit_ops.axis_size(a)
-            gshard = gshard / total_ranks
+        with jax.named_scope("hvd_exchange"):
+            if len(axes) > 1:
+                gvec = lax.psum(gvec, tuple(axes[1:]))
+            gshard = lax.psum_scatter(gvec, shard_ax, scatter_dimension=0,
+                                      tiled=True)
+            if op == ReduceOp.AVERAGE:
+                total_ranks = 1
+                for a in axes:
+                    total_ranks *= _jit_ops.axis_size(a)
+                gshard = gshard / total_ranks
         token = _inner_shard_axis.set(shard_ax)
         try:
-            upd_shard, new_inner = optimizer.update(
-                gshard, state.inner_state, state.master)
+            with jax.named_scope("hvd_update"):
+                upd_shard, new_inner = optimizer.update(
+                    gshard, state.inner_state, state.master)
         finally:
             _inner_shard_axis.reset(token)
         # fp32 master weights: the update lands on the master shard, and
@@ -629,8 +648,9 @@ def _sharded_distributed_optimizer(optimizer: optax.GradientTransformation,
         # Varying -> Invariant gather: every rank assembles the identical
         # full master vector, and its type says so (out_specs expecting
         # replicated params keep working).
-        master_vec = all_gather_invariant(new_master, shard_ax,
-                                          tiled=True)[:total]
+        with jax.named_scope("hvd_exchange"):
+            master_vec = all_gather_invariant(new_master, shard_ax,
+                                              tiled=True)[:total]
         updates = []
         offset = 0
         for leaf in pleaves:

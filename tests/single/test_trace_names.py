@@ -1,0 +1,236 @@
+"""The names the program writes into a profiler trace (docs/observability.md,
+"Names on the profiler's clock"): ``hvd_*`` scopes in a compiled step's
+``op_name``s, ``hvd_*`` host spans on the eager spine, and the rule that
+every such name starts with ``hvd_``.  All on the CPU: names and their
+nesting, never a time."""
+
+import ast
+import glob
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+from benchmark import common, run, trace_reduce  # noqa: E402
+from benchmark import traffic as traffic_gen  # noqa: E402
+from benchmark.families import gpt  # noqa: E402
+
+
+def _lowered_gpt_step(hvd, **tx_options) -> str:
+    """The ``shard_map`` step of ``benchmark/families/gpt.py:build`` (its
+    lines, with the optimizer's options open) at the configuration's
+    rehearsal sizes on four virtual devices, lowered with debug
+    information: each op's ``loc("<op_name>")``."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu import models
+
+    cfg = run.load_json("configs", "gpt2-medium.json")
+    traffic = traffic_gen.resolve(
+        run.load_json("traffic", "fixed-batch-8x1024x4.json"), rehearse=True)
+    mesh = common.hvd_mesh(jax.devices()[:4])
+    cell = gpt.setup(cfg, mesh, seed=3, rehearse=True)
+    (ids,), = traffic_gen.make_batches(traffic, gpt.inputs(cell, traffic),
+                                       mesh, seed=3)
+    model = cell["model"]
+    tx = hvd.DistributedOptimizer(
+        common.make_optimizer(cfg["optimizer"]), axis_name="hvd",
+        **tx_options)
+
+    def train_step(params, ids):
+        # The state is made inside the step, so that the sharded optimizer's
+        # per-chip chunks need no out_specs of their own.
+        opt_state = tx.init(params)
+        loss, grads = jax.value_and_grad(
+            lambda p: models.lm_loss(model.apply(p, ids), ids))(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates),
+                hvd.allreduce(loss, axis_name="hvd"))
+
+    step = jax.jit(shard_map(train_step, mesh=mesh, in_specs=(P(), P("hvd")),
+                             out_specs=(P(), P())))
+    return step.lower(cell["params"], ids).as_text(debug_info=True)
+
+
+def _op_names(lowered: str) -> list:
+    """Every ``op_name`` of the module's debug locations (inside the
+    ``shard_map`` body they are relative to it: ``hvd_update/mul``)."""
+    return re.findall(r'loc\("([^"]+)"', lowered)
+
+
+UPDATE = re.compile(r"(^|/)hvd_update/")
+EXCHANGE = re.compile(r"(^|/)hvd_exchange/")
+
+
+def test_compiled_step_names_its_update(hvd_single):
+    names = _op_names(_lowered_gpt_step(hvd_single))
+    update = [n for n in names if UPDATE.search(n)]
+    # AdamW's arithmetic, all of it under the scope and outside the model's
+    # passes: moments, bias corrections, the decayed update.
+    assert {n.rsplit("/", 1)[1] for n in update} >= {"mul", "add", "sqrt",
+                                                     "div"}
+    assert not any("jvp(" in n or "transpose(" in n for n in update)
+    # apply_updates is the user's line, not the optimizer's.
+    assert "add" in names
+
+
+def test_sharded_step_names_its_exchange_and_its_shard_update(hvd_single):
+    names = _op_names(_lowered_gpt_step(hvd_single,
+                                        shard_optimizer_states=True))
+    for collective in ("reduce_scatter", "all_gather_invariant"):
+        found = [n for n in names if n.endswith("/" + collective)]
+        assert found, (collective, sorted({n.rsplit("/", 1)[1]
+                                           for n in names}))
+        assert all(EXCHANGE.search(n) for n in found), found
+    assert any(UPDATE.search(n) and n.endswith("/sqrt") for n in names)
+
+
+def _profile_eager_update(hvd, out_dir):
+    """One eager ``tx.update`` over three leaves under the profiler (the
+    harness's options), after one outside it.  Returns the ``.xplane.pb``."""
+    from jax.profiler import TraceAnnotation
+
+    params = {"w": jnp.ones((64, 64)), "b": jnp.ones((64,)),
+              "s": jnp.ones((8,))}
+    grads = jax.tree_util.tree_map(lambda p: 0.5 * p, params)
+    tx = hvd.DistributedOptimizer(optax.sgd(0.1, momentum=0.9),
+                                  op=hvd.Average)
+    state = tx.init(params)
+    _, state = tx.update(grads, state, params)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(out_dir), profiler_options=options)
+    try:
+        with TraceAnnotation("bench_window"):
+            updates, state = tx.update(grads, state, params)
+            jax.block_until_ready(updates)
+    finally:
+        jax.profiler.stop_trace()
+    found = glob.glob(os.path.join(str(out_dir), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert len(found) == 1, found
+    return found[0]
+
+
+def test_eager_update_writes_the_spine_s_spans(hvd_single, tmp_path):
+    path = _profile_eager_update(hvd_single, tmp_path)
+    trace = trace_reduce.read_xplane(path, steps=1)
+    spans = {}
+    for h in trace.host:
+        spans.setdefault(h.name, []).append(h)
+    # Bare names: the arguments travel as the event's stats (below).
+    assert set(spans) == {"bench_window", "hvd_exchange", "hvd_enqueue",
+                          "hvd_wait", "hvd_execute", "hvd_update"}
+    assert [len(spans[n]) for n in ("hvd_exchange", "hvd_enqueue", "hvd_wait",
+                                    "hvd_update")] == [1, 3, 3, 1]
+    exchange, update = spans["hvd_exchange"][0], spans["hvd_update"][0]
+    for inner in spans["hvd_enqueue"] + spans["hvd_wait"]:
+        assert exchange.start_ns <= inner.start_ns
+        assert inner.end_ns <= exchange.end_ns
+    assert exchange.end_ns <= update.start_ns  # exchange, then update
+    for pattern in ("^hvd_enqueue$", "^hvd_wait$", "^hvd_execute$",
+                    "^hvd_update$"):
+        assert trace_reduce.host_span_ms(trace, {}, pattern) > 0
+
+    # Threads and arguments: what ``read_xplane`` does not keep.
+    from jax.profiler import ProfileData
+
+    lines = {}   # span name -> {index of the thread's line}
+    stats = {}   # span name -> [stats of each event]
+    at = 0
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            at += 1
+            for e in line.events:
+                if e.name.startswith("hvd_"):
+                    lines.setdefault(e.name, set()).add(at)
+                    stats.setdefault(e.name, []).append(
+                        (e.start_ns, dict(e.stats)))
+    caller = lines["hvd_exchange"]
+    assert len(caller) == 1
+    assert lines["hvd_enqueue"] == lines["hvd_wait"] == caller
+    assert lines["hvd_update"] == caller
+    assert lines["hvd_execute"].isdisjoint(caller)  # the dispatcher's
+    handles = sorted(s["handle"] for _, s in stats["hvd_enqueue"])
+    assert handles == sorted(s["handle"] for _, s in stats["hvd_wait"])
+    assert len(set(handles)) == 3
+    # A response carries tensors that were enqueued before it executes:
+    # when the j-th hvd_execute starts, at least as many enqueues have
+    # started as the responses so far carry tensors.
+    enqueued = sorted(t for t, _ in stats["hvd_enqueue"])
+    carried = 0
+    for start, s in sorted(stats["hvd_execute"], key=lambda kv: kv[0]):
+        assert "seq" in s  # -1 with one rank: nothing goes on the wire
+        carried += s["tensors"]
+        assert sum(t <= start for t in enqueued) >= carried
+    assert carried == 3
+
+
+NAMING_CALLS = {"TraceAnnotation", "named_scope"}
+
+
+def _callee_names(func) -> set:
+    """The names a call's callee may resolve to: ``f``, ``m.f``,
+    ``(f if c else g)``."""
+    if isinstance(func, ast.Name):
+        return {func.id}
+    if isinstance(func, ast.Attribute):
+        return {func.attr}
+    if isinstance(func, ast.IfExp):
+        return _callee_names(func.body) | _callee_names(func.orelse)
+    return set()
+
+
+def _names_written(tree) -> list:
+    """``(line, name or None)`` of every name a module writes into a trace:
+    the first argument of a ``TraceAnnotation`` / ``named_scope`` call and a
+    ``pallas_call``'s ``name=``; None where it is not a string literal."""
+    out = []
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call):
+            continue
+        callee = _callee_names(node.func)
+        if callee & NAMING_CALLS:
+            args = node.args[:1] or [k.value for k in node.keywords
+                                     if k.arg == "name"]
+        elif "pallas_call" in callee:
+            args = [k.value for k in node.keywords if k.arg == "name"]
+        else:
+            continue
+        for arg in args:
+            literal = isinstance(arg, ast.Constant) and isinstance(arg.value,
+                                                                   str)
+            out.append((node.lineno, arg.value if literal else None))
+    return out
+
+
+def test_every_name_the_program_writes_into_a_trace_starts_with_hvd():
+    """``read_xplane`` keeps a host span only if it is named ``bench_*``
+    (the harness's) or ``hvd_*`` (the program's), and a reader finds a scope
+    or a kernel by that prefix: a name outside it never reaches a metric."""
+    seen, bad = 0, []
+    for path in glob.glob(os.path.join(REPO, "horovod_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            tree = ast.parse(f.read(), path)
+        for line, name in _names_written(tree):
+            seen += 1
+            if name is None or not re.match(r"^hvd_[a-z0-9_]+$", name):
+                bad.append(f"{os.path.relpath(path, REPO)}:{line}: {name!r}")
+    assert not bad, bad
+    assert seen >= 12  # optimizer.py 8, context.py 3, ops/device_plane.py 3
+    # The rule itself, on a module that breaks it three ways.
+    broken = ast.parse(
+        "with jax.named_scope('update'): pass\n"
+        "with TraceAnnotation(label): pass\n"
+        "pl.pallas_call(k, name='flash_fwd')(x)\n"
+        "pl.pallas_call(k)(x)\n"
+        "with (jax.named_scope if t else TraceAnnotation)('hvd_ok'): pass\n")
+    assert _names_written(broken) == [(1, "update"), (2, None),
+                                      (3, "flash_fwd"), (5, "hvd_ok")]

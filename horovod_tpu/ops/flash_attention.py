@@ -8,15 +8,37 @@ out of HBM entirely (VMEM-blocked online softmax), the classic
 flash-attention trade.
 
 Layout: inputs [batch, seq, heads, head_dim]; the kernels run on
-[batch*heads, seq, head_dim] with streaming (BH, n_q, n_kv)-style grids:
-K/V (forward) or Q/dO (dK/dV backward) blocks flow through VMEM while the
-online-softmax state (acc/m/l, or the dq/dk/dv partials) persists in f32
-scratch across the innermost grid steps — so no operand is ever VMEM-whole
-and sequence length is HBM-bound, not VMEM-bound.  Causal mode requires
-block_q == block_k; tiles above the diagonal (and tiles entirely in tail
-padding) are predicated off with pl.when, so every processed row has at
-least one valid key (keeps the online-softmax max finite with a -1e30 mask
-value, no NaN guards needed).
+[batch*heads, seq, head_dim] with (BH, n_resident, n_streamed) grids: a
+block of queries (forward, dQ) or of keys (dK/dV) stays resident while
+blocks of the other operand stream through VMEM, and the online-softmax
+state (acc/m/l, or the dq/dk/dv partials) persists in f32 scratch across
+the innermost grid steps — so sequence length is HBM-bound, not
+VMEM-bound.
+
+The schedule has three nested sizes, chosen from the shape by
+:func:`tile_plan` (no knob): a grid **block** as large as a VMEM budget
+allows (the whole sequence up to 2048 x 128 in bf16, so a grid step a
+head: the fixed price of a grid step is paid a few hundred times a call,
+not once per 128 x 128 tile); inside a grid step a resident **tile** of up
+to 1024 rows walks the streamed block in **steps** of up to 256 rows, each
+step one large score tile for the scheduler to overlap dots, exps and
+reductions in.  Under a causal mask the walk stops at the diagonal: the
+steps before it run as one ``fori_loop`` without a mask, and of the static
+``tile // step`` steps the diagonal crosses, each takes only the rows of
+the tile it can see (a 1024-row tile in 256-row steps computes 0.625 of
+the square; whole 128 x 128 tiles would take 0.5625, a 1024 x 1024 tile
+all of it).  Grid tiles wholly above the diagonal or in tail padding are
+predicated off and their index maps hold the nearest live block, so they
+cost no copy.  ``block_q`` and ``block_k`` need not be equal.  Every
+processed row meets a valid key in the first step it takes (column 0
+under a causal mask; a real key otherwise), which keeps the running max
+finite with a -1e30 mask value: no NaN guards needed.
+
+Every dot takes its operands in the dtype they arrive in (bf16 at the
+MXU's native rate, float32 accumulation); softmax math is float32.  The
+forward and dK/dV build the score tile transposed ([keys, queries]), so
+the row statistics are lane-dense rows that reduce and broadcast along
+sublanes, and cross HBM as one float32 a row ([BH, 1, S]).
 
 Off-TPU (CPU tests) the public wrapper falls back to an identical-math
 dense implementation; the kernels are unit-tested in interpret mode and
@@ -26,7 +48,8 @@ validated on hardware by chip_smoke.py's ``kernels`` phase.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -35,13 +58,97 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# Per-row statistics (lse, delta) cross the pallas_call boundary broadcast
-# across a trailing 128-lane dimension: the TPU lowering requires the last
-# two block dims to be (sublane-multiple, lane-multiple-or-whole), so a
-# [rows] vector must ride as [rows, 128] (the same layout the reference
-# jax TPU kernel uses for its l/m outputs, MIN_BLOCK_SIZE lanes).  Inside
-# kernels the [:, :1] column is the value; wrappers squeeze lane 0.
-LANES = 128
+LANES = 128          # a vreg's lane count and the MXU's width
+# The largest resident tile and the largest step of the kernels' inner walk
+# (see tile_plan).  Read on a v5e at 128 x 1024 x 64 in bf16 (PERF.md, PR 26).
+_MAX_TILE = 1024
+_MAX_STEP = 256
+# What tile_plan lets the hungriest kernel (dkv) hold in VMEM, and what the
+# pallas_calls ask Mosaic for: the rest is headroom for the compiler's own
+# temporaries (spilled score tiles, relayouts).  A v5e / v6e core has
+# 128 MiB of VMEM, a v7x core 64 MiB.
+_VMEM_BUDGET = 16 * 1024 * 1024
+_VMEM_LIMIT = 2 * _VMEM_BUDGET
+
+_NT = (((1,), (1,)), ((), ()))      # A @ B^T: contract the minor dims
+_TN = (((0,), (0,)), ((), ()))      # A^T @ B: contract the major dims
+
+
+class TilePlan(NamedTuple):
+    """The schedule of one flash_attention call (see :func:`tile_plan`)."""
+    seq_pad: int       # the sequence length the kernels see
+    block_q: int       # grid block of queries: resident in fwd / dq
+    block_k: int       # grid block of keys: resident in dkv
+    tile_q: int        # resident tile of a query block (fwd / dq)
+    tile_k: int        # resident tile of a key block (dkv)
+    step_q: int        # dkv walks a query block in steps of this many rows
+    step_k: int        # fwd / dq walk a key block in steps of this many rows
+    vmem_bytes: int    # estimate for the hungriest kernel (dkv)
+
+    def grid_steps(self, batch_heads: int) -> int:
+        return (batch_heads * (self.seq_pad // self.block_q)
+                * (self.seq_pad // self.block_k))
+
+
+def _vmem_estimate(block_q, block_k, tile, step, head_dim, itemsize):
+    """VMEM bytes of the dkv kernel, the hungriest of the three: q, dO, k, v
+    blocks and the dk, dv outputs double-buffered by the pipeline (the minor
+    dim padded to the lane count), the two row statistics (8 sublanes each),
+    the two float32 accumulators, and one step's float32 score, p, dp and
+    ds tiles with their casts."""
+    lanes = -(-head_dim // LANES) * LANES
+    streamed = 2 * 2 * block_q * (lanes * itemsize + 8 * 4)   # q, dO, stats
+    resident = 2 * 4 * block_k * lanes * itemsize             # k, v, dk, dv
+    accumulators = 2 * block_k * lanes * 4
+    one_step = tile * step * (4 * 4 + 2 * itemsize)
+    return streamed + resident + accumulators + one_step
+
+
+def _divisor(n: int, cap: int) -> int:
+    """The largest divisor of ``n`` under ``cap``, in whole LANES if ``n``
+    is (what the TPU's tiling wants; an explicit small block is not)."""
+    unit = LANES if n % LANES == 0 and cap >= LANES else 1
+    return next(d for d in range(min(n, cap) // unit * unit, 0, -unit)
+                if n % d == 0)
+
+
+def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
+              block_q: Optional[int] = None,
+              block_k: Optional[int] = None) -> TilePlan:
+    """Choose the schedule from the shape.  Pure: shapes in, sizes out.
+
+    Three sizes nest.  A grid **block** is what a grid step holds in VMEM.
+    Without explicit blocks the sequence is padded to a multiple of LANES
+    and both blocks are the largest divisor of it (in whole LANES) whose
+    VMEM estimate fits the budget: at GPT-2-medium's 1024 x 64 in bf16 the
+    whole sequence, so a call's grid is one step a head.  An explicit
+    block is honoured (clipped to the sequence; the sequence padded to the
+    blocks' least common multiple).  A resident **tile** (at most
+    _MAX_TILE rows of the block that stays) walks the other block in
+    **steps** (at most _MAX_STEP rows); a step divides the tile it walks
+    past, so the causal diagonal crosses a tile in a whole number of
+    steps.  ``causal`` does not change the sizes: the kernels' walks stop
+    at the diagonal whatever they are.
+    """
+    del causal
+    if block_q is None and block_k is None:
+        seq_pad = -(-seq // LANES) * LANES
+        block_q = block_k = next(
+            b for b in range(seq_pad, 0, -LANES) if seq_pad % b == 0
+            and _vmem_estimate(b, b, min(b, _MAX_TILE), min(b, _MAX_STEP),
+                               head_dim, itemsize) <= _VMEM_BUDGET)
+    else:
+        block_q = min(block_q if block_q is not None else block_k, seq)
+        block_k = min(block_k if block_k is not None else block_q, seq)
+        lcm = math.lcm(block_q, block_k)
+        seq_pad = -(-seq // lcm) * lcm
+    tile_q, tile_k = _divisor(block_q, _MAX_TILE), _divisor(block_k, _MAX_TILE)
+    step_q = math.gcd(_divisor(block_q, _MAX_STEP), tile_k)
+    step_k = math.gcd(_divisor(block_k, _MAX_STEP), tile_q)
+    vmem = _vmem_estimate(block_q, block_k, max(tile_q, tile_k),
+                          max(step_q, step_k), head_dim, itemsize)
+    return TilePlan(seq_pad, block_q, block_k, tile_q, tile_k, step_q,
+                    step_k, vmem)
 
 
 def _out_struct(shape, dtype, like):
@@ -51,30 +158,123 @@ def _out_struct(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-def _block_live(iq, jk, causal: bool, block_q: int, block_k: int,
-                valid_len: int, seq_len: int):
-    """Whether the (q-block iq, k-block jk) tile can contribute: on the TPU
-    the grid is sequential and can't be shortened per-row, so dead tiles
-    (above the causal diagonal, or entirely in tail padding) are skipped by
-    predication — the dots never issue, only the pipelined DMA runs."""
-    live = jk * block_k < valid_len
+# batch*heads and the resident axis are independent; the streamed axis
+# carries the accumulators.  One TensorCore (v5e) gains nothing from it.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _last_live_k(iq, causal: bool, plan: TilePlan, valid_len: int):
+    """The last key block a query block needs: the one that holds the end
+    of the real sequence or, under a causal mask, the block's last row."""
+    last = (valid_len - 1) // plan.block_k
     if causal:
-        live = jnp.logical_and(live,
-                               (iq + 1) * block_q - 1 >= jk * block_k)
-    return live
+        last = jnp.minimum(last, ((iq + 1) * plan.block_q - 1) // plan.block_k)
+    return last
+
+
+def _block_live(iq, jk, causal: bool, plan: TilePlan, valid_len: int):
+    """Whether the (q-block iq, k-block jk) grid tile can contribute.  The
+    grid is sequential and cannot be shortened per row, so a dead tile
+    (above the causal diagonal, or wholly tail padding) is still a grid
+    step: its body is predicated off and its index maps hold the block of
+    the nearest live step, so it costs neither dots nor copies."""
+    return jnp.logical_and(jk <= _last_live_k(iq, causal, plan, valid_len),
+                           iq * plan.block_q < valid_len)
+
+
+def _steps(valid_len: int, step: int) -> int:
+    """How many steps of a sequence hold a real row."""
+    return -(-valid_len // step)
+
+
+def _diag_steps(first_step, count: int, block_first, n: int, last: int,
+                body):
+    """``body(d, j)`` for the d-th of ``count`` (static) consecutive steps
+    of the sequence from ``first_step`` on, wherever that step is local
+    step ``j`` of a grid block that holds steps ``block_first`` ..
+    ``block_first + n``, and one of the ``last`` steps with a real row."""
+    for d in range(count):
+        j = first_step + d - block_first
+        live = jnp.logical_and(jnp.logical_and(j >= 0, j < n),
+                               first_step + d < last)
+        pl.when(live)(functools.partial(body, d, j))
+
+
+def _run(body, lo, hi, state):
+    """Local steps [lo, hi) of a walk, or (``hi`` None) the one step ``lo``."""
+    return (body(lo, state) if hi is None else
+            jax.lax.fori_loop(lo, hi, body, state))
+
+
+def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len: int, visit):
+    """fwd / dq: walk the key steps of key block ``jk`` that the query tile
+    starting at row ``row0`` can see.  ``visit(off, masked, lo, hi)`` takes
+    local steps [lo, hi) (``hi`` None: the one step ``lo``) with the tile's
+    rows from ``off`` on.  Steps before the diagonal are seen whole, in one
+    run; the diagonal crosses a static ``tile_q // step_k`` steps, and the
+    d-th of them is seen by the rows from ``d * step_k`` on only, so the
+    part of a tile above the diagonal is never computed.  Without a causal
+    mask the run ends with the real keys, and a step that holds the end of
+    them is masked."""
+    step = plan.step_k
+    n = plan.block_k // step
+    first, last = jk * n, _steps(valid_len, step)
+    if causal:
+        on_diag = row0 // step
+        visit(0, False, 0, jnp.clip(jnp.minimum(on_diag, last) - first, 0, n))
+        _diag_steps(on_diag, plan.tile_q // step, first, n, last,
+                    lambda d, j: visit(d * step, True, j, None))
+    else:
+        whole = valid_len // step
+        visit(0, False, 0, jnp.clip(whole - first, 0, n))
+        _diag_steps(whole, last - whole, first, n, last,
+                    lambda d, j: visit(0, True, j, None))
+
+
+def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
+            transposed: bool = False):
+    """The float32 score tile q @ k^T * sm_scale ([Tq, Tk]; or its
+    transpose k @ q^T), masked where the diagonal or the tail padding
+    crosses it.  The dot takes its operands as they arrive."""
+    a, b = (k, q) if transposed else (q, k)
+    s = jax.lax.dot_general(a, b, _NT,
+                            preferred_element_type=jnp.float32) * sm_scale
+    if masked:
+        q_axis, k_axis = (1, 0) if transposed else (0, 1)
+        kpos = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
+        if causal:
+            # Padding lives at the tail, so kpos > any real qpos: the
+            # causal mask already excludes padded keys.
+            qpos = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+            s = jnp.where(qpos >= kpos, s, NEG_INF)
+        else:
+            s = jnp.where(kpos < valid_len, s, NEG_INF)
+    return s
+
+
+def _row_to_col(row):
+    """[1, T] -> [T, 1]: dq wants the statistics along the score's rows."""
+    t = row.shape[1]
+    return jnp.transpose(jnp.broadcast_to(row, (min(t, LANES), t)))[:, :1]
 
 
 def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, sm_scale: float, causal: bool, block_q: int, block_k: int,
+                *, sm_scale: float, causal: bool, plan: TilePlan,
                 valid_len: int):
-    """Streaming forward: grid (BH, n_q, n_kv), K/V blocks flow through
-    VMEM while acc/m/l persist in scratch across the innermost kv steps
-    (the o/lse output blocks are revisited and written on the last step)."""
-    iq = pl.program_id(1)
-    jk = pl.program_id(2)
+    """Forward: grid (BH, n_q, n_kv).  A query block stays resident while
+    key/value blocks stream past it.  The score tile is built transposed
+    ([Tk, Tq] = k @ q^T): the online-softmax statistics are then rows
+    ([1, Tq], one vreg per 1024 queries where a column takes one per 8),
+    their reductions run down the sublanes on the VPU, and the
+    accumulator is acc^T [D, Tq]; m / l / acc^T persist in scratch across
+    the kv grid steps and ride in registers inside a run of steps."""
+    iq, jk = pl.program_id(1), pl.program_id(2)
     n_kv = pl.num_programs(2)
-    seq_len = n_kv * block_k
-    padded = valid_len < seq_len
+    tile, step = plan.tile_q, plan.step_k
+    score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
+                              valid_len=valid_len, transposed=True)
 
     @pl.when(jk == 0)
     def _init():
@@ -82,128 +282,147 @@ def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    @pl.when(_block_live(iq, jk, causal, block_q, block_k, valid_len,
-                         seq_len))
+    @pl.when(_block_live(iq, jk, causal, plan, valid_len))
     def _compute():
-        # Dots run on the MXU in the input dtype (bf16 native rate, 2x the
-        # f32 path) with f32 accumulation; softmax math stays f32.  The
-        # sm_scale folds in after the QK dot so it happens in f32.
-        q = q_ref[:]                                      # [Bq, D]
-        k = k_ref[:]                                      # [Bk, D]
-        v = v_ref[:]
-        s = jax.lax.dot_general(                          # [Bq, Bk] on MXU
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal or padded:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = jk * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            if causal:
-                # Padding lives at the tail, so kpos > any real qpos —
-                # the causal mask already excludes padded keys.
-                s = jnp.where(qpos >= kpos, s, NEG_INF)
-            else:
-                s = jnp.where(kpos < valid_len, s, NEG_INF)
-        m = m_ref[:]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        def q_tile(c, _):
+            start = pl.multiple_of(c * tile, tile)
+            row0 = iq * plan.block_q + c * tile
+
+            def visit(off, masked, lo, hi):
+                rows = pl.ds(start + off, tile - off)
+                q = q_ref[rows, :]                          # [Tq, D]
+
+                def body(j, state):
+                    m, l, acc = state                # [1, Tq] x 2, [D, Tq]
+                    cols = pl.ds(pl.multiple_of(j * step, step), step)
+                    v = v_ref[cols, :]                      # [Tk, D]
+                    s = score(q, k_ref[cols, :], row0 + off,
+                              jk * plan.block_k + j * step, masked)
+                    m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+                    p = jnp.exp(s - m_new)                  # [Tk, Tq]
+                    alpha = jnp.exp(m - m_new)
+                    l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+                    acc = acc * alpha + jax.lax.dot_general(    # v^T @ p
+                        v, p.astype(v.dtype), _TN,
+                        preferred_element_type=jnp.float32)
+                    return m_new, l, acc
+
+                state = m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows]
+                m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows] = _run(
+                    body, lo, hi, state)
+
+            _k_walk(row0, jk, causal, plan, valid_len, visit)
+
+        jax.lax.fori_loop(0, plan.block_q // tile, q_tile, None)
 
     @pl.when(jk == n_kv - 1)
     def _flush():
-        l = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[:] = (acc_ref[:] / l).astype(o_ref.dtype)
-        # Log-sum-exp per query row, the residual the backward pass needs
-        # to re-materialize P = exp(S - lse) blockwise without storing
-        # [S, S].  Written lane-broadcast ([Bq, LANES]) per the TPU
-        # block-shape rule.
-        lse_ref[:] = jnp.broadcast_to(m_ref[:] + jnp.log(l),
-                                      (block_q, LANES))
+        def q_tile(c, _):
+            rows = pl.ds(pl.multiple_of(c * tile, tile), tile)
+            l = jnp.maximum(l_ref[:, rows], 1e-30)
+            o_ref[rows, :] = jnp.transpose(
+                acc_ref[:, rows] / l).astype(o_ref.dtype)
+            # Log-sum-exp per query row, the residual the backward pass
+            # needs to re-materialize P = exp(S - lse) tile by tile.
+            lse_ref[:, rows] = m_ref[:, rows] + jnp.log(l)
+
+        jax.lax.fori_loop(0, plan.block_q // tile, q_tile, None)
 
 
-def _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, block_q, block_k,
-                    interpret, valid_len):
+def _row_stat_spec(block, index_map):
+    """One float32 a row, lane-dense: a [BH, 1, S] array in (1, block) rows."""
+    return pl.BlockSpec((None, 1, block), index_map)
+
+
+def _streamed_kv_spec(d, causal: bool, plan: TilePlan, valid_len: int):
+    """Key/value blocks streaming past query block ``i`` (fwd, dq): a dead
+    grid tile holds the block of the last live one, so it costs no copy."""
+    return pl.BlockSpec(
+        (None, plan.block_k, d), lambda b, i, j: (
+            b, jnp.minimum(j, _last_live_k(i, causal, plan, valid_len)), 0))
+
+
+# Inlined jits: a model calls these once a layer with the same shapes, and
+# jit's cache then traces each kernel once a process and not once a layer
+# (the step is traced in every process; that time is part of a job's
+# start).  ``inline`` leaves no call in the jaxpr, so an op's name keeps the
+# scope of the layer that made it.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7), inline=True)
+def _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, plan, interpret,
+                    valid_len):
     """Forward kernel over [BH, S, D] (S already padded): out + row lse."""
     bh, s, d = qb.shape
-    grid = (bh, s // block_q, s // block_k)
-    kernel = functools.partial(_mha_kernel, sm_scale=sm_scale, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               valid_len=valid_len)
-    out, lse_lanes = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_q, LANES), lambda b, i, j: (b, i, 0)),
-        ],
+    bq, bk = plan.block_q, plan.block_k
+    q_spec = pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0))
+    kv_spec = _streamed_kv_spec(d, causal, plan, valid_len)
+    out, lse = pl.pallas_call(
+        functools.partial(_mha_kernel, sm_scale=sm_scale, causal=causal,
+                          plan=plan, valid_len=valid_len),
+        grid=(bh, s // bq, s // bk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, _row_stat_spec(bq, lambda b, i, j: (b, 0, i))],
         out_shape=[
             _out_struct((bh, s, d), qb.dtype, qb),
-            _out_struct((bh, s, LANES), jnp.float32, qb),
+            _out_struct((bh, 1, s), jnp.float32, qb),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((d, bq), jnp.float32),
+            pltpu.VMEM((1, bq), jnp.float32),
+            pltpu.VMEM((1, bq), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(qb, kb, vb)
-    return out, lse_lanes[:, :, 0]
+    return out, lse[:, 0]
 
 
 def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dq_ref, acc_ref, *, sm_scale: float, causal: bool,
-                       block_q: int, block_k: int, valid_len: int):
-    """dQ, streaming: grid (BH, n_q, n_kv); K/V blocks flow past a fixed
-    query block while dq accumulates in f32 scratch (the dq output block is
-    revisited and written on the last kv step).  P is re-materialized from
-    the lse residual — the [S, S] score matrix never exists."""
-    iq = pl.program_id(1)
-    jk = pl.program_id(2)
+                       plan: TilePlan, valid_len: int):
+    """dQ: grid (BH, n_q, n_kv); key/value blocks stream past a resident
+    query block while dq accumulates in f32 scratch.  P is re-materialized
+    from the lse residual: the [S, S] score matrix never exists."""
+    iq, jk = pl.program_id(1), pl.program_id(2)
     n_kv = pl.num_programs(2)
-    seq_len = n_kv * block_k
-    padded = valid_len < seq_len
+    tile, step = plan.tile_q, plan.step_k
+    score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
+                              valid_len=valid_len)
 
     @pl.when(jk == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(_block_live(iq, jk, causal, block_q, block_k, valid_len,
-                         seq_len))
+    @pl.when(_block_live(iq, jk, causal, plan, valid_len))
     def _compute():
-        q = q_ref[:]                                       # [Bq, D]
-        k = k_ref[:]                                       # [Bk, D]
-        v = v_ref[:]
-        do = do_ref[:].astype(jnp.float32)                 # [Bq, D]
-        lse = lse_ref[:][:, :1]                            # [Bq, 1] f32
-        delta = delta_ref[:][:, :1]                        # [Bq, 1] f32
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal or padded:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = jk * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            if causal:
-                s = jnp.where(qpos >= kpos, s, NEG_INF)
-            else:
-                s = jnp.where(kpos < valid_len, s, NEG_INF)
-        p = jnp.exp(s - lse)                               # [Bq, Bk]
-        dp = jax.lax.dot_general(do, v.astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale                   # [Bq, Bk]
-        acc_ref[:] = acc_ref[:] + jnp.dot(
-            ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
+        def q_tile(c, _):
+            start = pl.multiple_of(c * tile, tile)
+            row0 = iq * plan.block_q + c * tile
+
+            def visit(off, masked, lo, hi):
+                rows = pl.ds(start + off, tile - off)
+                q = q_ref[rows, :]                          # [Tq, D]
+                do = do_ref[rows, :]
+                lse = _row_to_col(lse_ref[:, rows])         # [Tq, 1]
+                delta = _row_to_col(delta_ref[:, rows])
+
+                def body(j, acc):
+                    cols = pl.ds(pl.multiple_of(j * step, step), step)
+                    k = k_ref[cols, :]                      # [Tk, D]
+                    s = score(q, k, row0 + off,
+                              jk * plan.block_k + j * step, masked)
+                    p = jnp.exp(s - lse)                    # [Tq, Tk]
+                    dp = jax.lax.dot_general(
+                        do, v_ref[cols, :], _NT,
+                        preferred_element_type=jnp.float32)
+                    ds = p * (dp - delta) * sm_scale
+                    return acc + jnp.dot(ds.astype(k.dtype), k,
+                                         preferred_element_type=jnp.float32)
+
+                acc_ref[rows, :] = _run(body, lo, hi, acc_ref[rows, :])
+
+            _k_walk(row0, jk, causal, plan, valid_len, visit)
+
+        jax.lax.fori_loop(0, plan.block_q // tile, q_tile, None)
 
     @pl.when(jk == n_kv - 1)
     def _flush():
@@ -212,52 +431,75 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale: float,
-                        causal: bool, block_q: int, block_k: int,
-                        valid_len: int):
-    """dK/dV, streaming: grid (BH, n_kv, n_q); Q/dO/stat blocks flow past a
-    fixed key block while dk/dv accumulate in f32 scratch."""
-    jk = pl.program_id(1)
-    iq = pl.program_id(2)
+                        causal: bool, plan: TilePlan, valid_len: int):
+    """dK/dV: grid (BH, n_kv, n_q); query/dO/statistic blocks stream past
+    a resident key block while dk/dv accumulate in f32 scratch.  The score
+    tile is built transposed ([Tk, Tq] = k @ q^T), so the row statistics
+    broadcast down the sublanes as they arrive and all four dots are
+    plain: no operand is transposed on the way to the MXU.
+
+    A key tile at column ``col0`` is seen whole by the query steps after
+    the diagonal, in one run up to the last real row (the rows past it
+    carry a zero dO); of the static ``tile_k // step_q`` steps the
+    diagonal crosses, the d-th sees the tile's first ``(d + 1) * step_q``
+    keys only.  Without a causal mask every step sees the whole tile, and
+    padded keys are masked in each."""
+    jk, iq = pl.program_id(1), pl.program_id(2)
     n_q = pl.num_programs(2)
-    seq_len = n_q * block_q
-    padded = valid_len < seq_len
+    tile, step = plan.tile_k, plan.step_q
+    n = plan.block_q // step
+    last = _steps(valid_len, step)
+    score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
+                              valid_len=valid_len, transposed=True)
 
     @pl.when(iq == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_block_live(iq, jk, causal, block_q, block_k, valid_len,
-                         seq_len))
+    @pl.when(_block_live(iq, jk, causal, plan, valid_len))
     def _compute():
-        q = q_ref[:]                                       # [Bq, D]
-        k = k_ref[:]                                       # [Bk, D]
-        v = v_ref[:]
-        do = do_ref[:].astype(jnp.float32)
-        lse = lse_ref[:][:, :1]
-        delta = delta_ref[:][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal or padded:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = jk * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
+        def k_tile(c, _):
+            start = pl.multiple_of(c * tile, tile)
+            col0 = jk * plan.block_k + c * tile
+
+            def visit(size, masked, lo, hi):
+                cols = pl.ds(start, size)
+                k = k_ref[cols, :]                          # [Tk, D]
+                v = v_ref[cols, :]
+
+                def body(j, state):
+                    dk, dv = state
+                    rows = pl.ds(pl.multiple_of(j * step, step), step)
+                    q = q_ref[rows, :]                      # [Tq, D]
+                    do = do_ref[rows, :]
+                    s = score(q, k, iq * plan.block_q + j * step, col0,
+                              masked)
+                    p = jnp.exp(s - lse_ref[:, rows])       # [Tk, Tq]
+                    dv = dv + jnp.dot(p.astype(do.dtype), do,
+                                      preferred_element_type=jnp.float32)
+                    dp = jax.lax.dot_general(
+                        v, do, _NT, preferred_element_type=jnp.float32)
+                    ds = p * (dp - delta_ref[:, rows]) * sm_scale
+                    dk = dk + jnp.dot(ds.astype(q.dtype), q,
+                                      preferred_element_type=jnp.float32)
+                    return dk, dv
+
+                state = dk_acc[cols, :], dv_acc[cols, :]
+                dk_acc[cols, :], dv_acc[cols, :] = _run(body, lo, hi, state)
+
+            first = iq * n
+            hi = jnp.clip(last - first, 0, n)
             if causal:
-                s = jnp.where(qpos >= kpos, s, NEG_INF)
+                on_diag, count = col0 // step, tile // step
+                _diag_steps(on_diag, count, first, n, last,
+                            lambda d, j: visit((d + 1) * step, True, j, None))
+                visit(tile, False, jnp.clip(on_diag + count - first, 0, hi),
+                      hi)
             else:
-                s = jnp.where(kpos < valid_len, s, NEG_INF)
-        p = jnp.exp(s - lse)                               # [Bq, Bk]
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(       # P^T @ dO
-            p.astype(do_ref.dtype), do.astype(do_ref.dtype),
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v.astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(       # dS^T @ Q
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+                visit(tile, valid_len < plan.seq_pad, 0, hi)
+
+        jax.lax.fori_loop(0, plan.block_k // tile, k_tile, None)
 
     @pl.when(iq == n_q - 1)
     def _flush():
@@ -265,57 +507,64 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd_bhsd(qb, kb, vb, ob, lse, dob, sm_scale, causal, block_q,
-                    block_k, interpret, valid_len, dlse=None):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10), inline=True)
+def _flash_bwd_bhsd(qb, kb, vb, ob, lse, dob, sm_scale, causal, plan,
+                    interpret, valid_len, dlse=None):
     bh, s, d = qb.shape
+    bq, bk = plan.block_q, plan.block_k
     # delta_i = rowsum(dO_i * O_i) — the standard backward residual.  An
     # lse cotangent (pair-valued VJP) folds in as delta - dlse.
     delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
                     axis=-1)                               # [BH, S]
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
-    # Per-row stats enter the kernels lane-broadcast (see LANES).
-    lse_l = jnp.broadcast_to(lse.astype(jnp.float32)[..., None],
-                             (bh, s, LANES))
-    delta_l = jnp.broadcast_to(delta[..., None], (bh, s, LANES))
-    common = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-                  block_k=block_k, valid_len=valid_len)
+    # The row statistics cross HBM one value a row, lane-dense.
+    lse = lse.astype(jnp.float32)[:, None]                 # [BH, 1, S]
+    delta = delta[:, None]
+    kernel_args = dict(sm_scale=sm_scale, causal=causal, plan=plan,
+                       valid_len=valid_len)
+    call_args = dict(compiler_params=_COMPILER_PARAMS, interpret=interpret)
+
     # dq: q-block fixed per outer step, k/v stream on the inner grid dim.
-    q_by_i = pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0))
-    kv_by_j = pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0))
-    row_by_i = pl.BlockSpec((None, block_q, LANES), lambda b, i, j: (b, i, 0))
+    q_by_i = pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0))
+    kv_by_j = _streamed_kv_spec(d, causal, plan, valid_len)
+    row_by_i = _row_stat_spec(bq, lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
-        functools.partial(_mha_bwd_dq_kernel, **common),
-        grid=(bh, s // block_q, s // block_k),
+        functools.partial(_mha_bwd_dq_kernel, **kernel_args),
+        grid=(bh, s // bq, s // bk),
         in_specs=[q_by_i, kv_by_j, kv_by_j, q_by_i, row_by_i, row_by_i],
         out_specs=q_by_i,
         out_shape=_out_struct((bh, s, d), qb.dtype, qb),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(qb, kb, vb, dob, lse_l, delta_l)
-    # dk/dv: k-block fixed per outer step, q/do/stats stream inside.
-    q_by_j = pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, j, 0))
-    kv_by_i = pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, i, 0))
-    row_by_j = pl.BlockSpec((None, block_q, LANES), lambda b, i, j: (b, j, 0))
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        **call_args,
+    )(qb, kb, vb, dob, lse, delta)
+
+    # dk/dv: k-block fixed per outer step, q/do/stats stream inside, from
+    # the first query block that sees it to the last real one.
+    def q_index(i, j):
+        first = (i * bk) // bq if causal else 0
+        return jnp.minimum(jnp.maximum(j, first), (valid_len - 1) // bq)
+
+    q_by_j = pl.BlockSpec((None, bq, d), lambda b, i, j: (b, q_index(i, j), 0))
+    kv_by_i = pl.BlockSpec((None, bk, d), lambda b, i, j: (b, i, 0))
+    row_by_j = _row_stat_spec(bq, lambda b, i, j: (b, 0, q_index(i, j)))
     dk, dv = pl.pallas_call(
-        functools.partial(_mha_bwd_dkv_kernel, **common),
-        grid=(bh, s // block_k, s // block_q),
+        functools.partial(_mha_bwd_dkv_kernel, **kernel_args),
+        grid=(bh, s // bk, s // bq),
         in_specs=[q_by_j, kv_by_i, kv_by_i, q_by_j, row_by_j, row_by_j],
         out_specs=[kv_by_i, kv_by_i],
         out_shape=[_out_struct((bh, s, d), kb.dtype, kb),
                    _out_struct((bh, s, d), vb.dtype, vb)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
-    )(qb, kb, vb, dob, lse_l, delta_l)
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        **call_args,
+    )(qb, kb, vb, dob, lse, delta)
     return dq, dk, dv
 
 
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_bhsd_lse(qb, kb, vb, sm_scale, causal, block_q, block_k,
-                    interpret, valid_len):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bhsd_lse(qb, kb, vb, sm_scale, causal, plan, interpret,
+                    valid_len):
     """Differentiable kernel entry over [BH, S, D] (S already padded),
     returning ``(out, lse)`` — the pair ring attention merges across hops
     (the public ``flash_attention`` wrapper simply discards the lse).
@@ -325,25 +574,23 @@ def _flash_bhsd_lse(qb, kb, vb, sm_scale, causal, block_q, block_k,
     existing kernels as ``delta_i -> delta_i - dlse_i`` (both enter as
     ``ds = p * (dp - delta)``) — no separate kernels needed.
     """
-    return _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, block_q, block_k,
-                           interpret, valid_len)
+    return _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, plan, interpret,
+                           valid_len)
 
 
-def _flash_bhsd_lse_fwd(qb, kb, vb, sm_scale, causal, block_q, block_k,
-                        interpret, valid_len):
-    out, lse = _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, block_q,
-                               block_k, interpret, valid_len)
+def _flash_bhsd_lse_fwd(qb, kb, vb, sm_scale, causal, plan, interpret,
+                        valid_len):
+    out, lse = _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, plan,
+                               interpret, valid_len)
     return (out, lse), (qb, kb, vb, out, lse)
 
 
-def _flash_bhsd_lse_bwd(sm_scale, causal, block_q, block_k, interpret,
-                        valid_len, res, cotangents):
+def _flash_bhsd_lse_bwd(sm_scale, causal, plan, interpret, valid_len, res,
+                        cotangents):
     qb, kb, vb, ob, lse = res
     dob, dlse = cotangents
-    dq, dk, dv = _flash_bwd_bhsd(qb, kb, vb, ob, lse, dob, sm_scale, causal,
-                                 block_q, block_k, interpret, valid_len,
-                                 dlse=dlse)
-    return dq, dk, dv
+    return _flash_bwd_bhsd(qb, kb, vb, ob, lse, dob, sm_scale, causal, plan,
+                           interpret, valid_len, dlse=dlse)
 
 
 _flash_bhsd_lse.defvjp(_flash_bhsd_lse_fwd, _flash_bhsd_lse_bwd)
@@ -375,7 +622,8 @@ def dense_attention_with_lse(q, k, v, causal: bool = False,
 
 def flash_attention_with_lse(q, k, v, causal: bool = False,
                              scale: Optional[float] = None,
-                             block_q: int = 128, block_k: int = 128,
+                             block_q: Optional[int] = None,
+                             block_k: Optional[int] = None,
                              interpret: Optional[bool] = None):
     """Pallas attention over [B, S, H, D] returning ``(out, lse)`` with
     lse shaped [B, H, S].  Same dispatch rules as :func:`flash_attention`;
@@ -386,14 +634,8 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
             return dense_attention_with_lse(q, k, v, causal, scale)
         interpret = False
     sm_scale = d ** -0.5 if scale is None else scale
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    if causal and block_q != block_k:
-        block_q = block_k = min(block_q, block_k)
-    import math
-
-    block = math.lcm(block_q, block_k)
-    s_pad = -(-s // block) * block
+    plan = tile_plan(s, d, q.dtype.itemsize, causal, block_q, block_k)
+    s_pad = plan.seq_pad
     if s_pad != s:
         pad = [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]
         q = jnp.pad(q, pad)
@@ -404,7 +646,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
         return x.transpose(0, 2, 1, 3).reshape(b * h, s_pad, d)
 
     out, lse = _flash_bhsd_lse(to_bhsd(q), to_bhsd(k), to_bhsd(v), sm_scale,
-                               causal, block_q, block_k, bool(interpret), s)
+                               causal, plan, bool(interpret), s)
     out = out.reshape(b, h, s_pad, d).transpose(0, 2, 1, 3)[:, :s]
     lse = lse.reshape(b, h, s_pad)[:, :, :s]
     return out, lse
@@ -412,7 +654,8 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
 
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """Attention over [batch, seq, heads, head_dim].
 

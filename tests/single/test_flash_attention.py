@@ -1,31 +1,74 @@
 """Pallas flash-attention kernel vs dense attention (interpret mode on CPU)
 and the GPT model family."""
 
+import functools
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops.flash_attention import (
-    dense_attention, flash_attention)
+    dense_attention, dense_attention_with_lse, flash_attention,
+    flash_attention_with_lse, tile_plan)
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """The schedule of a long sequence at a toy size: resident tiles of 32
+    rows walked in steps of 8, so a 64-long block holds two tiles and the
+    diagonal crosses each in four steps."""
+    monkeypatch.setattr(fa, "_MAX_TILE", 32)
+    monkeypatch.setattr(fa, "_MAX_STEP", 8)
+
+
+def _qkv(shape, dtype=jnp.float32, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return tuple(jax.random.normal(k, shape, dtype) for k in ks)
+
+
+# (shape, block_q, block_k): explicit 16-wide blocks; one block of several
+# tiles and steps (with small_tiles); blocks that differ; a tail-padded
+# length whose last block is partly dead; the plan's own default.
+SCHEDULES = {
+    "16x16": ((1, 32, 2, 16), 16, 16),
+    "16x16-b2h4": ((2, 64, 4, 32), 16, 16),
+    "one-block": ((1, 128, 2, 16), 128, 128),
+    "two-blocks": ((1, 128, 2, 16), 64, 64),
+    "bq>bk": ((1, 128, 2, 16), 64, 32),
+    "bq<bk": ((1, 128, 2, 16), 32, 64),
+    "padded-tail": ((1, 100, 2, 16), 64, 64),
+    "padded-bq>bk": ((1, 75, 2, 8), 64, 16),
+    "default-plan": ((1, 200, 2, 16), None, None),
+}
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("shape", [(1, 32, 2, 16), (2, 64, 4, 32)])
-def test_flash_kernel_matches_dense(causal, shape):
-    b, s, h, d = shape
-    key = jax.random.PRNGKey(0)
-    kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, shape, jnp.float32)
-    k = jax.random.normal(kk, shape, jnp.float32)
-    v = jax.random.normal(kv, shape, jnp.float32)
-
-    expected = dense_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16,
-                          interpret=True)
+@pytest.mark.parametrize("schedule", SCHEDULES.values(), ids=SCHEDULES.keys())
+def test_flash_kernel_matches_dense(causal, schedule, small_tiles):
+    shape, block_q, block_k = schedule
+    q, k, v = _qkv(shape)
+    expected, expected_lse = dense_attention_with_lse(q, k, v, causal=causal)
+    out, lse = flash_attention_with_lse(q, k, v, causal=causal,
+                                        block_q=block_q, block_k=block_k,
+                                        interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(expected_lse),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_default_plan_at_gpt2_medium(causal):
+    """The schedule the benchmark's GPT cells run (1024 x 64: one grid step
+    a head, a 1024-row tile walked in 256-row steps), B*H = 2."""
+    q, k, v = _qkv((1, 1024, 2, 64), seed=5)
+    out = flash_attention(q, k, v, causal=causal, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(dense_attention(q, k, v, causal=causal)),
+        rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -114,27 +157,46 @@ def test_gpt_sequence_parallel_matches_dense():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("shape", [(1, 32, 2, 16), (2, 64, 4, 32)])
-def test_flash_kernel_grads_match_dense(causal, shape):
+@pytest.mark.parametrize("schedule", SCHEDULES.values(), ids=SCHEDULES.keys())
+def test_flash_kernel_grads_match_dense(causal, schedule, small_tiles):
     """The custom-VJP backward kernels (dQ, dK/dV) against autodiff through
-    the dense reference."""
+    the dense reference, for the (out, lse) pair with a cotangent on each
+    (the lse cotangent folds into delta; ring attention needs it)."""
+    shape, block_q, block_k = schedule
     b, s, h, d = shape
-    key = jax.random.PRNGKey(3)
-    kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, shape, jnp.float32)
-    k = jax.random.normal(kk, shape, jnp.float32)
-    v = jax.random.normal(kv, shape, jnp.float32)
+    q, k, v = _qkv(shape, seed=3)
+    w_lse = jax.random.normal(jax.random.PRNGKey(4), (b, h, s), jnp.float32)
 
-    def loss_flash(q, k, v):
-        out = flash_attention(q, k, v, causal=causal, block_q=16,
-                              block_k=16, interpret=True)
-        return jnp.sum(jnp.sin(out))  # non-trivial cotangent
+    def loss(fn, q, k, v):
+        out, lse = fn(q, k, v)
+        return jnp.sum(jnp.sin(out)) + jnp.sum(lse * w_lse)
 
-    def loss_dense(q, k, v):
-        return jnp.sum(jnp.sin(dense_attention(q, k, v, causal=causal)))
+    def flash(q, k, v):
+        return flash_attention_with_lse(q, k, v, causal=causal,
+                                        block_q=block_q, block_k=block_k,
+                                        interpret=True)
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    def dense(q, k, v):
+        return dense_attention_with_lse(q, k, v, causal=causal)
+
+    gf = jax.grad(functools.partial(loss, flash), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(functools.partial(loss, dense), argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_flash_default_plan_grads_at_gpt2_medium():
+    q, k, v = _qkv((1, 1024, 2, 64), seed=6)
+
+    def loss(fn, q, k, v):
+        return jnp.sum(jnp.sin(fn(q, k, v, causal=True)))
+
+    gf = jax.grad(functools.partial(
+        loss, functools.partial(flash_attention, interpret=True)),
+        argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(functools.partial(loss, dense_attention),
+                  argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=2e-4)
@@ -190,3 +252,80 @@ def test_flash_kernel_grads_bf16():
         np.testing.assert_allclose(np.asarray(a, dtype=np.float32),
                                    np.asarray(b_, dtype=np.float32),
                                    rtol=0.1, atol=0.05)
+
+
+def _dot_operand_dtypes(jaxpr, found):
+    """Every dot_general of a jaxpr and of the jaxprs inside it (the Pallas
+    kernel's body, its loops and branches): the dtypes of its operands."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(tuple(v.aval.dtype for v in eqn.invars))
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _dot_operand_dtypes(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_dots_take_operands_as_they_arrive(dtype):
+    """bf16 inputs meet the MXU as bf16 in all three kernels (float32
+    accumulation): no convert_element_type to float32 feeds a dot."""
+    dtype = jnp.dtype(dtype)
+    q, k, v = _qkv((1, 64, 2, 16), dtype)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, block_q=32,
+                                       block_k=32, interpret=True)
+                       .astype(jnp.float32))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    dots = _dot_operand_dtypes(jaxpr.jaxpr, [])
+    assert len(dots) >= 2 + 3 + 4            # fwd, dq, dkv
+    assert all(a == b == dtype for a, b in dots), dots
+
+
+PLAN_SHAPES = {"gpt2-medium": (1024, 64), "bench": (2048, 128),
+               "five-tiles": (640, 64), "tiny": (32, 16),
+               "long": (8192, 128)}
+
+
+@pytest.mark.parametrize("seq,head_dim", PLAN_SHAPES.values(),
+                         ids=PLAN_SHAPES.keys())
+@pytest.mark.parametrize("itemsize", [2, 4])
+def test_tile_plan(seq, head_dim, itemsize):
+    """The schedule is decided at trace time from the shape alone: this is
+    the record of where the large blocks engage."""
+    plan = tile_plan(seq, head_dim, itemsize, True)
+    padded = -(-seq // 128) * 128
+    assert plan.seq_pad == padded                    # 640 stays 640
+    assert padded % plan.block_q == 0 and plan.block_q == plan.block_k
+    assert plan.block_q % plan.tile_q == 0 and plan.tile_q % plan.step_k == 0
+    assert plan.block_k % plan.tile_k == 0 and plan.tile_k % plan.step_q == 0
+    assert plan.step_q % 128 == 0 and plan.step_k % 128 == 0
+    assert plan.vmem_bytes <= fa._VMEM_BUDGET < fa._VMEM_LIMIT
+    assert plan == tile_plan(seq, head_dim, itemsize, False)
+    if seq * head_dim * itemsize <= 2048 * 128 * 2:
+        assert plan.block_q == padded                # one grid step a head
+    else:
+        assert plan.grid_steps(1) > 1                # streams, and fits
+    if (seq, head_dim, itemsize) == (1024, 64, 2):
+        # 8 x 16 head-sequences: 128 grid steps a call where 128 x 128
+        # blocks took 8,192.
+        assert plan.grid_steps(128) <= 512
+        assert (plan.tile_q, plan.step_k) == (1024, 256)
+
+
+def test_tile_plan_explicit_block_wins():
+    plan = tile_plan(1024, 64, 2, True, block_q=256, block_k=128)
+    assert (plan.block_q, plan.block_k, plan.seq_pad) == (256, 128, 1024)
+    assert plan.grid_steps(1) == 4 * 8
+    plan = tile_plan(23, 8, 4, True, block_q=16, block_k=16)
+    assert (plan.block_q, plan.tile_q, plan.step_k, plan.seq_pad) == (
+        16, 16, 16, 32)
+    # blocks that differ, and do not nest: the steps shrink until they do
+    plan = tile_plan(96, 8, 4, True, block_q=24, block_k=32)
+    assert (plan.block_q, plan.block_k, plan.seq_pad) == (24, 32, 96)
+    assert plan.tile_q % plan.step_k == 0 and plan.tile_k % plan.step_q == 0

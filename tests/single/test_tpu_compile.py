@@ -26,9 +26,11 @@ from horovod_tpu.ops.flash_attention import (
     flash_attention, flash_attention_with_lse)
 from horovod_tpu.parallel.ring_attention import ring_attention
 
-# [batch, seq, heads, head_dim]: the long-context shape bench.py times, and
-# GPT-2-small's attention at the batch chip_smoke.py trains.
-SHAPES = {"4x2048x8x128": (4, 2048, 8, 128), "8x1024x12x64": (8, 1024, 12, 64)}
+# [batch, seq, heads, head_dim]: the long-context shape bench.py times,
+# GPT-2-small's attention at the batch chip_smoke.py trains, and
+# GPT-2-medium's at the benchmark's GPT cells' batch (the default plan there).
+SHAPES = {"4x2048x8x128": (4, 2048, 8, 128), "8x1024x12x64": (8, 1024, 12, 64),
+          "8x1024x16x64": (8, 1024, 16, 64)}
 CODEC_ELEMS = 1 << 22
 
 

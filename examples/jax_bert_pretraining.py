@@ -8,6 +8,14 @@ and decompresses after — halving ICI bytes the way the reference's fp16
 compression halves NCCL bytes.  Optionally shards long sequences over an
 ``sp`` axis with ring attention (--seq-parallel).
 
+The objective is the published one: the masked positions (15 % of a
+sequence) are gathered before the head, the decoder is tied to the word
+embeddings, and the next-sentence loss is added; every tenth sequence is
+padded to half its length and the padding is masked through the flash
+kernels' per-sequence key length.  With --seq-parallel each shard holds a
+slice of every sequence, so a gather of positions is not local: that path
+keeps the every-position masked-LM call and no padding.
+
 Run:  python examples/jax_bert_pretraining.py [--large] [--seq-parallel]
 """
 
@@ -71,35 +79,54 @@ def main():
     ids = jnp.ones((batch, S), jnp.int32)
     labels = jnp.zeros((batch, S), jnp.int32)
     weights = jnp.ones((batch, S), jnp.float32)
+    # The published data shape: 15 % of a sequence masked, a padding mask.
+    n_masked = max(1, round(0.15 * S))
+    lengths = jnp.where(jnp.arange(batch) % 10 == 9, S // 2, S).astype(
+        jnp.int32)
+    positions = jnp.arange(n_masked, dtype=jnp.int32)[None, :] % lengths[:, None]
+    mlm_labels = jnp.zeros((batch, n_masked), jnp.int32)
+    mlm_weights = jnp.ones((batch, n_masked), jnp.float32)
+    nsp_labels = jnp.zeros((batch,), jnp.int32)
 
     cfg_dense = dataclasses.replace(cfg, sp_axis_name=None)
     params = jax.jit(lambda: models.BertForPreTraining(cfg_dense).init(
-        jax.random.PRNGKey(0), ids[:1, :16])["params"])()
+        jax.random.PRNGKey(0), ids[:1, :16], ids[:1, :16])["params"])()
 
     tx = hvd.DistributedOptimizer(
         optax.adamw(1e-4), compression=hvd.Compression.fp16, axis_name=axes)
     opt_state = tx.init(params)
 
-    def train_step(params, opt_state, ids, labels, weights):
+    def train_step(params, opt_state, *batch):
         def loss_fn(p):
-            logits = model.apply({"params": p}, ids)
-            return models.mlm_loss(logits, labels, weights)
+            if sp_axis is not None:
+                ids, labels, weights = batch
+                logits = model.apply({"params": p}, ids, jnp.zeros_like(ids))
+                return models.mlm_loss(logits, labels, weights)
+            ids, lengths, positions, mlm_labels, mlm_weights, nsp_labels = \
+                batch
+            mlm, nsp = model.apply({"params": p}, ids, jnp.zeros_like(ids),
+                                   lengths=lengths,
+                                   masked_positions=positions)
+            return models.pretraining_loss(mlm, nsp, mlm_labels, mlm_weights,
+                                           nsp_labels)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
         updates, opt_state = tx.update(grads, opt_state, params)
         return (optax.apply_updates(params, updates), opt_state,
                 hvd.allreduce(loss, axis_name=axes))
 
+    data = ((ids, labels, weights) if sp_axis is not None else
+            (ids, lengths, positions, mlm_labels, mlm_weights, nsp_labels))
     step = jax.jit(shard_map(
         train_step, mesh=mesh,
-        in_specs=(P(), P(), data_spec, data_spec, data_spec),
+        in_specs=(P(), P(), *(data_spec for _ in data)),
         out_specs=(P(), P(), P())), donate_argnums=(0, 1))
 
-    params, opt_state, loss = step(params, opt_state, ids, labels, weights)
+    params, opt_state, loss = step(params, opt_state, *data)
     jax.block_until_ready(loss)
     t0 = time.perf_counter()
     for _ in range(args.steps):
-        params, opt_state, loss = step(params, opt_state, ids, labels, weights)
+        params, opt_state, loss = step(params, opt_state, *data)
     float(loss)  # host readback bounds the donated-state chain
     dt = time.perf_counter() - t0
     if hvd.rank() == 0:

@@ -6,7 +6,8 @@ from .resnet import (  # noqa: F401
     ResNet, ResNet18, ResNet34, ResNet50, ResNet101, ResNet152, ResNetTiny,
 )
 from .bert import (  # noqa: F401
-    BertConfig, BertEncoder, BertForPreTraining, mlm_loss,
+    BertConfig, BertEncoder, BertForPreTraining, mlm_loss, nsp_loss,
+    pretraining_loss,
     BERT_BASE, BERT_LARGE, BERT_TINY,
 )
 from .gpt import (  # noqa: F401

@@ -8,6 +8,17 @@ framework, shaped for the MXU:
 - all projections are single fused matmuls over [hidden, 3*hidden]-style
   shapes (multiples of 128);
 - bfloat16 activations, fp32 params, fp32 softmax accumulation;
+- attention runs through the Pallas flash kernels (``use_flash``, on by
+  default as in ``GPTConfig``; dense off-TPU) when padding is given as
+  ``lengths``, one key length per sequence (BERT's tail padding), or not at
+  all; an ``attention_mask`` alone may have any shape of holes and takes
+  the dense path, as it always did;
+- the pretraining head is the published one (Devlin et al., arXiv:1810.04805
+  and ``run_pretraining.py``): the masked positions are gathered *before*
+  the transform, the decoder is the word-embedding matrix transposed plus a
+  bias, and a ``tanh`` pooler over position 0 feeds a 2-way next-sentence
+  classifier.  The vocabulary is padded to a multiple of 128 rows; the
+  padded logits are masked out of the softmax;
 - attention can run sequence-parallel over a mesh axis via
   ``horovod_tpu.parallel.ring_attention`` (pass ``sp_axis_name``) — the
   long-context path the reference lacks (SURVEY.md §5 "long-context").
@@ -36,6 +47,13 @@ class BertConfig:
     dtype: Any = jnp.bfloat16
     sp_axis_name: Optional[str] = None  # sequence-parallel mesh axis
     sp_use_flash: bool = False          # flash kernel per ring hop
+    use_flash: bool = True              # Pallas kernel on TPU
+
+    @property
+    def padded_vocab_size(self) -> int:
+        """Rows of the word-embedding matrix (= width of the logits): the
+        vocabulary rounded up to whole 128-lane tiles."""
+        return -(-self.vocab_size // 128) * 128
 
 
 BERT_BASE = BertConfig(hidden_size=768, num_layers=12, num_heads=12,
@@ -50,7 +68,8 @@ class SelfAttention(nn.Module):
     config: BertConfig
 
     @nn.compact
-    def __call__(self, x, mask=None, deterministic: bool = True):
+    def __call__(self, x, mask=None, deterministic: bool = True,
+                 lengths=None):
         cfg = self.config
         head_dim = cfg.hidden_size // cfg.num_heads
         # One fused QKV projection: [B, S, H] @ [H, 3H] keeps the MXU at a
@@ -64,7 +83,15 @@ class SelfAttention(nn.Module):
             ctx = ring_attention(q, k, v, axis_name=cfg.sp_axis_name,
                                  causal=False,
                                  use_flash=cfg.sp_use_flash)
+        elif cfg.use_flash and (lengths is not None or mask is None):
+            from ..ops.flash_attention import flash_attention
+
+            # The kernels take tail padding only, as a length per sequence;
+            # a mask alone goes to the dense path, which honours any mask.
+            ctx = flash_attention(q, k, v, causal=False, kv_lens=lengths)
         else:
+            if mask is None and lengths is not None:
+                mask = jnp.arange(x.shape[1])[None, :] < lengths[:, None]
             scale = head_dim ** -0.5
             # fp32 logits/softmax regardless of activation dtype.
             logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
@@ -83,9 +110,11 @@ class TransformerLayer(nn.Module):
     config: BertConfig
 
     @nn.compact
-    def __call__(self, x, mask=None, deterministic: bool = True):
+    def __call__(self, x, mask=None, deterministic: bool = True,
+                 lengths=None):
         cfg = self.config
-        attn = SelfAttention(cfg, name="attention")(x, mask, deterministic)
+        attn = SelfAttention(cfg, name="attention")(x, mask, deterministic,
+                                                    lengths)
         attn = nn.Dropout(cfg.dropout_rate)(attn, deterministic=deterministic)
         # Post-LN like original BERT; LN in fp32 for stability.
         x = nn.LayerNorm(dtype=jnp.float32, name="ln_attn")(
@@ -104,10 +133,17 @@ class BertEncoder(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, token_type_ids=None, attention_mask=None,
-                 deterministic: bool = True):
+                 deterministic: bool = True, lengths=None):
+        """``lengths`` (int32 [B]: the real tokens of a sequence are its
+        first ``lengths[b]``) or ``attention_mask`` (bool [B, S], any
+        pattern) keep padded keys out of every softmax.  The flash kernels
+        take ``lengths`` only (rows beyond a length come out zero); with a
+        mask and no ``lengths`` attention is dense and the mask is honoured
+        key by key.  Where both are given they must mean the same: the
+        kernels read ``lengths`` and the dense path the mask."""
         cfg = self.config
         seq_len = input_ids.shape[-1]
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size,
+        x = nn.Embed(cfg.padded_vocab_size, cfg.hidden_size,
                      dtype=cfg.dtype, name="word_embeddings")(input_ids)
         if cfg.sp_axis_name is not None:
             # Sequence-parallel: this shard holds a contiguous chunk of the
@@ -126,30 +162,69 @@ class BertEncoder(nn.Module):
             x.astype(jnp.float32)).astype(cfg.dtype)
         for i in range(cfg.num_layers):
             x = TransformerLayer(cfg, name=f"layer_{i}")(
-                x, attention_mask, deterministic)
+                x, attention_mask, deterministic, lengths)
         return x
 
 
 class BertForPreTraining(nn.Module):
-    """Encoder + MLM head (the pretraining benchmark objective)."""
+    """Encoder + the published pretraining heads (masked LM with the tied
+    decoder, next-sentence prediction).
+
+    With ``masked_positions`` (int32 [B, P]) the call returns
+    ``(mlm_logits [B, P, V], nsp_logits [B, 2])``: the hidden states at
+    those positions are gathered before the transform, so the vocabulary
+    matmul runs over P rows a sequence and not S.  Without it the call
+    returns the masked-LM logits at every position, ``[B, S, V]``, as it
+    always did.  V is ``config.padded_vocab_size``; the logits of the
+    padding rows are -1e30.  Logits are float32."""
 
     config: BertConfig
 
-    @nn.compact
-    def __call__(self, input_ids, token_type_ids=None, attention_mask=None,
-                 deterministic: bool = True):
+    def setup(self):
+        # Attributes, not ``nn.compact``, so that ``decode`` can be applied
+        # on its own (``method="decode"``); the names are the parameters'.
         cfg = self.config
-        hidden = BertEncoder(cfg, name="encoder")(
-            input_ids, token_type_ids, attention_mask, deterministic)
-        h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="mlm_transform")(
-            hidden)
-        h = nn.gelu(h)
-        h = nn.LayerNorm(dtype=jnp.float32, name="mlm_ln")(
-            h.astype(jnp.float32))
-        # Logits in fp32: [B, S, V] matmul feeds a stable softmax-xent.
-        logits = nn.Dense(cfg.vocab_size, dtype=jnp.float32,
-                          name="mlm_head")(h)
+        self.encoder = BertEncoder(cfg)
+        self.mlm_transform = nn.Dense(cfg.hidden_size, dtype=cfg.dtype)
+        self.mlm_ln = nn.LayerNorm(dtype=jnp.float32)
+        self.mlm_bias = self.param("mlm_bias", nn.initializers.zeros,
+                                   (cfg.padded_vocab_size,), jnp.float32)
+        self.pooler = nn.Dense(cfg.hidden_size, dtype=cfg.dtype)
+        self.nsp_head = nn.Dense(2, dtype=jnp.float32)
+
+    def decode(self, h):
+        """The float32 end of the masked-LM head: layer norm of the
+        transformed hidden states ``h`` [..., H], then the decoder, which is
+        the word-embedding matrix itself (its gradient is the sum of the
+        lookup's and this matmul's) plus a bias."""
+        cfg = self.config
+        h = self.mlm_ln(h.astype(jnp.float32))
+        embedding = self.encoder.variables["params"]["word_embeddings"][
+            "embedding"]
+        logits = jnp.einsum("...h,vh->...v", h, embedding,
+                            preferred_element_type=jnp.float32) + self.mlm_bias
+        if cfg.padded_vocab_size != cfg.vocab_size:
+            real = jnp.arange(cfg.padded_vocab_size) < cfg.vocab_size
+            logits = jnp.where(real, logits, -1e30)
         return logits
+
+    def __call__(self, input_ids, token_type_ids=None, attention_mask=None,
+                 deterministic: bool = True, lengths=None,
+                 masked_positions=None):
+        hidden = self.encoder(input_ids, token_type_ids, attention_mask,
+                              deterministic, lengths)
+        with jax.named_scope("hvd_mlm_head"):
+            h = hidden
+            if masked_positions is not None:
+                h = jnp.take_along_axis(
+                    hidden, masked_positions[..., None], axis=1)
+            logits = self.decode(nn.gelu(self.mlm_transform(h)))
+        with jax.named_scope("hvd_nsp_head"):
+            pooled = jnp.tanh(self.pooler(hidden[:, 0]))
+            nsp_logits = self.nsp_head(pooled.astype(jnp.float32))
+        if masked_positions is None:
+            return logits
+        return logits, nsp_logits
 
 
 def mlm_loss(logits, labels, label_weights):
@@ -158,3 +233,21 @@ def mlm_loss(logits, labels, label_weights):
     ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     w = label_weights.astype(jnp.float32)
     return -(ll * w).sum() / jnp.maximum(w.sum(), 1.0)
+
+
+def nsp_loss(nsp_logits, nsp_labels):
+    """Next-sentence cross-entropy, mean over the batch."""
+    logp = jax.nn.log_softmax(nsp_logits, axis=-1)
+    return -jnp.mean(jnp.take_along_axis(logp, nsp_labels[:, None],
+                                         axis=-1))
+
+
+def pretraining_loss(mlm_logits, nsp_logits, mlm_labels, mlm_weights,
+                     nsp_labels):
+    """The paper's objective: the masked-LM mean over the weighted
+    positions plus the next-sentence mean."""
+    with jax.named_scope("hvd_mlm_head"):
+        masked_lm = mlm_loss(mlm_logits, mlm_labels, mlm_weights)
+    with jax.named_scope("hvd_nsp_head"):
+        next_sentence = nsp_loss(nsp_logits, nsp_labels)
+    return masked_lm + next_sentence

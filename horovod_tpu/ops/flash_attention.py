@@ -34,6 +34,13 @@ processed row meets a valid key in the first step it takes (column 0
 under a causal mask; a real key otherwise), which keeps the running max
 finite with a -1e30 mask value: no NaN guards needed.
 
+``kv_lens`` (non-causal calls: an int32 length per sequence, BERT's padding
+mask) reaches the kernels as a scalar-prefetch operand, one length per
+[batch * heads] row of the grid: the same walks then end at that
+sequence's last real key where they otherwise end at the static
+``valid_len``, and rows at or beyond the length come out zero.  Without it
+the kernels and their operands are what they were.
+
 Every dot takes its operands in the dtype they arrive in (bf16 at the
 MXU's native rate, float32 accumulation); softmax math is float32.  The
 forward and dK/dV build the score tile transposed ([keys, queries]), so
@@ -165,7 +172,14 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _last_live_k(iq, causal: bool, plan: TilePlan, valid_len: int):
+def _len_at(b, lens, valid_len):
+    """The sequence length at grid row ``b``: an index map's trailing
+    arguments are the scalar-prefetch refs, the per-row lengths if the call
+    has them, else nothing and the length is the static one."""
+    return lens[0][b] if lens else valid_len
+
+
+def _last_live_k(iq, causal: bool, plan: TilePlan, valid_len):
     """The last key block a query block needs: the one that holds the end
     of the real sequence or, under a causal mask, the block's last row."""
     last = (valid_len - 1) // plan.block_k
@@ -174,7 +188,7 @@ def _last_live_k(iq, causal: bool, plan: TilePlan, valid_len: int):
     return last
 
 
-def _block_live(iq, jk, causal: bool, plan: TilePlan, valid_len: int):
+def _block_live(iq, jk, causal: bool, plan: TilePlan, valid_len):
     """Whether the (q-block iq, k-block jk) grid tile can contribute.  The
     grid is sequential and cannot be shortened per row, so a dead tile
     (above the causal diagonal, or wholly tail padding) is still a grid
@@ -184,9 +198,9 @@ def _block_live(iq, jk, causal: bool, plan: TilePlan, valid_len: int):
                            iq * plan.block_q < valid_len)
 
 
-def _steps(valid_len: int, step: int) -> int:
+def _steps(valid_len, step: int):
     """How many steps of a sequence hold a real row."""
-    return -(-valid_len // step)
+    return (valid_len + step - 1) // step
 
 
 def _diag_steps(first_step, count: int, block_first, n: int, last: int,
@@ -208,7 +222,7 @@ def _run(body, lo, hi, state):
             jax.lax.fori_loop(lo, hi, body, state))
 
 
-def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len: int, visit):
+def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit):
     """fwd / dq: walk the key steps of key block ``jk`` that the query tile
     starting at row ``row0`` can see.  ``visit(off, masked, lo, hi)`` takes
     local steps [lo, hi) (``hi`` None: the one step ``lo``) with the tile's
@@ -217,7 +231,8 @@ def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len: int, visit):
     d-th of them is seen by the rows from ``d * step_k`` on only, so the
     part of a tile above the diagonal is never computed.  Without a causal
     mask the run ends with the real keys, and a step that holds the end of
-    them is masked."""
+    them is masked (a length read in the kernel may end inside a step or
+    not: the one masked step is then live only if it does)."""
     step = plan.step_k
     n = plan.block_k // step
     first, last = jk * n, _steps(valid_len, step)
@@ -229,7 +244,8 @@ def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len: int, visit):
     else:
         whole = valid_len // step
         visit(0, False, 0, jnp.clip(whole - first, 0, n))
-        _diag_steps(whole, last - whole, first, n, last,
+        partial = last - whole if isinstance(valid_len, int) else 1
+        _diag_steps(whole, partial, first, n, last,
                     lambda d, j: visit(0, True, j, None))
 
 
@@ -262,7 +278,7 @@ def _row_to_col(row):
 
 def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, sm_scale: float, causal: bool, plan: TilePlan,
-                valid_len: int):
+                valid_len):
     """Forward: grid (BH, n_q, n_kv).  A query block stays resident while
     key/value blocks stream past it.  The score tile is built transposed
     ([Tk, Tq] = k @ q^T): the online-softmax statistics are then rows
@@ -320,8 +336,13 @@ def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         def q_tile(c, _):
             rows = pl.ds(pl.multiple_of(c * tile, tile), tile)
             l = jnp.maximum(l_ref[:, rows], 1e-30)
-            o_ref[rows, :] = jnp.transpose(
-                acc_ref[:, rows] / l).astype(o_ref.dtype)
+            out = acc_ref[:, rows] / l
+            if not isinstance(valid_len, int):
+                # A row at or beyond its sequence's length is padding.
+                qpos = (iq * plan.block_q + c * tile
+                        + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1))
+                out = jnp.where(qpos < valid_len, out, 0.0)
+            o_ref[rows, :] = jnp.transpose(out).astype(o_ref.dtype)
             # Log-sum-exp per query row, the residual the backward pass
             # needs to re-materialize P = exp(S - lse) tile by tile.
             lse_ref[:, rows] = m_ref[:, rows] + jnp.log(l)
@@ -334,12 +355,38 @@ def _row_stat_spec(block, index_map):
     return pl.BlockSpec((None, 1, block), index_map)
 
 
-def _streamed_kv_spec(d, causal: bool, plan: TilePlan, valid_len: int):
+def _streamed_kv_spec(d, causal: bool, plan: TilePlan, valid_len):
     """Key/value blocks streaming past query block ``i`` (fwd, dq): a dead
     grid tile holds the block of the last live one, so it costs no copy."""
     return pl.BlockSpec(
-        (None, plan.block_k, d), lambda b, i, j: (
-            b, jnp.minimum(j, _last_live_k(i, causal, plan, valid_len)), 0))
+        (None, plan.block_k, d), lambda b, i, j, *lens: (
+            b, jnp.minimum(j, _last_live_k(
+                i, causal, plan, _len_at(b, lens, valid_len))), 0))
+
+
+def _kernel_call(kernel, lens, *, grid, in_specs, out_specs, out_shape,
+                 scratch_shapes, interpret, **kernel_args):
+    """The ``pallas_call`` of one of the three kernels.  ``lens`` None: the
+    kernel's ``valid_len`` is the static one in ``kernel_args``.  Else
+    ``lens`` (int32 [BH]) is a scalar-prefetch operand and each grid row
+    takes its own length from it."""
+    call_args = dict(out_shape=out_shape, compiler_params=_COMPILER_PARAMS,
+                     interpret=interpret)
+    if lens is None:
+        return pl.pallas_call(
+            functools.partial(kernel, **kernel_args), grid=grid,
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes, **call_args)
+
+    def per_sequence(lens_ref, *refs):
+        kernel(*refs, **{**kernel_args,
+                         "valid_len": lens_ref[pl.program_id(0)]})
+
+    return functools.partial(pl.pallas_call(
+        per_sequence, grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes),
+        **call_args), lens)
 
 
 # Inlined jits: a model calls these once a layer with the same shapes, and
@@ -349,18 +396,19 @@ def _streamed_kv_spec(d, causal: bool, plan: TilePlan, valid_len: int):
 # scope of the layer that made it.
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7), inline=True)
 def _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, plan, interpret,
-                    valid_len):
+                    valid_len, lens=None):
     """Forward kernel over [BH, S, D] (S already padded): out + row lse."""
     bh, s, d = qb.shape
     bq, bk = plan.block_q, plan.block_k
-    q_spec = pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0))
+    q_spec = pl.BlockSpec((None, bq, d), lambda b, i, j, *lens: (b, i, 0))
     kv_spec = _streamed_kv_spec(d, causal, plan, valid_len)
-    out, lse = pl.pallas_call(
-        functools.partial(_mha_kernel, sm_scale=sm_scale, causal=causal,
-                          plan=plan, valid_len=valid_len),
+    out, lse = _kernel_call(
+        _mha_kernel, lens, sm_scale=sm_scale, causal=causal, plan=plan,
+        valid_len=valid_len, interpret=interpret,
         grid=(bh, s // bq, s // bk),
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, _row_stat_spec(bq, lambda b, i, j: (b, 0, i))],
+        out_specs=[q_spec,
+                   _row_stat_spec(bq, lambda b, i, j, *lens: (b, 0, i))],
         out_shape=[
             _out_struct((bh, s, d), qb.dtype, qb),
             _out_struct((bh, 1, s), jnp.float32, qb),
@@ -370,15 +418,13 @@ def _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, plan, interpret,
             pltpu.VMEM((1, bq), jnp.float32),
             pltpu.VMEM((1, bq), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
     )(qb, kb, vb)
     return out, lse[:, 0]
 
 
 def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dq_ref, acc_ref, *, sm_scale: float, causal: bool,
-                       plan: TilePlan, valid_len: int):
+                       plan: TilePlan, valid_len):
     """dQ: grid (BH, n_q, n_kv); key/value blocks stream past a resident
     query block while dq accumulates in f32 scratch.  P is re-materialized
     from the lse residual: the [S, S] score matrix never exists."""
@@ -431,7 +477,7 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale: float,
-                        causal: bool, plan: TilePlan, valid_len: int):
+                        causal: bool, plan: TilePlan, valid_len):
     """dK/dV: grid (BH, n_kv, n_q); query/dO/statistic blocks stream past
     a resident key block while dk/dv accumulate in f32 scratch.  The score
     tile is built transposed ([Tk, Tq] = k @ q^T), so the row statistics
@@ -496,8 +542,16 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                             lambda d, j: visit((d + 1) * step, True, j, None))
                 visit(tile, False, jnp.clip(on_diag + count - first, 0, hi),
                       hi)
-            else:
+            elif isinstance(valid_len, int):
                 visit(tile, valid_len < plan.seq_pad, 0, hi)
+            else:
+                # A length read in the kernel: a tile of real keys alone
+                # takes no mask, a tile wholly beyond the length no step.
+                whole = col0 + tile <= valid_len
+                pl.when(whole)(lambda: visit(tile, False, 0, hi))
+                pl.when(jnp.logical_and(jnp.logical_not(whole),
+                                        col0 < valid_len))(
+                    lambda: visit(tile, True, 0, hi))
 
         jax.lax.fori_loop(0, plan.block_k // tile, k_tile, None)
 
@@ -509,9 +563,16 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10), inline=True)
 def _flash_bwd_bhsd(qb, kb, vb, ob, lse, dob, sm_scale, causal, plan,
-                    interpret, valid_len, dlse=None):
+                    interpret, valid_len, dlse=None, lens=None):
     bh, s, d = qb.shape
     bq, bk = plan.block_q, plan.block_k
+    if lens is not None:
+        # A row at or beyond its length came out as a constant zero: what
+        # arrives as its cotangent is no gradient, and the walks that end
+        # with the real rows count on its being zero.  (No caller with
+        # lengths reads lse, so dlse is zero already.)
+        real = jnp.arange(s)[None, :] < lens[:, None]          # [BH, S]
+        dob = jnp.where(real[..., None], dob, 0)
     # delta_i = rowsum(dO_i * O_i) — the standard backward residual.  An
     # lse cotangent (pair-valued VJP) folds in as delta - dlse.
     delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
@@ -522,34 +583,35 @@ def _flash_bwd_bhsd(qb, kb, vb, ob, lse, dob, sm_scale, causal, plan,
     lse = lse.astype(jnp.float32)[:, None]                 # [BH, 1, S]
     delta = delta[:, None]
     kernel_args = dict(sm_scale=sm_scale, causal=causal, plan=plan,
-                       valid_len=valid_len)
-    call_args = dict(compiler_params=_COMPILER_PARAMS, interpret=interpret)
+                       valid_len=valid_len, interpret=interpret)
 
     # dq: q-block fixed per outer step, k/v stream on the inner grid dim.
-    q_by_i = pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0))
+    q_by_i = pl.BlockSpec((None, bq, d), lambda b, i, j, *lens: (b, i, 0))
     kv_by_j = _streamed_kv_spec(d, causal, plan, valid_len)
-    row_by_i = _row_stat_spec(bq, lambda b, i, j: (b, 0, i))
-    dq = pl.pallas_call(
-        functools.partial(_mha_bwd_dq_kernel, **kernel_args),
+    row_by_i = _row_stat_spec(bq, lambda b, i, j, *lens: (b, 0, i))
+    dq = _kernel_call(
+        _mha_bwd_dq_kernel, lens, **kernel_args,
         grid=(bh, s // bq, s // bk),
         in_specs=[q_by_i, kv_by_j, kv_by_j, q_by_i, row_by_i, row_by_i],
         out_specs=q_by_i,
         out_shape=_out_struct((bh, s, d), qb.dtype, qb),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        **call_args,
     )(qb, kb, vb, dob, lse, delta)
 
     # dk/dv: k-block fixed per outer step, q/do/stats stream inside, from
     # the first query block that sees it to the last real one.
-    def q_index(i, j):
+    def q_index(b, i, j, lens):
         first = (i * bk) // bq if causal else 0
-        return jnp.minimum(jnp.maximum(j, first), (valid_len - 1) // bq)
+        return jnp.minimum(jnp.maximum(j, first),
+                           (_len_at(b, lens, valid_len) - 1) // bq)
 
-    q_by_j = pl.BlockSpec((None, bq, d), lambda b, i, j: (b, q_index(i, j), 0))
-    kv_by_i = pl.BlockSpec((None, bk, d), lambda b, i, j: (b, i, 0))
-    row_by_j = _row_stat_spec(bq, lambda b, i, j: (b, 0, q_index(i, j)))
-    dk, dv = pl.pallas_call(
-        functools.partial(_mha_bwd_dkv_kernel, **kernel_args),
+    q_by_j = pl.BlockSpec((None, bq, d), lambda b, i, j, *lens: (
+        b, q_index(b, i, j, lens), 0))
+    kv_by_i = pl.BlockSpec((None, bk, d), lambda b, i, j, *lens: (b, i, 0))
+    row_by_j = _row_stat_spec(bq, lambda b, i, j, *lens: (
+        b, 0, q_index(b, i, j, lens)))
+    dk, dv = _kernel_call(
+        _mha_bwd_dkv_kernel, lens, **kernel_args,
         grid=(bh, s // bk, s // bq),
         in_specs=[q_by_j, kv_by_i, kv_by_i, q_by_j, row_by_j, row_by_j],
         out_specs=[kv_by_i, kv_by_i],
@@ -557,13 +619,12 @@ def _flash_bwd_bhsd(qb, kb, vb, ob, lse, dob, sm_scale, causal, plan,
                    _out_struct((bh, s, d), vb.dtype, vb)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        **call_args,
     )(qb, kb, vb, dob, lse, delta)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhsd_lse(qb, kb, vb, sm_scale, causal, plan, interpret,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_bhsd_lse(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
                     valid_len):
     """Differentiable kernel entry over [BH, S, D] (S already padded),
     returning ``(out, lse)`` — the pair ring attention merges across hops
@@ -573,33 +634,72 @@ def _flash_bhsd_lse(qb, kb, vb, sm_scale, causal, plan, interpret,
     twist: dL/dS_ij gains a ``+ dlse_i * p_ij`` term, which folds into the
     existing kernels as ``delta_i -> delta_i - dlse_i`` (both enter as
     ``ds = p * (dp - delta)``) — no separate kernels needed.
+
+    ``lens`` is None (no operand: the static ``valid_len`` holds) or the
+    int32 [BH] lengths of a ``kv_lens`` call.
     """
     return _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, plan, interpret,
-                           valid_len)
+                           valid_len, lens)
 
 
-def _flash_bhsd_lse_fwd(qb, kb, vb, sm_scale, causal, plan, interpret,
+def _flash_bhsd_lse_fwd(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
                         valid_len):
     out, lse = _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, plan,
-                               interpret, valid_len)
-    return (out, lse), (qb, kb, vb, out, lse)
+                               interpret, valid_len, lens)
+    return (out, lse), (qb, kb, vb, lens, out, lse)
 
 
 def _flash_bhsd_lse_bwd(sm_scale, causal, plan, interpret, valid_len, res,
                         cotangents):
-    qb, kb, vb, ob, lse = res
+    qb, kb, vb, lens, ob, lse = res
     dob, dlse = cotangents
-    return _flash_bwd_bhsd(qb, kb, vb, ob, lse, dob, sm_scale, causal, plan,
-                           interpret, valid_len, dlse=dlse)
+    return (*_flash_bwd_bhsd(qb, kb, vb, ob, lse, dob, sm_scale, causal,
+                             plan, interpret, valid_len, dlse=dlse,
+                             lens=lens), None)
 
 
 _flash_bhsd_lse.defvjp(_flash_bhsd_lse_fwd, _flash_bhsd_lse_bwd)
 
 
+def _kv_lens(kv_lens, batch: int, seq: int, causal: bool):
+    """``kv_lens`` as the int32 [B] the masks read: a sequence's real
+    tokens are its first ``kv_lens[b]``, at least one and at most all."""
+    if causal:
+        raise ValueError(
+            "kv_lens with causal=True: padding lies at the tail, where no "
+            "real query sees it under a causal mask; pass no length")
+    kv_lens = jnp.asarray(kv_lens, jnp.int32)
+    if kv_lens.shape != (batch,):
+        raise ValueError(f"kv_lens has shape {kv_lens.shape}, expected one "
+                         f"length per sequence: ({batch},)")
+    return jnp.clip(kv_lens, 1, seq)
+
+
+def _dense(q, k, v, causal, scale, kv_lens):
+    b, s = q.shape[:2]
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    if causal:
+        mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
+        logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+    if kv_lens is not None:
+        real = jnp.arange(s)[None, :] < _kv_lens(kv_lens, b, s, causal)[:, None]
+        logits = jnp.where(real[:, None, None, :], logits,
+                           jnp.finfo(jnp.float32).min)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)      # [B, H, S]
+    probs = jnp.exp(logits - lse[..., None]).astype(v.dtype)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    if kv_lens is not None:
+        out = jnp.where(real[:, :, None, None], out, 0)
+    return out, lse
+
+
 def dense_attention(q, k, v, causal: bool = False,
-                    scale: Optional[float] = None):
-    """Reference-math dense attention over [B, S, H, D] (fp32 softmax)."""
-    out, _ = dense_attention_with_lse(q, k, v, causal, scale)
+                    scale: Optional[float] = None, kv_lens=None):
+    """Reference-math dense attention over [B, S, H, D] (fp32 softmax).
+    ``kv_lens`` as in :func:`flash_attention`."""
+    out, _ = _dense(q, k, v, causal, scale, kv_lens)
     return out
 
 
@@ -607,31 +707,15 @@ def dense_attention_with_lse(q, k, v, causal: bool = False,
                              scale: Optional[float] = None):
     """Dense attention that also returns log-sum-exp [B, H, S] (the chunk
     statistic ring attention merges across hops)."""
-    scale = q.shape[-1] ** -0.5 if scale is None else scale
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    if causal:
-        s = q.shape[1]
-        mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
-        logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)      # [B, H, S]
-    probs = jnp.exp(logits - lse[..., None]).astype(v.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    return out, lse
+    return _dense(q, k, v, causal, scale, None)
 
 
-def flash_attention_with_lse(q, k, v, causal: bool = False,
-                             scale: Optional[float] = None,
-                             block_q: Optional[int] = None,
-                             block_k: Optional[int] = None,
-                             interpret: Optional[bool] = None):
-    """Pallas attention over [B, S, H, D] returning ``(out, lse)`` with
-    lse shaped [B, H, S].  Same dispatch rules as :func:`flash_attention`;
-    off-TPU it falls back to :func:`dense_attention_with_lse`."""
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, kv_lens):
+    """``(out, lse)`` of the kernels, or of the dense fallback off-TPU."""
     b, s, h, d = q.shape
     if interpret is None:
         if jax.default_backend() != "tpu":
-            return dense_attention_with_lse(q, k, v, causal, scale)
+            return _dense(q, k, v, causal, scale, kv_lens)
         interpret = False
     sm_scale = d ** -0.5 if scale is None else scale
     plan = tile_plan(s, d, q.dtype.itemsize, causal, block_q, block_k)
@@ -645,24 +729,44 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
     def to_bhsd(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, s_pad, d)
 
-    out, lse = _flash_bhsd_lse(to_bhsd(q), to_bhsd(k), to_bhsd(v), sm_scale,
-                               causal, plan, bool(interpret), s)
+    # One length per [batch * heads] row of the kernels' grid.
+    lens = (None if kv_lens is None else
+            jnp.repeat(_kv_lens(kv_lens, b, s, causal), h))
+    out, lse = _flash_bhsd_lse(to_bhsd(q), to_bhsd(k), to_bhsd(v), lens,
+                               sm_scale, causal, plan, bool(interpret), s)
     out = out.reshape(b, h, s_pad, d).transpose(0, 2, 1, 3)[:, :s]
     lse = lse.reshape(b, h, s_pad)[:, :, :s]
     return out, lse
+
+
+def flash_attention_with_lse(q, k, v, causal: bool = False,
+                             scale: Optional[float] = None,
+                             block_q: Optional[int] = None,
+                             block_k: Optional[int] = None,
+                             interpret: Optional[bool] = None):
+    """Pallas attention over [B, S, H, D] returning ``(out, lse)`` with
+    lse shaped [B, H, S].  Same dispatch rules as :func:`flash_attention`;
+    off-TPU it falls back to :func:`dense_attention_with_lse`."""
+    return _flash(q, k, v, causal, scale, block_q, block_k, interpret, None)
 
 
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None, kv_lens=None):
     """Attention over [batch, seq, heads, head_dim].
 
     On TPU this is the Pallas kernel; elsewhere it falls back to the dense
     implementation (identical math) unless ``interpret=True`` forces the
     kernel through the Pallas interpreter (tests).
+
+    ``kv_lens`` (int32 [batch], non-causal calls only): sequence ``b``
+    holds ``kv_lens[b]`` real tokens (clipped to 1 .. seq) and padding
+    after them.  Keys at or beyond the length are masked for every query,
+    and the rows at or beyond it come out zero and pass no gradient on,
+    here and in :func:`dense_attention`.
     """
-    out, _ = flash_attention_with_lse(q, k, v, causal, scale, block_q,
-                                      block_k, interpret)
+    out, _ = _flash(q, k, v, causal, scale, block_q, block_k, interpret,
+                    kv_lens)
     return out

@@ -92,6 +92,21 @@ def test_flash_out_lse_pair_compiles(one_chip, shape):
     assert text.count("tpu_custom_call") >= 3
 
 
+def test_flash_with_a_key_length_per_sequence_compiles(one_chip):
+    """BERT-Large's attention at the benchmark's cell (32 x 512 x 16 x 64,
+    non-causal, ``kv_lens``): the lengths reach the three kernels as a
+    scalar-prefetch operand and the walks end at a length read in the
+    kernel, which only Mosaic can refuse."""
+    def loss(q, k, v, lens):
+        return jnp.sum(flash_attention(q, k, v, interpret=False,
+                                       kv_lens=lens).astype(jnp.float32))
+
+    lens = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          *_qkv((32, 512, 16, 64), one_chip), lens)
+    assert text.count("tpu_custom_call") >= 3
+
+
 @pytest.mark.parametrize("codec", ["int8", "int4", "int8g"])
 def test_codec_encode_decode_compiles(one_chip, codec):
     def roundtrip(flat):

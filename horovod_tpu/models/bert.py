@@ -33,6 +33,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .flat_dense import FlatDenseGeneral
+
 
 @dataclasses.dataclass(frozen=True)
 class BertConfig:
@@ -74,9 +76,10 @@ class SelfAttention(nn.Module):
         head_dim = cfg.hidden_size // cfg.num_heads
         # One fused QKV projection: [B, S, H] @ [H, 3H] keeps the MXU at a
         # single large matmul instead of three small ones.
-        qkv = nn.DenseGeneral((3, cfg.num_heads, head_dim), dtype=cfg.dtype,
-                              name="qkv")(x)
-        q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
+        qkv = FlatDenseGeneral((3, cfg.num_heads, head_dim), dtype=cfg.dtype,
+                               name="qkv")(x)           # [B, S, 3 * H * D]
+        q, k, v = (part.reshape(*x.shape[:-1], cfg.num_heads, head_dim)
+                   for part in jnp.split(qkv, 3, axis=-1))
         if cfg.sp_axis_name is not None:
             from ..parallel.ring_attention import ring_attention
 
@@ -101,8 +104,8 @@ class SelfAttention(nn.Module):
                 logits = jnp.where(mask[:, None, None, :], logits, big_neg)
             probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
             ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), dtype=cfg.dtype,
-                              name="out")(ctx)
+        out = FlatDenseGeneral(cfg.hidden_size, axis=(-2, -1), dtype=cfg.dtype,
+                               name="out")(ctx)
         return out
 
 

@@ -16,6 +16,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .flat_dense import FlatDenseGeneral
+
 
 @dataclasses.dataclass(frozen=True)
 class GPTConfig:
@@ -45,9 +47,10 @@ class CausalSelfAttention(nn.Module):
     def __call__(self, x, deterministic: bool = True):
         cfg = self.config
         head_dim = cfg.hidden_size // cfg.num_heads
-        qkv = nn.DenseGeneral((3, cfg.num_heads, head_dim), dtype=cfg.dtype,
-                              name="qkv")(x)
-        q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
+        qkv = FlatDenseGeneral((3, cfg.num_heads, head_dim), dtype=cfg.dtype,
+                               name="qkv")(x)           # [B, S, 3 * H * D]
+        q, k, v = (part.reshape(*x.shape[:-1], cfg.num_heads, head_dim)
+                   for part in jnp.split(qkv, 3, axis=-1))
         if cfg.sp_axis_name is not None:
             from ..parallel.ring_attention import ring_attention
 
@@ -62,8 +65,8 @@ class CausalSelfAttention(nn.Module):
             from ..ops.flash_attention import dense_attention
 
             ctx = dense_attention(q, k, v, causal=True)
-        return nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1),
-                               dtype=cfg.dtype, name="out")(ctx)
+        return FlatDenseGeneral(cfg.hidden_size, axis=(-2, -1),
+                                dtype=cfg.dtype, name="out")(ctx)
 
 
 class GPTBlock(nn.Module):

@@ -7,13 +7,26 @@ hand kernel on TPU is attention: this kernel keeps the [S, S] score matrix
 out of HBM entirely (VMEM-blocked online softmax), the classic
 flash-attention trade.
 
-Layout: inputs [batch, seq, heads, head_dim]; the kernels run on
-[batch*heads, seq, head_dim] with (BH, n_resident, n_streamed) grids: a
-block of queries (forward, dQ) or of keys (dK/dV) stays resident while
-blocks of the other operand stream through VMEM, and the online-softmax
-state (acc/m/l, or the dq/dk/dv partials) persists in f32 scratch across
-the innermost grid steps — so sequence length is HBM-bound, not
-VMEM-bound.
+Layout: inputs [batch, seq, heads, head_dim].  The kernels read and write
+the model's own [batch, seq, heads * head_dim] (a reshape of what a flat
+projection produces: no data moves; models/flat_dense.py), a **128-lane
+column block** of it a grid step: ``G = 128 // head_dim`` heads side by side
+(two at 64, four at 32; one head where ``head_dim`` is whole lane tiles), on
+(batch * heads / G, n_resident, n_streamed) grids.  Inside a grid step the
+G heads are walked by a static loop, G independent dot -> reduce -> exp ->
+dot chains a step: the forward slices each head's lanes out of the block
+and stacks the heads' acc^T for one lane-dense store; dq and dkv keep every
+operand whole and zero the other heads' lanes in one operand of each dot
+(settled on the chip, PERF.md PR 28).  Where no whole
+number of heads fills 128 lanes (``head_dim`` 80; three heads of 64) the
+same kernels run with G = 1 on operands turned round to
+[batch * heads, seq, head_dim], under the scope ``hvd_flash_relayout``.
+:func:`tile_plan` decides from ``head_dim`` and ``heads``; nothing else
+does.  Either way a block of queries (forward, dQ) or of keys (dK/dV) stays
+resident while blocks of the other operand stream through VMEM, and the
+online-softmax state (acc/m/l, or the dq/dk/dv partials) persists in f32
+scratch across the innermost grid steps — so sequence length is HBM-bound,
+not VMEM-bound.
 
 The schedule has three nested sizes, chosen from the shape by
 :func:`tile_plan` (no knob): a grid **block** as large as a VMEM budget
@@ -36,7 +49,7 @@ finite with a -1e30 mask value: no NaN guards needed.
 
 ``kv_lens`` (non-causal calls: an int32 length per sequence, BERT's padding
 mask) reaches the kernels as a scalar-prefetch operand, one length per
-[batch * heads] row of the grid: the same walks then end at that
+row of the grid (a row's heads are one sequence's): the same walks then end at that
 sequence's last real key where they otherwise end at the static
 ``valid_len``, and rows at or beyond the length come out zero.  Without it
 the kernels and their operands are what they were.
@@ -45,7 +58,10 @@ Every dot takes its operands in the dtype they arrive in (bf16 at the
 MXU's native rate, float32 accumulation); softmax math is float32.  The
 forward and dK/dV build the score tile transposed ([keys, queries]), so
 the row statistics are lane-dense rows that reduce and broadcast along
-sublanes, and cross HBM as one float32 a row ([BH, 1, S]).
+sublanes, and cross HBM as one float32 a row ([BH / G, G, S]).  The
+backward's ``delta = rowsum(dO * O)`` is made inside dq and dkv from the dO
+and O blocks they hold: a head's lanes of the model's layout are nothing
+XLA can reduce over without relayouting the product.
 
 Off-TPU (CPU tests) the public wrapper falls back to an identical-math
 dense implementation; the kernels are unit-tested in interpret mode and
@@ -82,7 +98,8 @@ _TN = (((0,), (0,)), ((), ()))      # A^T @ B: contract the major dims
 
 
 class TilePlan(NamedTuple):
-    """The schedule of one flash_attention call (see :func:`tile_plan`)."""
+    """The schedule and block layout of one flash_attention call (see
+    :func:`tile_plan`)."""
     seq_pad: int       # the sequence length the kernels see
     block_q: int       # grid block of queries: resident in fwd / dq
     block_k: int       # grid block of keys: resident in dkv
@@ -91,23 +108,45 @@ class TilePlan(NamedTuple):
     step_q: int        # dkv walks a query block in steps of this many rows
     step_k: int        # fwd / dq walk a key block in steps of this many rows
     vmem_bytes: int    # estimate for the hungriest kernel (dkv)
+    heads_per_block: int   # heads a grid step holds side by side (G)
+    lanes: int         # lanes a block: heads_per_block * head_dim
+
+    @property
+    def lane_dense(self) -> bool:
+        """Whether a block fills whole 128-lane tiles: the kernels then
+        take [batch, seq, heads * head_dim] as the model holds it.  Else
+        the operands are relayouted to [batch * heads, seq, head_dim]."""
+        return self.lanes % LANES == 0
 
     def grid_steps(self, batch_heads: int) -> int:
-        return (batch_heads * (self.seq_pad // self.block_q)
+        return (batch_heads // self.heads_per_block
+                * (self.seq_pad // self.block_q)
                 * (self.seq_pad // self.block_k))
 
 
-def _vmem_estimate(block_q, block_k, tile, step, head_dim, itemsize):
-    """VMEM bytes of the dkv kernel, the hungriest of the three: q, dO, k, v
-    blocks and the dk, dv outputs double-buffered by the pipeline (the minor
-    dim padded to the lane count), the two row statistics (8 sublanes each),
-    the two float32 accumulators, and one step's float32 score, p, dp and
-    ds tiles with their casts."""
-    lanes = -(-head_dim // LANES) * LANES
-    streamed = 2 * 2 * block_q * (lanes * itemsize + 8 * 4)   # q, dO, stats
-    resident = 2 * 4 * block_k * lanes * itemsize             # k, v, dk, dv
-    accumulators = 2 * block_k * lanes * 4
-    one_step = tile * step * (4 * 4 + 2 * itemsize)
+def heads_per_block(head_dim: int, heads: int) -> int:
+    """How many heads fill a 128-lane column block of [B, S, H * D]: two at
+    64, four at 32, one where ``head_dim`` is whole lane tiles already.
+    Where no whole number of heads does (a ``head_dim`` that does not
+    divide 128, fewer heads than fill it), one: the fallback layout."""
+    g = LANES // head_dim if LANES % head_dim == 0 else 1
+    return g if heads % g == 0 else 1
+
+
+def _vmem_estimate(block_q, block_k, tile, step, lanes, heads, itemsize):
+    """VMEM bytes of the dkv kernel, the hungriest of the three: q, dO, O,
+    k, v blocks and the dk, dv outputs double-buffered by the pipeline (the
+    minor dim padded to the lane count: nothing where the block is
+    lane-dense), the two row statistics (a row a head, in whole 8-sublane
+    tiles), the two float32 accumulators, and one step's float32 score, p,
+    dp and ds tiles with their casts for each of the block's heads (their
+    chains are independent, so the scheduler may hold them all)."""
+    width = -(-lanes // LANES) * LANES
+    stat_rows = -(-heads // 8) * 8
+    streamed = 2 * block_q * (3 * width * itemsize + 2 * stat_rows * 4)
+    resident = 2 * 4 * block_k * width * itemsize             # k, v, dk, dv
+    accumulators = 2 * block_k * width * 4
+    one_step = heads * tile * step * (4 * 4 + 2 * itemsize)
     return streamed + resident + accumulators + one_step
 
 
@@ -121,29 +160,37 @@ def _divisor(n: int, cap: int) -> int:
 
 def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
               block_q: Optional[int] = None,
-              block_k: Optional[int] = None) -> TilePlan:
-    """Choose the schedule from the shape.  Pure: shapes in, sizes out.
+              block_k: Optional[int] = None, heads: int = 1) -> TilePlan:
+    """Choose the schedule and the block layout from the shape.  Pure:
+    shapes in, sizes out.
+
+    The layout: a grid step holds :func:`heads_per_block` heads side by
+    side, a 128-lane column block of [B, S, H * D] (``heads`` = 1, the
+    default, can fill 128 lanes only with a ``head_dim`` of whole lane
+    tiles).
 
     Three sizes nest.  A grid **block** is what a grid step holds in VMEM.
     Without explicit blocks the sequence is padded to a multiple of LANES
     and both blocks are the largest divisor of it (in whole LANES) whose
     VMEM estimate fits the budget: at GPT-2-medium's 1024 x 64 in bf16 the
-    whole sequence, so a call's grid is one step a head.  An explicit
-    block is honoured (clipped to the sequence; the sequence padded to the
-    blocks' least common multiple).  A resident **tile** (at most
-    _MAX_TILE rows of the block that stays) walks the other block in
-    **steps** (at most _MAX_STEP rows); a step divides the tile it walks
-    past, so the causal diagonal crosses a tile in a whole number of
-    steps.  ``causal`` does not change the sizes: the kernels' walks stop
-    at the diagonal whatever they are.
+    whole sequence, so a call's grid is one step a pair of heads.  An
+    explicit block is honoured in either layout (clipped to the sequence;
+    the sequence padded to the blocks' least common multiple).  A resident
+    **tile** (at most _MAX_TILE rows of the block that stays) walks the
+    other block in **steps** (at most _MAX_STEP rows); a step divides the
+    tile it walks past, so the causal diagonal crosses a tile in a whole
+    number of steps.  ``causal`` does not change the sizes: the kernels'
+    walks stop at the diagonal whatever they are.
     """
     del causal
+    g = heads_per_block(head_dim, heads)
+    lanes = g * head_dim
     if block_q is None and block_k is None:
         seq_pad = -(-seq // LANES) * LANES
         block_q = block_k = next(
             b for b in range(seq_pad, 0, -LANES) if seq_pad % b == 0
             and _vmem_estimate(b, b, min(b, _MAX_TILE), min(b, _MAX_STEP),
-                               head_dim, itemsize) <= _VMEM_BUDGET)
+                               lanes, g, itemsize) <= _VMEM_BUDGET)
     else:
         block_q = min(block_q if block_q is not None else block_k, seq)
         block_k = min(block_k if block_k is not None else block_q, seq)
@@ -153,9 +200,9 @@ def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
     step_q = math.gcd(_divisor(block_q, _MAX_STEP), tile_k)
     step_k = math.gcd(_divisor(block_k, _MAX_STEP), tile_q)
     vmem = _vmem_estimate(block_q, block_k, max(tile_q, tile_k),
-                          max(step_q, step_k), head_dim, itemsize)
+                          max(step_q, step_k), lanes, g, itemsize)
     return TilePlan(seq_pad, block_q, block_k, tile_q, tile_k, step_q,
-                    step_k, vmem)
+                    step_k, vmem, g, lanes)
 
 
 def _out_struct(shape, dtype, like):
@@ -270,6 +317,25 @@ def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
     return s
 
 
+def _head_lanes(plan: TilePlan):
+    """The lanes of each head of a block, as static slices: of an operand
+    block's minor dim, and of the rows of the forward's transposed
+    accumulator."""
+    d = plan.lanes // plan.heads_per_block
+    return [slice(g * d, (g + 1) * d) for g in range(plan.heads_per_block)]
+
+
+def _only(x, h, plan: TilePlan):
+    """``x`` [T, lanes] with the lanes of every head but ``h`` zeroed: as
+    an operand of a dot it contributes head ``h`` alone, and the zeros ride
+    in the half of the MXU a 64-wide operand leaves idle."""
+    if plan.heads_per_block == 1:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, plan.lanes), 1)
+    return jnp.where((lane >= h.start) & (lane < h.stop), x,
+                     jnp.zeros_like(x))
+
+
 def _row_to_col(row):
     """[1, T] -> [T, 1]: dq wants the statistics along the score's rows."""
     t = row.shape[1]
@@ -279,16 +345,20 @@ def _row_to_col(row):
 def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, sm_scale: float, causal: bool, plan: TilePlan,
                 valid_len):
-    """Forward: grid (BH, n_q, n_kv).  A query block stays resident while
-    key/value blocks stream past it.  The score tile is built transposed
-    ([Tk, Tq] = k @ q^T): the online-softmax statistics are then rows
-    ([1, Tq], one vreg per 1024 queries where a column takes one per 8),
-    their reductions run down the sublanes on the VPU, and the
+    """Forward: grid (BH / G, n_q, n_kv).  A query block stays resident
+    while key/value blocks stream past it.  The score tile is built
+    transposed ([Tk, Tq] = k @ q^T): the online-softmax statistics are then
+    rows ([1, Tq], one vreg per 1024 queries where a column takes one per
+    8), their reductions run down the sublanes on the VPU, and the
     accumulator is acc^T [D, Tq]; m / l / acc^T persist in scratch across
-    the kv grid steps and ride in registers inside a run of steps."""
+    the kv grid steps and ride in registers inside a run of steps.  The
+    block's G heads take each step together, G independent chains; their
+    acc^T stack to [G * D, Tq], which leaves as one lane-dense [Tq, G * D]
+    store."""
     iq, jk = pl.program_id(1), pl.program_id(2)
     n_kv = pl.num_programs(2)
     tile, step = plan.tile_q, plan.step_k
+    heads = _head_lanes(plan)
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
                               valid_len=valid_len, transposed=True)
 
@@ -306,26 +376,35 @@ def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
             def visit(off, masked, lo, hi):
                 rows = pl.ds(start + off, tile - off)
-                q = q_ref[rows, :]                          # [Tq, D]
+                qs = [q_ref[rows, h] for h in heads]        # [Tq, D] a head
 
                 def body(j, state):
-                    m, l, acc = state                # [1, Tq] x 2, [D, Tq]
                     cols = pl.ds(pl.multiple_of(j * step, step), step)
-                    v = v_ref[cols, :]                      # [Tk, D]
-                    s = score(q, k_ref[cols, :], row0 + off,
-                              jk * plan.block_k + j * step, masked)
-                    m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-                    p = jnp.exp(s - m_new)                  # [Tk, Tq]
-                    alpha = jnp.exp(m - m_new)
-                    l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
-                    acc = acc * alpha + jax.lax.dot_general(    # v^T @ p
-                        v, p.astype(v.dtype), _TN,
-                        preferred_element_type=jnp.float32)
-                    return m_new, l, acc
+                    col0 = jk * plan.block_k + j * step
+                    new = []
+                    for q, h, (m, l, acc) in zip(qs, heads, state):
+                        # m, l [1, Tq]; acc [D, Tq]
+                        v = v_ref[cols, h]                  # [Tk, D]
+                        s = score(q, k_ref[cols, h], row0 + off, col0,
+                                  masked)
+                        m_new = jnp.maximum(
+                            m, jnp.max(s, axis=0, keepdims=True))
+                        p = jnp.exp(s - m_new)              # [Tk, Tq]
+                        alpha = jnp.exp(m - m_new)
+                        l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+                        acc = acc * alpha + jax.lax.dot_general(  # v^T @ p
+                            v, p.astype(v.dtype), _TN,
+                            preferred_element_type=jnp.float32)
+                        new.append((m_new, l, acc))
+                    return tuple(new)
 
-                state = m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows]
-                m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows] = _run(
-                    body, lo, hi, state)
+                state = _run(body, lo, hi, tuple(
+                    (m_ref[g:g + 1, rows], l_ref[g:g + 1, rows],
+                     acc_ref[h, rows]) for g, h in enumerate(heads)))
+                for g, (h, (m, l, acc)) in enumerate(zip(heads, state)):
+                    m_ref[g:g + 1, rows] = m
+                    l_ref[g:g + 1, rows] = l
+                    acc_ref[h, rows] = acc
 
             _k_walk(row0, jk, causal, plan, valid_len, visit)
 
@@ -335,41 +414,62 @@ def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     def _flush():
         def q_tile(c, _):
             rows = pl.ds(pl.multiple_of(c * tile, tile), tile)
-            l = jnp.maximum(l_ref[:, rows], 1e-30)
-            out = acc_ref[:, rows] / l
+            outs = []
+            for g, h in enumerate(heads):
+                l = jnp.maximum(l_ref[g:g + 1, rows], 1e-30)
+                outs.append(acc_ref[h, rows] / l)           # [D, Tq]
+                # Log-sum-exp per query row, the residual the backward
+                # pass needs to re-materialize P = exp(S - lse) tile by
+                # tile.
+                lse_ref[g:g + 1, rows] = m_ref[g:g + 1, rows] + jnp.log(l)
+            out = jnp.concatenate(outs, axis=0)             # [G * D, Tq]
             if not isinstance(valid_len, int):
                 # A row at or beyond its sequence's length is padding.
                 qpos = (iq * plan.block_q + c * tile
                         + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1))
                 out = jnp.where(qpos < valid_len, out, 0.0)
             o_ref[rows, :] = jnp.transpose(out).astype(o_ref.dtype)
-            # Log-sum-exp per query row, the residual the backward pass
-            # needs to re-materialize P = exp(S - lse) tile by tile.
-            lse_ref[:, rows] = m_ref[:, rows] + jnp.log(l)
 
         jax.lax.fori_loop(0, plan.block_q // tile, q_tile, None)
 
 
-def _row_stat_spec(block, index_map):
-    """One float32 a row, lane-dense: a [BH, 1, S] array in (1, block) rows."""
-    return pl.BlockSpec((None, 1, block), index_map)
+def _operand_spec(rows: int, plan: TilePlan, n_col: int, seq_block):
+    """A [rows, lanes] block of an operand [N, S, n_col * lanes]: grid row
+    ``r`` is column block ``r % n_col`` of sequence ``r // n_col`` (the
+    model's [B, S, H * D] in 128-lane blocks; ``n_col`` is 1 and N = B * H
+    in the fallback layout).  ``seq_block(r, i, j, lens)`` picks the block
+    along the sequence."""
+    return pl.BlockSpec(
+        (None, rows, plan.lanes), lambda r, i, j, *lens: (
+            r // n_col, seq_block(r, i, j, lens), r % n_col))
 
 
-def _streamed_kv_spec(d, causal: bool, plan: TilePlan, valid_len):
+def _row_stat_spec(plan: TilePlan, seq_block):
+    """One float32 a row, lane-dense: a [BH / G, G, S] array in
+    (G, block_q) blocks, a row a head of the grid row."""
+    return pl.BlockSpec(
+        (None, plan.heads_per_block, plan.block_q),
+        lambda r, i, j, *lens: (r, 0, seq_block(r, i, j, lens)))
+
+
+def _by_i(r, i, j, lens):
+    """The block of the resident grid axis."""
+    return i
+
+
+def _streamed_k(causal: bool, plan: TilePlan, valid_len):
     """Key/value blocks streaming past query block ``i`` (fwd, dq): a dead
     grid tile holds the block of the last live one, so it costs no copy."""
-    return pl.BlockSpec(
-        (None, plan.block_k, d), lambda b, i, j, *lens: (
-            b, jnp.minimum(j, _last_live_k(
-                i, causal, plan, _len_at(b, lens, valid_len))), 0))
+    return lambda r, i, j, lens: jnp.minimum(j, _last_live_k(
+        i, causal, plan, _len_at(r, lens, valid_len)))
 
 
 def _kernel_call(kernel, lens, *, grid, in_specs, out_specs, out_shape,
                  scratch_shapes, interpret, **kernel_args):
     """The ``pallas_call`` of one of the three kernels.  ``lens`` None: the
     kernel's ``valid_len`` is the static one in ``kernel_args``.  Else
-    ``lens`` (int32 [BH]) is a scalar-prefetch operand and each grid row
-    takes its own length from it."""
+    ``lens`` (int32, one per grid row) is a scalar-prefetch operand and
+    each grid row takes its own length from it."""
     call_args = dict(out_shape=out_shape, compiler_params=_COMPILER_PARAMS,
                      interpret=interpret)
     if lens is None:
@@ -395,48 +495,83 @@ def _kernel_call(kernel, lens, *, grid, in_specs, out_specs, out_shape,
 # start).  ``inline`` leaves no call in the jaxpr, so an op's name keeps the
 # scope of the layer that made it.
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7), inline=True)
-def _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, plan, interpret,
-                    valid_len, lens=None):
-    """Forward kernel over [BH, S, D] (S already padded): out + row lse."""
-    bh, s, d = qb.shape
+def _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret, valid_len,
+               lens=None):
+    """Forward kernel over operands [N, S, n_col * lanes] (S already
+    padded; see :func:`_operand_spec`): out likewise + the rows' lse
+    [BH / G, G, S]."""
+    n, s, width = qb.shape
+    n_col, g = width // plan.lanes, plan.heads_per_block
     bq, bk = plan.block_q, plan.block_k
-    q_spec = pl.BlockSpec((None, bq, d), lambda b, i, j, *lens: (b, i, 0))
-    kv_spec = _streamed_kv_spec(d, causal, plan, valid_len)
-    out, lse = _kernel_call(
+    q_spec = _operand_spec(bq, plan, n_col, _by_i)
+    kv_spec = _operand_spec(bk, plan, n_col,
+                            _streamed_k(causal, plan, valid_len))
+    return _kernel_call(
         _mha_kernel, lens, sm_scale=sm_scale, causal=causal, plan=plan,
         valid_len=valid_len, interpret=interpret,
-        grid=(bh, s // bq, s // bk),
+        grid=(n * n_col, s // bq, s // bk),
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec,
-                   _row_stat_spec(bq, lambda b, i, j, *lens: (b, 0, i))],
+        out_specs=[q_spec, _row_stat_spec(plan, _by_i)],
         out_shape=[
-            _out_struct((bh, s, d), qb.dtype, qb),
-            _out_struct((bh, 1, s), jnp.float32, qb),
+            _out_struct(qb.shape, qb.dtype, qb),
+            _out_struct((n * n_col, g, s), jnp.float32, qb),
         ],
         scratch_shapes=[
-            pltpu.VMEM((d, bq), jnp.float32),
-            pltpu.VMEM((1, bq), jnp.float32),
-            pltpu.VMEM((1, bq), jnp.float32),
+            pltpu.VMEM((plan.lanes, bq), jnp.float32),
+            pltpu.VMEM((g, bq), jnp.float32),
+            pltpu.VMEM((g, bq), jnp.float32),
         ],
     )(qb, kb, vb)
-    return out, lse[:, 0]
 
 
-def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dq_ref, acc_ref, *, sm_scale: float, causal: bool,
-                       plan: TilePlan, valid_len):
-    """dQ: grid (BH, n_q, n_kv); key/value blocks stream past a resident
-    query block while dq accumulates in f32 scratch.  P is re-materialized
-    from the lse residual: the [S, S] score matrix never exists."""
+def _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan: TilePlan):
+    """delta_i = rowsum(dO_i * O_i) - dlse_i of each head of a query block,
+    the standard backward residual, into ``delta_ref`` [G, block_q] as
+    lane-dense rows like lse.  (An lse cotangent folds in here: both enter
+    as ``ds = p * (dp - delta)``.)  It is made where it is used: a head's
+    ``head_dim`` lanes of the model's layout are no dimension XLA could
+    reduce over without turning the whole product round first."""
+    step = plan.step_q
+
+    def chunk(c, _):
+        rows = pl.ds(pl.multiple_of(c * step, step), step)
+        prod = jnp.transpose(do_ref[rows, :].astype(jnp.float32)
+                             * o_ref[rows, :].astype(jnp.float32))
+        for g, h in enumerate(_head_lanes(plan)):           # [lanes, Tq]
+            delta_ref[g:g + 1, rows] = (
+                jnp.sum(prod[h], axis=0, keepdims=True)
+                - dlse_ref[g:g + 1, rows])
+
+    jax.lax.fori_loop(0, plan.block_q // step, chunk, None)
+
+
+def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
+                       dq_ref, acc_ref, delta_ref, *, sm_scale: float,
+                       causal: bool, plan: TilePlan, valid_len):
+    """dQ: grid (BH / G, n_q, n_kv); key/value blocks stream past a
+    resident query block while dq accumulates in f32 scratch.  P is
+    re-materialized from the lse residual: the [S, S] score matrix never
+    exists.
+
+    The block's G heads share one [Tq, lanes] accumulator and every operand
+    keeps all its lanes: a head's q and dO enter the score and dP dots with
+    the other heads' lanes zeroed (:func:`_only`), its dS meets k zeroed
+    likewise, so each dot writes its head's lanes and zeros elsewhere.  A
+    64-wide operand fills half the MXU's contraction or output width as it
+    is; the zeros ride in the other half (read on the chip, PERF.md PR 28:
+    faster than slicing a head's lanes out and shifting the second head's
+    back in)."""
     iq, jk = pl.program_id(1), pl.program_id(2)
     n_kv = pl.num_programs(2)
     tile, step = plan.tile_q, plan.step_k
+    heads = _head_lanes(plan)
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
                               valid_len=valid_len)
 
     @pl.when(jk == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan)
 
     @pl.when(_block_live(iq, jk, causal, plan, valid_len))
     def _compute():
@@ -446,23 +581,29 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
             def visit(off, masked, lo, hi):
                 rows = pl.ds(start + off, tile - off)
-                q = q_ref[rows, :]                          # [Tq, D]
-                do = do_ref[rows, :]
-                lse = _row_to_col(lse_ref[:, rows])         # [Tq, 1]
-                delta = _row_to_col(delta_ref[:, rows])
+                q, do = q_ref[rows, :], do_ref[rows, :]     # [Tq, lanes]
+                # Per head: q, dO with the other heads' lanes zeroed;
+                # lse, delta [Tq, 1].
+                resident = [
+                    (_only(q, h, plan), _only(do, h, plan),
+                     _row_to_col(lse_ref[g:g + 1, rows]),
+                     _row_to_col(delta_ref[g:g + 1, rows]))
+                    for g, h in enumerate(heads)]
 
-                def body(j, acc):
+                def body(j, acc):                           # [Tq, lanes]
                     cols = pl.ds(pl.multiple_of(j * step, step), step)
-                    k = k_ref[cols, :]                      # [Tk, D]
-                    s = score(q, k, row0 + off,
-                              jk * plan.block_k + j * step, masked)
-                    p = jnp.exp(s - lse)                    # [Tq, Tk]
-                    dp = jax.lax.dot_general(
-                        do, v_ref[cols, :], _NT,
-                        preferred_element_type=jnp.float32)
-                    ds = p * (dp - delta) * sm_scale
-                    return acc + jnp.dot(ds.astype(k.dtype), k,
-                                         preferred_element_type=jnp.float32)
+                    col0 = jk * plan.block_k + j * step
+                    k, v = k_ref[cols, :], v_ref[cols, :]   # [Tk, lanes]
+                    for (q, do, lse, delta), h in zip(resident, heads):
+                        s = score(q, k, row0 + off, col0, masked)
+                        p = jnp.exp(s - lse)                # [Tq, Tk]
+                        dp = jax.lax.dot_general(
+                            do, v, _NT, preferred_element_type=jnp.float32)
+                        ds = p * (dp - delta) * sm_scale
+                        acc = acc + jnp.dot(                # head h's lanes
+                            ds.astype(k.dtype), _only(k, h, plan),
+                            preferred_element_type=jnp.float32)
+                    return acc
 
                 acc_ref[rows, :] = _run(body, lo, hi, acc_ref[rows, :])
 
@@ -475,11 +616,15 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[:] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale: float,
-                        causal: bool, plan: TilePlan, valid_len):
-    """dK/dV: grid (BH, n_kv, n_q); query/dO/statistic blocks stream past
-    a resident key block while dk/dv accumulate in f32 scratch.  The score
+def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
+                        dk_ref, dv_ref, dk_acc, dv_acc, delta_ref, *,
+                        sm_scale: float, causal: bool, plan: TilePlan,
+                        valid_len):
+    """dK/dV: grid (BH / G, n_kv, n_q); query/dO/statistic blocks stream
+    past a resident key block while dk/dv accumulate in f32 scratch, the
+    block's G heads side by side in [Tk, lanes] accumulators (operands
+    whole, a head's k and v with the other heads' lanes zeroed, as in dq;
+    the heads' p and dS tiles meet dO and q in one dot each).  The score
     tile is built transposed ([Tk, Tq] = k @ q^T), so the row statistics
     broadcast down the sublanes as they arrive and all four dots are
     plain: no operand is transposed on the way to the MXU.
@@ -495,6 +640,7 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     tile, step = plan.tile_k, plan.step_q
     n = plan.block_q // step
     last = _steps(valid_len, step)
+    heads = _head_lanes(plan)
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
                               valid_len=valid_len, transposed=True)
 
@@ -505,34 +651,46 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(_block_live(iq, jk, causal, plan, valid_len))
     def _compute():
+        _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan)
+
         def k_tile(c, _):
             start = pl.multiple_of(c * tile, tile)
             col0 = jk * plan.block_k + c * tile
 
             def visit(size, masked, lo, hi):
                 cols = pl.ds(start, size)
-                k = k_ref[cols, :]                          # [Tk, D]
-                v = v_ref[cols, :]
+                k, v = k_ref[cols, :], v_ref[cols, :]       # [Tk, lanes]
+                # Per head: k, v with the other heads' lanes zeroed.
+                kv = [(_only(k, h, plan), _only(v, h, plan)) for h in heads]
 
                 def body(j, state):
-                    dk, dv = state
+                    dk, dv = state                          # [Tk, lanes]
                     rows = pl.ds(pl.multiple_of(j * step, step), step)
-                    q = q_ref[rows, :]                      # [Tq, D]
-                    do = do_ref[rows, :]
-                    s = score(q, k, iq * plan.block_q + j * step, col0,
-                              masked)
-                    p = jnp.exp(s - lse_ref[:, rows])       # [Tk, Tq]
-                    dv = dv + jnp.dot(p.astype(do.dtype), do,
-                                      preferred_element_type=jnp.float32)
-                    dp = jax.lax.dot_general(
-                        v, do, _NT, preferred_element_type=jnp.float32)
-                    ds = p * (dp - delta_ref[:, rows]) * sm_scale
-                    dk = dk + jnp.dot(ds.astype(q.dtype), q,
-                                      preferred_element_type=jnp.float32)
+                    row0 = iq * plan.block_q + j * step
+                    q, do = q_ref[rows, :], do_ref[rows, :]     # [Tq, lanes]
+                    ps, dss = [], []
+                    for g, (k, v) in enumerate(kv):
+                        s = score(q, k, row0, col0, masked)
+                        p = jnp.exp(s - lse_ref[g:g + 1, rows])  # [Tk, Tq]
+                        dp = jax.lax.dot_general(
+                            v, do, _NT, preferred_element_type=jnp.float32)
+                        ds = p * (dp - delta_ref[g:g + 1, rows]) * sm_scale
+                        ps.append(p.astype(do.dtype))
+                        dss.append(ds.astype(q.dtype))
+                    # One dot for all heads: [p_0 | p_1] @ [dO_0; dO_1],
+                    # each dO_h zero outside head h's lanes.
+                    dv = dv + jnp.dot(
+                        jnp.concatenate(ps, axis=1), jnp.concatenate(
+                            [_only(do, h, plan) for h in heads], axis=0),
+                        preferred_element_type=jnp.float32)
+                    dk = dk + jnp.dot(
+                        jnp.concatenate(dss, axis=1), jnp.concatenate(
+                            [_only(q, h, plan) for h in heads], axis=0),
+                        preferred_element_type=jnp.float32)
                     return dk, dv
 
-                state = dk_acc[cols, :], dv_acc[cols, :]
-                dk_acc[cols, :], dv_acc[cols, :] = _run(body, lo, hi, state)
+                dk_acc[cols, :], dv_acc[cols, :] = _run(
+                    body, lo, hi, (dk_acc[cols, :], dv_acc[cols, :]))
 
             first = iq * n
             hi = jnp.clip(last - first, 0, n)
@@ -561,104 +719,102 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10), inline=True)
-def _flash_bwd_bhsd(qb, kb, vb, ob, lse, dob, sm_scale, causal, plan,
-                    interpret, valid_len, dlse=None, lens=None):
-    bh, s, d = qb.shape
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11), inline=True)
+def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
+               interpret, valid_len, lens=None):
+    n, s, width = qb.shape
+    n_col, g = width // plan.lanes, plan.heads_per_block
     bq, bk = plan.block_q, plan.block_k
     if lens is not None:
         # A row at or beyond its length came out as a constant zero: what
         # arrives as its cotangent is no gradient, and the walks that end
         # with the real rows count on its being zero.  (No caller with
         # lengths reads lse, so dlse is zero already.)
-        real = jnp.arange(s)[None, :] < lens[:, None]          # [BH, S]
+        real = jnp.arange(s)[None, :] < lens[::n_col, None]    # [N, S]
         dob = jnp.where(real[..., None], dob, 0)
-    # delta_i = rowsum(dO_i * O_i) — the standard backward residual.  An
-    # lse cotangent (pair-valued VJP) folds in as delta - dlse.
-    delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
-                    axis=-1)                               # [BH, S]
-    if dlse is not None:
-        delta = delta - dlse.astype(jnp.float32)
-    # The row statistics cross HBM one value a row, lane-dense.
-    lse = lse.astype(jnp.float32)[:, None]                 # [BH, 1, S]
-    delta = delta[:, None]
+    # The row statistics cross HBM one float32 a row, lane-dense.
+    lse, dlse = lse.astype(jnp.float32), dlse.astype(jnp.float32)
+    delta_scratch = pltpu.VMEM((g, bq), jnp.float32)
     kernel_args = dict(sm_scale=sm_scale, causal=causal, plan=plan,
                        valid_len=valid_len, interpret=interpret)
 
     # dq: q-block fixed per outer step, k/v stream on the inner grid dim.
-    q_by_i = pl.BlockSpec((None, bq, d), lambda b, i, j, *lens: (b, i, 0))
-    kv_by_j = _streamed_kv_spec(d, causal, plan, valid_len)
-    row_by_i = _row_stat_spec(bq, lambda b, i, j, *lens: (b, 0, i))
+    q_by_i = _operand_spec(bq, plan, n_col, _by_i)
+    kv_by_j = _operand_spec(bk, plan, n_col,
+                            _streamed_k(causal, plan, valid_len))
+    row_by_i = _row_stat_spec(plan, _by_i)
     dq = _kernel_call(
         _mha_bwd_dq_kernel, lens, **kernel_args,
-        grid=(bh, s // bq, s // bk),
-        in_specs=[q_by_i, kv_by_j, kv_by_j, q_by_i, row_by_i, row_by_i],
+        grid=(n * n_col, s // bq, s // bk),
+        in_specs=[q_by_i, kv_by_j, kv_by_j, q_by_i, q_by_i, row_by_i,
+                  row_by_i],
         out_specs=q_by_i,
-        out_shape=_out_struct((bh, s, d), qb.dtype, qb),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-    )(qb, kb, vb, dob, lse, delta)
+        out_shape=_out_struct(qb.shape, qb.dtype, qb),
+        scratch_shapes=[pltpu.VMEM((bq, plan.lanes), jnp.float32),
+                        delta_scratch],
+    )(qb, kb, vb, dob, ob, lse, dlse)
 
     # dk/dv: k-block fixed per outer step, q/do/stats stream inside, from
     # the first query block that sees it to the last real one.
-    def q_index(b, i, j, lens):
+    def streamed_q(r, i, j, lens):
         first = (i * bk) // bq if causal else 0
         return jnp.minimum(jnp.maximum(j, first),
-                           (_len_at(b, lens, valid_len) - 1) // bq)
+                           (_len_at(r, lens, valid_len) - 1) // bq)
 
-    q_by_j = pl.BlockSpec((None, bq, d), lambda b, i, j, *lens: (
-        b, q_index(b, i, j, lens), 0))
-    kv_by_i = pl.BlockSpec((None, bk, d), lambda b, i, j, *lens: (b, i, 0))
-    row_by_j = _row_stat_spec(bq, lambda b, i, j, *lens: (
-        b, 0, q_index(b, i, j, lens)))
+    q_by_j = _operand_spec(bq, plan, n_col, streamed_q)
+    kv_by_i = _operand_spec(bk, plan, n_col, _by_i)
+    row_by_j = _row_stat_spec(plan, streamed_q)
     dk, dv = _kernel_call(
         _mha_bwd_dkv_kernel, lens, **kernel_args,
-        grid=(bh, s // bk, s // bq),
-        in_specs=[q_by_j, kv_by_i, kv_by_i, q_by_j, row_by_j, row_by_j],
+        grid=(n * n_col, s // bk, s // bq),
+        in_specs=[q_by_j, kv_by_i, kv_by_i, q_by_j, q_by_j, row_by_j,
+                  row_by_j],
         out_specs=[kv_by_i, kv_by_i],
-        out_shape=[_out_struct((bh, s, d), kb.dtype, kb),
-                   _out_struct((bh, s, d), vb.dtype, vb)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-    )(qb, kb, vb, dob, lse, delta)
+        out_shape=[_out_struct(kb.shape, kb.dtype, kb),
+                   _out_struct(vb.shape, vb.dtype, vb)],
+        scratch_shapes=[pltpu.VMEM((bk, plan.lanes), jnp.float32),
+                        pltpu.VMEM((bk, plan.lanes), jnp.float32),
+                        delta_scratch],
+    )(qb, kb, vb, dob, ob, lse, dlse)
     return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_bhsd_lse(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
-                    valid_len):
-    """Differentiable kernel entry over [BH, S, D] (S already padded),
-    returning ``(out, lse)`` — the pair ring attention merges across hops
-    (the public ``flash_attention`` wrapper simply discards the lse).
+def _flash_lse(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
+               valid_len):
+    """Differentiable kernel entry over operands in the kernels' layout
+    ([N, S, n_col * lanes], S already padded), returning ``(out, lse)``,
+    the pair ring attention merges across hops (the public
+    ``flash_attention`` wrapper simply discards the lse).
 
     The backward for the pair is the standard flash backward with one
     twist: dL/dS_ij gains a ``+ dlse_i * p_ij`` term, which folds into the
-    existing kernels as ``delta_i -> delta_i - dlse_i`` (both enter as
-    ``ds = p * (dp - delta)``) — no separate kernels needed.
+    existing kernels as ``delta_i -> delta_i - dlse_i`` (:func:`_delta_rows`)
+    — no separate kernels needed.
 
     ``lens`` is None (no operand: the static ``valid_len`` holds) or the
-    int32 [BH] lengths of a ``kv_lens`` call.
+    int32 lengths of a ``kv_lens`` call, one per row of the grid.
     """
-    return _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, plan, interpret,
-                           valid_len, lens)
+    return _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret,
+                      valid_len, lens)
 
 
-def _flash_bhsd_lse_fwd(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
-                        valid_len):
-    out, lse = _flash_fwd_bhsd(qb, kb, vb, sm_scale, causal, plan,
-                               interpret, valid_len, lens)
+def _flash_lse_fwd(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
+                   valid_len):
+    out, lse = _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret,
+                          valid_len, lens)
     return (out, lse), (qb, kb, vb, lens, out, lse)
 
 
-def _flash_bhsd_lse_bwd(sm_scale, causal, plan, interpret, valid_len, res,
-                        cotangents):
+def _flash_lse_bwd(sm_scale, causal, plan, interpret, valid_len, res,
+                   cotangents):
     qb, kb, vb, lens, ob, lse = res
     dob, dlse = cotangents
-    return (*_flash_bwd_bhsd(qb, kb, vb, ob, lse, dob, sm_scale, causal,
-                             plan, interpret, valid_len, dlse=dlse,
-                             lens=lens), None)
+    return (*_flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal,
+                        plan, interpret, valid_len, lens=lens), None)
 
 
-_flash_bhsd_lse.defvjp(_flash_bhsd_lse_fwd, _flash_bhsd_lse_bwd)
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
 def _kv_lens(kv_lens, batch: int, seq: int, causal: bool):
@@ -718,7 +874,8 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret, kv_lens):
             return _dense(q, k, v, causal, scale, kv_lens)
         interpret = False
     sm_scale = d ** -0.5 if scale is None else scale
-    plan = tile_plan(s, d, q.dtype.itemsize, causal, block_q, block_k)
+    plan = tile_plan(s, d, q.dtype.itemsize, causal, block_q, block_k,
+                     heads=h)
     s_pad = plan.seq_pad
     if s_pad != s:
         pad = [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]
@@ -726,15 +883,32 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret, kv_lens):
         k = jnp.pad(k, pad)
         v = jnp.pad(v, pad)
 
-    def to_bhsd(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s_pad, d)
+    if plan.lane_dense:
+        # The model's own layout: heads side by side in the minor dim.
+        def to_kernel(x):
+            return x.reshape(b, s_pad, h * d)
 
-    # One length per [batch * heads] row of the kernels' grid.
+        def from_kernel(x):
+            return x.reshape(b, s_pad, h, d)
+    else:
+        # No whole number of heads fills 128 lanes: one head a grid row,
+        # turned round under a scope that says so on the profiler's clock
+        # (docs/observability.md); no op carries it on the path above.
+        def to_kernel(x):
+            with jax.named_scope("hvd_flash_relayout"):
+                return x.transpose(0, 2, 1, 3).reshape(b * h, s_pad, d)
+
+        def from_kernel(x):
+            with jax.named_scope("hvd_flash_relayout"):
+                return x.reshape(b, h, s_pad, d).transpose(0, 2, 1, 3)
+
+    # One length per row of the kernels' grid.
     lens = (None if kv_lens is None else
-            jnp.repeat(_kv_lens(kv_lens, b, s, causal), h))
-    out, lse = _flash_bhsd_lse(to_bhsd(q), to_bhsd(k), to_bhsd(v), lens,
-                               sm_scale, causal, plan, bool(interpret), s)
-    out = out.reshape(b, h, s_pad, d).transpose(0, 2, 1, 3)[:, :s]
+            jnp.repeat(_kv_lens(kv_lens, b, s, causal),
+                       h // plan.heads_per_block))
+    out, lse = _flash_lse(to_kernel(q), to_kernel(k), to_kernel(v), lens,
+                          sm_scale, causal, plan, bool(interpret), s)
+    out = from_kernel(out)[:, :s]
     lse = lse.reshape(b, h, s_pad)[:, :, :s]
     return out, lse
 

@@ -42,7 +42,33 @@ SCHEDULES = {
     "padded-tail": ((1, 100, 2, 16), 64, 64),
     "padded-bq>bk": ((1, 75, 2, 8), 64, 16),
     "default-plan": ((1, 200, 2, 16), None, None),
+    # The block layout (PR 28).  The cases above run the fallback
+    # ([B * H, S, D]: two heads of 16 or 8 fill no lane tile) except
+    # 16x16-b2h4, four heads of 32 a block.  Lane-dense blocks of the
+    # model's [B, S, H * D]: two heads of 64 a grid step, streamed in small
+    # blocks, tail-padded, and on the default plan; one head of 128; and the
+    # fallback where no whole number of heads fills 128 lanes.
+    "g2-two-blocks": ((1, 128, 2, 64), 64, 64),
+    "g2-bq>bk-b2h4": ((2, 64, 4, 64), 32, 16),
+    "g2-padded-tail": ((1, 100, 2, 64), 64, 64),
+    "g2-default-plan": ((1, 200, 4, 64), None, None),
+    "g1-head_dim-128": ((1, 64, 2, 128), 32, 32),
+    "fallback-head_dim-80": ((1, 64, 2, 80), 32, 32),
+    "fallback-3-heads-of-64": ((1, 64, 3, 64), 32, 32),
 }
+# heads_per_block and whether the blocks are lane-dense, where not (1, False).
+LAYOUTS = {"16x16-b2h4": (4, True), "g2-two-blocks": (2, True),
+           "g2-bq>bk-b2h4": (2, True), "g2-padded-tail": (2, True),
+           "g2-default-plan": (2, True), "g1-head_dim-128": (1, True)}
+
+
+@pytest.mark.parametrize("name", SCHEDULES.keys())
+def test_schedule_cases_run_the_layout_they_say(name):
+    (_, seq, heads, head_dim), block_q, block_k = SCHEDULES[name]
+    plan = tile_plan(seq, head_dim, 4, True, block_q, block_k, heads=heads)
+    assert (plan.heads_per_block, plan.lane_dense) == LAYOUTS.get(
+        name, (1, False))
+    assert plan.lanes == plan.heads_per_block * head_dim
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -295,10 +321,19 @@ PLAN_SHAPES = {"gpt2-medium": (1024, 64), "bench": (2048, 128),
 @pytest.mark.parametrize("seq,head_dim", PLAN_SHAPES.values(),
                          ids=PLAN_SHAPES.keys())
 @pytest.mark.parametrize("itemsize", [2, 4])
-def test_tile_plan(seq, head_dim, itemsize):
+@pytest.mark.parametrize("heads", [1, 16])
+def test_tile_plan(seq, head_dim, itemsize, heads):
     """The schedule is decided at trace time from the shape alone: this is
     the record of where the large blocks engage."""
-    plan = tile_plan(seq, head_dim, itemsize, True)
+    plan = tile_plan(seq, head_dim, itemsize, True, heads=heads)
+    if heads == 1:                                   # the default
+        assert plan == tile_plan(seq, head_dim, itemsize, True)
+    # Whole heads side by side fill 128 lanes, or one head a block.
+    per_block = 128 // head_dim if heads == 16 and head_dim < 128 else 1
+    assert plan.heads_per_block == per_block
+    assert plan.lanes == per_block * head_dim
+    assert plan.lane_dense == (plan.lanes % 128 == 0)
+    assert plan.lane_dense == (heads == 16 or head_dim == 128)
     padded = -(-seq // 128) * 128
     assert plan.seq_pad == padded                    # 640 stays 640
     assert padded % plan.block_q == 0 and plan.block_q == plan.block_k
@@ -306,16 +341,45 @@ def test_tile_plan(seq, head_dim, itemsize):
     assert plan.block_k % plan.tile_k == 0 and plan.tile_k % plan.step_q == 0
     assert plan.step_q % 128 == 0 and plan.step_k % 128 == 0
     assert plan.vmem_bytes <= fa._VMEM_BUDGET < fa._VMEM_LIMIT
-    assert plan == tile_plan(seq, head_dim, itemsize, False)
-    if seq * head_dim * itemsize <= 2048 * 128 * 2:
-        assert plan.block_q == padded                # one grid step a head
+    assert plan == tile_plan(seq, head_dim, itemsize, False, heads=heads)
+    whole = fa._vmem_estimate(padded, padded, min(padded, 1024),
+                              min(padded, 256), plan.lanes, per_block,
+                              itemsize) <= fa._VMEM_BUDGET
+    if whole:
+        assert plan.block_q == padded          # one grid step a block
     else:
-        assert plan.grid_steps(1) > 1                # streams, and fits
+        assert plan.grid_steps(heads) > 1      # streams, and fits
+    # Up to 2048 x 128 lanes in bf16 with one head a block, 1024 with two
+    # (each head has a score tile of its own in flight).
+    if itemsize == 2 and seq <= (2048 if per_block == 1 else 1024):
+        assert whole
     if (seq, head_dim, itemsize) == (1024, 64, 2):
-        # 8 x 16 head-sequences: 128 grid steps a call where 128 x 128
-        # blocks took 8,192.
-        assert plan.grid_steps(128) <= 512
+        # 8 x 16 head-sequences: 128 grid steps a call (64 with two heads
+        # a block) where 128 x 128 blocks took 8,192.
+        assert plan.grid_steps(128) == 128 // per_block
         assert (plan.tile_q, plan.step_k) == (1024, 256)
+
+
+def test_vmem_estimate_counts_the_block_as_it_lies():
+    """A 64-wide block is padded to the lane count in VMEM, a 128-lane
+    block of two heads is not, and holds two heads' score tiles: the same
+    operand bytes, twice the step."""
+    one = fa._vmem_estimate(1024, 1024, 1024, 256, 64, 1, 2)
+    two = fa._vmem_estimate(1024, 1024, 1024, 256, 128, 2, 2)
+    step = 1024 * 256 * (4 * 4 + 2 * 2)
+    assert two - one == step
+    assert fa._vmem_estimate(1024, 1024, 1024, 256, 128, 1, 2) == one
+    assert tile_plan(1024, 64, 2, True, heads=16).vmem_bytes == two
+    # 80 lanes take the room of 128.
+    assert fa._vmem_estimate(512, 512, 512, 256, 80, 1, 2) == \
+        fa._vmem_estimate(512, 512, 512, 256, 128, 1, 2)
+
+
+@pytest.mark.parametrize("head_dim,heads,expected", [
+    (64, 16, 2), (64, 12, 2), (32, 4, 4), (128, 8, 1), (256, 2, 1),
+    (64, 3, 1), (64, 1, 1), (80, 16, 1), (16, 2, 1), (16, 8, 8)])
+def test_heads_per_block(head_dim, heads, expected):
+    assert fa.heads_per_block(head_dim, heads) == expected
 
 
 def test_tile_plan_explicit_block_wins():

@@ -83,7 +83,15 @@ def test_kv_lens_gradients_match_masked_attention(bert_phase2, impl):
             assert not np.asarray(g)[b, n:].any()
 
 
-def test_kv_lens_streamed_blocks_and_small_steps(monkeypatch):
+# [heads, head_dim]: the fallback layout (one head a grid row), and the
+# model's own with two and four heads a grid step, which share the row's one
+# length (``bert_phase2`` above is two heads of 64 on the default plan).
+HEADS = {"fallback-2x16": (2, 16), "g2-2x64": (2, 64), "g2-4x64": (4, 64),
+         "g4-4x32": (4, 32), "fallback-3x64": (3, 64)}
+
+
+@pytest.mark.parametrize("heads", HEADS.values(), ids=HEADS.keys())
+def test_kv_lens_streamed_blocks_and_small_steps(monkeypatch, heads):
     """The same mask where the plan streams: two grid blocks a sequence,
     tiles of 32 rows in steps of 8, lengths that end inside a step, at a
     block's edge and inside the second block, on a sequence that is itself
@@ -91,7 +99,7 @@ def test_kv_lens_streamed_blocks_and_small_steps(monkeypatch):
     monkeypatch.setattr(fa, "_MAX_TILE", 32)
     monkeypatch.setattr(fa, "_MAX_STEP", 8)
     lens = jnp.asarray([1, 7, 64, 65, 93, 100], jnp.int32)
-    q, k, v, w = _qkvw((6, 100, 2, 16), seed=22)
+    q, k, v, w = _qkvw((6, 100, *heads), seed=22)
 
     def loss(attend, q, k, v):
         out = attend(q, k, v)

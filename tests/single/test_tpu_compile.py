@@ -10,8 +10,10 @@ not in conftest.py) and every compile happens in the test's own process,
 because one process at a time may load the TPU's library.
 """
 
+import dataclasses
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -105,6 +107,100 @@ def test_flash_with_a_key_length_per_sequence_compiles(one_chip):
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
                           *_qkv((32, 512, 16, 64), one_chip), lens)
     assert text.count("tpu_custom_call") >= 3
+
+
+# One transformer block of each model family at its benchmark cell's shape:
+# (model family, batch, seq, heads, head_dim, the attention module's scope).
+BLOCKS = {"gpt2-medium": ("gpt", 8, 1024, 16, 64, "attn"),
+          "bert-large": ("bert", 32, 512, 16, 64, "attention"),
+          "head_dim-80": ("gpt", 8, 1024, 16, 80, "attn")}
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%[\w.\-]+ = (\(?\w+\[[\d,]*\].*?) ([\w\-]+)\(")
+
+
+def _block_grad_text(family, batch, seq, heads, head_dim, one_chip):
+    """The compiled forward + backward of one block (the model's own
+    module, bf16, kernels on) for the described chip."""
+    from horovod_tpu import models
+    from horovod_tpu.models import bert, gpt
+
+    hidden = heads * head_dim
+    x = jax.ShapeDtypeStruct((batch, seq, hidden), jnp.bfloat16,
+                             sharding=one_chip)
+    if family == "gpt":
+        block = gpt.GPTBlock(models.GPTConfig(
+            hidden_size=hidden, num_heads=heads, max_seq_len=seq,
+            use_flash=True, dtype=jnp.bfloat16))
+        args = (x,)
+
+        def call(method, variables_or_key, x):
+            return method(variables_or_key, x)
+    else:
+        block = bert.TransformerLayer(dataclasses.replace(
+            models.BERT_LARGE, hidden_size=hidden, num_heads=heads,
+            use_flash=True))
+        args = (x, jax.ShapeDtypeStruct((batch,), jnp.int32,
+                                        sharding=one_chip))
+
+        def call(method, variables_or_key, x, lens):
+            return method(variables_or_key, x, lengths=lens)
+
+    shapes = jax.eval_shape(functools.partial(
+        call, block.init, jax.random.PRNGKey(0)), *args)
+    params = jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=one_chip), shapes)
+
+    def loss(params, *args):
+        return jnp.sum(call(block.apply, params, *args)
+                       .astype(jnp.float32) ** 2)
+
+    return _compiled_text(jax.grad(loss, argnums=(0, 1)), params, *args)
+
+
+def _entry_instructions(text, scope):
+    """(opcode, result type, op_name) of the entry computation's
+    instructions whose scope holds ``/<scope>/``."""
+    entry = text[text.index("\nENTRY"):]
+    for line in entry.splitlines():
+        found = _INSTRUCTION.match(line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if found and name and f"/{scope}/" in name.group(1):
+            yield found.group(2), found.group(1), name.group(1)
+
+
+@pytest.mark.parametrize("block", BLOCKS.values(), ids=BLOCKS.keys())
+def test_block_takes_the_kernels_without_relayout(one_chip, monkeypatch,
+                                                  block):
+    """What PR 28 bought, held without a chip: where whole heads fill 128
+    lanes the three kernels read and write the model's own [B, S, H * D]
+    tensors, so no activation is copied or transposed under the attention
+    scope (the parent compiled eight such copies a layer).  At head_dim 80
+    the fallback's relayouts are there and say who they are."""
+    # The models leave interpret=None, and the dispatch then asks which
+    # backend is attached; here that is the CPU, so steer it in the test.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    family, batch, seq, heads, head_dim, scope = block
+    text = _block_grad_text(family, batch, seq, heads, head_dim, one_chip)
+    under = list(_entry_instructions(text, scope))
+    calls = [line for line in text[text.index("\nENTRY"):].splitlines()
+             if "tpu_custom_call" in line]
+    # forward (out, lse), dq one array, dkv a tuple of two: what the
+    # benchmark's flash_dq_ms / flash_dkv_ms part the backward's calls by.
+    returns = sorted(len(re.findall(r"\w+\[[\d,]+\]", _INSTRUCTION.match(
+        line).group(1))) for line in calls)
+    assert returns == [1, 2, 2], calls
+    activation = batch * seq * heads * head_dim
+    relayouts = [
+        (op, result, name) for op, result, name in under
+        if op in ("copy", "transpose") and np.prod(
+            [int(n) for n in re.search(r"\[([\d,]+)\]", result)
+             .group(1).split(",")]) >= activation]
+    if head_dim == 64:
+        assert not relayouts, relayouts
+    else:
+        assert len(relayouts) >= 8
+        marked = [r for r in relayouts if "hvd_flash_relayout" in r[2]]
+        assert len(marked) >= 8, relayouts
 
 
 @pytest.mark.parametrize("codec", ["int8", "int4", "int8g"])

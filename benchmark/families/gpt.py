@@ -10,29 +10,33 @@ from __future__ import annotations
 
 from benchmark import common, flops
 
+# How a limit is set: the rule at the head of families/bert.py, held on the
+# readings in benchmark/testdata/check_readings.json.  All three are kept
+# where PR 23's readings put them.
+#
 # (a) First loss, system (bf16 activations, flash kernels) against the same
-# module in float32 with dense attention at "highest" matmul precision.  The
-# chip read 1.0e-6 to 1.5e-5 over 29 runs of the two cells (PERF.md, PR 23);
-# the bound is ten times the largest.  At initialisation on random targets
-# this loss is ln V + sigma^2/2 whatever the blocks below ``ln_f`` compute: it
-# holds the embedding, the head and the float32 log-softmax, and no more.  On
-# the CPU, zeroing every block's attention output moved it by 5.9e-4 and full
-# bf16 by 6.6e-6 (REVIEW of PR 23): the kernels are held by (b).
+# module in float32 with dense attention at "highest" matmul precision.  At
+# initialisation on random targets this loss is ln V + sigma^2/2 whatever the
+# blocks below ``ln_f`` compute: it holds the embedding, the head and the
+# float32 log-softmax, and no more; the kernels are held by (b).  Sound: 1.0e-6
+# to 1.5e-5 over 29 runs of the two cells (PR 23), 1.7e-5 since (PR 29).
+# Fault (CPU, review of PR 23): every block's attention output zeroed moves it
+# by 5.9e-4 (full bf16 by 6.6e-6: it tells no precision from another).
 TOL_FIRST_LOSS = 2e-4
 # (b) The first moment after one step is (1 - b1) x the exchanged gradient:
 # bf16 backward through all layers and the flash dq/dkv kernels against
-# float32 dense attention, as an L2 error over the leaf.  The chip read
-# 4.8e-3 (ln_f scale) to 1.5e-2 (first qkv kernel) in both cells alike; the
-# bound is three times the largest.  It holds the scale (a sum in place of a
-# mean reads n - 1 here) and the kernels (a leaf of the first block's
-# gradient has passed through every layer's dq and dkv; without the causal
-# mask or with a wrong softmax scale it reads near 1).
+# float32 dense attention, as an L2 error over the leaf.  Sound: 4.8e-3 (ln_f
+# scale) to 1.5e-2 (first qkv kernel) in both cells alike.  Faults, by the
+# measure: a sum over the chips in place of a mean reads n - 1; a leaf of the
+# first block's gradient has passed through every layer's dq and dkv, and
+# without the causal mask or with a wrong softmax scale it reads near 1.
 TOL_FIRST_MOMENT = 5e-2
 # (b) The parameters themselves after one AdamW step move by lr x g/(|g|+eps):
 # the sign of the gradient, not its size.  Elements whose gradient is small
 # against bf16's noise take either sign, each such element contributing 2 lr,
-# so whole leaves agree only to some tenths (the chip read 6e-6 to 0.15); a
-# gradient of one shard alone or of the wrong sign reads near 1 or 2.
+# so whole leaves agree only to some tenths.  Sound: 6e-6 to 0.15.  Fault, by
+# the measure: a gradient of one shard alone or of the wrong sign reads near
+# 1 or 2.
 TOL_PARAM_DELTA = 0.5
 # The micro-batch of the reference: what one chip holds in float32 with
 # dense attention and one block rematerialized at a time.

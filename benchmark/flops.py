@@ -107,8 +107,8 @@ def flash_least_seconds(batch: int, heads: int, seq: int, head_dim: int,
     training step of ``layers`` attention layers, and which peak bounds it.
 
     Operations: 2 x S x S x D per matmul, halved under a causal mask (the
-    lower triangle; the kernels compute whole 128 x 128 tiles on the
-    diagonal, which is their cost, not the algorithm's).  Bytes: each array
+    lower triangle; what a kernel computes above the diagonal because its
+    tiles are rectangles is its cost, not the algorithm's).  Bytes: each array
     once, plus the float32 row statistics (lse, delta) where they cross the
     kernel boundary.  Per kernel the bound is the larger of operations over
     peak FLOP/s and bytes over peak bytes/s; the step's least time is the sum.

@@ -8,8 +8,10 @@ from its entry in BENCHMARK.json and the files that entry names
 ``traffic/<traffic>.json`` -> ``traffic.py``), weights and batches made on
 the device from ``--seed``, the step compiled ahead of time, the correctness
 pass, the warm-up, then the measured window.  The last line of stdout is the
-result: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` (and
-``breakdown`` with ``--trace 1``).  Earlier lines are JSON notes.
+result: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
+(``breakdown`` with ``--trace 1``) and, last, ``checks``: every number that
+``correct`` compared beside its limit, the refused ones at the end and marked.
+The same lines close standard error.  Earlier lines are JSON notes.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits non-zero
 and prints no result: there is no CPU fallback.  ``--rehearse`` runs the
@@ -29,6 +31,7 @@ import importlib  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import statistics  # noqa: E402
 import sys  # noqa: E402
@@ -283,6 +286,44 @@ def memory_peak_bytes(stats: list) -> int:
 
 
 # ---------------------------------------------------------------------------
+# The result line
+# ---------------------------------------------------------------------------
+
+
+def short_name(check: str) -> str:
+    """``first_moment['params']['nsp_head']['kernel']`` ->
+    ``first_moment.nsp_head.kernel``: a leaf's path as a plain name."""
+    return re.sub(r"\['([^']+)'\]", r".\1", check.replace("['params']", ""))
+
+
+def compared(checks: list) -> dict:
+    """Each check as ``{"value", "limit"}`` (sound below the limit),
+    ``{"value", "least"}`` (sound from it upward; a check that is only true
+    or false reads 1 or 0 against 1), the refused ones last with ``"ok":
+    false``: what a record that keeps only a line's end still holds."""
+    out = {}
+    for c in sorted(checks, key=lambda c: not c["ok"]):
+        entry = ({"value": c["value"], "limit": c["tol"]} if "tol" in c else
+                 {"value": c.get("value", int(c["ok"])),
+                  "least": c.get("least", 1)})
+        out[short_name(c["name"])] = entry if c["ok"] else {**entry,
+                                                            "ok": False}
+    return out
+
+
+def result_line(checks: list, attempted: int, failed: int, metrics: dict,
+                device: dict, breakdown=None) -> dict:
+    """The last line of a run.  ``checks`` comes last: the driver reads the
+    keys before it and keeps the line's numbers when a run is refused."""
+    result = {"correct": all(c["ok"] for c in checks), "attempted": attempted,
+              "failed": failed, "metrics": metrics, "device": device}
+    if breakdown is not None:
+        result["breakdown"] = breakdown
+    result["checks"] = compared(checks)
+    return result
+
+
+# ---------------------------------------------------------------------------
 # main
 # ---------------------------------------------------------------------------
 
@@ -490,9 +531,8 @@ def main(argv=None) -> int:
     checks.append({"name": "losses_finite_and_falling", "ok": bool(
         failed == 0 and math.isfinite(first_loss) and finite
         and finite[-1] < first_loss),
-        "first": first_loss, "last": finite[-1] if finite else None})
-    correct = all(c["ok"] for c in checks)
-    if not correct:
+        "value": finite[-1] if finite else None, "tol": first_loss})
+    if not all(c["ok"] for c in checks):
         note("failed_checks", checks=[c for c in checks if not c["ok"]])
 
     device = up["device"]
@@ -511,10 +551,9 @@ def main(argv=None) -> int:
         else:
             metrics = timed_metrics(args, spec, up, run, peaks)
 
-    result = {"correct": correct, "attempted": attempted, "failed": failed,
-              "metrics": metrics, "device": device}
-    if breakdown is not None:
-        result["breakdown"] = breakdown
+    result = result_line(checks, attempted, failed, metrics, device, breakdown)
+    for name, entry in result["checks"].items():
+        print(f"check {name}: {json.dumps(entry)}", file=sys.stderr)
     print(json.dumps(result), flush=True)
     return 0
 

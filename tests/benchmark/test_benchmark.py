@@ -991,7 +991,11 @@ def test_rehearsal_prints_the_contract_keys_and_no_metric(cell, trace,
                  "--trace", str(trace), "--rehearse"], tmp_path, devices)
     assert done.returncode == 0, done.stderr[-2000:]
     result = json.loads(done.stdout.strip().splitlines()[-1])
-    assert set(result) == RESULT_KEYS
+    # The keys the driver reads, then every compared number and its limit.
+    assert set(result) == RESULT_KEYS | {"checks"}
+    assert list(result)[-1] == "checks" and all(
+        {"value", "limit"} <= set(c) or {"value", "least"} <= set(c)
+        for c in result["checks"].values())
     assert result["correct"] is True, done.stdout[-3000:]
     assert result["attempted"] >= 2 and result["failed"] == 0
     # A CPU number is never written under a device metric's name.

@@ -1,16 +1,22 @@
-"""Bring-up smoke: the trainer's main path, once, on the TPU.
+"""Bring-up smoke: what only a run on the TPU can show, once.
 
     python chip_smoke.py             one chip, one process, every phase below
-    python chip_smoke.py --chips 4   four chips: launch_np4 and spmd_dp4 only
+    python chip_smoke.py --chips 4   four chips: launch_np4, then device(4)
 
-Default mode drives ``hvd.init()`` -> ``hvd.DistributedOptimizer`` -> a
-jitted ``shard_map`` step over the ``hvd`` mesh axis on ResNet-50 and on
-GPT-2-small with the flash kernels, the Pallas kernels alone against their
-references, and the eager spine (C++ core -> device plane) on device-resident
-arrays.  Each phase prints one JSON line; the last line of stdout is
-``{"ok": true, "device": {"platform", "kind", "count"}}``.  Any phase that
-fails raises, and the script exits non-zero.  There is no CPU mode: without
-a TPU it exits before the first phase.
+One chip: the device JAX found, a clean build of the C++ core and
+``hvd.init()`` on it, the Pallas kernels alone against their references (at
+layouts no benchmark cell runs), and the eager spine (C++ core -> device
+plane) on device-resident arrays.  Four chips: one process a chip through
+``runner/launch.py --jax-distributed``.  Each phase prints one JSON line; the
+last line of stdout is ``{"ok": true, "device": {"platform", "kind",
+"count"}}``.  Any phase that fails raises, and the script exits non-zero.
+There is no CPU mode: without a TPU it exits before the first phase.
+
+The training steps it once drove are the benchmark's cells now
+(``python benchmark/run.py --workload <cell>``), at published widths and held
+to stricter checks on every PR: ``resnet50_train`` and ``sync`` went to
+``resnet50-1chip``, ``gpt_flash_train`` to ``gpt2m-1chip``, ``spmd_dp4`` to
+``gpt2m-dp4``.
 
 The phases are plain functions with size arguments, so
 tests/single/test_chip_smoke.py calls them tiny on the CPU mesh.
@@ -32,13 +38,10 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 
 # Stated tolerances (max |a-b| / max |b|).  f32 inputs still go through the
 # MXU's default bf16 passes in kernel and reference alike; bf16 carries 8
-# bits of mantissa per operation, and a 12-layer backward compounds them.
+# bits of mantissa per operation.
 TOL_F32 = 6e-3
 TOL_BF16_FWD = 2e-2
 TOL_BF16_BWD = 4e-2
-TOL_GPT_LOSS = 1e-2
-TOL_GPT_GRAD = 5e-2
-TOL_DP4_LOSS = 2e-2
 
 
 def emit(phase: str, **kv) -> dict:
@@ -111,221 +114,6 @@ def native_core(clean: bool = True) -> dict:
     assert isinstance(core, _core.NativeCore), type(core)
     return emit("native_core", core=type(core).__name__, clean_build=clean,
                 build_s=round(build_s, 2), size=hvd.size())
-
-
-def _hvd_mesh(devices):
-    import numpy as np
-    from jax.sharding import Mesh
-
-    return Mesh(np.asarray(devices), ("hvd",))
-
-
-def _resnet_run(model, devices, batch: int, image: int):
-    """The example's own step (examples/jax_cnn_benchmark.build_train_step)
-    compiled ahead of time for a fixed seeded batch."""
-    import jax
-    import optax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    import horovod_tpu as hvd
-    from examples.jax_cnn_benchmark import build_train_step
-
-    mesh = _hvd_mesh(devices)
-    k_img, k_lbl = jax.random.split(jax.random.PRNGKey(0))
-    images = jax.random.normal(k_img, (batch, image, image, 3), model.dtype)
-    labels = jax.random.randint(k_lbl, (batch,), 0, model.num_classes)
-    data = NamedSharding(mesh, P("hvd"))
-    images, labels = jax.device_put((images, labels), data)
-    tx = hvd.DistributedOptimizer(optax.sgd(0.01, momentum=0.9),
-                                  axis_name="hvd")
-    step, _, state = build_train_step(model, mesh, images, labels, tx)
-    state = jax.device_put(state, NamedSharding(mesh, P()))
-    t0 = time.perf_counter()
-    compiled = step.lower(*state, images, labels).compile()
-    compile_s = time.perf_counter() - t0
-    return {"step": compiled, "state": state, "images": images,
-            "labels": labels, "compile_s": compile_s}
-
-
-def _run_steps(run: dict, n: int) -> list:
-    """n steps through the donated state; losses read back one by one."""
-    losses = []
-    for _ in range(n):
-        *run["state"], loss = run["step"](*run["state"], run["images"],
-                                          run["labels"])
-        losses.append(float(loss))
-    return losses
-
-
-def resnet50_train(model=None, devices=None, batch: int = 256,
-                   image: int = 224, steps: int = 5):
-    """ResNet-50 as published through DistributedOptimizer: one compile,
-    ``steps`` steps on a fixed batch, loss finite and falling."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from horovod_tpu import models
-
-    devices = devices or jax.devices()
-    if model is None:
-        model = models.ResNet50(num_classes=1000, dtype=jnp.bfloat16,
-                                bn_axis_name="hvd")
-    run = _resnet_run(model, devices, batch, image)
-    t0 = time.perf_counter()
-    losses = _run_steps(run, steps)
-    step_ms = (time.perf_counter() - t0) / steps * 1e3
-    assert np.all(np.isfinite(losses)), losses
-    assert losses[-1] < losses[0], f"loss did not fall: {losses}"
-    stats = devices[0].memory_stats() or {}
-    report = emit("resnet50_train", batch=batch, image=image, steps=steps,
-                  losses=losses, compile_s=round(run["compile_s"], 2),
-                  step_ms_info=round(step_ms, 2),
-                  peak_bytes_in_use=stats.get("peak_bytes_in_use"))
-    return report, run
-
-
-def sync(run: dict, n: int = 10) -> dict:
-    """Does ``block_until_ready`` wait for the device?  Time ``n`` chained
-    steps three ways: enqueue only, ended by block_until_ready, ended by a
-    scalar readback; and how long a readback still takes after
-    block_until_ready returned."""
-    import jax
-
-    def chain():
-        loss = None
-        for _ in range(n):
-            *run["state"], loss = run["step"](*run["state"], run["images"],
-                                              run["labels"])
-        return loss
-
-    float(chain())  # settle
-    t0 = time.perf_counter()
-    loss = chain()
-    enqueue_ms = (time.perf_counter() - t0) * 1e3
-    float(loss)
-    t0 = time.perf_counter()
-    loss = chain()
-    jax.block_until_ready(loss)
-    bur_ms = (time.perf_counter() - t0) * 1e3
-    t0 = time.perf_counter()
-    float(loss)
-    after_bur_ms = (time.perf_counter() - t0) * 1e3
-    t0 = time.perf_counter()
-    float(chain())
-    readback_ms = (time.perf_counter() - t0) * 1e3
-    assert min(bur_ms, readback_ms) > 0
-    # It waits if the window it ends is as long as the one a readback ends,
-    # and nothing is left for a readback to wait for afterwards.
-    waits = bur_ms > 0.9 * readback_ms and after_bur_ms < 0.1 * readback_ms
-    return emit("sync", steps=n, enqueue_only_ms=round(enqueue_ms, 2),
-                block_until_ready_ms=round(bur_ms, 2),
-                scalar_readback_ms=round(readback_ms, 2),
-                readback_after_block_until_ready_ms=round(after_bur_ms, 3),
-                block_until_ready_waits=bool(waits))
-
-
-def _gpt_step(cfg, mesh):
-    """AdamW through DistributedOptimizer over the hvd axis; the step also
-    hands back the (reduced) gradient of the token embedding."""
-    import jax
-    import optax
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    import horovod_tpu as hvd
-    from horovod_tpu import models
-
-    model = models.GPT(cfg)
-    tx = hvd.DistributedOptimizer(optax.adamw(3e-4), axis_name="hvd")
-
-    def train_step(params, opt_state, ids):
-        loss, grads = jax.value_and_grad(
-            lambda p: models.lm_loss(model.apply(p, ids), ids))(params)
-        wte_grad = hvd.allreduce(grads["params"]["wte"]["embedding"],
-                                 axis_name="hvd")
-        updates, opt_state = tx.update(grads, opt_state, params)
-        return (optax.apply_updates(params, updates), opt_state,
-                hvd.allreduce(loss, axis_name="hvd"), wte_grad)
-
-    step = jax.jit(shard_map(
-        train_step, mesh=mesh, in_specs=(P(), P(), P("hvd")),
-        out_specs=(P(), P(), P(), P())), donate_argnums=(0, 1))
-    return step, tx
-
-
-def _gpt_run(cfg, devices, ids, params):
-    """The step compiled ahead of time, and its own copy of the state."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    mesh = _hvd_mesh(devices)
-    step, tx = _gpt_step(cfg, mesh)
-    rep = NamedSharding(mesh, P())
-    params = jax.device_put(jax.tree_util.tree_map(lambda x: x.copy(),
-                                                   params), rep)
-    opt_state = jax.device_put(tx.init(params), rep)
-    ids = jax.device_put(ids, NamedSharding(mesh, P("hvd")))
-    t0 = time.perf_counter()
-    compiled = step.lower(params, opt_state, ids).compile()
-    return compiled, (params, opt_state, ids), time.perf_counter() - t0
-
-
-def gpt_flash_train(cfg=None, devices=None, batch: int = 8, steps: int = 3,
-                    expect_kernel: bool = True) -> dict:
-    """GPT-2-small, flash kernels in the compiled step; its first loss and
-    token-embedding gradient against the dense path on the same weights."""
-    import dataclasses
-
-    import jax
-    import numpy as np
-
-    from horovod_tpu import models
-
-    devices = devices or jax.devices()
-    cfg = cfg or models.GPT_SMALL
-    assert cfg.use_flash
-    ids = jax.random.randint(jax.random.PRNGKey(0), (batch, cfg.max_seq_len),
-                             0, cfg.vocab_size)
-    params = jax.jit(lambda: models.GPT(cfg).init(
-        jax.random.PRNGKey(1), ids[:1, :32]))()
-
-    flash, (p, s, x), compile_s = _gpt_run(cfg, devices, ids, params)
-    n_kernels = flash.as_text().count("tpu_custom_call")
-    if expect_kernel:
-        # forward, dq and dkv per layer: the Pallas kernels, not the dense
-        # fallback, are in the program.
-        assert n_kernels >= 3 * cfg.num_layers, n_kernels
-    losses, wte_flash = [], None
-    t0 = time.perf_counter()
-    for i in range(steps):
-        p, s, loss, g = flash(p, s, x)
-        losses.append(float(loss))
-        if i == 0:
-            wte_flash = np.asarray(g, np.float32)
-    step_ms = (time.perf_counter() - t0) / steps * 1e3
-    assert np.all(np.isfinite(losses)), losses
-    assert np.all(np.isfinite(wte_flash))
-    del p, s, flash
-
-    dense_cfg = dataclasses.replace(cfg, use_flash=False)
-    dense, (p, s, x), dense_compile_s = _gpt_run(dense_cfg, devices, ids,
-                                                 params)
-    _, _, dense_loss, dense_g = dense(p, s, x)
-    checks = []
-    _check(checks, "first_loss", losses[0], float(dense_loss), TOL_GPT_LOSS)
-    _check(checks, "wte_grad", wte_flash, dense_g, TOL_GPT_GRAD)
-    stats = devices[0].memory_stats() or {}
-    report = emit("gpt_flash_train", batch=batch, seq=cfg.max_seq_len,
-                  layers=cfg.num_layers, steps=steps, losses=losses,
-                  dense_first_loss=float(dense_loss),
-                  tpu_custom_calls=n_kernels, checks=checks,
-                  compile_s=round(compile_s, 2),
-                  dense_compile_s=round(dense_compile_s, 2),
-                  step_ms_info=round(step_ms, 2),
-                  peak_bytes_in_use=stats.get("peak_bytes_in_use"))
-    _raise_on_failed("gpt_flash_train", checks)
-    return report
 
 
 def _qkv(b, s, h, d, dtype, key):
@@ -576,58 +364,13 @@ def launch_np4(np_workers: int = 4, timeout: float = 600.0) -> dict:
     return emit("launch_np4", workers=workers)
 
 
-def spmd_dp4(model=None, devices=None, batch: int = 256, image: int = 224,
-             steps: int = 3) -> dict:
-    """The ResNet step on a 4-device hvd mesh against the same images on a
-    one-device mesh: same losses, parameters on four chips, all-reduces in
-    the compiled module."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from horovod_tpu import models
-
-    devices = devices or jax.devices()
-    assert len(devices) == 4, devices
-    if model is None:
-        model = models.ResNet50(num_classes=1000, dtype=jnp.bfloat16,
-                                bn_axis_name="hvd")
-    dp = _resnet_run(model, devices, batch, image)
-    text = dp["step"].as_text()
-    n_allreduce = text.count(" all-reduce(") + text.count(" all-reduce-start(")
-    assert n_allreduce > 0, "no all-reduce in the 4-device module"
-    dp_losses = _run_steps(dp, steps)
-    leaves = jax.tree_util.tree_leaves(dp["state"][0])
-    on = {s.device for leaf in leaves for s in leaf.addressable_shards}
-    assert on == set(devices), (on, devices)
-    assert dp["images"].sharding.shard_shape(
-        dp["images"].shape)[0] == batch // 4
-    dp_compile_s = dp.pop("compile_s")
-    del dp
-
-    one = _resnet_run(model, devices[:1], batch, image)
-    one_losses = _run_steps(one, steps)
-    assert np.all(np.isfinite(dp_losses + one_losses))
-    checks = []
-    for i, (a, b) in enumerate(zip(dp_losses, one_losses)):
-        _check(checks, f"loss[{i}]", a, b, TOL_DP4_LOSS)
-    report = emit("spmd_dp4", batch=batch, per_chip=batch // 4, steps=steps,
-                  dp4_losses=dp_losses, one_device_losses=one_losses,
-                  all_reduce_ops=n_allreduce,
-                  param_devices=sorted(d.id for d in on), checks=checks,
-                  compile_s=round(dp_compile_s, 2),
-                  one_device_compile_s=round(one["compile_s"], 2))
-    _raise_on_failed("spmd_dp4", checks)
-    return report
-
-
 # ---------------------------------------------------------------------------
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: run launch_np4 and spmd_dp4 and nothing else")
+                    help="4: run launch_np4 and nothing else")
     ap.add_argument("--worker", action="store_true",
                     help="internal: one launch_np4 worker")
     args = ap.parse_args(argv)
@@ -641,17 +384,9 @@ def main(argv=None) -> int:
     if args.chips == 4:
         launch_np4()
         info = device(expect_count=4)
-        import horovod_tpu as hvd
-
-        hvd.init()
-        spmd_dp4()
     else:
         info = device()
         native_core()
-        _, run = resnet50_train()
-        sync(run)
-        del run
-        gpt_flash_train()
         kernels()
         eager()
     print(json.dumps({"ok": True, "device": info}), flush=True)

@@ -47,7 +47,6 @@ def build_train_step(model, mesh, images, labels, tx, init_opt_state=True):
     is the per-shard function, for callers that put it in a loop of their
     own.  ``opt_state`` is None when ``init_opt_state`` is false (sharded
     optimizer states are built on the mesh, inside the caller's shard_map).
-    bench.py and chip_smoke.py drive this same function.
     """
     variables = jax.jit(lambda: model.init(
         jax.random.PRNGKey(0), jnp.zeros((2, *images.shape[1:]), images.dtype),

@@ -25,7 +25,8 @@ log = get_logger()
 
 # Wire signing lives with the other launcher security utilities; re-exported
 # here because the worker-side protocol uses it too.
-from ..runner.util import signed_dumps, verified_loads  # noqa: F401,E402
+from ..runner.util import (find_free_port, signed_dumps,  # noqa: F401,E402
+                           verified_loads)
 
 
 class NotificationManager:
@@ -180,18 +181,10 @@ class ElasticCoordinatorClient:
         jax.distributed coordinator and (in autopilot mode) the policy
         listener bind here, and only a local probe proves a port is
         actually free (the driver may be a different machine)."""
-        socks = []
         try:
-            for _ in range(3):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.bind(("0.0.0.0", 0))
-                socks.append(s)   # hold open so the probed ports are distinct
-            ports = [s.getsockname()[1] for s in socks]
+            ports = [find_free_port("0.0.0.0") for _ in range(3)]
         except OSError:
             ports = []
-        finally:
-            for s in socks:
-                s.close()
         self._send({"type": "ready", "ports": ports})
 
 

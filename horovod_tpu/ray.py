@@ -260,8 +260,6 @@ class ElasticRayExecutor:
 
 
 def _free_port() -> int:
-    s = socket.socket()
-    s.bind(("", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
+    from .runner.util import find_free_port
+
+    return find_free_port("0.0.0.0")

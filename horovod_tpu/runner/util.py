@@ -2,10 +2,12 @@
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
 import shlex
 import socket
+import threading
 from typing import List, Optional, Sequence
 
 
@@ -59,12 +61,69 @@ def assign_ranks(hosts: List[HostSlots], np_: int):
     return assignments
 
 
+# find_free_port's candidates start here: below it live other services'
+# registered ports.  Successive candidates lie a stride apart, as the kernel's
+# are strangers to each other: a caller that derives a port of its own from
+# one it was given (tests/integration/test_jax_distributed.py takes port + 1
+# and port + 2 for its re-inits) must not land on the next one handed out.
+# The cursor is keyed by pid so that a forked child starts a walk of its own.
+# A port handed out stays claimed (an abstract unix socket named for it, gone
+# with its process) for this process's next 64 calls, or until the process
+# ends.
+_PORT_FLOOR = 10000
+_PORT_STRIDE = 7
+_port_cursor: dict = {}
+_port_claims: collections.deque = collections.deque(maxlen=64)
+_port_lock = threading.Lock()
+
+
+def _ephemeral_low() -> int:
+    """Where the range begins that the kernel draws from for ``bind(0)`` and
+    for every outgoing connection."""
+    try:
+        with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
+            return int(f.read().split()[0])
+    except (OSError, ValueError, IndexError):
+        return 32768
+
+
+def _claim_port(addr: str, port: int) -> bool:
+    """Nothing is bound to ``port`` on ``addr``, and no live process of this
+    host was handed it by ``find_free_port``."""
+    claim = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+    try:
+        claim.bind("\0horovod_tpu.port.%d" % port)
+        with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as probe:
+            probe.bind((addr, port))
+    except OSError:
+        claim.close()
+        return False
+    _port_claims.append(claim)
+    return True
+
+
 def find_free_port(addr: str = "127.0.0.1") -> int:
-    s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-    s.bind((addr, 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
+    """A port on ``addr`` for a listener that starts seconds later (its
+    process imports jax first).  Nothing may be given the port meanwhile, so
+    it lies below the kernel's ephemeral range, where no ``bind(0)`` and no
+    outgoing connection on this host can land, and the only other taker,
+    another caller of this function, is held off by the claim.  The walk
+    starts at a point spread by the process id and every call goes on where
+    the last one stopped, so callers seldom meet at all."""
+    span = _ephemeral_low() - _PORT_FLOOR
+    pid = os.getpid()
+    with _port_lock:
+        for _ in range(max(span, 0)):
+            # The golden-ratio sequence: neighbouring pids start far apart.
+            at = _port_cursor.get(
+                pid, (pid * 2654435761 % 2**32) * span >> 32) % span
+            _port_cursor[pid] = at + _PORT_STRIDE
+            if _claim_port(addr, _PORT_FLOOR + at):
+                return _PORT_FLOOR + at
+    # The ephemeral range leaves nothing below it: whatever the kernel gives.
+    with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as s:
+        s.bind((addr, 0))
+        return s.getsockname()[1]
 
 
 def local_hostnames() -> List[str]:

@@ -63,16 +63,13 @@ def run(fn: Callable, args=(), kwargs=None, num_proc: Optional[int] = None,
         rank = ctx.partitionId()
         host = socket.gethostname()
         if rank == 0:
-            s = socket.socket()
-            s.bind(("", 0))
-            port = s.getsockname()[1]
-            s.close()
             # Advertise a routable IP: executor hostnames are not always
             # resolvable from peers, and gethostbyname(hostname) maps to
             # 127.0.1.1 on stock Debian — useless off-host.
             from ..runner.driver_service import local_addresses
+            from ..runner.util import find_free_port
 
-            info = f"{local_addresses()[0]}:{port}"
+            info = f"{local_addresses()[0]}:{find_free_port('0.0.0.0')}"
         else:
             info = ""
         all_info = [i for i in ctx.allGather(info) if i]

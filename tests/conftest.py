@@ -22,6 +22,39 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import pytest  # noqa: E402
 
+# Helper modules that hold assertions of tests split over several files.
+pytest.register_assert_rewrite("_jit_helpers")
+
+# The files one pytest-xdist worker needs minutes for, longest first.
+# ``--dist loadfile`` hands whole files out in collection order, and the
+# alphabet puts most of these last: started when nothing is left to run beside
+# them, they were the gate's tail.  Started first, the short files fill in
+# behind them.  (Seconds a file: PERF.md, "PR 31"; tests/single/
+# test_docs_name_files.py holds every name to a tracked file.)
+LONG_FILES = (
+    "tests/single/test_ops_jit_schedule_matrix_int8g.py",
+    "tests/single/test_ops_jit_schedule_parity_int4.py",
+    "tests/single/test_ops_jit_schedule_matrix_int4.py",
+    "tests/parallel/test_shm_plane_perf.py",
+    "tests/single/test_ops_jit_quantized_allreduce.py",
+    "tests/single/test_ops_jit_quantized_allreduce_bits.py",
+    "tests/integration/test_matrix.py",
+    "tests/single/test_flash_attention_grads.py",
+    "tests/single/test_ops_jit_schedule_parity_int8.py",
+    "tests/single/test_ops_jit_schedule_matrix_int8.py",
+    "tests/single/test_ops_jit_quantized_reducescatter.py",
+    "tests/single/test_ops_jit_quantized_alltoall.py",
+    "tests/parallel/test_multiprocess.py",
+    "tests/single/test_ring_attention.py",
+    "tests/single/test_flash_attention.py",
+)
+
+
+def pytest_collection_modifyitems(items):
+    first = {path: i for i, path in enumerate(LONG_FILES)}
+    items.sort(key=lambda item: first.get(item.nodeid.split("::")[0],
+                                          len(first)))
+
 
 @pytest.fixture()
 def hvd_single():

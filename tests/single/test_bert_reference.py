@@ -5,7 +5,6 @@ against the plain float32 ``jax.numpy`` reference, on seeded weights at
 matrix has padding rows to keep out of the softmax."""
 
 import dataclasses
-import os
 
 import numpy as np
 import pytest
@@ -14,11 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.common import l2_rel_err as _l2, rel_err as _rel
+from benchmark.references import bert as bert_ref
 from horovod_tpu import models
-from horovod_tpu.models.reference import bert_ref
-
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 CFG = dataclasses.replace(models.BERT_TINY, vocab_size=1000,
                           dtype=jnp.float32)
@@ -257,12 +253,3 @@ def test_a_mask_that_is_not_tail_padding_is_honoured_key_by_key(params,
     as_tail = np.asarray(flash.apply(*args, lengths=lengths))
     keep = np.asarray(left & (jnp.arange(SEQ)[None, :] < lengths[:, None]))
     assert np.max(np.abs(as_tail[keep] - want[keep])) > 1e-2
-
-
-def test_the_benchmark_carries_the_same_reference():
-    def body(path):
-        with open(os.path.join(REPO, path)) as f:
-            return f.read()
-
-    assert (body("horovod_tpu/models/reference/bert_ref.py")
-            == body("benchmark/references/bert.py"))

@@ -3,36 +3,18 @@ its phase functions passes at a tiny size on the virtual CPU mesh (Pallas
 kernels in the interpreter).  What the phases prove, they prove on the chip;
 this file keeps their control flow from rotting between chip runs."""
 
-import dataclasses
 import json
 import os
 import subprocess
 import sys
 
-import flax.linen as nn
 import jax
-import jax.numpy as jnp
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
-from horovod_tpu import models  # noqa: E402
-
-
-class _TinyNet(nn.Module):
-    """The smallest model with what the ResNet phases lean on: a conv, a
-    cross-replica BatchNorm over ``hvd``, ``num_classes`` and ``dtype``."""
-    num_classes: int = 10
-    dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x, train: bool = True):
-        x = nn.Conv(8, (3, 3), strides=2, use_bias=False)(x)
-        x = nn.BatchNorm(use_running_average=not train, axis_name="hvd")(x)
-        return nn.Dense(self.num_classes)(nn.relu(x).mean(axis=(1, 2)))
 
 
 def test_exits_nonzero_without_tpu(tmp_path):
@@ -68,25 +50,6 @@ def test_native_core_and_eager_phases():
         hvd.shutdown()
 
 
-def test_resnet_train_and_sync_phases():
-    report, run = chip_smoke.resnet50_train(
-        model=_TinyNet(), devices=jax.devices()[:1], batch=8, image=16,
-        steps=5)
-    assert report["losses"][-1] < report["losses"][0]
-    report = chip_smoke.sync(run, n=2)
-    assert report["block_until_ready_ms"] > 0
-
-
-def test_gpt_flash_train_phase():
-    cfg = dataclasses.replace(models.GPT_TINY, use_flash=True, num_layers=1,
-                              max_seq_len=64, dtype=jnp.bfloat16)
-    # Off the TPU flash_attention dispatches to its dense fallback, so the
-    # kernel count is the chip's to assert.
-    report = chip_smoke.gpt_flash_train(cfg=cfg, devices=jax.devices()[:1],
-                                        batch=2, steps=2, expect_kernel=False)
-    assert all(c["ok"] for c in report["checks"])
-
-
 def test_kernels_phase_interpreted():
     # 160 pads to 256: the padding path.  One dtype and one mask here; the
     # chip runs the product.
@@ -95,17 +58,3 @@ def test_kernels_phase_interpreted():
                                 dtypes=("bfloat16",), causals=(True,))
     assert all(c["ok"] for c in report["checks"])
     assert [c["codec"] for c in report["codecs"]] == ["int8", "int4", "int8g"]
-
-
-def test_spmd_dp4_phase_on_four_virtual_devices():
-    report = chip_smoke.spmd_dp4(model=_TinyNet(),
-                                 devices=jax.devices()[:4], batch=16,
-                                 image=16, steps=2)
-    assert report["per_chip"] == 4 and report["all_reduce_ops"] > 0
-    assert len(report["param_devices"]) == 4
-
-
-def test_spmd_dp4_refuses_fewer_devices():
-    with pytest.raises(AssertionError):
-        chip_smoke.spmd_dp4(model=_TinyNet(), devices=jax.devices()[:2],
-                            batch=8, image=16, steps=1)

@@ -26,12 +26,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax.sharding import PartitionSpec as P
 
 optax = pytest.importorskip("optax")
 
@@ -39,23 +34,9 @@ import horovod_tpu.ops.collectives as cl
 import horovod_tpu.ops.quantize as qz
 from horovod_tpu.optimizer import DistributedOptimizer
 from horovod_tpu.wire import ReduceOp
+from _jit_helpers import N_DEV, _smap
 
-N_DEV = 8
 MIN_BYTES = 4096
-
-
-def _mesh():
-    return Mesh(np.asarray(jax.devices()[:N_DEV]), ("hvd",))
-
-
-def _smap(fn, in_specs, out_specs):
-    mesh = _mesh()
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:  # newer jax renamed the kwarg
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
 
 
 @pytest.fixture

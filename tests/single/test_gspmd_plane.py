@@ -16,10 +16,7 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 layout
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from horovod_tpu.ops import gspmd_plane as gp
 from horovod_tpu.optimizer import DistributedOptimizer
@@ -164,14 +161,9 @@ def _train_eager(tx, steps=5):
         u, s2 = tx.update(g, s, p)      # psum-average -> global mean
         return optax.apply_updates(p, u), s2
 
-    try:
-        smap = shard_map(shard_step, mesh=mesh,
-                         in_specs=(P(), P(), P("hvd"), P("hvd")),
-                         out_specs=(P(), P()), check_rep=False)
-    except TypeError:  # newer jax renamed the kwarg
-        smap = shard_map(shard_step, mesh=mesh,
-                         in_specs=(P(), P(), P("hvd"), P("hvd")),
-                         out_specs=(P(), P()), check_vma=False)
+    smap = shard_map(shard_step, mesh=mesh,
+                     in_specs=(P(), P(), P("hvd"), P("hvd")),
+                     out_specs=(P(), P()), check_vma=False)
     step = jax.jit(smap)
     for _ in range(steps):
         params, state = step(params, state, x, y)

@@ -16,10 +16,7 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 layout
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from horovod_tpu.ops import gspmd_plane as gp
 from horovod_tpu.ops import hlo_inspect as hi
@@ -262,10 +259,7 @@ def test_instrument_eager_trace_reports_empty():
 
     specs = dict(mesh=mesh, in_specs=(P(), P(), P("hvd"), P("hvd")),
                  out_specs=(P(), P()))
-    try:
-        sm = shard_map(shard_step, check_rep=False, **specs)
-    except TypeError:  # newer jax renamed the kwarg
-        sm = shard_map(shard_step, check_vma=False, **specs)
+    sm = shard_map(shard_step, check_vma=False, **specs)
     wrapped = hi.instrument(jax.jit(sm), label="eager")
     p, s = wrapped(params, state, x, y)
     jax.block_until_ready(p)

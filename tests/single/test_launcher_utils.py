@@ -77,6 +77,82 @@ def test_host_hash_stable():
     assert len(host_hash()) == 16
 
 
+def _ephemeral_range():
+    try:
+        with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
+            low, high = (int(x) for x in f.read().split())
+        return low, high
+    except OSError:
+        return 32768, 60999
+
+
+def test_find_free_port_lies_outside_the_ephemeral_range_and_binds():
+    """A port the kernel cannot hand to a ``bind(0)`` or an outgoing
+    connection between the probe and the listener's start."""
+    import socket
+
+    from horovod_tpu.runner.util import find_free_port
+
+    low, high = _ephemeral_range()
+    for addr in ("127.0.0.1", "0.0.0.0"):
+        port = find_free_port(addr)
+        assert 1024 <= port < low or high < port <= 65535, (port, low, high)
+        with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as s:
+            s.bind((addr, port))
+            s.listen(1)
+
+
+def test_find_free_port_skips_held_ports_and_never_repeats():
+    """Twenty sockets held on ``bind(0)`` (what five other test workers'
+    launchers and coordinators do all the time), a listener on the very
+    port the next call would try first, and the port after it handed to
+    another launcher that has not started its listener yet: successive
+    calls give distinct ports, none of them held."""
+    import os
+    import socket
+    import subprocess
+    import sys
+
+    from horovod_tpu.runner import util
+
+    held, other = [], None
+    try:
+        for _ in range(20):
+            s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+            s.bind(("127.0.0.1", 0))
+            held.append(s)
+        first = util.find_free_port()
+        span = util._ephemeral_low() - util._PORT_FLOOR
+        nxt, after = (util._PORT_FLOOR + (first - util._PORT_FLOOR
+                                          + i * util._PORT_STRIDE) % span
+                      for i in (1, 2))
+        listener = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+        held.append(listener)
+        listener.bind(("127.0.0.1", nxt))
+        listener.listen(1)
+        # Another process whose own walk stands at ``after``.
+        other = subprocess.Popen(
+            [sys.executable, "-c",
+             "import os, sys\n"
+             "from horovod_tpu.runner import util\n"
+             f"util._port_cursor[os.getpid()] = {after - util._PORT_FLOOR}\n"
+             "print(util.find_free_port(), flush=True)\n"
+             "sys.stdin.read()\n"],
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
+            env=dict(os.environ, PYTHONPATH=os.path.dirname(os.path.dirname(
+                os.path.dirname(util.__file__)))))
+        assert int(other.stdout.readline()) == after
+        taken = {s.getsockname()[1] for s in held} | {after}
+        ports = [first] + [util.find_free_port() for _ in range(8)]
+        assert len(set(ports)) == len(ports), ports
+        assert not taken & set(ports), (sorted(taken), ports)
+    finally:
+        if other is not None:
+            other.communicate("")
+        for s in held:
+            s.close()
+
+
 def test_config_file_yaml(tmp_path):
     """--config-file fills launcher params; explicit CLI flags win
     (reference: horovodrun --config-file)."""

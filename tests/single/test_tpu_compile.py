@@ -4,10 +4,10 @@ own compiler, on a described (not attached) ``v5e:2x2`` topology.
 Interpret mode cannot show what Mosaic refuses: a block not aligned to the
 tiling, more VMEM than a kernel may use, a kernel that cannot be
 partitioned.  Nothing runs here, so these say nothing about results or
-times; chip_smoke.py does.  This is the only file that describes the chip:
-the topology is described inside a fixture (never at import, not autouse,
-not in conftest.py) and every compile happens in the test's own process,
-because one process at a time may load the TPU's library.
+times; chip_smoke.py and the benchmark do.  This is the only file that
+describes the chip: the topology is described inside a fixture (never at
+import, not autouse, not in conftest.py) and every compile happens in the
+test's own process, because one process at a time may load the TPU's library.
 """
 
 import dataclasses
@@ -28,9 +28,9 @@ from horovod_tpu.ops.flash_attention import (
     flash_attention, flash_attention_with_lse)
 from horovod_tpu.parallel.ring_attention import ring_attention
 
-# [batch, seq, heads, head_dim]: the long-context shape bench.py times,
-# GPT-2-small's attention at the batch chip_smoke.py trains, and
-# GPT-2-medium's at the benchmark's GPT cells' batch (the default plan there).
+# [batch, seq, heads, head_dim]: a long-context shape with 128-wide heads,
+# GPT-2-small's attention at batch 8, and GPT-2-medium's at the benchmark's
+# GPT cells' batch (the default plan there).
 SHAPES = {"4x2048x8x128": (4, 2048, 8, 128), "8x1024x12x64": (8, 1024, 12, 64),
           "8x1024x16x64": (8, 1024, 16, 64)}
 CODEC_ELEMS = 1 << 22

@@ -1,6 +1,7 @@
 """Flagship models for the framework's benchmarks (SURVEY.md §6;
 BASELINE.json configs 1-3): MNIST MLP, ResNet family, BERT family."""
 
+from .losses import softmax_cross_entropy  # noqa: F401
 from .mlp import MLP, xent_loss  # noqa: F401
 from .resnet import (  # noqa: F401
     ResNet, ResNet18, ResNet34, ResNet50, ResNet101, ResNet152, ResNetTiny,

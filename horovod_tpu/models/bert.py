@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from .flat_dense import FlatDenseGeneral
+from .losses import softmax_cross_entropy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -232,17 +233,14 @@ class BertForPreTraining(nn.Module):
 
 def mlm_loss(logits, labels, label_weights):
     """Masked-LM cross-entropy: mean over positions where weight == 1."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     w = label_weights.astype(jnp.float32)
-    return -(ll * w).sum() / jnp.maximum(w.sum(), 1.0)
+    nll = softmax_cross_entropy(logits, labels)
+    return (nll * w).sum() / jnp.maximum(w.sum(), 1.0)
 
 
 def nsp_loss(nsp_logits, nsp_labels):
     """Next-sentence cross-entropy, mean over the batch."""
-    logp = jax.nn.log_softmax(nsp_logits, axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, nsp_labels[:, None],
-                                         axis=-1))
+    return softmax_cross_entropy(nsp_logits, nsp_labels).mean()
 
 
 def pretraining_loss(mlm_logits, nsp_logits, mlm_labels, mlm_weights,

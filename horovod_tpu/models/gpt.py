@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from .flat_dense import FlatDenseGeneral
+from .losses import softmax_cross_entropy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,7 +123,4 @@ class GPT(nn.Module):
 
 def lm_loss(logits, input_ids):
     """Next-token cross entropy (shifted), mean over positions."""
-    logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
-    tgt = input_ids[:, 1:]
-    ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-    return -ll.mean()
+    return softmax_cross_entropy(logits[:, :-1], input_ids[:, 1:]).mean()

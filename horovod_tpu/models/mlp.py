@@ -8,6 +8,8 @@ from typing import Any, Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
+from .losses import softmax_cross_entropy
+
 
 class MLP(nn.Module):
     features: Sequence[int] = (512, 256, 10)
@@ -23,6 +25,4 @@ class MLP(nn.Module):
 
 
 def xent_loss(logits, labels):
-    logp = jnp.take_along_axis(
-        nn.log_softmax(logits, axis=-1), labels[:, None], axis=-1)
-    return -logp.mean()
+    return softmax_cross_entropy(logits, labels).mean()

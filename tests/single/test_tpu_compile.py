@@ -56,6 +56,12 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _shapes_on(sharding, tree):
+    """``tree``'s leaves as shapes placed on the described chip."""
+    return jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=sharding), tree)
+
+
 def _qkv(shape, sharding):
     return (jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding),) * 3
 
@@ -145,10 +151,8 @@ def _block_grad_text(family, batch, seq, heads, head_dim, one_chip):
         def call(method, variables_or_key, x, lens):
             return method(variables_or_key, x, lengths=lens)
 
-    shapes = jax.eval_shape(functools.partial(
-        call, block.init, jax.random.PRNGKey(0)), *args)
-    params = jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(
-        leaf.shape, leaf.dtype, sharding=one_chip), shapes)
+    params = _shapes_on(one_chip, jax.eval_shape(functools.partial(
+        call, block.init, jax.random.PRNGKey(0)), *args))
 
     def loss(params, *args):
         return jnp.sum(call(block.apply, params, *args)
@@ -201,6 +205,39 @@ def test_block_takes_the_kernels_without_relayout(one_chip, monkeypatch,
         assert len(relayouts) >= 8
         marked = [r for r in relayouts if "hvd_flash_relayout" in r[2]]
         assert len(marked) >= 8, relayouts
+
+
+def test_head_and_loss_write_the_logits_once_in_float32(one_chip):
+    """GPT-2-medium's float32 head and ``lm_loss`` at the benchmark's GPT
+    cells' shape, value and gradient: the head's matmul writes the logits
+    (1.65 GB in float32), the loss's hand-written backward writes dlogits
+    (XLA narrows it to bf16 for the two matmuls that read it), and nothing
+    else writes an array of that size.  ``log_softmax`` + ``take_along_axis``
+    wrote a float32 log-probability for every class besides, for the loss
+    to pick 8,184 of 412 M."""
+    import flax.linen as nn
+    from horovod_tpu import models
+
+    head = nn.Dense(50304, use_bias=False, dtype=jnp.float32)
+    x = jax.ShapeDtypeStruct((8, 1024, 1024), jnp.float32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one_chip)
+    params = _shapes_on(one_chip, jax.eval_shape(
+        head.init, jax.random.PRNGKey(0), x))
+
+    def loss(params, x, ids):
+        return models.lm_loss(head.apply(params, x), ids)
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)),
+                          params, x, ids)
+    entry = text[text.index("\nENTRY"):]
+    writers = [
+        found.group(1) for found in map(_INSTRUCTION.match,
+                                        entry.splitlines())
+        if found and found.group(2) in ("fusion", "custom-call", "copy")
+        and "[8,1023,50304]" in found.group(1)]
+    assert 1 <= len(writers) <= 2, writers
+    assert sum("f32[8,1023,50304]" in w for w in writers) == 1, writers
+    assert "log_softmax" not in text
 
 
 @pytest.mark.parametrize("codec", ["int8", "int4", "int8g"])

@@ -28,9 +28,9 @@ from __future__ import annotations
 from benchmark import common, flops
 from benchmark.references import bert as reference_bert
 
-# How a limit is set.  One rule for every TOL_* of the three families;
+# How a limit is set.  One rule for every TOL_* of every family;
 # tests/benchmark/test_check_limits.py holds it on the readings kept in
-# benchmark/testdata/check_readings.json:
+# benchmark/testdata/check_readings/<family>.json, one file a family:
 #
 #   A limit stands between the largest reading a sound tree has given on the
 #   chip, over every seed on record, and the smallest reading of each fault
@@ -45,8 +45,8 @@ from benchmark.references import bert as reference_bert
 #
 # Readings: TPU v5 lite.  "PR 27": 34 runs over 33 seeds for (a) to (c), 17
 # runs for (d) and (e).  "PR 30": seeds 0 to 31, 2147483693 and 3000000019,
-# every check (PERF.md section 6; with PR 29's three seeds, each run is a
-# line of check_readings.json).  The faults: two lower precisions of the plain
+# every check (PERF.md section 6; with PR 29's three seeds, each run is a line
+# of check_readings/bert.json).  The faults: two lower precisions of the plain
 # reference against the float32 one, "bf16 throughout" (float32 given up for
 # the parameters, layer norms, softmax and logits: the nearest below what the
 # configuration states) and "e4m3" (every parameter and every function's

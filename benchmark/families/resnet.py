@@ -14,8 +14,8 @@ from __future__ import annotations
 from benchmark import common, flops
 
 # How a limit is set: the rule at the head of families/bert.py, held on the
-# readings in benchmark/testdata/check_readings.json.  Both are kept where
-# PR 23's readings put them.
+# readings in benchmark/testdata/check_readings/resnet.json.  Both are kept
+# where PR 23's readings put them.
 #
 # (a) First loss, system (bf16 activations, sync-BN over the mesh) against
 # the float32 whole-batch reference at "highest" matmul precision.  The loss

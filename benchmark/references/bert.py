@@ -29,9 +29,9 @@ Departures from the paper, all shared with the system under test:
   whole tiles): only the first ``vocab_size`` rows are decoded, so padded
   rows take no part in the softmax and get a zero gradient.
 
-A copy of this file lives at ``benchmark/references/bert.py`` (the benchmark
-carries its own reference); ``tests/single/test_bert_reference.py`` holds
-the two to the same text.
+This file is the one text of it (the benchmark carries its own reference):
+``benchmark/families/bert.py`` and ``tests/single/test_bert_reference.py``
+import it from here.
 """
 
 from __future__ import annotations

@@ -2,7 +2,7 @@
 there to catch, made in the plain reference and read in those checks' own
 measures against the plain reference itself: what a limit on the sample's
 masked-LM logits and on the tied word embeddings' first moment must stay
-under (``benchmark/testdata/check_readings.json`` keeps the readings).
+under (``benchmark/testdata/check_readings/bert.json`` keeps the readings).
 
     python tests/benchmark/bert_faults.py --seeds 1 2 3
 

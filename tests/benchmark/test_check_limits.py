@@ -1,5 +1,6 @@
 """Where the limits of ``correct`` stand, held against the readings on record
-(``benchmark/testdata/check_readings.json``); the two faults that read
+(``benchmark/testdata/check_readings/<family>.json``, one file a family, by
+the rule of ``benchmark/testdata/check_rule.json``); the two faults that read
 nearest to the limits of ``bert``'s (b) and (c), made at ``--rehearse``'s
 sizes; the last line a run prints; a run whose step is broken.  Nothing here measures anything and nothing
 starts a process."""
@@ -20,11 +21,17 @@ from benchmark.families import bert  # noqa: E402
 
 import bert_faults  # noqa: E402  (beside this file)
 
-with open(os.path.join(REPO, "benchmark", "testdata",
-                       "check_readings.json")) as f:
-    READINGS = json.load(f)
-CONSTANTS = [(family, name) for family, limits in READINGS["limits"].items()
-             for name in limits]
+MARGIN = run.load_json("testdata", "check_rule.json")["rule"]["margin"]
+# A family's readings are the file of its name: a later PR adds a family's
+# file and edits none (test_a_new_family.py does so in a copy).
+READINGS = {os.path.splitext(f)[0]: run.load_json("testdata",
+                                                  "check_readings", f)
+            for f in sorted(os.listdir(os.path.join(
+                REPO, "benchmark", "testdata", "check_readings")))}
+CONSTANTS = [(family, name) for family, readings in READINGS.items()
+             for name in readings["limits"]]
+FAMILIES = sorted(os.path.splitext(f)[0] for f in os.listdir(os.path.join(
+    REPO, "benchmark", "families")) if not f.startswith("_"))
 
 
 def _family(cell: str) -> str:
@@ -33,14 +40,11 @@ def _family(cell: str) -> str:
     return run.load_json("configs", entry["config"] + ".json")["family"]
 
 
-FAMILY = {cell: _family(cell) for cell in {r["cell"] for r in READINGS["runs"]}}
-
-
 def _sound_readings(family: str, pattern: str) -> list:
     """``(value, where)`` of every reading on record of the checks that
     ``pattern`` names, in the runs of ``family``'s cells."""
     return [(value, f"PR {r['pr']}, {r['cell']}, seed {r['seed']}: {check}")
-            for r in READINGS["runs"] if FAMILY[r["cell"]] == family
+            for r in READINGS[family]["runs"]
             for check, value in r["checks"].items()
             if re.search(pattern, check)]
 
@@ -49,38 +53,50 @@ def _sound_readings(family: str, pattern: str) -> list:
                          ids=[f"{f}.{n}" for f, n in CONSTANTS])
 def test_a_limit_stands_between_the_sound_readings_and_the_faults(family,
                                                                   name):
-    """The rule at the head of ``families/bert.py``.  A larger sound reading
-    than any on record is added to the file as one more run, and this says
-    which limit has gone thin."""
-    margin, entry = READINGS["rule"]["margin"], READINGS["limits"][family][name]
+    """The rule at the head of ``families/bert.py``.  A ``benchmark`` PR adds
+    a larger sound reading than any on record to the family's file as one
+    more run, and this says which limit has gone thin."""
+    entry = READINGS[family]["limits"][name]
     limit = getattr(importlib.import_module(f"benchmark.families.{family}"),
                     name)
     largest, where = max(_sound_readings(family, entry["checks"]) + [
         (e["largest"], f"PR {e['pr']}, largest of {e['runs']} runs")
         for e in entry["earlier"]])
-    assert limit >= margin * largest, (
-        f"{family}.{name} = {limit} is under {margin} x the largest sound "
+    assert limit >= MARGIN * largest, (
+        f"{family}.{name} = {limit} is under {MARGIN} x the largest sound "
         f"reading {largest} ({where})")
     for fault in entry["faults"]:
-        assert limit * margin <= min(fault["readings"]), (
-            f"{family}.{name} = {limit} is not {margin} x under "
+        assert limit * MARGIN <= min(fault["readings"]), (
+            f"{family}.{name} = {limit} is not {MARGIN} x under "
             f"{min(fault['readings'])}, what {fault['fault']!r} reads")
 
 
 def test_every_limit_and_every_reading_is_on_record():
-    """A ``TOL_*`` that a family gains comes with its readings, and a check
-    that a recorded run compared is some limit's: none is passed over."""
-    families = {os.path.splitext(f)[0] for f in os.listdir(os.path.join(
-        REPO, "benchmark", "families")) if not f.startswith("_")}
-    assert families == set(READINGS["limits"]) == set(FAMILY.values())
-    for family, limits in READINGS["limits"].items():
+    """A family comes with the file of its readings, a ``TOL_*`` that a
+    family gains with its entry there, and a check that a recorded run
+    compared is some limit's: none is passed over."""
+    for family in FAMILIES:
+        rel = f"benchmark/testdata/check_readings/{family}.json"
+        assert family in READINGS, (
+            f"benchmark/families/{family}.py has no readings on record: add "
+            f"{rel} with its `limits` and the `runs` they were set from "
+            "(benchmark/README.md, \"A family\")")
+        limits, runs = READINGS[family]["limits"], READINGS[family]["runs"]
         module = importlib.import_module(f"benchmark.families.{family}")
-        assert set(limits) == {n for n in vars(module) if n.startswith("TOL_")}
-        for check in {c for r in READINGS["runs"]
-                      if FAMILY[r["cell"]] == family for c in r["checks"]}:
+        assert set(limits) == {n for n in vars(module)
+                               if n.startswith("TOL_")}, rel
+        assert runs, f"{rel} has no run"
+        for cell in {r["cell"] for r in runs}:
+            assert _family(cell) == family, (
+                f"{rel}: a run of {cell}, whose configuration's family is "
+                f"{_family(cell)}")
+        for check in {c for r in runs for c in r["checks"]}:
             owners = [n for n, e in limits.items()
                       if re.search(e["checks"], check)]
-            assert len(owners) == 1, (family, check, owners)
+            assert len(owners) == 1, (rel, check, owners)
+    assert sorted(READINGS) == FAMILIES, (
+        "a file under benchmark/testdata/check_readings/ is named for no "
+        "file under benchmark/families/")
 
 
 # ---------------------------------------------------------------------------
@@ -139,8 +155,8 @@ def test_a_missing_mask_and_e4m3_read_over_the_limits_that_moved():
     ``--rehearse``'s sizes: the two faults that read nearest to those limits
     in the cell, padded keys left in the softmax and the reference in
     float8_e4m3, are refused by both with the rule's room.  (What they read
-    at the cell's own size, on the chip, is in check_readings.json; a gather
-    one position off reads 1 here and 1e-3 there.)"""
+    at the cell's own size, on the chip, is in check_readings/bert.json; a
+    gather one position off reads 1 here and 1e-3 there.)"""
     import jax
 
     cfg = run.load_json("configs", "bert-large.json")
@@ -153,11 +169,10 @@ def test_a_missing_mask_and_e4m3_read_over_the_limits_that_moved():
     got = bert_faults.readings(
         ["missing_mask", "e4m3"], cell["params"]["params"],
         cell["bcfg"].vocab_size, bert.shape_batch(traffic, *drawn), micro=4)
-    margin = READINGS["rule"]["margin"]
     for fault, read in got.items():
-        assert read["sample_mlm_logits"] > margin * bert.TOL_SAMPLE_MLM_LOGITS, (
+        assert read["sample_mlm_logits"] > MARGIN * bert.TOL_SAMPLE_MLM_LOGITS, (
             fault, read)
-        assert read["first_moment_tied"] > margin * bert.TOL_FIRST_MOMENT_TIED, (
+        assert read["first_moment_tied"] > MARGIN * bert.TOL_FIRST_MOMENT_TIED, (
             fault, read)
 
 

@@ -1,0 +1,498 @@
+"""Family ``sdar``: ``horovod_tpu.models.SDAR`` (a Qwen3-MoE decoder:
+grouped-query attention with q/k norms and rotary positions, top-k routed
+SwiGLU experts) trained by block diffusion as SDAR adapts a checkpoint
+(arXiv:2510.06303; BD3-LM, arXiv:2503.09573), one chip's share of a layer
+spread over several: ``num_experts_held`` of the experts, ``vocab_size_held``
+rows of the embedding and the head, attention and the router whole.
+
+The step has the shape of ``families/gpt.py``'s: a jitted ``shard_map`` over
+the ``hvd`` axis, the optimizer wrapped in ``hvd.DistributedOptimizer``, the
+loss averaged over the axis.  It takes three drawn arguments (token ids, a
+level draw per block, a draw per token: ``traffic.py``'s ``randint``s) and
+folds them itself (``shape_batch``), as ``bert.shape_batch`` folds its draws.
+
+The reference is ``benchmark/references/sdar.py``: plain float32
+``jax.numpy``, one device, "highest" matmul precision, one sequence a
+micro-batch, attention in query chunks and each block under
+``jax.checkpoint``.  **Top-k is discrete**: a bfloat16 activation flips the
+choices whose probabilities nearly tie, and what follows a flipped choice is
+simply different.  So the router is compared on its own (its probabilities on
+the reference's float32 input; the share of the system's choices that differ
+from the reference's), and everything downstream is compared with the
+reference run on the system's own choices, which it takes as an argument.
+"""
+
+from __future__ import annotations
+
+from benchmark import common, sdar_flops
+from benchmark.families import bert
+from benchmark.references import sdar as reference_sdar
+
+# How a limit is set: the rule at the head of families/bert.py, held on the
+# readings in benchmark/testdata/check_readings/sdar.json.  Readings: TPU v5
+# lite, the cell sdar-moe-ep8-s4096, PR 34: "first" are 7 runs over 7 seeds
+# (401, 402, 404, 405, 407, 2147483753, 3000000077), "kept" the 18 runs kept
+# one by one in the file (seven of them read after the review, on the expert
+# layer of one row buffer).  The faults are ISSUE 34's list, made in the plain reference
+# and read against the plain reference itself in each check's own measure at
+# the cell's own size (tests/benchmark/sdar_faults.py, seeds 1 to 3; with
+# --grads seed 4).  At initialisation under unit-variance embeddings a
+# token's own embedding is most of its hidden state through all five
+# pre-norm blocks, so a fault of attention or of the experts reads a few
+# tenths where a two-layer model with small embeddings reads near 1.
+#
+# What tells what apart.  bfloat16 throughout reads on (a), (b) and (d) what
+# the system reads (logits 5e-3, choices 3e-3): nothing downstream of the
+# activations' noise can hold the float32 parts.  (c) holds the router's, and
+# the two dtype checks the state's and the logits'; e4m3 is caught by (c) and
+# by the choices, not by (b) (6.3e-2 against a sound 6.2e-2).  A ``route``
+# cast to either, in the program's place through a whole run, comes out not
+# correct (tests/benchmark/test_sdar_cell.py).  Not held by anything here: the
+# float32 arithmetic of the RMSNorms, the rotary angles, the kernels' softmax
+# statistics (they write bfloat16 either way).
+#
+# (a) First loss of the compiled step on the whole batch against the
+# reference's on the system's choices: a signed difference around zero.
+# Sound: 1.8e-7 to 6.7e-5.  Fault: the 1 / t weight left out 0.49 to 0.57
+# (every structural fault reads under 1e-2 here: the loss at initialisation
+# is ln V whatever the blocks compute).  Middle: 100 x from either.
+TOL_FIRST_LOSS = 5e-3
+# (b) Logits of the sample (the first sequence's first SAMPLE_POSITIONS noised
+# positions, 256 x 18,992) of the system's forward against the reference's on
+# the system's choices, max |a - b| / max |b|.  Sound: 3.5e-2 to 6.2e-2 (25
+# runs).  Faults: norm_topk_prob left out 0.163 to 0.177, the held range off
+# by one 0.31 to 0.34, the clean copy seeing the noised 0.30 to 0.38, the
+# noised copy's positions offset by L 0.32 to 0.38, a noised row seeing its
+# own clean block 0.41 to 0.71, absent experts' part added 0.46 to 0.50, key
+# head i mod 4 0.97 to 1.04.  1.6 x from either: the room there is.
+TOL_SAMPLE_LOGITS = 0.1
+# (c) The router alone: the program's ``route`` on the reference's float32
+# input of the first block's expert layer against the reference's
+# probabilities, max |a - b| / max |b| over 8192 x 128.  Sound: 0.0 on every
+# run (the same float32 arithmetic at "highest").  Faults: bfloat16
+# throughout 2.9e-3 to 4.6e-3, e4m3 3.7e-2 to 4.6e-2.  Kept: 30 x under the
+# nearer fault, and room for another compiler's order of a 2048-term sum.
+TOL_ROUTER_PROBS = 1e-4
+# (c) The share of the system's (token, expert) choices, all layers of the
+# sample, that the reference's own top-8 of the same token does not hold:
+# near-ties that bfloat16 activations flip.  Sound: 4.6e-3 to 6.5e-3.
+# Faults: e4m3 2.9e-2 to 3.2e-2, positions offset 3.1e-2 to 3.6e-2, the clean
+# copy seeing the noised 3.6e-2 to 3.9e-2, norm_topk_prob left out 5.3e-2 to
+# 5.6e-2, the held range off by one 0.10, key head i mod 4 0.17 to 0.18,
+# absent experts added 0.23.  Middle: 2 x from either.
+TOL_CHOICES_DIFFERING = 0.013
+# (d) The first moment after one step is (1 - b1) x the gradient.  Dense
+# leaves (embedding rows, the first block's q, k and v kernels, the head),
+# L2 error over the leaf.  Sound: 6.3e-3 to 2.8e-2.  Faults, the smallest of
+# the five leaves: the held range off by one 0.26, key head i mod 4 0.50, the
+# 1 / t weight left out 0.86.  Middle: 3 x from either.
+TOL_FIRST_MOMENT = 0.085
+# Routed leaves (the first block's router, the last block's down kernels):
+# the median over the experts of each expert's L2 error (``moment_error``
+# says why).  Sound: router 1.8e-2 to 3.9e-2, down kernels 1.8e-2 to 0.114.
+# Faults, the smaller of the two leaves: the 1 / t weight left out 0.88, the
+# held range off by one 1.0, key head i mod 4 1.02.  Middle: 2.9 x from
+# either (2.6 x since the reading of 0.114).
+TOL_FIRST_MOMENT_ROUTED = 0.3
+# (e) What the first step did to the same leaves against plain AdamW of the
+# moments the step itself left behind (``bert.adamw_first_update``, float64):
+# the L2 error of the change.  The rate 2e-7 is 100 float32 ulps of a kernel
+# entry near 0.02.  Sound: 3.1e-3 to 1.5e-2 (1.6e-2 to 2.5e-2 at
+# --rehearse's sizes, where a weight is 0.1).  Fault: the parameters kept in
+# bfloat16 lose the update whole, 1.0.  Middle: 10 x from either.
+TOL_FIRST_UPDATE = 0.1
+# An embedding entry is of order one and 2e-7 is two or three of its float32
+# ulps: the update itself is rounded by a fifth.  Sound: 0.19 to 0.21 (0.23 at
+# --rehearse's sizes).  Fault: as above, 1.0.  Middle: 2.1 x from either.
+TOL_FIRST_UPDATE_EMBEDDING = 0.45
+DRAWS = 1 << 20           # a level is (draw + 1) / DRAWS, a token's u draw / DRAWS
+SAMPLE_POSITIONS = 256
+EMBEDDING_ROWS = 1024     # of the embedding, the rows that are compared
+
+
+def _sizes(cfg: dict, rehearse: bool) -> dict:
+    return {**cfg["assumed"], **cfg, **(cfg["rehearse"] if rehearse else {})}
+
+
+def _sdar_config(cfg: dict, rehearse: bool):
+    import jax.numpy as jnp
+
+    from horovod_tpu import models
+
+    c = _sizes(cfg, rehearse)
+    return models.SDARConfig(
+        vocab_size=c["vocab_size_held"], hidden_size=c["hidden_size"],
+        num_layers=c["num_hidden_layers"],
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        num_experts=c["num_experts"],
+        num_experts_per_tok=c["num_experts_per_tok"],
+        moe_intermediate_size=c["moe_intermediate_size"],
+        norm_topk_prob=c["norm_topk_prob"], rms_norm_eps=c["rms_norm_eps"],
+        rope_theta=float(c["rope_theta"]), block_length=c["block_length"],
+        num_experts_held=c["num_experts_held"],
+        first_expert=c["first_expert"], dtype=jnp.dtype(c["dtype"]),
+        use_flash=c["use_flash"])
+
+
+def reference_config(scfg) -> dict:
+    """What ``references/sdar.py`` reads of a configuration."""
+    return {"num_attention_heads": scfg.num_heads,
+            "num_key_value_heads": scfg.num_kv_heads,
+            "head_dim": scfg.head_dim,
+            "num_experts_per_tok": scfg.num_experts_per_tok,
+            "norm_topk_prob": scfg.norm_topk_prob,
+            "rms_norm_eps": scfg.rms_norm_eps, "rope_theta": scfg.rope_theta,
+            "block_length": scfg.block_length,
+            "first_expert": scfg.first_expert}
+
+
+def setup(cfg: dict, mesh, seed: int, rehearse: bool = False) -> dict:
+    """Model and seeded weights (replicated), made on the device in one
+    jitted call."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from horovod_tpu import models
+
+    scfg = _sdar_config(cfg, rehearse)
+    model = models.SDAR(scfg)
+
+    def init(key):
+        ids = jnp.zeros((1, 4 * scfg.block_length), jnp.int32)
+        return model.init(key, ids, ids)
+
+    # The key is an argument, not a constant of the program (families/gpt.py).
+    key = jax.random.fold_in(jax.random.key(seed), 0)
+    params = jax.jit(init, out_shardings=NamedSharding(mesh, P()))(key)
+    return {"cfg": cfg, "mesh": mesh, "model": model, "scfg": scfg,
+            "rehearse": rehearse, "params": params}
+
+
+def inputs(cell: dict, traffic: dict) -> list:
+    """The three drawn arguments of the step, per sequence: token ids below
+    the mask id, a draw per block (its level) and a draw per token."""
+    import jax.numpy as jnp
+
+    from benchmark.traffic import Input
+
+    scfg, seq = cell["scfg"], traffic["seq_len"]
+    block = traffic.get("block_length", scfg.block_length)
+    if block != scfg.block_length or seq % block:
+        raise ValueError(f"traffic: blocks of {block} in {seq} tokens, the "
+                         f"configuration's block_length is "
+                         f"{scfg.block_length}")
+    return [Input((seq,), jnp.int32, "randint", scfg.mask_token_id),
+            Input((seq // block,), jnp.int32, "randint", DRAWS),
+            Input((seq,), jnp.int32, "randint", DRAWS)]
+
+
+def shape_batch(scfg, ids, level_draws, token_draws) -> dict:
+    """The model's and the loss's arguments from the drawn ones: a level
+    t = (draw + 1) / DRAWS in (0, 1] per block, a token replaced by the mask
+    id where its own draw / DRAWS lies below its block's level."""
+    import jax.numpy as jnp
+
+    from horovod_tpu import models
+
+    levels = (level_draws.astype(jnp.float32) + 1.0) / DRAWS
+    noised, masked, per_token = models.noise_blocks(
+        ids, levels, token_draws.astype(jnp.float32) / DRAWS,
+        scfg.block_length, scfg.mask_token_id)
+    return {"clean": ids, "noised": noised, "masked": masked,
+            "levels": per_token}
+
+
+def _loss(model, params, b: dict):
+    from horovod_tpu import models
+
+    return models.block_diffusion_loss(
+        model.apply(params, b["clean"], b["noised"]), b["clean"],
+        b["masked"], b["levels"])
+
+
+def _checked_tree(tree, scfg) -> dict:
+    """The leaves checks (d) and (e) compare, as a sub-tree with the whole
+    tree's paths: rows of the embedding, the first block's q and k/v kernels
+    (they have passed through every layer's dq and dkv), its router, the
+    last block's down kernels of the held experts (whole: a router at
+    initialisation may send one expert nothing), the head."""
+    p = tree["params"]
+    first, last = p["layer_0"], f"layer_{scfg.num_layers - 1}"
+    return {"params": {
+        "embed": {"embedding": p["embed"]["embedding"][:EMBEDDING_ROWS]},
+        "layer_0": {
+            "attn": {k: {"kernel": first["attn"][k]["kernel"]}
+                     for k in ("q_proj", "k_proj", "v_proj")},
+            "moe": {"router": first["moe"]["router"]}},
+        last: {"moe": {"w_down": p[last]["moe"]["w_down"]}},
+        "lm_head": {"kernel": p["lm_head"]["kernel"]}}}
+
+
+def _cut(path: str, leaf):
+    """A whole leaf of the state as ``_checked_tree`` cuts it."""
+    return leaf[:EMBEDDING_ROWS] if path.endswith("['embedding']") else leaf
+
+
+def _system_forward(cell: dict, params, b: dict):
+    """The system's forward on ``b`` under the cell's precision and kernels:
+    logits, and per layer what its router chose and the rows it sent to each
+    held expert."""
+    import jax
+    import jax.numpy as jnp
+
+    model, layers = cell["model"], cell["scfg"].num_layers
+
+    def forward(p, b):
+        logits, seen = model.apply(p, b["clean"], b["noised"],
+                                   mutable=["intermediates"])
+        moe = [seen["intermediates"][f"layer_{i}"]["moe"]
+               for i in range(layers)]
+        return (logits, jnp.stack([m["chosen_experts"][0] for m in moe]),
+                jnp.stack([m["expert_load"][0] for m in moe]))
+
+    return jax.jit(forward)(params, b)
+
+
+def reference(cell: dict) -> dict:
+    """The plain float32 reference on the first global batch, a sequence at
+    a time, on the choices the system's routers make on that batch: its
+    loss, its gradient of the named leaves and the first moment one plain
+    optax update of them leaves behind; on the sample (the first sequence)
+    its logits at the first positions, its own choices and what the first
+    block's router saw (kept in ``cell["sample"]`` for ``probe``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    scfg, mesh = cell["scfg"], cell["mesh"]
+    device = mesh.devices.flat[0]
+    params = common.first_shard(cell["params"])
+    drawn = [jax.device_put(x, device) for x in cell["batches"][0]]
+    batch = jax.jit(lambda *d: shape_batch(scfg, *d))(*drawn)
+    sequences, length = batch["clean"].shape
+    rcfg = reference_config(scfg)
+    positions = min(SAMPLE_POSITIONS, length)
+    _, chosen, load = _system_forward(cell, params, batch)
+    # [layers, sequences * 2L, k] -> a sequence's rows, layer by layer
+    chosen = chosen.reshape(scfg.num_layers, sequences, 2 * length, -1)
+    cell["expert_load"] = np.asarray(load).tolist()
+
+    def part(p, b, chosen):
+        logits, seen = reference_sdar.logits(
+            p["params"], b["clean"], b["noised"], rcfg, chosen)
+        loss = reference_sdar.loss_sum(
+            logits, b["clean"], b["masked"], b["levels"]) / (sequences
+                                                             * length)
+        return loss, (logits[:positions], seen[0]["routed"],
+                      seen[0]["probs"])
+
+    def part_and_leaf_grads(p, b, chosen):
+        (loss, aux), grads = jax.value_and_grad(part, has_aux=True)(
+            p, b, chosen)
+        return loss, aux, _checked_tree(grads, scfg)
+
+    def own_choices(p, b):
+        _, seen = reference_sdar.logits(p["params"], b["clean"], b["noised"],
+                                        rcfg)
+        return jnp.stack([s["chosen"] for s in seen])
+
+    fn = jax.jit(part_and_leaf_grads)
+    loss, grads, sample = 0.0, None, None
+    with jax.default_matmul_precision("highest"):
+        for i in range(sequences):
+            b = {k: v[i] for k, v in batch.items()}
+            part_loss, aux, part_grads = fn(params, b, chosen[:, i])
+            loss += float(part_loss)
+            grads = part_grads if grads is None else jax.tree_util.tree_map(
+                jnp.add, grads, part_grads)
+            if sample is None:
+                sample = {
+                    "batch": {k: v[:1] for k, v in batch.items()},
+                    "logits": np.asarray(aux[0]), "routed": aux[1],
+                    "probs": np.asarray(aux[2]),
+                    "system_chose": np.asarray(chosen[:, i]),
+                    "reference_chose": np.asarray(
+                        jax.jit(own_choices)(params, b))}
+    cell["sample"] = sample
+    leaves = _checked_tree(params, scfg)
+    tx = common.make_optimizer(cell["cfg"]["optimizer"])
+    _, opt_state = tx.update(grads, tx.init(leaves), leaves)
+    # The step donates the parameters: the leaves as they are before it go
+    # to the host here, for (e).
+    return {"loss": loss, "leaves": {
+        k: {"first_moment": np.asarray(common.first_moments(opt_state, k)[0]),
+            "before": np.array(v)}
+        for k, v in common.leaf_paths(leaves).items()}}
+
+
+def choices_differing(system, reference_) -> float:
+    """The share of the system's (token, expert) choices that the
+    reference's top-k of the same token does not hold."""
+    import numpy as np
+
+    same = (np.asarray(system)[..., :, None]
+            == np.asarray(reference_)[..., None, :]).any(-1)
+    return float(1.0 - same.mean())
+
+
+def probe(cell: dict, step, state) -> list:
+    """(b) The system's forward on the sample before the first step moves
+    the weights: its logits against the reference's; (c) the program's
+    router on the reference's float32 input against the reference's
+    probabilities, and the share of the system's choices the reference does
+    not make.  The rows each held expert got on the first batch ride on the
+    last as ``expert_load`` (largest and mean by layer, beside the expert
+    layer's row buffer: the timed step is the buffer's while every layer's
+    rows fit it)."""
+    import jax
+    import numpy as np
+
+    from horovod_tpu.models import sdar as model_sdar
+    from horovod_tpu.parallel import moe
+
+    sample, scfg = cell.pop("sample"), cell["scfg"]
+    params = common.first_shard(state[0])
+    logits, _, _ = _system_forward(cell, params, sample["batch"])
+    positions = sample["logits"].shape[0]
+    router = params["params"]["layer_0"]["moe"]["router"]
+    probs = jax.jit(lambda x, w: moe.route(
+        x, w, scfg.num_experts_per_tok, scfg.first_expert,
+        scfg.experts_held, scfg.norm_topk_prob).probs)(sample["routed"],
+                                                       router)
+    load = np.asarray(cell["expert_load"])
+    tokens = int(np.prod(cell["batches"][0][0].shape)) * 2 // cell["mesh"].size
+    buffer = moe.row_buffer(tokens, scfg.num_experts_per_tok,
+                            scfg.experts_held, scfg.num_experts,
+                            model_sdar.EXPERT_CAPACITY_FACTOR)
+    return [
+        common.check("sample_logits_vs_reference", common.rel_err(
+            np.asarray(logits)[0, :positions], sample["logits"]),
+            TOL_SAMPLE_LOGITS),
+        {"name": "logits_are_float32",
+         "ok": bool(logits.dtype == np.float32)},
+        common.check("router_probs_of_the_reference_s_input_vs_reference",
+                     common.rel_err(np.asarray(probs), sample["probs"]),
+                     TOL_ROUTER_PROBS),
+        {**common.check("choices_differing_from_the_reference",
+                        choices_differing(sample["system_chose"],
+                                          sample["reference_chose"]),
+                        TOL_CHOICES_DIFFERING),
+         "expert_load": {"row_buffer": buffer,
+                         "rows_by_layer": load.sum(axis=1).tolist(),
+                         "largest_by_layer": load.max(axis=1).tolist(),
+                         "mean_by_layer": load.mean(axis=1).tolist()}}]
+
+
+def build(cell: dict):
+    """``(compiled step, state)``, compiled ahead of time.
+    ``*state, loss = step(*state, *batch)`` for each batch of the cell."""
+    import jax
+    import optax
+    from jax import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+
+    model, mesh, scfg = cell["model"], cell["mesh"], cell["scfg"]
+    tx = hvd.DistributedOptimizer(
+        common.make_optimizer(cell["cfg"]["optimizer"]), axis_name="hvd")
+
+    def train_step(params, opt_state, *drawn):
+        batch = shape_batch(scfg, *drawn)
+        loss, grads = jax.value_and_grad(
+            lambda p: _loss(model, p, batch))(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                hvd.allreduce(loss, axis_name="hvd"))
+
+    drawn = cell["batches"][0]
+    step = jax.jit(shard_map(
+        train_step, mesh=mesh,
+        in_specs=(P(), P(), *(P("hvd") for _ in drawn)),
+        out_specs=(P(), P(), P())), donate_argnums=(0, 1))
+    opt_state = jax.jit(tx.init, out_shardings=NamedSharding(mesh, P()))(
+        cell["params"])
+    state = (cell["params"], opt_state)
+    return step.lower(*state, *drawn).compile(), state
+
+
+def moment_error(path: str, got, want) -> float:
+    """||a - b|| / ||b|| over a leaf; over a leaf that has an expert axis
+    (the router's columns, the held experts' down kernels) the **median over
+    the experts** of that error, each expert by itself.  The loss weighs a
+    masked token 1 / t, so now and then one row is a third of a gradient,
+    and where the step and the forward that chose for the reference send
+    that row differently (a near-tie), the two experts it moved between
+    differ by that much while the others agree; a fault of the backward
+    reaches every expert."""
+    import numpy as np
+
+    axis = {"['router']": 1, "['w_down']": 0}.get(path[path.rindex("["):])
+    if axis is None:
+        return common.l2_rel_err(got, want)
+    pairs = [(g, w) for g, w in zip(np.moveaxis(np.asarray(got), axis, 0),
+                                    np.moveaxis(np.asarray(want), axis, 0))
+             if np.any(w)]
+    return float(np.median([common.l2_rel_err(g, w) for g, w in pairs]))
+
+
+def checks(cell: dict, ref: dict, first_loss: float, state, hlo: dict) -> list:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    scfg = cell["scfg"]
+    out = [common.check("first_loss_vs_reference",
+                        common.rel_err(first_loss, ref["loss"]),
+                        TOL_FIRST_LOSS)]
+    if scfg.use_flash:
+        # forward, dq and dkv per layer: the Pallas kernels, not the dense
+        # fallback, are in the compiled step.
+        out.append(common.at_least("tpu_custom_calls",
+                                   hlo["tpu_custom_call"],
+                                   3 * scfg.num_layers))
+    if cell["mesh"].size > 1:
+        out.append(common.at_least("all_reduce_ops",
+                                   hlo.get("all-reduce", 0), 1))
+    params, opt_state = state
+    leaves = common.leaf_paths(params)
+    for k, want in ref["leaves"].items():
+        moments = common.first_moments(opt_state, k)
+        assert len(moments) == 1, (k, len(moments))
+        mu = jax.device_get(_cut(k, moments[0]))
+        out.append(common.check(f"first_moment{k}", moment_error(
+            k, mu, want["first_moment"]), TOL_FIRST_MOMENT_ROUTED
+            if "['moe']" in k else TOL_FIRST_MOMENT))
+        nu = jax.device_get(_cut(k, bert._second_moment(opt_state, k)))
+        after = np.asarray(jax.device_get(_cut(k, leaves[k])), np.float64)
+        out.append(common.check(f"first_update{k}", common.l2_rel_err(
+            after - want["before"], bert.adamw_first_update(
+                want["before"], mu, nu,
+                **cell["cfg"]["optimizer"]["args"])),
+            TOL_FIRST_UPDATE_EMBEDDING if "['embedding']" in k
+            else TOL_FIRST_UPDATE))
+    inexact = [x.dtype for x in jax.tree_util.tree_leaves(state)
+               if jnp.issubdtype(x.dtype, jnp.inexact)]
+    out.append({"name": "parameters_and_moments_are_float32",
+                "ok": bool(inexact) and all(
+                    d == jnp.float32 for d in inexact)})
+    return out
+
+
+def model_flops(cell: dict) -> float:
+    """Forward + backward FLOPs of one step of the whole (global) batch, as
+    the algorithm needs them (``sdar_flops.forward_macs``): attention over
+    the live pairs of the mask, the experts over the rows an even router
+    sends to the held ones, the head over the noised half and the held
+    vocabulary; recomputation is not counted."""
+    cfg = _sizes(cell["cfg"], cell["rehearse"])
+    return sdar_flops.model_flops(cfg, cell["traffic"], cell["mesh"].size)
+
+
+def units(cell: dict) -> tuple:
+    """What one step processes, for the tokens/s line: the sequences'
+    tokens (each is seen twice, clean and noised)."""
+    batch, seq = cell["batches"][0][0].shape
+    return "tokens", batch * seq

@@ -2,6 +2,12 @@
 
     python chip_smoke.py             one chip, one process, every phase below
     python chip_smoke.py --chips 4   four chips: launch_np4, then device(4)
+    python chip_smoke.py --grouped-products
+                                     one chip: the expert layer of
+                                     ``parallel/moe.py`` against a loop over
+                                     its experts, and what its grouped
+                                     products cost by ``ragged_dot`` and by
+                                     megablox ``gmm``; nothing else
 
 One chip: the device JAX found, a clean build of the C++ core and
 ``hvd.init()`` on it, the Pallas kernels alone against their references (at
@@ -125,10 +131,14 @@ def _qkv(b, s, h, d, dtype, key):
 
 def kernels(interpret: bool = False, seqs=(512, 777), heads: int = 4,
             head_dim: int = 64, codec_elems: int = 1 << 22,
-            dtypes=("float32", "bfloat16"), causals=(False, True)) -> dict:
+            dtypes=("float32", "bfloat16"), causals=(False, True),
+            grouped=((8, 2, 128, 1024, 4), (8, 2, 128, 608, 32),
+                     (4, 1, 64, 512, 4))) -> dict:
     """The Pallas kernels alone, compiled for the device (``interpret`` off):
     flash forward, dq/dk/dv, the (out, lse) pair the ring hop differentiates
-    through, one ring hop under shard_map, and the three wire codecs
+    through, grouped-query heads under the causal and the block-diffusion
+    mask (``grouped``: query heads, key/value heads, head width, L, block
+    length), one ring hop under shard_map, and the three wire codecs
     bit-exact against their jnp mirror."""
     import jax
     import jax.numpy as jnp
@@ -192,6 +202,36 @@ def kernels(interpret: bool = False, seqs=(512, 777), heads: int = 4,
     for g, a, b in zip(("dq", "dk", "dv"), got, ref):
         _check(checks, f"lse_vjp/{g}", a, b, TOL_F32)
 
+    # Fewer key/value heads than query heads, under the causal mask over L
+    # positions and under the block-diffusion mask over [clean ; noised].
+    for hq, hkv, d, length, block in grouped:
+        for name, mask in (("causal", {"causal": True}),
+                           ("block_diffusion",
+                            {"block_diffusion": (length, block)})):
+            s = length * (2 if name == "block_diffusion" else 1)
+            ks = jax.random.split(jax.random.PRNGKey(7), 4)
+            q = jax.random.normal(ks[0], (2, s, hq, d), jnp.bfloat16)
+            k, v = (jax.random.normal(x, (2, s, hkv, d), jnp.bfloat16)
+                    for x in ks[1:3])
+            w = jax.random.normal(ks[3], q.shape, jnp.float32)
+
+            def out_and_grads(fn, q, k, v):
+                def loss(*a):
+                    out = fn(*a, **mask).astype(jnp.float32)
+                    return jnp.sum(out * w), out
+
+                (_, out), grads = jax.value_and_grad(
+                    loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+                return out, grads
+
+            got = jax.jit(functools.partial(out_and_grads, flash))(q, k, v)
+            ref = jax.jit(functools.partial(out_and_grads, dense_attention))(
+                *f32((q, k, v)))
+            tag = f"gqa/{name}/h={hq}:{hkv}/d={d}/L={length}/B={block}"
+            _check(checks, f"{tag}/out", got[0], ref[0], TOL_BF16_FWD)
+            for g, a, b in zip(("dq", "dk", "dv"), got[1], ref[1]):
+                _check(checks, f"{tag}/{g}", a, b, TOL_BF16_BWD)
+
     # pallas inside lax.switch inside fori_loop inside shard_map: the
     # composition ring_attention(use_flash=True) builds, on a 1-device mesh.
     q, k, v = _qkv(2, s0, heads, head_dim, jnp.bfloat16, key=5)
@@ -231,6 +271,146 @@ def kernels(interpret: bool = False, seqs=(512, 777), heads: int = 4,
                   codecs=codecs)
     _raise_on_failed("kernels", checks)
     assert all(c["bit_exact"] for c in codecs), codecs
+    return report
+
+
+def _expert_layer(tokens, d, f, held, experts, dtype, key, busy=0):
+    """Seeded rows, a router over ``experts`` and ``held`` SwiGLU experts,
+    the first ``busy`` of them in nearly every token's top-k."""
+    import jax
+    import jax.numpy as jnp
+
+    ks = jax.random.split(jax.random.PRNGKey(key), 5)
+    x = jax.random.normal(ks[0], (tokens, d), dtype)
+    router = jax.random.normal(ks[1], (d, experts), jnp.float32) * d ** -0.5
+    if busy:
+        x = x.at[:, 0].set(1.0)
+        router = router.at[0, :busy].add(6.0)
+    w_gate, w_up = (jax.random.normal(k, (held, d, f), jnp.float32)
+                    * d ** -0.5 for k in ks[2:4])
+    w_down = jax.random.normal(ks[4], (held, f, d), jnp.float32) * f ** -0.5
+    return x, router, w_gate, w_up, w_down
+
+
+def grouped_products(tokens: int = 16384, d: int = 2048, f: int = 768,
+                     held: int = 16, experts: int = 128, top_k: int = 8,
+                     capacity_factor: float = 2.25, repeats: int = 5,
+                     megablox: bool = True) -> dict:
+    """What one chip's share of a top-k expert layer costs, forward and
+    backward, under an even router (the rows fit the layer's buffer) and
+    under one with three busy experts (they do not: every row a router can
+    send, in parts), and what its grouped products alone cost by ``jax.lax.
+    ragged_dot`` and by the megablox ``gmm`` that ships with jax, over that
+    buffer and over ``tokens x top_k`` rows: milliseconds, best of
+    ``repeats``.  ``parallel/moe.py`` uses the first; this is the reading
+    behind that."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.parallel import moe
+
+    # The layer against a loop over its experts, values and gradients, at a
+    # size where a third of the row buffer lies past the rows routed (what a
+    # grouped product leaves there must reach neither) and, with two busy
+    # experts, where the rows outgrow the buffer and are walked in parts.
+    checks = []
+
+    def by_loop(x, router, w_gate, w_up, w_down):
+        probs = jax.nn.softmax(x @ router, axis=-1)
+        weights, chosen = jax.lax.top_k(probs, 4)
+        weights = weights / weights.sum(-1, keepdims=True)
+        y = jnp.zeros_like(x)
+        for i in range(w_gate.shape[0]):
+            mine = jnp.sum(jnp.where(chosen == i, weights, 0.0), axis=-1)
+            y = y + mine[:, None] * ((jax.nn.silu(x @ w_gate[i])
+                                      * (x @ w_up[i])) @ w_down[i])
+        return y
+
+    def value_and_grads(fn, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (out, *vjp(jnp.cos(out)))
+
+    for case, busy in (("routed_experts", 0), ("routed_experts_in_parts", 2)):
+        small = _expert_layer(1024, 256, 128, 4, 16, jnp.float32, key=1,
+                              busy=busy)
+        with jax.default_matmul_precision("highest"):
+            got = jax.jit(functools.partial(value_and_grads, lambda *a: (
+                moe.routed_experts(*a, top_k=4, capacity_factor=1.5)[0])))(
+                    *small)
+            ref = jax.jit(functools.partial(value_and_grads, by_loop))(*small)
+        for name, a, b in zip(("y", "dx", "drouter", "dgate", "dup", "ddown"),
+                              got, ref):
+            _check(checks, f"{case}/{name}", a, b, TOL_F32)
+
+    x, router, w_gate, w_up, w_down = _expert_layer(
+        tokens, d, f, held, experts, jnp.bfloat16, key=0)
+
+    def best_ms(fn, *args):
+        jax.block_until_ready(fn(*args))
+        times = []
+        for _ in range(repeats):
+            start = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            times.append(time.perf_counter() - start)
+        return round(1e3 * min(times), 3)
+
+    def layer(x, router, *kernels):
+        y, routing = moe.routed_experts(x, router, *kernels, top_k=top_k,
+                                        capacity_factor=capacity_factor)
+        return jnp.sum(y.astype(jnp.float32) ** 2), routing.load
+
+    step = jax.jit(jax.value_and_grad(layer, argnums=(0, 1, 2, 3, 4),
+                                      has_aux=True))
+    (_, load), _ = step(x, router, w_gate, w_up, w_down)
+    # The layer's buffer (the defaults are SDAR-30B-A3B's share of eight at
+    # 16,384 positions), and every row a router can send.
+    buffer = moe.row_buffer(tokens, top_k, held, experts, capacity_factor)
+    worst = tokens * min(top_k, held)
+    busy = _expert_layer(tokens, d, f, held, experts, jnp.bfloat16, key=0,
+                         busy=3)
+    (_, busy_load), _ = step(*busy)
+    report = {"layer_fwd_bwd_ms": best_ms(step, x, router, w_gate, w_up,
+                                          w_down),
+              "layer_fwd_bwd_ms/in_parts": best_ms(step, *busy),
+              "load": [int(n) for n in load],
+              "load/in_parts": [int(n) for n in busy_load],
+              "buffer": buffer, "worst": worst}
+
+    def products(dot):
+        def fn(rows, sizes, *kernels):
+            def loss(rows, *kernels):
+                kernels = [k.astype(rows.dtype) for k in kernels]
+                h = jax.nn.silu(dot(rows, kernels[0], sizes)) * dot(
+                    rows, kernels[1], sizes)
+                return jnp.sum(dot(h, kernels[2], sizes).astype(jnp.float32))
+            return jax.grad(loss, argnums=(0, 1, 2, 3))(rows, *kernels)
+        return jax.jit(fn)
+
+    dots = {"ragged_dot": jax.lax.ragged_dot}
+    if megablox:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        dots["megablox_gmm"] = lambda a, b, sizes: gmm(
+            a, b, sizes, a.dtype,
+            lambda m, k, n: (512, min(k, 1024), min(n, 1024)))
+    # The buffer and the worst case holding the rows of this router; the
+    # buffer filled (as the layer fills it) and half empty; the worst case
+    # with every row routed: tokens x top_k rows of work.
+    routed = jnp.asarray(load, jnp.int32)
+    cases = {f"rows={buffer}": (buffer, routed),
+             f"rows={worst}": (worst, routed),
+             f"rows={buffer}/filled": (
+                 buffer, routed.at[-1].add(buffer - routed.sum())),
+             f"rows={buffer}/half": (buffer, routed // 2),
+             f"rows={worst}/all_routed": (
+                 worst, jnp.full((held,), worst // held))}
+    for name, dot in dots.items():
+        for case, (rows, sizes) in cases.items():
+            some = jnp.zeros((rows, d), jnp.bfloat16).at[:tokens].set(x)
+            report[f"{name}_fwd_bwd_ms/{case}"] = best_ms(
+                products(dot), some, sizes, w_gate, w_up, w_down)
+    report = emit("grouped_products", checks=checks, **report)
+    _raise_on_failed("grouped_products", checks)
     return report
 
 
@@ -371,6 +551,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run launch_np4 and nothing else")
+    ap.add_argument("--grouped-products", action="store_true",
+                    help="time the expert layer's grouped products, and "
+                         "nothing else")
     ap.add_argument("--worker", action="store_true",
                     help="internal: one launch_np4 worker")
     args = ap.parse_args(argv)
@@ -384,6 +567,9 @@ def main(argv=None) -> int:
     if args.chips == 4:
         launch_np4()
         info = device(expect_count=4)
+    elif args.grouped_products:
+        info = device()
+        grouped_products()
     else:
         info = device()
         native_core()

@@ -1,5 +1,7 @@
-"""Flagship models for the framework's benchmarks (SURVEY.md §6;
-BASELINE.json configs 1-3): MNIST MLP, ResNet family, BERT family."""
+"""The framework's model zoo: MNIST MLP, the ResNet family, VGG and
+Inception-v3, the BERT family (SURVEY.md §6; BASELINE.json configs 1-3), a
+GPT-style causal decoder, and SDAR-MoE (a Qwen3-MoE decoder of grouped-query
+attention and top-k routed experts, trained by block diffusion)."""
 
 from .losses import softmax_cross_entropy  # noqa: F401
 from .mlp import MLP, xent_loss  # noqa: F401
@@ -13,6 +15,10 @@ from .bert import (  # noqa: F401
 )
 from .gpt import (  # noqa: F401
     GPT, GPTConfig, GPT_SMALL, GPT_TINY, lm_loss,
+)
+from .sdar import (  # noqa: F401
+    SDAR, SDARConfig, SDAR_30B_A3B, SDAR_TINY, block_diffusion_loss,
+    noise_blocks,
 )
 from .vgg import VGG, VGG16, VGG19, VGGTiny  # noqa: F401
 from .inception import InceptionV3  # noqa: F401

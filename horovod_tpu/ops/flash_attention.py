@@ -42,10 +42,40 @@ the tile it can see (a 1024-row tile in 256-row steps computes 0.625 of
 the square; whole 128 x 128 tiles would take 0.5625, a 1024 x 1024 tile
 all of it).  Grid tiles wholly above the diagonal or in tail padding are
 predicated off and their index maps hold the nearest live block, so they
-cost no copy.  ``block_q`` and ``block_k`` need not be equal.  Every
-processed row meets a valid key in the first step it takes (column 0
-under a causal mask; a real key otherwise), which keeps the running max
-finite with a -1e30 mask value: no NaN guards needed.
+cost no copy.  ``block_q`` and ``block_k`` need not be equal.  Under the
+causal mask and under ``kv_lens`` every processed row meets a valid key in
+the first step it takes (column 0 under a causal mask; a real key
+otherwise), which keeps the running max finite with a -1e30 mask value: no
+NaN guards needed.  The third mask breaks that, and is guarded where it does.
+
+Three masks: none or the causal diagonal (``causal``), a key length per
+sequence (``kv_lens``, below), and **block diffusion**
+(``block_diffusion=(L, B)``, :func:`block_diffusion_mask`): the sequence is
+``[clean ; noised]``, two copies of L positions in blocks of B, and the live
+area is L^2 (1 + B / L) of the (2L)^2 square.  It is walked in three pieces
+(:func:`_flash_block_diffusion`): clean queries on the clean blocks up to
+their own and noised queries on the clean blocks before theirs are ONE call
+of the kernels, the copies taken as neighbouring sequences whose index maps
+read the same (clean) keys, with the causal walks and one comparison of
+block numbers in the masked steps, ``<=`` or ``<`` by the grid row; the
+noised copy's own blocks, L x B pairs, are plain block-wise products merged
+with the kernels' part through its log-sum-exp.  A noised row of the first
+block sees **no clean key**: in its masked steps the kernels zero a hidden
+pair's probability outright (:func:`_seen`), so the row leaves with a zero
+output and an lse of the mask value, and the merge weighs it by exactly
+zero.
+
+**Grouped-query heads**: k and v may have fewer heads than q (query head
+``i`` reads key/value head ``i // group``).  The forward and dq take a query
+head a grid row and their key/value index maps name its group's head; the
+dkv kernel takes a key/value head a grid row and its streamed axis walks
+the query blocks of the group's heads one after another, so dk / dv gather
+the whole group in float32 before they leave.  A grouped call holds one head
+a grid step (several query heads side by side would want as many different
+key/value heads beside each other).  Calls with as many key/value heads as
+query heads and no block-diffusion mask compile to what they were; the
+others name their kernels ``hvd_flash_fwd`` / ``hvd_flash_dq`` /
+``hvd_flash_dkv``.
 
 ``kv_lens`` (non-causal calls: an int32 length per sequence, BERT's padding
 mask) reaches the kernels as a scalar-prefetch operand, one length per
@@ -122,6 +152,21 @@ class TilePlan(NamedTuple):
         return (batch_heads // self.heads_per_block
                 * (self.seq_pad // self.block_q)
                 * (self.seq_pad // self.block_k))
+
+
+class _Variant(NamedTuple):
+    """What a call adds to the plain kernels (all static).  ``group``: query
+    heads a key/value head (1: as many of each).  ``bd``: the block length
+    of a block-diffusion call, 0 without; the kernels then see the clean and
+    the noised copy as neighbouring sequences (even, odd) of ``q_rows``
+    grid rows each, ``kv_rows`` in the dkv kernel's grid."""
+    group: int = 1
+    bd: int = 0
+    q_rows: int = 1
+    kv_rows: int = 1
+
+
+_PLAIN = _Variant()
 
 
 def heads_per_block(head_dim: int, heads: int) -> int:
@@ -297,7 +342,7 @@ def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit):
 
 
 def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
-            transposed: bool = False):
+            transposed: bool = False, bd: int = 0, strict=0):
     """The float32 score tile q @ k^T * sm_scale ([Tq, Tk]; or its
     transpose k @ q^T), masked where the diagonal or the tail padding
     crosses it.  The dot takes its operands as they arrive."""
@@ -307,7 +352,12 @@ def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
     if masked:
         q_axis, k_axis = (1, 0) if transposed else (0, 1)
         kpos = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
-        if causal:
+        if bd:
+            # Block diffusion over clean keys: a clean query sees the blocks
+            # up to its own, a noised one (``strict`` 1) those before it.
+            qpos = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+            s = jnp.where(kpos // bd + strict <= qpos // bd, s, NEG_INF)
+        elif causal:
             # Padding lives at the tail, so kpos > any real qpos: the
             # causal mask already excludes padded keys.
             qpos = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
@@ -315,6 +365,21 @@ def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
         else:
             s = jnp.where(kpos < valid_len, s, NEG_INF)
     return s
+
+
+def _seen(p, s, masked: bool, bd: int):
+    """``p`` with the pairs the mask hides set to zero outright.  Only a
+    block-diffusion call needs it, in its masked steps: a noised row of the
+    first block sees no clean key at all, so its running maximum (or its
+    lse) is the mask value itself and ``exp(s - m)`` reads 1 where it should
+    read 0.  Every other row has met a visible key by the time it meets a
+    hidden one, and the exponential is zero by itself."""
+    return jnp.where(s > 0.5 * NEG_INF, p, 0.0) if masked and bd else p
+
+
+def _strict(variant: _Variant, rows: int):
+    """1 in the grid rows of a noised copy (the odd sequences), else 0."""
+    return (pl.program_id(0) // rows) % 2 if variant.bd else 0
 
 
 def _head_lanes(plan: TilePlan):
@@ -344,7 +409,7 @@ def _row_to_col(row):
 
 def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, sm_scale: float, causal: bool, plan: TilePlan,
-                valid_len):
+                valid_len, variant: _Variant = _PLAIN):
     """Forward: grid (BH / G, n_q, n_kv).  A query block stays resident
     while key/value blocks stream past it.  The score tile is built
     transposed ([Tk, Tq] = k @ q^T): the online-softmax statistics are then
@@ -359,8 +424,10 @@ def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     n_kv = pl.num_programs(2)
     tile, step = plan.tile_q, plan.step_k
     heads = _head_lanes(plan)
+    bd = variant.bd
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
-                              valid_len=valid_len, transposed=True)
+                              valid_len=valid_len, transposed=True, bd=bd,
+                              strict=_strict(variant, variant.q_rows))
 
     @pl.when(jk == 0)
     def _init():
@@ -389,8 +456,8 @@ def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                                   masked)
                         m_new = jnp.maximum(
                             m, jnp.max(s, axis=0, keepdims=True))
-                        p = jnp.exp(s - m_new)              # [Tk, Tq]
-                        alpha = jnp.exp(m - m_new)
+                        p = _seen(jnp.exp(s - m_new), s, masked, bd)
+                        alpha = jnp.exp(m - m_new)          # p [Tk, Tq]
                         l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
                         acc = acc * alpha + jax.lax.dot_general(  # v^T @ p
                             v, p.astype(v.dtype), _TN,
@@ -433,23 +500,47 @@ def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         jax.lax.fori_loop(0, plan.block_q // tile, q_tile, None)
 
 
-def _operand_spec(rows: int, plan: TilePlan, n_col: int, seq_block):
+def _operand_spec(rows: int, plan: TilePlan, n_col: int, seq_block,
+                  row_of=None):
     """A [rows, lanes] block of an operand [N, S, n_col * lanes]: grid row
     ``r`` is column block ``r % n_col`` of sequence ``r // n_col`` (the
     model's [B, S, H * D] in 128-lane blocks; ``n_col`` is 1 and N = B * H
     in the fallback layout).  ``seq_block(r, i, j, lens)`` picks the block
-    along the sequence."""
-    return pl.BlockSpec(
-        (None, rows, plan.lanes), lambda r, i, j, *lens: (
-            r // n_col, seq_block(r, i, j, lens), r % n_col))
+    along the sequence.  ``row_of(r, j)``, where a call has one, names the
+    operand's row for grid row ``r`` (a query head's key/value head, a
+    noised copy's clean keys, the query heads a key/value head gathers)."""
+    def index(r, i, j, *lens):
+        row = r if row_of is None else row_of(r, j)
+        return row // n_col, seq_block(r, i, j, lens), row % n_col
+
+    return pl.BlockSpec((None, rows, plan.lanes), index)
 
 
-def _row_stat_spec(plan: TilePlan, seq_block):
+def _row_stat_spec(plan: TilePlan, seq_block, row_of=None):
     """One float32 a row, lane-dense: a [BH / G, G, S] array in
     (G, block_q) blocks, a row a head of the grid row."""
     return pl.BlockSpec(
         (None, plan.heads_per_block, plan.block_q),
-        lambda r, i, j, *lens: (r, 0, seq_block(r, i, j, lens)))
+        lambda r, i, j, *lens: (r if row_of is None else row_of(r, j), 0,
+                                seq_block(r, i, j, lens)))
+
+
+def _clean_row(row, rows_per_seq: int):
+    """The same row of the clean copy: sequences lie (clean, noised) by
+    turns, ``rows_per_seq`` rows each."""
+    return row - ((row // rows_per_seq) % 2) * rows_per_seq
+
+
+def _kv_row_of(variant: _Variant):
+    """fwd / dq: the key/value row that the query row ``r`` reads."""
+    if variant == _PLAIN:
+        return None
+
+    def row_of(r, j):
+        row = r if variant.group == 1 else r // variant.group
+        return _clean_row(row, variant.kv_rows) if variant.bd else row
+
+    return row_of
 
 
 def _by_i(r, i, j, lens):
@@ -464,14 +555,23 @@ def _streamed_k(causal: bool, plan: TilePlan, valid_len):
         i, causal, plan, _len_at(r, lens, valid_len)))
 
 
+def _named(variant: _Variant, kernel: str) -> dict:
+    """The kernel arguments a grouped or block-diffusion call adds: the
+    variant, and a name by which a trace tells its three kernels apart
+    (``hvd_flash_fwd`` / ``_dq`` / ``_dkv``).  A plain call adds neither, so
+    its kernels compile to what they were."""
+    return {} if variant == _PLAIN else {"variant": variant,
+                                         "name": f"hvd_flash_{kernel}"}
+
+
 def _kernel_call(kernel, lens, *, grid, in_specs, out_specs, out_shape,
-                 scratch_shapes, interpret, **kernel_args):
+                 scratch_shapes, interpret, name=None, **kernel_args):
     """The ``pallas_call`` of one of the three kernels.  ``lens`` None: the
     kernel's ``valid_len`` is the static one in ``kernel_args``.  Else
     ``lens`` (int32, one per grid row) is a scalar-prefetch operand and
     each grid row takes its own length from it."""
     call_args = dict(out_shape=out_shape, compiler_params=_COMPILER_PARAMS,
-                     interpret=interpret)
+                     interpret=interpret, **({"name": name} if name else {}))
     if lens is None:
         return pl.pallas_call(
             functools.partial(kernel, **kernel_args), grid=grid,
@@ -494,9 +594,9 @@ def _kernel_call(kernel, lens, *, grid, in_specs, out_specs, out_shape,
 # (the step is traced in every process; that time is part of a job's
 # start).  ``inline`` leaves no call in the jaxpr, so an op's name keeps the
 # scope of the layer that made it.
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7), inline=True)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 9), inline=True)
 def _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret, valid_len,
-               lens=None):
+               lens=None, variant=_PLAIN):
     """Forward kernel over operands [N, S, n_col * lanes] (S already
     padded; see :func:`_operand_spec`): out likewise + the rows' lse
     [BH / G, G, S]."""
@@ -504,11 +604,12 @@ def _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret, valid_len,
     n_col, g = width // plan.lanes, plan.heads_per_block
     bq, bk = plan.block_q, plan.block_k
     q_spec = _operand_spec(bq, plan, n_col, _by_i)
-    kv_spec = _operand_spec(bk, plan, n_col,
-                            _streamed_k(causal, plan, valid_len))
+    kv_spec = _operand_spec(bk, plan, kb.shape[2] // plan.lanes,
+                            _streamed_k(causal, plan, valid_len),
+                            _kv_row_of(variant))
     return _kernel_call(
         _mha_kernel, lens, sm_scale=sm_scale, causal=causal, plan=plan,
-        valid_len=valid_len, interpret=interpret,
+        valid_len=valid_len, interpret=interpret, **_named(variant, "fwd"),
         grid=(n * n_col, s // bq, s // bk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, _row_stat_spec(plan, _by_i)],
@@ -547,7 +648,8 @@ def _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan: TilePlan):
 
 def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
                        dq_ref, acc_ref, delta_ref, *, sm_scale: float,
-                       causal: bool, plan: TilePlan, valid_len):
+                       causal: bool, plan: TilePlan, valid_len,
+                       variant: _Variant = _PLAIN):
     """dQ: grid (BH / G, n_q, n_kv); key/value blocks stream past a
     resident query block while dq accumulates in f32 scratch.  P is
     re-materialized from the lse residual: the [S, S] score matrix never
@@ -565,8 +667,10 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     n_kv = pl.num_programs(2)
     tile, step = plan.tile_q, plan.step_k
     heads = _head_lanes(plan)
+    bd = variant.bd
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
-                              valid_len=valid_len)
+                              valid_len=valid_len, bd=bd,
+                              strict=_strict(variant, variant.q_rows))
 
     @pl.when(jk == 0)
     def _init():
@@ -596,7 +700,7 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
                     k, v = k_ref[cols, :], v_ref[cols, :]   # [Tk, lanes]
                     for (q, do, lse, delta), h in zip(resident, heads):
                         s = score(q, k, row0 + off, col0, masked)
-                        p = jnp.exp(s - lse)                # [Tq, Tk]
+                        p = _seen(jnp.exp(s - lse), s, masked, bd)  # [Tq, Tk]
                         dp = jax.lax.dot_general(
                             do, v, _NT, preferred_element_type=jnp.float32)
                         ds = p * (dp - delta) * sm_scale
@@ -619,7 +723,7 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
 def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
                         dk_ref, dv_ref, dk_acc, dv_acc, delta_ref, *,
                         sm_scale: float, causal: bool, plan: TilePlan,
-                        valid_len):
+                        valid_len, variant: _Variant = _PLAIN):
     """dK/dV: grid (BH / G, n_kv, n_q); query/dO/statistic blocks stream
     past a resident key block while dk/dv accumulate in f32 scratch, the
     block's G heads side by side in [Tk, lanes] accumulators (operands
@@ -634,17 +738,25 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     carry a zero dO); of the static ``tile_k // step_q`` steps the
     diagonal crosses, the d-th sees the tile's first ``(d + 1) * step_q``
     keys only.  Without a causal mask every step sees the whole tile, and
-    padded keys are masked in each."""
-    jk, iq = pl.program_id(1), pl.program_id(2)
-    n_q = pl.num_programs(2)
+    padded keys are masked in each.
+
+    Grouped queries (``variant.group`` query heads read this key/value
+    head): the streamed axis walks the query blocks of one head after
+    another, ``group * n_q`` grid steps, and dk / dv gather all of them
+    before they leave."""
+    jk, t = pl.program_id(1), pl.program_id(2)
+    last_t, bd = pl.num_programs(2) - 1, variant.bd
+    iq = t if variant.group == 1 else t % (pl.num_programs(2)
+                                           // variant.group)
     tile, step = plan.tile_k, plan.step_q
     n = plan.block_q // step
     last = _steps(valid_len, step)
     heads = _head_lanes(plan)
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
-                              valid_len=valid_len, transposed=True)
+                              valid_len=valid_len, transposed=True, bd=bd,
+                              strict=_strict(variant, variant.kv_rows))
 
-    @pl.when(iq == 0)
+    @pl.when(t == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -671,7 +783,8 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
                     ps, dss = [], []
                     for g, (k, v) in enumerate(kv):
                         s = score(q, k, row0, col0, masked)
-                        p = jnp.exp(s - lse_ref[g:g + 1, rows])  # [Tk, Tq]
+                        p = _seen(jnp.exp(s - lse_ref[g:g + 1, rows]), s,
+                                  masked, bd)               # [Tk, Tq]
                         dp = jax.lax.dot_general(
                             v, do, _NT, preferred_element_type=jnp.float32)
                         ds = p * (dp - delta_ref[g:g + 1, rows]) * sm_scale
@@ -713,17 +826,19 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
 
         jax.lax.fori_loop(0, plan.block_k // tile, k_tile, None)
 
-    @pl.when(iq == n_q - 1)
+    @pl.when(t == last_t)
     def _flush():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11), inline=True)
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 13),
+                   inline=True)
 def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
-               interpret, valid_len, lens=None):
+               interpret, valid_len, lens=None, variant=_PLAIN):
     n, s, width = qb.shape
     n_col, g = width // plan.lanes, plan.heads_per_block
+    kv_col, group = kb.shape[2] // plan.lanes, variant.group
     bq, bk = plan.block_q, plan.block_k
     if lens is not None:
         # A row at or beyond its length came out as a constant zero: what
@@ -740,11 +855,12 @@ def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
 
     # dq: q-block fixed per outer step, k/v stream on the inner grid dim.
     q_by_i = _operand_spec(bq, plan, n_col, _by_i)
-    kv_by_j = _operand_spec(bk, plan, n_col,
-                            _streamed_k(causal, plan, valid_len))
+    kv_by_j = _operand_spec(bk, plan, kv_col,
+                            _streamed_k(causal, plan, valid_len),
+                            _kv_row_of(variant))
     row_by_i = _row_stat_spec(plan, _by_i)
     dq = _kernel_call(
-        _mha_bwd_dq_kernel, lens, **kernel_args,
+        _mha_bwd_dq_kernel, lens, **kernel_args, **_named(variant, "dq"),
         grid=(n * n_col, s // bq, s // bk),
         in_specs=[q_by_i, kv_by_j, kv_by_j, q_by_i, q_by_i, row_by_i,
                   row_by_i],
@@ -756,18 +872,26 @@ def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
 
     # dk/dv: k-block fixed per outer step, q/do/stats stream inside, from
     # the first query block that sees it to the last real one.
+    # Grouped queries: the streamed axis holds the query blocks of one
+    # head of the group after another's.
+    n_q = s // bq
+
     def streamed_q(r, i, j, lens):
         first = (i * bk) // bq if causal else 0
-        return jnp.minimum(jnp.maximum(j, first),
+        return jnp.minimum(jnp.maximum(j if group == 1 else j % n_q, first),
                            (_len_at(r, lens, valid_len) - 1) // bq)
 
-    q_by_j = _operand_spec(bq, plan, n_col, streamed_q)
-    kv_by_i = _operand_spec(bk, plan, n_col, _by_i)
-    row_by_j = _row_stat_spec(plan, streamed_q)
+    q_row_of = None if group == 1 else (lambda r, j: r * group + j // n_q)
+    k_row_of = (None if not variant.bd else
+                lambda r, j: _clean_row(r, variant.kv_rows))
+    q_by_j = _operand_spec(bq, plan, n_col, streamed_q, q_row_of)
+    kv_by_i = _operand_spec(bk, plan, kv_col, _by_i)
+    kv_in = _operand_spec(bk, plan, kv_col, _by_i, k_row_of)
+    row_by_j = _row_stat_spec(plan, streamed_q, q_row_of)
     dk, dv = _kernel_call(
-        _mha_bwd_dkv_kernel, lens, **kernel_args,
-        grid=(n * n_col, s // bk, s // bq),
-        in_specs=[q_by_j, kv_by_i, kv_by_i, q_by_j, q_by_j, row_by_j,
+        _mha_bwd_dkv_kernel, lens, **kernel_args, **_named(variant, "dkv"),
+        grid=(kb.shape[0] * kv_col, s // bk, group * n_q),
+        in_specs=[q_by_j, kv_in, kv_in, q_by_j, q_by_j, row_by_j,
                   row_by_j],
         out_specs=[kv_by_i, kv_by_i],
         out_shape=[_out_struct(kb.shape, kb.dtype, kb),
@@ -779,9 +903,9 @@ def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_lse(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
-               valid_len):
+               valid_len, variant=_PLAIN):
     """Differentiable kernel entry over operands in the kernels' layout
     ([N, S, n_col * lanes], S already padded), returning ``(out, lse)``,
     the pair ring attention merges across hops (the public
@@ -796,22 +920,34 @@ def _flash_lse(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
     int32 lengths of a ``kv_lens`` call, one per row of the grid.
     """
     return _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret,
-                      valid_len, lens)
+                      valid_len, lens, variant)
 
 
 def _flash_lse_fwd(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
-                   valid_len):
+                   valid_len, variant):
     out, lse = _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret,
-                          valid_len, lens)
+                          valid_len, lens, variant)
     return (out, lse), (qb, kb, vb, lens, out, lse)
 
 
-def _flash_lse_bwd(sm_scale, causal, plan, interpret, valid_len, res,
-                   cotangents):
+def _flash_lse_bwd(sm_scale, causal, plan, interpret, valid_len, variant,
+                   res, cotangents):
     qb, kb, vb, lens, ob, lse = res
     dob, dlse = cotangents
-    return (*_flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal,
-                        plan, interpret, valid_len, lens=lens), None)
+    dq, dk, dv = _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal,
+                            plan, interpret, valid_len, lens, variant)
+    if variant.bd:
+        # The noised copy's rows read the clean copy's keys: what the dkv
+        # kernel gathered in their name belongs to those.
+        def to_clean(x):
+            rows = x.shape[0] * (x.shape[2] // plan.lanes)
+            pair = x.reshape(rows // (2 * variant.kv_rows), 2, -1)
+            both = pair[:, 0].astype(jnp.float32) + pair[:, 1]
+            return jnp.stack([both.astype(x.dtype),
+                              jnp.zeros_like(pair[:, 1])], 1).reshape(x.shape)
+
+        dk, dv = to_clean(dk), to_clean(dv)
+    return dq, dk, dv, None
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -831,11 +967,57 @@ def _kv_lens(kv_lens, batch: int, seq: int, causal: bool):
     return jnp.clip(kv_lens, 1, seq)
 
 
-def _dense(q, k, v, causal, scale, kv_lens):
+def block_diffusion_mask(length: int, block: int):
+    """[2L, 2L] booleans, true where query i sees key j, over a sequence
+    laid out as ``[clean ; noised]`` (BD3-LM, arXiv:2503.09573, section 3.1):
+    with ``blk(i) = (i mod L) // block``, a clean query sees the clean keys
+    of the blocks up to its own and no noised key; a noised query sees the
+    noised keys of its own block and the clean keys of the blocks before
+    it."""
+    pos = jnp.arange(2 * length)
+    blk, noised = (pos % length) // block, pos >= length
+    qb, kb, qn, kn = blk[:, None], blk[None, :], noised[:, None], noised[None]
+    return jnp.where(qn, jnp.where(kn, kb == qb, kb < qb), ~kn & (kb <= qb))
+
+
+def _block_diffusion(block_diffusion, seq: int, causal: bool, kv_lens):
+    """``(L, B)`` checked against the call it came with."""
+    length, block = block_diffusion
+    if causal or kv_lens is not None:
+        raise ValueError("block_diffusion is a mask of its own: pass neither "
+                         "causal=True nor kv_lens with it")
+    if seq != 2 * length or block < 1 or length % block:
+        raise ValueError(
+            f"block_diffusion={block_diffusion}: the sequence holds the clean "
+            f"and the noised copy, 2 x {length} positions (got {seq}), in "
+            "whole blocks")
+    return length, block
+
+
+def _group(q, k, v) -> int:
+    """Query heads a key/value head: head ``i`` reads key/value head
+    ``i // group``."""
+    h, hkv = q.shape[2], k.shape[2]
+    if k.shape != v.shape or hkv < 1 or h % hkv or (
+            q.shape[:2] + q.shape[3:] != k.shape[:2] + k.shape[3:]):
+        raise ValueError(f"q {q.shape} against k {k.shape}, v {v.shape}: the "
+                         "key/value heads must divide the query heads")
+    return h // hkv
+
+
+def _dense(q, k, v, causal, scale, kv_lens, block_diffusion=None):
     b, s = q.shape[:2]
     scale = q.shape[-1] ** -0.5 if scale is None else scale
+    group = _group(q, k, v)
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
+    if block_diffusion is not None:
+        mask = block_diffusion_mask(
+            *_block_diffusion(block_diffusion, s, causal, kv_lens))
+        logits = jnp.where(mask[None, None], logits,
+                           jnp.finfo(jnp.float32).min)
     if causal:
         mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
@@ -852,10 +1034,12 @@ def _dense(q, k, v, causal, scale, kv_lens):
 
 
 def dense_attention(q, k, v, causal: bool = False,
-                    scale: Optional[float] = None, kv_lens=None):
+                    scale: Optional[float] = None, kv_lens=None,
+                    block_diffusion=None):
     """Reference-math dense attention over [B, S, H, D] (fp32 softmax).
-    ``kv_lens`` as in :func:`flash_attention`."""
-    out, _ = _dense(q, k, v, causal, scale, kv_lens)
+    ``kv_lens``, ``block_diffusion`` and fewer key/value heads than query
+    heads as in :func:`flash_attention`."""
+    out, _ = _dense(q, k, v, causal, scale, kv_lens, block_diffusion)
     return out
 
 
@@ -866,51 +1050,135 @@ def dense_attention_with_lse(q, k, v, causal: bool = False,
     return _dense(q, k, v, causal, scale, None)
 
 
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret, kv_lens):
-    """``(out, lse)`` of the kernels, or of the dense fallback off-TPU."""
-    b, s, h, d = q.shape
-    if interpret is None:
-        if jax.default_backend() != "tpu":
-            return _dense(q, k, v, causal, scale, kv_lens)
-        interpret = False
-    sm_scale = d ** -0.5 if scale is None else scale
-    plan = tile_plan(s, d, q.dtype.itemsize, causal, block_q, block_k,
-                     heads=h)
-    s_pad = plan.seq_pad
-    if s_pad != s:
-        pad = [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]
-        q = jnp.pad(q, pad)
-        k = jnp.pad(k, pad)
-        v = jnp.pad(v, pad)
-
+def _kernel_layout(plan: TilePlan, n: int, s_pad: int, d: int):
+    """``(to_kernel, from_kernel)`` between [n, s_pad, heads, d] and what the
+    kernels take."""
     if plan.lane_dense:
         # The model's own layout: heads side by side in the minor dim.
         def to_kernel(x):
-            return x.reshape(b, s_pad, h * d)
+            return x.reshape(n, s_pad, x.shape[2] * d)
 
         def from_kernel(x):
-            return x.reshape(b, s_pad, h, d)
+            return x.reshape(n, s_pad, x.shape[2] // d, d)
     else:
         # No whole number of heads fills 128 lanes: one head a grid row,
         # turned round under a scope that says so on the profiler's clock
         # (docs/observability.md); no op carries it on the path above.
         def to_kernel(x):
             with jax.named_scope("hvd_flash_relayout"):
-                return x.transpose(0, 2, 1, 3).reshape(b * h, s_pad, d)
+                return x.transpose(0, 2, 1, 3).reshape(-1, s_pad, d)
 
         def from_kernel(x):
             with jax.named_scope("hvd_flash_relayout"):
-                return x.reshape(b, h, s_pad, d).transpose(0, 2, 1, 3)
+                return x.reshape(n, -1, s_pad, d).transpose(0, 2, 1, 3)
 
+    return to_kernel, from_kernel
+
+
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, kv_lens,
+           block_diffusion=None):
+    """``(out, lse)`` of the kernels, or of the dense fallback off-TPU."""
+    b, s, h, d = q.shape
+    group = _group(q, k, v)
+    if interpret is None:
+        if jax.default_backend() != "tpu":
+            return _dense(q, k, v, causal, scale, kv_lens, block_diffusion)
+        interpret = False
+    sm_scale = d ** -0.5 if scale is None else scale
+    if block_diffusion is not None:
+        return _flash_block_diffusion(
+            q, k, v, *_block_diffusion(block_diffusion, s, causal, kv_lens),
+            sm_scale, block_q, block_k, bool(interpret))
+    if group > 1 and kv_lens is not None:
+        raise ValueError("kv_lens with fewer key/value heads than query "
+                         "heads is not built: the dkv kernel's grid rows "
+                         "are key/value heads")
+    # Grouped queries: one head a grid step (a block of several query heads
+    # would want as many different key/value heads beside each other).
+    plan = tile_plan(s, d, q.dtype.itemsize, causal, block_q, block_k,
+                     heads=h if group == 1 else 1)
+    s_pad = plan.seq_pad
+    if s_pad != s:
+        pad = [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]
+        q = jnp.pad(q, pad)
+        k = jnp.pad(k, pad)
+        v = jnp.pad(v, pad)
+    to_kernel, from_kernel = _kernel_layout(plan, b, s_pad, d)
     # One length per row of the kernels' grid.
     lens = (None if kv_lens is None else
             jnp.repeat(_kv_lens(kv_lens, b, s, causal),
                        h // plan.heads_per_block))
+    variant = _PLAIN if group == 1 else _Variant(group=group)
     out, lse = _flash_lse(to_kernel(q), to_kernel(k), to_kernel(v), lens,
-                          sm_scale, causal, plan, bool(interpret), s)
+                          sm_scale, causal, plan, bool(interpret), s, variant)
     out = from_kernel(out)[:, :s]
     lse = lse.reshape(b, h, s_pad)[:, :, :s]
     return out, lse
+
+
+def _flash_block_diffusion(q, k, v, length, block, sm_scale, block_q,
+                           block_k, interpret):
+    """``(out, lse)`` under the block-diffusion mask.  Its live area is a
+    quarter of the 2L x 2L square and lies in three pieces, each walked by
+    what suits it:
+
+    - clean queries on clean keys (blocks up to the query's own) and noised
+      queries on clean keys (blocks before it): both stop at the diagonal
+      as a causal call does and differ in one comparison, so they are one
+      call of the kernels, the two copies taken as neighbouring sequences
+      of L rows that read the same keys (no operand is cut or copied: the
+      index maps send a noised row to the clean copy's keys).  dk and dv of
+      the two come out apart and are added here;
+    - noised queries on the noised keys of their own block: L x B pairs a
+      head, as plain block-wise products under ``hvd_flash_block_diag``,
+      merged with the kernel's part through its log-sum-exp (the merge's
+      backward reaches the kernels as the cotangent of lse).
+
+    A noised row of the first block sees no clean key: the kernels give it
+    a zero output and an lse of the mask value, and the merge then weighs it
+    by exactly zero."""
+    b, _, h, d = q.shape
+    hkv, group = k.shape[2], _group(q, k, v)
+    plan = tile_plan(length, d, q.dtype.itemsize, True, block_q, block_k,
+                     heads=h if group == 1 else 1)
+    if plan.step_q % block or plan.step_k % block:
+        raise ValueError(
+            f"block_diffusion: the block length {block} must divide the "
+            f"kernels' steps ({plan.step_q}, {plan.step_k})")
+    s_pad, g = plan.seq_pad, plan.heads_per_block
+
+    def halves(x):                    # [b, 2L, H, D] -> [2b, L_pad, H, D]
+        x = x.reshape(2 * b, length, *x.shape[2:])
+        return jnp.pad(x, [(0, 0), (0, s_pad - length), (0, 0), (0, 0)])
+
+    to_kernel, from_kernel = _kernel_layout(plan, 2 * b, s_pad, d)
+    variant = _Variant(group, block, h // g, hkv // g)
+    out, lse = _flash_lse(*(to_kernel(halves(x)) for x in (q, k, v)), None,
+                          sm_scale, True, plan, interpret, length, variant)
+    out = from_kernel(out)[:, :length].reshape(b, 2, length, h, d)
+    lse = lse.reshape(b, 2, h, s_pad)[..., :length]
+    with jax.named_scope("hvd_flash_block_diag"):
+        n = length // block
+
+        def blocks(x):                # the noised copy, block by block
+            return x[:, length:].reshape(b, n, block, hkv, -1, d)
+
+        kn, vn = blocks(k)[:, :, :, :, 0], blocks(v)[:, :, :, :, 0]
+        own = jnp.einsum("bnqkgd,bnjkd->bnkgqj", blocks(q), kn,
+                         preferred_element_type=jnp.float32) * sm_scale
+        # The kernels' part of a noised row, as [b, n, hkv, group, block].
+        before = lse[:, 1].reshape(b, hkv, group, n, block).transpose(
+            0, 3, 1, 2, 4)
+        total = jnp.logaddexp(before, jax.scipy.special.logsumexp(own, -1))
+        out_own = jnp.einsum(
+            "bnkgqj,bnjkd->bnqkgd",
+            jnp.exp(own - total[..., None]).astype(v.dtype), vn)
+        weight = jnp.exp(before - total).transpose(0, 1, 4, 2, 3)
+        noised = (out[:, 1] * weight.reshape(b, length, h, 1).astype(
+            out.dtype) + out_own.reshape(b, length, h, d))
+        lse_noised = total.transpose(0, 2, 3, 1, 4).reshape(b, h, length)
+    return (jnp.concatenate([out[:, 0], noised], axis=1),
+            jnp.concatenate([lse[:, 0], lse_noised], axis=-1))
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = False,
@@ -928,8 +1196,11 @@ def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None, kv_lens=None):
-    """Attention over [batch, seq, heads, head_dim].
+                    interpret: Optional[bool] = None, kv_lens=None,
+                    block_diffusion=None):
+    """Attention over q [batch, seq, heads, head_dim] and k, v [batch, seq,
+    kv_heads, head_dim]; with fewer key/value heads than query heads, query
+    head ``i`` reads key/value head ``i // (heads // kv_heads)``.
 
     On TPU this is the Pallas kernel; elsewhere it falls back to the dense
     implementation (identical math) unless ``interpret=True`` forces the
@@ -940,7 +1211,12 @@ def flash_attention(q, k, v, causal: bool = False,
     after them.  Keys at or beyond the length are masked for every query,
     and the rows at or beyond it come out zero and pass no gradient on,
     here and in :func:`dense_attention`.
+
+    ``block_diffusion=(L, B)``: the sequence is ``[clean ; noised]``, two
+    copies of L positions in blocks of B, under
+    :func:`block_diffusion_mask` (neither ``causal`` nor ``kv_lens`` with
+    it).
     """
     out, _ = _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-                    kv_lens)
+                    kv_lens, block_diffusion)
     return out

@@ -1,18 +1,37 @@
-"""Expert parallelism: switch-routed mixture-of-experts over a mesh axis.
+"""Expert parallelism: mixtures of experts, two ways.
 
 Absent in the reference (SURVEY.md §2.7 — its alltoall is the primitive EP
-would need).  TPU-native design: one expert (or expert group) per ep rank;
-top-1 (switch) routing with a fixed capacity per expert so every shape is
-static; the token dispatch and return are each ONE ``lax.all_to_all`` on
-ICI — the canonical MoE communication pattern.
+would need).
 
+:func:`switch_moe` is the switch layer over a mesh axis: one expert (or
+expert group) per ep rank; top-1 routing with a fixed capacity per expert so
+every shape is static; the token dispatch and return are each ONE
+``lax.all_to_all`` on ICI, the canonical MoE communication pattern.
 Dropped tokens (over capacity) pass through with a zero expert output,
-scaled by their gate as usual — the standard switch-transformer behavior.
+scaled by their gate as usual: the standard switch-transformer behavior.
+
+:func:`routed_experts` is one chip's share of a layer of many experts
+(Qwen3-MoE's form: softmax router over all experts, top-k, weights
+renormalised over the chosen k, SwiGLU experts).  The chip is told which
+consecutive experts it holds; it routes over all of them and computes the
+part of each token's sum that its own experts contribute.  Nothing is
+dropped: the rows routed here are sorted by expert and multiplied in grouped
+matrix products (``jax.lax.ragged_dot``) over a row buffer of the caller's
+``capacity_factor`` times what an even router sends here: the cost is the
+buffer's, not ``tokens x top_k``'s, and while the rows fit it does not follow
+the data.  A step whose rows do not fit walks every row a router can send,
+in parts (a ``lax.cond``, taken while the step runs).  It has no exchange:
+what the other chips' experts would add is not there (ROADMAP Reach B1 keeps
+the all-to-all).  (megablox's ``gmm``, which ships with jax, is a quarter
+faster at these sizes on a v5e but declares no ``vma`` on its outputs, so
+``shard_map`` refuses it under ``check_vma``: PERF.md, PR 34.)
 """
 
 from __future__ import annotations
 
-from typing import Callable
+import functools
+import math
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -98,3 +117,197 @@ def load_balancing_loss(x, router_kernel, axis_name: str = "ep"):
                     axis=0)
     prob = jnp.mean(gates, axis=0)
     return n_expert * jnp.sum(frac * prob)
+
+
+# ---------------------------------------------------------------------------
+# One chip's share of a layer of many experts
+# ---------------------------------------------------------------------------
+
+
+class Routing(NamedTuple):
+    """What the router decided for [T] tokens (float32, int32)."""
+    probs: jax.Array        # [T, E] softmax over all experts
+    experts: jax.Array      # [T, k] the chosen experts, best first
+    weights: jax.Array      # [T, k] their weights in the token's sum
+    load: jax.Array         # [held] rows routed to each held expert
+
+
+def route(x, router_kernel, top_k: int, first_expert: int, held: int,
+          renormalize: bool = True) -> Routing:
+    """Softmax router over every expert, in float32 whatever ``x`` is kept
+    in (the product at "highest" precision: top-k is discrete, and a
+    bfloat16 pass flips the choices whose probabilities nearly tie)."""
+    logits = jnp.dot(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = lax.top_k(probs, top_k)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    load = jnp.sum(jax.nn.one_hot(_local(experts, first_expert, held), held,
+                                  dtype=jnp.int32), axis=(0, 1))
+    return Routing(probs, experts, weights, load)
+
+
+def _local(experts, first_expert: int, held: int):
+    """The chosen experts as numbers among the held ones, ``held`` itself
+    for one that is absent (it sorts last and is no one-hot class)."""
+    local = experts - first_expert
+    return jnp.where((local >= 0) & (local < held), local, held)
+
+
+def row_buffer(tokens: int, top_k: int, held: int, experts: int,
+               capacity_factor: float) -> int:
+    """The rows of the buffer a call takes while the rows routed here fit
+    it: ``capacity_factor`` times what an even router sends here
+    (``tokens x top_k x held / experts``) in whole 128-row tiles, and never
+    more than any router can send (every token's ``min(top_k, held)``
+    choices)."""
+    even = tokens * top_k * held / experts
+    return min(tokens * min(top_k, held),
+               math.ceil(even * capacity_factor / 128) * 128)
+
+
+def _swiglu_rows(rows, group_sizes, w_gate, w_up, w_down):
+    """``down(silu(gate(x)) * up(x))`` of rows sorted by expert: three
+    grouped products."""
+    with jax.named_scope("hvd_moe_experts"):
+        gate = lax.ragged_dot(rows, w_gate.astype(rows.dtype), group_sizes)
+        up = lax.ragged_dot(rows, w_up.astype(rows.dtype), group_sizes)
+        return lax.ragged_dot(jax.nn.silu(gate) * up,
+                              w_down.astype(rows.dtype), group_sizes)
+
+
+def _held_part(capacity: int, x, local, weights, w_gate, w_up, w_down):
+    """The held experts' part of every token's sum through a row buffer of
+    ``capacity`` rows (at least as many as are routed here)."""
+    tokens, top_k = local.shape
+    held = w_gate.shape[0]
+    with jax.named_scope("hvd_moe_route"):
+        flat = local.reshape(-1)                    # absent experts: ``held``
+        order = jnp.argsort(flat, stable=True)[:capacity]
+        sizes = jnp.sum(jax.nn.one_hot(flat, held, dtype=jnp.int32), axis=0)
+        live = jnp.arange(capacity) < jnp.sum(sizes)
+        # The last group takes the buffer's empty tail (zero rows in, zero
+        # rows out): the products then cost what the buffer costs whatever
+        # the router did, and a step's time does not follow its data.
+        sizes = sizes.at[-1].add(capacity - jnp.sum(sizes))
+        token = order // top_k
+        # The buffer's rows past the routed ones are no token's: zeros on
+        # the way in, which cuts their cotangent off on the way back.
+        rows = jnp.where(live[:, None], x[token], jnp.zeros((), x.dtype))
+    out = _swiglu_rows(rows, sizes, w_gate, w_up, w_down)
+    with jax.named_scope("hvd_moe_route"):
+        # Weighted in float32, gathered back in the activations' dtype: a
+        # token's sum has at most min(top_k, held) terms.
+        scale = jnp.where(live, weights.reshape(-1)[order], 0.0)
+        out = (out.astype(jnp.float32) * scale[:, None]).astype(x.dtype)
+        return jnp.zeros_like(x).at[token].add(
+            jnp.where(live[:, None], out, jnp.zeros_like(out)))
+
+
+def _in_parts(parts: int, fn, summed: int, token_args, kernels):
+    """``fn(*token_args, *kernels)`` a ``1 / parts`` of the tokens at a time
+    (a scan; ``parts`` divides them): its per-token results laid end to end,
+    its last ``summed`` results (the kernels' gradients) added up.  A part's
+    temporaries are a part's size; under ``jax.vjp`` a part is recomputed
+    rather than kept."""
+    def split(a):
+        return a.reshape(parts, a.shape[0] // parts, *a.shape[1:])
+
+    def body(total, part):
+        out = jax.checkpoint(fn)(*part, *kernels)
+        out = out if isinstance(out, tuple) else (out,)
+        mine = len(out) - summed
+        return (tuple(t + o for t, o in zip(total, out[mine:])), out[:mine])
+
+    start = tuple(jnp.zeros_like(k) for k in kernels[len(kernels) - summed:])
+    total, per_token = lax.scan(body, start, tuple(map(split, token_args)))
+    joined = tuple(a.reshape(-1, *a.shape[2:]) for a in per_token)
+    return (*joined, *total) if summed or len(joined) > 1 else joined[0]
+
+
+def _fitting(rows: int, fn, summed: int, token_args, kernels):
+    """``fn(capacity, *token_args, *kernels)`` through a buffer of ``rows``
+    rows where the rows routed here fit it, chosen while the step runs
+    (``lax.cond``: the work done is the taken side's alone).  Where they do
+    not, every row a router can send is walked in the fewest parts that are
+    no larger than that buffer, so that a side that hardly ever runs is not
+    what the step's memory is sized by."""
+    local, tokens = token_args[1], token_args[0].shape[0]
+    held = kernels[0].shape[0]
+    worst = tokens * min(local.shape[1], held)
+    if rows >= worst:
+        return fn(worst, *token_args, *kernels)
+    parts = next(p for p in range(-(-worst // rows), tokens + 1)
+                 if tokens % p == 0)
+    return lax.cond(
+        jnp.sum(local < held) <= rows, functools.partial(fn, rows),
+        lambda *a: _in_parts(parts, functools.partial(fn, worst // parts),
+                             summed, a[:len(token_args)],
+                             a[len(token_args):]),
+        *token_args, *kernels)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dropless(rows: int, x, local, weights, w_gate, w_up, w_down):
+    """``_held_part`` through a buffer of ``rows`` rows, or in parts over
+    all a router can send where more are routed here.  Forward and backward
+    each look at the rows themselves and nothing of a buffer's size is kept
+    between them: the backward recomputes the forward of the side it
+    takes."""
+    return _fitting(rows, _held_part, 0, (x, local, weights),
+                    (w_gate, w_up, w_down))
+
+
+def _dropless_fwd(rows, *args):
+    return _dropless(rows, *args), args
+
+
+def _dropless_bwd(rows, args, g):
+    x, local, weights, *kernels = args
+
+    def grads(capacity, x, local, weights, g, *kernels):
+        _, vjp = jax.vjp(
+            lambda x, w, *k: _held_part(capacity, x, local, w, *k),
+            x, weights, *kernels)
+        return vjp(g)
+
+    dx, dweights, *dkernels = _fitting(
+        rows, grads, len(kernels), (x, local, weights, g), tuple(kernels))
+    return (dx, None, dweights, *dkernels)
+
+
+_dropless.defvjp(_dropless_fwd, _dropless_bwd)
+
+
+def routed_experts(x, router_kernel, w_gate, w_up, w_down, *, top_k: int,
+                   capacity_factor: float, first_expert: int = 0,
+                   renormalize: bool = True):
+    """One chip's share of a top-k mixture of SwiGLU experts.
+
+    Args:
+      x: [tokens, d], in the activations' dtype.
+      router_kernel: [d, experts], the router over ALL experts.
+      w_gate, w_up: [held, d, f]; w_down: [held, f, d]: the experts this
+        chip holds, experts ``first_expert`` .. ``first_expert + held``.
+      top_k: experts a token; ``renormalize``: the chosen k weights are
+        divided by their sum (``norm_topk_prob``).
+      capacity_factor: the row buffer over what an even router sends here
+        (:func:`row_buffer`), as :func:`switch_moe`'s, but nothing is
+        dropped past it: a step that routes more rows here takes them all,
+        in parts, at the cost of every row a router can send.  The caller
+        knows how alike its tokens are, and so how uneven its router.
+
+    Returns ``(y, routing)``: y [tokens, d] is the sum over the token's
+    chosen experts **that are held here** of weight x expert(x) (with every
+    expert held, the whole layer); the weights come from the router over all
+    experts.  No token is dropped whatever the imbalance."""
+    tokens, held, experts = x.shape[0], w_gate.shape[0], router_kernel.shape[1]
+    with jax.named_scope("hvd_moe_route"):
+        routing = route(x, router_kernel, top_k, first_expert, held,
+                        renormalize)
+        local = _local(routing.experts, first_expert, held)
+    rows = row_buffer(tokens, top_k, held, experts, capacity_factor)
+    y = _dropless(rows, x, local, routing.weights.astype(jnp.float32),
+                  w_gate, w_up, w_down)
+    return y, routing

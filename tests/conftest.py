@@ -39,6 +39,7 @@ LONG_FILES = (
     "tests/single/test_ops_jit_quantized_allreduce.py",
     "tests/single/test_ops_jit_quantized_allreduce_bits.py",
     "tests/integration/test_matrix.py",
+    "tests/single/test_flash_gqa_block_diffusion.py",
     "tests/single/test_flash_attention_grads.py",
     "tests/single/test_ops_jit_schedule_parity_int8.py",
     "tests/single/test_ops_jit_schedule_matrix_int8.py",
@@ -47,6 +48,7 @@ LONG_FILES = (
     "tests/parallel/test_multiprocess.py",
     "tests/single/test_ring_attention.py",
     "tests/single/test_flash_attention.py",
+    "tests/benchmark/test_sdar_cell.py",
 )
 
 

@@ -50,11 +50,31 @@ def test_native_core_and_eager_phases():
         hvd.shutdown()
 
 
+def test_grouped_products_phase_tiny():
+    """The expert layer against a loop over its experts (values, gradients)
+    and the timing table's keys; megablox's kernels want a TPU."""
+    report = chip_smoke.grouped_products(tokens=256, d=32, f=16, held=4,
+                                         experts=16, top_k=2, repeats=1,
+                                         megablox=False)
+    assert [c["name"] for c in report["checks"]] == [
+        f"{case}/{n}" for case in ("routed_experts", "routed_experts_in_parts")
+        for n in ("y", "dx", "drouter", "dgate", "dup", "ddown")]
+    assert all(c["ok"] for c in report["checks"])
+    assert sum(report["load"]) <= report["buffer"] < sum(
+        report["load/in_parts"]) <= report["worst"]
+    assert {"layer_fwd_bwd_ms", "layer_fwd_bwd_ms/in_parts",
+            f"ragged_dot_fwd_bwd_ms/rows={report['buffer']}/filled",
+            f"ragged_dot_fwd_bwd_ms/rows={report['worst']}/all_routed"
+            } <= set(report)
+
+
 def test_kernels_phase_interpreted():
     # 160 pads to 256: the padding path.  One dtype and one mask here; the
     # chip runs the product.
     report = chip_smoke.kernels(interpret=True, seqs=(64, 160), heads=2,
                                 head_dim=32, codec_elems=5000,
-                                dtypes=("bfloat16",), causals=(True,))
+                                dtypes=("bfloat16",), causals=(True,),
+                                grouped=((4, 2, 32, 96, 4),))
     assert all(c["ok"] for c in report["checks"])
+    assert sum(c["name"].startswith("gqa/") for c in report["checks"]) == 8
     assert [c["codec"] for c in report["codecs"]] == ["int8", "int4", "int8g"]

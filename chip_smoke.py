@@ -6,8 +6,9 @@
                                      one chip: the expert layer of
                                      ``parallel/moe.py`` against a loop over
                                      its experts, and what its grouped
-                                     products cost by ``ragged_dot`` and by
-                                     megablox ``gmm``; nothing else
+                                     products cost by ``ragged_dot``, by
+                                     megablox ``gmm`` and by the repo's own
+                                     kernels; nothing else
 
 One chip: the device JAX found, a clean build of the C++ core and
 ``hvd.init()`` on it, the Pallas kernels alone against their references (at
@@ -295,23 +296,29 @@ def _expert_layer(tokens, d, f, held, experts, dtype, key, busy=0):
 def grouped_products(tokens: int = 16384, d: int = 2048, f: int = 768,
                      held: int = 16, experts: int = 128, top_k: int = 8,
                      capacity_factor: float = 2.25, repeats: int = 5,
-                     megablox: bool = True) -> dict:
+                     megablox: bool = True, interpret: bool = False) -> dict:
     """What one chip's share of a top-k expert layer costs, forward and
     backward, under an even router (the rows fit the layer's buffer) and
     under one with three busy experts (they do not: every row a router can
-    send, in parts), and what its grouped products alone cost by ``jax.lax.
-    ragged_dot`` and by the megablox ``gmm`` that ships with jax, over that
-    buffer and over ``tokens x top_k`` rows: milliseconds, best of
-    ``repeats``.  ``parallel/moe.py`` uses the first; this is the reading
-    behind that."""
+    send, in parts), and what its grouped products alone cost three ways,
+    over that buffer and over ``tokens x top_k`` rows: milliseconds, best of
+    ``repeats``.  The three: ``jax.lax.ragged_dot`` (what ``parallel/moe.py``
+    uses off the TPU), the megablox ``gmm`` that ships with jax (declares no
+    ``vma``: of no use under ``shard_map``), and the repo's own
+    ``ops/grouped_matmul.py:grouped_dot``, **which is what ``parallel/
+    moe.py`` uses on a TPU**: as the layer calls it (float32 kernels, cast a
+    group at a time in VMEM) and with the kernels cast before the call as
+    the other two take them.  ``interpret``: the repo's kernels through the
+    Pallas interpreter (the CPU rehearsal)."""
     import jax
     import jax.numpy as jnp
 
+    from horovod_tpu.ops.grouped_matmul import grouped_dot
     from horovod_tpu.parallel import moe
 
     # The layer against a loop over its experts, values and gradients, at a
     # size where a third of the row buffer lies past the rows routed (what a
-    # grouped product leaves there must reach neither) and, with two busy
+    # grouped product leaves there must reach neither) and, with three busy
     # experts, where the rows outgrow the buffer and are walked in parts.
     checks = []
 
@@ -330,7 +337,7 @@ def grouped_products(tokens: int = 16384, d: int = 2048, f: int = 768,
         out, vjp = jax.vjp(fn, *args)
         return (out, *vjp(jnp.cos(out)))
 
-    for case, busy in (("routed_experts", 0), ("routed_experts_in_parts", 2)):
+    for case, busy in (("routed_experts", 0), ("routed_experts_in_parts", 3)):
         small = _expert_layer(1024, 256, 128, 4, 16, jnp.float32, key=1,
                               busy=busy)
         with jax.default_matmul_precision("highest"):
@@ -377,38 +384,60 @@ def grouped_products(tokens: int = 16384, d: int = 2048, f: int = 768,
               "buffer": buffer, "worst": worst}
 
     def products(dot):
+        """Gradients of the three products' sum with respect to the rows
+        and the float32 kernels, as the layer's backward asks for them."""
         def fn(rows, sizes, *kernels):
             def loss(rows, *kernels):
-                kernels = [k.astype(rows.dtype) for k in kernels]
                 h = jax.nn.silu(dot(rows, kernels[0], sizes)) * dot(
                     rows, kernels[1], sizes)
                 return jnp.sum(dot(h, kernels[2], sizes).astype(jnp.float32))
             return jax.grad(loss, argnums=(0, 1, 2, 3))(rows, *kernels)
         return jax.jit(fn)
 
-    dots = {"ragged_dot": jax.lax.ragged_dot}
+    def cast_first(dot):
+        return lambda rows, w, sizes: dot(rows, w.astype(rows.dtype), sizes)
+
+    own = functools.partial(grouped_dot, interpret=interpret or None)
+    dots = {"ragged_dot": cast_first(jax.lax.ragged_dot),
+            "hvd_grouped_dot": own,
+            "hvd_grouped_dot_cast_first": cast_first(own)}
     if megablox:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        dots["megablox_gmm"] = lambda a, b, sizes: gmm(
+        dots["megablox_gmm"] = cast_first(lambda a, b, sizes: gmm(
             a, b, sizes, a.dtype,
-            lambda m, k, n: (512, min(k, 1024), min(n, 1024)))
+            lambda m, k, n: (512, min(k, 1024), min(n, 1024))))
     # The buffer and the worst case holding the rows of this router; the
-    # buffer filled (as the layer fills it) and half empty; the worst case
-    # with every row routed: tokens x top_k rows of work.
+    # buffer half empty; the worst case with every row routed: tokens x
+    # top_k rows of work.  (No case fills the buffer: since PR 35 the layer
+    # leaves its tail to no group.)
     routed = jnp.asarray(load, jnp.int32)
     cases = {f"rows={buffer}": (buffer, routed),
              f"rows={worst}": (worst, routed),
-             f"rows={buffer}/filled": (
-                 buffer, routed.at[-1].add(buffer - routed.sum())),
              f"rows={buffer}/half": (buffer, routed // 2),
              f"rows={worst}/all_routed": (
                  worst, jnp.full((held,), worst // held))}
+    grads = {}
     for name, dot in dots.items():
+        fn = products(dot)
         for case, (rows, sizes) in cases.items():
             some = jnp.zeros((rows, d), jnp.bfloat16).at[:tokens].set(x)
             report[f"{name}_fwd_bwd_ms/{case}"] = best_ms(
-                products(dot), some, sizes, w_gate, w_up, w_down)
+                fn, some, sizes, w_gate, w_up, w_down)
+            if rows == buffer and sizes is routed:
+                grads[name] = fn(some, sizes, w_gate, w_up, w_down)
+    # The repo's kernels against ragged_dot at the timed size, over the rows
+    # routed (past them a ragged_dot's d rows are undefined on a TPU, the
+    # kernels' are zeros).
+    n_routed = int(routed.sum())
+    for name in ("hvd_grouped_dot", "hvd_grouped_dot_cast_first"):
+        for what, a, b in zip(("drows", "dgate", "dup", "ddown"),
+                              grads[name], grads["ragged_dot"]):
+            if what == "drows":
+                checks.append({"name": f"{name}/drows_tail_is_zero",
+                               "ok": not bool(jnp.any(a[n_routed:]))})
+                a, b = a[:n_routed], b[:n_routed]
+            _check(checks, f"{name}/{what}", a, b, TOL_BF16_BWD)
     report = emit("grouped_products", checks=checks, **report)
     _raise_on_failed("grouped_products", checks)
     return report

@@ -1,0 +1,356 @@
+"""Grouped matrix products as Pallas TPU kernels: ``rows [M, K]`` sorted by
+group times ``w [G, K, N]``, each row by its own group's matrix.
+
+It is what an expert layer's products are (``parallel/moe.py``: the rows
+routed to a chip's experts, sorted by expert, through each expert's gate, up
+and down kernels), and what ``jax.lax.ragged_dot`` computes.  Two things are
+this module's own.  **The rows past the groups' sum cost nothing**: a dropless
+layer's buffer is sized for the router's bad days and more than half of it is
+empty on the others; those rows come out as zeros (forward and d rows) and add
+nothing to dW, and no dot runs for them.  And the calls **declare ``vma`` on
+their outputs** (:func:`_out_struct`), so they run inside ``shard_map`` under
+``check_vma``, which megablox's ``gmm`` (``jax.experimental.pallas.ops.tpu.
+megablox``, whose scheme this follows) does not.
+
+The scheme: the row axis is cut into tiles of :data:`TILE_ROWS`; a grid step
+is a **visit** of one tile by one group, and a tile that holds the edge
+between two groups is visited by each in turn, a row mask keeping each to its
+own rows.  The groups' offsets and every visit's group and tile are computed
+outside the kernel (:func:`_schedule`, a few small integer ops) and reach it
+as scalar-prefetched int32, so the index maps fetch the visiting group's
+matrix.  The grid is static, tiles + groups visits, whatever the sizes are:
+a group without rows keeps one visit (its dW is zeroed there), the tiles past
+the last routed row are visited by a last, pseudo group that has no rows and
+no matrix (their output blocks are written as zeros; their input blocks are
+not fetched: the index maps hold the last live tile), and what is left over
+visits nothing.
+
+Three calls, named for a trace: ``hvd_moe_gmm`` is the product and, on the
+matrices read transposed, its d rows; ``hvd_moe_tgmm`` is dW, ``rows^T [K, M]
+. g [M, N]`` by group, gathered in a float32 accumulator over a group's
+visits.  Every dot takes ``rows.dtype`` operands (bfloat16 at the MXU's
+native rate) and accumulates in float32.  ``w`` may be kept in another dtype
+(float32 parameters under bfloat16 activations): a group's matrix is then cast
+in VMEM when its first visit fetches it, not in a pass of its own over all of
+``w``, and dW leaves the accumulator in ``w``'s dtype.
+
+Off the TPU :func:`grouped_dot` is ``jax.lax.ragged_dot`` on ``w`` cast; the
+kernels are unit-tested in interpret mode (``tests/single/
+test_grouped_matmul.py``) and held against a loop over the experts on the chip
+by ``chip_smoke.py --grouped-products``.
+"""
+
+from __future__ import annotations
+
+import functools
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .collectives import ensure_varying
+
+LANES = 128
+# Rows a visit: a 36,864-row buffer is 72 of them.  megablox's tiling at an
+# expert layer's sizes, read on a v5e (PERF.md, PR 34 and PR 35).
+TILE_ROWS = 512
+# What a call's blocks may take of VMEM by :func:`_gmm_bytes` /
+# :func:`_tgmm_bytes`, and what the calls ask Mosaic for.  Whole
+# [2048, 768] float32 matrices fit: no contraction is cut at SDAR's sizes.
+# A v5e / v6e core has 128 MiB of VMEM, a v7x core 64 MiB.
+_VMEM_BUDGET = 28 * 1024 * 1024
+_VMEM_LIMIT = 48 * 1024 * 1024
+
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))      # A @ B^T: contract the minor dims
+_TN = (((0,), (0,)), ((), ()))      # A^T @ B: contract the major dims
+
+
+def _out_struct(shape, dtype, *like):
+    """ShapeDtypeStruct of a pallas_call output that varies over the mesh
+    axes any of ``like`` varies over (``shard_map``'s ``check_vma`` wants it
+    declared)."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+def _vary_like(a, b):
+    """``a``, varying over every mesh axis ``b`` varies over: a custom_vjp
+    returns each cotangent in its argument's type, and the dW of kernels
+    that ``shard_map`` holds replicated is a chip's own part until this
+    cast's transpose sums the parts."""
+    return ensure_varying(a, sorted(jax.typeof(b).vma))
+
+
+# An inlined jit, as the kernels' below: its twenty small ops are traced once
+# a process, not at each of a model's calls (31 ms each: with them a cell's
+# set-up was 12 s longer, PERF.md PR 35).
+@functools.partial(jax.jit, static_argnums=(1, 2), inline=True)
+def _schedule(group_sizes, rows: int, tile: int):
+    """``(offsets [G + 2], groups [V], tiles [V])``, int32, for V = tiles +
+    G visits in the order both kernels walk them: tiles never fall, groups
+    never fall.  ``offsets[g]:offsets[g + 1]`` are group g's rows, and the
+    pseudo group G's are none.  A group with rows visits every tile it has
+    rows in, a group without visits the tile it would start in, group G
+    visits every tile past the last routed row, and the visits left over
+    are group G's on the last tile once more (nothing to do there).  The
+    sizes may add up to less than ``rows``, never to more."""
+    held = group_sizes.shape[0]
+    n_tiles = -(-rows // tile)
+    visits = n_tiles + held
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    total = ends[-1:]
+    first = jnp.minimum((ends - sizes) // tile, n_tiles - 1)
+    span = jnp.where(sizes > 0, (ends - 1) // tile - first + 1, 1)
+    tail_first = (total + tile - 1) // tile
+    counts = jnp.concatenate([span, n_tiles - tail_first])
+    firsts = jnp.concatenate([first, tail_first])
+    groups = jnp.repeat(jnp.arange(held + 1, dtype=jnp.int32), counts,
+                        total_repeat_length=visits)
+    nth = jnp.arange(visits, dtype=jnp.int32) - (jnp.cumsum(counts)
+                                                 - counts)[groups]
+    tiles = jnp.minimum(firsts[groups] + nth, n_tiles - 1)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends, total])
+    return offsets, groups, tiles
+
+
+def _rows_of(v, offsets, groups, tiles, tile: int):
+    """Visit v's rows ``[lo, hi)`` (none where ``hi <= lo``), its group,
+    its tile's first row."""
+    g, first_row = groups[v], tiles[v] * tile
+    return (jnp.maximum(offsets[g], first_row),
+            jnp.minimum(offsets[g + 1], first_row + tile), g, first_row)
+
+
+def _input_tile(v, offsets, tiles, tile: int, held: int):
+    """The row tile a visit reads: its own, or the last tile with routed
+    rows where it lies past them (a block whose index stands is not fetched
+    again)."""
+    return jnp.minimum(tiles[v], jnp.maximum(offsets[held] - 1, 0) // tile)
+
+
+def _gmm_kernel(offsets, groups, tiles, lhs_ref, rhs_ref, out_ref, *cast_ref,
+                tile: int, dims):
+    """One visit of the product: ``out[rows of the group in this tile] =
+    lhs[those rows] . rhs[group]``.  Grid (column tiles, visits)."""
+    v = pl.program_id(1)
+    before = jnp.maximum(v - 1, 0)
+    lo, hi, g, first_row = _rows_of(v, offsets, groups, tiles, tile)
+
+    @pl.when((v == 0) | (tiles[before] != tiles[v]))
+    def _new_tile():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    if cast_ref:
+        # A matrix kept in another dtype is cast where it arrives, once a
+        # group (a group with rows has some in every visit it makes).
+        @pl.when((hi > lo) & ((v == 0) | (groups[before] != g)))
+        def _new_group():
+            cast_ref[0][...] = rhs_ref[...].astype(lhs_ref.dtype)
+
+    @pl.when(hi > lo)
+    def _visit():
+        rhs = cast_ref[0][...] if cast_ref else rhs_ref[...]
+        res = lax.dot_general(lhs_ref[...], rhs, dims,
+                              preferred_element_type=jnp.float32)
+        row = first_row + lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+        out_ref[...] = jnp.where((row >= lo) & (row < hi),
+                                 res.astype(out_ref.dtype), out_ref[...])
+
+
+def _tgmm_kernel(offsets, groups, tiles, lhs_ref, rhs_ref, out_ref, acc_ref,
+                 *, tile: int, held: int):
+    """One visit of dW: ``out[group] += lhs[its rows in this tile]^T .
+    rhs[those rows]``.  Grid (k tiles, n tiles, visits); the pseudo group's
+    visits go on the last group's block and add nothing to it."""
+    v, last = pl.program_id(2), pl.num_programs(2) - 1
+    lo, hi, g, first_row = _rows_of(v, offsets, groups, tiles, tile)
+    g = jnp.minimum(g, held - 1)
+
+    @pl.when((v == 0) | (jnp.minimum(groups[jnp.maximum(v - 1, 0)],
+                                     held - 1) != g))
+    def _new_group():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(hi > lo)
+    def _visit():
+        # Both operands masked: a row of another group, or past them all,
+        # may hold anything.
+        row = first_row + lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+        mine = (row >= lo) & (row < hi)
+        lhs, rhs = lhs_ref[...], rhs_ref[...]
+        acc_ref[...] += lax.dot_general(
+            jnp.where(mine, lhs, jnp.zeros_like(lhs)),
+            jnp.where(mine, rhs, jnp.zeros_like(rhs)), _TN,
+            preferred_element_type=jnp.float32)
+
+    @pl.when((v == last) | (jnp.minimum(groups[jnp.minimum(v + 1, last)],
+                                        held - 1) != g))
+    def _group_done():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _divisors(n: int) -> list:
+    """The block sizes a dimension of ``n`` may take, largest first: its
+    divisors in whole lane tiles, or ``n`` alone where it is not one."""
+    if n % LANES:
+        return [n]
+    return [d for d in range(n, 0, -LANES) if n % d == 0]
+
+
+def _gmm_bytes(tile, c, o, itemsize, w_itemsize) -> int:
+    """VMEM of a product's visit: the row tile, the group's matrix and the
+    output tile double-buffered by the pipeline, the float32 result, the
+    cast matrix where ``w`` is kept in another dtype."""
+    return (2 * (tile * c * itemsize + c * o * w_itemsize
+                 + tile * o * itemsize) + tile * o * 4
+            + (c * o * itemsize if w_itemsize != itemsize else 0))
+
+
+def _tgmm_bytes(tile, k, n, itemsize, out_itemsize) -> int:
+    """VMEM of a dW visit: both row tiles double-buffered and once more
+    masked, the output block double-buffered, the float32 accumulator."""
+    return (3 * tile * (k + n) * itemsize + 2 * k * n * out_itemsize
+            + k * n * 4)
+
+
+def _plan_error(what, *sizes):
+    return ValueError(f"grouped_dot: no block of {what} at sizes {sizes} "
+                      f"fits {_VMEM_BUDGET} bytes of VMEM")
+
+
+def _tile_rows(rows: int) -> int:
+    return min(TILE_ROWS, rows)
+
+
+# Inlined jits, as the flash kernels': traced once a process for a layer's
+# shapes, and an op keeps the scope of the layer that made it.
+@functools.partial(jax.jit, static_argnums=(5, 6), inline=True)
+def _gmm(lhs, rhs, offsets, groups, tiles, transposed: bool, interpret: bool):
+    """``[M, C] . [G, C, O] -> [M, O]`` by group; ``transposed``: the
+    matrices are ``[G, O, C]`` and read as their transposes."""
+    (m, c), held = lhs.shape, rhs.shape[0]
+    o = rhs.shape[1] if transposed else rhs.shape[2]
+    tile = _tile_rows(m)
+    itemsize, w_itemsize = lhs.dtype.itemsize, rhs.dtype.itemsize
+    block = next((d for d in _divisors(o) if _gmm_bytes(
+        tile, c, d, itemsize, w_itemsize) <= _VMEM_BUDGET), None)
+    if block is None:
+        raise _plan_error("the product", m, c, o)
+
+    def group_of(v, groups):
+        return jnp.minimum(groups[v], held - 1)
+
+    if transposed:
+        rhs_spec = pl.BlockSpec(
+            (None, block, c), lambda n, v, offsets, groups, tiles:
+            (group_of(v, groups), n, 0))
+    else:
+        rhs_spec = pl.BlockSpec(
+            (None, c, block), lambda n, v, offsets, groups, tiles:
+            (group_of(v, groups), 0, n))
+    cast = rhs.dtype != lhs.dtype
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tile=tile,
+                          dims=_NT if transposed else _NN),
+        name="hvd_moe_gmm",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(o // block, groups.shape[0]),
+            in_specs=[
+                pl.BlockSpec((tile, c), lambda n, v, offsets, groups, tiles:
+                             (_input_tile(v, offsets, tiles, tile, held), 0)),
+                rhs_spec],
+            out_specs=pl.BlockSpec(
+                (tile, block), lambda n, v, offsets, groups, tiles:
+                (tiles[v], n)),
+            scratch_shapes=[pltpu.VMEM(rhs_spec.block_shape[1:], lhs.dtype)]
+            if cast else []),
+        out_shape=_out_struct((m, o), lhs.dtype, lhs, rhs),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret)(offsets, groups, tiles, lhs, rhs)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7), inline=True)
+def _tgmm(lhs, rhs, offsets, groups, tiles, held: int, dtype, interpret: bool):
+    """``[M, K]^T . [M, N] -> [G, K, N]`` by group, in ``dtype``."""
+    (m, k), n = lhs.shape, rhs.shape[1]
+    tile = _tile_rows(m)
+    itemsize, out_itemsize = lhs.dtype.itemsize, jnp.dtype(dtype).itemsize
+    blocks = [(bk, bn) for bk in _divisors(k) for bn in _divisors(n)
+              if _tgmm_bytes(tile, bk, bn, itemsize, out_itemsize)
+              <= _VMEM_BUDGET]
+    if not blocks:
+        raise _plan_error("dW", m, k, n)
+    bk, bn = max(blocks, key=lambda b: (b[0] * b[1], b[1]))
+
+    def row_tile(v, offsets, tiles):
+        return _input_tile(v, offsets, tiles, tile, held)
+
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, tile=tile, held=held),
+        name="hvd_moe_tgmm",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(k // bk, n // bn, groups.shape[0]),
+            in_specs=[
+                pl.BlockSpec((tile, bk), lambda i, j, v, offsets, groups,
+                             tiles: (row_tile(v, offsets, tiles), i)),
+                pl.BlockSpec((tile, bn), lambda i, j, v, offsets, groups,
+                             tiles: (row_tile(v, offsets, tiles), j))],
+            out_specs=pl.BlockSpec(
+                (None, bk, bn), lambda i, j, v, offsets, groups, tiles:
+                (jnp.minimum(groups[v], held - 1), i, j)),
+            scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)]),
+        out_shape=_out_struct((held, k, n), dtype, lhs, rhs),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret)(offsets, groups, tiles, lhs, rhs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_dot(rows, w, schedule, interpret):
+    return _gmm(rows, w, *schedule, False, interpret)
+
+
+def _grouped_dot_fwd(rows, w, schedule, interpret):
+    return _grouped_dot(rows, w, schedule, interpret), (rows, w, schedule)
+
+
+def _grouped_dot_bwd(interpret, saved, g):
+    rows, w, schedule = saved
+    return (_gmm(g, w, *schedule, True, interpret),
+            _tgmm(rows, g, *schedule, w.shape[0], w.dtype, interpret), None)
+
+
+_grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
+
+
+def grouped_dot(rows, w, group_sizes, *, interpret=None):
+    """``out[i] = rows[i] . w[group of row i]`` for rows sorted by group.
+
+    Args:
+      rows: [M, K], the first ``group_sizes[0]`` rows of group 0, the next
+        ``group_sizes[1]`` of group 1, ...; the sizes may add up to less
+        than M (never to more).
+      w: [G, K, N], in ``rows.dtype`` or not: the product reads it cast to
+        ``rows.dtype`` and accumulates in float32.
+      group_sizes: [G] integers.
+      interpret: None runs the Pallas kernels on a TPU and ``jax.lax.
+        ragged_dot`` elsewhere; True, or a ``pltpu.InterpretParams``, forces
+        the kernels through a Pallas interpreter (tests; only the latter
+        runs scalar prefetch inside ``shard_map``).
+
+    Returns [M, N] in ``rows.dtype``; by the kernels, the rows past the
+    groups' sum are zeros, in the result and in d rows, and add nothing to
+    dW (in ``w.dtype``), whatever they hold."""
+    if interpret is None:
+        if jax.default_backend() != "tpu":
+            return lax.ragged_dot(rows, w.astype(rows.dtype), group_sizes)
+        interpret = False
+    schedule = _schedule(group_sizes, rows.shape[0], _tile_rows(rows.shape[0]))
+    return _grouped_dot(_vary_like(rows, w), _vary_like(w, rows), schedule,
+                        interpret)

@@ -15,16 +15,26 @@ scaled by their gate as usual: the standard switch-transformer behavior.
 renormalised over the chosen k, SwiGLU experts).  The chip is told which
 consecutive experts it holds; it routes over all of them and computes the
 part of each token's sum that its own experts contribute.  Nothing is
-dropped: the rows routed here are sorted by expert and multiplied in grouped
-matrix products (``jax.lax.ragged_dot``) over a row buffer of the caller's
-``capacity_factor`` times what an even router sends here: the cost is the
-buffer's, not ``tokens x top_k``'s, and while the rows fit it does not follow
-the data.  A step whose rows do not fit walks every row a router can send,
-in parts (a ``lax.cond``, taken while the step runs).  It has no exchange:
-what the other chips' experts would add is not there (ROADMAP Reach B1 keeps
-the all-to-all).  (megablox's ``gmm``, which ships with jax, is a quarter
-faster at these sizes on a v5e but declares no ``vma`` on its outputs, so
-``shard_map`` refuses it under ``check_vma``: PERF.md, PR 34.)
+dropped: the rows routed here are sorted by expert into a row buffer of the
+caller's ``capacity_factor`` times what an even router sends here and
+multiplied in grouped matrix products, a group an expert.  The buffer's rows
+past the routed ones are in no group.  A step whose rows do not fit the
+buffer walks every row a router can send, in parts (a ``lax.cond``, taken
+while the step runs).  It has no exchange: what the other chips' experts
+would add is not there (ROADMAP Reach B1 keeps the all-to-all).
+
+**Which product runs where**: on a TPU the three products, forward and
+backward, are the Pallas kernels of ``ops/grouped_matmul.py``
+(:func:`~horovod_tpu.ops.grouped_matmul.grouped_dot`; ``hvd_moe_gmm`` /
+``hvd_moe_tgmm`` in a trace): they walk the rows routed and nothing else, so
+their time follows the rows and not the buffer, and they read the float32
+kernels as they are, cast a group at a time in VMEM.  Elsewhere ``grouped_dot``
+is ``jax.lax.ragged_dot``.  The backend is what it looks at, as
+``flash_attention`` does; no argument chooses.  (megablox's ``gmm``, which
+ships with jax and whose scheme the kernels follow, declares no ``vma`` on its
+outputs, so ``shard_map`` refuses it under ``check_vma``; ``ragged_dot`` on a
+TPU costs 9.7 ms a layer where the kernels cost 6.8 and ``gmm`` 7.5: PERF.md,
+PR 35.)
 """
 
 from __future__ import annotations
@@ -38,6 +48,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.collectives import axis_size, ensure_varying
+from ..ops.grouped_matmul import grouped_dot
 
 
 def switch_moe(x, router_kernel, expert_fn: Callable, axis_name: str = "ep",
@@ -169,12 +180,12 @@ def row_buffer(tokens: int, top_k: int, held: int, experts: int,
 
 def _swiglu_rows(rows, group_sizes, w_gate, w_up, w_down):
     """``down(silu(gate(x)) * up(x))`` of rows sorted by expert: three
-    grouped products."""
+    grouped products, over the rows in a group and no others (the kernels
+    are read cast to the rows' dtype)."""
     with jax.named_scope("hvd_moe_experts"):
-        gate = lax.ragged_dot(rows, w_gate.astype(rows.dtype), group_sizes)
-        up = lax.ragged_dot(rows, w_up.astype(rows.dtype), group_sizes)
-        return lax.ragged_dot(jax.nn.silu(gate) * up,
-                              w_down.astype(rows.dtype), group_sizes)
+        gate = grouped_dot(rows, w_gate, group_sizes)
+        up = grouped_dot(rows, w_up, group_sizes)
+        return grouped_dot(jax.nn.silu(gate) * up, w_down, group_sizes)
 
 
 def _held_part(capacity: int, x, local, weights, w_gate, w_up, w_down):
@@ -187,13 +198,10 @@ def _held_part(capacity: int, x, local, weights, w_gate, w_up, w_down):
         order = jnp.argsort(flat, stable=True)[:capacity]
         sizes = jnp.sum(jax.nn.one_hot(flat, held, dtype=jnp.int32), axis=0)
         live = jnp.arange(capacity) < jnp.sum(sizes)
-        # The last group takes the buffer's empty tail (zero rows in, zero
-        # rows out): the products then cost what the buffer costs whatever
-        # the router did, and a step's time does not follow its data.
-        sizes = sizes.at[-1].add(capacity - jnp.sum(sizes))
         token = order // top_k
-        # The buffer's rows past the routed ones are no token's: zeros on
-        # the way in, which cuts their cotangent off on the way back.
+        # The buffer's rows past the routed ones are no token's and in no
+        # group: zeros on the way in, which cuts their cotangent off on the
+        # way back, and no product is computed for them.
         rows = jnp.where(live[:, None], x[token], jnp.zeros((), x.dtype))
     out = _swiglu_rows(rows, sizes, w_gate, w_up, w_down)
     with jax.named_scope("hvd_moe_route"):
@@ -248,6 +256,29 @@ def _fitting(rows: int, fn, summed: int, token_args, kernels):
         *token_args, *kernels)
 
 
+# Inlined jits, as the flash kernels' (``ops/flash_attention.py``): a model
+# calls the layer once a block with the same shapes, and jit's cache then
+# traces each pass of it, its two sides and their kernels, once a process and
+# not once a block; ``inline`` leaves no call in the jaxpr, so an op keeps the
+# scope of the block that made it.
+@functools.partial(jax.jit, static_argnums=(0,), inline=True)
+def _forward(rows: int, x, local, weights, w_gate, w_up, w_down):
+    return _fitting(rows, _held_part, 0, (x, local, weights),
+                    (w_gate, w_up, w_down))
+
+
+@functools.partial(jax.jit, static_argnums=(0,), inline=True)
+def _backward(rows: int, x, local, weights, g, *kernels):
+    def grads(capacity, x, local, weights, g, *kernels):
+        _, vjp = jax.vjp(
+            lambda x, w, *k: _held_part(capacity, x, local, w, *k),
+            x, weights, *kernels)
+        return vjp(g)
+
+    return _fitting(rows, grads, len(kernels), (x, local, weights, g),
+                    kernels)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _dropless(rows: int, x, local, weights, w_gate, w_up, w_down):
     """``_held_part`` through a buffer of ``rows`` rows, or in parts over
@@ -255,8 +286,7 @@ def _dropless(rows: int, x, local, weights, w_gate, w_up, w_down):
     each look at the rows themselves and nothing of a buffer's size is kept
     between them: the backward recomputes the forward of the side it
     takes."""
-    return _fitting(rows, _held_part, 0, (x, local, weights),
-                    (w_gate, w_up, w_down))
+    return _forward(rows, x, local, weights, w_gate, w_up, w_down)
 
 
 def _dropless_fwd(rows, *args):
@@ -265,15 +295,7 @@ def _dropless_fwd(rows, *args):
 
 def _dropless_bwd(rows, args, g):
     x, local, weights, *kernels = args
-
-    def grads(capacity, x, local, weights, g, *kernels):
-        _, vjp = jax.vjp(
-            lambda x, w, *k: _held_part(capacity, x, local, w, *k),
-            x, weights, *kernels)
-        return vjp(g)
-
-    dx, dweights, *dkernels = _fitting(
-        rows, grads, len(kernels), (x, local, weights, g), tuple(kernels))
+    dx, dweights, *dkernels = _backward(rows, x, local, weights, g, *kernels)
     return (dx, None, dweights, *dkernels)
 
 

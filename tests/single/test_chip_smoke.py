@@ -51,20 +51,26 @@ def test_native_core_and_eager_phases():
 
 
 def test_grouped_products_phase_tiny():
-    """The expert layer against a loop over its experts (values, gradients)
-    and the timing table's keys; megablox's kernels want a TPU."""
+    """The expert layer against a loop over its experts (values, gradients),
+    the repo's kernels (interpreted) against ``ragged_dot`` and the timing
+    table's keys; megablox's kernels want a TPU."""
     report = chip_smoke.grouped_products(tokens=256, d=32, f=16, held=4,
                                          experts=16, top_k=2, repeats=1,
-                                         megablox=False)
+                                         megablox=False, interpret=True)
     assert [c["name"] for c in report["checks"]] == [
         f"{case}/{n}" for case in ("routed_experts", "routed_experts_in_parts")
-        for n in ("y", "dx", "drouter", "dgate", "dup", "ddown")]
+        for n in ("y", "dx", "drouter", "dgate", "dup", "ddown")] + [
+        f"{dot}/{n}" for dot in ("hvd_grouped_dot",
+                                 "hvd_grouped_dot_cast_first")
+        for n in ("drows_tail_is_zero", "drows", "dgate", "dup", "ddown")]
     assert all(c["ok"] for c in report["checks"])
     assert sum(report["load"]) <= report["buffer"] < sum(
         report["load/in_parts"]) <= report["worst"]
     assert {"layer_fwd_bwd_ms", "layer_fwd_bwd_ms/in_parts",
-            f"ragged_dot_fwd_bwd_ms/rows={report['buffer']}/filled",
-            f"ragged_dot_fwd_bwd_ms/rows={report['worst']}/all_routed"
+            f"ragged_dot_fwd_bwd_ms/rows={report['buffer']}/half",
+            f"hvd_grouped_dot_fwd_bwd_ms/rows={report['buffer']}",
+            f"hvd_grouped_dot_cast_first_fwd_bwd_ms/rows={report['worst']}"
+            "/all_routed"
             } <= set(report)
 
 

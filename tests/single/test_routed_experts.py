@@ -3,6 +3,8 @@ SwiGLU experts against a plain loop over the experts, under a skewed router
 (nothing dropped), whether or not the rows routed fit its row buffer; the
 shares add up to the uncut layer."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -122,6 +124,55 @@ def test_both_sides_inside_a_jitted_shard_map_step(skewed):
     for name, a, b in zip(("x", "router", "gate", "up", "down"), got[1],
                           want[1]):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("products", ["ragged_dot", "kernels"])
+@pytest.mark.parametrize("skewed", [(1,), (1, 2)], ids=["fits", "in-parts"])
+def test_the_buffer_s_tail_reaches_nothing(monkeypatch, request, skewed,
+                                            products):
+    """The row buffer's rows past the routed ones are in no group: set to
+    NaN just before the products, they touch neither ``y`` nor a gradient,
+    by ``ragged_dot`` (what a CPU runs) and by the Pallas kernels (what a TPU
+    runs; interpreted here).  Before PR 35 the last expert took the tail as
+    rows of its own."""
+    from horovod_tpu.ops.grouped_matmul import grouped_dot
+
+    def traced_anew():
+        # The layer's passes are traced once a process for a shape: what a
+        # test puts in their way is seen by a new trace only, and must not
+        # stay behind in the cache.
+        moe._forward.clear_cache()
+        moe._backward.clear_cache()
+
+    x, router, *kernels = _layer(skew=6.0, skewed=skewed)
+    mine = _held(kernels, 0, 4)
+    request.addfinalizer(traced_anew)
+    if products == "kernels":
+        monkeypatch.setattr(moe, "grouped_dot", functools.partial(
+            grouped_dot, interpret=True))
+        traced_anew()
+
+    def value_and_grads(*a):
+        return jax.value_and_grad(lambda *a: jnp.sum(moe.routed_experts(
+            *a, top_k=TOP_K, capacity_factor=2.0)[0] ** 2),
+            argnums=(0, 1, 2, 3, 4))(*a)
+
+    want = value_and_grads(x, router, *mine)
+    swiglu_rows, tails = moe._swiglu_rows, []
+
+    def poisoned(rows, group_sizes, *k):
+        past = jnp.arange(rows.shape[0]) >= jnp.sum(group_sizes)
+        tails.append(rows.shape[0])
+        return swiglu_rows(jnp.where(past[:, None], jnp.nan, rows),
+                           group_sizes, *k)
+
+    monkeypatch.setattr(moe, "_swiglu_rows", poisoned)
+    traced_anew()
+    got = value_and_grads(x, router, *mine)
+    assert tails                                  # the hook was on the path
+    for name, a, b in zip(("y", "x", "router", "gate", "up", "down"),
+                          jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(a, b, err_msg=name)
 
 
 @pytest.mark.parametrize("held", [2, 4, 16])

@@ -240,6 +240,33 @@ def test_head_and_loss_write_the_logits_once_in_float32(one_chip):
     assert "log_softmax" not in text
 
 
+@pytest.mark.parametrize("w_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)],
+                         ids=["gate-up", "down"])
+def test_grouped_products_compile_at_an_expert_layer_s_sizes(one_chip, k, n,
+                                                             w_dtype):
+    """``ops/grouped_matmul.py`` at ``sdar-moe-ep8-s4096``'s sizes (a
+    36,864-row buffer, 16 held experts of 2048 x 768, whole matrices in
+    VMEM): the product, d rows and dW are three kernel calls, and no
+    ``ragged-dot`` is left."""
+    from horovod_tpu.ops.grouped_matmul import grouped_dot
+
+    def fwd_bwd(rows, w, sizes, ct):
+        out, vjp = jax.vjp(lambda r, w: grouped_dot(r, w, sizes,
+                                                    interpret=False), rows, w)
+        return (out, *vjp(ct))
+
+    text = _compiled_text(fwd_bwd, *_shapes_on(one_chip, (
+        jax.ShapeDtypeStruct((36864, k), jnp.bfloat16),
+        jax.ShapeDtypeStruct((16, k, n), jnp.dtype(w_dtype)),
+        jax.ShapeDtypeStruct((16,), jnp.int32),
+        jax.ShapeDtypeStruct((36864, n), jnp.bfloat16))))
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert len(re.findall(r"hvd_moe_gmm[\w.]* = ", text)) == 2
+    assert len(re.findall(r"hvd_moe_tgmm[\w.]* = ", text)) == 1
+    assert "ragged-dot" not in text
+
+
 @pytest.mark.parametrize("codec", ["int8", "int4", "int8g"])
 def test_codec_encode_decode_compiles(one_chip, codec):
     def roundtrip(flat):

@@ -8,7 +8,9 @@
                                      its experts, and what its grouped
                                      products cost by ``ragged_dot``, by
                                      megablox ``gmm`` and by the repo's own
-                                     kernels; nothing else
+                                     kernels; the same kernels at an expert
+                                     of [2048, 2048], wider than their VMEM
+                                     budget, each call timed; nothing else
 
 One chip: the device JAX found, a clean build of the C++ core and
 ``hvd.init()`` on it, the Pallas kernels alone against their references (at
@@ -293,6 +295,20 @@ def _expert_layer(tokens, d, f, held, experts, dtype, key, busy=0):
     return x, router, w_gate, w_up, w_down
 
 
+def _best_ms(repeats: int, fn, *args) -> float:
+    """Milliseconds of ``fn(*args)`` to completion, best of ``repeats``
+    after one call that compiles."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(repeats):
+        start = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - start)
+    return round(1e3 * min(times), 3)
+
+
 def grouped_products(tokens: int = 16384, d: int = 2048, f: int = 768,
                      held: int = 16, experts: int = 128, top_k: int = 8,
                      capacity_factor: float = 2.25, repeats: int = 5,
@@ -352,14 +368,7 @@ def grouped_products(tokens: int = 16384, d: int = 2048, f: int = 768,
     x, router, w_gate, w_up, w_down = _expert_layer(
         tokens, d, f, held, experts, jnp.bfloat16, key=0)
 
-    def best_ms(fn, *args):
-        jax.block_until_ready(fn(*args))
-        times = []
-        for _ in range(repeats):
-            start = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            times.append(time.perf_counter() - start)
-        return round(1e3 * min(times), 3)
+    best_ms = functools.partial(_best_ms, repeats)
 
     def layer(x, router, *kernels):
         y, routing = moe.routed_experts(x, router, *kernels, top_k=top_k,
@@ -573,6 +582,82 @@ def launch_np4(np_workers: int = 4, timeout: float = 600.0) -> dict:
     return emit("launch_np4", workers=workers)
 
 
+def wide_expert_products(rows: int = 16384, routed: int = 8192,
+                         d: int = 2048, f: int = 2048, held: int = 8,
+                         repeats: int = 5, interpret: bool = False) -> dict:
+    """The grouped products at an expert wider than the kernels' VMEM
+    budget (``ops/grouped_matmul.py``; the defaults are ZAYA1-8B's share of
+    two at 16,384 positions: 8 experts of [2048, 2048] float32, half of the
+    buffer routed): a whole matrix does not fit, so the product runs in
+    column blocks and dW in ``(bk, bn)`` blocks.  The product, its d rows
+    and dW against a loop over the experts, then each call's time alone
+    (``gmm``: the product; ``gmm_t``: d rows, the matrices read transposed;
+    ``tgmm``: dW) and the SwiGLU's three products forward and backward, as
+    :func:`grouped_products` times a layer's: milliseconds, best of
+    ``repeats``."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    ks = jax.random.split(jax.random.PRNGKey(2), 5)
+    x = jax.random.normal(ks[0], (rows, d), jnp.bfloat16)
+    ct = jax.random.normal(ks[1], (rows, f), jnp.bfloat16)
+    w_gate, w_up = (jax.random.normal(k, (held, d, f), jnp.float32)
+                    * d ** -0.5 for k in ks[2:4])
+    w_down = jax.random.normal(ks[4], (held, f, d), jnp.float32) * f ** -0.5
+    # Uneven groups whose edges fall inside tiles; the tail is in no group.
+    sizes = jnp.asarray([routed // held + (37 if i % 2 else -37)
+                         for i in range(held)], jnp.int32)
+    dot = functools.partial(gm.grouped_dot, interpret=interpret or None)
+
+    def own(x, w, ct):
+        out, vjp = jax.vjp(lambda x, w: dot(x, w, sizes), x, w)
+        return (out, *vjp(ct))
+
+    def by_loop(x, w, ct):
+        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        x, ct = jnp.where(live, x, 0), jnp.where(live, ct, 0)
+        group = jnp.repeat(jnp.arange(held), sizes, total_repeat_length=rows)
+        out, vjp = jax.vjp(lambda x, w: sum(
+            jnp.where((group == g)[:, None] & live,
+                      x @ w[g].astype(x.dtype), 0) for g in range(held)),
+            x, w)
+        return (out, *vjp(ct))
+
+    checks = []
+    for name, a, b in zip(("out", "drows", "dw"),
+                          jax.jit(own)(x, w_gate, ct),
+                          jax.jit(by_loop)(x, w_gate, ct)):
+        _check(checks, f"wide/{name}", a, b, TOL_BF16_BWD)
+
+    best_ms = functools.partial(_best_ms, repeats)
+
+    def swiglu(x, *kernels):
+        def loss(x, *kernels):
+            h = jax.nn.silu(dot(x, kernels[0], sizes)) * dot(x, kernels[1],
+                                                             sizes)
+            return jnp.sum(dot(h, kernels[2], sizes).astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(x, *kernels)
+
+    w_itemsize = w_gate.dtype.itemsize
+    report = {
+        "gmm_block": next(b for b in gm._divisors(f) if gm._gmm_bytes(
+            gm._tile_rows(rows), d, b, 2, w_itemsize) <= gm._VMEM_BUDGET),
+        "gmm_ms": best_ms(jax.jit(lambda x, w: dot(x, w, sizes)), x, w_gate),
+        "gmm_t_ms": best_ms(jax.jit(jax.grad(lambda x, w: jnp.sum(
+            dot(x, w, sizes).astype(jnp.float32)))), x, w_gate),
+        "tgmm_ms": best_ms(jax.jit(jax.grad(lambda w, x: jnp.sum(
+            dot(x, w, sizes).astype(jnp.float32)))), w_gate, x),
+        f"hvd_grouped_dot_fwd_bwd_ms/rows={rows}": best_ms(
+            jax.jit(swiglu), x, w_gate, w_up, w_down),
+        "rows": rows, "routed": int(jnp.sum(sizes)), "d": d, "f": f,
+        "held": held}
+    report = emit("wide_expert_products", checks=checks, **report)
+    _raise_on_failed("wide_expert_products", checks)
+    return report
+
+
 # ---------------------------------------------------------------------------
 
 
@@ -599,6 +684,7 @@ def main(argv=None) -> int:
     elif args.grouped_products:
         info = device()
         grouped_products()
+        wide_expert_products()
     else:
         info = device()
         native_core()

@@ -1,7 +1,9 @@
 """The framework's model zoo: MNIST MLP, the ResNet family, VGG and
 Inception-v3, the BERT family (SURVEY.md §6; BASELINE.json configs 1-3), a
 GPT-style causal decoder, and SDAR-MoE (a Qwen3-MoE decoder of grouped-query
-attention and top-k routed experts, trained by block diffusion)."""
+attention and top-k routed experts, trained by block diffusion), and ZAYA1
+(compressed convolutional attention, a top-1 mixture whose MLP router carries
+a state down the layers, a tied head; its loss is ``zaya.lm_loss``)."""
 
 from .losses import softmax_cross_entropy  # noqa: F401
 from .mlp import MLP, xent_loss  # noqa: F401
@@ -20,5 +22,7 @@ from .sdar import (  # noqa: F401
     SDAR, SDARConfig, SDAR_30B_A3B, SDAR_TINY, block_diffusion_loss,
     noise_blocks,
 )
+from . import zaya  # noqa: F401
+from .zaya import Zaya, ZayaConfig, ZAYA1_8B, ZAYA_TINY  # noqa: F401
 from .vgg import VGG, VGG16, VGG19, VGGTiny  # noqa: F401
 from .inception import InceptionV3  # noqa: F401

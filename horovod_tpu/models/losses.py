@@ -1,5 +1,7 @@
 """The one softmax cross-entropy of the models' loss functions
-(``gpt.lm_loss``, ``bert.mlm_loss`` / ``nsp_loss``, ``mlp.xent_loss``).
+(``gpt.lm_loss``, ``bert.mlm_loss`` / ``nsp_loss``, ``mlp.xent_loss``), and
+the same under a head tied to the embedding, a block of tokens at a time
+(``zaya.lm_loss``: :func:`tied_head_cross_entropy`, at the end).
 
 ``log_softmax`` followed by ``take_along_axis`` writes a log-probability for
 every class though the loss reads one a row, and autodiff keeps that array
@@ -13,6 +15,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from ..ops.collectives import vary_like as _vary_like
 
 
 @jax.custom_vjp
@@ -56,3 +60,108 @@ def _backward(residuals, g):
 
 
 softmax_cross_entropy.defvjp(_forward, _backward)
+
+
+# ---------------------------------------------------------------------------
+# A tied head and its cross-entropy, a block of tokens at a time
+# ---------------------------------------------------------------------------
+
+# Tokens a block.  The float32 logits of one block are ``HEAD_BLOCK x V x 4``
+# bytes (1.07 GB at 131,136 rows) and nothing else of that shape lives; a
+# block reads the embedding three times and the gradient's accumulator once
+# each way, so fewer, larger blocks cost fewer bytes.
+HEAD_BLOCK = 2048
+
+
+def _head_blocks(tokens: int) -> int:
+    """The fewest blocks of at most ``HEAD_BLOCK`` tokens that divide
+    ``tokens`` evenly."""
+    return next(n for n in range(-(-tokens // HEAD_BLOCK), tokens + 1)
+                if tokens % n == 0)
+
+
+def _block_nll(x, embedding, labels):
+    """One block: float32 logits ``x . embedding^T`` (the product in
+    ``x.dtype``, accumulated in float32), each row's negative
+    log-likelihood, and what the gradient needs of the softmax."""
+    logits = jax.lax.dot_general(x, embedding, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+    top = jnp.max(logits, axis=-1, keepdims=True)
+    log_sum = jnp.log(jnp.sum(jnp.exp(logits - top), axis=-1, keepdims=True))
+    picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)
+    return (log_sum - (picked - top))[:, 0], logits, top + log_sum
+
+
+def _in_blocks(*per_token):
+    """Each ``[T, ...]`` array as ``[blocks, T / blocks, ...]``."""
+    blocks = _head_blocks(per_token[0].shape[0])
+    return tuple(a.reshape(blocks, a.shape[0] // blocks, *a.shape[1:])
+                 for a in per_token)
+
+
+def tied_head_cross_entropy(x, embedding, labels, weights):
+    """``sum_t weights[t] x nll_t`` where ``nll_t`` is the negative
+    log-likelihood of ``labels[t]`` under ``softmax(x[t] . embedding^T)``:
+    a head tied to the embedding ``[V, d]`` and :func:`softmax_cross_entropy`
+    in one, over ``x`` [T, d], a block of ``HEAD_BLOCK`` tokens at a time (a
+    ``lax.scan``), so that no ``[T, V]`` array ever lives: at 16,384 tokens
+    and 131,136 rows the float32 logits would be 8.6 GB.
+
+    The products run in ``x.dtype`` (the embedding is cast to it once) and
+    accumulate in float32; the logits of a block are float32.  ``weights``
+    [T] float32 are constants of the loss (a mask over the positions that
+    predict, over their number); no gradient flows to them.
+
+    Reverse mode only, as :func:`softmax_cross_entropy`.  Under ``jax.grad``
+    the forward makes the gradients too, block by block beside the loss
+    (``d logits = weights x (softmax - onehot)``, then ``dx = d logits .
+    E`` and ``dE += d logits^T . x``), so the logits are never computed
+    twice: three products a block.  The backward scales them by the
+    cotangent.  ``dE`` is float32 ``[V, d]``, the embedding's gradient as
+    the head sees it; autodiff adds the gather's."""
+    return _tied(x, _vary_like(embedding, x), _vary_like(labels, x),
+                 _vary_like(weights, x))
+
+
+@jax.custom_vjp
+def _tied(x, embedding, labels, weights):
+    table = embedding.astype(x.dtype)
+
+    def block(total, part):
+        xb, lb, wb = part
+        return total + jnp.sum(wb * _block_nll(xb, table, lb)[0]), None
+
+    return jax.lax.scan(block, _vary_like(jnp.zeros((), jnp.float32), x),
+                        _in_blocks(x, labels, weights))[0]
+
+
+def _tied_forward(x, embedding, labels, weights):
+    table = embedding.astype(x.dtype)
+
+    def block(carry, part):
+        total, d_table = carry
+        xb, lb, wb = part
+        nll, logits, lse = _block_nll(xb, table, lb)
+        classes = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        d_logits = (wb[:, None] * (jnp.exp(logits - lse)
+                                   - (classes == lb[:, None]))).astype(x.dtype)
+        dx = jnp.dot(d_logits, table, preferred_element_type=jnp.float32)
+        d_table = d_table + jax.lax.dot_general(
+            d_logits, xb, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return (total + jnp.sum(wb * nll), d_table), dx.astype(x.dtype)
+
+    start = (_vary_like(jnp.zeros((), jnp.float32), x),
+             _vary_like(jnp.zeros(embedding.shape, jnp.float32), x))
+    (total, d_table), dx = jax.lax.scan(block, start,
+                                        _in_blocks(x, labels, weights))
+    return total, (dx.reshape(x.shape), d_table.astype(embedding.dtype))
+
+
+def _tied_backward(residuals, g):
+    dx, d_table = residuals
+    return ((g * dx).astype(dx.dtype), (g * d_table).astype(d_table.dtype),
+            None, None)
+
+
+_tied.defvjp(_tied_forward, _tied_backward)

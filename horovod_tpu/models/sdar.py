@@ -105,9 +105,15 @@ class RMSNorm(nn.Module):
         return jax.checkpoint(norm)(x, scale)
 
 
-def rotary(x, positions, theta: float):
+def rotary(x, positions, theta: float, width: Optional[int] = None):
     """Rotary position embedding in Qwen's half-split form: the pairs are
-    (i, i + D/2).  x [..., S, H, D], positions [S]; float32 in and out."""
+    (i, i + D/2).  x [..., S, H, D], positions [S]; float32 in and out.
+    ``width``: only the first ``width`` of a head's D are rotated (the pairs
+    lie within them), the rest pass as they are (a partial rotary factor);
+    None rotates the whole head."""
+    if width is not None and width != x.shape[-1]:
+        return jnp.concatenate([
+            rotary(x[..., :width], positions, theta), x[..., width:]], -1)
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = positions.astype(jnp.float32)[:, None] * freq[None, :]   # [S, D/2]

@@ -55,6 +55,15 @@ def ensure_varying(x, axis_name: AxisName):
     return lax.pcast(x, missing, to="varying") if missing else x
 
 
+def vary_like(a, b):
+    """``a``, varying over every mesh axis ``b`` varies over: a scan's carry
+    keeps its type and a custom_vjp returns each cotangent in its argument's
+    type, so an operand that ``shard_map`` holds replicated is cast before
+    it meets a chip's own values, and the cast's transpose sums the chips'
+    parts of its gradient."""
+    return ensure_varying(a, sorted(jax.typeof(b).vma))
+
+
 class _Subset:
     """Static geometry of a rank subset over ONE mesh axis — the traced
     process-set bridge (reference: process_set.cc communicator subsetting;

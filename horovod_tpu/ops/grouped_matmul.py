@@ -49,7 +49,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .collectives import ensure_varying
+from .collectives import vary_like as _vary_like
 
 LANES = 128
 # Rows a visit: a 36,864-row buffer is 72 of them.  megablox's tiling at an
@@ -73,14 +73,6 @@ def _out_struct(shape, dtype, *like):
     declared)."""
     vma = frozenset().union(*(jax.typeof(a).vma for a in like))
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-
-
-def _vary_like(a, b):
-    """``a``, varying over every mesh axis ``b`` varies over: a custom_vjp
-    returns each cotangent in its argument's type, and the dW of kernels
-    that ``shard_map`` holds replicated is a chip's own part until this
-    cast's transpose sums the parts."""
-    return ensure_varying(a, sorted(jax.typeof(b).vma))
 
 
 # An inlined jit, as the kernels' below: its twenty small ops are traced once

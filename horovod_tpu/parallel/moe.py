@@ -23,6 +23,15 @@ buffer walks every row a router can send, in parts (a ``lax.cond``, taken
 while the step runs).  It has no exchange: what the other chips' experts
 would add is not there (ROADMAP Reach B1 keeps the all-to-all).
 
+**Who routes.**  :func:`routed_experts` makes the choice itself
+(:func:`route`: a ``[d, experts]`` matrix, softmax, top-k, renormalised or
+not) and is what ``models/sdar.py`` calls.  :func:`dispatch_experts` is the
+dropless layer alone, for a caller that routes for itself and brings each
+token's chosen experts and their weights: ``models/zaya.py``, whose router is
+a small MLP with a state carried down the layers, chooses under a balancing
+bias and gates by the unbiased probability, top-1 and not renormalised.
+``routed_experts`` is ``route`` followed by that call.
+
 **Which product runs where**: on a TPU the three products, forward and
 backward, are the Pallas kernels of ``ops/grouped_matmul.py``
 (:func:`~horovod_tpu.ops.grouped_matmul.grouped_dot`; ``hvd_moe_gmm`` /
@@ -154,9 +163,15 @@ def route(x, router_kernel, top_k: int, first_expert: int, held: int,
     weights, experts = lax.top_k(probs, top_k)
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    load = jnp.sum(jax.nn.one_hot(_local(experts, first_expert, held), held,
+    return Routing(probs, experts, weights,
+                   expert_load(experts, first_expert, held))
+
+
+def expert_load(experts, first_expert: int, held: int):
+    """[held] int32: the rows that the choices ``experts`` [T, k] send to
+    each held expert."""
+    return jnp.sum(jax.nn.one_hot(_local(experts, first_expert, held), held,
                                   dtype=jnp.int32), axis=(0, 1))
-    return Routing(probs, experts, weights, load)
 
 
 def _local(experts, first_expert: int, held: int):
@@ -302,6 +317,30 @@ def _dropless_bwd(rows, args, g):
 _dropless.defvjp(_dropless_fwd, _dropless_bwd)
 
 
+def dispatch_experts(x, experts, weights, w_gate, w_up, w_down, *,
+                     first_expert: int, experts_total: int,
+                     capacity_factor: float):
+    """The held experts' part of every token's sum, for a caller that has
+    routed: ``sum over the token's chosen experts held here of weight x
+    expert(x)``, nothing dropped.
+
+    Args:
+      x: [tokens, d], in the activations' dtype.
+      experts: [tokens, k] int32, each token's chosen experts among all
+        ``experts_total`` of the layer; weights: [tokens, k], their weights
+        in the token's sum (differentiated; the choice is not).
+      w_gate, w_up: [held, d, f]; w_down: [held, f, d]: experts
+        ``first_expert`` .. ``first_expert + held``.
+      capacity_factor: the row buffer over what an even router sends here
+        (:func:`row_buffer`); see :func:`routed_experts`."""
+    (tokens, top_k), held = experts.shape, w_gate.shape[0]
+    with jax.named_scope("hvd_moe_route"):
+        local = _local(experts, first_expert, held)
+    rows = row_buffer(tokens, top_k, held, experts_total, capacity_factor)
+    return _dropless(rows, x, local, weights.astype(jnp.float32),
+                     w_gate, w_up, w_down)
+
+
 def routed_experts(x, router_kernel, w_gate, w_up, w_down, *, top_k: int,
                    capacity_factor: float, first_expert: int = 0,
                    renormalize: bool = True):
@@ -324,12 +363,11 @@ def routed_experts(x, router_kernel, w_gate, w_up, w_down, *, top_k: int,
     chosen experts **that are held here** of weight x expert(x) (with every
     expert held, the whole layer); the weights come from the router over all
     experts.  No token is dropped whatever the imbalance."""
-    tokens, held, experts = x.shape[0], w_gate.shape[0], router_kernel.shape[1]
     with jax.named_scope("hvd_moe_route"):
-        routing = route(x, router_kernel, top_k, first_expert, held,
-                        renormalize)
-        local = _local(routing.experts, first_expert, held)
-    rows = row_buffer(tokens, top_k, held, experts, capacity_factor)
-    y = _dropless(rows, x, local, routing.weights.astype(jnp.float32),
-                  w_gate, w_up, w_down)
+        routing = route(x, router_kernel, top_k, first_expert,
+                        w_gate.shape[0], renormalize)
+    y = dispatch_experts(x, routing.experts, routing.weights, w_gate, w_up,
+                         w_down, first_expert=first_expert,
+                         experts_total=router_kernel.shape[1],
+                         capacity_factor=capacity_factor)
     return y, routing

@@ -49,6 +49,8 @@ LONG_FILES = (
     "tests/single/test_ring_attention.py",
     "tests/single/test_flash_attention.py",
     "tests/benchmark/test_sdar_cell.py",
+    "tests/single/test_zaya.py",
+    "tests/benchmark/test_zaya_cell.py",
 )
 
 

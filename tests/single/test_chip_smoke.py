@@ -84,3 +84,21 @@ def test_kernels_phase_interpreted():
     assert all(c["ok"] for c in report["checks"])
     assert sum(c["name"].startswith("gqa/") for c in report["checks"]) == 8
     assert [c["codec"] for c in report["codecs"]] == ["int8", "int4", "int8g"]
+
+
+def test_wide_expert_products_phase_tiny(monkeypatch):
+    """The kernels (interpreted) where a whole matrix does not fit their
+    budget, against a loop over the experts, and each call's time."""
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_VMEM_BUDGET", gm._gmm_bytes(512, 256, 128, 2,
+                                                          4))
+    report = chip_smoke.wide_expert_products(
+        rows=1024, routed=640, d=256, f=256, held=4, repeats=1,
+        interpret=True)
+    assert [c["name"] for c in report["checks"]] == [
+        "wide/out", "wide/drows", "wide/dw"]
+    assert all(c["ok"] for c in report["checks"])
+    assert report["gmm_block"] == 128 and report["routed"] == 640
+    assert {"gmm_ms", "gmm_t_ms", "tgmm_ms",
+            "hvd_grouped_dot_fwd_bwd_ms/rows=1024"} <= set(report)

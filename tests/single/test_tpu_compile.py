@@ -267,6 +267,35 @@ def test_grouped_products_compile_at_an_expert_layer_s_sizes(one_chip, k, n,
     assert "ragged-dot" not in text
 
 
+@pytest.mark.parametrize("w_dtype", ["float32", "bfloat16"])
+def test_grouped_products_compile_at_an_expert_wider_than_the_budget(
+        one_chip, w_dtype):
+    """``ops/grouped_matmul.py`` at ``zaya1-moe-ep2-s16384``'s sizes (a
+    16,384-row buffer, 8 held experts of 2048 x 2048): a whole float32
+    matrix does not fit the kernels' VMEM budget, the product runs in column
+    blocks and dW in ``(bk, bn)`` blocks, and the chip's compiler takes all
+    three."""
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    w_itemsize = jnp.dtype(w_dtype).itemsize
+    whole = gm._gmm_bytes(gm.TILE_ROWS, 2048, 2048, 2, w_itemsize)
+    assert (whole > gm._VMEM_BUDGET) == (w_dtype == "float32")
+
+    def fwd_bwd(rows, w, sizes, ct):
+        out, vjp = jax.vjp(lambda r, w: gm.grouped_dot(
+            r, w, sizes, interpret=False), rows, w)
+        return (out, *vjp(ct))
+
+    text = _compiled_text(fwd_bwd, *_shapes_on(one_chip, (
+        jax.ShapeDtypeStruct((16384, 2048), jnp.bfloat16),
+        jax.ShapeDtypeStruct((8, 2048, 2048), jnp.dtype(w_dtype)),
+        jax.ShapeDtypeStruct((8,), jnp.int32),
+        jax.ShapeDtypeStruct((16384, 2048), jnp.bfloat16))))
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert len(re.findall(r"hvd_moe_gmm[\w.]* = ", text)) == 2
+    assert len(re.findall(r"hvd_moe_tgmm[\w.]* = ", text)) == 1
+
+
 @pytest.mark.parametrize("codec", ["int8", "int4", "int8g"])
 def test_codec_encode_decode_compiles(one_chip, codec):
     def roundtrip(flat):

@@ -295,6 +295,18 @@ def _expert_layer(tokens, d, f, held, experts, dtype, key, busy=0):
     return x, router, w_gate, w_up, w_down
 
 
+def _choices(tokens, top_k, held, experts, routed):
+    """[tokens, top_k] int32 choices of which ``routed``, seeded and spread
+    over the tokens, are held experts in turn and the others absent ones."""
+    import jax
+    import jax.numpy as jnp
+
+    pair = jax.random.permutation(jax.random.PRNGKey(2), tokens * top_k)
+    absent = held + pair % max(1, experts - held)
+    return jnp.where(pair < routed, pair % held, absent).reshape(
+        tokens, top_k).astype(jnp.int32)
+
+
 def _best_ms(repeats: int, fn, *args) -> float:
     """Milliseconds of ``fn(*args)`` to completion, best of ``repeats``
     after one call that compiles."""
@@ -316,7 +328,11 @@ def grouped_products(tokens: int = 16384, d: int = 2048, f: int = 768,
     """What one chip's share of a top-k expert layer costs, forward and
     backward, under an even router (the rows fit the layer's buffer) and
     under one with three busy experts (they do not: every row a router can
-    send, in parts), and what its grouped products alone cost three ways,
+    send, in parts), what it costs on a caller's choices that route a
+    quarter, a half and all of the buffer's rows here (the gather, the sum
+    back and the products follow the rows routed, not the buffer:
+    ``parallel/moe.py:rows_walked``), and what its grouped products alone
+    cost three ways,
     over that buffer and over ``tokens x top_k`` rows: milliseconds, best of
     ``repeats``.  The three: ``jax.lax.ragged_dot`` (what ``parallel/moe.py``
     uses off the TPU), the megablox ``gmm`` that ships with jax (declares no
@@ -391,6 +407,20 @@ def grouped_products(tokens: int = 16384, d: int = 2048, f: int = 768,
               "load": [int(n) for n in load],
               "load/in_parts": [int(n) for n in busy_load],
               "buffer": buffer, "worst": worst}
+    # The same layer on a caller's choices that route a quarter, a half and
+    # all of the buffer's rows here: what is paid by the row (gather, sum
+    # back, products) follows the rows routed, not the buffer.
+    dispatch = jax.jit(jax.grad(
+        lambda x, chosen, weights, *kernels: jnp.sum(moe.dispatch_experts(
+            x, chosen, weights, *kernels, first_expert=0,
+            experts_total=experts, capacity_factor=capacity_factor).astype(
+                jnp.float32) ** 2), argnums=(0, 2, 3, 4, 5)))
+    even = jnp.full((tokens, top_k), 1.0 / top_k, jnp.float32)
+    for share, routed in (("quarter", buffer // 4), ("half", buffer // 2),
+                          ("all", buffer)):
+        report[f"layer_fwd_bwd_ms/routed={share}"] = best_ms(
+            dispatch, x, _choices(tokens, top_k, held, experts, routed), even,
+            w_gate, w_up, w_down)
 
     def products(dot):
         """Gradients of the three products' sum with respect to the rows

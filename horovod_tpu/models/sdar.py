@@ -72,6 +72,10 @@ class SDARConfig:
 # together: 3/4 + j/4 of the even rows where j of the mask token's 8 experts
 # are among 16 of 128 held.  2.25 is j = 6, which one layer in 12,000 draws
 # at initialisation; a step that routes more drops nothing and costs more.
+# What the buffer's size costs a step whose rows fit it: its products, its
+# gather and its sum back into the tokens follow the rows routed
+# (``parallel/moe.py:rows_walked``); only what is paid by the byte (the zeros
+# the buffer starts from, the weighting, the d rows' sum) is paid by its rows.
 EXPERT_CAPACITY_FACTOR = 2.25
 
 # The published sizes (config.json of JetLM/SDAR-30B-A3B-Chat), whole.

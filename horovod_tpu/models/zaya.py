@@ -107,8 +107,10 @@ class ZayaConfig:
 # The expert layer's row buffer over an even router's rows
 # (``parallel/moe.py:row_buffer``).  Top-1 with half the experts held: twice
 # the even rows is every row a router can send, so the layer never walks its
-# rows in parts and its program holds no conditional.  The kernels skip the
-# buffer's empty tail, so the cost follows the rows routed.
+# rows in parts and its program holds no conditional.  The kernels, the
+# gather and the sum back into the tokens skip the buffer's empty tail
+# (``parallel/moe.py:rows_walked``: 8,192 of 16,384 rows where the load is
+# even), so what is paid by the row follows the rows routed.
 EXPERT_CAPACITY_FACTOR = 2.0
 
 # The tied embedding's standard deviation at initialisation (the

@@ -18,7 +18,10 @@ part of each token's sum that its own experts contribute.  Nothing is
 dropped: the rows routed here are sorted by expert into a row buffer of the
 caller's ``capacity_factor`` times what an even router sends here and
 multiplied in grouped matrix products, a group an expert.  The buffer's rows
-past the routed ones are in no group.  A step whose rows do not fit the
+past the routed ones are in no group, and neither the products nor the
+gather into the buffer nor the sum back into the tokens visits them
+(:func:`take_rows`, :func:`add_rows`: trips of :data:`WALK_ROWS` rows over
+the routed prefix; :func:`rows_walked`).  A step whose rows do not fit the
 buffer walks every row a router can send, in parts (a ``lax.cond``, taken
 while the step runs).  It has no exchange: what the other chips' experts
 would add is not there (ROADMAP Reach B1 keeps the all-to-all).
@@ -56,8 +59,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.collectives import axis_size, ensure_varying
-from ..ops.grouped_matmul import grouped_dot
+from ..ops.collectives import axis_size, ensure_varying, vary_like
+from ..ops.grouped_matmul import TILE_ROWS, grouped_dot
 
 
 def switch_moe(x, router_kernel, expert_fn: Callable, axis_name: str = "ep",
@@ -203,29 +206,135 @@ def _swiglu_rows(rows, group_sizes, w_gate, w_up, w_down):
         return grouped_dot(jax.nn.silu(gate) * up, w_down, group_sizes)
 
 
+# Rows a trip of :func:`take_rows` / :func:`add_rows`: one of the kernels'
+# tiles.  Chosen on a v5e in both cells (PERF.md, PR 37: 2048 / 1024 / 512
+# rows read 405.2 / 401.8 / 400.8 ms a step in SDAR's, 453.1 / 451.0 / 450.3
+# in ZAYA's: a trip costs little, the half trip walked past the last row
+# routed costs what its rows do; 4096 no longer fits what the sum keeps in
+# VMEM and costs twice as much a row).
+WALK_ROWS = TILE_ROWS
+
+
+def rows_walked(load_sum: int, capacity: int) -> int:
+    """The buffer's rows that a pass of :func:`take_rows` or :func:`add_rows`
+    visits when ``load_sum`` rows are routed into ``capacity``: whole trips
+    of :data:`WALK_ROWS`, up to the one that holds the last routed row."""
+    tile = min(WALK_ROWS, capacity)
+    return min(capacity, -(-load_sum // tile) * tile)
+
+
+def _tiles(n, capacity: int):
+    """``(tile, trips, place)``: the rows a trip takes, the trips that hold
+    the buffer's first ``n`` rows, and the first row of trip i (a buffer
+    that is no whole number of tiles ends in a tile that overlaps the one
+    before it).  The trips are counted on the device, so a loop over them has
+    no reverse mode: each of the two ops below is the other's."""
+    tile = min(WALK_ROWS, capacity)
+    return (tile, (n + tile - 1) // tile,
+            lambda i: jnp.minimum(i * tile, capacity - tile))
+
+
+def _rows_from(lo, tile: int):
+    return lo + lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+
+
+def _zeros(shape, dtype, *like):
+    """Zeros a loop starts from, varying as its operands ``like`` do (a
+    carry keeps its type under ``shard_map``'s ``check_vma``)."""
+    return functools.reduce(vary_like, like, jnp.zeros(shape, dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def take_rows(x, token, n, single: bool = False):
+    """``[C, d]``: row i is ``x[token[i]]`` for i < ``n`` and zeros past it
+    (x [T, d], token [C] int32, n int32).  Linear in ``x``; its transpose is
+    :func:`add_rows`, which ``single`` is for.  Costs what ``n`` rows cost,
+    not what C do."""
+    tile, trips, place = _tiles(n, token.shape[0])
+
+    def trip(i, rows):
+        lo = place(i)
+        return lax.dynamic_update_slice(
+            rows, x[lax.dynamic_slice(token, (lo,), (tile,))], (lo, 0))
+
+    rows = lax.fori_loop(0, trips, trip, _zeros(
+        (token.shape[0], x.shape[1]), x.dtype, x, token))
+    # The last trip may have gone past row n: zeros there too.
+    lo = place(jnp.maximum(trips - 1, 0))
+    last = lax.dynamic_slice(rows, (lo, 0), (tile, x.shape[1]))
+    return lax.dynamic_update_slice(
+        rows, jnp.where(_rows_from(lo, tile) < n, last,
+                        jnp.zeros_like(last)), (lo, 0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def add_rows(rows, token, n, tokens: int, single: bool = False):
+    """``[tokens, d]``: the sum of ``rows[i]`` into row ``token[i]`` for
+    i < ``n``, in ``rows.dtype`` and in row order, as
+    ``zeros.at[token[:n]].add(rows[:n])`` makes it.  Linear in ``rows``; its
+    transpose is :func:`take_rows`.  ``single``: no token has more than one
+    of the n rows (top-1, or one expert held), so its sum is that row and is
+    gathered: a scatter-add costs some 100 ns a row on a v5e whatever the
+    rows' bytes, a gather what its bytes cost (PERF.md, PR 37)."""
+    capacity = token.shape[0]
+    if single:
+        at = jnp.arange(capacity, dtype=jnp.int32)
+        row_of = jnp.full((tokens,), capacity, jnp.int32).at[
+            jnp.where(at < n, token, tokens)].set(at, mode="drop")
+        mine = rows[jnp.minimum(row_of, capacity - 1)]
+        return jnp.where((row_of < capacity)[:, None], mine,
+                         jnp.zeros_like(mine))
+    tile, trips, place = _tiles(n, capacity)
+
+    def trip(i, total):
+        lo = place(i)
+        row = _rows_from(lo, tile)      # below n, and in no earlier trip
+        part = lax.dynamic_slice(rows, (lo, 0), (tile, rows.shape[1]))
+        return total.at[lax.dynamic_slice(token, (lo,), (tile,))].add(
+            jnp.where((row < n) & (row >= i * tile), part,
+                      jnp.zeros_like(part)))
+
+    return lax.fori_loop(0, trips, trip, _zeros(
+        (tokens, rows.shape[1]), rows.dtype, rows, token))
+
+
+take_rows.defvjp(
+    lambda x, token, n, single: (take_rows(x, token, n, single),
+                                 (token, n, x.shape[0])),
+    lambda single, saved, g: (add_rows(g, *saved, single), None, None))
+add_rows.defvjp(
+    lambda rows, token, n, tokens, single: (
+        add_rows(rows, token, n, tokens, single), (token, n)),
+    lambda tokens, single, saved, g: (take_rows(g, *saved, single), None,
+                                      None))
+
+
 def _held_part(capacity: int, x, local, weights, w_gate, w_up, w_down):
     """The held experts' part of every token's sum through a row buffer of
-    ``capacity`` rows (at least as many as are routed here)."""
+    ``capacity`` rows (at least as many as are routed here).  What is paid by
+    the row (the gather into the buffer, the sum back into the tokens, and
+    their transposes) walks the rows routed, as the products do."""
     tokens, top_k = local.shape
     held = w_gate.shape[0]
     with jax.named_scope("hvd_moe_route"):
         flat = local.reshape(-1)                    # absent experts: ``held``
         order = jnp.argsort(flat, stable=True)[:capacity]
         sizes = jnp.sum(jax.nn.one_hot(flat, held, dtype=jnp.int32), axis=0)
-        live = jnp.arange(capacity) < jnp.sum(sizes)
+        routed = jnp.sum(sizes)
         token = order // top_k
         # The buffer's rows past the routed ones are no token's and in no
-        # group: zeros on the way in, which cuts their cotangent off on the
-        # way back, and no product is computed for them.
-        rows = jnp.where(live[:, None], x[token], jnp.zeros((), x.dtype))
+        # group: zeros on the way in, no cotangent on the way back, and no
+        # product is computed for them.
+        single = min(top_k, held) == 1      # a token has one row at most
+        rows = take_rows(x, token, routed, single)
     out = _swiglu_rows(rows, sizes, w_gate, w_up, w_down)
     with jax.named_scope("hvd_moe_route"):
-        # Weighted in float32, gathered back in the activations' dtype: a
+        # Weighted in float32, summed back in the activations' dtype: a
         # token's sum has at most min(top_k, held) terms.
-        scale = jnp.where(live, weights.reshape(-1)[order], 0.0)
+        scale = jnp.where(jnp.arange(capacity) < routed,
+                          weights.reshape(-1)[order], 0.0)
         out = (out.astype(jnp.float32) * scale[:, None]).astype(x.dtype)
-        return jnp.zeros_like(x).at[token].add(
-            jnp.where(live[:, None], out, jnp.zeros_like(out)))
+        return add_rows(out, token, routed, tokens, single)
 
 
 def _in_parts(parts: int, fn, summed: int, token_args, kernels):

@@ -67,6 +67,8 @@ def test_grouped_products_phase_tiny():
     assert sum(report["load"]) <= report["buffer"] < sum(
         report["load/in_parts"]) <= report["worst"]
     assert {"layer_fwd_bwd_ms", "layer_fwd_bwd_ms/in_parts",
+            "layer_fwd_bwd_ms/routed=quarter", "layer_fwd_bwd_ms/routed=half",
+            "layer_fwd_bwd_ms/routed=all",
             f"ragged_dot_fwd_bwd_ms/rows={report['buffer']}/half",
             f"hvd_grouped_dot_fwd_bwd_ms/rows={report['buffer']}",
             f"hvd_grouped_dot_cast_first_fwd_bwd_ms/rows={report['worst']}"

@@ -1,7 +1,8 @@
 """``parallel/moe.py:routed_experts``: one chip's share of a top-k layer of
 SwiGLU experts against a plain loop over the experts, under a skewed router
 (nothing dropped), whether or not the rows routed fit its row buffer; the
-shares add up to the uncut layer."""
+shares add up to the uncut layer; the two ops that walk the rows routed
+(``take_rows``, ``add_rows``) against whole-buffer indexing."""
 
 import functools
 
@@ -47,6 +48,14 @@ def _loop(x, router, w_gate, w_up, w_down, first=0, renormalize=True):
 
 def _held(kernels, first, held):
     return tuple(k[first:first + held] for k in kernels)
+
+
+def _traced_anew():
+    """The layer's passes are traced once a process for a shape: what a
+    test puts in their way is seen by a new trace only, and must not stay
+    behind in the cache."""
+    moe._forward.clear_cache()
+    moe._backward.clear_cache()
 
 
 @pytest.fixture(autouse=True)
@@ -137,13 +146,7 @@ def test_the_buffer_s_tail_reaches_nothing(monkeypatch, request, skewed,
     rows of its own."""
     from horovod_tpu.ops.grouped_matmul import grouped_dot
 
-    def traced_anew():
-        # The layer's passes are traced once a process for a shape: what a
-        # test puts in their way is seen by a new trace only, and must not
-        # stay behind in the cache.
-        moe._forward.clear_cache()
-        moe._backward.clear_cache()
-
+    traced_anew = _traced_anew
     x, router, *kernels = _layer(skew=6.0, skewed=skewed)
     mine = _held(kernels, 0, 4)
     request.addfinalizer(traced_anew)
@@ -240,3 +243,187 @@ def test_the_row_buffer():
     assert moe.row_buffer(16384, 8, 16, 128, 9.0) == 131072
     assert moe.row_buffer(256, 4, 2, 16, 8.0) == 512
     assert moe.row_buffer(256, 4, 16, 16, 2.0) == 1024
+
+
+# ---------------------------------------------------------------------------
+# The two row ops: the buffer's first n rows, in trips
+# ---------------------------------------------------------------------------
+
+
+def _rows_case(capacity, dtype, tokens=96, d=16, top_k=4):
+    ks = jax.random.split(jax.random.PRNGKey(capacity), 3)
+    x = jax.random.normal(ks[0], (tokens, d)).astype(dtype)
+    rows = jax.random.normal(ks[1], (capacity, d)).astype(dtype)
+    token = (jax.random.permutation(ks[2], tokens * top_k)[:capacity]
+             // top_k).astype(jnp.int32)
+    return x, rows, token
+
+
+@pytest.fixture
+def trips_of_64(monkeypatch):
+    """Trips of 64 rows, so that a small buffer is several; no pass of the
+    layer traced with another trip is left in the caches, before or after."""
+    monkeypatch.setattr(moe, "WALK_ROWS", 64)
+    _traced_anew()
+    yield
+    _traced_anew()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("capacity", [256, 300],
+                         ids=["whole-tiles", "last-tile-overlaps"])
+@pytest.mark.parametrize("n", [0, 1, 100, 128, None],
+                         ids=["none", "one", "an-edge-inside-a-tile",
+                              "a-whole-number-of-tiles", "the-full-buffer"])
+def test_the_row_ops_are_indexing_on_the_first_n_rows(trips_of_64, n,
+                                                      capacity, dtype):
+    """``take_rows`` is ``x[token]`` on the first n rows and zeros past
+    them, ``add_rows`` is ``zeros.at[token].add`` of the first n rows: the
+    same bits (a token's terms are added in row order), for a buffer of
+    whole tiles and for one whose last tile overlaps the one before it."""
+    x, rows, token = _rows_case(capacity, jnp.dtype(dtype))
+    n = capacity if n is None else n
+    live = (jnp.arange(capacity) < n)[:, None]
+    np.testing.assert_array_equal(
+        moe.take_rows(x, token, jnp.int32(n)),
+        jnp.where(live, x[token], jnp.zeros((), x.dtype)))
+    np.testing.assert_array_equal(
+        jax.jit(moe.add_rows, static_argnums=3)(rows, token, jnp.int32(n),
+                                                x.shape[0]),
+        jnp.zeros_like(x).at[token].add(jnp.where(live, rows,
+                                                  jnp.zeros_like(rows))))
+    assert moe.rows_walked(n, capacity) == min(capacity, -(-n // 64) * 64)
+
+
+@pytest.mark.parametrize("n", [0, 100, 300])
+def test_each_row_op_is_the_other_s_transpose(trips_of_64, n):
+    """The cotangent of ``take_rows`` is ``add_rows`` of the cotangent and
+    the other way round (a loop whose trips are counted while the step runs
+    has no reverse mode of its own), and both are what indexing's are."""
+    x, rows, token = _rows_case(300, jnp.float32)
+    n, live = jnp.int32(n), (jnp.arange(300) < n)[:, None]
+    _, take_vjp = jax.vjp(lambda x: moe.take_rows(x, token, n), x)
+    _, index_vjp = jax.vjp(lambda x: jnp.where(live, x[token], 0.0), x)
+    np.testing.assert_array_equal(take_vjp(rows)[0],
+                                  moe.add_rows(rows, token, n, x.shape[0]))
+    np.testing.assert_array_equal(take_vjp(rows)[0], index_vjp(rows)[0])
+    _, add_vjp = jax.vjp(lambda r: moe.add_rows(r, token, n, x.shape[0]), rows)
+    np.testing.assert_array_equal(add_vjp(x)[0], moe.take_rows(x, token, n))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n", [0, 1, 100, 300])
+def test_a_token_with_one_row_gathers_it(trips_of_64, n, dtype):
+    """``single``: no token has more than one of the n rows (top-1, or one
+    expert held), and ``add_rows`` gathers where it would add: the same
+    values, and the same transposes."""
+    x, rows, _ = _rows_case(300, jnp.dtype(dtype), tokens=400)
+    token = jax.random.permutation(jax.random.PRNGKey(7), 400)[:300].astype(
+        jnp.int32)
+    n = jnp.int32(n)
+    want = moe.add_rows(rows, token, n, 400)
+    np.testing.assert_array_equal(moe.add_rows(rows, token, n, 400, True),
+                                  want)
+    _, take_vjp = jax.vjp(lambda x: moe.take_rows(x, token, n, True), x)
+    np.testing.assert_array_equal(take_vjp(rows)[0], want)
+    _, add_vjp = jax.vjp(lambda r: moe.add_rows(r, token, n, 400, True), rows)
+    np.testing.assert_array_equal(add_vjp(x)[0], moe.take_rows(x, token, n))
+
+
+@pytest.mark.parametrize("top_k,held", [(1, 4), (4, 1)],
+                         ids=["top-1", "one-expert-held"])
+def test_a_layer_whose_tokens_have_one_row_each(top_k, held):
+    """Top-1 (ZAYA's layer) and a share of one expert: values and gradients
+    against the loop, through the gathered sum."""
+    x, router, *kernels = _layer(seed=4)
+    mine = _held(kernels, 2, held)
+
+    def loop(x, router, *k):
+        probs = jax.nn.softmax(x @ router, axis=-1)
+        weights, chosen = jax.lax.top_k(probs, top_k)
+        weights = weights / weights.sum(-1, keepdims=True)
+        y = jnp.zeros_like(x)
+        for i in range(held):
+            w = jnp.sum(jnp.where(chosen == 2 + i, weights, 0.0), axis=-1)
+            y = y + w[:, None] * ((jax.nn.silu(x @ k[0][i]) * (x @ k[1][i]))
+                                  @ k[2][i])
+        return y
+
+    def layer(x, router, *k):
+        return moe.routed_experts(x, router, *k, top_k=top_k, first_expert=2,
+                                  capacity_factor=2.0)[0]
+
+    np.testing.assert_allclose(layer(x, router, *mine),
+                               loop(x, router, *mine), rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(layer(*a) ** 2),
+                   argnums=(0, 1, 2, 3, 4))(x, router, *mine)
+    want = jax.grad(lambda *a: jnp.sum(loop(*a) ** 2),
+                    argnums=(0, 1, 2, 3, 4))(x, router, *mine)
+    for name, a, b in zip(("x", "router", "gate", "up", "down"), got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+def test_rows_walked():
+    # whole trips of WALK_ROWS up to the one that holds the last routed row
+    assert moe.WALK_ROWS == 512
+    assert moe.rows_walked(0, 36864) == 0
+    assert moe.rows_walked(1, 36864) == 512
+    assert moe.rows_walked(512, 36864) == 512
+    assert moe.rows_walked(513, 36864) == 1024
+    assert moe.rows_walked(16400, 36864) == 16896      # 33 of 72 trips
+    assert moe.rows_walked(8192, 16384) == 8192        # ZAYA: 16 of 32
+    assert moe.rows_walked(36864, 36864) == 36864
+    # a buffer smaller than a trip is one trip; one that is no whole number
+    # of trips ends at its own end
+    assert moe.rows_walked(5, 384) == 384
+    assert moe.rows_walked(1025, 1100) == 1100
+
+
+def _wide_passes_outside_loops(jaxpr, capacity, width, scope="",
+                               in_loop=False):
+    """The gathers, scatters and selects of ``jaxpr`` under the scope
+    ``hvd_moe_route`` that read or write a ``[capacity, width]`` array and
+    sit in no ``while`` body."""
+    found = []
+    for eqn in jaxpr.eqns:
+        here = f"{scope}/{eqn.source_info.name_stack}"
+        wide = any(getattr(v.aval, "shape", None) == (capacity, width)
+                   for v in (*eqn.invars, *eqn.outvars))
+        if (eqn.primitive.name in ("gather", "scatter", "scatter-add",
+                                   "select_n") and wide and not in_loop
+                and "hvd_moe_route" in here):
+            found.append((eqn.primitive.name, here))
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _wide_passes_outside_loops(
+                        sub, capacity, width, here,
+                        in_loop or eqn.primitive.name == "while")
+    return found
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_no_pass_of_the_routing_walks_the_whole_buffer(trips_of_64,
+                                                       backward):
+    """In the traced ``_forward`` and ``_backward`` at a small buffer, on
+    both sides of the ``cond``: every gather, scatter and select of the
+    scope ``hvd_moe_route`` that touches a ``[capacity, d]`` array is in a
+    ``while`` body, a trip's rows at a time.  (What is paid by the byte
+    stays whole: the weighting, the zeros the loops start from; a layer
+    whose tokens have one row each sums them back by one gather.)"""
+    x, router, *kernels = _layer()
+    mine = _held(kernels, 0, 4)
+    routing = moe.route(x, router, TOP_K, 0, 4)
+    local = moe._local(routing.experts, 0, 4)
+    rows = moe.row_buffer(TOKENS, TOP_K, 4, EXPERTS, 2.0)
+    assert rows == 512 and rows not in (TOKENS, D, F)
+    args = (x, local, routing.weights) + ((x,) if backward else ()) + mine
+    traced = jax.make_jaxpr(
+        moe._backward if backward else moe._forward, static_argnums=0)(
+            rows, *args)
+    loops = str(traced).count("while[")
+    assert loops >= (3 if backward else 2), loops
+    assert _wide_passes_outside_loops(traced.jaxpr, rows, D) == []

@@ -38,6 +38,7 @@ from .functions import (  # noqa: F401
     broadcast_object_fn, allgather_object,
 )
 from .compression import Compression  # noqa: F401
+from .utils.step_watch import StepWatch  # noqa: F401
 from . import elastic  # noqa: F401
 from . import checkpoint  # noqa: F401
 

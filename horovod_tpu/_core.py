@@ -86,6 +86,8 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.hvd_shutdown.restype = c.c_int
     lib.hvd_is_initialized.restype = c.c_int
     lib.hvd_rank.restype = c.c_int
+    lib.hvd_cycle_count.restype = c.c_longlong
+    lib.hvd_cycle_count.argtypes = []
     lib.hvd_size.restype = c.c_int
     lib.hvd_local_rank.restype = c.c_int
     lib.hvd_local_size.restype = c.c_int
@@ -692,6 +694,13 @@ class NativeCore(CoreBackend):
                 "device_encoded": dev_enc,
                 "gspmd_raw": gspmd_raw,
                 "gspmd_wire": gspmd_wire}
+
+    def cycle_count(self) -> Optional[int]:
+        """Cycles of the native background loop since init, counted whether
+        or not the metrics plane is on: a second clock, in a C++ thread, for
+        ``hvd.StepWatch``."""
+        n = self._lib.hvd_cycle_count()
+        return n if n >= 0 else None
 
     _warned_no_metrics = False
 

@@ -17,6 +17,7 @@ import numpy as np
 from .context import HorovodContext
 from .utils.env import Config, get_bool
 from .utils.logging import get_logger
+from .utils import step_watch
 from .parallel import mesh as _mesh
 
 log = get_logger()
@@ -117,6 +118,8 @@ def shutdown() -> None:
     # The jax.distributed runtime deliberately survives shutdown: it is
     # process-level, and the next init reuses it when (coordinator, size,
     # rank) are unchanged or re-initializes when they differ (elastic).
+    # Step watches first: their thread reads the core's cycle count.
+    step_watch.close_all()
     HorovodContext.shutdown()
     _mesh.reset()
 

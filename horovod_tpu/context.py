@@ -396,6 +396,12 @@ class HorovodContext:
         while not self._shutdown.is_set():
             resp = self.core.pop_response(timeout=0.05)
             if resp is None:
+                # The host-alive mark: twenty a second while nothing is
+                # negotiated, so a profiler trace of a compiled loop says of
+                # a gap of the device whether this process's host threads
+                # ran in it (docs/observability.md, "Stalls").
+                with TraceAnnotation("hvd_alive"):
+                    pass
                 continue
             # Join-state transitions must follow the GLOBAL negotiated
             # order, which only the dispatcher sees: stamp the current

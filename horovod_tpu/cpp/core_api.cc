@@ -21,6 +21,9 @@
 #include <mutex>
 #include <sstream>
 #include <thread>
+#ifdef __linux__
+#include <pthread.h>
+#endif
 #include <unordered_map>
 
 #include "common.h"
@@ -68,6 +71,11 @@ struct GlobalState {
   ParameterManager params;
   std::atomic<int64_t> fusion_threshold{64LL << 20};
   double cycle_ms = 1.0;
+  // Cycles of the background loop since init, counted whether or not the
+  // metrics plane is on: a clock in a C++ thread that hvd.StepWatch lays
+  // beside the host's (a loop that stood still while this ran on did not
+  // lose the whole process).
+  std::atomic<int64_t> cycles{0};
   double last_stall_check = 0.0;
   std::string metrics_path;  // per-rank resolved HOROVOD_METRICS_FILE
   double last_metrics_write = 0.0;
@@ -184,6 +192,11 @@ void WriteMetricsFile() {
 }
 
 void BackgroundLoop() {
+#ifdef __linux__
+  // A name of its own in /proc/<pid>/task/*/comm: hvd.StepWatch's records
+  // and top -H tell this loop from the interpreter's threads.
+  pthread_setname_np(pthread_self(), "hvd-core");
+#endif
   auto& cfg = g->cfg;
   double stall_period = cfg.stall_warn_s > 0 ? cfg.stall_warn_s : 60.0;
   while (!g->shutdown.load()) {
@@ -191,6 +204,7 @@ void BackgroundLoop() {
     std::this_thread::sleep_for(
         std::chrono::microseconds(static_cast<int64_t>(g->cycle_ms * 1000)));
     double work_start = MonotonicSeconds();
+    g->cycles.fetch_add(1, std::memory_order_relaxed);
     if (MetricsOn()) {
       auto& mreg = GlobalMetrics();
       mreg.cycle_count.fetch_add(1, std::memory_order_relaxed);
@@ -661,6 +675,11 @@ int hvd_shutdown() {
 }
 
 int hvd_is_initialized() { return g != nullptr ? 1 : 0; }
+// Background-loop cycles since init (-1 before it): always on, unlike the
+// metrics plane's cycle_count.
+long long hvd_cycle_count() {
+  return g ? g->cycles.load(std::memory_order_relaxed) : -1;
+}
 int hvd_rank() { return g ? g->cfg.rank : -1; }
 int hvd_size() { return g ? g->cfg.size : -1; }
 int hvd_local_rank() { return g ? g->cfg.local_rank : -1; }

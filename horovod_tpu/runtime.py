@@ -340,6 +340,11 @@ class CoreBackend:
         for backends without the native registry."""
         return {}
 
+    def cycle_count(self) -> Optional[int]:
+        """Cycles of the native background loop since init; None for
+        backends without one."""
+        return None
+
     def flight_record(self) -> dict:
         """Snapshot of the flight-recorder event ring (always-on black
         box); empty for backends without the native recorder."""

@@ -73,6 +73,16 @@ def get_bool(name: str, default: bool = False) -> bool:
     return val.strip().lower() in ("1", "true", "yes", "on")
 
 
+def step_watch_file() -> Optional[str]:
+    """Where ``hvd.StepWatch`` appends its stall records as JSON lines
+    (HOROVOD_STEP_WATCH_FILE; unset = kept in memory and logged only).  A
+    literal ``{rank}`` becomes this process's HOROVOD_RANK."""
+    path = os.environ.get("HOROVOD_STEP_WATCH_FILE")
+    if not path:
+        return None
+    return path.replace("{rank}", os.environ.get("HOROVOD_RANK", "0"))
+
+
 def get_int(name: str, default: int) -> int:
     val = os.environ.get(name)
     if val is None or not val.strip():

@@ -125,8 +125,11 @@ def test_eager_update_writes_the_spine_s_spans(hvd_single, tmp_path):
     for h in trace.host:
         spans.setdefault(h.name, []).append(h)
     # Bare names: the arguments travel as the event's stats (below).
-    assert set(spans) == {"bench_window", "hvd_exchange", "hvd_enqueue",
-                          "hvd_wait", "hvd_execute", "hvd_update"}
+    # (The dispatcher's host-alive mark is there too if its wait for a
+    # response timed out inside the profile.)
+    assert set(spans) - {"hvd_alive"} == {
+        "bench_window", "hvd_exchange", "hvd_enqueue", "hvd_wait",
+        "hvd_execute", "hvd_update"}
     assert [len(spans[n]) for n in ("hvd_exchange", "hvd_enqueue", "hvd_wait",
                                     "hvd_update")] == [1, 3, 3, 1]
     exchange, update = spans["hvd_exchange"][0], spans["hvd_update"][0]
@@ -224,7 +227,8 @@ def test_every_name_the_program_writes_into_a_trace_starts_with_hvd():
             if name is None or not re.match(r"^hvd_[a-z0-9_]+$", name):
                 bad.append(f"{os.path.relpath(path, REPO)}:{line}: {name!r}")
     assert not bad, bad
-    assert seen >= 12  # optimizer.py 8, context.py 3, ops/device_plane.py 3
+    # optimizer.py 8, context.py 4, ops/device_plane.py 3, step_watch.py 2
+    assert seen >= 14
     # The rule itself, on a module that breaks it three ways.
     broken = ast.parse(
         "with jax.named_scope('update'): pass\n"

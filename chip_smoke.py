@@ -11,6 +11,11 @@
                                      kernels; the same kernels at an expert
                                      of [2048, 2048], wider than their VMEM
                                      budget, each call timed; nothing else
+    python chip_smoke.py --qk-norm-rope
+                                     one chip: ``ops/qk_norm_rope.py`` at
+                                     ``sdar-moe-ep8-s4096``'s q and k against
+                                     its ``jax.numpy`` form, forward and
+                                     backward timed; nothing else
 
 One chip: the device JAX found, a clean build of the C++ core and
 ``hvd.init()`` on it, the Pallas kernels alone against their references (at
@@ -688,6 +693,82 @@ def wide_expert_products(rows: int = 16384, routed: int = 8192,
     return report
 
 
+def qk_norm_rope(batch: int = 2, length: int = 4096, heads=(32, 4),
+                 head_dim: int = 128, repeats: int = 5, chain: int = 8,
+                 interpret: bool = False) -> dict:
+    """``ops/qk_norm_rope.py`` alone (the defaults are ``sdar-moe-ep8-s4096``'s
+    q and k: 2 x 8,192 rows of 32 and of 4 heads of 128 in bfloat16, the
+    clean and the noised copy at the same positions): value, ``dx`` and
+    ``dscale`` of the kernels against the ``jax.numpy`` form, then each
+    pass's time, best of ``repeats``, and the bytes it has to move (x and
+    the result; x, the cotangent and dx) over that time, for the kernels and
+    for the ``jax.numpy`` form as XLA compiles it.  A pass is timed as one of
+    ``chain`` in one compiled program, each fed by the one before, as a step
+    runs them: the cos / sin tables are made once for all of them and one
+    dispatch is spread over the chain (written out and not a ``fori_loop``,
+    whose carry costs a copy of x a pass: 0.47 ms of 0.93 at q's size)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import qk_norm_rope as op
+
+    positions = jnp.tile(jnp.arange(length), 2)
+    forms = {"kernels": functools.partial(op.qk_norm_rope,
+                                          interpret=interpret or None),
+             "dense": op.dense_qk_norm_rope}
+    checks, report = [], {}
+    for h in heads:
+        ks = jax.random.split(jax.random.PRNGKey(h), 3)
+        x, g = (jax.random.normal(k, (batch, 2 * length, h * head_dim),
+                                  jnp.bfloat16) for k in ks[:2])
+        scale = 1.0 + 0.1 * jax.random.normal(ks[2], (head_dim,))
+        passes, chains = {}, {}
+        for form, fn in forms.items():
+            fn = functools.partial(fn, positions=positions, heads=h,
+                                   head_dim=head_dim, eps=1e-6, theta=1e6)
+
+            def fwd(x, s, fn=fn):
+                return fn(x, s)
+
+            # The backward alone: its forward's result is not asked for.
+            def bwd(x, s, g, fn=fn):
+                return jax.vjp(fn, x, s)[1](g)
+
+            passes[form] = jax.jit(fwd), jax.jit(bwd)
+            # Each pass's x is the result of the one before (the backward's:
+            # its dx, and its cotangent the x before), so nothing but the
+            # tables is the same from pass to pass.
+            def fwd_chain(x, s, fwd=fwd):
+                for _ in range(chain):
+                    x = fwd(x, s)
+                return x
+
+            def bwd_chain(x, s, g, bwd=bwd):
+                for _ in range(chain):
+                    x, g = bwd(x, s, g)[0], x
+                return x, g
+
+            chains[form] = (("fwd", 2, jax.jit(fwd_chain), (x, scale)),
+                            ("bwd", 3, jax.jit(bwd_chain), (x, scale, g)))
+        for name, a, b in zip(
+                ("out", "dx", "dscale"),
+                (passes["kernels"][0](x, scale),
+                 *passes["kernels"][1](x, scale, g)),
+                (passes["dense"][0](x, scale),
+                 *passes["dense"][1](x, scale, g))):
+            _check(checks, f"heads={h}/{name}", a, b, TOL_BF16_FWD)
+        for form, timed in chains.items():
+            for name, arrays, loop, args in timed:
+                ms = round(_best_ms(repeats, loop, *args) / chain, 3)
+                report[f"{form}_{name}_ms/heads={h}"] = ms
+                report[f"{form}_{name}_gb_s/heads={h}"] = round(
+                    arrays * x.nbytes / max(ms, 1e-3) / 1e6, 1)
+    report = emit("qk_norm_rope", checks=checks, batch=batch, length=length,
+                  head_dim=head_dim, chain=chain, **report)
+    _raise_on_failed("qk_norm_rope", checks)
+    return report
+
+
 # ---------------------------------------------------------------------------
 
 
@@ -697,6 +778,9 @@ def main(argv=None) -> int:
                     help="4: run launch_np4 and nothing else")
     ap.add_argument("--grouped-products", action="store_true",
                     help="time the expert layer's grouped products, and "
+                         "nothing else")
+    ap.add_argument("--qk-norm-rope", action="store_true",
+                    help="check and time the q/k norm + rotary op, and "
                          "nothing else")
     ap.add_argument("--worker", action="store_true",
                     help="internal: one launch_np4 worker")
@@ -715,6 +799,9 @@ def main(argv=None) -> int:
         info = device()
         grouped_products()
         wide_expert_products()
+    elif args.qk_norm_rope:
+        info = device()
+        qk_norm_rope()
     else:
         info = device()
         native_core()

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.flash_attention import dense_attention, flash_attention
+from ..ops.qk_norm_rope import dense_qk_norm_rope, qk_norm_rope
 from ..parallel.moe import routed_experts
 from .losses import softmax_cross_entropy
 
@@ -126,6 +127,25 @@ def rotary(x, positions, theta: float, width: Optional[int] = None):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
+class HeadNormRope(nn.Module):
+    """Each head of a flat projection [B, S, heads * head_dim] under one
+    RMSNorm (``scale`` [head_dim], as :class:`RMSNorm` holds it) and then
+    turned by its position, as one op on that layout
+    (``ops/qk_norm_rope.py``: one pass forward and one backward where
+    ``RMSNorm(...)(x, rope)`` on [B, S, heads, head_dim] compiles to a dozen,
+    two of them relayouts and two in float32)."""
+    config: SDARConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.config
+        scale = self.param("scale", nn.initializers.ones, (cfg.head_dim,))
+        op = qk_norm_rope if cfg.use_flash else dense_qk_norm_rope
+        return op(x, scale, positions, heads=x.shape[-1] // cfg.head_dim,
+                  head_dim=cfg.head_dim, eps=cfg.rms_norm_eps,
+                  theta=cfg.rope_theta)
+
+
 class SDARAttention(nn.Module):
     config: SDARConfig
 
@@ -137,21 +157,18 @@ class SDARAttention(nn.Module):
 
         def proj(name, heads):
             return nn.Dense(heads * d, use_bias=False, dtype=cfg.dtype,
-                            name=name)(x).reshape(*lead, heads, d)
+                            name=name)(x)
 
         q, k = proj("q_proj", cfg.num_heads), proj("k_proj", cfg.num_kv_heads)
         v = proj("v_proj", cfg.num_kv_heads)
         # The clean and the noised copy carry the same positions.
         positions = jnp.tile(jnp.arange(length), 2)
-
-        def rope(x):
-            with jax.named_scope("hvd_rope"):
-                return rotary(x, positions, cfg.rope_theta)
-
-        q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q, rope)
-        k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k, rope)
+        q = HeadNormRope(cfg, name="q_norm")(q, positions)
+        k = HeadNormRope(cfg, name="k_norm")(k, positions)
+        # q, k and v are [B, S, heads * d] from the projections on: the heads
+        # are a view at the kernels' door, which read that layout.
         attend = flash_attention if cfg.use_flash else dense_attention
-        ctx = attend(q, k, v,
+        ctx = attend(*(t.reshape(*lead, -1, d) for t in (q, k, v)),
                      block_diffusion=(length, cfg.block_length))
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                         name="o_proj")(ctx.reshape(*lead, -1))

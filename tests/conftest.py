@@ -51,6 +51,7 @@ LONG_FILES = (
     "tests/benchmark/test_sdar_cell.py",
     "tests/single/test_zaya.py",
     "tests/benchmark/test_zaya_cell.py",
+    "tests/single/test_tpu_compile.py",
 )
 
 

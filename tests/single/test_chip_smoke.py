@@ -88,6 +88,19 @@ def test_kernels_phase_interpreted():
     assert [c["codec"] for c in report["codecs"]] == ["int8", "int4", "int8g"]
 
 
+def test_qk_norm_rope_phase_tiny():
+    """The q/k norm + rotary kernels (interpreted) against the ``jax.numpy``
+    form at two head counts, and the timing table's keys."""
+    report = chip_smoke.qk_norm_rope(batch=1, length=48, heads=(2, 1),
+                                     repeats=1, chain=2, interpret=True)
+    assert [c["name"] for c in report["checks"]] == [
+        f"heads={h}/{n}" for h in (2, 1) for n in ("out", "dx", "dscale")]
+    assert all(c["ok"] for c in report["checks"])
+    assert {f"{form}_{p}_{unit}/heads={h}" for form in ("kernels", "dense")
+            for p in ("fwd", "bwd") for unit in ("ms", "gb_s")
+            for h in (2, 1)} <= set(report)
+
+
 def test_wide_expert_products_phase_tiny(monkeypatch):
     """The kernels (interpreted) where a whole matrix does not fit their
     budget, against a loop over the experts, and each call's time."""

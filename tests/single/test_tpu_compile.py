@@ -207,6 +207,61 @@ def test_block_takes_the_kernels_without_relayout(one_chip, monkeypatch,
         assert len(marked) >= 8, relayouts
 
 
+def _elements(result: str) -> int:
+    """Elements of the first array of an instruction's result type."""
+    return int(np.prod([int(n) for n in re.search(
+        r"\[([\d,]*)\]", result).group(1).split(",") if n]))
+
+
+def test_sdar_norms_and_turns_q_and_k_on_the_kernels_layout(one_chip,
+                                                            monkeypatch):
+    """``SDARAttention`` at ``sdar-moe-ep8-s4096``'s shape (2 x 8,192
+    positions, 32 query heads on 4 key/value heads of 128), forward +
+    backward: the per-head norm and the rotary of q and k are the two
+    kernels of ``ops/qk_norm_rope.py`` on the projections' own
+    [B, S, H * D], so nothing of q's size is copied, transposed or reshaped
+    under their scopes and no float32 array of q's size exists (the parent
+    compiled a relayout on either side of ``RMSNorm(...)(q, rope)``, the
+    normed q in float32 and the rotated halves padded to a lane tile).
+    What ``hvd_flash_block_diag`` and the ``[clean ; noised]`` split still
+    relay is counted in the message and held to nothing: the next issue's."""
+    from horovod_tpu import models
+    from horovod_tpu.models import sdar
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(models.SDAR_30B_A3B, use_flash=True)
+    attn = sdar.SDARAttention(cfg)
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    params = _shapes_on(one_chip, jax.eval_shape(
+        attn.init, jax.random.PRNGKey(0), x))
+
+    def loss(params, x):
+        return jnp.sum(attn.apply(params, x).astype(jnp.float32) ** 2)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1)), params, x)
+    for kernel in ("hvd_qk_norm_rope_fwd", "hvd_qk_norm_rope_bwd"):
+        # q's and k's
+        assert len(re.findall(rf"%{kernel}[\w.]* = ", text)) == 2, kernel
+    q_elements = 2 * 8192 * cfg.num_heads * cfg.head_dim
+    entry = [(found.group(2), found.group(1), line) for found, line in (
+        (_INSTRUCTION.match(line), line)
+        for line in text[text.index("\nENTRY"):].splitlines()) if found]
+    moved = [(op, "".join(re.findall(r'op_name="([^"]*)"', line)))
+             for op, result, line in entry
+             if op in ("copy", "transpose", "reshape")
+             and _elements(result) >= q_elements]
+    left = (f"{len(moved)} relayouts of q's size left in the block: "
+            f"{[(op, name[-40:] or 'no scope') for op, name in moved]}")
+    ours = [m for m in moved if re.search(
+        "q_norm|k_norm|hvd_rope|hvd_qk_norm_rope", m[1])]
+    assert not ours, left
+    wide = [result for _, result, _ in entry if result.startswith("f32[")
+            and _elements(result) >= q_elements]
+    assert not wide, (wide, left)
+    print(left)
+
+
 def test_head_and_loss_write_the_logits_once_in_float32(one_chip):
     """GPT-2-medium's float32 head and ``lm_loss`` at the benchmark's GPT
     cells' shape, value and gradient: the head's matmul writes the logits

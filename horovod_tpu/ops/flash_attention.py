@@ -46,24 +46,26 @@ cost no copy.  ``block_q`` and ``block_k`` need not be equal.  Under the
 causal mask and under ``kv_lens`` every processed row meets a valid key in
 the first step it takes (column 0 under a causal mask; a real key
 otherwise), which keeps the running max finite with a -1e30 mask value: no
-NaN guards needed.  The third mask breaks that, and is guarded where it does.
+NaN guards needed.  The third mask keeps that by the order of its visits.
 
 Three masks: none or the causal diagonal (``causal``), a key length per
 sequence (``kv_lens``, below), and **block diffusion**
 (``block_diffusion=(L, B)``, :func:`block_diffusion_mask`): the sequence is
 ``[clean ; noised]``, two copies of L positions in blocks of B, and the live
-area is L^2 (1 + B / L) of the (2L)^2 square.  It is walked in three pieces
-(:func:`_flash_block_diffusion`): clean queries on the clean blocks up to
-their own and noised queries on the clean blocks before theirs are ONE call
-of the kernels, the copies taken as neighbouring sequences whose index maps
-read the same (clean) keys, with the causal walks and one comparison of
-block numbers in the masked steps, ``<=`` or ``<`` by the grid row; the
-noised copy's own blocks, L x B pairs, are plain block-wise products merged
-with the kernels' part through its log-sum-exp.  A noised row of the first
-block sees **no clean key**: in its masked steps the kernels zero a hidden
-pair's probability outright (:func:`_seen`), so the row leaves with a zero
-output and an lse of the mask value, and the merge weighs it by exactly
-zero.
+area is L^2 (1 + B / L) of the (2L)^2 square.  It is ONE call of the
+kernels on the model's own arrays (:func:`_flash_block_diffusion`): the two
+copies are taken as neighbouring sequences of L rows whose index maps read
+the same (clean) keys, clean queries on the clean blocks up to their own and
+noised queries on the clean blocks before theirs, with the causal walks and
+one comparison of block numbers in the masked steps, ``<=`` or ``<`` by the
+grid row.  The noised copy's own blocks, L x B pairs a head, are squares on
+the diagonal that the same kernels visit (:func:`_own_side`): a noised grid
+row holds its own copy's k and v at the resident block's positions through
+a second pair of block specs on the same arrays, and meets them under the
+mask "same block" before anything else.  A noised row of the first block
+sees **no clean key**, but by then it has met itself: its running maximum
+and its lse are real scores, and a hidden pair's probability is an
+exponential that underflows to zero, as under the other masks.
 
 **Grouped-query heads**: k and v may have fewer heads than q (query head
 ``i`` reads key/value head ``i // group``).  The forward and dq take a query
@@ -342,7 +344,8 @@ def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit):
 
 
 def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
-            transposed: bool = False, bd: int = 0, strict=0):
+            transposed: bool = False, bd: int = 0, strict=0,
+            own: bool = False):
     """The float32 score tile q @ k^T * sm_scale ([Tq, Tk]; or its
     transpose k @ q^T), masked where the diagonal or the tail padding
     crosses it.  The dot takes its operands as they arrive."""
@@ -353,10 +356,14 @@ def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
         q_axis, k_axis = (1, 0) if transposed else (0, 1)
         kpos = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
         if bd:
-            # Block diffusion over clean keys: a clean query sees the blocks
-            # up to its own, a noised one (``strict`` 1) those before it.
+            # Block diffusion.  Over clean keys a clean query sees the blocks
+            # up to its own, a noised one (``strict`` 1) those before it;
+            # over the noised copy's keys (``own``) a noised query sees its
+            # own block.
             qpos = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
-            s = jnp.where(kpos // bd + strict <= qpos // bd, s, NEG_INF)
+            seen = (kpos // bd == qpos // bd if own else
+                    kpos // bd + strict <= qpos // bd)
+            s = jnp.where(seen, s, NEG_INF)
         elif causal:
             # Padding lives at the tail, so kpos > any real qpos: the
             # causal mask already excludes padded keys.
@@ -367,19 +374,37 @@ def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
     return s
 
 
-def _seen(p, s, masked: bool, bd: int):
-    """``p`` with the pairs the mask hides set to zero outright.  Only a
-    block-diffusion call needs it, in its masked steps: a noised row of the
-    first block sees no clean key at all, so its running maximum (or its
-    lse) is the mask value itself and ``exp(s - m)`` reads 1 where it should
-    read 0.  Every other row has met a visible key by the time it meets a
-    hidden one, and the exponential is zero by itself."""
-    return jnp.where(s > 0.5 * NEG_INF, p, 0.0) if masked and bd else p
-
-
 def _strict(variant: _Variant, rows: int):
     """1 in the grid rows of a noised copy (the odd sequences), else 0."""
     return (pl.program_id(0) // rows) % 2 if variant.bd else 0
+
+
+def _own_side(step: int, bd: int) -> int:
+    """Rows (= keys) of one square of a noised copy's visit to its own
+    blocks: the positions ``[c * side, (c + 1) * side)`` of the noised
+    queries against the same positions of the noised keys, under the mask
+    "same block".  One lane tile where whole blocks fill it (the MXU's
+    width: nothing smaller computes less), else the walk's own step, which
+    whole blocks always fill."""
+    return LANES if step % LANES == 0 and LANES % bd == 0 else step
+
+
+def _own_squares(lo, hi, rows: int, side: int, square):
+    """``square(c)`` for the squares of the stretches ``[lo, hi)`` of
+    ``rows`` positions each (a resident tile's), square ``c`` at position
+    ``c * side`` of the block.  A stretch's squares are written out one
+    after another: a square alone is two to four small products that wait
+    for each other, and only side by side do the squares fill the MXU
+    (read on a v5e at 2 x 8,192 x 32 x 128: 128-row squares one a loop
+    trip cost the three kernels 2.3 ms a call, eight a trip 1.0; PERF.md,
+    PR 45)."""
+    per = rows // side
+
+    def stretch(t, _):
+        for d in range(per):
+            square(t * per + d)
+
+    jax.lax.fori_loop(lo, hi, stretch, None)
 
 
 def _head_lanes(plan: TilePlan):
@@ -407,9 +432,8 @@ def _row_to_col(row):
     return jnp.transpose(jnp.broadcast_to(row, (min(t, LANES), t)))[:, :1]
 
 
-def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, sm_scale: float, causal: bool, plan: TilePlan,
-                valid_len, variant: _Variant = _PLAIN):
+def _mha_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, causal: bool,
+                plan: TilePlan, valid_len, variant: _Variant = _PLAIN):
     """Forward: grid (BH / G, n_q, n_kv).  A query block stays resident
     while key/value blocks stream past it.  The score tile is built
     transposed ([Tk, Tq] = k @ q^T): the online-softmax statistics are then
@@ -419,21 +443,54 @@ def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     the kv grid steps and ride in registers inside a run of steps.  The
     block's G heads take each step together, G independent chains; their
     acc^T stack to [G * D, Tq], which leaves as one lane-dense [Tq, G * D]
-    store."""
+    store.
+
+    ``rest``: the outputs o, lse and the scratch acc^T, m, l; before them,
+    in a block-diffusion call, the noised copy's own k and v at the resident
+    query block's positions (:func:`_own_side`)."""
+    if variant.bd:
+        k_own_ref, v_own_ref, *rest = rest
+    o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     iq, jk = pl.program_id(1), pl.program_id(2)
     n_kv = pl.num_programs(2)
     tile, step = plan.tile_q, plan.step_k
     heads = _head_lanes(plan)
-    bd = variant.bd
+    bd, strict = variant.bd, _strict(variant, variant.q_rows)
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
                               valid_len=valid_len, transposed=True, bd=bd,
-                              strict=_strict(variant, variant.q_rows))
+                              strict=strict)
 
     @pl.when(jk == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
+
+    if bd:
+        # A noised row starts with its own block, which holds the row
+        # itself: its running maximum is a real score before the walk over
+        # the clean keys shows it a hidden pair (a row of the first block
+        # sees no clean key at all), so the mask value never stands in for
+        # a maximum and exp(s - m) is zero wherever the mask hides a pair.
+        @pl.when(jnp.logical_and(jk == 0, strict == 1))
+        def _own():
+            side = _own_side(step, bd)
+
+            def square(c):
+                rows = pl.ds(pl.multiple_of(c * side, side), side)
+                for g, h in enumerate(heads):
+                    v = v_own_ref[rows, h]
+                    s = score(q_ref[rows, h], k_own_ref[rows, h], 0, 0, True,
+                              own=True)                     # [Tk, Tq]
+                    m = jnp.max(s, axis=0, keepdims=True)
+                    p = jnp.exp(s - m)
+                    m_ref[g:g + 1, rows] = m
+                    l_ref[g:g + 1, rows] = jnp.sum(p, axis=0, keepdims=True)
+                    acc_ref[h, rows] = jax.lax.dot_general(
+                        v, p.astype(v.dtype), _TN,
+                        preferred_element_type=jnp.float32)
+
+            _own_squares(0, plan.block_q // tile, tile, side, square)
 
     @pl.when(_block_live(iq, jk, causal, plan, valid_len))
     def _compute():
@@ -456,8 +513,8 @@ def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                                   masked)
                         m_new = jnp.maximum(
                             m, jnp.max(s, axis=0, keepdims=True))
-                        p = _seen(jnp.exp(s - m_new), s, masked, bd)
-                        alpha = jnp.exp(m - m_new)          # p [Tk, Tq]
+                        p = jnp.exp(s - m_new)              # [Tk, Tq]
+                        alpha = jnp.exp(m - m_new)
                         l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
                         acc = acc * alpha + jax.lax.dot_general(  # v^T @ p
                             v, p.astype(v.dtype), _TN,
@@ -531,16 +588,31 @@ def _clean_row(row, rows_per_seq: int):
     return row - ((row // rows_per_seq) % 2) * rows_per_seq
 
 
-def _kv_row_of(variant: _Variant):
-    """fwd / dq: the key/value row that the query row ``r`` reads."""
+def _kv_row_of(variant: _Variant, own: bool = False):
+    """fwd / dq: the key/value row that the query row ``r`` reads; under
+    the block-diffusion mask the clean copy's or (``own``) its own copy's."""
     if variant == _PLAIN:
         return None
 
     def row_of(r, j):
         row = r if variant.group == 1 else r // variant.group
-        return _clean_row(row, variant.kv_rows) if variant.bd else row
+        return (_clean_row(row, variant.kv_rows) if variant.bd and not own
+                else row)
 
     return row_of
+
+
+def _own_kv(variant: _Variant, plan: TilePlan, kb, vb):
+    """``(in_specs, operands)`` that a block-diffusion call appends to the
+    forward's and dq's: k and v once more, under a spec that keeps the grid
+    row's own copy at the resident query block's positions, so the noised
+    rows meet their own blocks where q already is.  Nothing without the
+    mask."""
+    if not variant.bd:
+        return [], ()
+    spec = _operand_spec(plan.block_q, plan, kb.shape[2] // plan.lanes,
+                         _by_i, _kv_row_of(variant, own=True))
+    return [spec, spec], (kb, vb)
 
 
 def _by_i(r, i, j, lens):
@@ -607,11 +679,12 @@ def _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret, valid_len,
     kv_spec = _operand_spec(bk, plan, kb.shape[2] // plan.lanes,
                             _streamed_k(causal, plan, valid_len),
                             _kv_row_of(variant))
+    own_specs, own_kv = _own_kv(variant, plan, kb, vb)
     return _kernel_call(
         _mha_kernel, lens, sm_scale=sm_scale, causal=causal, plan=plan,
         valid_len=valid_len, interpret=interpret, **_named(variant, "fwd"),
         grid=(n * n_col, s // bq, s // bk),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, *own_specs],
         out_specs=[q_spec, _row_stat_spec(plan, _by_i)],
         out_shape=[
             _out_struct(qb.shape, qb.dtype, qb),
@@ -622,7 +695,7 @@ def _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret, valid_len,
             pltpu.VMEM((g, bq), jnp.float32),
             pltpu.VMEM((g, bq), jnp.float32),
         ],
-    )(qb, kb, vb)
+    )(qb, kb, vb, *own_kv)
 
 
 def _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan: TilePlan):
@@ -647,9 +720,8 @@ def _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan: TilePlan):
 
 
 def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
-                       dq_ref, acc_ref, delta_ref, *, sm_scale: float,
-                       causal: bool, plan: TilePlan, valid_len,
-                       variant: _Variant = _PLAIN):
+                       *rest, sm_scale: float, causal: bool, plan: TilePlan,
+                       valid_len, variant: _Variant = _PLAIN):
     """dQ: grid (BH / G, n_q, n_kv); key/value blocks stream past a
     resident query block while dq accumulates in f32 scratch.  P is
     re-materialized from the lse residual: the [S, S] score matrix never
@@ -662,20 +734,63 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     64-wide operand fills half the MXU's contraction or output width as it
     is; the zeros ride in the other half (read on the chip, PERF.md PR 28:
     faster than slicing a head's lanes out and shifting the second head's
-    back in)."""
+    back in).
+
+    ``rest``: the output dq and the scratch acc, delta; before them, in a
+    block-diffusion call, the noised copy's own k and v as in the
+    forward."""
+    if variant.bd:
+        k_own_ref, v_own_ref, *rest = rest
+    dq_ref, acc_ref, delta_ref = rest
     iq, jk = pl.program_id(1), pl.program_id(2)
     n_kv = pl.num_programs(2)
     tile, step = plan.tile_q, plan.step_k
     heads = _head_lanes(plan)
-    bd = variant.bd
+    bd, strict = variant.bd, _strict(variant, variant.q_rows)
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
-                              valid_len=valid_len, bd=bd,
-                              strict=_strict(variant, variant.q_rows))
+                              valid_len=valid_len, bd=bd, strict=strict)
+
+    def resident(rows):
+        """Per head: a tile's q, dO with the other heads' lanes zeroed;
+        lse, delta [Tq, 1]."""
+        q, do = q_ref[rows, :], do_ref[rows, :]             # [Tq, lanes]
+        return [(_only(q, h, plan), _only(do, h, plan),
+                 _row_to_col(lse_ref[g:g + 1, rows]),
+                 _row_to_col(delta_ref[g:g + 1, rows]))
+                for g, h in enumerate(heads)]
+
+    def gather(acc, tile_of, k, v, row0, off, col0, masked, **mask):
+        """``acc`` [Tq, lanes] + what the keys k, v [Tk, lanes] from
+        position ``col0`` on give the dq of a tile's rows from ``row0 + off``
+        on, each head into its own lanes."""
+        for (q, do, lse, delta), h in zip(tile_of, heads):
+            s = score(q, k, row0 + off, col0, masked, **mask)
+            p = jnp.exp(s - lse)                            # [Tq, Tk]
+            dp = jax.lax.dot_general(
+                do, v, _NT, preferred_element_type=jnp.float32)
+            ds = p * (dp - delta) * sm_scale
+            acc = acc + jnp.dot(                            # head h's lanes
+                ds.astype(k.dtype), _only(k, h, plan),
+                preferred_element_type=jnp.float32)
+        return acc
 
     @pl.when(jk == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan)
+
+    if bd:
+        @pl.when(jnp.logical_and(jk == 0, strict == 1))
+        def _own():
+            side = _own_side(step, bd)
+
+            def square(c):
+                rows = pl.ds(pl.multiple_of(c * side, side), side)
+                acc_ref[rows, :] = gather(
+                    acc_ref[rows, :], resident(rows), k_own_ref[rows, :],
+                    v_own_ref[rows, :], 0, 0, 0, True, own=True)
+
+            _own_squares(0, plan.block_q // tile, tile, side, square)
 
     @pl.when(_block_live(iq, jk, causal, plan, valid_len))
     def _compute():
@@ -685,29 +800,13 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
 
             def visit(off, masked, lo, hi):
                 rows = pl.ds(start + off, tile - off)
-                q, do = q_ref[rows, :], do_ref[rows, :]     # [Tq, lanes]
-                # Per head: q, dO with the other heads' lanes zeroed;
-                # lse, delta [Tq, 1].
-                resident = [
-                    (_only(q, h, plan), _only(do, h, plan),
-                     _row_to_col(lse_ref[g:g + 1, rows]),
-                     _row_to_col(delta_ref[g:g + 1, rows]))
-                    for g, h in enumerate(heads)]
+                tile_of = resident(rows)
 
                 def body(j, acc):                           # [Tq, lanes]
                     cols = pl.ds(pl.multiple_of(j * step, step), step)
                     col0 = jk * plan.block_k + j * step
-                    k, v = k_ref[cols, :], v_ref[cols, :]   # [Tk, lanes]
-                    for (q, do, lse, delta), h in zip(resident, heads):
-                        s = score(q, k, row0 + off, col0, masked)
-                        p = _seen(jnp.exp(s - lse), s, masked, bd)  # [Tq, Tk]
-                        dp = jax.lax.dot_general(
-                            do, v, _NT, preferred_element_type=jnp.float32)
-                        ds = p * (dp - delta) * sm_scale
-                        acc = acc + jnp.dot(                # head h's lanes
-                            ds.astype(k.dtype), _only(k, h, plan),
-                            preferred_element_type=jnp.float32)
-                    return acc
+                    return gather(acc, tile_of, k_ref[cols, :],
+                                  v_ref[cols, :], row0, off, col0, masked)
 
                 acc_ref[rows, :] = _run(body, lo, hi, acc_ref[rows, :])
 
@@ -721,8 +820,7 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
 
 
 def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
-                        dk_ref, dv_ref, dk_acc, dv_acc, delta_ref, *,
-                        sm_scale: float, causal: bool, plan: TilePlan,
+                        *rest, sm_scale: float, causal: bool, plan: TilePlan,
                         valid_len, variant: _Variant = _PLAIN):
     """dK/dV: grid (BH / G, n_kv, n_q); query/dO/statistic blocks stream
     past a resident key block while dk/dv accumulate in f32 scratch, the
@@ -743,7 +841,21 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     Grouped queries (``variant.group`` query heads read this key/value
     head): the streamed axis walks the query blocks of one head after
     another, ``group * n_q`` grid steps, and dk / dv gather all of them
-    before they leave."""
+    before they leave.
+
+    ``rest``: the outputs dk, dv and the scratch dk, dv accumulators and
+    delta.  A block-diffusion call has the noised copy's own k and v block
+    before them and, after each of the three groups, what the own block
+    gathers: outputs dk, dv and two accumulators more.  A noised grid row
+    keeps the *clean* keys resident as k, v (what its queries owe them
+    leaves as dk, dv and is the clean keys' by right) and beside them its
+    own keys, which meet the queries of their own block
+    (:func:`_own_side`)."""
+    if variant.bd:
+        (k_own_ref, v_own_ref, dk_ref, dv_ref, dk_own_ref, dv_own_ref,
+         dk_acc, dv_acc, delta_ref, dk_own_acc, dv_own_acc) = rest
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc, delta_ref = rest
     jk, t = pl.program_id(1), pl.program_id(2)
     last_t, bd = pl.num_programs(2) - 1, variant.bd
     iq = t if variant.group == 1 else t % (pl.num_programs(2)
@@ -752,18 +864,77 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     n = plan.block_q // step
     last = _steps(valid_len, step)
     heads = _head_lanes(plan)
+    strict = _strict(variant, variant.kv_rows)
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
                               valid_len=valid_len, transposed=True, bd=bd,
-                              strict=_strict(variant, variant.kv_rows))
+                              strict=strict)
+
+    def gather(state, kv, rows, row0, col0, masked, **mask):
+        """``(dk, dv)`` [Tk, lanes] + what the queries ``rows`` of the
+        streamed block, at position ``row0``, owe the keys ``kv`` (per head:
+        k, v with the other heads' lanes zeroed) at position ``col0``."""
+        dk, dv = state
+        q, do = q_ref[rows, :], do_ref[rows, :]             # [Tq, lanes]
+        ps, dss = [], []
+        for g, (k, v) in enumerate(kv):
+            s = score(q, k, row0, col0, masked, **mask)
+            p = jnp.exp(s - lse_ref[g:g + 1, rows])         # [Tk, Tq]
+            dp = jax.lax.dot_general(
+                v, do, _NT, preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_ref[g:g + 1, rows]) * sm_scale
+            ps.append(p.astype(do.dtype))
+            dss.append(ds.astype(q.dtype))
+        # One dot for all heads: [p_0 | p_1] @ [dO_0; dO_1], each dO_h zero
+        # outside head h's lanes.
+        dv = dv + jnp.dot(
+            jnp.concatenate(ps, axis=1), jnp.concatenate(
+                [_only(do, h, plan) for h in heads], axis=0),
+            preferred_element_type=jnp.float32)
+        dk = dk + jnp.dot(
+            jnp.concatenate(dss, axis=1), jnp.concatenate(
+                [_only(q, h, plan) for h in heads], axis=0),
+            preferred_element_type=jnp.float32)
+        return dk, dv
 
     @pl.when(t == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        if bd:
+            dk_own_acc[:] = jnp.zeros_like(dk_own_acc)
+            dv_own_acc[:] = jnp.zeros_like(dv_own_acc)
 
     @pl.when(_block_live(iq, jk, causal, plan, valid_len))
     def _compute():
         _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan)
+
+        if bd:
+            # The own keys meet the queries at their positions, which the
+            # streamed block holds for all of the key block or for none
+            # where the two blocks are of one size; else for some of its
+            # stretches, each short enough to lie inside one query block.
+            # ``ahead``: by how many stretches the key block starts after
+            # the streamed one.
+            @pl.when(strict == 1)
+            def _own():
+                side = _own_side(step, bd)
+                stretch = math.gcd(tile, plan.block_q)
+                per_q, per_k = plan.block_q // stretch, plan.block_k // stretch
+                ahead = jk * per_k - iq * per_q
+
+                def square(c):
+                    cols = pl.ds(pl.multiple_of(c * side, side), side)
+                    rows = pl.ds(pl.multiple_of(
+                        c * side + ahead * stretch, side), side)
+                    k, v = k_own_ref[cols, :], v_own_ref[cols, :]
+                    dk_own_acc[cols, :], dv_own_acc[cols, :] = gather(
+                        (dk_own_acc[cols, :], dv_own_acc[cols, :]),
+                        [(_only(k, h, plan), _only(v, h, plan))
+                         for h in heads], rows, 0, 0, True, own=True)
+
+                _own_squares(jnp.clip(-ahead, 0, per_k),
+                             jnp.clip(per_q - ahead, 0, per_k), stretch,
+                             side, square)
 
         def k_tile(c, _):
             start = pl.multiple_of(c * tile, tile)
@@ -775,32 +946,10 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
                 # Per head: k, v with the other heads' lanes zeroed.
                 kv = [(_only(k, h, plan), _only(v, h, plan)) for h in heads]
 
-                def body(j, state):
-                    dk, dv = state                          # [Tk, lanes]
+                def body(j, state):                         # [Tk, lanes]
                     rows = pl.ds(pl.multiple_of(j * step, step), step)
                     row0 = iq * plan.block_q + j * step
-                    q, do = q_ref[rows, :], do_ref[rows, :]     # [Tq, lanes]
-                    ps, dss = [], []
-                    for g, (k, v) in enumerate(kv):
-                        s = score(q, k, row0, col0, masked)
-                        p = _seen(jnp.exp(s - lse_ref[g:g + 1, rows]), s,
-                                  masked, bd)               # [Tk, Tq]
-                        dp = jax.lax.dot_general(
-                            v, do, _NT, preferred_element_type=jnp.float32)
-                        ds = p * (dp - delta_ref[g:g + 1, rows]) * sm_scale
-                        ps.append(p.astype(do.dtype))
-                        dss.append(ds.astype(q.dtype))
-                    # One dot for all heads: [p_0 | p_1] @ [dO_0; dO_1],
-                    # each dO_h zero outside head h's lanes.
-                    dv = dv + jnp.dot(
-                        jnp.concatenate(ps, axis=1), jnp.concatenate(
-                            [_only(do, h, plan) for h in heads], axis=0),
-                        preferred_element_type=jnp.float32)
-                    dk = dk + jnp.dot(
-                        jnp.concatenate(dss, axis=1), jnp.concatenate(
-                            [_only(q, h, plan) for h in heads], axis=0),
-                        preferred_element_type=jnp.float32)
-                    return dk, dv
+                    return gather(state, kv, rows, row0, col0, masked)
 
                 dk_acc[cols, :], dv_acc[cols, :] = _run(
                     body, lo, hi, (dk_acc[cols, :], dv_acc[cols, :]))
@@ -830,6 +979,9 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     def _flush():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+        if bd:
+            dk_own_ref[:] = dk_own_acc[:].astype(dk_own_ref.dtype)
+            dv_own_ref[:] = dv_own_acc[:].astype(dv_own_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 13),
@@ -859,16 +1011,17 @@ def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
                             _streamed_k(causal, plan, valid_len),
                             _kv_row_of(variant))
     row_by_i = _row_stat_spec(plan, _by_i)
+    own_specs, own_kv = _own_kv(variant, plan, kb, vb)
     dq = _kernel_call(
         _mha_bwd_dq_kernel, lens, **kernel_args, **_named(variant, "dq"),
         grid=(n * n_col, s // bq, s // bk),
         in_specs=[q_by_i, kv_by_j, kv_by_j, q_by_i, q_by_i, row_by_i,
-                  row_by_i],
+                  row_by_i, *own_specs],
         out_specs=q_by_i,
         out_shape=_out_struct(qb.shape, qb.dtype, qb),
         scratch_shapes=[pltpu.VMEM((bq, plan.lanes), jnp.float32),
                         delta_scratch],
-    )(qb, kb, vb, dob, ob, lse, dlse)
+    )(qb, kb, vb, dob, ob, lse, dlse, *own_kv)
 
     # dk/dv: k-block fixed per outer step, q/do/stats stream inside, from
     # the first query block that sees it to the last real one.
@@ -888,19 +1041,22 @@ def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
     kv_by_i = _operand_spec(bk, plan, kv_col, _by_i)
     kv_in = _operand_spec(bk, plan, kv_col, _by_i, k_row_of)
     row_by_j = _row_stat_spec(plan, streamed_q, q_row_of)
-    dk, dv = _kernel_call(
+    # Under the block-diffusion mask a key/value grid row holds its own
+    # copy's block too, and what that gathers leaves beside dk, dv.
+    more = 1 if variant.bd else 0
+    accumulator = pltpu.VMEM((bk, plan.lanes), jnp.float32)
+    dk, dv, *d_own = _kernel_call(
         _mha_bwd_dkv_kernel, lens, **kernel_args, **_named(variant, "dkv"),
         grid=(kb.shape[0] * kv_col, s // bk, group * n_q),
         in_specs=[q_by_j, kv_in, kv_in, q_by_j, q_by_j, row_by_j,
-                  row_by_j],
-        out_specs=[kv_by_i, kv_by_i],
+                  row_by_j] + [kv_by_i, kv_by_i] * more,
+        out_specs=[kv_by_i, kv_by_i] * (1 + more),
         out_shape=[_out_struct(kb.shape, kb.dtype, kb),
-                   _out_struct(vb.shape, vb.dtype, vb)],
-        scratch_shapes=[pltpu.VMEM((bk, plan.lanes), jnp.float32),
-                        pltpu.VMEM((bk, plan.lanes), jnp.float32),
-                        delta_scratch],
-    )(qb, kb, vb, dob, ob, lse, dlse)
-    return dq, dk, dv
+                   _out_struct(vb.shape, vb.dtype, vb)] * (1 + more),
+        scratch_shapes=[accumulator, accumulator, delta_scratch]
+        + [accumulator, accumulator] * more,
+    )(qb, kb, vb, dob, ob, lse, dlse, *own_kv)
+    return dq, dk, dv, d_own
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
@@ -934,19 +1090,25 @@ def _flash_lse_bwd(sm_scale, causal, plan, interpret, valid_len, variant,
                    res, cotangents):
     qb, kb, vb, lens, ob, lse = res
     dob, dlse = cotangents
-    dq, dk, dv = _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal,
-                            plan, interpret, valid_len, lens, variant)
+    dq, dk, dv, d_own = _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale,
+                                   causal, plan, interpret, valid_len, lens,
+                                   variant)
     if variant.bd:
         # The noised copy's rows read the clean copy's keys: what the dkv
-        # kernel gathered in their name belongs to those.
-        def to_clean(x):
+        # kernel gathered in their name belongs to those, and the noised
+        # keys are owed what their own blocks gathered.
+        # (The copies are parted along the leading dimension alone: a view.
+        # Flattened to [pairs, 2, everything] the same arrays are relaid,
+        # 0.4 ms a layer at SDAR's k and v; PERF.md, PR 45.)
+        def to_clean(x, own):
             rows = x.shape[0] * (x.shape[2] // plan.lanes)
-            pair = x.reshape(rows // (2 * variant.kv_rows), 2, -1)
-            both = pair[:, 0].astype(jnp.float32) + pair[:, 1]
-            return jnp.stack([both.astype(x.dtype),
-                              jnp.zeros_like(pair[:, 1])], 1).reshape(x.shape)
+            pairs = rows // (2 * variant.kv_rows)
+            x, own = (y.reshape(pairs, 2, -1, *y.shape[1:]) for y in (x, own))
+            both = x[:, 0].astype(jnp.float32) + x[:, 1]
+            return jnp.stack([both.astype(x.dtype), own[:, 1]],
+                             1).reshape(-1, *x.shape[3:])
 
-        dk, dv = to_clean(dk), to_clean(dv)
+        dk, dv = to_clean(dk, d_own[0]), to_clean(dv, d_own[1])
     return dq, dk, dv, None
 
 
@@ -1118,25 +1280,17 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret, kv_lens,
 
 def _flash_block_diffusion(q, k, v, length, block, sm_scale, block_q,
                            block_k, interpret):
-    """``(out, lse)`` under the block-diffusion mask.  Its live area is a
-    quarter of the 2L x 2L square and lies in three pieces, each walked by
-    what suits it:
-
-    - clean queries on clean keys (blocks up to the query's own) and noised
-      queries on clean keys (blocks before it): both stop at the diagonal
-      as a causal call does and differ in one comparison, so they are one
-      call of the kernels, the two copies taken as neighbouring sequences
-      of L rows that read the same keys (no operand is cut or copied: the
-      index maps send a noised row to the clean copy's keys).  dk and dv of
-      the two come out apart and are added here;
-    - noised queries on the noised keys of their own block: L x B pairs a
-      head, as plain block-wise products under ``hvd_flash_block_diag``,
-      merged with the kernel's part through its log-sum-exp (the merge's
-      backward reaches the kernels as the cotangent of lse).
-
-    A noised row of the first block sees no clean key: the kernels give it
-    a zero output and an lse of the mask value, and the merge then weighs it
-    by exactly zero."""
+    """``(out, lse)`` under the block-diffusion mask: one call of the
+    kernels on the model's own arrays.  The clean and the noised copy are
+    taken as neighbouring sequences of L rows (a view of [b, 2L, ...] as
+    [2b, L, ...]; only a length the plan pads moves anything).  Both copies'
+    queries walk the *clean* keys and stop at the diagonal as a causal call
+    does, differing in one comparison of block numbers (the index maps send
+    a noised row to the clean copy's keys), and a noised row visits its own
+    block besides, through a second view of the same k and v.  The live
+    area is a quarter of the 2L x 2L square and L x B pairs a head.  dk and
+    dv of the clean keys come out in two parts, one a copy's queries, and
+    are added in the backward (:func:`_flash_lse_bwd`)."""
     b, _, h, d = q.shape
     hkv, group = k.shape[2], _group(q, k, v)
     plan = tile_plan(length, d, q.dtype.itemsize, True, block_q, block_k,
@@ -1149,36 +1303,16 @@ def _flash_block_diffusion(q, k, v, length, block, sm_scale, block_q,
 
     def halves(x):                    # [b, 2L, H, D] -> [2b, L_pad, H, D]
         x = x.reshape(2 * b, length, *x.shape[2:])
-        return jnp.pad(x, [(0, 0), (0, s_pad - length), (0, 0), (0, 0)])
+        return x if s_pad == length else jnp.pad(
+            x, [(0, 0), (0, s_pad - length), (0, 0), (0, 0)])
 
     to_kernel, from_kernel = _kernel_layout(plan, 2 * b, s_pad, d)
     variant = _Variant(group, block, h // g, hkv // g)
     out, lse = _flash_lse(*(to_kernel(halves(x)) for x in (q, k, v)), None,
                           sm_scale, True, plan, interpret, length, variant)
-    out = from_kernel(out)[:, :length].reshape(b, 2, length, h, d)
+    out = from_kernel(out)[:, :length].reshape(b, 2 * length, h, d)
     lse = lse.reshape(b, 2, h, s_pad)[..., :length]
-    with jax.named_scope("hvd_flash_block_diag"):
-        n = length // block
-
-        def blocks(x):                # the noised copy, block by block
-            return x[:, length:].reshape(b, n, block, hkv, -1, d)
-
-        kn, vn = blocks(k)[:, :, :, :, 0], blocks(v)[:, :, :, :, 0]
-        own = jnp.einsum("bnqkgd,bnjkd->bnkgqj", blocks(q), kn,
-                         preferred_element_type=jnp.float32) * sm_scale
-        # The kernels' part of a noised row, as [b, n, hkv, group, block].
-        before = lse[:, 1].reshape(b, hkv, group, n, block).transpose(
-            0, 3, 1, 2, 4)
-        total = jnp.logaddexp(before, jax.scipy.special.logsumexp(own, -1))
-        out_own = jnp.einsum(
-            "bnkgqj,bnjkd->bnqkgd",
-            jnp.exp(own - total[..., None]).astype(v.dtype), vn)
-        weight = jnp.exp(before - total).transpose(0, 1, 4, 2, 3)
-        noised = (out[:, 1] * weight.reshape(b, length, h, 1).astype(
-            out.dtype) + out_own.reshape(b, length, h, d))
-        lse_noised = total.transpose(0, 2, 3, 1, 4).reshape(b, h, length)
-    return (jnp.concatenate([out[:, 0], noised], axis=1),
-            jnp.concatenate([lse[:, 0], lse_noised], axis=-1))
+    return out, lse.transpose(0, 2, 1, 3).reshape(b, h, 2 * length)
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = False,

@@ -2,6 +2,8 @@
 estimate, heads a 128-lane block.  No kernel runs here.  Split from
 test_flash_attention.py."""
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.ops import flash_attention as fa
@@ -104,3 +106,67 @@ def test_tile_plan_explicit_block_wins():
     plan = tile_plan(96, 8, 4, True, block_q=24, block_k=32)
     assert (plan.block_q, plan.block_k, plan.seq_pad) == (24, 32, 96)
     assert plan.tile_q % plan.step_k == 0 and plan.tile_k % plan.step_q == 0
+
+
+# The benchmark's cells: sequence, head width, a call's heads (one where the
+# heads are grouped), then the plan.  A mask of its own (block diffusion) may
+# hold more in VMEM than the estimate counts; it does not move these.
+CELLS = {
+    "gpt2m-causal-1024x64": ((1024, 64, 16), (
+        1024, 1024, 1024, 1024, 1024, 256, 256, 15335424, 2, 128)),
+    "bert-kv_lens-512x64": ((512, 64, 16), (
+        512, 512, 512, 512, 512, 256, 256, 7667712, 2, 128)),
+    "sdar-grouped-4096x128": ((4096, 128, 1), (
+        4096, 2048, 2048, 1024, 1024, 256, 256, 14942208, 1, 128)),
+    "zaya-grouped-16384x128": ((16384, 128, 1), (
+        16384, 2048, 2048, 1024, 1024, 256, 256, 14942208, 1, 128))}
+
+
+@pytest.mark.parametrize("shape,plan", CELLS.values(), ids=CELLS.keys())
+def test_tile_plan_at_the_cells_shapes(shape, plan):
+    seq, head_dim, heads = shape
+    for causal in (True, False):
+        assert tuple(tile_plan(seq, head_dim, 2, causal, heads=heads)) == plan
+
+
+def _kernel_calls(jaxpr):
+    """(inputs, outputs) of every ``pallas_call`` in a jaxpr, nested ones
+    too, in order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((len(eqn.invars), len(eqn.outvars)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _kernel_calls(sub)
+    return found
+
+
+# What reaches the three kernels: q, k, v into the forward, those with dO, O,
+# lse and dlse into dq and dkv (and the lengths of a ``kv_lens`` call before
+# them).  Only the block-diffusion mask hands k and v over a second time,
+# for the noised copy's own blocks, and takes their dk, dv from dkv apart.
+OPERANDS = {
+    "plain-causal": ((1, 128, 2, 64), 2, {"causal": True},
+                     [(3, 2), (7, 1), (7, 2)]),
+    "kv_lens": ((2, 128, 2, 64), 2, {"kv_lens": [100, 128]},
+                [(4, 2), (8, 1), (8, 2)]),
+    "grouped-causal": ((1, 128, 4, 128), 2, {"causal": True},
+                       [(3, 2), (7, 1), (7, 2)]),
+    "grouped-block-diffusion": ((1, 256, 4, 128), 2,
+                                {"block_diffusion": (128, 4)},
+                                [(5, 2), (9, 1), (9, 4)])}
+
+
+@pytest.mark.parametrize("shape,kv_heads,mask,calls", OPERANDS.values(),
+                         ids=OPERANDS.keys())
+def test_only_a_block_diffusion_call_hands_k_and_v_over_twice(
+        shape, kv_heads, mask, calls):
+    q = jnp.zeros(shape, jnp.bfloat16)
+    k = v = jnp.zeros((*shape[:2], kv_heads, shape[3]), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, interpret=True, **mask)
+                       .astype(jnp.float32))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    assert _kernel_calls(jaxpr.jaxpr) == calls

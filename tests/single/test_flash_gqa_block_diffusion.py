@@ -101,6 +101,66 @@ def test_block_diffusion_walks_over_several_tiles_and_steps(
     _assert_same(got, want)
 
 
+# The noised copy's own blocks, met inside the kernels.  Query heads,
+# key/value heads, head width, L, block length, (block_q, block_k), toy tiles
+# (tiles of 32 rows in steps of 8, so L = 128 in grid blocks of 64 is two
+# grid blocks of two tiles and the own squares are 8 x 8; without them a
+# step is whole lane tiles and the squares are 128 x 128).
+OWN = {"two-grid-blocks-8on2-B4": (8, 2, 32, 128, 4, (64, 64), True),
+       "two-grid-blocks-4on4-B8": (4, 4, 32, 128, 8, (64, 64), True),
+       "bq>bk-4on2-B4": (4, 2, 32, 128, 4, (64, 32), True),
+       "bq<bk-4on1-B8": (4, 1, 32, 128, 8, (32, 64), True),
+       "lane-tile-squares-2on1-B8": (2, 1, 128, 256, 8, (None, None), False),
+       "lane-tile-squares-2on2-B128": (2, 2, 128, 256, 128, (None, None),
+                                       False)}
+
+
+@pytest.mark.parametrize("case", OWN.values(), ids=OWN.keys())
+def test_a_noised_row_meets_its_own_block_inside_the_kernels(
+        case, request):
+    """Values, the lse and dq, dk, dv against the dense form where the
+    diagonal grid step is not the first, where the query and key blocks
+    differ, and at both sizes of the own squares; the noised keys' and
+    values' gradients on their own rows (nothing but the own squares gives
+    them any); a noised row of the first block, which sees no clean key:
+    a finite lse, the dense one, and the softmax over its own block."""
+    heads, kv_heads, d, length, block, (block_q, block_k), toy = case
+    if toy:
+        request.getfixturevalue("small_tiles")
+    from horovod_tpu.ops import flash_attention as fa
+
+    q, k, v, w = _qkv(1, 2 * length, heads, kv_heads, d, seed=4)
+    wl = jax.random.normal(jax.random.PRNGKey(5), (1, heads, 2 * length))
+
+    def value_and_grads(pair):
+        def loss(q, k, v):
+            out, lse = pair(q, k, v)
+            return jnp.sum(out * w) + jnp.sum(lse * wl), (out, lse)
+
+        (_, (out, lse)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out, *grads), lse
+
+    got, lse = value_and_grads(lambda q, k, v: fa._flash(
+        q, k, v, False, None, block_q, block_k, True, None, (length, block)))
+    want, want_lse = value_and_grads(lambda q, k, v: fa._dense(
+        q, k, v, False, None, None, (length, block)))
+    _assert_same(got, want)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=2e-5, atol=2e-5)
+    for name, a, b in zip(("dk", "dv"), got[2:], want[2:]):
+        noised = np.asarray(a[:, length:])
+        assert np.abs(noised).max() > 1e-3, name
+        np.testing.assert_allclose(noised, np.asarray(b[:, length:]),
+                                   rtol=2e-4, atol=2e-5, err_msg=name)
+    first = slice(length, length + block)
+    assert np.isfinite(np.asarray(lse[..., first])).all()
+    assert np.abs(np.asarray(lse[..., first])).max() < 1e3
+    alone = dense_attention(q[:, first], k[:, first], v[:, first])
+    np.testing.assert_allclose(np.asarray(got[0][:, first]),
+                               np.asarray(alone), rtol=2e-4, atol=2e-5)
+
+
 GROUPED = {"causal-4on1-d32": (True, 4, 1, 32, 100),
            "causal-4on2-d128": (True, 4, 2, 128, 200),
            "full-4on2-d32": (False, 4, 2, 32, 130),

@@ -219,12 +219,16 @@ def test_sdar_norms_and_turns_q_and_k_on_the_kernels_layout(one_chip,
     positions, 32 query heads on 4 key/value heads of 128), forward +
     backward: the per-head norm and the rotary of q and k are the two
     kernels of ``ops/qk_norm_rope.py`` on the projections' own
-    [B, S, H * D], so nothing of q's size is copied, transposed or reshaped
-    under their scopes and no float32 array of q's size exists (the parent
-    compiled a relayout on either side of ``RMSNorm(...)(q, rope)``, the
-    normed q in float32 and the rotated halves padded to a lane tile).
-    What ``hvd_flash_block_diag`` and the ``[clean ; noised]`` split still
-    relay is counted in the message and held to nothing: the next issue's."""
+    [B, S, H * D], and the block-diffusion mask is one call each of the
+    three flash kernels on the same arrays (the noised copy's own blocks
+    inside them, the two copies as views), so nothing of q's size is copied,
+    transposed or reshaped anywhere in the block and no float32 array of
+    q's size exists.  (Before ``ops/qk_norm_rope.py`` there was a relayout
+    on either side of ``RMSNorm(...)(q, rope)``, the normed q in float32
+    and the rotated halves padded to a lane tile; while the own blocks were
+    products outside the kernels, under ``hvd_flash_block_diag``, head-major
+    copies of the noised half of q going in and of q's cotangent coming
+    out.)"""
     from horovod_tpu import models
     from horovod_tpu.models import sdar
 
@@ -240,9 +244,11 @@ def test_sdar_norms_and_turns_q_and_k_on_the_kernels_layout(one_chip,
         return jnp.sum(attn.apply(params, x).astype(jnp.float32) ** 2)
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1)), params, x)
-    for kernel in ("hvd_qk_norm_rope_fwd", "hvd_qk_norm_rope_bwd"):
-        # q's and k's
-        assert len(re.findall(rf"%{kernel}[\w.]* = ", text)) == 2, kernel
+    for kernel, calls in (("hvd_qk_norm_rope_fwd", 2),       # q's and k's
+                          ("hvd_qk_norm_rope_bwd", 2), ("hvd_flash_fwd", 1),
+                          ("hvd_flash_dq", 1), ("hvd_flash_dkv", 1)):
+        assert len(re.findall(rf"%{kernel}[\w.]* = ", text)) == calls, kernel
+    assert "hvd_flash_block_diag" not in text
     q_elements = 2 * 8192 * cfg.num_heads * cfg.head_dim
     entry = [(found.group(2), found.group(1), line) for found, line in (
         (_INSTRUCTION.match(line), line)
@@ -251,15 +257,10 @@ def test_sdar_norms_and_turns_q_and_k_on_the_kernels_layout(one_chip,
              for op, result, line in entry
              if op in ("copy", "transpose", "reshape")
              and _elements(result) >= q_elements]
-    left = (f"{len(moved)} relayouts of q's size left in the block: "
-            f"{[(op, name[-40:] or 'no scope') for op, name in moved]}")
-    ours = [m for m in moved if re.search(
-        "q_norm|k_norm|hvd_rope|hvd_qk_norm_rope", m[1])]
-    assert not ours, left
+    assert not moved, [(op, name[-40:] or "no scope") for op, name in moved]
     wide = [result for _, result, _ in entry if result.startswith("f32[")
             and _elements(result) >= q_elements]
-    assert not wide, (wide, left)
-    print(left)
+    assert not wide, wide
 
 
 def test_head_and_loss_write_the_logits_once_in_float32(one_chip):

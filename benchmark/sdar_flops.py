@@ -1,9 +1,10 @@
 """The operations and bytes of family ``sdar``: the step's multiply-adds as the
 algorithm needs them, the least work of the flash kernels under the
 block-diffusion mask with grouped key/value heads, the least work of the
-expert layer's grouped products from the rows routed, and three readers: of
-the probe's expert-load counters, and of the grouped products' time and
-roofline share (their ops carry a scope or a name, not both).
+expert layer's grouped products from the rows routed, and two readers: of
+the probe's expert-load counters, and of the time of ops found by a scope or
+by a name (``zaya_flops`` and ZAYA's metric files use it; this family's
+products are found by their scope alone, through ``trace_reduce``'s readers).
 
 Everything is computed from shapes (``flops.py``'s rule): nothing reads
 ``cost_analysis()``.  A jaxpr walk would not do here: ``jax.lax.ragged_dot``
@@ -25,8 +26,9 @@ def live_pairs(length: int, block: int) -> dict:
     L / B: clean on clean B^2 n (n + 1) / 2, noised on the clean blocks
     before B^2 n (n - 1) / 2, noised on its own noised block n B^2.  Together
     L^2 (1 + 1 / n) of the (2L)^2 square; the first two are the flash
-    kernels' (L^2 exactly), the third is L x B and runs as plain block-wise
-    products beside them."""
+    kernels' main walk (L^2 exactly, ``kernels``); the third is L x B, 0.1 %
+    of them at L 4096 and B 4, and since PR 45 runs inside the same three
+    kernels as 128 x 128 squares on the diagonal."""
     n = length // block
     assert n * block == length, (length, block)
     pairs = {"clean_on_clean": block * block * n * (n + 1) // 2,
@@ -77,9 +79,13 @@ def flash_step_least(ctx: dict) -> dict:
     """The least time one chip could spend in the three flash kernels of one
     step (``flops.flash_least_seconds``'s rule, per kernel the larger of
     operations over peak FLOP/s and bytes over peak bytes/s).  Operations:
-    2 x pairs x head width per matmul over the pairs the kernels are called
-    for (``live_pairs(...)["kernels"]``, L^2 a sequence and a query head; the
-    noised copy's own blocks are not theirs).  Bytes: the query-side arrays
+    2 x pairs x head width per matmul over ``live_pairs(...)["kernels"]``,
+    L^2 a sequence and a query head.  The noised copy's own L x B pairs,
+    which the kernels have computed themselves since PR 45 (a 128 x 128
+    square where a 4 x 4 block is needed), are left out of the least work:
+    they are 0.1 % of it, and what the squares take (6.4 of 125 ms a step,
+    PERF.md section 6, PR 45) reads in the three rooflines as cost.  Bytes:
+    the query-side arrays
     (q, o or dO, dq) over the 2L rows of every query head; k and v, dk and
     dv over the clean copy's L rows of every key/value head, once a group
     however many query heads read them; the float32 row statistics."""
@@ -157,12 +163,12 @@ def scope_or_name_ms(trace, ctx: dict, scope: str, pattern: str,
                      **_) -> Optional[float]:
     """Device time a step spends in the core's ops whose scope matches
     ``scope`` **or** whose name matches ``pattern`` (the union of their
-    intervals, mean over the chips).  XLA's expansion of a ``ragged_dot``
-    leaves the products themselves without any scope (``%ragged-dot-none.N``:
-    77.9 of the 91 ms a step that the expert layer's products take in
-    ``sdar-moe-ep8-s4096``, my chip run, PR 34), so neither a scope nor a
-    name finds all of them and ``trace_reduce.op_time_ms`` takes one
-    conjunction.  None when nothing matches."""
+    intervals, mean over the chips), where ``trace_reduce.op_time_ms`` takes
+    one conjunction.  Written when XLA's expansion of a ``ragged_dot`` left
+    the products without any scope (``%ragged-dot-none.N``, PR 34); the
+    kernels of ``ops/grouped_matmul.py`` carry the caller's scope, so since
+    PR 46 this family's two metrics select by the scope alone and only
+    ZAYA's files name this reader.  None when nothing matches."""
     import re
 
     from benchmark import trace_reduce
@@ -175,15 +181,6 @@ def scope_or_name_ms(trace, ctx: dict, scope: str, pattern: str,
     if not any(busy):
         return None
     return trace_reduce.per_step(sum(busy) / len(busy), trace.steps)
-
-
-def experts_roofline_pct(trace, ctx: dict, scope: str, pattern: str,
-                         **_) -> Optional[float]:
-    """``experts_step_least`` over ``scope_or_name_ms``, in per cent."""
-    took = scope_or_name_ms(trace, ctx, scope, pattern)
-    if not took:
-        return None
-    return 100.0 * experts_step_least(ctx)["seconds"] * 1e3 / took
 
 
 def expert_load_max_over_mean(trace, ctx: dict, **_) -> Optional[float]:

@@ -774,6 +774,38 @@ def test_traffic_generator_reads_the_file_and_the_seed(name):
     assert not np.array_equal(a[0][0], c[0][0])     # another seed
 
 
+@pytest.mark.parametrize("count", [1, 3])
+def test_a_batchs_argument_is_the_draw_of_its_own_fold_of_the_seed(count):
+    """The generator draws an argument over all the batches at once; what
+    batch b gets as argument i is still what ``fold_in(fold_in(fold_in(
+    key(seed), 1), b), i)`` draws by itself, bit for bit, so a mix's first
+    batch does not change with the number of its batches."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sizes = {"batch_per_chip": 2, "distinct_batches": count}
+    inputs = [traffic_gen.Input((8,), jnp.int32, "randint", 18991),
+              traffic_gen.Input((3, 5), jnp.bfloat16, "normal"),
+              traffic_gen.Input((2,), jnp.int32, "randint", 1 << 20)]
+    mesh = common.hvd_mesh(jax.devices()[:1])
+    seed = 2 ** 31 + 5
+    got = traffic_gen.make_batches(sizes, inputs, mesh, seed)
+    assert len(got) == count
+    key = jax.random.fold_in(jax.random.key(seed), 1)
+    for b, batch in enumerate(got):
+        for i, (spec, x) in enumerate(zip(inputs, batch)):
+            k = jax.random.fold_in(jax.random.fold_in(key, b), i)
+            want = (jax.random.normal(k, (2, *spec.shape), spec.dtype)
+                    if spec.draw == "normal" else jax.random.randint(
+                        k, (2, *spec.shape), 0, spec.high, spec.dtype))
+            assert x.dtype == want.dtype and x.shape == want.shape
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(want))
+    with pytest.raises(ValueError, match="unknown draw"):
+        traffic_gen.make_batches(sizes, [traffic_gen.Input(
+            (2,), jnp.int32, "poisson")], mesh, seed)
+
+
 # Recorded on a TPU v5 lite by run.py's traced window (two steps each of a
 # tiny GPT): the rehearsal sizes with dense attention (PR 23), and 256 wide
 # with the flash kernels on (PR 24).

@@ -58,10 +58,10 @@ def _context():
             "peaks": peaks}
 
 
-def test_rehearsal_prints_the_contract_keys_and_no_metric(tmp_path):
-    """``run.py --rehearse`` at tiny sizes (4 of 8 experts held from the
-    third on, top-2, 4 query heads on 2): every check against the plain
-    reference passes and no CPU number is written as a metric."""
+@pytest.fixture(scope="module")
+def rehearsal(tmp_path_factory):
+    """One ``run.py --rehearse`` of the cell in a process of its own."""
+    tmp_path = tmp_path_factory.mktemp("rehearsal")
     env = {k: v for k, v in os.environ.items() if k != "BENCH_RUN"}
     env.update(JAX_PLATFORMS="cpu", BENCH_RUN="7",
                JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
@@ -74,6 +74,14 @@ def test_rehearsal_prints_the_contract_keys_and_no_metric(tmp_path):
          "--rehearse"], env=env, cwd=REPO, capture_output=True, text=True,
         timeout=240)
     assert done.returncode == 0, done.stderr[-2000:]
+    return done
+
+
+def test_rehearsal_prints_the_contract_keys_and_no_metric(rehearsal):
+    """``run.py --rehearse`` at tiny sizes (4 of 8 experts held from the
+    third on, top-2, 4 query heads on 2): every check against the plain
+    reference passes and no CPU number is written as a metric."""
+    done = rehearsal
     lines = [json.loads(x) for x in done.stdout.strip().splitlines()]
     result = lines[-1]
     assert set(result) == RESULT_KEYS | {"checks"}
@@ -118,7 +126,7 @@ def test_the_cell_is_the_published_model_at_one_chips_share():
     assert (traffic["batch_per_chip"], traffic["seq_len"],
             traffic["block_length"], traffic["distinct_batches"],
             traffic["warmup_steps"], traffic["trace_steps"]) == (
-                2, 4096, 4, 1, 3, 10)
+                2, 4096, 4, 64, 3, 10)
     assert cfg["assumed"]["block_length"] == traffic["block_length"]
     scfg = sdar._sdar_config(cfg, rehearse=False)
     assert (scfg.vocab_size, scfg.mask_token_id) == (18992, 18991)
@@ -284,6 +292,111 @@ def test_the_family_shapes_what_the_generator_draws(tiny):
         sdar.inputs(cell, {**traffic, "block_length": 8})
 
 
+RUN_SEEDS = (7, 3000000019)     # the ``tiny`` fixture's and the rehearsal's
+
+
+def test_setup_at_two_run_seeds_gives_the_same_parameters_leaf_for_leaf(tiny):
+    """The weights' key comes from the configuration (``--rehearse``'s from
+    its ``rehearse`` group): the run's seed moves no leaf, another
+    ``weights_seed`` moves every one."""
+    cell, _, _ = tiny                                 # seed=RUN_SEEDS[0]
+    cfg = cell["cfg"]
+    other = sdar.setup(cfg, cell["mesh"], seed=RUN_SEEDS[1], rehearse=True)
+    here = common.leaf_paths(cell["params"])
+    there = common.leaf_paths(other["params"])
+    assert list(here) == list(there) and len(here) == 2 * 12 + 3
+    for path, leaf in here.items():
+        assert np.array_equal(np.asarray(leaf), np.asarray(there[path])), path
+    redrawn = {**cfg, "rehearse": {**cfg["rehearse"], "weights_seed": 8}}
+    drawn = common.leaf_paths(sdar.setup(
+        redrawn, cell["mesh"], seed=RUN_SEEDS[0], rehearse=True)["params"])
+    scales = [p for p in here if p.endswith("['scale']")]   # ones, any key
+    assert len(scales) == 2 * 4 + 1
+    for path, leaf in here.items():
+        assert (path in scales) == np.array_equal(
+            np.asarray(leaf), np.asarray(drawn[path])), path
+
+
+@pytest.mark.parametrize("argument", [0, 1, 2],
+                         ids=["ids", "levels", "token_draws"])
+def test_make_batches_at_two_run_seeds_draws_other_traffic(argument, tiny):
+    """``--seed`` still reaches the generator: ids, the blocks' levels and
+    the tokens' draws all differ between two run seeds, and the same seed
+    gives the same batch."""
+    cell, _, traffic = tiny
+    drawn = [traffic_gen.make_batches(traffic, sdar.inputs(cell, traffic),
+                                      cell["mesh"], seed)[0][argument]
+             for seed in (*RUN_SEEDS, RUN_SEEDS[0])]
+    a, b, again = (np.asarray(x) for x in drawn)
+    assert a.shape == b.shape and (a != b).mean() > 0.9
+    assert np.array_equal(a, again)
+
+
+def test_the_configuration_names_its_weights_seed_and_says_why():
+    _, cfg, _ = _files()
+    seed = cfg["assumed"]["weights_seed"]
+    assert isinstance(seed, int) and 0 <= seed < 2 ** 32
+    assert sdar.weights_seed(cfg) == seed
+    assert sdar.weights_seed(cfg, rehearse=True) == cfg["rehearse"][
+        "weights_seed"]
+    why = cfg["assumed"]["weights_seed_why"]
+    for said in ("checkpoint", "--seed", "traffic", "rows", str(seed)):
+        assert said in why, said
+    # the top level of the file stays the published config's keys
+    assert "weights_seed" not in cfg
+
+
+@pytest.mark.parametrize("named", ["nothing", True, "2147485001", 1.0],
+                         ids=["nothing", "a_bool", "a_string", "a_float"])
+def test_the_family_refuses_a_configuration_without_a_weights_seed(named):
+    """By name, before any weight is made: it makes no key up."""
+    import jax
+
+    _, cfg, _ = _files(rehearse=True)
+    assumed = {k: v for k, v in cfg["assumed"].items() if k != "weights_seed"}
+    if named != "nothing":
+        assumed["weights_seed"] = named
+    broken = {**cfg, "assumed": assumed}
+    for rehearse in (False, True):
+        with pytest.raises(KeyError, match="assumed.weights_seed"):
+            sdar.weights_seed(broken, rehearse)
+    with pytest.raises(KeyError, match="sdar-30b-a3b-ep8.*weights_seed"):
+        sdar.setup(broken, common.hvd_mesh(jax.devices()[:1]), seed=7,
+                   rehearse=True)
+
+
+def test_two_run_seeds_rehearse_correct_from_different_first_losses(
+        rehearsal, monkeypatch, capsys):
+    """A whole rehearsal at each of two run seeds, the fixture's in its own
+    process and one here: both end ``correct`` on the same weights, and the
+    first losses differ because the batches do."""
+    runs = [[json.loads(x) for x in rehearsal.stdout.strip().splitlines()],
+            _rehearsal_lines(monkeypatch, capsys, seed=8)]
+    cells = [next(x for x in lines if x.get("note") == "cell")
+             for lines in runs]
+    assert [c["seed"] for c in cells] == [3000000019, 8]
+    assert all(lines[-1]["correct"] is True for lines in runs)
+    losses = [c["first_loss"] for c in cells]
+    assert all(np.isfinite(losses)) and abs(losses[0] - losses[1]) > 1e-3
+    rows = [next(c["expert_load"]["rows_by_layer"] for c in cell["checks"]
+                 if "expert_load" in c) for cell in cells]
+    assert rows[0] != rows[1]
+
+
+@pytest.mark.parametrize("steps,distinct,at", [
+    (153, 1, 152), (153, 64, 128), (10, 64, 0), (64, 64, 0), (65, 64, 64),
+    (7, 3, 6), (1, 3, 0)])
+def test_the_first_loss_is_held_against_the_last_step_on_the_first_batch(
+        steps, distinct, at):
+    """Step i takes batch i modulo their number, and the first loss is the
+    first batch's: with the cell's 64 batches the window's last loss is
+    another batch's, and says nothing of whether the first one's fell."""
+    losses = [100.0 - i for i in range(steps)]
+    assert run.last_loss_of_the_first_batch(losses, distinct) == losses[at]
+    assert at % distinct == 0 and at + distinct > steps - 1
+    assert run.last_loss_of_the_first_batch([], distinct) is None
+
+
 def test_choices_differing_counts_the_choices_the_reference_does_not_make():
     system = np.array([[[0, 1], [2, 3]], [[4, 5], [6, 7]]])
     reference_ = np.array([[[1, 0], [2, 9]], [[4, 5], [8, 9]]])
@@ -397,9 +510,14 @@ def test_parameters_kept_in_bfloat16_read_over_the_first_updates_limit():
 
 
 def _rehearsal_in_this_process(monkeypatch, capsys, seed) -> dict:
+    """The result line of ``_rehearsal_lines``."""
+    return _rehearsal_lines(monkeypatch, capsys, seed)[-1]
+
+
+def _rehearsal_lines(monkeypatch, capsys, seed) -> list:
     """The whole of a run past its look for a chip (``--rehearse``), in this
-    process, so that what a test has patched underneath is what runs: the
-    result line."""
+    process, so that what a test has patched underneath is what runs: every
+    line it prints, the result last."""
     import jax
 
     from horovod_tpu.utils import compile_cache
@@ -415,7 +533,8 @@ def _rehearsal_in_this_process(monkeypatch, capsys, seed) -> dict:
         for k, v in kept.items():
             jax.config.update(k, v)
     assert code == 0
-    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    return [json.loads(x)
+            for x in capsys.readouterr().out.strip().splitlines()]
 
 
 def test_a_step_that_returns_its_state_unchanged_is_not_correct(monkeypatch,

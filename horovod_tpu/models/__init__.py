@@ -3,7 +3,11 @@ Inception-v3, the BERT family (SURVEY.md §6; BASELINE.json configs 1-3), a
 GPT-style causal decoder, and SDAR-MoE (a Qwen3-MoE decoder of grouped-query
 attention and top-k routed experts, trained by block diffusion), and ZAYA1
 (compressed convolutional attention, a top-1 mixture whose MLP router carries
-a state down the layers, a tied head; its loss is ``zaya.lm_loss``)."""
+a state down the layers, a tied head; its loss is ``zaya.lm_loss``) and
+``Jamba`` (Mamba-1 selective-scan layers with an attention layer every
+``attn_layer_period``, a dense SwiGLU feed-forward a block, a tied head; each
+layer a share of a tensor-parallel one where the configuration says so; its
+loss is ``jamba.lm_loss``)."""
 
 from .losses import softmax_cross_entropy  # noqa: F401
 from .mlp import MLP, xent_loss  # noqa: F401
@@ -26,3 +30,5 @@ from . import zaya  # noqa: F401
 from .zaya import Zaya, ZayaConfig, ZAYA1_8B, ZAYA_TINY  # noqa: F401
 from .vgg import VGG, VGG16, VGG19, VGGTiny  # noqa: F401
 from .inception import InceptionV3  # noqa: F401
+from . import jamba  # noqa: F401
+from .jamba import Jamba, JambaConfig, JAMBA2_3B, JAMBA_TINY  # noqa: F401

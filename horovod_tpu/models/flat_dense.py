@@ -35,6 +35,7 @@ class FlatDenseGeneral(nn.Module):
     features: Union[int, Sequence[int]]
     axis: Union[int, Sequence[int]] = -1
     dtype: Any = None
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -52,7 +53,8 @@ class FlatDenseGeneral(nn.Module):
             "kernel", lambda rng, shape: jnp.reshape(
                 nn.linear.default_kernel_init(rng, flat), shape),
             in_shape + features)
-        bias = self.param("bias", nn.initializers.zeros_init(), features)
+        bias = (self.param("bias", nn.initializers.zeros_init(), features)
+                if self.use_bias else None)
         x, kernel, bias = promote_dtype(x, kernel, bias, dtype=self.dtype)
-        return (jnp.dot(x.reshape(*lead, flat[0]), kernel.reshape(flat))
-                + bias.reshape(flat[1]))
+        y = jnp.dot(x.reshape(*lead, flat[0]), kernel.reshape(flat))
+        return y if bias is None else y + bias.reshape(flat[1])

@@ -49,7 +49,7 @@ def column_parallel_dense(x, kernel_local, bias_local=None,
 
 
 def row_parallel_dense(x_local, kernel_local, bias=None,
-                       axis_name: str = "tp"):
+                       axis_name: Optional[str] = "tp", dtype=None):
     """y = psum_tp(x_local @ W[shard, :]) (+ b).
 
     Args:
@@ -57,10 +57,17 @@ def row_parallel_dense(x_local, kernel_local, bias=None,
         output).
       kernel_local: [d_in / tp, d_out] — this shard's row slice.
       bias: [d_out], logically replicated; added once AFTER the psum.
+      axis_name: None leaves the sum out: the result is this shard's part
+        of it (one chip's share of a layer run alone).
+      dtype: the result's; None is ``x_local``'s.  The partial products and
+        their sum over the axis are float32 either way, so a sum that feeds
+        float32 arithmetic (a norm) asks for float32 and is never rounded.
     """
     partial = jnp.einsum("...i,ij->...j", x_local, kernel_local,
                          preferred_element_type=jnp.float32)
-    y = lax.psum(partial, axis_name).astype(x_local.dtype)
+    if axis_name is not None:
+        partial = lax.psum(partial, axis_name)
+    y = partial.astype(dtype or x_local.dtype)
     if bias is not None:
         y = y + bias
     return y

@@ -352,6 +352,44 @@ def test_grouped_products_compile_at_an_expert_wider_than_the_budget(
     assert len(re.findall(r"hvd_moe_tgmm[\w.]* = ", text)) == 1
 
 
+def test_selective_scan_compiles_at_the_jamba_cell_s_shapes_in_shard_map(
+        topo):
+    """``ops/selective_scan.py`` at ``jamba2-ssm-tp4-s16384``'s shapes (one
+    sequence of 16,384 steps, 1,280 channels, 16 states, ``u`` in bfloat16 and
+    ``dt``, ``B``, ``C`` in float32), forward and backward, inside a
+    ``shard_map`` over one described chip with ``A`` and ``D`` held whole:
+    one call of each kernel by name, and ``shard_map``'s types accept what
+    the kernels declare (``vma`` on every output; no loop in a kernel
+    carries a value read from an operand's ref beside the kernel's own
+    arithmetic, which the interpreter cannot show)."""
+    from jax import shard_map
+
+    from horovod_tpu.ops.selective_scan import selective_scan
+
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("hvd",))
+    rows, whole = P("hvd"), P()
+    specs = (rows, rows, whole, rows, rows, whole)
+
+    def total(*operands):
+        y = selective_scan(*operands, interpret=False)
+        return jax.lax.psum(jnp.sum(y.astype(jnp.float32)), "hvd")
+
+    shapes = [((1, 16384, 1280), jnp.bfloat16), ((1, 16384, 1280),
+                                                 jnp.float32),
+              ((1280, 16), jnp.float32), ((1, 16384, 16), jnp.float32),
+              ((1, 16384, 16), jnp.float32), ((1280,), jnp.float32)]
+    text = jax.jit(shard_map(
+        jax.value_and_grad(total, argnums=tuple(range(6))), mesh=mesh,
+        in_specs=specs, out_specs=(whole, specs))).lower(*(
+            jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=NamedSharding(mesh, spec))
+            for (shape, dtype), spec in zip(shapes, specs))
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert len(re.findall(r"hvd_ssm_scan_fwd[\w.]* = ", text)) == 1
+    assert len(re.findall(r"hvd_ssm_scan_bwd[\w.]* = ", text)) == 1
+
+
 @pytest.mark.parametrize("codec", ["int8", "int4", "int8g"])
 def test_codec_encode_decode_compiles(one_chip, codec):
     def roundtrip(flat):

@@ -118,6 +118,49 @@ def _profile_eager_update(hvd, out_dir):
     return found[0]
 
 
+def test_a_state_space_step_names_its_projections_mix_scan_and_head():
+    """``models/jamba.py``'s scopes in the lowered step of
+    ``benchmark/families/jamba.py`` at the configuration's rehearsal sizes:
+    ``hvd_ssm_proj`` holds the mixer's two large products, ``hvd_ssm_mix``
+    the convolution, ``x_proj``, the norms, the softplus and the gate,
+    ``hvd_ssm_scan`` the scan (off the TPU its ``lax.scan`` form; on it the
+    kernels ``hvd_ssm_scan_fwd`` / ``_bwd``), ``hvd_lm_head`` the final norm
+    and the tied head; both passes carry them."""
+    from benchmark.families import jamba
+    from horovod_tpu.models import jamba as model_jamba
+
+    cfg = run.load_json("configs", "jamba2-3b-tp4.json")
+    traffic = traffic_gen.resolve(
+        run.load_json("traffic", "jamba-causal-1x16384x1.json"),
+        rehearse=True)
+    mesh = common.hvd_mesh(jax.devices()[:1])
+    cell = jamba.setup(cfg, mesh, seed=3, rehearse=True)
+    (ids,), = traffic_gen.make_batches(traffic, jamba.inputs(cell, traffic),
+                                       mesh, seed=3)
+    lowered = jax.jit(jax.grad(lambda v: model_jamba.lm_loss(
+        cell["model"], v, ids))).lower(cell["params"]).as_text(
+            debug_info=True)
+    names = _op_names(lowered)
+
+    def under(scope, op, backward=False):
+        return any(f"/{scope}/" in n and n.endswith(op)
+                   and ("transpose(" in n) == backward for n in names)
+
+    for backward in (False, True):
+        assert under("hvd_ssm_proj", "dot_general", backward)
+        assert under("hvd_ssm_mix", "dot_general", backward)   # x_proj, dt
+        assert under("hvd_ssm_mix", "jit(silu)", backward)
+        assert under("hvd_ssm_mix", "jit(softplus)", backward)
+        assert under("hvd_ssm_scan", "", backward)
+        assert under("hvd_lm_head", "", backward)
+    # The scan's arithmetic is under its own scope, not under the mix's.
+    scan = [n for n in names if "/hvd_ssm_scan/" in n]
+    assert scan and not any("/hvd_ssm_mix/" in n for n in scan)
+    # The attention block (index 2 of 4 here) has none of the four but the
+    # head's.
+    assert not any("layer_2" in n and "hvd_ssm" in n for n in names)
+
+
 def test_eager_update_writes_the_spine_s_spans(hvd_single, tmp_path):
     path = _profile_eager_update(hvd_single, tmp_path)
     trace = trace_reduce.read_xplane(path, steps=1)

@@ -51,7 +51,12 @@ The whole router runs in float32, its products at "highest" precision: the
 choice is discrete.  The dropless dispatch is ``parallel/moe.py:
 dispatch_experts``; attention is the Pallas flash kernels on a TPU
 (``use_flash``), the dense oracle elsewhere; the head and the loss run a block
-of tokens at a time (``losses.tied_head_cross_entropy``).
+of tokens at a time (``losses.tied_head_cross_entropy``).  With
+``checkpoint_blocks`` each block is under ``jax.checkpoint`` and keeps two
+arrays, the flash kernel's output and its row statistics
+(``ops/flash_attention.py:CHECKPOINT_NAMES``): the backward runs the rest of
+the block's forward again, projections, mixing, router and experts, and not
+the kernel.
 
 A chip may hold a share of the model, as ``models/sdar.py``:
 ``num_experts_held`` consecutive experts from ``first_expert`` on and
@@ -69,7 +74,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.flash_attention import dense_attention, flash_attention
+from ..ops.flash_attention import (
+    CHECKPOINT_NAMES, dense_attention, flash_attention)
 from ..parallel.moe import dispatch_experts, expert_load
 from .losses import tied_head_cross_entropy
 from .sdar import RMSNorm, _expert_init, rotary
@@ -94,7 +100,11 @@ class ZayaConfig:
     rms_norm_eps: float = 1e-5
     num_experts_held: Optional[int] = None   # None: every expert
     first_expert: int = 0
-    checkpoint_blocks: bool = False  # jax.checkpoint around each block
+    # jax.checkpoint around each block, which keeps its flash kernel's output
+    # and row statistics alone ([B, S, heads * head_dim] in ``dtype`` and a
+    # float32 a row and head: 34 MB a layer at one sequence of 16,384); no
+    # key chooses what is kept.
+    checkpoint_blocks: bool = False
     dtype: Any = jnp.bfloat16
     use_flash: bool = True           # Pallas kernels on TPU
 
@@ -342,7 +352,12 @@ class Zaya(nn.Module):
         self.embed = nn.Embed(
             cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
             embedding_init=nn.initializers.normal(stddev=EMBEDDING_STDDEV))
-        block = nn.remat(ZayaBlock) if cfg.checkpoint_blocks else ZayaBlock
+        block = ZayaBlock
+        if cfg.checkpoint_blocks:
+            # With ``use_flash`` off nothing carries the names: nothing kept.
+            kept = jax.checkpoint_policies.save_only_these_names(
+                *CHECKPOINT_NAMES)
+            block = nn.remat(ZayaBlock, policy=kept)
         self.layers = [block(cfg, first=i == 0, name=f"layer_{i}")
                        for i in range(cfg.num_layers)]
         self.res_final = ResidualScale(cfg.dtype)

@@ -108,6 +108,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1079,10 +1080,23 @@ def _flash_lse(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
                       valid_len, lens, variant)
 
 
+# The names the forward kernel's two results carry for ``jax.checkpoint``:
+# a policy ``save_only_these_names(*CHECKPOINT_NAMES)`` round a block keeps
+# them ([N, S, n_col * lanes] in the operands' dtype and one float32 a row
+# and head) and the block's backward runs no second forward kernel to get
+# them back.  Under any other checkpoint, and under none, a name is an
+# identity that lowers to nothing.
+CHECKPOINT_NAMES = ("hvd_flash_out", "hvd_flash_lse")
+
+
 def _flash_lse_fwd(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
                    valid_len, variant):
     out, lse = _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret,
                           valid_len, lens, variant)
+    # Named here and nowhere further out: the backward kernels read the
+    # residuals, ``lse`` among them, which never leaves some callers.
+    out = checkpoint_name(out, "hvd_flash_out")
+    lse = checkpoint_name(lse, "hvd_flash_lse")
     return (out, lse), (qb, kb, vb, lens, out, lse)
 
 

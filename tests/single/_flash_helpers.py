@@ -21,6 +21,26 @@ def small_tiles(monkeypatch):
     monkeypatch.setattr(fa, "_MAX_STEP", 8)
 
 
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it: a checkpoint's,
+    a ``custom_vjp``'s, a ``pjit``'s, a Pallas kernel's body, its loops and
+    branches."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(sub)
+
+
+def kernel_calls(jaxpr) -> int:
+    """The ``pallas_call`` equations among them."""
+    return sum(eqn.primitive.name == "pallas_call"
+               for eqn in equations(jaxpr))
+
+
 def _qkv(shape, dtype=jnp.float32, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     return tuple(jax.random.normal(k, shape, dtype) for k in ks)

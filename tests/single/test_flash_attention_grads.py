@@ -11,9 +11,10 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.ops.flash_attention import (
-    dense_attention, dense_attention_with_lse, flash_attention,
-    flash_attention_with_lse)
-from _flash_helpers import SCHEDULES, _qkv, small_tiles  # noqa: F401
+    CHECKPOINT_NAMES, dense_attention, dense_attention_with_lse,
+    flash_attention, flash_attention_with_lse)
+from _flash_helpers import (  # noqa: F401
+    SCHEDULES, _qkv, equations, kernel_calls, small_tiles)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -114,19 +115,53 @@ def test_flash_kernel_grads_bf16():
                                    rtol=0.1, atol=0.05)
 
 
-def _dot_operand_dtypes(jaxpr, found):
+MASKS = {"causal": {"causal": True}, "kv_lens": {"kv_lens": [19]},
+         "block_diffusion": {"block_diffusion": (16, 8)}}
+
+
+def _checkpointed(mask):
+    """``{kind: value and gradients of a loss over one call}`` traced under
+    ``jax.jit``: the call bare, inside ``jax.checkpoint``, and inside one
+    whose policy asks for the names ``_flash_lse_fwd`` gives its output and
+    row statistics."""
+    def attend(q, k, v):
+        return flash_attention(q, k, v, block_q=16, block_k=16,
+                               interpret=True, **mask)
+
+    kept = jax.checkpoint_policies.save_only_these_names(*CHECKPOINT_NAMES)
+    q, k, v = operands = _qkv((1, 32, 2, 16), seed=17)
+    return {kind: jax.jit(jax.value_and_grad(
+        lambda q, k, v, fn=fn: jnp.sum(jnp.sin(fn(q, k, v))),
+        argnums=(0, 1, 2))).trace(q, k, v)
+        for kind, fn in {"none": attend, "plain": jax.checkpoint(attend),
+                         "policy": jax.checkpoint(attend, policy=kept)}.items()
+    }, operands
+
+
+@pytest.mark.parametrize("mask", MASKS.values(), ids=MASKS.keys())
+def test_a_checkpoint_keeps_the_forward_s_results_only_where_asked(mask):
+    """Forward, dq, dkv.  With no policy the names are identities and the
+    checkpoint runs the forward kernel again, as it did; a policy over the
+    names keeps the two and the second run is gone.  Counted, not run."""
+    traced, _ = _checkpointed(mask)
+    assert {kind: kernel_calls(t.jaxpr.jaxpr) for kind, t in traced.items()
+            } == {"none": 3, "plain": 4, "policy": 3}
+
+
+def test_a_call_inside_a_checkpoint_gives_the_bits_it_gave_outside():
+    traced, operands = _checkpointed(MASKS["causal"])
+    bare, inside = (jax.tree.leaves(traced[kind].lower().compile()(*operands))
+                    for kind in ("none", "plain"))
+    for a, b_ in zip(inside, bare):
+        assert float(jnp.linalg.norm(b_)) > 0
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+
+def _dot_operand_dtypes(jaxpr) -> list:
     """Every dot_general of a jaxpr and of the jaxprs inside it (the Pallas
     kernel's body, its loops and branches): the dtypes of its operands."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            found.append(tuple(v.aval.dtype for v in eqn.invars))
-        for param in eqn.params.values():
-            for sub in (param if isinstance(param, (list, tuple))
-                        else [param]):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    _dot_operand_dtypes(sub, found)
-    return found
+    return [tuple(v.aval.dtype for v in eqn.invars)
+            for eqn in equations(jaxpr) if eqn.primitive.name == "dot_general"]
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
@@ -142,6 +177,6 @@ def test_flash_dots_take_operands_as_they_arrive(dtype):
                        .astype(jnp.float32))
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
-    dots = _dot_operand_dtypes(jaxpr.jaxpr, [])
+    dots = _dot_operand_dtypes(jaxpr.jaxpr)
     assert len(dots) >= 2 + 3 + 4            # fwd, dq, dkv
     assert all(a == b == dtype for a, b in dots), dots

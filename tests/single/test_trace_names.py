@@ -236,7 +236,9 @@ def _callee_names(func) -> set:
 def _names_written(tree) -> list:
     """``(line, name or None)`` of every name a module writes into a trace:
     the first argument of a ``TraceAnnotation`` / ``named_scope`` call and a
-    ``pallas_call``'s ``name=``; None where it is not a string literal."""
+    ``pallas_call``'s ``name=``; None where it is not a string literal.  And
+    the second argument of a ``checkpoint_name`` call: no trace shows it, a
+    checkpoint's policy asks for it, and it goes by the same rule."""
     out = []
     for node in ast.walk(tree):
         if not isinstance(node, ast.Call):
@@ -245,6 +247,9 @@ def _names_written(tree) -> list:
         if callee & NAMING_CALLS:
             args = node.args[:1] or [k.value for k in node.keywords
                                      if k.arg == "name"]
+        elif "checkpoint_name" in callee:
+            args = node.args[1:2] or [k.value for k in node.keywords
+                                      if k.arg == "name"]
         elif "pallas_call" in callee:
             args = [k.value for k in node.keywords if k.arg == "name"]
         else:
@@ -278,6 +283,23 @@ def test_every_name_the_program_writes_into_a_trace_starts_with_hvd():
         "with TraceAnnotation(label): pass\n"
         "pl.pallas_call(k, name='flash_fwd')(x)\n"
         "pl.pallas_call(k)(x)\n"
-        "with (jax.named_scope if t else TraceAnnotation)('hvd_ok'): pass\n")
-    assert _names_written(broken) == [(1, "update"), (2, None),
-                                      (3, "flash_fwd"), (5, "hvd_ok")]
+        "with (jax.named_scope if t else TraceAnnotation)('hvd_ok'): pass\n"
+        "y = checkpoint_name(x, 'kept')\n")
+    assert sorted(_names_written(broken), key=lambda found: found[0]) == [
+        (1, "update"), (2, None), (3, "flash_fwd"), (5, "hvd_ok"),
+        (6, "kept")]
+
+
+def test_the_names_a_checkpoint_may_keep_are_the_ones_the_kernels_give():
+    """``ops/flash_attention.py:CHECKPOINT_NAMES`` is what a model's policy
+    asks for; the literals of the file's ``checkpoint_name`` calls are what
+    the forward rule gives.  A policy over a name nothing carries keeps
+    nothing, in silence."""
+    from horovod_tpu.ops import flash_attention
+
+    with open(flash_attention.__file__) as f:
+        tree = ast.parse(f.read())
+    given = [node.args[1].value for node in ast.walk(tree)
+             if isinstance(node, ast.Call)
+             and "checkpoint_name" in _callee_names(node.func)]
+    assert sorted(given) == sorted(flash_attention.CHECKPOINT_NAMES)

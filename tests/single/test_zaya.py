@@ -6,19 +6,24 @@ layer; compressed convolutional attention against a loop over the positions,
 and nothing in it sees to the right; the balancing bias evens the loads; the
 blocked tied head and loss against ``softmax_cross_entropy`` on whole logits;
 the dispatch alone against ``routed_experts``; the grouped products in
-interpret mode at a matrix wider than the kernels' VMEM budget."""
+interpret mode at a matrix wider than the kernels' VMEM budget; a
+checkpointed block keeps what its flash kernel made (the kernel calls of the
+gradient's jaxpr counted, the gradients bit for bit)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _flash_helpers import kernel_calls
 from benchmark import common
 from benchmark.references import zaya as reference
 from horovod_tpu import models
 from horovod_tpu.models import losses, zaya
+from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import grouped_matmul as gm
 from horovod_tpu.parallel import moe
 
@@ -579,3 +584,74 @@ def test_grouped_products_of_a_matrix_wider_than_the_vmem_budget(
         assert np.isfinite(got).all()
         np.testing.assert_allclose(got, want, rtol=2e-2,
                                    atol=2e-2 * np.abs(want).max())
+
+
+# A checkpointed block and its flash kernel.  ``policy``: the block as the
+# model builds it; ``plain``: ``nn.remat(ZayaBlock)`` keeping nothing (a
+# policy over no name is ``jax.checkpoint``'s own); ``none``: no checkpoint.
+CHECKPOINTS = {"policy": (True, fa.CHECKPOINT_NAMES), "plain": (True, ()),
+               "none": (False, fa.CHECKPOINT_NAMES)}
+
+
+@pytest.fixture(scope="module")
+def checkpointed(tiny):
+    """``(traced, params)``: the tiny model's loss and gradient traced under
+    ``jax.jit`` for each kind of ``CHECKPOINTS``, its attention the Pallas
+    kernels in the interpreter."""
+    _, variables, ids = tiny
+    interpreted = functools.partial(fa.flash_attention, interpret=True)
+
+    def loss_of(kind):
+        blocks, names = CHECKPOINTS[kind]
+        model = models.Zaya(dataclasses.replace(
+            CFG, use_flash=True, checkpoint_blocks=blocks))
+
+        def loss(params):
+            with pytest.MonkeyPatch.context() as patch:
+                patch.setattr(zaya, "flash_attention", interpreted)
+                patch.setattr(zaya, "CHECKPOINT_NAMES", names)
+                return zaya.lm_loss(model, {**variables, "params": params},
+                                    ids)
+        return loss
+
+    with jax.default_matmul_precision("highest"):
+        return {kind: jax.jit(jax.value_and_grad(loss_of(kind))).trace(
+            variables["params"]) for kind in CHECKPOINTS}, variables["params"]
+
+
+@pytest.mark.parametrize("kind,calls_a_layer", [("policy", 3), ("plain", 4),
+                                                ("none", 3)])
+def test_a_checkpointed_block_runs_its_flash_forward_once(
+        kind, calls_a_layer, checkpointed):
+    """Forward, dq and dkv a layer; a checkpoint that keeps nothing runs the
+    forward kernel a second time to get its output and row statistics back,
+    the model's does not.  Counted in the gradient's jaxpr: nothing runs."""
+    traced, _ = checkpointed
+    assert kernel_calls(
+        traced[kind].jaxpr.jaxpr) == calls_a_layer * CFG.num_layers
+
+
+@pytest.fixture(scope="module")
+def checkpointed_gradients(checkpointed):
+    traced, params = checkpointed
+    return {kind: traced[kind].lower().compile()(params)
+            for kind in CHECKPOINTS}
+
+
+@pytest.mark.parametrize("other", ["plain", "none"])
+def test_what_a_checkpoint_keeps_changes_no_bit_of_a_gradient(
+        other, checkpointed_gradients, gradients):
+    """The kept arrays are what the second run would make again from the same
+    operands: the loss and every gradient leaf are the same numbers."""
+    (loss, grads), (other_loss, other_grads) = (
+        checkpointed_gradients[kind] for kind in ("policy", other))
+    assert np.asarray(loss) == np.asarray(other_loss)
+    got, want = common.leaf_paths(grads), common.leaf_paths(other_grads)
+    assert set(got) == set(want) and len(got) == 61
+    for path in want:
+        assert float(np.linalg.norm(want[path])) > 0, path
+        np.testing.assert_array_equal(got[path], want[path], err_msg=path)
+    # And they are the model's gradients: the dense oracle's, to rounding.
+    dense, _ = gradients
+    for path in dense:
+        assert common.l2_rel_err(got[path], dense[path]) < 1e-4, path

@@ -14,7 +14,7 @@ With ``x`` [S, d], RMSNorm at eps 1e-6, everything causal::
 **Mamba mixer**, ``d_inner = mamba_expand x d`` channels, state N =
 ``mamba_d_state``, no bias on a projection, one on the convolution::
 
-    [u | z] = h W_in
+    [u | z] = h W_in                  W_in stored [d, 2 d_inner], u first
     u  = silu(causal depthwise conv over mamba_d_conv steps of u, + b)
     [dt | B | C] = u W_x                        mamba_dt_rank + N + N
     dt, B, C = RMSNorm each, a learnt scale each               (Jamba's own)
@@ -50,18 +50,33 @@ norms, and ``W_out``; attention's output projection; the feed-forward's
 sum and nothing stands in for the others'.  The kernels of row-parallel
 products are drawn at the whole layer's fan-in, so a share is a slice of the
 whole model's initial weights in distribution.
+
+**The paired kernels are stored flat** (``PairedDense``): ``in_proj`` is one
+float32 ``[d, 2 x held]`` kernel with columns ``[u | z]`` and ``gate_up``
+one with columns ``[gate | up]`` (as the published checkpoint stores
+``in_proj``: one linear of ``2 d_inner`` outputs), and so are their gradients
+and an optimizer's moments; each half is a product of its own.  A chip's
+share of either kernel is **its own channels' columns of both halves**: the
+whole layer's kernel viewed ``[d, 2, width]``, cut on the last axis,
+flattened again (not a run of neighbouring columns of the flat kernel).
+``q_proj`` ``[d, heads, head_dim]`` and ``kv_proj`` ``[d, 2, groups,
+head_dim]`` keep their head axes, which the plain reference reads the head
+counts off.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
+from ..ops.collectives import vary_like
 from ..ops.flash_attention import dense_attention, flash_attention
 from ..ops.selective_scan import selective_scan
 from ..parallel.tensor_parallel import (
@@ -197,6 +212,61 @@ class RowParallel(nn.Module):
             axis_name=self.axis_name, dtype=self.out_dtype or self.dtype)
 
 
+def _halves(x, kernel, dtype):
+    held = kernel.shape[1] // 2
+    x, kernel = x.astype(dtype), kernel.astype(dtype)
+    return jnp.dot(x, kernel[:, :held]), jnp.dot(x, kernel[:, held:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def paired_dot(x, kernel, dtype):
+    """``(x @ W[:, :held], x @ W[:, held:])`` in ``dtype`` of a kernel ``[d,
+    2 x held]``, whose gradient leaves its two products in the kernel's own
+    type (the accumulator's float32, never rounded to ``dtype``) and in the
+    kernel's row-major layout, the one an optimizer's update reads it in."""
+    return _halves(x, kernel, dtype)
+
+
+def _paired_fwd(x, kernel, dtype):
+    return _halves(x, kernel, dtype), (x, kernel)
+
+
+def _paired_bwd(dtype, residuals, cotangents):
+    x, kernel = residuals
+    held = kernel.shape[1] // 2
+    rows = x.astype(dtype).reshape(-1, x.shape[-1])
+    first, second = (c.reshape(len(rows), held) for c in cotangents)
+    rounded = kernel.astype(dtype)
+    dx = (jnp.dot(first, rounded[:, :held].T)
+          + jnp.dot(second, rounded[:, held:].T))
+    dw = jnp.concatenate([jax.lax.dot_general(
+        rows, c, (((0,), (0,)), ((), ())),
+        preferred_element_type=kernel.dtype) for c in (first, second)], axis=1)
+    # Left to itself a TPU's compiler lays a float32 product of this shape
+    # out column-major, and then relays the kernel and an optimizer's moments
+    # to match where they cross the step's boundary.
+    return (dx.reshape(x.shape).astype(x.dtype),
+            with_layout_constraint(dw, Layout(major_to_minor=(0, 1))))
+
+
+paired_dot.defvjp(_paired_fwd, _paired_bwd)
+
+
+class PairedDense(nn.Module):
+    """Two column-parallel products of one kernel stored flat ``[d, 2 x
+    held]`` (lecun-normal over ``d``: ``nn.DenseGeneral``'s draw at that
+    shape), columns ``[first | second]``: ``paired_dot`` in ``dtype``."""
+    held: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.linear.default_kernel_init,
+                            (x.shape[-1], 2 * self.held))
+        return paired_dot(vary_like(x, kernel), vary_like(kernel, x),
+                          self.dtype)
+
+
 class MambaMixer(nn.Module):
     config: JambaConfig
     axis_name: Optional[str] = None
@@ -207,9 +277,7 @@ class MambaMixer(nn.Module):
         held, n, rank = cfg.channels_held, cfg.mamba_d_state, \
             cfg.mamba_dt_rank
         with jax.named_scope("hvd_ssm_proj"):
-            uz = FlatDenseGeneral((2, held), dtype=cfg.dtype, use_bias=False,
-                                  name="in_proj")(h)
-            u, z = uz[..., :held], uz[..., held:]
+            u, z = PairedDense(held, cfg.dtype, name="in_proj")(h)
         taps = self.param("conv", _taps_init, (cfg.mamba_d_conv, held))
         conv_bias = self.param("conv_bias", nn.initializers.zeros, (held,)) \
             if cfg.mamba_conv_bias else 0.0
@@ -285,11 +353,10 @@ class JambaMLP(nn.Module):
     def __call__(self, h):
         cfg = self.config
         held = cfg.columns_held
-        gu = FlatDenseGeneral((2, held), dtype=cfg.dtype, use_bias=False,
-                              name="gate_up")(h)
+        gate, up = PairedDense(held, cfg.dtype, name="gate_up")(h)
         return RowParallel(cfg.hidden_size, cfg.intermediate_size,
                            self.axis_name, cfg.dtype, name="down")(
-                               jax.nn.silu(gu[..., :held]) * gu[..., held:])
+                               jax.nn.silu(gate) * up)
 
 
 class JambaBlock(nn.Module):

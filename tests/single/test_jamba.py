@@ -21,6 +21,7 @@ from benchmark import common
 from benchmark.references import jamba as reference
 from horovod_tpu import models
 from horovod_tpu.models import jamba
+from horovod_tpu.models.flat_dense import FlatDenseGeneral
 from horovod_tpu.ops.selective_scan import selective_scan
 
 CFG = models.JAMBA_TINY
@@ -135,10 +136,14 @@ def test_checkpointed_blocks_give_the_same_loss_and_gradients(tiny):
 
 # Where each leaf of a block is cut among the chips that share it: the axis
 # of the leaf that holds the channels, heads or columns; the others are
-# whole on every chip.
+# whole on every chip.  ``in_proj`` and ``gate_up`` are stored flat,
+# ``[hidden, 2 * width]`` with columns ``[u | z]`` / ``[gate | up]``: their
+# axis 2 is that of the ``[hidden, 2, width]`` view (``_paired(tree, 2)``),
+# so that a share holds its own channels' columns of both halves.
 CUT_AXIS = {"in_proj": 2, "conv": 1, "conv_bias": 0, "A_log": 0, "D": 0,
             "dt_proj": 1, "dt_bias": 0, "x_proj": 0, "out_proj": 0,
             "q_proj": 1, "o_proj": 0, "gate_up": 2, "down": 0}
+PAIRED = ("in_proj", "gate_up")
 WHOLE = ("dt_norm", "b_norm", "c_norm", "kv_proj", "input_norm",
          "pre_ff_norm")
 
@@ -152,6 +157,15 @@ def _cut_axis(path) -> int:
     return None
 
 
+def _paired(block_params, *middle):
+    """The paired kernels reshaped ``[hidden, *middle, -1]``, every other
+    leaf as it is: ``_paired(tree, 2)`` is the ``[hidden, 2, width]`` view,
+    ``_paired(tree)`` the flat kernels the model stores."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: leaf.reshape(leaf.shape[0], *middle, -1)
+        if any(k.key in PAIRED for k in path) else leaf, block_params)
+
+
 def _share(block_params, share: int):
     """Share ``share`` of ``SHARES`` of a whole block's parameters."""
     def cut(path, leaf):
@@ -162,7 +176,8 @@ def _share(block_params, share: int):
         return jax.lax.slice_in_dim(leaf, share * size, (share + 1) * size,
                                     axis=axis)
 
-    return jax.tree_util.tree_map_with_path(cut, block_params)
+    return _paired(jax.tree_util.tree_map_with_path(
+        cut, _paired(block_params, 2)))
 
 
 def _share_config():
@@ -191,12 +206,15 @@ def test_the_four_shares_under_shard_map_are_the_uncut_block(attention):
     block = jamba.JambaBlock(_share_config(), attention=attention,
                              axis_name="tp")
     mesh = Mesh(np.asarray(jax.devices()[:SHARES]), ("tp",))
+    # The mesh cuts the paired kernels through their [hidden, 2, width]
+    # view; a chip flattens its cut into what the model stores.
+    whole = _paired(whole, 2)
     specs = jax.tree_util.tree_map_with_path(
         lambda path, leaf: P() if _cut_axis(path) is None else P(
             *([None] * _cut_axis(path) + ["tp"])), whole)
 
     def run(params, x):
-        return block.apply({"params": params}, x)[None]
+        return block.apply({"params": _paired(params)}, x)[None]
 
     got = jax.jit(shard_map(run, mesh=mesh, in_specs=(specs, P()),
                             out_specs=P("tp")))(whole, x)
@@ -206,8 +224,8 @@ def test_the_four_shares_under_shard_map_are_the_uncut_block(attention):
     # The gradient of a leaf that is whole on every chip (the key/value
     # projection, the three norms' scales) is the sum of the chips' parts.
     def loss(params, x):
-        return jax.lax.pmean(jnp.sum(block.apply({"params": params}, x) ** 2),
-                             "tp")
+        return jax.lax.pmean(
+            jnp.sum(block.apply({"params": _paired(params)}, x) ** 2), "tp")
 
     grads = jax.jit(shard_map(jax.grad(loss), mesh=mesh,
                               in_specs=(specs, P()), out_specs=specs))(
@@ -234,6 +252,67 @@ def test_one_share_alone_is_the_reference_given_that_share(attention):
         want = jnp.stack([reference.block(mine, row, RCFG) for row in x])
         assert common.rel_err(got, want) < 2e-5, share
         assert common.rel_err(got, uncut) > 1e-2
+
+
+@pytest.mark.parametrize("name, layer, formula", [
+    ("in_proj", "mamba", lambda p, h: reference.mamba(p, h[0], RCFG)[None]),
+    ("gate_up", "mlp", lambda p, h: reference.mlp(p, h[0])[None])])
+def test_a_paired_kernel_is_the_3d_one_reshaped_from_the_same_key(
+        name, layer, formula):
+    """``in_proj`` and ``gate_up`` are stored ``[hidden, 2 * width]``: the
+    kernel a ``(2, width)`` declaration draws from the same key, reshaped
+    value for value, and the layer's output and gradients are those of the
+    formula on the ``[hidden, 2, width]`` kernel (the reference's, which
+    reads either shape)."""
+    whole, x = _whole_block(False)
+    params, h = whole[layer], x[:1]
+    stored = params[name]["kernel"]
+    width = stored.shape[1] // 2
+    assert stored.shape == (CFG.hidden_size, 2 * width)
+    old = FlatDenseGeneral((2, width), use_bias=False)
+    new = jamba.PairedDense(width, jnp.float32)
+    key = jax.random.key(11)
+    old_v, new_v = old.init(key, h), new.init(key, h)
+    assert old_v["params"]["kernel"].shape == (CFG.hidden_size, 2, width)
+    np.testing.assert_array_equal(
+        old_v["params"]["kernel"].reshape(stored.shape),
+        new_v["params"]["kernel"])
+    np.testing.assert_allclose(
+        old.apply(old_v, h), jnp.concatenate(new.apply(new_v, h), axis=-1),
+        rtol=1e-6, atol=1e-6)
+
+    module = (jamba.MambaMixer if layer == "mamba" else jamba.JambaMLP)(CFG)
+    viewed = _paired({layer: params}, 2)[layer]
+    assert viewed[name]["kernel"].shape == (CFG.hidden_size, 2, width)
+    got, got_grads = jax.value_and_grad(lambda p: jnp.sum(
+        module.apply({"params": p}, h) ** 2))(params)
+    want, want_grads = jax.value_and_grad(lambda p: jnp.sum(
+        formula(p, h) ** 2))(viewed)
+    assert common.rel_err(got, want) < 2e-5
+    want_grads = common.leaf_paths(_paired({layer: want_grads}))
+    for path, leaf in common.leaf_paths({layer: got_grads}).items():
+        assert leaf.shape == want_grads[path].shape, path
+        assert common.l2_rel_err(leaf, want_grads[path]) < 1e-4, path
+
+
+def test_a_paired_kernel_s_gradient_is_a_float32_product():
+    """In bfloat16 the paired product's weight gradient is the float32
+    accumulator of ``x^T dy`` itself, half by half, as the configuration's
+    "float32 gradients" says: equal to that product to float32's rounding,
+    where one rounded to bfloat16 on its way out differs by 2e-3."""
+    keys = jax.random.split(jax.random.key(5), 4)
+    x = jax.random.normal(keys[0], (2, 64, 32), jnp.bfloat16)
+    kernel = jax.random.normal(keys[1], (32, 2 * 48), jnp.float32)
+    cts = tuple(jax.random.normal(k, (2, 64, 48), jnp.bfloat16)
+                for k in keys[2:])
+    _, pull = jax.vjp(lambda k: jamba.paired_dot(x, k, jnp.bfloat16), kernel)
+    (got,) = pull(cts)
+    assert got.dtype == jnp.float32 and got.shape == kernel.shape
+    want = jnp.einsum("bsd,bsc->dc", x, jnp.concatenate(cts, axis=-1),
+                      preferred_element_type=jnp.float32)
+    assert common.l2_rel_err(got, want) < 1e-6
+    assert common.l2_rel_err(
+        want.astype(jnp.bfloat16).astype(jnp.float32), want) > 1e-3
 
 
 def test_the_model_with_an_axis_runs_its_blocks_and_refuses_the_split_head():

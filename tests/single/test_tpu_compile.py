@@ -12,6 +12,7 @@ test's own process, because one process at a time may load the TPU's library.
 
 import dataclasses
 import functools
+import math
 import os
 import re
 
@@ -388,6 +389,70 @@ def test_selective_scan_compiles_at_the_jamba_cell_s_shapes_in_shard_map(
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert len(re.findall(r"hvd_ssm_scan_fwd[\w.]* = ", text)) == 1
     assert len(re.findall(r"hvd_ssm_scan_bwd[\w.]* = ", text)) == 1
+
+
+def test_a_jamba_step_relays_none_of_its_paired_kernels(topo, monkeypatch):
+    """One Mamba block with its feed-forward at ``jamba2-ssm-tp4-s16384``'s
+    widths (2,560 wide; 1,280 channels and 2,048 columns held) on a short
+    sequence, a whole step: gradients, ``optax.adamw`` through
+    ``DistributedOptimizer``, donated state, ``shard_map`` over one described
+    chip.  ``in_proj`` and ``gate_up`` cross the step's boundary 2-D,
+    ``[2560, 2 * held]``, and the step holds no ``copy`` of a kernel's size:
+    declared ``(2, held)`` the parameter, both moments and the gradient were
+    ``[2560, 2, held]``, the middle 2 tiled ``T(2,128)``, and every step
+    relaid each of them on the way in and out again (PERF.md section 6, PR
+    49: 10 GB a step in the cell).  Their weight gradients are float32
+    products in the leaves' own layout: left to itself the compiler lays a
+    float32 one out ``{0,1}`` and relays kernel and moments to match."""
+    import optax
+    from jax import shard_map
+
+    import horovod_tpu as hvd
+    from horovod_tpu.models import jamba
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(
+        jamba.JAMBA2_3B, mamba_d_inner_held=1280, num_heads_held=5,
+        intermediate_size_held=2048)
+    block = jamba.JambaBlock(cfg, attention=False)
+    tx = hvd.DistributedOptimizer(optax.adamw(2e-7), axis_name="hvd")
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("hvd",))
+
+    def train_step(variables, opt_state, x):
+        loss, grads = jax.value_and_grad(lambda v: jnp.sum(
+            block.apply(v, x).astype(jnp.float32) ** 2))(variables)
+        updates, opt_state = tx.update(grads, opt_state, variables)
+        return (optax.apply_updates(variables, updates), opt_state,
+                hvd.allreduce(loss, axis_name="hvd"))
+
+    x = jax.ShapeDtypeStruct((1, 1024, cfg.hidden_size), jnp.bfloat16)
+    variables = jax.eval_shape(block.init, jax.random.key(0), x)
+    state = (variables, jax.eval_shape(tx.init, variables))
+    paired = {"in_proj": (2560, 2 * 1280), "gate_up": (2560, 2 * 2048)}
+    seen = [leaf.shape for path, leaf in
+            jax.tree_util.tree_leaves_with_path(state)
+            if any(getattr(k, "key", None) in paired for k in path)]
+    # The parameter, the optimizer's accumulator and AdamW's two moments.
+    assert sorted(seen) == sorted(list(paired.values()) * 4)
+    text = jax.jit(
+        shard_map(train_step, mesh=mesh, in_specs=(P(), P(), P("hvd")),
+                  out_specs=(P(), P(), P())),
+        donate_argnums=(0, 1)).lower(
+            *_shapes_on(NamedSharding(mesh, P()), state),
+            _shapes_on(NamedSharding(mesh, P("hvd")), x)).compile().as_text()
+    assert len(re.findall(r"hvd_ssm_scan_fwd[\w.]* = ", text)) == 1
+    sizes = {math.prod(shape) for shape in paired.values()}
+    copies = [
+        line.strip()[:160] for line in text.splitlines()
+        for m in [re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                           r"copy\(", line)]
+        if m and math.prod(map(int, m.group(1).split(","))) in sizes]
+    assert not copies, copies
+    # The weight gradients: two products a kernel, each float32 and row-major
+    # as the leaf it updates (``paired_dot``'s backward), none in bfloat16.
+    products = re.findall(
+        r" = (\w+)\[2560,(?:1280|2048)\](\{[\d,]+)\S* convolution\(", text)
+    assert sorted(products) == [("f32", "{1,0")] * 4, products
 
 
 @pytest.mark.parametrize("codec", ["int8", "int4", "int8g"])

@@ -146,7 +146,7 @@ def kernels(interpret: bool = False, seqs=(512, 777), heads: int = 4,
     flash forward, dq/dk/dv, the (out, lse) pair the ring hop differentiates
     through, grouped-query heads under the causal and the block-diffusion
     mask (``grouped``: query heads, key/value heads, head width, L, block
-    length), one ring hop under shard_map, and the three wire codecs
+    length), one ring hop under shard_map, and the two device codecs
     bit-exact against their jnp mirror."""
     import jax
     import jax.numpy as jnp
@@ -263,7 +263,7 @@ def kernels(interpret: bool = False, seqs=(512, 777), heads: int = 4,
         q = qz.quantize(flat, codec, interpret)
         return q, qz.dequantize(*q, codec_elems, codec, interpret)
 
-    for codec in ("int8", "int4", "int8g"):
+    for codec in ("int8", "int4"):
         got_q, got_x = jax.jit(functools.partial(
             roundtrip, codec=codec, interpret=interpret))(flat)
         with mock.patch.object(qz, "_dispatch", lambda _interpret: None):

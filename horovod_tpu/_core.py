@@ -290,10 +290,10 @@ class NativeCore(CoreBackend):
         controller = cfg.controller
         if controller in ("auto",):
             controller = "socket" if cfg.size > 1 else "local"
-        # Device-plane codec: 0=none, 1=int8, 2=int4, 3=int8g from config;
+        # Device-plane codec: 0=none, 1=int8, 2=int4 from config;
         # -1 pins the autotuner's qdev arm when no jax device plane can
         # exist here.
-        qdev = {"none": 0, "int8": 1, "int4": 2, "int8g": 3}.get(
+        qdev = {"none": 0, "int8": 1, "int4": 2}.get(
             getattr(cfg, "wire_compression_device", "none"), 0)
         # Device-ring schedule: 0=ring, 1=bidi, 2=torus ("auto" resolves
         # from the world size); -1 pins the autotuner's schedule arm when
@@ -345,7 +345,7 @@ class NativeCore(CoreBackend):
             1 if cfg.autotune else 0,
             (cfg.autotune_log or "").encode(),
             1 if cfg.hierarchical_allreduce else 0,
-            {"none": 0, "bf16": 1, "int8": 2, "int4": 3, "int8g": 4}.get(
+            {"none": 0, "bf16": 1, "int8": 2, "int4": 3}.get(
                 cfg.wire_compression, 0),
             qdev, qsched,
             1 if cfg.metrics_enabled else 0,

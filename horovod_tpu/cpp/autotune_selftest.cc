@@ -59,7 +59,7 @@ int main() {
         std::printf("FAIL: pinned x4 knob was explored\n");
         return 1;
       }
-      if (x5 >= 1.0 / 6.0) {
+      if (x5 >= 0.25) {
         std::printf("FAIL: pinned x5 knob was explored\n");
         return 1;
       }
@@ -179,11 +179,9 @@ int main() {
     }
   }
   {
-    // Device-codec arm: a 4-level categorical {none, int8, int4, int8g}
-    // where the interior int4 level (x5=2/3 — the deepest wire cut) is
-    // best: 30% over none, ahead of int8's 15% and int8g's 20% on this
-    // synthetic surface.  The optimizer must land on the interior codec
-    // level, which the old binary knob could not express.
+    // Device-codec arm: a 3-level categorical {none, int8, int4} where
+    // the int4 level (x5=1 — the deepest wire cut) is best: 30% over none,
+    // ahead of int8's 15% on this synthetic surface.
     BayesianOptimizer bo;
     bo.set_tune_x3(false);
     bo.set_tune_x4(false);
@@ -194,16 +192,14 @@ int main() {
     bo.AddSample(x0, x1, x2, x3, x4, x5, x6, Surface(x0, x1, &rng));
     for (int round = 0; round < 60; ++round) {
       bo.Suggest(&x0, &x1, &x2, &x3, &x4, &x5, &x6);
-      double mult = x5 < 1.0 / 6.0
-                        ? 1.0
-                        : (x5 < 0.5 ? 1.15 : (x5 < 5.0 / 6.0 ? 1.3 : 1.2));
+      double mult = x5 < 0.25 ? 1.0 : (x5 < 0.75 ? 1.15 : 1.3);
       bo.AddSample(x0, x1, x2, x3, x4, x5, x6, Surface(x0, x1, &rng) * mult);
     }
     double bx0, bx1, bx2, bx3, bx4, bx5, bx6, best;
     bo.Best(&bx0, &bx1, &bx2, &bx3, &bx4, &bx5, &bx6, &best);
     std::printf("qdev best=%.3e at (%.2f, %.2f, qdev=%.2f)\n", best, bx0,
                 bx1, bx5);
-    if (bx5 < 0.5 || bx5 >= 5.0 / 6.0) {
+    if (bx5 < 0.75) {
       std::printf("FAIL: qdev knob did not converge to the int4 level\n");
       return 1;
     }
@@ -216,7 +212,7 @@ int main() {
     // Device-schedule arm: a 3-level categorical {ring, bidi, torus} where
     // the middle bidi level (x6=0.5) is best — both ICI directions without
     // torus's second-axis latency on this synthetic surface.  Tuned
-    // jointly with an active 4-level codec knob to exercise the full
+    // jointly with an active 3-level codec knob to exercise the full
     // qdev x schedule grid.
     BayesianOptimizer bo;
     bo.set_tune_x3(false);
@@ -227,7 +223,7 @@ int main() {
     bo.AddSample(x0, x1, x2, x3, x4, x5, x6, Surface(x0, x1, &rng));
     for (int round = 0; round < 60; ++round) {
       bo.Suggest(&x0, &x1, &x2, &x3, &x4, &x5, &x6);
-      double cmult = x5 < 1.0 / 6.0 ? 1.0 : 1.2;
+      double cmult = x5 < 0.25 ? 1.0 : 1.2;
       double smult = x6 < 0.25 ? 1.0 : (x6 < 0.75 ? 1.3 : 1.1);
       bo.AddSample(x0, x1, x2, x3, x4, x5, x6,
                    Surface(x0, x1, &rng) * cmult * smult);
@@ -241,7 +237,7 @@ int main() {
                   "level\n");
       return 1;
     }
-    if (bx5 < 1.0 / 6.0) {
+    if (bx5 < 0.25) {
       std::printf("FAIL: codec knob did not engage alongside the "
                   "schedule\n");
       return 1;

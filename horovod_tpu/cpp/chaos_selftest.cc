@@ -30,6 +30,7 @@
 #include "fault_injection.h"
 #include "flight_recorder.h"
 #include "metrics.h"
+#include "selftest_port.h"
 #include "socket_controller.h"
 
 namespace hvdtpu {
@@ -49,12 +50,6 @@ void Fail(const char* scenario, int rank, const std::string& what) {
   std::fprintf(stderr, "FAIL [%s] rank %d: %s\n", scenario, rank,
                what.c_str());
   failures.fetch_add(1);
-}
-
-int FreePort() {
-  Listener probe;
-  if (!probe.Listen("127.0.0.1", 0)) return -1;
-  return probe.port();
 }
 
 struct RankOutcome {
@@ -142,7 +137,7 @@ std::vector<RankOutcome> RunScenario(const char* name, const std::string& spec,
     Fail(name, -1, "unexpected spec error: " + err);
     return out;
   }
-  int port = FreePort();
+  int port = ClaimFreePort();
   if (port < 0) {
     Fail(name, -1, "no free port");
     return out;

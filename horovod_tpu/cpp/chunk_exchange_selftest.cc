@@ -191,7 +191,7 @@ void CodecBf16RoundTrip() {
       static_cast<size_t>(WireEncodedBytes(WireCodec::kBf16, n)));
   std::vector<float> dec(static_cast<size_t>(n));
   WireEncode(WireCodec::kBf16, vals, n, enc.data());
-  WireDecodeRange(WireCodec::kBf16, enc.data(), n, 0, n, dec.data());
+  WireDecodeRange(WireCodec::kBf16, enc.data(), 0, n, dec.data());
   for (int64_t i = 0; i < n; ++i) {
     if (std::memcmp(&dec[i], &vals[i], 4) != 0) {
       Fail("bf16 round-trip not exact for representable value", -3);
@@ -203,7 +203,7 @@ void CodecBf16RoundTrip() {
   const float odd[] = {3.14159265f, 1.0001f, -123.456f, 7.7777e-5f};
   const int64_t m = sizeof(odd) / sizeof(odd[0]);
   WireEncode(WireCodec::kBf16, odd, m, enc.data());
-  WireDecodeRange(WireCodec::kBf16, enc.data(), m, 0, m, dec.data());
+  WireDecodeRange(WireCodec::kBf16, enc.data(), 0, m, dec.data());
   for (int64_t i = 0; i < m; ++i) {
     if (std::fabs(dec[i] - odd[i]) > std::fabs(odd[i]) * (1.0f / 128.0f)) {
       Fail("bf16 truncation error exceeds one ulp bound", -3);
@@ -227,7 +227,7 @@ void CodecInt8ErrorBound() {
       static_cast<size_t>(WireEncodedBytes(WireCodec::kInt8, n)));
   WireEncode(WireCodec::kInt8, src.data(), n, enc.data());
   std::vector<float> dec(static_cast<size_t>(n));
-  WireDecodeRange(WireCodec::kInt8, enc.data(), n, 0, n, dec.data());
+  WireDecodeRange(WireCodec::kInt8, enc.data(), 0, n, dec.data());
   for (int64_t b0 = 0; b0 < n; b0 += kWireBlock) {
     const int64_t bn = std::min(kWireBlock, n - b0);
     float maxabs = 0.f;
@@ -254,7 +254,7 @@ void CodecInt8ErrorBound() {
       return;
     }
     if (avail > decoded) {
-      WireDecodeRange(WireCodec::kInt8, enc.data(), n, decoded, avail,
+      WireDecodeRange(WireCodec::kInt8, enc.data(), decoded, avail,
                       inc.data() + decoded);
       decoded = avail;
     }
@@ -262,7 +262,7 @@ void CodecInt8ErrorBound() {
   const int64_t tail = WireDecodableElems(
       WireCodec::kInt8, WireEncodedBytes(WireCodec::kInt8, n), n);
   if (tail > decoded) {
-    WireDecodeRange(WireCodec::kInt8, enc.data(), n, decoded, tail,
+    WireDecodeRange(WireCodec::kInt8, enc.data(), decoded, tail,
                     inc.data() + decoded);
     decoded = tail;
   }
@@ -292,7 +292,7 @@ void CodecRingAccumulationBound() {
     std::vector<float> x(static_cast<size_t>(n));
     for (auto& v : x) v = mag(rng);
     WireEncode(WireCodec::kInt8, x.data(), n, enc.data());
-    WireDecodeRange(WireCodec::kInt8, enc.data(), n, 0, n, dec.data());
+    WireDecodeRange(WireCodec::kInt8, enc.data(), 0, n, dec.data());
     for (int64_t i = 0; i < n; ++i) {
       exact[i] += x[i];
       acc[i] += dec[i];  // fp32 accumulate of the decoded contribution
@@ -334,7 +334,7 @@ void CodecInt4ErrorBound() {
       static_cast<size_t>(WireEncodedBytes(WireCodec::kInt4, n)));
   WireEncode(WireCodec::kInt4, src.data(), n, enc.data());
   std::vector<float> dec(static_cast<size_t>(n));
-  WireDecodeRange(WireCodec::kInt4, enc.data(), n, 0, n, dec.data());
+  WireDecodeRange(WireCodec::kInt4, enc.data(), 0, n, dec.data());
   for (int64_t b0 = 0; b0 < n; b0 += kWireBlock) {
     const int64_t bn = std::min(kWireBlock, n - b0);
     float maxabs = 0.f;
@@ -360,7 +360,7 @@ void CodecInt4ErrorBound() {
       return;
     }
     if (avail > decoded) {
-      WireDecodeRange(WireCodec::kInt4, enc.data(), n, decoded, avail,
+      WireDecodeRange(WireCodec::kInt4, enc.data(), decoded, avail,
                       inc.data() + decoded);
       decoded = avail;
     }
@@ -368,7 +368,7 @@ void CodecInt4ErrorBound() {
   const int64_t tail = WireDecodableElems(
       WireCodec::kInt4, WireEncodedBytes(WireCodec::kInt4, n), n);
   if (tail > decoded) {
-    WireDecodeRange(WireCodec::kInt4, enc.data(), n, decoded, tail,
+    WireDecodeRange(WireCodec::kInt4, enc.data(), decoded, tail,
                     inc.data() + decoded);
     decoded = tail;
   }
@@ -378,85 +378,21 @@ void CodecInt4ErrorBound() {
   }
 }
 
-// int8g two-level scaling: |decode(encode(x)) - x| <= eff/2 per element
-// where eff = gscale * sub/kWireSubDenom is the per-block effective scale
-// actually stored on the wire; a short last group and an all-zero block
-// inside a finite group must round-trip; incremental decode must agree
-// with the full decode.
-void CodecInt8gErrorBound() {
-  std::mt19937 rng(0xD00D);
-  std::uniform_real_distribution<float> mag(-50.f, 50.f);
-  // One full group + a short group with a partial block; zero out one
-  // block inside the full group (sub-scale byte 0 path).
-  const int64_t n = kWireGroup + 5 * kWireBlock + 77;
-  std::vector<float> src(static_cast<size_t>(n));
-  for (auto& v : src) v = mag(rng);
-  for (int64_t i = 3 * kWireBlock; i < 4 * kWireBlock; ++i) src[i] = 0.0f;
-  // Spread magnitudes so sub-scales actually vary within a group.
-  for (int64_t i = 0; i < n; ++i) {
-    if ((i / kWireBlock) % 3 == 1) src[i] *= 0.01f;
-  }
-  std::vector<char> enc(
-      static_cast<size_t>(WireEncodedBytes(WireCodec::kInt8g, n)));
-  WireEncode(WireCodec::kInt8g, src.data(), n, enc.data());
-  std::vector<float> dec(static_cast<size_t>(n));
-  WireDecodeRange(WireCodec::kInt8g, enc.data(), n, 0, n, dec.data());
-  for (int64_t g0 = 0; g0 < n; g0 += kWireGroup) {
-    const int64_t gn = std::min(kWireGroup, n - g0);
-    float gmax = 0.f;
-    for (int64_t i = 0; i < gn; ++i) {
-      gmax = std::max(gmax, std::fabs(src[g0 + i]));
+// A response names its wire codec by id, and the id is input from outside:
+// one past the last codec must come back as an error on the op, not as a
+// WireCodec the ring would frame its bytes by.
+void ResponseCodecOutOfRange() {
+  for (int32_t id : {kWireCodecMax, kWireCodecMax + 1, 255}) {
+    Response sent;
+    sent.wire_comp = id;
+    Writer w;
+    SerializeResponse(sent, &w);
+    Reader r(w.data());
+    const Response got = DeserializeResponse(&r);
+    const bool refused = !got.error.empty() && got.wire_comp == 0;
+    if (refused != (id > kWireCodecMax)) {
+      Fail("wire codec id range check on a response", id);
     }
-    const float gscale = gmax / 127.0f;
-    for (int64_t b0 = 0; b0 < gn; b0 += kWireBlock) {
-      const int64_t bn = std::min(kWireBlock, gn - b0);
-      float bmax = 0.f;
-      for (int64_t i = 0; i < bn; ++i) {
-        bmax = std::max(bmax, std::fabs(src[g0 + b0 + i]));
-      }
-      const float s = std::min(
-          255.0f,
-          std::nearbyintf(bmax / gmax * static_cast<float>(kWireSubDenom)));
-      const float eff = gscale * (s / static_cast<float>(kWireSubDenom));
-      // Sub-scale rounding can sit eff slightly under bmax/127; allow the
-      // corresponding clipping slack (<= gscale/kWireSubDenom per unit
-      // code, codes bounded by 127).
-      const float slack =
-          127.0f * std::max(0.0f, bmax / 127.0f - eff) + 1e-12f;
-      for (int64_t i = 0; i < bn; ++i) {
-        if (std::fabs(dec[g0 + b0 + i] - src[g0 + b0 + i]) >
-            eff * 0.5f + slack) {
-          Fail("int8g two-level error exceeds eff/2", -4);
-          return;
-        }
-      }
-    }
-  }
-  int64_t decoded = 0;
-  std::vector<float> inc(static_cast<size_t>(n));
-  for (int64_t bytes = 0; bytes <= WireEncodedBytes(WireCodec::kInt8g, n);
-       bytes += 97) {
-    const int64_t avail = WireDecodableElems(WireCodec::kInt8g, bytes, n);
-    if (avail < decoded) {
-      Fail("int8g WireDecodableElems not monotone", -4);
-      return;
-    }
-    if (avail > decoded) {
-      WireDecodeRange(WireCodec::kInt8g, enc.data(), n, decoded, avail,
-                      inc.data() + decoded);
-      decoded = avail;
-    }
-  }
-  const int64_t tail = WireDecodableElems(
-      WireCodec::kInt8g, WireEncodedBytes(WireCodec::kInt8g, n), n);
-  if (tail > decoded) {
-    WireDecodeRange(WireCodec::kInt8g, enc.data(), n, decoded, tail,
-                    inc.data() + decoded);
-    decoded = tail;
-  }
-  if (decoded != n ||
-      std::memcmp(inc.data(), dec.data(), static_cast<size_t>(4 * n)) != 0) {
-    Fail("incremental int8g decode diverges from full decode", -4);
   }
 }
 
@@ -469,8 +405,8 @@ int main() {
   CodecBf16RoundTrip();
   CodecInt8ErrorBound();
   CodecInt4ErrorBound();
-  CodecInt8gErrorBound();
   CodecRingAccumulationBound();
+  ResponseCodecOutOfRange();
   if (failures.load() != 0) {
     std::fprintf(stderr, "%d failure(s)\n", failures.load());
     return 1;

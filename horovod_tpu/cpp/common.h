@@ -137,7 +137,7 @@ struct Response {
   // a split plane would deadlock the data plane.
   bool hier = false;
   // Coordinator-decided wire codec for the cross-host ring hops of this
-  // response (0=none, 1=bf16, 2=int8 — hvd::WireCodec).  Rides the
+  // response (0=none, 1=bf16, 2=int8, 3=int4 — hvdtpu::WireCodec).  Rides the
   // serialized response for the same reason as `hier`: a codec split
   // across ranks would be a framing mismatch on the data plane.  Demoted
   // to 0 for non-fp32 dtypes, device-plane ops, sub-floor payloads, and
@@ -171,11 +171,11 @@ struct CoreConfig {
   // rides in each response), so per-rank divergence is harmless.
   bool hierarchical = false;
   // HOROVOD_WIRE_COMPRESSION: codec for cross-host ring hops (0=none,
-  // 1=bf16, 2=int8, 3=int4, 4=int8g — hvdtpu::WireCodec).
+  // 1=bf16, 2=int8, 3=int4 — hvdtpu::WireCodec).
   // Coordinator-authoritative like `hierarchical`.
   int wire_compression = 0;
   // HOROVOD_WIRE_COMPRESSION device= plane: codec for in-jit / eager-XLA
-  // device collectives (0=none, 1=int8, 2=int4, 3=int8g; -1 = no device
+  // device collectives (0=none, 1=int8, 2=int4; -1 = no device
   // plane, autotune arm pinned).  Enforced on the Python side; stored
   // here so the autotuner's qdev coordinate starts from the configured
   // value.

@@ -491,12 +491,14 @@ int hvd_init(int rank, int size, int local_rank, int local_size,
   cfg.autotune_log = autotune_log ? autotune_log : "";
   cfg.hierarchical = hierarchical != 0;
   cfg.wire_compression =
-      wire_compression >= 0 && wire_compression <= 4 ? wire_compression : 0;
-  // Device-plane codec (0=none, 1=int8, 2=int4, 3=int8g).  -1 means the
+      wire_compression >= 0 && wire_compression <= kWireCodecMax
+          ? wire_compression
+          : 0;
+  // Device-plane codec (0=none, 1=int8, 2=int4).  -1 means the
   // caller has no device plane at all (no jax mesh): the knob is then
   // pinned for the autotuner, not merely off.
   cfg.qdev_compression =
-      qdev_compression >= -1 && qdev_compression <= 3 ? qdev_compression : 0;
+      qdev_compression >= -1 && qdev_compression <= 2 ? qdev_compression : 0;
   // Device-ring schedule (0=ring, 1=bidi, 2=torus).  -1 pins the autotune
   // arm: no device plane, or a member count that only admits the
   // unidirectional ring.
@@ -1010,7 +1012,7 @@ void hvd_step_trace_note_plane(int plane) {
 }
 
 // The autotuner's current device-plane codec decision (0=none, 1=int8,
-// 2=int4, 3=int8g; -1 = not initialized).  The Python side polls it
+// 2=int4; -1 = not initialized).  The Python side polls it
 // between steps and re-traces with the quantized ring when it flips — the
 // device plane's analog of SetWireCompression on the host ring.
 int hvd_autotune_qdev() {

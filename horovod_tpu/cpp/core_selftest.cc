@@ -16,6 +16,7 @@
 #include <vector>
 
 #include "metrics.h"
+#include "selftest_port.h"
 #include "socket_controller.h"
 
 namespace hvdtpu {
@@ -84,14 +85,10 @@ void RankMain(int rank, int port) {
 
 int main() {
   // Pick a free port for the rendezvous.
-  int port;
-  {
-    Listener probe;
-    if (!probe.Listen("127.0.0.1", 0)) {
-      std::fprintf(stderr, "no free port\n");
-      return 2;
-    }
-    port = probe.port();
+  const int port = ClaimFreePort();
+  if (port < 0) {
+    std::fprintf(stderr, "no free port\n");
+    return 2;
   }
   // Metrics stay ON for the whole run: the rank threads increment the
   // global registry (ring hops from ChunkedStep, shm fence waits from

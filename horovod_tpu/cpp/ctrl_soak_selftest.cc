@@ -56,6 +56,7 @@
 #include "fleet_telemetry.h"
 #include "fault_injection.h"
 #include "metrics.h"
+#include "selftest_port.h"
 #include "socket_controller.h"
 
 namespace hvdtpu {
@@ -72,12 +73,6 @@ int failures = 0;
 void Fail(const char* phase, int rank, const std::string& what) {
   std::fprintf(stderr, "FAIL [%s] rank %d: %s\n", phase, rank, what.c_str());
   ++failures;
-}
-
-int FreePort() {
-  Listener probe;
-  if (!probe.Listen("127.0.0.1", 0)) return -1;
-  return probe.port();
 }
 
 // When set, every rank notes one replication refresh per negotiation cycle
@@ -259,7 +254,7 @@ int64_t RunPhase(const char* name, const char* tree_mode, int size,
                  int cycles, int* fleet_sources = nullptr,
                  int64_t* fleet_sum_count = nullptr) {
   ::setenv("HOROVOD_CONTROL_TREE", tree_mode, 1);
-  const int port = FreePort();
+  const int port = ClaimFreePort();
   if (port < 0) {
     Fail(name, -1, "no free port");
     return -1;
@@ -363,7 +358,7 @@ void EvictRank(int rank, int size, int port, int pre, int post,
 
 void RunEvictPhase(const char* name, int size, int hosts, int evict_host) {
   ::setenv("HOROVOD_CONTROL_TREE", "on", 1);
-  const int port = FreePort();
+  const int port = ClaimFreePort();
   if (port < 0) {
     Fail(name, -1, "no free port");
     return;
@@ -456,7 +451,7 @@ void RunChaosPhase(const char* name, int depth, const std::string& spec,
     Fail(name, -1, "spec error: " + perr);
     return;
   }
-  const int port = FreePort();
+  const int port = ClaimFreePort();
   if (port < 0) {
     Fail(name, -1, "no free port");
     return;

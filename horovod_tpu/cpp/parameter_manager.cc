@@ -51,30 +51,16 @@ double XToCycle(double x) {
 // {0, 0.5, 1}, so codec aggressiveness is ordinal in the kernel.
 constexpr double kCatScale = 0.5;
 
-// x4 <-> WireCodec: the GP works on {0, 0.5, 1}; the data plane wants
-// {0, 1, 2}.
-constexpr double kWireLevels[3] = {0.0, 0.5, 1.0};
-int X4ToWire(double x4) { return x4 < 0.25 ? 0 : (x4 < 0.75 ? 1 : 2); }
-double WireToX4(int wire) {
-  return kWireLevels[std::min(2, std::max(0, wire))];
-}
-
-// x5 <-> device codec: {0, 1/3, 2/3, 1} for {none, int8, int4, int8g} —
-// ordinal in codec aggressiveness so adjacent codecs share GP shape.
-constexpr double kQdevLevels[4] = {0.0, 1.0 / 3.0, 2.0 / 3.0, 1.0};
-int X5ToQdev(double x5) {
-  return x5 < 1.0 / 6.0 ? 0 : (x5 < 0.5 ? 1 : (x5 < 5.0 / 6.0 ? 2 : 3));
-}
-double QdevToX5(int qdev) {
-  return kQdevLevels[std::min(3, std::max(0, qdev))];
-}
-
-// x6 <-> device-ring schedule: {0, 0.5, 1} for {ring, bidi, torus} —
-// ordinal in parallelism (one ICI direction, both, both axes of a torus).
-constexpr double kSchedLevels[3] = {0.0, 0.5, 1.0};
-int X6ToSched(double x6) { return x6 < 0.25 ? 0 : (x6 < 0.75 ? 1 : 2); }
-double SchedToX6(int sched) {
-  return kSchedLevels[std::min(2, std::max(0, sched))];
+// The three-level coordinates: the GP works on {0, 0.5, 1}, the planes
+// want {0, 1, 2}.  x4 = host wire codec {none, bf16, int8}, x5 = device
+// codec {none, int8, int4}, both ordinal in codec aggressiveness so that
+// adjacent codecs share GP shape; x6 = device-ring schedule {ring, bidi,
+// torus}, ordinal in parallelism (one ICI direction, both, both axes of a
+// torus).
+constexpr double kLevels3[3] = {0.0, 0.5, 1.0};
+int XToLevel3(double x) { return x < 0.25 ? 0 : (x < 0.75 ? 1 : 2); }
+double Level3ToX(int level) {
+  return kLevels3[std::min(2, std::max(0, level))];
 }
 
 // x7 <-> data plane: {0, 1} for {eager explicit, gspmd compiler-inserted}
@@ -193,16 +179,16 @@ void BayesianOptimizer::Suggest(double* x0, double* x1, double* x2,
   // Seed phase: spread the first probes over the categories before
   // trusting the GP (the reference warms its GP with a fixed design too).
   // When x3/x4/x5/x6/x7 are pinned, their seed columns collapse to 0 so
-  // no probe is wasted on a dead arm.  The x5 column walks all four codec
+  // no probe is wasted on a dead arm.  The x5 column walks all three codec
   // levels, the x6 column all three schedules, and the x7 column
   // alternates the two planes.
   static const double kSeeds[][8] = {
       {0.15, 0.15, 0, 0, 0, 0, 0, 0},
       {0.85, 0.15, 1, 1, 1, 1, 1, 1},
-      {0.5, 0.5, 0, 1, 0.5, 1.0 / 3.0, 0.5, 1},
-      {0.5, 0.5, 1, 0, 1, 2.0 / 3.0, 1, 0},
+      {0.5, 0.5, 0, 1, 0.5, 0.5, 0.5, 1},
+      {0.5, 0.5, 1, 0, 1, 1, 1, 0},
       {0.15, 0.85, 0, 1, 0.5, 1, 0.5, 1},
-      {0.85, 0.85, 1, 0, 0, 2.0 / 3.0, 0, 0}};
+      {0.85, 0.85, 1, 0, 0, 0.5, 0, 0}};
   const int n = num_samples();
   if (n < 6) {
     *x0 = kSeeds[n][0];
@@ -221,7 +207,7 @@ void BayesianOptimizer::Suggest(double* x0, double* x1, double* x2,
          bu = 0.0, bt = 0.0, bs = 0.0;
   const int cat3_max = tune_x3_ ? 1 : 0;
   const int cat4_max = tune_x4_ ? 2 : 0;
-  const int cat5_max = tune_x5_ ? 3 : 0;
+  const int cat5_max = tune_x5_ ? 2 : 0;
   const int cat6_max = tune_x6_ ? 2 : 0;
   const int cat7_max = tune_x7_ ? 1 : 0;
   for (int cat7 = 0; cat7 <= cat7_max; ++cat7) {
@@ -243,8 +229,8 @@ void BayesianOptimizer::Suggest(double* x0, double* x1, double* x2,
                   double cy =
                       std::min(1.0, std::max(0.0, (j + 0.5 * jy) / kGrid));
                   double mean, var;
-                  Predict(cx, cy, cat, cat3, kWireLevels[cat4],
-                          kQdevLevels[cat5], kSchedLevels[cat6],
+                  Predict(cx, cy, cat, cat3, kLevels3[cat4],
+                          kLevels3[cat5], kLevels3[cat6],
                           kPlaneLevels[cat7], &mean, &var);
                   double sd = std::sqrt(var);
                   double z = (mean - best_y - 0.01) / sd;
@@ -255,9 +241,9 @@ void BayesianOptimizer::Suggest(double* x0, double* x1, double* x2,
                     by = cy;
                     bz = cat;
                     bw = cat3;
-                    bv = kWireLevels[cat4];
-                    bu = kQdevLevels[cat5];
-                    bt = kSchedLevels[cat6];
+                    bv = kLevels3[cat4];
+                    bu = kLevels3[cat5];
+                    bt = kLevels3[cat6];
                     bs = kPlaneLevels[cat7];
                   }
                 }
@@ -324,7 +310,7 @@ void ParameterManager::Initialize(int64_t fusion_threshold,
   bo_.set_tune_x4(wire_tunable);
   qdev_tunable_ = qdev_tunable;
   qdev_use_ = best_qdev_ =
-      qdev_tunable ? std::min(3, std::max(0, qdev_comp)) : 0;
+      qdev_tunable ? std::min(2, std::max(0, qdev_comp)) : 0;
   bo_.set_tune_x5(qdev_tunable);
   sched_tunable_ = sched_tunable;
   qdev_sched_use_ = best_qdev_sched_ =
@@ -372,8 +358,8 @@ void ParameterManager::Score(double score) {
   }
   bo_.AddSample(FusionToX(fusion_), CycleToX(cycle_ms_),
                 cache_use_ ? 1.0 : 0.0, hier_use_ ? 1.0 : 0.0,
-                WireToX4(wire_use_), QdevToX5(qdev_use_),
-                SchedToX6(qdev_sched_use_), PlaneToX7(plane_use_), score);
+                Level3ToX(wire_use_), Level3ToX(qdev_use_),
+                Level3ToX(qdev_sched_use_), PlaneToX7(plane_use_), score);
   if (score > best_score_ * 1.02) {
     windows_since_best_ = 0;
   } else {
@@ -421,9 +407,9 @@ void ParameterManager::Score(double score) {
   cycle_ms_ = XToCycle(x1);
   cache_use_ = x2 >= 0.5;
   hier_use_ = hier_tunable_ && x3 >= 0.5;
-  wire_use_ = wire_tunable_ ? X4ToWire(x4) : 0;
-  qdev_use_ = qdev_tunable_ ? X5ToQdev(x5) : 0;
-  qdev_sched_use_ = sched_tunable_ ? X6ToSched(x6) : 0;
+  wire_use_ = wire_tunable_ ? XToLevel3(x4) : 0;
+  qdev_use_ = qdev_tunable_ ? XToLevel3(x5) : 0;
+  qdev_sched_use_ = sched_tunable_ ? XToLevel3(x6) : 0;
   plane_use_ = plane_tunable_ ? X7ToPlane(x7) : 0;
 }
 

@@ -24,15 +24,15 @@ namespace hvdtpu {
 // allreduce — categorical coordinates in the same GP are the cheap
 // TPU-native form; x2 = announce-cache {0,1}, x3 = hierarchical allreduce
 // {0,1}, x4 = wire compression {0, 0.5, 1} for {none, bf16, int8},
-// x5 = device-plane codec {0, 1/3, 2/3, 1} for {none, int8, int4, int8g}
+// x5 = device-plane codec {0, 0.5, 1} for {none, int8, int4}
 // (ordinal in codec aggressiveness like x4), x6 = device-ring schedule
 // {0, 0.5, 1} for {ring, bidi, torus}, x7 = data plane {0, 1} for
 // {eager explicit collectives, gspmd compiler-inserted}).
 // Exposed for the synthetic-surface self-test (autotune_selftest.cc).
 class BayesianOptimizer {
  public:
-  // Observations are (x in [0,1]^2, x2/x3/x7 in {0,1}, x4/x6 in {0,0.5,1},
-  // x5 in {0,1/3,2/3,1}, score); scores are internally max-normalized so
+  // Observations are (x in [0,1]^2, x2/x3/x7 in {0,1}, x4/x5/x6 in
+  // {0,0.5,1}, score); scores are internally max-normalized so
   // the kernel scales stay dimensionless.
   void AddSample(double x0, double x1, double x2, double x3, double x4,
                  double x5, double x6, double x7, double score);
@@ -108,7 +108,7 @@ class ParameterManager {
   // the GP never explores that arm.  wire_comp / wire_tunable: same pair
   // for the wire-compression codec (0=none, 1=bf16, 2=int8), pinned when
   // no all-cross-host ring exists.  qdev_comp / qdev_tunable: same pair
-  // for the device-plane codec (0=none, 1=int8, 2=int4, 3=int8g), pinned
+  // for the device-plane codec (0=none, 1=int8, 2=int4), pinned
   // when the process has no usable jax device plane.  qdev_sched /
   // sched_tunable: same pair for the device-ring schedule (0=ring,
   // 1=bidi, 2=torus), pinned alongside qdev or when the plane's member
@@ -149,8 +149,8 @@ class ParameterManager {
   // (0=none, 1=bf16, 2=int8 — hvdtpu::WireCodec).  Coordinator-only for
   // the same reason as hierarchical().
   int wire_compression() const { return wire_use_; }
-  // Categorical knob: device-plane codec (0=none, 1=int8, 2=int4,
-  // 3=int8g — ops/quantize.py's DEVICE_WIRE_CODECS order).  The Python
+  // Categorical knob: device-plane codec (0=none, 1=int8, 2=int4 —
+  // ops/quantize.py's DEVICE_WIRE_CODECS order).  The Python
   // side polls it and flips the in-jit/eager quantized ring on the next
   // trace; per-rank consistent because config (and therefore the tunable
   // bit) is rank-uniform.

@@ -3390,7 +3390,7 @@ Status SocketController::CompressedRingAllreduce(
       // is byte-, not block-aligned; carry partial blocks forward).
       const int64_t avail = WireDecodableElems(codec, off + nb, relems);
       if (avail > decoded) {
-        WireDecodeRange(codec, enc_recv.data(), relems, decoded, avail,
+        WireDecodeRange(codec, enc_recv.data(), decoded, avail,
                         stage.data());
         ReduceInto(seg + decoded, stage.data(), avail - decoded,
                    DataType::FLOAT32, op);
@@ -3412,7 +3412,7 @@ Status SocketController::CompressedRingAllreduce(
   // (one quantization total in this phase, regardless of ring length).
   const int own_c = (idx + 1) % m;
   WireEncode(codec, base + start(own_c), len(own_c), enc_send.data());
-  WireDecodeRange(codec, enc_send.data(), len(own_c), 0, len(own_c), stage.data());
+  WireDecodeRange(codec, enc_send.data(), 0, len(own_c), stage.data());
   std::memcpy(base + start(own_c), stage.data(),
               static_cast<size_t>(4 * len(own_c)));
   for (int s = 0; s < m - 1; ++s) {
@@ -3424,7 +3424,7 @@ Status SocketController::CompressedRingAllreduce(
     auto consume = [&](int64_t off, const char* /*data*/, int64_t nb) {
       const int64_t avail = WireDecodableElems(codec, off + nb, relems);
       if (avail > decoded) {
-        WireDecodeRange(codec, enc_recv.data(), relems, decoded, avail,
+        WireDecodeRange(codec, enc_recv.data(), decoded, avail,
                         seg + decoded);
         decoded = avail;
       }

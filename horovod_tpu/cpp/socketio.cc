@@ -15,6 +15,7 @@
 #include <thread>
 
 #include "logging.h"
+#include "wire_codec.h"
 
 namespace hvdtpu {
 
@@ -91,6 +92,13 @@ Response DeserializeResponse(Reader* r) {
   resp.cache_hit = r->GetU8() != 0;
   resp.hier = r->GetU8() != 0;
   resp.wire_comp = r->GetU8();
+  if (resp.wire_comp > kWireCodecMax && resp.error.empty()) {
+    // A coordinator of another build named a codec this one does not have:
+    // the op fails here rather than frame the ring's bytes by guesswork.
+    resp.error = "malformed response: wire codec id " +
+                 std::to_string(resp.wire_comp) + " out of range";
+    resp.wire_comp = 0;
+  }
   resp.seq = r->GetI64();
   resp.last_joined = r->GetI32();
   resp.target_rank = r->GetI32();

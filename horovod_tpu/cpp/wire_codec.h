@@ -1,6 +1,6 @@
 // Wire-compression codecs for the cross-host chunk ring (docs/compression.md).
 //
-// Four codecs, all fp32-in / fp32-out with full-precision accumulation on
+// Three codecs, all fp32-in / fp32-out with full-precision accumulation on
 // the receive side (the ring never adds quantized values together):
 //
 //  - bf16: truncate each fp32 to its high 16 bits.  The exponent field is
@@ -13,19 +13,6 @@
 //  - int4: the same 256-element block scale with 4-bit codes, two per byte
 //    (element 2i in the low nibble, 2i+1 in the high nibble).  scale =
 //    max|x|/7; per-element error bounded by scale/2.  ~0.13x the raw bytes.
-//  - int8g: two-level scales (EQuARX's dynamic block scaling).  One fp32
-//    scale per 4096-element GROUP (kWireGroup) plus one uint8 sub-scale
-//    per 256-element block: group scale = max|group|/127, sub-scale byte
-//    s = min(255, nearbyint(max|block|/max|group| * kWireSubDenom)),
-//    effective block scale = group_scale * s/kWireSubDenom.  The sub-scale
-//    denominator is a power of two (256) on purpose: scaling by 2^-8
-//    commutes exactly with fp32 rounding, so the effective scale is
-//    bit-identical no matter how encoder/decoder (or a compiled traced
-//    mirror) associate the multiply — required for cross-rank bit-identity
-//    when encoded bytes are forwarded verbatim.  Group layout on the wire
-//    is [4-byte fp32 group scale][one sub-scale byte per block][one int8
-//    code per element].  Fine-grained per-block scaling at ~1/4 of int8's
-//    scale overhead.
 //
 // The encoded stream is position-independent per element: byte offsets are
 // pure functions of the element index, so a receiver can decode any prefix
@@ -34,8 +21,8 @@
 // bit-identity.
 //
 // Shared edge semantics for every block-scaled codec: the max|x| scan uses
-// `a > maxabs`, so NaN elements never win (an all-NaN block/group keeps
-// scale 0 and encodes zeros); a block/group whose max is inf stores a
+// `a > maxabs`, so NaN elements never win (an all-NaN block keeps
+// scale 0 and encodes zeros); a block whose max is inf stores a
 // non-finite scale with zero codes (decode yields NaN via inf*0 rather
 // than inventing values); a NaN element inside an otherwise-finite block
 // clamps to the positive code bound (std::min/std::max operand order).
@@ -57,20 +44,19 @@ enum class WireCodec : int32_t {
   kBf16 = 1,
   kInt8 = 2,
   kInt4 = 3,
-  kInt8g = 4,
 };
 
-// Block geometry: one scale record per 256 elements (int8/int4), one fp32
-// group scale per 4096 elements with uint8 sub-scales (int8g), 4-bit codes
+// The last id a peer may name (socketio.cc refuses a response beyond it).
+constexpr int32_t kWireCodecMax = static_cast<int32_t>(WireCodec::kInt4);
+
+// Block geometry: one scale record per 256 elements (int8/int4), 4-bit codes
 // clamp to +/-kWireInt4Max.  Mirrored as traced math by
-// horovod_tpu/ops/quantize.py (WIRE_BLOCK / WIRE_SCALE_BYTES / WIRE_GROUP /
+// horovod_tpu/ops/quantize.py (WIRE_BLOCK / WIRE_SCALE_BYTES /
 // WIRE_INT4_MAX / WIRE_CODEC_IDS) for the device-plane quantized ring;
 // tools/hvd_lint.py enforces the two stay in sync.
 constexpr int64_t kWireBlock = 256;
 constexpr int64_t kWireScaleBytes = 4;
-constexpr int64_t kWireGroup = 4096;
 constexpr int64_t kWireInt4Max = 7;
-constexpr int64_t kWireSubDenom = 256;
 
 // Encoded size in bytes of `count` fp32 elements under `codec`.
 inline int64_t WireEncodedBytes(WireCodec codec, int64_t count) {
@@ -84,11 +70,6 @@ inline int64_t WireEncodedBytes(WireCodec codec, int64_t count) {
     case WireCodec::kInt4: {
       const int64_t blocks = (count + kWireBlock - 1) / kWireBlock;
       return blocks * kWireScaleBytes + (count + 1) / 2;
-    }
-    case WireCodec::kInt8g: {
-      const int64_t groups = (count + kWireGroup - 1) / kWireGroup;
-      const int64_t blocks = (count + kWireBlock - 1) / kWireBlock;
-      return groups * kWireScaleBytes + blocks + count;
     }
     case WireCodec::kNone:
     default:
@@ -171,59 +152,13 @@ inline void WireEncode(WireCodec codec, const float* src, int64_t count,
     }
     return;
   }
-  if (codec == WireCodec::kInt8g) {
-    for (int64_t g0 = 0; g0 < count; g0 += kWireGroup) {
-      const int64_t gn = std::min(kWireGroup, count - g0);
-      const int64_t nblk = (gn + kWireBlock - 1) / kWireBlock;
-      const float gmax = wire_internal::MaxAbs(src + g0, gn);
-      const float gscale = gmax / 127.0f;
-      std::memcpy(dst, &gscale, kWireScaleBytes);
-      uint8_t* sub = reinterpret_cast<uint8_t*>(dst + kWireScaleBytes);
-      int8_t* q = reinterpret_cast<int8_t*>(dst + kWireScaleBytes + nblk);
-      if (gscale > 0.0f && std::isfinite(gscale)) {
-        for (int64_t b = 0; b < nblk; ++b) {
-          const int64_t b0 = b * kWireBlock;
-          const int64_t n = std::min(kWireBlock, gn - b0);
-          const float bmax = wire_internal::MaxAbs(src + g0 + b0, n);
-          // bmax <= gmax, so the ratio is in [0, 1]; the block holding
-          // gmax rounds to kWireSubDenom and clamps to 255 (its max
-          // element still encodes as code 127 after round).
-          const float ratio = bmax / gmax;
-          const uint8_t s = static_cast<uint8_t>(std::min(
-              255.0f,
-              std::nearbyintf(ratio * static_cast<float>(kWireSubDenom))));
-          sub[b] = s;
-          if (s > 0) {
-            const float eff =
-                gscale * (static_cast<float>(s) /
-                          static_cast<float>(kWireSubDenom));
-            const float inv = 1.0f / eff;
-            for (int64_t i = 0; i < n; ++i) {
-              const float v = std::nearbyintf(src[g0 + b0 + i] * inv);
-              q[b0 + i] = static_cast<int8_t>(
-                  std::max(-127.0f, std::min(127.0f, v)));
-            }
-          } else {
-            // All-zero / all-NaN block inside a finite group, or a block
-            // whose max rounds below the sub-scale resolution: codes 0.
-            std::memset(q + b0, 0, static_cast<size_t>(n));
-          }
-        }
-      } else {
-        std::memset(sub, 0, static_cast<size_t>(nblk));
-        std::memset(q, 0, static_cast<size_t>(gn));
-      }
-      dst += kWireScaleBytes + nblk + gn;
-    }
-    return;
-  }
   std::memcpy(dst, src, static_cast<size_t>(4 * count));
 }
 
-// Decode elements [elem_lo, elem_hi) of an encoded stream that carries
-// `count` elements total.  `src` points at the START of the encoded stream
-// (not at elem_lo); `dst` receives elem_hi - elem_lo fp32 values.
-inline void WireDecodeRange(WireCodec codec, const char* src, int64_t count,
+// Decode elements [elem_lo, elem_hi) of an encoded stream.  `src` points at
+// the START of the encoded stream (not at elem_lo); `dst` receives
+// elem_hi - elem_lo fp32 values.
+inline void WireDecodeRange(WireCodec codec, const char* src,
                             int64_t elem_lo, int64_t elem_hi, float* dst) {
   if (codec == WireCodec::kBf16) {
     const uint16_t* in = reinterpret_cast<const uint16_t*>(src) + elem_lo;
@@ -272,35 +207,6 @@ inline void WireDecodeRange(WireCodec codec, const char* src, int64_t count,
     }
     return;
   }
-  if (codec == WireCodec::kInt8g) {
-    for (int64_t e = elem_lo; e < elem_hi;) {
-      const int64_t grp = e / kWireGroup;
-      const int64_t g0 = grp * kWireGroup;
-      const int64_t gn = std::min(kWireGroup, count - g0);
-      const int64_t nblk = (gn + kWireBlock - 1) / kWireBlock;
-      const int64_t grp_end = std::min(g0 + gn, elem_hi);
-      // Only the LAST group of a stream may be short, so full-group
-      // offsets stay pure functions of the element index.
-      const char* base = src + grp * (kWireScaleBytes + kWireGroup / kWireBlock +
-                                      kWireGroup);
-      float gscale;
-      std::memcpy(&gscale, base, 4);
-      const uint8_t* sub =
-          reinterpret_cast<const uint8_t*>(base + kWireScaleBytes);
-      const int8_t* q =
-          reinterpret_cast<const int8_t*>(base + kWireScaleBytes + nblk);
-      for (int64_t i = e; i < grp_end; ++i) {
-        const int64_t ig = i - g0;
-        const float eff =
-            gscale * (static_cast<float>(sub[ig / kWireBlock]) /
-                      static_cast<float>(kWireSubDenom));
-        dst[i - elem_lo] = eff * static_cast<float>(q[ig]);
-      }
-      e = grp_end;
-    }
-    return;
-  }
-  (void)count;
   std::memcpy(dst, src + 4 * elem_lo,
               static_cast<size_t>(4 * (elem_hi - elem_lo)));
 }
@@ -330,27 +236,6 @@ inline int64_t WireDecodableElems(WireCodec codec, int64_t bytes_received,
       const int64_t rem = bytes_received % per_block;
       n = full * kWireBlock +
           std::max<int64_t>(0, (rem - kWireScaleBytes) * 2);
-      break;
-    }
-    case WireCodec::kInt8g: {
-      const int64_t per_group =
-          kWireScaleBytes + kWireGroup / kWireBlock + kWireGroup;
-      const int64_t full_groups = total_elems / kWireGroup;
-      if (bytes_received >= full_groups * per_group) {
-        // The prefix covers every complete group; the remainder lands in
-        // the short tail group, whose header carries only as many
-        // sub-scale bytes as it has blocks.
-        const int64_t tail = total_elems - full_groups * kWireGroup;
-        const int64_t nblk = (tail + kWireBlock - 1) / kWireBlock;
-        const int64_t rem = bytes_received - full_groups * per_group;
-        n = full_groups * kWireGroup +
-            std::max<int64_t>(0, rem - (kWireScaleBytes + nblk));
-      } else {
-        const int64_t header = kWireScaleBytes + kWireGroup / kWireBlock;
-        const int64_t full = bytes_received / per_group;
-        const int64_t rem = bytes_received % per_group;
-        n = full * kWireGroup + std::max<int64_t>(0, rem - header);
-      }
       break;
     }
     case WireCodec::kNone:

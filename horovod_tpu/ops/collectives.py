@@ -127,7 +127,7 @@ def allreduce(x, axis_name: AxisName, op: ReduceOp = ReduceOp.AVERAGE,
     if (op in (ReduceOp.SUM, ReduceOp.AVERAGE)
             and prescale_factor == 1.0 and postscale_factor == 1.0):
         # Device-plane codec auto-dispatch (HOROVOD_WIRE_COMPRESSION
-        # device=int8|int4|int8g): eligible fp32 payloads ride the
+        # device=int8|int4): eligible fp32 payloads ride the
         # block-scaled ring under the configured schedule; everything else
         # falls through bit-identically.  No recursion:
         # quantized_allreduce only calls back here when the same
@@ -531,8 +531,7 @@ def quantized_allreduce_eligible(x, world: int, min_bytes: int) -> bool:
 
 
 def _tree_permute(payload, axis_name: str, perm):
-    """ppermute every leaf of a (codes, scales) payload pytree — scales
-    may be a nested (sub, group) pair for the int8g codec."""
+    """ppermute both leaves of a (codes, scales) payload."""
     return jax.tree_util.tree_map(
         lambda a: lax.ppermute(a, axis_name, perm), payload)
 

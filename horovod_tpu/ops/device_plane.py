@@ -249,7 +249,7 @@ class DevicePlane:
 
     def _device_codec(self, rop: ReduceOp, dtype, length: int,
                       k: int) -> str:
-        """The configured block-scaled codec (``int8``/``int4``/``int8g``)
+        """The configured block-scaled codec (``int8``/``int4``)
         when this fused bucket should ride the quantized ring, else
         ``"none"``.  Demotion rules mirror the traced path (fp32 Sum/
         Average, payload >= HOROVOD_WIRE_COMPRESSION_MIN_BYTES, k > 1); the

@@ -3,35 +3,24 @@
 This is the in-``jit`` mirror of the host ring's block-scaled wire codecs
 (``cpp/wire_codec.h``): the same block geometry, the same scale rules, and
 the same all-zero / non-finite-block handling, so a tensor quantized on the
-device plane decodes to exactly the values the host codec would have
-produced.  EQuARX (PAPERS.md) is the design reference: block-scaled codes
-inside the XLA program keep the compression on-chip — no host transfers —
-while fp32 accumulation between hops preserves reduction accuracy.
+device plane decodes to the values the host codec would have produced (to
+the scale's last place inside a compiled program: see the divides below).
+EQuARX (PAPERS.md) is the design reference: block-scaled codes inside the
+XLA program keep the compression on-chip — no host transfers — while fp32
+accumulation between hops preserves reduction accuracy.
 
-Three device codecs:
+Two device codecs:
 
 - ``int8``: one fp32 scale per 256-element block, ``scale = max|x| / 127``.
 - ``int4``: the same block scale with 4-bit codes packed two per byte
   (``scale = max|x| / WIRE_INT4_MAX``); on the wire this is ~0.13x raw.
-- ``int8g``: EQuARX-style two-level scales — one fp32 scale per
-  4096-element group (``WIRE_GROUP``) plus one uint8 sub-scale per block:
-  ``group scale = max|group|/127``, ``sub = round(max|block|/max|group| *
-  WIRE_SUB_DENOM)`` clamped to 255, effective block scale ``= group_scale
-  * sub/WIRE_SUB_DENOM``.  The denominator is a power of two (256) so the
-  effective scale is bit-stable under any multiply association order —
-  every rank recomputing ``eff`` from the same wire bytes gets the same
-  bits regardless of how the compiler fuses the expression (a /127
-  denominator is 1-ulp sensitive to reassociation, which breaks cross-rank
-  bit-identity when encoded payloads are forwarded verbatim).  Per-block
-  granularity at ~1/4 of int8's scale overhead.
 
 Layout: a flat fp32 tensor is viewed as ``[nblocks, WIRE_BLOCK]`` (the last
 block zero-padded; zeros cannot raise ``max|x|``, so a short last block
 quantizes exactly as the byte-stream codec quantizes it).  Quantization
-yields a code array plus scales — for int8/int4 one fp32 per block, for
-int8g a ``(sub, group_scale)`` pair — together the traced analog of the
-wire stream's records, and what actually rides ``lax.ppermute`` between
-devices.
+yields a code array plus one fp32 scale per block — together the traced
+analog of the wire stream's records, and what actually rides
+``lax.ppermute`` between devices.
 
 The kernels are Pallas with the same dispatch rules as
 ``ops/flash_attention.py``: on TPU the Pallas kernel runs natively,
@@ -40,7 +29,13 @@ implementation, and ``interpret=True`` forces the kernels through the
 Pallas interpreter (tests).  Scale/inv divides are computed OUTSIDE the
 kernels (XLA's fp32 divide is correctly rounded, matching the C++ side;
 the Pallas interpreter's is not), and the int4 nibble pack/unpack is exact
-integer math in plain jnp.
+integer math in plain jnp.  Correctly rounded is what a divide dispatched by
+itself is.  Inside one compiled program XLA's algebraic simplifier, for the
+CPU and the TPU alike, states ``max|x| / 127`` and ``max|x| / 7`` as a
+multiply by the rounded reciprocal, so a compiled scale can differ from
+WireEncode's in its last place (21 / 7 reads 3.0000002).  Encode and decode
+share one scale array, so the ring agrees with itself; only an exact
+comparison with the C++ stream or a plain psum sees it (PERF.md section 7).
 
 Byte accounting: every quantized collective calls :func:`note_device_bytes`
 with the raw-vs-encoded wire byte counts so the realized compression ratio
@@ -63,16 +58,12 @@ from jax.experimental import pallas as pl
 # drift fails lint.)
 WIRE_BLOCK = 256           # kWireBlock: elements per scale record
 WIRE_SCALE_BYTES = 4       # kWireScaleBytes: little-endian fp32 scale
-WIRE_GROUP = 4096          # kWireGroup: elements per int8g group scale
 WIRE_INT4_MAX = 7          # kWireInt4Max: int4 code clamp bound
-WIRE_SUB_DENOM = 256       # kWireSubDenom: int8g sub-scale denominator (2^8)
-WIRE_CODEC_IDS = {"none": 0, "bf16": 1, "int8": 2, "int4": 3, "int8g": 4}
+WIRE_CODEC_IDS = {"none": 0, "bf16": 1, "int8": 2, "int4": 3}
 # Codecs the device plane can engage.  bf16 stays host-only: on-chip the
 # bf16 cast is a plain convert_element_type XLA already fuses — only the
 # block-scaled codecs need an implementation here.
-DEVICE_WIRE_CODECS = ("none", "int8", "int4", "int8g")
-
-_BLOCKS_PER_GROUP = WIRE_GROUP // WIRE_BLOCK   # int8g sub-scales per group
+DEVICE_WIRE_CODECS = ("none", "int8", "int4")
 
 # Rows per Pallas grid step: 32 sublanes satisfies the int8 (32, 128) and
 # fp32 (8, 128) minimum tile constraints simultaneously (WIRE_BLOCK = 256
@@ -91,9 +82,6 @@ def encoded_nbytes(count: int, codec: str = "int8") -> int:
         return 2 * count
     if codec == "int4":
         return blocks * WIRE_SCALE_BYTES + (count + 1) // 2
-    if codec == "int8g":
-        groups = -(-count // WIRE_GROUP)
-        return groups * WIRE_SCALE_BYTES + blocks + count
     return blocks * WIRE_SCALE_BYTES + count
 
 
@@ -210,8 +198,10 @@ def _block_scales(xb, qmax: float = 127.0):
     ``inv`` is 0 exactly for the all-zero / non-finite blocks (a finite
     positive scale can never reciprocate to 0 in fp32), so ``inv > 0`` is
     the block-ok predicate downstream.  Computed in plain jnp — XLA's
-    fp32 divide is correctly rounded, matching the C++ divides; the Pallas
-    interpreter's is not, which is why the divides live outside the kernel.
+    fp32 divide is correctly rounded, matching the C++ divides (dispatched
+    alone; compiled, ``/ qmax`` is a multiply by a rounded reciprocal: the
+    module's head); the Pallas interpreter's is not, which is why the
+    divides live outside the kernel.
     """
     absx = jnp.abs(xb)
     maxabs = jnp.max(jnp.where(jnp.isnan(absx), 0.0, absx),
@@ -220,70 +210,6 @@ def _block_scales(xb, qmax: float = 127.0):
     ok = (scale > 0.0) & jnp.isfinite(scale)
     inv = jnp.where(ok, 1.0 / jnp.where(ok, scale, 1.0), 0.0)
     return scale.astype(jnp.float32), inv.astype(jnp.float32)
-
-
-def _group_scales(xb):
-    """Two-level (int8g) scale derivation mirroring WireEncode(kInt8g):
-
-    - group max = max over the group's block maxes (fp32 max is exact, so
-      this equals the C++ single-pass group scan, NaN-excluded alike);
-    - ``gscale = gmax / 127``; a zero or non-finite group stores sub-scale
-      bytes 0 and codes 0 (non-finite keeps gscale inf, so decode flags
-      the group as NaN via inf * 0, exactly like the single-level codecs);
-    - per block ``sub = round(bmax/gmax * WIRE_SUB_DENOM)`` clamped to
-      [0, 255] (the block holding gmax rounds to 256 and clamps), effective
-      scale ``eff = gscale * (sub/WIRE_SUB_DENOM)``.  The power-of-two
-      denominator makes ``eff`` association-order-independent — multiplying
-      by 2^-8 commutes exactly with fp32 rounding — so the C++ decoder and
-      every XLA fusion of the traced decoder reproduce the encoder's eff
-      bit-for-bit.
-
-    Returns (sub [nb,1] uint8, gscale [ng,1] fp32, inv [nb,1] fp32) where
-    ``inv`` is 1/eff for ok blocks and 0 otherwise.
-    """
-    nb = xb.shape[0]
-    ng = -(-nb // _BLOCKS_PER_GROUP)
-    absx = jnp.abs(xb)
-    bmax = jnp.max(jnp.where(jnp.isnan(absx), 0.0, absx),
-                   axis=1, keepdims=True)
-    pad = ng * _BLOCKS_PER_GROUP - nb
-    bmax_p = jnp.pad(bmax, ((0, pad), (0, 0)))
-    gmax = jnp.max(bmax_p.reshape(ng, _BLOCKS_PER_GROUP), axis=1,
-                   keepdims=True)
-    gscale = (gmax / 127.0).astype(jnp.float32)
-    gok = (gscale > 0.0) & jnp.isfinite(gscale)
-
-    def rep(a):
-        return jnp.repeat(a, _BLOCKS_PER_GROUP, axis=0)[:nb]
-
-    gmax_b, gok_b, gscale_b = rep(gmax), rep(gok), rep(gscale)
-    ratio = bmax / jnp.where(gok_b, gmax_b, 1.0)
-    sub_f = jnp.where(gok_b,
-                      jnp.minimum(jnp.round(ratio * float(WIRE_SUB_DENOM)),
-                                  255.0),
-                      0.0)
-    eff = gscale_b * (sub_f / float(WIRE_SUB_DENOM))
-    ok = gok_b & (sub_f > 0.0)
-    inv = jnp.where(ok, 1.0 / jnp.where(ok, eff, 1.0), 0.0)
-    return (sub_f.astype(jnp.uint8), gscale.astype(jnp.float32),
-            inv.astype(jnp.float32))
-
-
-def _effective_scales(sub, gscale, nblocks: int):
-    """Per-block effective fp32 scale from int8g (sub, group) scales —
-    the decoder's ``gscale * (sub/WIRE_SUB_DENOM)``, bit-identical to the
-    encode-side ``eff``: sub is an exact small integer and the denominator
-    is a power of two, so whether the compiler evaluates
-    ``(gscale*sub)/256`` or ``gscale*(sub/256)`` the result carries the
-    same bits (scaling by 2^-8 commutes exactly with fp32 rounding).
-    Decode runs both on a rank's own fresh payload and on ppermute'd
-    copies of the same bytes; with a non-power-of-two denominator XLA's
-    per-fusion-context codegen produced 1-ulp drift between those two
-    sites, breaking the cross-rank bit-identity the verbatim-forwarding
-    gather relies on."""
-    gs_b = jnp.repeat(gscale.astype(jnp.float32), _BLOCKS_PER_GROUP,
-                      axis=0)[:nblocks]
-    return gs_b * (sub.astype(jnp.float32) / float(WIRE_SUB_DENOM))
 
 
 def _quantize_codes_ref(xb, inv, qmax: float = 127.0):
@@ -450,8 +376,6 @@ def quantize(flat, codec: str = "int8", interpret: Optional[bool] = None):
     - ``int8``: codes [nblocks, WIRE_BLOCK] int8, scales [nblocks, 1] fp32.
     - ``int4``: codes [nblocks, WIRE_BLOCK/2] int8 (packed nibbles),
       scales [nblocks, 1] fp32.
-    - ``int8g``: codes [nblocks, WIRE_BLOCK] int8, scales = (sub
-      [nblocks, 1] uint8, group [ngroups, 1] fp32).
 
     The short last block is zero-padded, which cannot change its max|x| —
     identical to the byte codec's short-block rule.  The (codes, scales)
@@ -468,13 +392,6 @@ def quantize(flat, codec: str = "int8", interpret: Optional[bool] = None):
             codes = _quantize_codes_pallas(xb, inv, mode,
                                            float(WIRE_INT4_MAX))
         return _pack_int4(codes), scale
-    if codec == "int8g":
-        sub, gscale, inv = _group_scales(xb)
-        if mode is None:
-            codes = _quantize_codes_ref(xb, inv)
-        else:
-            codes = _quantize_codes_pallas(xb, inv, mode, 127.0)
-        return codes, (sub, gscale)
     if mode is None:
         return _quantize_blocks_ref(xb)
     return _quantize_blocks_pallas(xb, mode)
@@ -485,9 +402,6 @@ def dequantize(qb, scales, count: int, codec: str = "int8",
     """Inverse of :func:`quantize`: back to flat fp32 [count]."""
     if codec == "int4":
         qb = _unpack_int4(qb)
-    elif codec == "int8g":
-        sub, gscale = scales
-        scales = _effective_scales(sub, gscale, qb.shape[0])
     xb = dequantize_blocks(qb, scales, interpret)
     return xb.reshape(-1)[:count]
 

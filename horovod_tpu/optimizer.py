@@ -227,7 +227,7 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
     incompatible with compression/backward_passes_per_step/predivide.
 
     ``device_compression`` selects the in-jit device-plane codec for the
-    traced gradient reduction: ``"int8"``/``"int4"``/``"int8g"`` routes
+    traced gradient reduction: ``"int8"``/``"int4"`` routes
     eligible leaves (fp32, at least HOROVOD_WIRE_COMPRESSION_MIN_BYTES of
     payload) through the block-scaled ring of that codec
     (``ops.collectives.quantized_allreduce``) with

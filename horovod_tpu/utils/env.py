@@ -93,12 +93,12 @@ def get_int(name: str, default: int) -> int:
         return default
 
 
-WIRE_COMPRESSION_CODECS = ("none", "bf16", "int8", "int4", "int8g")
+WIRE_COMPRESSION_CODECS = ("none", "bf16", "int8", "int4")
 # Codecs the in-jit device plane implements (ops/quantize.py): bf16 stays a
 # host-ring-only codec — on-chip a bf16 cast is a plain convert XLA already
-# fuses, so only the block-scaled codecs (int8, packed int4, two-level
-# int8g) earn a device implementation.
-DEVICE_WIRE_COMPRESSION_CODECS = ("none", "int8", "int4", "int8g")
+# fuses, so only the block-scaled codecs (int8, packed int4) earn a device
+# implementation.
+DEVICE_WIRE_COMPRESSION_CODECS = ("none", "int8", "int4")
 
 # Ring schedules the device plane's quantized collectives can run
 # (ops/collectives.py): 'auto' resolves from the axis size — torus for
@@ -263,7 +263,7 @@ class Config:
     # keeps the historical host-only meaning.
     wire_compression: str = "none"
     # Device-plane codec parsed from the same variable
-    # ("none" | "int8" | "int4" | "int8g").
+    # ("none" | "int8" | "int4").
     wire_compression_device: str = "none"
     # HOROVOD_DEVICE_SCHEDULE: ring schedule for the device plane's
     # quantized collectives ("auto" | "ring" | "bidi" | "torus"); 'auto'

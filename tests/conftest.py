@@ -29,32 +29,34 @@ pytest.register_assert_rewrite("_jit_helpers")
 # ``--dist loadfile`` hands whole files out in collection order, and the
 # alphabet puts most of these last: started when nothing is left to run beside
 # them, they were the gate's tail.  Started first, the short files fill in
-# behind them.  (Seconds a file: PERF.md, "PR 31"; tests/single/
-# test_docs_name_files.py holds every name to a tracked file.)
+# behind them.  Every file over a minute, longest first (seconds a file:
+# PERF.md, "PR 50"; tests/single/test_docs_name_files.py holds every name to a
+# tracked file), but for the longest, tests/single/test_native_selftests.py:
+# its sanitizer builds, started beside five workers that are all compiling,
+# once lost ``make selftest`` to its limit (PERF.md, "PR 31").
 LONG_FILES = (
-    "tests/single/test_ops_jit_schedule_matrix_int8g.py",
-    "tests/single/test_ops_jit_schedule_parity_int4.py",
-    "tests/single/test_ops_jit_schedule_matrix_int4.py",
-    "tests/parallel/test_shm_plane_perf.py",
-    "tests/single/test_ops_jit_quantized_allreduce.py",
-    "tests/single/test_ops_jit_quantized_allreduce_bits.py",
-    "tests/integration/test_matrix.py",
+    "tests/single/test_ops_jit_schedule_parity.py",
     "tests/single/test_flash_gqa_block_diffusion.py",
-    "tests/single/test_flash_attention_grads.py",
-    "tests/single/test_ops_jit_schedule_parity_int8.py",
-    "tests/single/test_ops_jit_schedule_matrix_int8.py",
-    "tests/single/test_ops_jit_quantized_reducescatter.py",
-    "tests/single/test_ops_jit_quantized_alltoall.py",
-    "tests/parallel/test_multiprocess.py",
+    "tests/benchmark/test_jamba_cell.py",
     "tests/single/test_ring_attention.py",
-    "tests/single/test_flash_attention.py",
     "tests/benchmark/test_sdar_cell.py",
+    "tests/single/test_flash_attention_grads.py",
     "tests/single/test_zaya.py",
     "tests/benchmark/test_zaya_cell.py",
     "tests/single/test_jamba.py",
     "tests/single/test_tpu_compile.py",
-    "tests/benchmark/test_jamba_cell.py",
+    "tests/single/test_flash_attention.py",
+    "tests/parallel/test_shm_plane_perf.py",
+    "tests/single/test_bert_reference.py",
     "tests/single/test_selective_scan.py",
+    "tests/single/test_ops_jit_quantized_allreduce_bits.py",
+    "tests/single/test_qk_norm_rope.py",
+    "tests/benchmark/test_benchmark.py",
+    "tests/single/test_chip_smoke.py",
+    "tests/single/test_routed_experts.py",
+    "tests/parallel/test_multiprocess.py",
+    "tests/integration/test_matrix.py",
+    "tests/parallel/test_grouped_atomic.py",
 )
 
 

@@ -85,7 +85,7 @@ def test_kernels_phase_interpreted():
                                 grouped=((4, 2, 32, 96, 4),))
     assert all(c["ok"] for c in report["checks"])
     assert sum(c["name"].startswith("gqa/") for c in report["checks"]) == 8
-    assert [c["codec"] for c in report["codecs"]] == ["int8", "int4", "int8g"]
+    assert [c["codec"] for c in report["codecs"]] == ["int8", "int4"]
 
 
 def test_qk_norm_rope_phase_tiny():

@@ -81,7 +81,6 @@ def test_none_compressor_identity():
 # modes (jnp fallback vs the Pallas interpreter) stay bit-identical.
 # ---------------------------------------------------------------------------
 
-import jax
 import jax.numpy as jnp
 
 import horovod_tpu.ops.quantize as qz
@@ -205,9 +204,8 @@ def test_encoded_nbytes_and_ring_bytes():
 
 
 # ---------------------------------------------------------------------------
-# int4 packed-nibble codec and int8g two-level codec: numpy transliterations
-# of WireEncode(kInt4) / WireEncode(kInt8g), same edge-case contract as the
-# int8 cases above.
+# int4 packed-nibble codec: a numpy transliteration of WireEncode(kInt4), same
+# edge-case contract as the int8 cases above.
 # ---------------------------------------------------------------------------
 
 def _np_quantize_int4(flat):
@@ -235,49 +233,6 @@ def _np_quantize_int4(flat):
     u = codes.astype(np.uint8)
     packed = ((u[:, 0::2] & 0x0F) | ((u[:, 1::2] & 0x0F) << 4)).astype(np.int8)
     return packed, scale
-
-
-def _np_quantize_int8g(flat):
-    """numpy transliteration of WireEncode(kInt8g): per-4096-group fp32
-    scale, per-256-block uint8 sub-scale ``min(255, rint(bmax/gmax * 256))``,
-    effective scale ``gscale * sub/256``."""
-    flat = np.asarray(flat, dtype=np.float32)
-    n = flat.size
-    nblocks = max(1, -(-n // qz.WIRE_BLOCK))
-    xb = np.zeros((nblocks, qz.WIRE_BLOCK), np.float32)
-    xb.reshape(-1)[:n] = flat
-    bpg = qz.WIRE_GROUP // qz.WIRE_BLOCK
-    ngroups = -(-nblocks // bpg)
-    absx = np.abs(xb)
-    absx[np.isnan(absx)] = 0.0
-    bmax = absx.max(axis=1, keepdims=True).astype(np.float32)
-    bmax_p = np.zeros((ngroups * bpg, 1), np.float32)
-    bmax_p[:nblocks] = bmax
-    gmax = bmax_p.reshape(ngroups, bpg).max(axis=1, keepdims=True)
-    gscale = (gmax / np.float32(127.0)).astype(np.float32)
-    gok = (gscale > 0.0) & np.isfinite(gscale)
-    gmax_b = np.repeat(gmax, bpg, axis=0)[:nblocks]
-    gok_b = np.repeat(gok, bpg, axis=0)[:nblocks]
-    gscale_b = np.repeat(gscale, bpg, axis=0)[:nblocks]
-    ratio = (bmax / np.where(gok_b, gmax_b, np.float32(1.0))).astype(
-        np.float32)
-    with np.errstate(invalid="ignore"):
-        sub_f = np.where(
-            gok_b,
-            np.minimum(np.rint(ratio * np.float32(qz.WIRE_SUB_DENOM)),
-                       np.float32(255.0)),
-            np.float32(0.0)).astype(np.float32)
-    eff = (gscale_b * (sub_f / np.float32(qz.WIRE_SUB_DENOM))).astype(
-        np.float32)
-    ok = gok_b & (sub_f > 0.0)
-    inv = np.where(ok, np.float32(1.0) / np.where(ok, eff, 1.0),
-                   0.0).astype(np.float32)
-    with np.errstate(invalid="ignore"):
-        v = np.rint(xb * inv)
-        v = np.where(v < 127.0, v, 127.0)
-        v = np.where(v > -127.0, v, -127.0)
-    codes = np.where(inv > 0.0, v, 0.0).astype(np.int8)
-    return codes, sub_f.astype(np.uint8), gscale
 
 
 @pytest.mark.parametrize("interpret", [None, True])
@@ -317,92 +272,17 @@ def test_int4_pack_unpack_round_trip(interpret):
     np.testing.assert_array_equal(repacked, np.asarray(codes))
 
 
-@pytest.mark.parametrize("interpret", [None, True])
-def test_int8g_matches_numpy_transliteration(interpret):
-    rng = np.random.RandomState(23)
-    n = qz.WIRE_GROUP + 5 * qz.WIRE_BLOCK + 77
-    x = (rng.randn(n) * 4).astype(np.float32)
-    # Shrink every third block so the uint8 sub-scales actually vary, and
-    # zero one block inside a finite group (sub 0, codes 0).
-    for b in range(0, n // qz.WIRE_BLOCK, 3):
-        x[b * qz.WIRE_BLOCK:(b + 1) * qz.WIRE_BLOCK] *= 0.01
-    zb = qz.WIRE_GROUP // qz.WIRE_BLOCK + 1
-    x[zb * qz.WIRE_BLOCK:(zb + 1) * qz.WIRE_BLOCK] = 0.0
-    codes, (sub, gscale) = qz.quantize(jnp.asarray(x), codec="int8g",
-                                       interpret=interpret)
-    ref_codes, ref_sub, ref_gscale = _np_quantize_int8g(x)
-    np.testing.assert_array_equal(np.asarray(codes), ref_codes)
-    np.testing.assert_array_equal(np.asarray(sub).reshape(-1),
-                                  ref_sub.reshape(-1))
-    np.testing.assert_array_equal(np.asarray(gscale), ref_gscale)
-    # The block holding the group max has ratio 1 -> rint(256) clamps to
-    # 255; the zeroed block has sub 0.
-    sub_flat = np.asarray(sub).reshape(-1)
-    bpg = qz.WIRE_GROUP // qz.WIRE_BLOCK
-    assert sub_flat[:bpg].max() == 255
-    assert sub_flat[zb] == 0
-    # Decode bit-identity vs the numpy effective scales.
-    back = np.asarray(qz.dequantize(codes, (sub, gscale), n, codec="int8g",
-                                    interpret=interpret))
-    nblocks = ref_codes.shape[0]
-    gscale_b = np.repeat(ref_gscale, bpg, axis=0)[:nblocks]
-    eff = (gscale_b * (ref_sub.astype(np.float32)
-                       / np.float32(qz.WIRE_SUB_DENOM))).astype(np.float32)
-    expect = (eff * ref_codes.astype(np.float32)).reshape(-1)[:n]
-    np.testing.assert_array_equal(back, expect)
-
-
-@pytest.mark.parametrize("interpret", [None, True])
-def test_int8g_nonfinite_and_zero_groups(interpret):
-    # Group 0: contains inf -> gscale inf, sub bytes 0, codes 0, decode NaN.
-    # Group 1: all zero -> gscale 0, sub 0, codes 0, decode exact zeros.
-    # Group 2: finite -> round-trips within eff/2 per element.
-    n = 3 * qz.WIRE_GROUP
-    rng = np.random.RandomState(24)
-    x = (rng.randn(n) * 2).astype(np.float32)
-    x[7] = np.inf
-    x[qz.WIRE_GROUP:2 * qz.WIRE_GROUP] = 0.0
-    codes, (sub, gscale) = qz.quantize(jnp.asarray(x), codec="int8g",
-                                       interpret=interpret)
-    codes = np.asarray(codes)
-    sub = np.asarray(sub).reshape(-1)
-    gscale = np.asarray(gscale).reshape(-1)
-    bpg = qz.WIRE_GROUP // qz.WIRE_BLOCK
-    assert np.isinf(gscale[0])
-    assert np.all(sub[:bpg] == 0) and np.all(codes[:bpg] == 0)
-    assert gscale[1] == 0.0 and np.all(sub[bpg:2 * bpg] == 0)
-    assert np.isfinite(gscale[2]) and gscale[2] > 0
-    back = np.asarray(qz.dequantize(jnp.asarray(codes),
-                                    (jnp.asarray(sub).reshape(-1, 1),
-                                     jnp.asarray(gscale).reshape(-1, 1)),
-                                    n, codec="int8g", interpret=interpret))
-    assert np.all(np.isnan(back[:qz.WIRE_GROUP]))
-    np.testing.assert_array_equal(back[qz.WIRE_GROUP:2 * qz.WIRE_GROUP], 0.0)
-    ref_codes, ref_sub, ref_gscale = _np_quantize_int8g(x)
-    eff2 = (np.float32(gscale[2]) *
-            (ref_sub[2 * bpg:3 * bpg].astype(np.float32)
-             / np.float32(qz.WIRE_SUB_DENOM)))
-    bound = np.repeat(eff2.reshape(-1), qz.WIRE_BLOCK) / 2
-    tail = slice(2 * qz.WIRE_GROUP, n)
-    assert np.all(np.abs(back[tail] - x[tail]) <= bound + 1e-7)
-
-
-def test_int8g_fake_quantize_and_dispatch_bit_identical():
+def test_int4_fake_quantize_and_dispatch_bit_identical():
     rng = np.random.RandomState(25)
-    x = (rng.randn(qz.WIRE_GROUP + 3 * qz.WIRE_BLOCK + 11) * 9).astype(
-        np.float32)
-    for codec in ("int4", "int8g"):
-        c_jnp, s_jnp = qz.quantize(jnp.asarray(x), codec=codec,
-                                   interpret=None)
-        c_int, s_int = qz.quantize(jnp.asarray(x), codec=codec,
-                                   interpret=True)
-        np.testing.assert_array_equal(np.asarray(c_jnp), np.asarray(c_int))
-        for a, b in zip(jax.tree_util.tree_leaves(s_jnp),
-                        jax.tree_util.tree_leaves(s_int)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        fq = np.asarray(qz.fake_quantize(jnp.asarray(x), codec=codec))
-        expect = np.asarray(qz.dequantize(c_jnp, s_jnp, x.size, codec=codec))
-        np.testing.assert_array_equal(fq, expect)
+    x = (rng.randn(4096 + 3 * qz.WIRE_BLOCK + 11) * 9).astype(np.float32)
+    codec = "int4"
+    c_jnp, s_jnp = qz.quantize(jnp.asarray(x), codec=codec, interpret=None)
+    c_int, s_int = qz.quantize(jnp.asarray(x), codec=codec, interpret=True)
+    np.testing.assert_array_equal(np.asarray(c_jnp), np.asarray(c_int))
+    np.testing.assert_array_equal(np.asarray(s_jnp), np.asarray(s_int))
+    fq = np.asarray(qz.fake_quantize(jnp.asarray(x), codec=codec))
+    expect = np.asarray(qz.dequantize(c_jnp, s_jnp, x.size, codec=codec))
+    np.testing.assert_array_equal(fq, expect)
 
 
 def test_encoded_nbytes_new_codecs_and_schedules():
@@ -410,9 +290,6 @@ def test_encoded_nbytes_new_codecs_and_schedules():
     assert qz.encoded_nbytes(qz.WIRE_BLOCK, "int4") == 4 + 128
     assert qz.encoded_nbytes(1, "int4") == 4 + 1
     assert qz.encoded_nbytes(16384, "int4") == 64 * 4 + 8192
-    # int8g: ceil(n/4096) group scales + ceil(n/256) sub bytes + n codes.
-    assert qz.encoded_nbytes(16384, "int8g") == 4 * 4 + 64 + 16384
-    assert qz.encoded_nbytes(qz.WIRE_GROUP + 1, "int8g") == 2 * 4 + 17 + 4097
     # The ISSUE acceptance floor: int4 on a 64 KiB fp32 payload.
     assert qz.encoded_nbytes(16384, "int4") / (4 * 16384) <= 0.16
     # bidi moves the same totals as ring (each hop splits the chunk across

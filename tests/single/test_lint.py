@@ -427,38 +427,32 @@ def test_cli_exits_nonzero_on_seeded_mismatch(tmp_path):
 
 # ---------------------------------------------------------------------------
 # protocol pass: quantize.py device-plane mirror (block geometry, codec-id
-# map, device codec names) against the five-codec wire_codec.h
+# map, device codec names) against the four-codec wire_codec.h
 # ---------------------------------------------------------------------------
 
-WIRE5_OK = """
+WIRE4_OK = """
 enum class WireCodec : int32_t {
-  kNone = 0, kBf16 = 1, kInt8 = 2, kInt4 = 3, kInt8g = 4,
+  kNone = 0, kBf16 = 1, kInt8 = 2, kInt4 = 3,
 };
 constexpr int64_t kWireBlock = 256;
 constexpr int64_t kWireScaleBytes = 4;
-constexpr int64_t kWireGroup = 4096;
 constexpr int64_t kWireInt4Max = 7;
-constexpr int64_t kWireSubDenom = 256;
 """
 
-CORE5_OK = ('codec = {"none": 0, "bf16": 1, "int8": 2, "int4": 3, '
-            '"int8g": 4}.get(name, 0)')
-ENV5_OK = ('WIRE_COMPRESSION_CODECS = ("none", "bf16", "int8", "int4", '
-           '"int8g")\n'
-           'DEVICE_WIRE_COMPRESSION_CODECS = ("none", "int8", "int4", '
-           '"int8g")\n')
+CORE4_OK = ('codec = {"none": 0, "bf16": 1, "int8": 2, '
+            '"int4": 3}.get(name, 0)')
+ENV4_OK = ('WIRE_COMPRESSION_CODECS = ("none", "bf16", "int8", "int4")\n'
+           'DEVICE_WIRE_COMPRESSION_CODECS = ("none", "int8", "int4")\n')
 QUANTIZE_OK = """
 WIRE_BLOCK = 256
 WIRE_SCALE_BYTES = 4
-WIRE_GROUP = 4096
 WIRE_INT4_MAX = 7
-WIRE_SUB_DENOM = 256
-WIRE_CODEC_IDS = {"none": 0, "bf16": 1, "int8": 2, "int4": 3, "int8g": 4}
-DEVICE_WIRE_CODECS = ("none", "int8", "int4", "int8g")
+WIRE_CODEC_IDS = {"none": 0, "bf16": 1, "int8": 2, "int4": 3}
+DEVICE_WIRE_CODECS = ("none", "int8", "int4")
 """
 
 
-def _proto_q(wire=WIRE5_OK, core=CORE5_OK, env=ENV5_OK, quantize=QUANTIZE_OK):
+def _proto_q(wire=WIRE4_OK, core=CORE4_OK, env=ENV4_OK, quantize=QUANTIZE_OK):
     return hvd_lint.protocol_pass(SC_OK, wire, core, RUNTIME_OK, env,
                                   DOC_PROTO_OK, quantize_py_text=quantize)
 
@@ -468,14 +462,14 @@ def test_protocol_quantize_mirror_clean_fixture():
 
 
 def test_protocol_qblock_drift_is_found():
-    # A sub-scale denominator drift desyncs every int8g effective scale
-    # between the C++ stream and the traced decoder.
+    # A drift in any of the three desyncs the traced codec from the C++
+    # stream: the block a scale covers, the bytes of a scale, int4's clamp.
     keys = {f.key for f in _proto_q(quantize=QUANTIZE_OK.replace(
-        "WIRE_SUB_DENOM = 256", "WIRE_SUB_DENOM = 255"))}
-    assert "PROTO-QBLOCK:WIRE_SUB_DENOM" in keys
+        "WIRE_BLOCK = 256", "WIRE_BLOCK = 128"))}
+    assert "PROTO-QBLOCK:WIRE_BLOCK" in keys
     keys = {f.key for f in _proto_q(quantize=QUANTIZE_OK.replace(
-        "WIRE_GROUP = 4096", "WIRE_GROUP = 2048"))}
-    assert "PROTO-QBLOCK:WIRE_GROUP" in keys
+        "WIRE_SCALE_BYTES = 4", "WIRE_SCALE_BYTES = 2"))}
+    assert "PROTO-QBLOCK:WIRE_SCALE_BYTES" in keys
     keys = {f.key for f in _proto_q(quantize=QUANTIZE_OK.replace(
         "WIRE_INT4_MAX = 7", "WIRE_INT4_MAX = 8"))}
     assert "PROTO-QBLOCK:WIRE_INT4_MAX" in keys
@@ -483,28 +477,27 @@ def test_protocol_qblock_drift_is_found():
 
 def test_protocol_qblock_missing_constant_is_found():
     keys = {f.key for f in _proto_q(quantize=QUANTIZE_OK.replace(
-        "WIRE_GROUP = 4096\n", ""))}
-    assert "PROTO-QBLOCK-MISSING:WIRE_GROUP" in keys
+        "WIRE_INT4_MAX = 7\n", ""))}
+    assert "PROTO-QBLOCK-MISSING:WIRE_INT4_MAX" in keys
 
 
 def test_protocol_qcodec_id_drift_is_found():
     keys = {f.key for f in _proto_q(quantize=QUANTIZE_OK.replace(
-        '"int8g": 4', '"int8g": 5'))}
+        '"int4": 3', '"int4": 4'))}
     assert "PROTO-QCODEC-MIRROR" in keys
 
 
 def test_protocol_device_codec_names_drift_is_found():
-    keys = {f.key for f in _proto_q(env=ENV5_OK.replace(
-        'DEVICE_WIRE_COMPRESSION_CODECS = ("none", "int8", "int4", '
-        '"int8g")',
-        'DEVICE_WIRE_COMPRESSION_CODECS = ("none", "int8", "int8g")'))}
+    keys = {f.key for f in _proto_q(env=ENV4_OK.replace(
+        'DEVICE_WIRE_COMPRESSION_CODECS = ("none", "int8", "int4")',
+        'DEVICE_WIRE_COMPRESSION_CODECS = ("none", "int8")'))}
     assert "PROTO-DEVICE-CODEC-NAMES" in keys
 
 
 def test_protocol_device_codec_without_enum_id_is_found():
     keys = {f.key for f in _proto_q(quantize=QUANTIZE_OK.replace(
-        'DEVICE_WIRE_CODECS = ("none", "int8", "int4", "int8g")',
-        'DEVICE_WIRE_CODECS = ("none", "int8", "int4", "int8g", "fp8")'))}
+        'DEVICE_WIRE_CODECS = ("none", "int8", "int4")',
+        'DEVICE_WIRE_CODECS = ("none", "int8", "int4", "fp8")'))}
     assert "PROTO-DEVICE-CODEC-UNKNOWN:fp8" in keys
 
 

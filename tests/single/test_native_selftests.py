@@ -8,6 +8,8 @@ checkable)."""
 import os
 import shutil
 import subprocess
+import sys
+import time
 
 import pytest
 
@@ -139,6 +141,48 @@ def test_ctrl_soak_under_asan():
 def test_ctrl_soak_under_ubsan():
     out = _build_and_run("ubsan_ctrl_soak_selftest")
     assert "runtime error" not in out, out
+
+
+# The child takes the first port its own pid's walk would be handed
+# (selftest_port.h and runner/util.py:find_free_port start at the same
+# point), listens on it, and becomes the selftest with the listener open.
+_TAKE_FIRST_PORT_THEN_EXEC = """
+import os, socket, sys
+from horovod_tpu.runner import util
+span = util._ephemeral_low() - util._PORT_FLOOR
+port = util._PORT_FLOOR + ((os.getpid() * 2654435761 % 2**32) * span >> 32)
+taken = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+taken.bind(("0.0.0.0", port))
+taken.listen(1)
+taken.set_inheritable(True)
+sys.stderr.write("holding %d\\n" % port)
+sys.stderr.flush()
+os.execv(sys.argv[1], [sys.argv[1]])
+"""
+
+
+def test_ctrl_soak_passes_over_a_taken_port():
+    """A rendezvous port that something else listens on cannot hang a phase
+    (the gate of PR 49 lost 300 s to `bind(...) failed: 98`): the port the
+    soak's first phase would have been handed is held by a listener, and the
+    soak says so, goes on to the next and passes in seconds."""
+    build = subprocess.run(["make", "ctrl_soak_selftest"], cwd=CPP_DIR,
+                           capture_output=True, text=True, timeout=300)
+    assert build.returncode == 0, build.stdout + build.stderr
+    t0 = time.monotonic()
+    run = subprocess.run(
+        [sys.executable, "-c", _TAKE_FIRST_PORT_THEN_EXEC,
+         os.path.join(CPP_DIR, "ctrl_soak_selftest")],
+        capture_output=True, text=True, timeout=120,
+        cwd=os.path.dirname(os.path.dirname(CPP_DIR)))
+    took = time.monotonic() - t0
+    assert run.returncode == 0, (
+        f"rc={run.returncode}\n{run.stdout}\n{run.stderr}")
+    assert "PASS" in run.stdout
+    held = run.stderr.split("holding ", 1)[1].split()[0]
+    assert f"selftest port {held} is taken: trying the next" in run.stderr
+    assert "bind(" not in run.stderr, run.stderr
+    assert took < 60, f"{took:.0f} s"
 
 
 def test_make_selftest_target():

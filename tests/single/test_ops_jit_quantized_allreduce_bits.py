@@ -3,21 +3,18 @@ bytes, a demoted call is the plain collective, and the traced program agrees
 with the eager one (docs/compression.md).  Values and byte counts are in
 test_ops_jit_quantized_allreduce.py.
 
-Split from test_ops_jit.py so that no pytest-xdist worker (``--dist
-loadfile`` gives a file to one worker) is left holding a ten-minute file:
-these cases compile the quantized rings with the Pallas codecs in the
-interpreter and take up to a minute each.
+Each case compiles one program (``_jit_helpers._smap``) and takes about a
+second, but for the eager half of the traced-against-eager case.
 """
 
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import horovod_tpu as hvd
 import horovod_tpu.ops.collectives as hvd_ops
-from _jit_helpers import N_DEV, _smap
+from _jit_helpers import N_DEV, _smap, _smap_eager
 
 pytestmark = pytest.mark.usefixtures("hvd_single")
 
@@ -78,8 +75,8 @@ def test_quantized_allreduce_traced_vs_eager_parity():
         return hvd_ops.quantized_allreduce(shard[0], "hvd", op=hvd.Sum,
                                            min_bytes=0)[None]
 
-    eager = np.asarray(_smap(fn)(x))
-    traced = np.asarray(jax.jit(_smap(fn))(x))
+    eager = np.asarray(_smap_eager(fn)(x))
+    traced = np.asarray(_smap(fn)(x))
     # On TPU both paths run the same Pallas kernels and agree bit-for-bit;
     # the CPU stand-in's whole-program fusion may contract mul+add into an
     # FMA, so allow 1-ulp-scale drift there.

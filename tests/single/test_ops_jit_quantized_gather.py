@@ -1,10 +1,8 @@
 """Universal quantized collectives under the block-scaled codecs
 (docs/compression.md): allgather and broadcast.
 
-Split from test_ops_jit.py so that no pytest-xdist worker (``--dist
-loadfile`` gives a file to one worker) is left holding a ten-minute file:
-these cases compile the quantized rings with the Pallas codecs in the
-interpreter and take up to a minute each.
+Each case compiles one program (``_jit_helpers._smap``) and takes about a
+second.
 """
 
 import numpy as np

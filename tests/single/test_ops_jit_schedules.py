@@ -1,7 +1,7 @@
 """Quantized allreduce schedules: int4 acceptance, schedule resolution, and
 the gspmd demotion.  Every codec under every schedule is in
-test_ops_jit_schedule_matrix_<codec>.py, the schedules' differential parity
-in test_ops_jit_schedule_parity_<codec>.py.
+test_ops_jit_schedule_matrix.py, the schedules' differential parity in
+test_ops_jit_schedule_parity.py.
 """
 
 import numpy as np

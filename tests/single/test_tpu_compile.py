@@ -455,7 +455,7 @@ def test_a_jamba_step_relays_none_of_its_paired_kernels(topo, monkeypatch):
     assert sorted(products) == [("f32", "{1,0")] * 4, products
 
 
-@pytest.mark.parametrize("codec", ["int8", "int4", "int8g"])
+@pytest.mark.parametrize("codec", ["int8", "int4"])
 def test_codec_encode_decode_compiles(one_chip, codec):
     def roundtrip(flat):
         codes, scales = qz.quantize(flat, codec, interpret=False)

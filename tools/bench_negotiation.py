@@ -18,7 +18,7 @@ the codec engages on the cross-host leader ring), reporting cross-host
 wire bytes/step against the fp32 baseline and the max abs error the codec
 introduced.
 
-With --device-codec {int8,int4,int8g} an additional device-plane section
+With --device-codec {int8,int4} an additional device-plane section
 runs: a jitted shard_map allreduce over a forced 8-device CPU host
 platform with the HOROVOD_WIRE_COMPRESSION ``device=`` plane on vs off,
 reporting the codec's encoded-vs-raw wire ratio (from the device-plane
@@ -483,7 +483,7 @@ def main():
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--tensors", type=int, default=50)
     ap.add_argument("--wire-compression", default=None,
-                    choices=["bf16", "int8", "int4", "int8g"],
+                    choices=["bf16", "int8", "int4"],
                     help="also benchmark the wire codec on a cross-host "
                          "(fake two-host, hierarchical) topology against "
                          "the fp32 baseline: bytes/step + max abs error")
@@ -491,7 +491,7 @@ def main():
                     help="fp32 payload size for the wire benchmark (MiB)")
     ap.add_argument("--wire-steps", type=int, default=10)
     ap.add_argument("--device-codec", default=None,
-                    choices=["int8", "int4", "int8g"],
+                    choices=["int8", "int4"],
                     help="also benchmark the in-jit device-plane codec "
                          "(HOROVOD_WIRE_COMPRESSION device= plane) over a "
                          "forced 8-device CPU host platform: encoded/raw "

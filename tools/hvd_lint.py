@@ -573,9 +573,7 @@ def protocol_pass(sc_text: str, wire_codec_text: str, core_py_text: str,
     if quantize_py_text:
         for py_name, cpp_name in (("WIRE_BLOCK", "kWireBlock"),
                                   ("WIRE_SCALE_BYTES", "kWireScaleBytes"),
-                                  ("WIRE_GROUP", "kWireGroup"),
-                                  ("WIRE_INT4_MAX", "kWireInt4Max"),
-                                  ("WIRE_SUB_DENOM", "kWireSubDenom")):
+                                  ("WIRE_INT4_MAX", "kWireInt4Max")):
             qm = re.search(r"^%s\s*=\s*(\d+)" % py_name, quantize_py_text,
                            re.M)
             cm = re.search(r"constexpr\s+int64_t\s+%s\s*=\s*(\d+)" % cpp_name,

@@ -42,7 +42,7 @@ combination of:
 - qdev:    off / <codec>[:<schedule>] / demote (the
            HOROVOD_WIRE_COMPRESSION ``device=`` plane) — the in-jit
            block-scaled device ring, exercised over a forced 4-device CPU
-           host platform; codec is int8 / int4 / int8g, the optional
+           host platform; codec is int8 / int4, the optional
            schedule suffix pins HOROVOD_DEVICE_SCHEDULE (ring/bidi/torus).
            A codec value asserts the auto-dispatch engaged (byte counters
            moved, scale/2-bounded error — int4's bound is 127/7 wider),
@@ -215,7 +215,7 @@ WORKLOAD = textwrap.dedent("""
 
     # qdev axis: the in-jit device-plane ring (HOROVOD_WIRE_COMPRESSION
     # device=<codec>) over the forced multi-device host platform.  A codec
-    # value ("int8" / "int4" / "int8g", optional ":<schedule>" suffix) must
+    # value ("int8" / "int4", optional ":<schedule>" suffix) must
     # engage the auto-dispatch (byte counters move) within the codec's
     # scale/2 error bound; "demote" pins the min-bytes floor: codec stays
     # cold and the result is bit-identical to the plain collective.
@@ -668,20 +668,15 @@ def combos(quick: bool):
            "def", "off", "int8")
     yield ("jax", "native", 1, "on", "on", "shm", "none", "off", "auto",
            "def", "off", "demote")
-    # The new codecs and schedules: int4 (nibble-packed, coarser bound),
-    # int8g (two-level scales), and the schedule suffix pinning the bidi
-    # and torus rings — 4 forced devices factor as 2x2, exercising the
-    # torus demotion-to-bidi rule as well as the explicit bidi path.
+    # int4 (nibble-packed, coarser bound) and the schedule suffix pinning
+    # the bidi and torus rings — 4 forced devices factor as 2x2, exercising
+    # the torus demotion-to-bidi rule as well as the explicit bidi path.
     yield ("jax", "native", 1, "on", "on", "shm", "none", "off", "auto",
            "def", "off", "int4")
-    yield ("jax", "native", 1, "on", "on", "shm", "none", "off", "auto",
-           "def", "off", "int8g")
     yield ("jax", "native", 1, "on", "on", "shm", "none", "off", "auto",
            "def", "off", "int8:bidi")
     yield ("jax", "native", 1, "on", "on", "shm", "none", "off", "auto",
            "def", "off", "int4:torus")
-    yield ("jax", "native", 1, "on", "on", "shm", "none", "off", "auto",
-           "def", "off", "int8g:ring")
     # dplane axis: the gspmd data plane over a forced 4-device host — the
     # env-plumbed engagement row (HOROVOD_DATA_PLANE=gspmd reaches the
     # optimizer, selection counter moves) and the eager-vs-gspmd

@@ -16,6 +16,14 @@
                                      ``sdar-moe-ep8-s4096``'s q and k against
                                      its ``jax.numpy`` form, forward and
                                      backward timed; nothing else
+    python chip_smoke.py --flash-window
+                                     one chip: the banded flash kernels at
+                                     ``laguna-swa-ep32-s16384``'s two shapes
+                                     (9 on 1 and 6 on 1 heads, 16,384 x 128,
+                                     window 512 and none) against
+                                     ``dense_attention``, values and the three
+                                     gradients, forward and backward timed;
+                                     nothing else
 
 One chip: the device JAX found, a clean build of the C++ core and
 ``hvd.init()`` on it, the Pallas kernels alone against their references (at
@@ -769,6 +777,76 @@ def qk_norm_rope(batch: int = 2, length: int = 4096, heads=(32, 4),
     return report
 
 
+def flash_window(length: int = 16384, heads=(9, 6), head_dim: int = 128,
+                 windows=(512, None), repeats: int = 5, chain: int = 4,
+                 interpret: bool = False) -> dict:
+    """The flash kernels under a band (the defaults are
+    ``laguna-swa-ep32-s16384``'s two attention shapes: 9 and 6 query heads on
+    one key/value head of 128 over 16,384 rows in bfloat16, window 512 and
+    none): value, dq, dk and dv of the kernels against ``dense_attention``
+    with the same mask, a query head at a time (the dense scores of one head
+    are a gigabyte; dk and dv are the heads' sum), then the forward's time
+    and the forward and backward's together, best of ``repeats``.  A pass is
+    timed as one of ``chain`` in one compiled program, each fed by the one
+    before, as ``qk_norm_rope`` does."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.flash_attention import (dense_attention,
+                                                 flash_attention)
+
+    checks, report = [], {}
+    for h in heads:
+        ks = jax.random.split(jax.random.PRNGKey(h), 4)
+        q, g = (jax.random.normal(k, (1, length, h, head_dim), jnp.bfloat16)
+                for k in ks[:2])
+        k, v = (jax.random.normal(key, (1, length, 1, head_dim),
+                                  jnp.bfloat16) for key in ks[2:])
+        for window in windows:
+            tag = f"heads={h}/window={window}"
+            kernel = functools.partial(flash_attention, causal=True,
+                                       window=window,
+                                       interpret=interpret or None)
+            dense = functools.partial(dense_attention, causal=True,
+                                      window=window)
+
+            def both(fn, q, k, v, g):
+                out, vjp = jax.vjp(fn, q, k, v)
+                return (out, *vjp(g))
+
+            got = jax.jit(functools.partial(both, kernel))(q, k, v, g)
+            one_head = jax.jit(functools.partial(both, dense))
+            per_head = [one_head(q[:, :, i:i + 1], k, v, g[:, :, i:i + 1])
+                        for i in range(h)]
+            want = (*(jnp.concatenate([p[j] for p in per_head], axis=2)
+                      for j in (0, 1)),
+                    *(sum(p[j].astype(jnp.float32) for p in per_head)
+                      for j in (2, 3)))
+            for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+                _check(checks, f"{tag}/{name}", a, b,
+                       TOL_BF16_FWD if name == "out" else TOL_BF16_BWD)
+
+            def fwd_chain(q, k, v, kernel=kernel):
+                for _ in range(chain):
+                    q = kernel(q, k, v)
+                return q
+
+            def bwd_chain(q, k, v, g, kernel=kernel):
+                for _ in range(chain):
+                    q, k, v = jax.vjp(kernel, q, k, v)[1](g)
+                return q, k, v
+
+            fwd_ms = _best_ms(repeats, jax.jit(fwd_chain), q, k, v) / chain
+            both_ms = _best_ms(repeats, jax.jit(bwd_chain), q, k, v,
+                               g) / chain
+            report[f"fwd_ms/{tag}"] = round(fwd_ms, 3)
+            report[f"fwd_bwd_ms/{tag}"] = round(both_ms, 3)
+    report = emit("flash_window", checks=checks, length=length,
+                  head_dim=head_dim, chain=chain, **report)
+    _raise_on_failed("flash_window", checks)
+    return report
+
+
 # ---------------------------------------------------------------------------
 
 
@@ -781,6 +859,9 @@ def main(argv=None) -> int:
                          "nothing else")
     ap.add_argument("--qk-norm-rope", action="store_true",
                     help="check and time the q/k norm + rotary op, and "
+                         "nothing else")
+    ap.add_argument("--flash-window", action="store_true",
+                    help="check and time the banded flash kernels, and "
                          "nothing else")
     ap.add_argument("--worker", action="store_true",
                     help="internal: one launch_np4 worker")
@@ -802,6 +883,9 @@ def main(argv=None) -> int:
     elif args.qk_norm_rope:
         info = device()
         qk_norm_rope()
+    elif args.flash_window:
+        info = device()
+        flash_window()
     else:
         info = device()
         native_core()

@@ -7,7 +7,10 @@ a state down the layers, a tied head; its loss is ``zaya.lm_loss``) and
 ``Jamba`` (Mamba-1 selective-scan layers with an attention layer every
 ``attn_layer_period``, a dense SwiGLU feed-forward a block, a tied head; each
 layer a share of a tensor-parallel one where the configuration says so; its
-loss is ``jamba.lm_loss``)."""
+loss is ``jamba.lm_loss``) and ``Laguna`` (sliding-window attention layers
+among global ones with different head counts and rotary rules, a gate a head,
+a top-k mixture of experts beside a shared one, an untied head; its loss is
+``laguna.lm_loss``)."""
 
 from .losses import softmax_cross_entropy  # noqa: F401
 from .mlp import MLP, xent_loss  # noqa: F401
@@ -32,3 +35,7 @@ from .vgg import VGG, VGG16, VGG19, VGGTiny  # noqa: F401
 from .inception import InceptionV3  # noqa: F401
 from . import jamba  # noqa: F401
 from .jamba import Jamba, JambaConfig, JAMBA2_3B, JAMBA_TINY  # noqa: F401
+from . import laguna  # noqa: F401
+from .laguna import (  # noqa: F401
+    Laguna, LagunaConfig, LAGUNA_S_2_1, LAGUNA_TINY,
+)

@@ -79,6 +79,21 @@ query heads and no block-diffusion mask compile to what they were; the
 others name their kernels ``hvd_flash_fwd`` / ``hvd_flash_dq`` /
 ``hvd_flash_dkv``.
 
+**A window** (``window=W`` on a causal call: query i sees the W keys ``i - W
+< j <= i``, its own among them) is a band under the diagonal, walked as the
+diagonal is: the forward's and dq's key walk starts at the band's far edge as
+it stops at the diagonal, dkv's query walk stops at the far edge, and a grid
+tile wholly outside the band is predicated off with its index maps clamped to
+the nearest live block, so it is neither computed nor fetched.  A banded
+call's resident tile is about as many rows as the window holds keys
+(:func:`tile_plan`, ``_band_tile``): every row of a tile pays for each step
+that any row of it sees, so under a band of 512 a row of a 256-row tile
+visits 768 keys, of a 512-row tile 896 and of a 1024-row tile 1152, while a
+larger tile gives the scheduler more independent work a step; at a window of
+512, the one window measured, 512 rows read fastest on a v5e (PERF.md, PR
+51).  Its kernels are named ``hvd_flash_swa_fwd`` / ``_dq`` / ``_dkv``; ``window=None``
+or a window of the whole sequence is the causal call, text for text.
+
 ``kv_lens`` (non-causal calls: an int32 length per sequence, BERT's padding
 mask) reaches the kernels as a scalar-prefetch operand, one length per
 row of the grid (a row's heads are one sequence's): the same walks then end at that
@@ -162,11 +177,13 @@ class _Variant(NamedTuple):
     heads a key/value head (1: as many of each).  ``bd``: the block length
     of a block-diffusion call, 0 without; the kernels then see the clean and
     the noised copy as neighbouring sequences (even, odd) of ``q_rows``
-    grid rows each, ``kv_rows`` in the dkv kernel's grid."""
+    grid rows each, ``kv_rows`` in the dkv kernel's grid.  ``window``: the
+    keys a query of a banded causal call sees, 0 without a band."""
     group: int = 1
     bd: int = 0
     q_rows: int = 1
     kv_rows: int = 1
+    window: int = 0
 
 
 _PLAIN = _Variant()
@@ -206,9 +223,27 @@ def _divisor(n: int, cap: int) -> int:
                 if n % d == 0)
 
 
+def _band_tile(tile: int, step: int, window: int) -> int:
+    """The resident tile of a call under a band of ``window`` keys: the
+    largest part of ``tile`` in whole ``step``s that divides it and holds no
+    more rows than the window holds keys (one step where nothing larger
+    does; the un-banded ``tile`` from a window of that many keys on).  Every
+    row of a tile pays for every step that any row of it sees, tile + window
+    keys less a step, so a tile of the window's size wastes under half of
+    them and a smaller one leaves the scheduler one dependent chain a step.
+    **One window is measured**: on a v5e at 9 query heads on 1 key/value
+    head, 16,384 x 128 in bf16, window 512 (PERF.md, PR 51), forward and
+    backward of one call read 5.18 ms at 256 rows, 4.77 at 512, 5.33 at 1024
+    (8.83 at 128-row tiles in 128-row steps); the rule gives that window its
+    512 rows and is unmeasured at any other."""
+    return next(t for t in range(tile // step * step, 0, -step)
+                if tile % t == 0 and (t <= window or t == step))
+
+
 def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
               block_q: Optional[int] = None,
-              block_k: Optional[int] = None, heads: int = 1) -> TilePlan:
+              block_k: Optional[int] = None, heads: int = 1,
+              window: Optional[int] = None) -> TilePlan:
     """Choose the schedule and the block layout from the shape.  Pure:
     shapes in, sizes out.
 
@@ -228,7 +263,9 @@ def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
     other block in **steps** (at most _MAX_STEP rows); a step divides the
     tile it walks past, so the causal diagonal crosses a tile in a whole
     number of steps.  ``causal`` does not change the sizes: the kernels'
-    walks stop at the diagonal whatever they are.
+    walks stop at the diagonal whatever they are.  Under a ``window`` the
+    resident tile holds at most as many rows as the window holds keys (whole
+    steps, one at least: ``_band_tile``).
     """
     del causal
     g = heads_per_block(head_dim, heads)
@@ -247,6 +284,9 @@ def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
     tile_q, tile_k = _divisor(block_q, _MAX_TILE), _divisor(block_k, _MAX_TILE)
     step_q = math.gcd(_divisor(block_q, _MAX_STEP), tile_k)
     step_k = math.gcd(_divisor(block_k, _MAX_STEP), tile_q)
+    if window is not None:
+        tile_q = _band_tile(tile_q, step_k, window)
+        tile_k = _band_tile(tile_k, step_q, window)
     vmem = _vmem_estimate(block_q, block_k, max(tile_q, tile_k),
                           max(step_q, step_k), lanes, g, itemsize)
     return TilePlan(seq_pad, block_q, block_k, tile_q, tile_k, step_q,
@@ -283,14 +323,37 @@ def _last_live_k(iq, causal: bool, plan: TilePlan, valid_len):
     return last
 
 
-def _block_live(iq, jk, causal: bool, plan: TilePlan, valid_len):
+def _first_live_k(iq, plan: TilePlan, window: int):
+    """The first key block a query block needs: under a band, the one that
+    holds the oldest key its first row sees."""
+    return (jnp.maximum(iq * plan.block_q - window + 1, 0) // plan.block_k
+            if window else 0)
+
+
+def _last_live_q(jk, plan: TilePlan, valid_len, window: int):
+    """The last query block a key block needs (dkv): the one that holds the
+    end of the real sequence or, under a band, the newest query that sees
+    the block's last key."""
+    last = (valid_len - 1) // plan.block_q
+    if window:
+        last = jnp.minimum(
+            last, ((jk + 1) * plan.block_k + window - 2) // plan.block_q)
+    return last
+
+
+def _block_live(iq, jk, causal: bool, plan: TilePlan, valid_len,
+                window: int = 0):
     """Whether the (q-block iq, k-block jk) grid tile can contribute.  The
     grid is sequential and cannot be shortened per row, so a dead tile
-    (above the causal diagonal, or wholly tail padding) is still a grid
-    step: its body is predicated off and its index maps hold the block of
-    the nearest live step, so it costs neither dots nor copies."""
-    return jnp.logical_and(jk <= _last_live_k(iq, causal, plan, valid_len),
+    (above the causal diagonal, below a band's far edge, or wholly tail
+    padding) is still a grid step: its body is predicated off and its index
+    maps hold the block of the nearest live step, so it costs neither dots
+    nor copies."""
+    live = jnp.logical_and(jk <= _last_live_k(iq, causal, plan, valid_len),
                            iq * plan.block_q < valid_len)
+    if window:
+        live = jnp.logical_and(live, jk >= _first_live_k(iq, plan, window))
+    return live
 
 
 def _steps(valid_len, step: int):
@@ -317,7 +380,8 @@ def _run(body, lo, hi, state):
             jax.lax.fori_loop(lo, hi, body, state))
 
 
-def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit):
+def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit,
+            window: int = 0):
     """fwd / dq: walk the key steps of key block ``jk`` that the query tile
     starting at row ``row0`` can see.  ``visit(off, masked, lo, hi)`` takes
     local steps [lo, hi) (``hi`` None: the one step ``lo``) with the tile's
@@ -327,13 +391,26 @@ def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit):
     part of a tile above the diagonal is never computed.  Without a causal
     mask the run ends with the real keys, and a step that holds the end of
     them is masked (a length read in the kernel may end inside a step or
-    not: the one masked step is then live only if it does)."""
+    not: the one masked step is then live only if it does).  Under a band
+    of ``window`` keys the walk starts at the step that holds the oldest key
+    the tile's first row sees; the steps from there to the first one that
+    the tile's last row sees whole are cut by the band's far edge and
+    masked, the rest as without a band."""
     step = plan.step_k
     n = plan.block_k // step
     first, last = jk * n, _steps(valid_len, step)
     if causal:
         on_diag = row0 // step
-        visit(0, False, 0, jnp.clip(jnp.minimum(on_diag, last) - first, 0, n))
+        until = jnp.minimum(on_diag, last)
+        lo = 0
+        if window:
+            live = jnp.maximum(row0 - window + 1, 0) // step
+            whole = jnp.clip(jnp.maximum(
+                row0 + plan.tile_q - window + step - 1, 0) // step,
+                live, until)
+            lo = jnp.clip(whole - first, 0, n)
+            visit(0, True, jnp.clip(live - first, 0, n), lo)
+        visit(0, False, lo, jnp.clip(until - first, 0, n))
         _diag_steps(on_diag, plan.tile_q // step, first, n, last,
                     lambda d, j: visit(d * step, True, j, None))
     else:
@@ -346,7 +423,7 @@ def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit):
 
 def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
             transposed: bool = False, bd: int = 0, strict=0,
-            own: bool = False):
+            own: bool = False, window: int = 0):
     """The float32 score tile q @ k^T * sm_scale ([Tq, Tk]; or its
     transpose k @ q^T), masked where the diagonal or the tail padding
     crosses it.  The dot takes its operands as they arrive."""
@@ -369,7 +446,13 @@ def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
             # Padding lives at the tail, so kpos > any real qpos: the
             # causal mask already excludes padded keys.
             qpos = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
+            seen = qpos >= kpos
+            if window:
+                # A row that no key of its first (far-edge) step is seen by
+                # takes the mask value for its maximum there; the next step
+                # holds a seen key and scales what that made by exp(-1e30).
+                seen = jnp.logical_and(seen, qpos - kpos < window)
+            s = jnp.where(seen, s, NEG_INF)
         else:
             s = jnp.where(kpos < valid_len, s, NEG_INF)
     return s
@@ -457,9 +540,10 @@ def _mha_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, causal: bool,
     tile, step = plan.tile_q, plan.step_k
     heads = _head_lanes(plan)
     bd, strict = variant.bd, _strict(variant, variant.q_rows)
+    window = variant.window
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
                               valid_len=valid_len, transposed=True, bd=bd,
-                              strict=strict)
+                              strict=strict, window=window)
 
     @pl.when(jk == 0)
     def _init():
@@ -493,7 +577,7 @@ def _mha_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, causal: bool,
 
             _own_squares(0, plan.block_q // tile, tile, side, square)
 
-    @pl.when(_block_live(iq, jk, causal, plan, valid_len))
+    @pl.when(_block_live(iq, jk, causal, plan, valid_len, window))
     def _compute():
         def q_tile(c, _):
             start = pl.multiple_of(c * tile, tile)
@@ -531,7 +615,7 @@ def _mha_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, causal: bool,
                     l_ref[g:g + 1, rows] = l
                     acc_ref[h, rows] = acc
 
-            _k_walk(row0, jk, causal, plan, valid_len, visit)
+            _k_walk(row0, jk, causal, plan, valid_len, visit, window)
 
         jax.lax.fori_loop(0, plan.block_q // tile, q_tile, None)
 
@@ -621,20 +705,27 @@ def _by_i(r, i, j, lens):
     return i
 
 
-def _streamed_k(causal: bool, plan: TilePlan, valid_len):
+def _streamed_k(causal: bool, plan: TilePlan, valid_len, window: int = 0):
     """Key/value blocks streaming past query block ``i`` (fwd, dq): a dead
-    grid tile holds the block of the last live one, so it costs no copy."""
-    return lambda r, i, j, lens: jnp.minimum(j, _last_live_k(
-        i, causal, plan, _len_at(r, lens, valid_len)))
+    grid tile holds the block of the nearest live one, so it costs no
+    copy."""
+    def block(r, i, j, lens):
+        j = jnp.minimum(j, _last_live_k(i, causal, plan,
+                                        _len_at(r, lens, valid_len)))
+        return jnp.maximum(j, _first_live_k(i, plan, window)) if window else j
+
+    return block
 
 
 def _named(variant: _Variant, kernel: str) -> dict:
     """The kernel arguments a grouped or block-diffusion call adds: the
     variant, and a name by which a trace tells its three kernels apart
-    (``hvd_flash_fwd`` / ``_dq`` / ``_dkv``).  A plain call adds neither, so
-    its kernels compile to what they were."""
-    return {} if variant == _PLAIN else {"variant": variant,
-                                         "name": f"hvd_flash_{kernel}"}
+    (``hvd_flash_fwd`` / ``_dq`` / ``_dkv``; ``hvd_flash_swa_fwd`` / ``_dq``
+    / ``_dkv`` under a band).  A plain call adds neither, so its kernels
+    compile to what they were."""
+    band = "swa_" if variant.window else ""
+    return {} if variant == _PLAIN else {
+        "variant": variant, "name": f"hvd_flash_{band}{kernel}"}
 
 
 def _kernel_call(kernel, lens, *, grid, in_specs, out_specs, out_shape,
@@ -678,7 +769,8 @@ def _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret, valid_len,
     bq, bk = plan.block_q, plan.block_k
     q_spec = _operand_spec(bq, plan, n_col, _by_i)
     kv_spec = _operand_spec(bk, plan, kb.shape[2] // plan.lanes,
-                            _streamed_k(causal, plan, valid_len),
+                            _streamed_k(causal, plan, valid_len,
+                                        variant.window),
                             _kv_row_of(variant))
     own_specs, own_kv = _own_kv(variant, plan, kb, vb)
     return _kernel_call(
@@ -748,8 +840,10 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     tile, step = plan.tile_q, plan.step_k
     heads = _head_lanes(plan)
     bd, strict = variant.bd, _strict(variant, variant.q_rows)
+    window = variant.window
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
-                              valid_len=valid_len, bd=bd, strict=strict)
+                              valid_len=valid_len, bd=bd, strict=strict,
+                              window=window)
 
     def resident(rows):
         """Per head: a tile's q, dO with the other heads' lanes zeroed;
@@ -793,7 +887,7 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
 
             _own_squares(0, plan.block_q // tile, tile, side, square)
 
-    @pl.when(_block_live(iq, jk, causal, plan, valid_len))
+    @pl.when(_block_live(iq, jk, causal, plan, valid_len, window))
     def _compute():
         def q_tile(c, _):
             start = pl.multiple_of(c * tile, tile)
@@ -811,7 +905,7 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
 
                 acc_ref[rows, :] = _run(body, lo, hi, acc_ref[rows, :])
 
-            _k_walk(row0, jk, causal, plan, valid_len, visit)
+            _k_walk(row0, jk, causal, plan, valid_len, visit, window)
 
         jax.lax.fori_loop(0, plan.block_q // tile, q_tile, None)
 
@@ -834,9 +928,10 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
 
     A key tile at column ``col0`` is seen whole by the query steps after
     the diagonal, in one run up to the last real row (the rows past it
-    carry a zero dO); of the static ``tile_k // step_q`` steps the
-    diagonal crosses, the d-th sees the tile's first ``(d + 1) * step_q``
-    keys only.  Without a causal mask every step sees the whole tile, and
+    carry a zero dO) or, under a band, up to its far edge, which cuts the
+    last steps of the walk as the diagonal cuts the first; of the static
+    ``tile_k // step_q`` steps the diagonal crosses, the d-th sees the
+    tile's first ``(d + 1) * step_q`` keys only.  Without a causal mask every step sees the whole tile, and
     padded keys are masked in each.
 
     Grouped queries (``variant.group`` query heads read this key/value
@@ -865,10 +960,10 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     n = plan.block_q // step
     last = _steps(valid_len, step)
     heads = _head_lanes(plan)
-    strict = _strict(variant, variant.kv_rows)
+    strict, window = _strict(variant, variant.kv_rows), variant.window
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
                               valid_len=valid_len, transposed=True, bd=bd,
-                              strict=strict)
+                              strict=strict, window=window)
 
     def gather(state, kv, rows, row0, col0, masked, **mask):
         """``(dk, dv)`` [Tk, lanes] + what the queries ``rows`` of the
@@ -905,7 +1000,7 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
             dk_own_acc[:] = jnp.zeros_like(dk_own_acc)
             dv_own_acc[:] = jnp.zeros_like(dv_own_acc)
 
-    @pl.when(_block_live(iq, jk, causal, plan, valid_len))
+    @pl.when(_block_live(iq, jk, causal, plan, valid_len, window))
     def _compute():
         _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan)
 
@@ -961,8 +1056,20 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
                 on_diag, count = col0 // step, tile // step
                 _diag_steps(on_diag, count, first, n, last,
                             lambda d, j: visit((d + 1) * step, True, j, None))
-                visit(tile, False, jnp.clip(on_diag + count - first, 0, hi),
-                      hi)
+                lo = jnp.clip(on_diag + count - first, 0, hi)
+                if window:
+                    # The query steps that see the tile whole end where a
+                    # step's last row no longer sees the tile's first key;
+                    # those from there to the last row that sees the tile's
+                    # last key are cut by the band's far edge.
+                    whole = jnp.clip(jnp.maximum(col0 + window - step, 0)
+                                     // step + 1 - first, lo, hi)
+                    visit(tile, False, lo, whole)
+                    visit(tile, True, whole, jnp.clip(
+                        (col0 + tile + window - 2) // step + 1 - first,
+                        whole, hi))
+                else:
+                    visit(tile, False, lo, hi)
             elif isinstance(valid_len, int):
                 visit(tile, valid_len < plan.seq_pad, 0, hi)
             else:
@@ -1009,7 +1116,8 @@ def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
     # dq: q-block fixed per outer step, k/v stream on the inner grid dim.
     q_by_i = _operand_spec(bq, plan, n_col, _by_i)
     kv_by_j = _operand_spec(bk, plan, kv_col,
-                            _streamed_k(causal, plan, valid_len),
+                            _streamed_k(causal, plan, valid_len,
+                                        variant.window),
                             _kv_row_of(variant))
     row_by_i = _row_stat_spec(plan, _by_i)
     own_specs, own_kv = _own_kv(variant, plan, kb, vb)
@@ -1033,7 +1141,8 @@ def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
     def streamed_q(r, i, j, lens):
         first = (i * bk) // bq if causal else 0
         return jnp.minimum(jnp.maximum(j if group == 1 else j % n_q, first),
-                           (_len_at(r, lens, valid_len) - 1) // bq)
+                           _last_live_q(i, plan, _len_at(r, lens, valid_len),
+                                        variant.window))
 
     q_row_of = None if group == 1 else (lambda r, j: r * group + j // n_q)
     k_row_of = (None if not variant.bd else
@@ -1181,8 +1290,25 @@ def _group(q, k, v) -> int:
     return h // hkv
 
 
-def _dense(q, k, v, causal, scale, kv_lens, block_diffusion=None):
+def _window(window, seq: int, causal: bool, kv_lens, block_diffusion):
+    """``window`` checked against the call it came with: the keys a query
+    sees, its own among them, or None where the band holds every causal pair
+    (the call is then the causal one, text for text)."""
+    if window is None:
+        return None
+    if not causal or kv_lens is not None or block_diffusion is not None:
+        raise ValueError("window is a band under the causal diagonal: pass "
+                         "causal=True with it and no other mask")
+    if window < 1:
+        raise ValueError(f"window={window}: a query sees its own key at "
+                         "least")
+    return None if window >= seq else int(window)
+
+
+def _dense(q, k, v, causal, scale, kv_lens, block_diffusion=None,
+           window=None):
     b, s = q.shape[:2]
+    window = _window(window, s, causal, kv_lens, block_diffusion)
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     group = _group(q, k, v)
     if group > 1:
@@ -1195,8 +1321,11 @@ def _dense(q, k, v, causal, scale, kv_lens, block_diffusion=None):
         logits = jnp.where(mask[None, None], logits,
                            jnp.finfo(jnp.float32).min)
     if causal:
-        mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
-        logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+        mask = jnp.tril(jnp.ones((s, s), bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((s, s), bool), -window)
+        logits = jnp.where(mask[None, None], logits,
+                           jnp.finfo(jnp.float32).min)
     if kv_lens is not None:
         real = jnp.arange(s)[None, :] < _kv_lens(kv_lens, b, s, causal)[:, None]
         logits = jnp.where(real[:, None, None, :], logits,
@@ -1211,11 +1340,11 @@ def _dense(q, k, v, causal, scale, kv_lens, block_diffusion=None):
 
 def dense_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, kv_lens=None,
-                    block_diffusion=None):
+                    block_diffusion=None, window: Optional[int] = None):
     """Reference-math dense attention over [B, S, H, D] (fp32 softmax).
-    ``kv_lens``, ``block_diffusion`` and fewer key/value heads than query
-    heads as in :func:`flash_attention`."""
-    out, _ = _dense(q, k, v, causal, scale, kv_lens, block_diffusion)
+    ``kv_lens``, ``block_diffusion``, ``window`` and fewer key/value heads
+    than query heads as in :func:`flash_attention`."""
+    out, _ = _dense(q, k, v, causal, scale, kv_lens, block_diffusion, window)
     return out
 
 
@@ -1252,13 +1381,15 @@ def _kernel_layout(plan: TilePlan, n: int, s_pad: int, d: int):
 
 
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret, kv_lens,
-           block_diffusion=None):
+           block_diffusion=None, window=None):
     """``(out, lse)`` of the kernels, or of the dense fallback off-TPU."""
     b, s, h, d = q.shape
     group = _group(q, k, v)
+    window = _window(window, s, causal, kv_lens, block_diffusion)
     if interpret is None:
         if jax.default_backend() != "tpu":
-            return _dense(q, k, v, causal, scale, kv_lens, block_diffusion)
+            return _dense(q, k, v, causal, scale, kv_lens, block_diffusion,
+                          window)
         interpret = False
     sm_scale = d ** -0.5 if scale is None else scale
     if block_diffusion is not None:
@@ -1272,7 +1403,7 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret, kv_lens,
     # Grouped queries: one head a grid step (a block of several query heads
     # would want as many different key/value heads beside each other).
     plan = tile_plan(s, d, q.dtype.itemsize, causal, block_q, block_k,
-                     heads=h if group == 1 else 1)
+                     heads=h if group == 1 else 1, window=window)
     s_pad = plan.seq_pad
     if s_pad != s:
         pad = [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]
@@ -1284,7 +1415,7 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret, kv_lens,
     lens = (None if kv_lens is None else
             jnp.repeat(_kv_lens(kv_lens, b, s, causal),
                        h // plan.heads_per_block))
-    variant = _PLAIN if group == 1 else _Variant(group=group)
+    variant = _Variant(group=group, window=window or 0)
     out, lse = _flash_lse(to_kernel(q), to_kernel(k), to_kernel(v), lens,
                           sm_scale, causal, plan, bool(interpret), s, variant)
     out = from_kernel(out)[:, :s]
@@ -1345,7 +1476,7 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None, kv_lens=None,
-                    block_diffusion=None):
+                    block_diffusion=None, window: Optional[int] = None):
     """Attention over q [batch, seq, heads, head_dim] and k, v [batch, seq,
     kv_heads, head_dim]; with fewer key/value heads than query heads, query
     head ``i`` reads key/value head ``i // (heads // kv_heads)``.
@@ -1364,7 +1495,11 @@ def flash_attention(q, k, v, causal: bool = False,
     copies of L positions in blocks of B, under
     :func:`block_diffusion_mask` (neither ``causal`` nor ``kv_lens`` with
     it).
+
+    ``window`` (causal calls only): query i sees the ``window`` keys ``i -
+    window < j <= i``, its own among them; None, or a window of the whole
+    sequence, is the causal call.
     """
     out, _ = _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-                    kv_lens, block_diffusion)
+                    kv_lens, block_diffusion, window)
     return out

@@ -30,8 +30,8 @@ pytest.register_assert_rewrite("_jit_helpers")
 # alphabet puts most of these last: started when nothing is left to run beside
 # them, they were the gate's tail.  Started first, the short files fill in
 # behind them.  Every file over a minute, longest first (seconds a file:
-# PERF.md, "PR 50"; tests/single/test_docs_name_files.py holds every name to a
-# tracked file), but for the longest, tests/single/test_native_selftests.py:
+# PERF.md, "PR 50", and "PR 51" for its three; tests/single/
+# test_docs_name_files.py holds every name to a tracked file), but for the longest, tests/single/test_native_selftests.py:
 # its sanitizer builds, started beside five workers that are all compiling,
 # once lost ``make selftest`` to its limit (PERF.md, "PR 31").
 LONG_FILES = (
@@ -45,8 +45,11 @@ LONG_FILES = (
     "tests/benchmark/test_zaya_cell.py",
     "tests/single/test_jamba.py",
     "tests/single/test_tpu_compile.py",
+    "tests/benchmark/test_laguna_cell.py",
     "tests/single/test_flash_attention.py",
     "tests/parallel/test_shm_plane_perf.py",
+    "tests/single/test_laguna.py",
+    "tests/single/test_flash_window.py",
     "tests/single/test_bert_reference.py",
     "tests/single/test_selective_scan.py",
     "tests/single/test_ops_jit_quantized_allreduce_bits.py",

@@ -117,3 +117,18 @@ def test_wide_expert_products_phase_tiny(monkeypatch):
     assert report["gmm_block"] == 128 and report["routed"] == 640
     assert {"gmm_ms", "gmm_t_ms", "tgmm_ms",
             "hvd_grouped_dot_fwd_bwd_ms/rows=1024"} <= set(report)
+
+
+def test_flash_window_phase_tiny():
+    """The banded flash kernels (interpreted) against ``dense_attention`` a
+    query head at a time, at two group sizes, with a band and without, and
+    the timing table's keys."""
+    report = chip_smoke.flash_window(length=160, heads=(3, 2), head_dim=16,
+                                     windows=(24, None), repeats=1, chain=2,
+                                     interpret=True)
+    tags = [f"heads={h}/window={w}" for h in (3, 2) for w in (24, None)]
+    assert [c["name"] for c in report["checks"]] == [
+        f"{tag}/{n}" for tag in tags for n in ("out", "dq", "dk", "dv")]
+    assert all(c["ok"] for c in report["checks"])
+    assert {f"{p}_ms/{tag}" for p in ("fwd", "fwd_bwd")
+            for tag in tags} <= set(report)

@@ -455,6 +455,76 @@ def test_a_jamba_step_relays_none_of_its_paired_kernels(topo, monkeypatch):
     assert sorted(products) == [("f32", "{1,0")] * 4, products
 
 
+def test_a_laguna_step_copies_nothing_of_q_s_size_round_its_kernels(
+        topo, monkeypatch):
+    """The first two layers of ``laguna-swa-ep32-s16384`` at its widths (a
+    full layer of 6 query heads with the dense feed-forward, a sliding layer
+    of 9 with the mixture and the shared expert; one key/value head, 1,536
+    dense columns, 8 experts and 12,544 rows held) on a short sequence, a
+    whole step: gradients, ``optax.adamw`` through ``DistributedOptimizer``,
+    donated state, ``shard_map`` over one described chip.  The banded kernels
+    are on the sliding layer and the un-banded ones on the full layer, one
+    call each by name; under the attention scope the step holds **no copy
+    and no transpose as large as q** (the rotary turn and the gate work on
+    ``[B, S, heads x 128]`` as it lies: tiled over the heads, the cos / sin
+    tables were two float32 copies of q's size a layer kind); the two paired
+    kernels cross the step's boundary 2-D and nothing of their size is
+    copied."""
+    import optax
+    from jax import shard_map
+
+    import horovod_tpu as hvd
+    from horovod_tpu.models import laguna
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(
+        laguna.LAGUNA_S_2_1, num_layers=2, num_kv_heads_held=1,
+        num_heads_per_layer_held=(6, 9), dense_columns_held=1536,
+        num_experts_held=8, vocab_size_held=12544)
+    model, seq = laguna.Laguna(cfg), 1024
+    tx = hvd.DistributedOptimizer(optax.adamw(2e-7), axis_name="hvd")
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("hvd",))
+
+    def train_step(variables, opt_state, ids):
+        loss, grads = jax.value_and_grad(
+            lambda v: laguna.lm_loss(model, v, ids))(variables)
+        updates, opt_state = tx.update(grads, opt_state, variables)
+        return (optax.apply_updates(variables, updates), opt_state,
+                hvd.allreduce(loss, axis_name="hvd"))
+
+    ids = jax.ShapeDtypeStruct((1, seq), jnp.int32)
+    variables = jax.eval_shape(model.init, jax.random.key(0), ids)
+    state = (variables, jax.eval_shape(tx.init, variables))
+    paired = {"gate_up": (3072, 2 * 1536), "shared_gate_up": (3072, 2 * 1024)}
+    seen = [leaf.shape for path, leaf in
+            jax.tree_util.tree_leaves_with_path(state)
+            if any(getattr(k, "key", None) in paired for k in path)]
+    assert sorted(seen) == sorted(list(paired.values()) * 4)
+    text = jax.jit(
+        shard_map(train_step, mesh=mesh, in_specs=(P(), P(), P("hvd")),
+                  out_specs=(P(), P(), P())),
+        donate_argnums=(0, 1)).lower(
+            *_shapes_on(NamedSharding(mesh, P()), state),
+            _shapes_on(NamedSharding(mesh, P("hvd")), ids)).compile().as_text()
+    for kernel in ("fwd", "dq", "dkv"):
+        assert len(re.findall(rf"hvd_flash_swa_{kernel}[\w.]* = ", text)) == 1
+        assert len(re.findall(rf"hvd_flash_{kernel}[\w.]* = ", text)) == 1
+    moved = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                       r"(copy|transpose)\(")
+    least = seq * 6 * 128           # the full layer's q, the smaller of two
+    round_the_kernels = [
+        line.strip()[:200] for line in text.splitlines()
+        for m in [moved.match(line)]
+        if m and math.prod(map(int, m.group(1).split(","))) >= least
+        and "/attn/" in line]
+    assert not round_the_kernels, round_the_kernels
+    sizes = {math.prod(shape) for shape in paired.values()}
+    copies = [line.strip()[:160] for line in text.splitlines()
+              for m in [moved.match(line)]
+              if m and math.prod(map(int, m.group(1).split(","))) in sizes]
+    assert not copies, copies
+
+
 @pytest.mark.parametrize("codec", ["int8", "int4"])
 def test_codec_encode_decode_compiles(one_chip, codec):
     def roundtrip(flat):

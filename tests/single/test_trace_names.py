@@ -161,6 +161,53 @@ def test_a_state_space_step_names_its_projections_mix_scan_and_head():
     assert not any("layer_2" in n and "hvd_ssm" in n for n in names)
 
 
+def test_a_laguna_step_names_what_its_layer_kinds_add():
+    """``models/laguna.py``'s scopes in the lowered step of
+    ``benchmark/families/laguna.py`` at the configuration's rehearsal sizes:
+    ``hvd_attn_proj`` holds the q, k, v and output products, ``hvd_rope`` the
+    rotary turn, ``hvd_attn_gate`` the gate's product, its sigmoid and the
+    0/1 product that spreads it, ``hvd_moe_shared`` the shared expert beside
+    ``parallel/moe.py``'s ``hvd_moe_route`` and ``hvd_moe_experts``,
+    ``hvd_lm_head`` the final norm, the untied head and the loss; both passes
+    carry them, and the dense layer has none of the mixture's."""
+    from benchmark.families import laguna
+    from horovod_tpu.models import laguna as model_laguna
+
+    cfg = run.load_json("configs", "laguna-s-2.1-ep32.json")
+    traffic = traffic_gen.resolve(
+        run.load_json("traffic", "laguna-causal-1x16384x1.json"),
+        rehearse=True)
+    mesh = common.hvd_mesh(jax.devices()[:1])
+    cell = laguna.setup(cfg, mesh, seed=3, rehearse=True)
+    (ids,) = traffic_gen.make_batches(traffic, laguna.inputs(cell, traffic),
+                                      mesh, seed=3)[0]
+    names = _op_names(jax.jit(jax.grad(lambda v: model_laguna.lm_loss(
+        cell["model"], v, ids))).lower(cell["params"]).as_text(
+            debug_info=True))
+
+    def under(scope, op, backward=False):
+        # The expert layer's backward is a custom_vjp's: its ops carry the
+        # scope inside transpose(jvp(...)).
+        return any(re.search(rf"[/(]{scope}[/)]", n) and n.endswith(op)
+                   and ("transpose(" in n) == backward for n in names)
+
+    for backward in (False, True):
+        assert under("hvd_attn_proj", "dot_general", backward)
+        assert under("hvd_rope", "mul", backward)
+        assert under("hvd_attn_gate", "dot_general", backward)
+        assert under("hvd_moe_route", "", backward)
+        assert under("hvd_moe_experts", "", backward)
+        assert under("hvd_moe_shared", "dot_general", backward)
+        assert under("hvd_lm_head", "dot_general", backward)
+    assert under("hvd_attn_gate", "logistic")
+    # The rotary tables are made under the turn's scope, not the products'.
+    assert not any("/hvd_attn_proj/" in n and n.endswith(("cos", "sin"))
+                   for n in names)
+    # Layer 0 is the dense one: nothing of the mixture's is in it.
+    assert not any("layer_0" in n and "hvd_moe" in n for n in names)
+    assert any("layer_1" in n and "hvd_moe_shared" in n for n in names)
+
+
 def test_eager_update_writes_the_spine_s_spans(hvd_single, tmp_path):
     path = _profile_eager_update(hvd_single, tmp_path)
     trace = trace_reduce.read_xplane(path, steps=1)

@@ -62,6 +62,15 @@ flattened again (not a run of neighbouring columns of the flat kernel).
 ``q_proj`` ``[d, heads, head_dim]`` and ``kv_proj`` ``[d, 2, groups,
 head_dim]`` keep their head axes, which the plain reference reads the head
 counts off.
+
+With ``checkpoint_blocks`` each block is under ``jax.checkpoint`` and keeps
+what ``CHECKPOINT_NAMES`` lists: the two halves of ``in_proj`` (``u``, ``z``)
+or, in an attention block, the flash kernel's output and its row statistics
+(``ops/flash_attention.py:CHECKPOINT_NAMES``), and the two halves of
+``gate_up``, each in ``dtype``.  The backward runs the rest of the block's
+forward again, the norms, the convolution, ``x_proj``, the scan's forward,
+``out_proj``, the attention layer's projections, and neither paired product
+nor the flash kernel.
 """
 
 from __future__ import annotations
@@ -74,10 +83,13 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..ops.collectives import vary_like
-from ..ops.flash_attention import dense_attention, flash_attention
+from ..ops.flash_attention import (
+    CHECKPOINT_NAMES as FLASH_CHECKPOINT_NAMES, dense_attention,
+    flash_attention)
 from ..ops.selective_scan import selective_scan
 from ..parallel.tensor_parallel import (
     row_parallel_dense, vocab_parallel_embedding)
@@ -192,6 +204,15 @@ def _scaled(x, scale, eps):
                              + eps) * scale
 
 
+# What a checkpointed block keeps for its backward (``jax.checkpoint``'s
+# ``save_only_these_names``): the paired projections' four halves, named where
+# ``MambaMixer`` and ``JambaMLP`` receive them in ``dtype`` ([B, S, held]
+# each), and the flash kernel's output and row statistics.  Under no
+# checkpoint a name is an identity that lowers to nothing.
+CHECKPOINT_NAMES = ("hvd_ssm_in_u", "hvd_ssm_in_z", "hvd_mlp_gate",
+                    "hvd_mlp_up") + FLASH_CHECKPOINT_NAMES
+
+
 class RowParallel(nn.Module):
     """``x_local @ kernel`` summed over ``axis_name`` (None: this chip's part
     of the sum), the kernel ``[held, features]`` drawn at the whole layer's
@@ -278,6 +299,8 @@ class MambaMixer(nn.Module):
             cfg.mamba_dt_rank
         with jax.named_scope("hvd_ssm_proj"):
             u, z = PairedDense(held, cfg.dtype, name="in_proj")(h)
+            u = checkpoint_name(u, "hvd_ssm_in_u")
+            z = checkpoint_name(z, "hvd_ssm_in_z")
         taps = self.param("conv", _taps_init, (cfg.mamba_d_conv, held))
         conv_bias = self.param("conv_bias", nn.initializers.zeros, (held,)) \
             if cfg.mamba_conv_bias else 0.0
@@ -354,9 +377,15 @@ class JambaMLP(nn.Module):
         cfg = self.config
         held = cfg.columns_held
         gate, up = PairedDense(held, cfg.dtype, name="gate_up")(h)
+        gate = checkpoint_name(gate, "hvd_mlp_gate")
+        up = checkpoint_name(up, "hvd_mlp_up")
+        # An array of its own.  With both halves kept for the backward a
+        # TPU's compiler computes this product inside ``down``'s, as one of
+        # its operands, and that product then runs at half its speed; held
+        # apart it leaves ``up``'s product as a second result.
+        hidden = jax.lax.optimization_barrier(jax.nn.silu(gate) * up)
         return RowParallel(cfg.hidden_size, cfg.intermediate_size,
-                           self.axis_name, cfg.dtype, name="down")(
-                               jax.nn.silu(gate) * up)
+                           self.axis_name, cfg.dtype, name="down")(hidden)
 
 
 class JambaBlock(nn.Module):
@@ -399,7 +428,11 @@ class Jamba(nn.Module):
         self.embed = nn.Embed(
             cfg.rows_held, cfg.hidden_size, dtype=cfg.dtype,
             embedding_init=nn.initializers.normal(stddev=EMBEDDING_STDDEV))
-        block = nn.remat(JambaBlock) if cfg.checkpoint_blocks else JambaBlock
+        block = JambaBlock
+        if cfg.checkpoint_blocks:
+            kept = jax.checkpoint_policies.save_only_these_names(
+                *CHECKPOINT_NAMES)
+            block = nn.remat(JambaBlock, policy=kept)
         self.layers = [block(cfg, attention=cfg.is_attention(i),
                              axis_name=self.axis_name, name=f"layer_{i}")
                        for i in range(cfg.num_layers)]

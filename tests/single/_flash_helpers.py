@@ -35,9 +35,11 @@ def equations(jaxpr):
                     yield from equations(sub)
 
 
-def kernel_calls(jaxpr) -> int:
-    """The ``pallas_call`` equations among them."""
+def kernel_calls(jaxpr, name=None) -> int:
+    """The ``pallas_call`` equations among them; with ``name``, those of the
+    kernel so named."""
     return sum(eqn.primitive.name == "pallas_call"
+               and name in (None, eqn.params["name"])
                for eqn in equations(jaxpr))
 
 

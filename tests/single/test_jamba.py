@@ -9,6 +9,7 @@ share equals the reference given that share; nothing in the mixer sees to
 the right."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -17,11 +18,13 @@ import pytest
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from _flash_helpers import equations, kernel_calls
 from benchmark import common
 from benchmark.references import jamba as reference
 from horovod_tpu import models
 from horovod_tpu.models import jamba
 from horovod_tpu.models.flat_dense import FlatDenseGeneral
+from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops.selective_scan import selective_scan
 
 CFG = models.JAMBA_TINY
@@ -128,6 +131,113 @@ def test_checkpointed_blocks_give_the_same_loss_and_gradients(tiny):
     for a, b in zip(jax.tree_util.tree_leaves(plain[1]),
                     jax.tree_util.tree_leaves(held[1])):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+# A checkpointed block, its paired projections and its kernels.  ``policy``:
+# the block as the model builds it; ``plain``: ``nn.remat(JambaBlock)``
+# keeping nothing (a policy over no name is ``jax.checkpoint``'s own);
+# ``none``: no checkpoint.
+CHECKPOINTS = {"policy": (True, jamba.CHECKPOINT_NAMES), "plain": (True, ()),
+               "none": (False, jamba.CHECKPOINT_NAMES)}
+MAMBA_LAYERS = CFG.layer_kinds.count("mamba")
+ATTENTION_LAYERS = CFG.layer_kinds.count("attention")
+
+
+@pytest.fixture(scope="module")
+def checkpointed(tiny):
+    """``(traced, params)``: the tiny model's loss and gradient traced under
+    ``jax.jit`` for each kind of ``CHECKPOINTS``, its attention and its scan
+    the Pallas kernels in the interpreter."""
+    _, variables, ids = tiny
+    interpreted = {
+        "flash_attention": functools.partial(fa.flash_attention,
+                                             interpret=True),
+        "selective_scan": functools.partial(selective_scan, interpret=True)}
+
+    def loss_of(kind):
+        blocks, names = CHECKPOINTS[kind]
+        model = models.Jamba(dataclasses.replace(
+            CFG, use_flash=True, checkpoint_blocks=blocks))
+
+        def loss(params):
+            with pytest.MonkeyPatch.context() as patch:
+                for name, kernel in interpreted.items():
+                    patch.setattr(jamba, name, kernel)
+                patch.setattr(jamba, "CHECKPOINT_NAMES", names)
+                return jamba.lm_loss(model, {"params": params}, ids)
+        return loss
+
+    with jax.default_matmul_precision("highest"):
+        return {kind: jax.jit(jax.value_and_grad(loss_of(kind))).trace(
+            variables["params"]) for kind in CHECKPOINTS}, variables["params"]
+
+
+def _forward_products(jaxpr, held: int) -> int:
+    """The ``dot_general``s that make a half of a paired projection ``held``
+    wide: ``[B, S, hidden] x [hidden, held] -> [B, S, held]``."""
+    want = ((BATCH, SEQ, CFG.hidden_size), (CFG.hidden_size, held),
+            (BATCH, SEQ, held))
+    return sum(
+        eqn.primitive.name == "dot_general"
+        and tuple(v.aval.shape for v in (*eqn.invars, *eqn.outvars)) == want
+        for eqn in equations(jaxpr))
+
+
+# What a step runs of each, a layer that holds it: the halves of ``in_proj``
+# a Mamba layer and of ``gate_up`` a feed-forward, the flash forward an
+# attention layer, the scan's forward a Mamba layer (a checkpointed block
+# runs it twice under any policy: ``benchmark/families/jamba.py:least_calls``
+# holds the cell to that).
+@pytest.mark.parametrize("kind,in_proj,gate_up,flash_fwd,scan_fwd", [
+    ("policy", 2, 2, 1, 2), ("plain", 4, 4, 2, 2), ("none", 2, 2, 1, 1)])
+def test_a_checkpointed_block_runs_its_paired_products_and_flash_forward_once(
+        kind, in_proj, gate_up, flash_fwd, scan_fwd, checkpointed):
+    """A checkpoint that keeps nothing runs both halves of ``in_proj`` and
+    ``gate_up`` and the flash forward a second time in the backward; the
+    model's keeps what they made.  Counted in the gradient's jaxpr: nothing
+    runs."""
+    traced, _ = checkpointed
+    jaxpr = traced[kind].jaxpr.jaxpr
+    assert _forward_products(jaxpr, CFG.d_inner) == in_proj * MAMBA_LAYERS
+    assert _forward_products(jaxpr, CFG.intermediate_size) == (
+        gate_up * CFG.num_layers)
+    assert {name: kernel_calls(jaxpr, name) for name in (
+        "hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv",
+        "hvd_ssm_scan_fwd", "hvd_ssm_scan_bwd")} == {
+            "hvd_flash_fwd": flash_fwd * ATTENTION_LAYERS,
+            "hvd_flash_dq": ATTENTION_LAYERS,
+            "hvd_flash_dkv": ATTENTION_LAYERS,
+            "hvd_ssm_scan_fwd": scan_fwd * MAMBA_LAYERS,
+            "hvd_ssm_scan_bwd": MAMBA_LAYERS}
+
+
+@pytest.fixture(scope="module")
+def checkpointed_gradients(checkpointed):
+    traced, params = checkpointed
+    return {kind: traced[kind].lower().compile()(params)
+            for kind in CHECKPOINTS}
+
+
+@pytest.mark.parametrize("other", ["plain", "none"])
+def test_what_a_checkpoint_keeps_changes_no_gradient(
+        other, checkpointed_gradients, gradients):
+    """The kept arrays are what the second run would make again from the same
+    operands: the loss is the same number and every gradient leaf the same to
+    float32's rounding (the CPU's compiler fuses the three programs'
+    elementwise passes differently: 1.5e-7 to 4.1e-6 over the 48 leaves)."""
+    (loss, grads), (other_loss, other_grads) = (
+        checkpointed_gradients[kind] for kind in ("policy", other))
+    assert np.asarray(loss) == np.asarray(other_loss)
+    got, want = common.leaf_paths(grads), common.leaf_paths(other_grads)
+    assert set(got) == set(want) and len(got) == 48
+    for path in want:
+        assert float(np.linalg.norm(want[path])) > 0, path
+        assert common.l2_rel_err(got[path], want[path]) < 1e-5, path
+    # And they are the model's gradients: the dense oracle's and XLA's own
+    # scan's, to rounding.
+    dense, _ = gradients
+    for path in dense:
+        assert common.l2_rel_err(got[path], dense[path]) < 1e-4, path
 
 
 # ---------------------------------------------------------------------------

@@ -455,6 +455,72 @@ def test_a_jamba_step_relays_none_of_its_paired_kernels(topo, monkeypatch):
     assert sorted(products) == [("f32", "{1,0")] * 4, products
 
 
+def _computations(text: str) -> dict:
+    """A compiled module's computations by name, each as its lines."""
+    out, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{$", line)
+        if m:
+            name = m.group(1)
+            out[name] = []
+        elif line == "}":
+            name = None
+        elif name:
+            out[name].append(line)
+    return out
+
+
+def test_a_checkpointed_jamba_block_keeps_its_paired_products(one_chip,
+                                                              monkeypatch):
+    """A Mamba block and an attention block at ``jamba2-ssm-tp4-s16384``'s
+    widths on a short sequence under the model's own checkpoint
+    (``checkpoint_blocks``: ``save_only_these_names(*CHECKPOINT_NAMES)``),
+    forward and backward.  The backward's second forward holds no product of
+    ``in_proj`` or ``gate_up`` and no flash forward (the scan's it runs
+    again: ``benchmark/families/jamba.py:least_calls``), and ``down``'s
+    forward product reads ``silu(gate) * up`` as an array: with both halves
+    kept for the backward the compiler made that product an operand computed
+    inside ``down``'s, which took 22.6 ms a step of the cell where reading
+    the array takes 13.4 (PERF.md section 5, PR 52)."""
+    from benchmark.families.jamba import kernel_calls
+    from horovod_tpu.models import jamba
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(
+        jamba.JAMBA2_3B, num_layers=2, attn_layer_period=2,
+        attn_layer_offset=1, mamba_d_inner_held=1280, num_heads_held=5,
+        intermediate_size_held=2048, vocab_size_held=1024,
+        checkpoint_blocks=True)
+    model = jamba.Jamba(cfg)
+    ids = jax.ShapeDtypeStruct((1, 1024), jnp.int32)
+    variables = jax.eval_shape(model.init, jax.random.key(0), ids)
+    text = _compiled_text(
+        jax.grad(lambda v, ids: jnp.sum(model.apply(
+            v, ids, method="hidden").astype(jnp.float32) ** 2)),
+        *_shapes_on(one_chip, (variables, ids)))
+    assert kernel_calls(text) == {
+        "hvd_ssm_scan_fwd": 2, "hvd_ssm_scan_bwd": 1, "hvd_flash_fwd": 1,
+        "hvd_flash_dq": 1, "hvd_flash_dkv": 1}
+    products = [line for line in text.splitlines()
+                if " convolution(" in line
+                and re.search(r"(in_proj|gate_up)/dot_general", line)]
+    again = [line.strip()[:200] for line in products
+             if "rematted_computation" in line]
+    assert len(products) >= 6 and not again, again
+    # ``down``'s forward: the computation that holds its product holds no
+    # instruction of the feed-forward's ``silu(gate) * up``.
+    forward = re.compile(
+        r'op_name="jit\([^"]*\)/jvp\([^"]*\)/[^"]*/mlp/down/[^"]*dot_general"')
+    holders = [(name, lines) for name, lines in _computations(text).items()
+               if any(" convolution(" in line and forward.search(line)
+                      and "transpose(" not in line for line in lines)]
+    assert len(holders) == 2, [name for name, _ in holders]
+    for name, lines in holders:
+        inside = [line.strip()[:160] for line in lines
+                  if re.search(r'/mlp/(mul|silu|logistic)[/"]', line)]
+        assert not inside, (name, inside)
+
+
 def test_a_laguna_step_copies_nothing_of_q_s_size_round_its_kernels(
         topo, monkeypatch):
     """The first two layers of ``laguna-swa-ep32-s16384`` at its widths (a

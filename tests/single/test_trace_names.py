@@ -12,6 +12,7 @@ import re
 import jax
 import jax.numpy as jnp
 import optax
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -337,16 +338,59 @@ def test_every_name_the_program_writes_into_a_trace_starts_with_hvd():
         (6, "kept")]
 
 
-def test_the_names_a_checkpoint_may_keep_are_the_ones_the_kernels_give():
-    """``ops/flash_attention.py:CHECKPOINT_NAMES`` is what a model's policy
-    asks for; the literals of the file's ``checkpoint_name`` calls are what
-    the forward rule gives.  A policy over a name nothing carries keeps
+def _checkpoint_names_given(module) -> list:
+    """The literals of a module's ``checkpoint_name`` calls."""
+    with open(module.__file__) as f:
+        tree = ast.parse(f.read())
+    return [node.args[1].value for node in ast.walk(tree)
+            if isinstance(node, ast.Call)
+            and "checkpoint_name" in _callee_names(node.func)]
+
+
+@pytest.mark.parametrize("asks", ["flash_attention", "jamba"])
+def test_the_names_a_checkpoint_may_keep_are_the_ones_the_kernels_give(asks):
+    """``ops/flash_attention.py:CHECKPOINT_NAMES`` is what ZAYA's policy asks
+    for, and the literals of that file's ``checkpoint_name`` calls are what
+    the forward rule gives; ``models/jamba.py:CHECKPOINT_NAMES`` is what a
+    checkpointed ``JambaBlock`` asks for: the literals of its own calls and
+    the flash kernel's two.  A policy over a name nothing carries keeps
     nothing, in silence."""
+    from horovod_tpu.models import jamba
     from horovod_tpu.ops import flash_attention
 
-    with open(flash_attention.__file__) as f:
-        tree = ast.parse(f.read())
-    given = [node.args[1].value for node in ast.walk(tree)
-             if isinstance(node, ast.Call)
-             and "checkpoint_name" in _callee_names(node.func)]
-    assert sorted(given) == sorted(flash_attention.CHECKPOINT_NAMES)
+    given = _checkpoint_names_given(flash_attention)
+    asked = flash_attention.CHECKPOINT_NAMES
+    if asks == "jamba":
+        given += _checkpoint_names_given(jamba)
+        asked = jamba.CHECKPOINT_NAMES
+    assert sorted(given) == sorted(asked)
+    assert len(given) == {"flash_attention": 2, "jamba": 6}[asks]
+
+
+@pytest.mark.parametrize("family", ["jamba", "laguna"])
+def test_a_name_under_no_checkpoint_lowers_to_nothing(family, monkeypatch):
+    """``models/jamba.py`` names the halves of its paired projections for its
+    own checkpoint's policy.  A block under no checkpoint (Jamba's with
+    ``checkpoint_blocks`` off; every one of Laguna's, which builds
+    ``PairedDense`` too and has no switch for one) lowers to the same text
+    with the names as with ``checkpoint_name`` an identity."""
+    from horovod_tpu import models
+    from horovod_tpu.models import jamba, laguna
+
+    module, model = {
+        "jamba": (jamba, models.Jamba(models.JAMBA_TINY)),
+        "laguna": (laguna, models.Laguna(models.LAGUNA_TINY))}[family]
+    ids = jnp.arange(2 * 16, dtype=jnp.int32).reshape(2, 16)
+    variables = jax.eval_shape(model.init, jax.random.key(0), ids)
+
+    def lowered() -> str:
+        text = jax.jit(jax.value_and_grad(
+            lambda v: module.lm_loss(model, v, ids))).lower(
+                variables).as_text()
+        # A private function's number counts the equations traced before it.
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+    named = lowered()
+    monkeypatch.setattr(jamba, "checkpoint_name", lambda x, name: x)
+    assert lowered() == named
+    assert "dot_general" in named

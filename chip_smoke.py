@@ -24,6 +24,13 @@
                                      ``dense_attention``, values and the three
                                      gradients, forward and backward timed;
                                      nothing else
+    python chip_smoke.py --tied-head
+                                     one chip: ``ops/tied_head.py`` at a block
+                                     of ``zaya1-moe-ep2-s16384``'s and of
+                                     ``jamba2-ssm-tp4-s16384``'s head against
+                                     the ``jax.numpy`` product and statistics,
+                                     alone and inside the whole head's value
+                                     and gradients, both timed; nothing else
 
 One chip: the device JAX found, a clean build of the C++ core and
 ``hvd.init()`` on it, the Pallas kernels alone against their references (at
@@ -847,6 +854,89 @@ def flash_window(length: int = 16384, heads=(9, 6), head_dim: int = 128,
     return report
 
 
+def tied_head(heads=((2048, 131136), (2560, 16384)), tokens: int = 16384,
+              block: int = 2048, repeats: int = 5, chain: int = 4,
+              interpret: bool = False) -> dict:
+    """``ops/tied_head.py`` alone and in its place (the defaults are the two
+    tied heads of the benchmark: ``zaya1-moe-ep2-s16384``'s 131,136 rows of
+    2,048 and ``jamba2-ssm-tp4-s16384``'s 16,384 held rows of 2,560, a block
+    of 2,048 tokens of 16,384, bfloat16).  A block's logits and log-sum-exp
+    by ``hvd_head_logits`` against the ``jax.numpy`` product and row
+    statistics as XLA compiles them alone, each timed as one of ``chain`` in
+    one compiled program; then value, ``dx`` and ``d table`` of
+    ``tied_head_cross_entropy`` over all the tokens with the kernel and with
+    ``_block_nll``'s ``jax.numpy``, and both times: the second pair is the
+    one that says what a step gains, since XLA schedules its own product
+    differently beside the two backward ones."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import losses
+    from horovod_tpu.ops import tied_head as op
+
+    kernel = functools.partial(op.head_logits, interpret=interpret or None)
+
+    def dense(x, table):
+        # ``_block_nll``'s own ``jax.numpy``: its logits and log-sum-exp.
+        with mock.patch.object(losses, "head_logits", lambda *_: None):
+            return losses._block_nll(
+                x, table, jnp.zeros(x.shape[:1], jnp.int32))[1:]
+
+    def chained(fn):
+        def run(x, table):
+            kept = []
+            for _ in range(chain):
+                logits, lse = fn(x, table)
+                kept.append(logits)
+                x = x + (0 * lse).astype(x.dtype)
+            return kept, x
+        return jax.jit(run)
+
+    def whole(head):
+        # A function of its own a side (jit's cache is keyed on it), traced
+        # while ``_block_nll`` finds ``head`` in the kernel's place.
+        def run(*args):
+            with mock.patch.object(losses, "head_logits", head), \
+                    mock.patch.object(losses, "HEAD_BLOCK", block):
+                return jax.value_and_grad(losses.tied_head_cross_entropy,
+                                          argnums=(0, 1))(*args)
+        return jax.jit(run)
+
+    checks, report = [], {}
+    for d, rows in heads:
+        tag = f"d={d}/rows={rows}"
+        ks = jax.random.split(jax.random.PRNGKey(rows), 4)
+        x = jax.random.normal(ks[0], (tokens, d)).astype(jnp.bfloat16)
+        table = jax.random.normal(ks[1], (rows, d)) / 20
+        labels = jax.random.randint(ks[2], (tokens,), 0, rows)
+        weights = jax.random.uniform(ks[3], (tokens,)) / tokens
+        xb, tb = x[:block], table.astype(jnp.bfloat16)
+        for name, a, b in zip(("logits", "lse"), jax.jit(kernel)(xb, tb),
+                              jax.jit(dense)(xb, tb)):
+            _check(checks, f"{tag}/{name}", a, b, 2e-6)
+        for form, fn in (("kernel", kernel), ("dense", dense)):
+            ms = _best_ms(repeats, chained(fn), xb, tb) / chain
+            report[f"{form}_ms/{tag}"] = round(ms, 3)
+            report[f"{form}_tflop_s/{tag}"] = round(
+                2 * block * d * rows / max(ms, 1e-3) / 1e9, 1)
+        sides = {"kernel": whole(kernel), "dense": whole(lambda *_: None)}
+        got, want = (sides[form](x, table, labels, weights)
+                     for form in ("kernel", "dense"))
+        _check(checks, f"{tag}/loss", got[0], want[0], 1e-6)
+        _check(checks, f"{tag}/dx", got[1][0], want[1][0], TOL_BF16_FWD)
+        _check(checks, f"{tag}/dtable", got[1][1], want[1][1], 1e-3)
+        del got, want
+        for form, fn in sides.items():
+            report[f"head_{form}_ms/{tag}"] = _best_ms(
+                repeats, fn, x, table, labels, weights)
+    report = emit("tied_head", checks=checks, tokens=tokens, block=block,
+                  chain=chain, **report)
+    _raise_on_failed("tied_head", checks)
+    return report
+
+
 # ---------------------------------------------------------------------------
 
 
@@ -863,6 +953,9 @@ def main(argv=None) -> int:
     ap.add_argument("--flash-window", action="store_true",
                     help="check and time the banded flash kernels, and "
                          "nothing else")
+    ap.add_argument("--tied-head", action="store_true",
+                    help="check and time the tied head's logits kernel, "
+                         "and nothing else")
     ap.add_argument("--worker", action="store_true",
                     help="internal: one launch_np4 worker")
     args = ap.parse_args(argv)
@@ -886,6 +979,9 @@ def main(argv=None) -> int:
     elif args.flash_window:
         info = device()
         flash_window()
+    elif args.tied_head:
+        info = device()
+        tied_head()
     else:
         info = device()
         native_core()

@@ -1,7 +1,9 @@
 """The one softmax cross-entropy of the models' loss functions
 (``gpt.lm_loss``, ``bert.mlm_loss`` / ``nsp_loss``, ``mlp.xent_loss``), and
 the same under a head tied to the embedding, a block of tokens at a time
-(``zaya.lm_loss``: :func:`tied_head_cross_entropy`, at the end).
+(``zaya.lm_loss``, ``jamba.lm_loss``: :func:`tied_head_cross_entropy`, at the
+end; on a TPU a block's logits and their log-sum-exp are one kernel's,
+``ops/tied_head.py``).
 
 ``log_softmax`` followed by ``take_along_axis`` writes a log-probability for
 every class though the loss reads one a row, and autodiff keeps that array
@@ -17,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.collectives import vary_like as _vary_like
+from ..ops.tied_head import head_logits
 
 
 @jax.custom_vjp
@@ -69,7 +72,10 @@ softmax_cross_entropy.defvjp(_forward, _backward)
 # Tokens a block.  The float32 logits of one block are ``HEAD_BLOCK x V x 4``
 # bytes (1.07 GB at 131,136 rows) and nothing else of that shape lives; a
 # block reads the embedding three times and the gradient's accumulator once
-# each way, so fewer, larger blocks cost fewer bytes.
+# each way, so fewer, larger blocks cost fewer bytes.  The logits themselves
+# are written once (by the product that makes them, which on a TPU folds the
+# row statistics as it goes) and read once by each of the two backward
+# products, which make ``d logits`` of them on the way in.
 HEAD_BLOCK = 2048
 
 
@@ -83,7 +89,16 @@ def _head_blocks(tokens: int) -> int:
 def _block_nll(x, embedding, labels):
     """One block: float32 logits ``x . embedding^T`` (the product in
     ``x.dtype``, accumulated in float32), each row's negative
-    log-likelihood, and what the gradient needs of the softmax."""
+    log-likelihood, and what the gradient needs of the softmax, the row's
+    log-sum-exp.  On a TPU the logits and the log-sum-exp are one kernel's
+    (``hvd_head_logits``, ``ops/tied_head.py``): the product's tiles are
+    folded into the rows' statistics before they leave VMEM.  Elsewhere the
+    product is XLA's and the statistics a second pass over its logits."""
+    made = head_logits(x, embedding)
+    if made is not None:
+        logits, lse = made
+        picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)
+        return (lse - picked)[:, 0], logits, lse
     logits = jax.lax.dot_general(x, embedding, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     top = jnp.max(logits, axis=-1, keepdims=True)
@@ -118,7 +133,19 @@ def tied_head_cross_entropy(x, embedding, labels, weights):
     E`` and ``dE += d logits^T . x``), so the logits are never computed
     twice: three products a block.  The backward scales them by the
     cotangent.  ``dE`` is float32 ``[V, d]``, the embedding's gradient as
-    the head sees it; autodiff adds the gather's."""
+    the head sees it; autodiff adds the gather's.
+
+    A block's passes.  (1) The logits' product reads the block's ``x`` and
+    the embedding and writes the float32 logits; on a TPU it is the kernel
+    ``hvd_head_logits`` (``ops/tied_head.py``), which folds each tile into
+    the rows' running maximum and sum before it leaves VMEM and hands back
+    the log-sum-exp too, so nothing reads the logits for their statistics;
+    elsewhere it is XLA's product and a second pass over the logits
+    (maximum, ``exp``, sum).  (2) One scalar a row is gathered from the
+    logits (the label's).  (3) and (4) The two backward products each read
+    the logits once, with the log-sum-exp, the labels and the weights, and
+    make ``d logits`` in ``x.dtype`` of them on the way in: XLA's own, as
+    is the scan over the blocks."""
     return _tied(x, _vary_like(embedding, x), _vary_like(labels, x),
                  _vary_like(weights, x))
 

@@ -51,7 +51,9 @@ The whole router runs in float32, its products at "highest" precision: the
 choice is discrete.  The dropless dispatch is ``parallel/moe.py:
 dispatch_experts``; attention is the Pallas flash kernels on a TPU
 (``use_flash``), the dense oracle elsewhere; the head and the loss run a block
-of tokens at a time (``losses.tied_head_cross_entropy``).  With
+of tokens at a time (``losses.tied_head_cross_entropy``: a block's logits and
+their log-sum-exp are one Pallas call on a TPU, ``hvd_head_logits`` of
+``ops/tied_head.py``, and the two backward products XLA's).  With
 ``checkpoint_blocks`` each block is under ``jax.checkpoint`` and keeps two
 arrays, the flash kernel's output and its row statistics
 (``ops/flash_attention.py:CHECKPOINT_NAMES``): the backward runs the rest of

@@ -132,3 +132,22 @@ def test_flash_window_phase_tiny():
     assert all(c["ok"] for c in report["checks"])
     assert {f"{p}_ms/{tag}" for p in ("fwd", "fwd_bwd")
             for tag in tags} <= set(report)
+
+
+def test_tied_head_phase_tiny():
+    """The tied head's kernel (interpreted) against the ``jax.numpy`` product
+    and statistics, alone and inside the whole head, at a table of whole
+    tiles and at one with a last tile in part, and the timing table's
+    keys."""
+    report = chip_smoke.tied_head(heads=((128, 1024), (256, 584)), tokens=256,
+                                  block=128, repeats=1, chain=2,
+                                  interpret=True)
+    tags = ["d=128/rows=1024", "d=256/rows=584"]
+    assert [c["name"] for c in report["checks"]] == [
+        f"{tag}/{n}" for tag in tags
+        for n in ("logits", "lse", "loss", "dx", "dtable")]
+    assert all(c["ok"] for c in report["checks"]), report["checks"]
+    assert {f"{form}_{unit}/{tag}" for form in ("kernel", "dense")
+            for unit in ("ms", "tflop_s") for tag in tags} | {
+                f"head_{form}_ms/{tag}" for form in ("kernel", "dense")
+                for tag in tags} <= set(report)

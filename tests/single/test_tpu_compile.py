@@ -353,6 +353,99 @@ def test_grouped_products_compile_at_an_expert_wider_than_the_budget(
     assert len(re.findall(r"hvd_moe_tgmm[\w.]* = ", text)) == 1
 
 
+HEADS = {"zaya": (2048, 131136), "jamba": (2560, 16384)}
+
+
+@pytest.mark.parametrize("d,rows", HEADS.values(), ids=HEADS.keys())
+def test_the_tied_head_s_kernel_compiles_at_both_cells_shapes(one_chip, d,
+                                                              rows):
+    """``ops/tied_head.py`` at a block of ``zaya1-moe-ep2-s16384`` (``[2048,
+    2048] x [131136, 2048]``, a last tile of 64 rows) and of
+    ``jamba2-ssm-tp4-s16384`` (``[2048, 2560] x [16384, 2560]``), bfloat16:
+    the chip's compiler takes it within the VMEM it asks for, the whole block
+    of tokens standing, and nothing but the kernel is in the program: the
+    logits leave as the kernel wrote them."""
+    from horovod_tpu.ops import tied_head as th
+
+    tiles = th.plan(2048, d, rows, 2)
+    assert tiles.tokens == 2048
+    assert th._vmem_bytes(*tiles, d, 2) <= th._VMEM_BUDGET < th._VMEM_LIMIT
+    compiled = jax.jit(lambda x, table: th._head_logits(
+        x, table, tiles, False)).lower(*_shapes_on(one_chip, (
+            jax.ShapeDtypeStruct((2048, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((rows, d), jnp.bfloat16)))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert len(re.findall(r"hvd_head_logits[\w.]* = ", text)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("d,rows", HEADS.values(), ids=HEADS.keys())
+def test_the_tied_head_s_step_reads_the_kernel_s_logits_as_they_are(
+        one_chip, monkeypatch, d, rows):
+    """Value and gradients of ``tied_head_cross_entropy`` over 16,384 tokens
+    at both cells' widths with the kernel on: the scan's body holds one
+    ``hvd_head_logits``, no pass of XLA's own over a block's logits for the
+    row statistics (no reduction to ``[2048]``), no ``copy`` or ``transpose``
+    of a block's logits or ``d logits`` (the kernel writes ``[V, T]``, the
+    layout the two backward products read), and those two products as they
+    were: ``dx`` and the accumulation into the float32 ``[V, d]``."""
+    from horovod_tpu.models import losses
+    from horovod_tpu.ops import tied_head as th
+
+    def compiled(kernel: bool):
+        monkeypatch.setattr(losses, "head_logits", functools.partial(
+            th.head_logits, interpret=False) if kernel
+            else lambda x, table: None)
+        # A function of its own a side: jit's cache is keyed on it.
+        return _compiled_text(
+            lambda *a: jax.value_and_grad(
+                losses.tied_head_cross_entropy, argnums=(0, 1))(*a),
+            *_shapes_on(one_chip, (
+                jax.ShapeDtypeStruct((16384, d), jnp.bfloat16),
+                jax.ShapeDtypeStruct((rows, d), jnp.float32),
+                jax.ShapeDtypeStruct((16384,), jnp.int32),
+                jax.ShapeDtypeStruct((16384,), jnp.float32))))
+
+    def census(text):
+        """The instructions outside fusions' bodies that make an array of a
+        block's logits' size, by opcode, and the products' result types."""
+        made, products = [], []
+        for name, lines in _computations(text).items():
+            for line in lines:
+                m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(",
+                             line)
+                if not m:
+                    continue
+                result, op = m.groups()
+                if op == "convolution":
+                    products.append(result.split("{")[0])
+                elif ("fused" not in name and op not in (
+                        "parameter", "get-tuple-element", "bitcast", "tuple")
+                      and re.match(rf"\w+\[({rows},2048|2048,{rows})\]",
+                                   result)):
+                    made.append(op)
+        return sorted(made), sorted(products)
+
+    kernel, plain = compiled(True), compiled(False)
+    assert len(re.findall(r"hvd_head_logits[\w.]* = ", kernel)) == 1
+    assert "hvd_head_logits" not in plain
+    made, products = census(kernel)
+    plain_made, plain_products = census(plain)
+    assert "copy" not in made and "transpose" not in made, made
+    # XLA's own logits' product is gone and the two backward ones stand.
+    logits = f"f32[2048,{rows}]"
+    assert plain_products.count(logits) == 1 and logits not in products
+    plain_products.remove(logits)
+    assert products == plain_products
+    assert f"f32[{rows},{d}]" in products and f"f32[2048,{d}]" in products
+    # No reduction over a block's logits is XLA's any more.
+    reduces = [line for line in kernel.splitlines()
+               if re.search(r"= f32\[2048\]\S* reduce\(", line)]
+    assert reduces == [] and re.search(
+        r"= f32\[2048\]\S* reduce\(", plain), reduces
+
+
 def test_selective_scan_compiles_at_the_jamba_cell_s_shapes_in_shard_map(
         topo):
     """``ops/selective_scan.py`` at ``jamba2-ssm-tp4-s16384``'s shapes (one

@@ -24,6 +24,16 @@
                                      ``dense_attention``, values and the three
                                      gradients, forward and backward timed;
                                      nothing else
+    python chip_smoke.py --flash-mla
+                                     one chip: the flash kernels with a second
+                                     score operand at ``joyai-mla-ep16-s16384``'s
+                                     shape (4 heads, 16,384 x 128 + 64 on one
+                                     shared rotary key) against
+                                     ``dense_attention``, values and the five
+                                     gradients, and its values against the
+                                     kernels without the pair (q and k
+                                     concatenated to 192, v padded), forward
+                                     and backward timed; nothing else
     python chip_smoke.py --tied-head
                                      one chip: ``ops/tied_head.py`` at a block
                                      of ``zaya1-moe-ep2-s16384``'s and of
@@ -854,6 +864,87 @@ def flash_window(length: int = 16384, heads=(9, 6), head_dim: int = 128,
     return report
 
 
+def flash_mla(length: int = 16384, heads: int = 4, head_dim: int = 128,
+              rope: int = 64, repeats: int = 5, chain: int = 4,
+              interpret: bool = False) -> dict:
+    """The flash kernels with a second score operand (the defaults are
+    ``joyai-mla-ep16-s16384``'s attention: 4 heads of 128 + 64 on one shared
+    rotary key over 16,384 rows in bfloat16, scores scaled by 192^-1/2):
+    value, dq, dk, dv, dq_rope and dk_rope against ``dense_attention`` with
+    the pair, a head at a time (dk_rope is the heads' sum), and the value
+    against the kernels without the pair (``concat192``: q and k concatenated
+    to 192 a head, the shared key copied a head, v padded to 192, which is
+    the ``hvd_flash_relayout`` path); then the forward's time and the forward
+    and backward's, each pass one of ``chain`` in one compiled program as
+    ``flash_window`` does.  What the other forms read (``concat192``, the
+    rotary parts padded to 128 lanes, other blocks): PERF.md, PR 54."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.flash_attention import (dense_attention,
+                                                 flash_attention)
+
+    scale = (head_dim + rope) ** -0.5
+    ks = jax.random.split(jax.random.PRNGKey(heads), 6)
+    q, k, v, g = (jax.random.normal(key, (1, length, heads, head_dim),
+                                    jnp.bfloat16) for key in ks[:4])
+    qr = jax.random.normal(ks[4], (1, length, heads, rope), jnp.bfloat16)
+    kr = jax.random.normal(ks[5], (1, length, 1, rope), jnp.bfloat16)
+
+    def both(fn, g, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (out, *vjp(g))
+
+    def paired(attend, **kw):
+        return lambda q, k, v, qr, kr: attend(
+            q, k, v, causal=True, scale=scale, q_rope=qr, k_rope=kr, **kw)
+
+    def concatenated(q, k, v, qr, kr):
+        # The kernels without the pair: one 192-wide product a head.
+        wide = [(0, 0)] * 3 + [(0, rope)]
+        return flash_attention(
+            jnp.concatenate([q, qr], -1), jnp.concatenate(
+                [k, jnp.broadcast_to(kr, qr.shape)], -1), jnp.pad(v, wide),
+            causal=True, scale=scale,
+            interpret=interpret or None)[..., :head_dim]
+
+    kernel = paired(flash_attention, interpret=interpret or None)
+    checks = []
+    got = jax.jit(functools.partial(both, kernel))(g, q, k, v, qr, kr)
+    one_head = jax.jit(functools.partial(both, paired(dense_attention)))
+    per_head = [one_head(*(x[:, :, i:i + 1] for x in (g, q, k, v, qr)), kr)
+                for i in range(heads)]
+    want = (*(jnp.concatenate([p[j] for p in per_head], axis=2)
+              for j in range(5)),
+            sum(p[5].astype(jnp.float32) for p in per_head))
+    for name, a, b in zip(("out", "dq", "dk", "dv", "dq_rope", "dk_rope"),
+                          got, want):
+        _check(checks, name, a, b,
+               TOL_BF16_FWD if name == "out" else TOL_BF16_BWD)
+    _check(checks, "concat192/out", jax.jit(concatenated)(q, k, v, qr, kr),
+           got[0], TOL_BF16_FWD)
+
+    def fwd_chain(q, *rest):
+        for _ in range(chain):
+            q = kernel(q, *rest)
+        return q
+
+    def bwd_chain(g, *args):
+        for _ in range(chain):
+            args = jax.vjp(kernel, *args)[1](g)
+        return args
+
+    report = emit(
+        "flash_mla", checks=checks, length=length, heads=heads,
+        head_dim=head_dim, rope=rope, chain=chain,
+        fwd_ms=round(_best_ms(repeats, jax.jit(fwd_chain), q, k, v, qr, kr)
+                     / chain, 3),
+        fwd_bwd_ms=round(_best_ms(repeats, jax.jit(bwd_chain), g, q, k, v,
+                                  qr, kr) / chain, 3))
+    _raise_on_failed("flash_mla", checks)
+    return report
+
+
 def tied_head(heads=((2048, 131136), (2560, 16384)), tokens: int = 16384,
               block: int = 2048, repeats: int = 5, chain: int = 4,
               interpret: bool = False) -> dict:
@@ -953,6 +1044,9 @@ def main(argv=None) -> int:
     ap.add_argument("--flash-window", action="store_true",
                     help="check and time the banded flash kernels, and "
                          "nothing else")
+    ap.add_argument("--flash-mla", action="store_true",
+                    help="check and time the flash kernels with a second "
+                         "score operand, and nothing else")
     ap.add_argument("--tied-head", action="store_true",
                     help="check and time the tied head's logits kernel, "
                          "and nothing else")
@@ -979,6 +1073,9 @@ def main(argv=None) -> int:
     elif args.flash_window:
         info = device()
         flash_window()
+    elif args.flash_mla:
+        info = device()
+        flash_mla()
     elif args.tied_head:
         info = device()
         tied_head()

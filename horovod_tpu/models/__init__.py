@@ -10,7 +10,10 @@ layer a share of a tensor-parallel one where the configuration says so; its
 loss is ``jamba.lm_loss``) and ``Laguna`` (sliding-window attention layers
 among global ones with different head counts and rotary rules, a gate a head,
 a top-k mixture of experts beside a shared one, an untied head; its loss is
-``laguna.lm_loss``)."""
+``laguna.lm_loss``) and ``JoyAI`` (multi-head latent attention: a low-rank
+latent between the stream and the heads, scores of two products, one rotary
+key for all heads; experts chosen by bias-corrected sigmoid scores beside a
+shared one; its loss is ``joyai.lm_loss``)."""
 
 from .losses import softmax_cross_entropy  # noqa: F401
 from .mlp import MLP, xent_loss  # noqa: F401
@@ -38,4 +41,8 @@ from .jamba import Jamba, JambaConfig, JAMBA2_3B, JAMBA_TINY  # noqa: F401
 from . import laguna  # noqa: F401
 from .laguna import (  # noqa: F401
     Laguna, LagunaConfig, LAGUNA_S_2_1, LAGUNA_TINY,
+)
+from . import joyai  # noqa: F401
+from .joyai import (  # noqa: F401
+    JoyAI, JoyAIConfig, JOYAI_LLM_FLASH, JOYAI_TINY,
 )

@@ -94,6 +94,31 @@ larger tile gives the scheduler more independent work a step; at a window of
 51).  Its kernels are named ``hvd_flash_swa_fwd`` / ``_dq`` / ``_dkv``; ``window=None``
 or a window of the whole sequence is the causal call, text for text.
 
+**A second score operand** (``q_rope`` [B, S, H, r] with ``k_rope`` [B, S, 1,
+r]: multi-head latent attention, whose scores are ``q . k + q_rope . k_rope``
+under one softmax, the rotary key one for all heads, v and the output as wide
+as q and k): the three kernels gain the pair as operands of their own and
+build a score tile from two dots accumulated in float32 before the mask; dq
+returns ``dq_rope`` beside dq, dkv a third gradient, ``dk_rope``, which every
+held head owes the one key: a float32 partial a grid row, summed outside.
+``k_rope`` is read from its one [B, S, r] array by every head (its block is
+the array's whole minor dimension) and nothing is copied a head.  A grid step
+holds two heads (``_rope_heads``: their 64-wide rotary parts fill the one
+lane tile a block's minor dimension must be, their 128-wide parts lie side by
+side, 256 lanes), each head's operands a slice of whole lane tiles and a
+chain of its own; the walks (``_k_walk``, ``_q_walk``), ``_scores``, the
+statistics and ``delta`` are the causal call's.  The forward takes the pair
+in the kernel it had, whose heads were slices and chains already.  The
+backward is two kernels of its own (``_mla_dq_kernel``, ``_mla_dkv_kernel``)
+beside ``_mha_bwd_*``, because those run a block's heads in the zero-lane
+form (:func:`_only`: every operand whole, the other heads' lanes zeroed, one
+accumulator and one dot for all heads' dk and dv), which costs nothing at
+64-wide heads, where the zeros ride in the half of the MXU a head leaves
+idle, and at two 128-wide heads would double every product's passes.  The
+kernels are named ``hvd_flash_mla_fwd`` / ``_dq`` / ``_dkv``; the pair goes
+with no length, no block-diffusion mask, no window and no grouped heads (each
+raises by name), and a call without the pair is what it was, text for text.
+
 ``kv_lens`` (non-causal calls: an int32 length per sequence, BERT's padding
 mask) reaches the kernels as a scalar-prefetch operand, one length per
 row of the grid (a row's heads are one sequence's): the same walks then end at that
@@ -140,6 +165,14 @@ _MAX_STEP = 256
 # 128 MiB of VMEM, a v7x core 64 MiB.
 _VMEM_BUDGET = 16 * 1024 * 1024
 _VMEM_LIMIT = 2 * _VMEM_BUDGET
+# The same for a call with a second score operand, whose grid step holds two
+# whole-tile heads: three quarters of the limit.  Set from what Mosaic keeps:
+# compiled for a v5e at 4 heads of 128 + 64 over 16,384 rows in bf16, the
+# three kernels in blocks of 1,024 rows fit a limit of 24 MiB and not one of
+# 22 (dq the hungriest; tile_plan's estimate reads 23.6), and in blocks of
+# 2,048 one of 32 and not one of 30 (the estimate reads 37.3 and refuses
+# them): PERF.md, PR 54.
+_VMEM_BUDGET_PAIR = 3 * _VMEM_LIMIT // 4
 
 _NT = (((1,), (1,)), ((), ()))      # A @ B^T: contract the minor dims
 _TN = (((0,), (0,)), ((), ()))      # A^T @ B: contract the major dims
@@ -178,12 +211,15 @@ class _Variant(NamedTuple):
     of a block-diffusion call, 0 without; the kernels then see the clean and
     the noised copy as neighbouring sequences (even, odd) of ``q_rows``
     grid rows each, ``kv_rows`` in the dkv kernel's grid.  ``window``: the
-    keys a query of a banded causal call sees, 0 without a band."""
+    keys a query of a banded causal call sees, 0 without a band.  ``rope``:
+    the width of a head's second score operand (``q_rope`` / ``k_rope``), 0
+    without the pair."""
     group: int = 1
     bd: int = 0
     q_rows: int = 1
     kv_rows: int = 1
     window: int = 0
+    rope: int = 0
 
 
 _PLAIN = _Variant()
@@ -196,6 +232,21 @@ def heads_per_block(head_dim: int, heads: int) -> int:
     divide 128, fewer heads than fill it), one: the fallback layout."""
     g = LANES // head_dim if LANES % head_dim == 0 else 1
     return g if heads % g == 0 else 1
+
+
+def _rope_heads(head_dim: int, heads: int, rope: int) -> int:
+    """Heads a grid step of a call with a second score operand: the two whose
+    ``rope``-wide parts fill a lane tile.  The ``head_dim``-wide parts must be
+    whole lane tiles, so that a head's operands are slices of them.  Two heads
+    of 64 rotary lanes are what was built and run on a chip; another width
+    is refused here and not planned on a guess."""
+    g = 2
+    if head_dim % LANES or g * rope != LANES or heads % g:
+        raise ValueError(
+            f"q_rope / k_rope {rope} wide on {heads} heads of {head_dim}: "
+            f"built for heads of whole {LANES}-lane tiles, {g} at a time, "
+            f"whose rotary parts fill one tile ({LANES // g} lanes each)")
+    return g
 
 
 def _vmem_estimate(block_q, block_k, tile, step, lanes, heads, itemsize):
@@ -243,7 +294,7 @@ def _band_tile(tile: int, step: int, window: int) -> int:
 def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
               block_q: Optional[int] = None,
               block_k: Optional[int] = None, heads: int = 1,
-              window: Optional[int] = None) -> TilePlan:
+              window: Optional[int] = None, rope: int = 0) -> TilePlan:
     """Choose the schedule and the block layout from the shape.  Pure:
     shapes in, sizes out.
 
@@ -266,16 +317,27 @@ def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
     walks stop at the diagonal whatever they are.  Under a ``window`` the
     resident tile holds at most as many rows as the window holds keys (whole
     steps, one at least: ``_band_tile``).
+
+    With a second score operand ``rope`` lanes wide a head (``q_rope`` /
+    ``k_rope``) a grid step holds the two heads whose rotary parts fill a
+    lane tile (``_rope_heads``), their ``head_dim``-wide parts side by side in
+    ``lanes``; the rotary blocks count in the estimate as lanes more, and the
+    estimate is held to ``_VMEM_BUDGET_PAIR``: blocks of 1,024 rows at 4 heads
+    of 128 + 64 over 16,384 rows in bf16 (on a v5e forward and backward of a
+    call read 16.0 ms in them and 19.4 in blocks of 512: PERF.md, PR 54).
     """
     del causal
-    g = heads_per_block(head_dim, heads)
+    g = _rope_heads(head_dim, heads, rope) if rope else heads_per_block(
+        head_dim, heads)
     lanes = g * head_dim
+    held = lanes + g * rope         # the estimate's lanes
+    budget = _VMEM_BUDGET_PAIR if rope else _VMEM_BUDGET
     if block_q is None and block_k is None:
         seq_pad = -(-seq // LANES) * LANES
         block_q = block_k = next(
             b for b in range(seq_pad, 0, -LANES) if seq_pad % b == 0
             and _vmem_estimate(b, b, min(b, _MAX_TILE), min(b, _MAX_STEP),
-                               lanes, g, itemsize) <= _VMEM_BUDGET)
+                               held, g, itemsize) <= budget)
     else:
         block_q = min(block_q if block_q is not None else block_k, seq)
         block_k = min(block_k if block_k is not None else block_q, seq)
@@ -288,7 +350,7 @@ def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
         tile_q = _band_tile(tile_q, step_k, window)
         tile_k = _band_tile(tile_k, step_q, window)
     vmem = _vmem_estimate(block_q, block_k, max(tile_q, tile_k),
-                          max(step_q, step_k), lanes, g, itemsize)
+                          max(step_q, step_k), held, g, itemsize)
     return TilePlan(seq_pad, block_q, block_k, tile_q, tile_k, step_q,
                     step_k, vmem, g, lanes)
 
@@ -421,15 +483,66 @@ def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit,
                     lambda d, j: visit(0, True, j, None))
 
 
+def _q_walk(col0, iq, last, causal: bool, plan: TilePlan, valid_len, visit,
+            window: int = 0):
+    """dkv: walk the query steps of query block ``iq`` that see the key tile
+    starting at column ``col0``, of the ``last`` steps with a real row.
+    ``visit(size, masked, lo, hi)`` takes local steps [lo, hi) (``hi`` None:
+    the one step ``lo``) with the tile's first ``size`` keys.  The steps
+    after the diagonal see the tile whole, in one run up to the last real
+    row (the rows past it carry a zero dO) or, under a band, up to its far
+    edge, which cuts the last steps of the walk as the diagonal cuts the
+    first; of the static ``tile_k // step_q`` steps the diagonal crosses,
+    the d-th sees the tile's first ``(d + 1) * step_q`` keys only.  Without
+    a causal mask every step sees the whole tile, and padded keys are masked
+    in each."""
+    tile, step = plan.tile_k, plan.step_q
+    n = plan.block_q // step
+    first = iq * n
+    hi = jnp.clip(last - first, 0, n)
+    if causal:
+        on_diag, count = col0 // step, tile // step
+        _diag_steps(on_diag, count, first, n, last,
+                    lambda d, j: visit((d + 1) * step, True, j, None))
+        lo = jnp.clip(on_diag + count - first, 0, hi)
+        if window:
+            # The query steps that see the tile whole end where a step's
+            # last row no longer sees the tile's first key; those from
+            # there to the last row that sees the tile's last key are cut
+            # by the band's far edge.
+            whole = jnp.clip(jnp.maximum(col0 + window - step, 0)
+                             // step + 1 - first, lo, hi)
+            visit(tile, False, lo, whole)
+            visit(tile, True, whole, jnp.clip(
+                (col0 + tile + window - 2) // step + 1 - first, whole, hi))
+        else:
+            visit(tile, False, lo, hi)
+    elif isinstance(valid_len, int):
+        visit(tile, valid_len < plan.seq_pad, 0, hi)
+    else:
+        # A length read in the kernel: a tile of real keys alone takes no
+        # mask, a tile wholly beyond the length no step.
+        whole = col0 + tile <= valid_len
+        pl.when(whole)(lambda: visit(tile, False, 0, hi))
+        pl.when(jnp.logical_and(jnp.logical_not(whole), col0 < valid_len))(
+            lambda: visit(tile, True, 0, hi))
+
+
 def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
             transposed: bool = False, bd: int = 0, strict=0,
-            own: bool = False, window: int = 0):
+            own: bool = False, window: int = 0, rope=None):
     """The float32 score tile q @ k^T * sm_scale ([Tq, Tk]; or its
     transpose k @ q^T), masked where the diagonal or the tail padding
-    crosses it.  The dot takes its operands as they arrive."""
+    crosses it.  The dot takes its operands as they arrive.  ``rope``: the
+    pair ``(q_rope, k_rope)`` of a second product, added in float32 before
+    the scale."""
     a, b = (k, q) if transposed else (q, k)
-    s = jax.lax.dot_general(a, b, _NT,
-                            preferred_element_type=jnp.float32) * sm_scale
+    s = jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+    if rope is not None:
+        a, b = rope[::-1] if transposed else rope
+        s = s + jax.lax.dot_general(a, b, _NT,
+                                    preferred_element_type=jnp.float32)
+    s = s * sm_scale
     if masked:
         q_axis, k_axis = (1, 0) if transposed else (0, 1)
         kpos = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
@@ -499,6 +612,12 @@ def _head_lanes(plan: TilePlan):
     return [slice(g * d, (g + 1) * d) for g in range(plan.heads_per_block)]
 
 
+def _rope_lanes(plan: TilePlan, rope: int):
+    """The lanes of each head's rotary part in a ``q_rope`` block."""
+    return [slice(g * rope, (g + 1) * rope)
+            for g in range(plan.heads_per_block)]
+
+
 def _only(x, h, plan: TilePlan):
     """``x`` [T, lanes] with the lanes of every head but ``h`` zeroed: as
     an operand of a dot it contributes head ``h`` alone, and the zeros ride
@@ -531,9 +650,12 @@ def _mha_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, causal: bool,
 
     ``rest``: the outputs o, lse and the scratch acc^T, m, l; before them,
     in a block-diffusion call, the noised copy's own k and v at the resident
-    query block's positions (:func:`_own_side`)."""
+    query block's positions (:func:`_own_side`), or, in a call with a second
+    score operand, ``q_rope``'s block and ``k_rope``'s."""
     if variant.bd:
         k_own_ref, v_own_ref, *rest = rest
+    if variant.rope:
+        qr_ref, kr_ref, *rest = rest
     o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     iq, jk = pl.program_id(1), pl.program_id(2)
     n_kv = pl.num_programs(2)
@@ -586,16 +708,22 @@ def _mha_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, causal: bool,
             def visit(off, masked, lo, hi):
                 rows = pl.ds(start + off, tile - off)
                 qs = [q_ref[rows, h] for h in heads]        # [Tq, D] a head
+                qrs = [qr_ref[rows, r] for r in _rope_lanes(
+                    plan, variant.rope)] if variant.rope else None
 
                 def body(j, state):
                     cols = pl.ds(pl.multiple_of(j * step, step), step)
                     col0 = jk * plan.block_k + j * step
+                    kr = kr_ref[cols, :] if qrs else None   # [Tk, r]
+                    ropes = ([(qr, kr) for qr in qrs] if qrs
+                             else [None] * len(qs))
                     new = []
-                    for q, h, (m, l, acc) in zip(qs, heads, state):
+                    for q, rope, h, (m, l, acc) in zip(qs, ropes, heads,
+                                                       state):
                         # m, l [1, Tq]; acc [D, Tq]
                         v = v_ref[cols, h]                  # [Tk, D]
                         s = score(q, k_ref[cols, h], row0 + off, col0,
-                                  masked)
+                                  masked, rope=rope)
                         m_new = jnp.maximum(
                             m, jnp.max(s, axis=0, keepdims=True))
                         p = jnp.exp(s - m_new)              # [Tk, Tq]
@@ -721,9 +849,10 @@ def _named(variant: _Variant, kernel: str) -> dict:
     """The kernel arguments a grouped or block-diffusion call adds: the
     variant, and a name by which a trace tells its three kernels apart
     (``hvd_flash_fwd`` / ``_dq`` / ``_dkv``; ``hvd_flash_swa_fwd`` / ``_dq``
-    / ``_dkv`` under a band).  A plain call adds neither, so its kernels
+    / ``_dkv`` under a band, ``hvd_flash_mla_fwd`` / ``_dq`` / ``_dkv`` with
+    a second score operand).  A plain call adds neither, so its kernels
     compile to what they were."""
-    band = "swa_" if variant.window else ""
+    band = "swa_" if variant.window else "mla_" if variant.rope else ""
     return {} if variant == _PLAIN else {
         "variant": variant, "name": f"hvd_flash_{band}{kernel}"}
 
@@ -758,12 +887,29 @@ def _kernel_call(kernel, lens, *, grid, in_specs, out_specs, out_shape,
 # (the step is traced in every process; that time is part of a job's
 # start).  ``inline`` leaves no call in the jaxpr, so an op's name keeps the
 # scope of the layer that made it.
+def _rope_specs(variant: _Variant, plan: TilePlan, n_col: int, q_block,
+                k_block):
+    """The block specs of ``q_rope`` [N, S, n_col * G * r] (``q_block``
+    names its block along the sequence) and of ``k_rope`` [N, S, r], one key
+    for every head of a sequence: the array's whole minor dimension, read
+    where it lies by each grid row."""
+    r = variant.rope
+    qr = pl.BlockSpec(
+        (None, plan.block_q, plan.heads_per_block * r),
+        lambda row, i, j: (row // n_col, q_block(row, i, j, ()), row % n_col))
+    kr = pl.BlockSpec(
+        (None, plan.block_k, r),
+        lambda row, i, j: (row // n_col, k_block(row, i, j, ()), 0))
+    return qr, kr
+
+
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 9), inline=True)
 def _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret, valid_len,
-               lens=None, variant=_PLAIN):
+               lens=None, variant=_PLAIN, rope=()):
     """Forward kernel over operands [N, S, n_col * lanes] (S already
     padded; see :func:`_operand_spec`): out likewise + the rows' lse
-    [BH / G, G, S]."""
+    [BH / G, G, S].  ``rope``: ``(q_rope, k_rope)`` in the kernels' layout
+    (:func:`_rope_specs`) where ``variant.rope``."""
     n, s, width = qb.shape
     n_col, g = width // plan.lanes, plan.heads_per_block
     bq, bk = plan.block_q, plan.block_k
@@ -772,12 +918,16 @@ def _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret, valid_len,
                             _streamed_k(causal, plan, valid_len,
                                         variant.window),
                             _kv_row_of(variant))
-    own_specs, own_kv = _own_kv(variant, plan, kb, vb)
+    # What the variant's mask or second operand adds to q, k and v.
+    more_specs, more = (
+        (_rope_specs(variant, plan, n_col, _by_i,
+                     _streamed_k(causal, plan, valid_len)), rope)
+        if variant.rope else _own_kv(variant, plan, kb, vb))
     return _kernel_call(
         _mha_kernel, lens, sm_scale=sm_scale, causal=causal, plan=plan,
         valid_len=valid_len, interpret=interpret, **_named(variant, "fwd"),
         grid=(n * n_col, s // bq, s // bk),
-        in_specs=[q_spec, kv_spec, kv_spec, *own_specs],
+        in_specs=[q_spec, kv_spec, kv_spec, *more_specs],
         out_specs=[q_spec, _row_stat_spec(plan, _by_i)],
         out_shape=[
             _out_struct(qb.shape, qb.dtype, qb),
@@ -788,7 +938,7 @@ def _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret, valid_len,
             pltpu.VMEM((g, bq), jnp.float32),
             pltpu.VMEM((g, bq), jnp.float32),
         ],
-    )(qb, kb, vb, *own_kv)
+    )(qb, kb, vb, *more)
 
 
 def _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan: TilePlan):
@@ -926,13 +1076,8 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     broadcast down the sublanes as they arrive and all four dots are
     plain: no operand is transposed on the way to the MXU.
 
-    A key tile at column ``col0`` is seen whole by the query steps after
-    the diagonal, in one run up to the last real row (the rows past it
-    carry a zero dO) or, under a band, up to its far edge, which cuts the
-    last steps of the walk as the diagonal cuts the first; of the static
-    ``tile_k // step_q`` steps the diagonal crosses, the d-th sees the
-    tile's first ``(d + 1) * step_q`` keys only.  Without a causal mask every step sees the whole tile, and
-    padded keys are masked in each.
+    Which query steps a key tile meets, and how much of it each sees:
+    :func:`_q_walk`.
 
     Grouped queries (``variant.group`` query heads read this key/value
     head): the streamed axis walks the query blocks of one head after
@@ -957,7 +1102,6 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     iq = t if variant.group == 1 else t % (pl.num_programs(2)
                                            // variant.group)
     tile, step = plan.tile_k, plan.step_q
-    n = plan.block_q // step
     last = _steps(valid_len, step)
     heads = _head_lanes(plan)
     strict, window = _strict(variant, variant.kv_rows), variant.window
@@ -1050,36 +1194,7 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
                 dk_acc[cols, :], dv_acc[cols, :] = _run(
                     body, lo, hi, (dk_acc[cols, :], dv_acc[cols, :]))
 
-            first = iq * n
-            hi = jnp.clip(last - first, 0, n)
-            if causal:
-                on_diag, count = col0 // step, tile // step
-                _diag_steps(on_diag, count, first, n, last,
-                            lambda d, j: visit((d + 1) * step, True, j, None))
-                lo = jnp.clip(on_diag + count - first, 0, hi)
-                if window:
-                    # The query steps that see the tile whole end where a
-                    # step's last row no longer sees the tile's first key;
-                    # those from there to the last row that sees the tile's
-                    # last key are cut by the band's far edge.
-                    whole = jnp.clip(jnp.maximum(col0 + window - step, 0)
-                                     // step + 1 - first, lo, hi)
-                    visit(tile, False, lo, whole)
-                    visit(tile, True, whole, jnp.clip(
-                        (col0 + tile + window - 2) // step + 1 - first,
-                        whole, hi))
-                else:
-                    visit(tile, False, lo, hi)
-            elif isinstance(valid_len, int):
-                visit(tile, valid_len < plan.seq_pad, 0, hi)
-            else:
-                # A length read in the kernel: a tile of real keys alone
-                # takes no mask, a tile wholly beyond the length no step.
-                whole = col0 + tile <= valid_len
-                pl.when(whole)(lambda: visit(tile, False, 0, hi))
-                pl.when(jnp.logical_and(jnp.logical_not(whole),
-                                        col0 < valid_len))(
-                    lambda: visit(tile, True, 0, hi))
+            _q_walk(col0, iq, last, causal, plan, valid_len, visit, window)
 
         jax.lax.fori_loop(0, plan.block_k // tile, k_tile, None)
 
@@ -1198,14 +1313,19 @@ def _flash_lse(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
 CHECKPOINT_NAMES = ("hvd_flash_out", "hvd_flash_lse")
 
 
+def _named_residuals(out, lse):
+    """The forward kernel's two results under their checkpoint names.  Named
+    in the forward rules and nowhere further out: the backward kernels read
+    the residuals, ``lse`` among them, which never leaves some callers."""
+    return (checkpoint_name(out, "hvd_flash_out"),
+            checkpoint_name(lse, "hvd_flash_lse"))
+
+
 def _flash_lse_fwd(qb, kb, vb, lens, sm_scale, causal, plan, interpret,
                    valid_len, variant):
-    out, lse = _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret,
-                          valid_len, lens, variant)
-    # Named here and nowhere further out: the backward kernels read the
-    # residuals, ``lse`` among them, which never leaves some callers.
-    out = checkpoint_name(out, "hvd_flash_out")
-    lse = checkpoint_name(lse, "hvd_flash_lse")
+    out, lse = _named_residuals(*_flash_fwd(
+        qb, kb, vb, sm_scale, causal, plan, interpret, valid_len, lens,
+        variant))
     return (out, lse), (qb, kb, vb, lens, out, lse)
 
 
@@ -1236,6 +1356,256 @@ def _flash_lse_bwd(sm_scale, causal, plan, interpret, valid_len, variant,
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+def _mla_dq_kernel(q_ref, k_ref, v_ref, qr_ref, kr_ref, do_ref, o_ref,
+                   lse_ref, dlse_ref, dq_ref, dqr_ref, acc_ref, accr_ref,
+                   delta_ref, *, sm_scale: float, causal: bool,
+                   plan: TilePlan, valid_len, variant: _Variant):
+    """dQ and dQ_rope of a call with a second score operand: the grid, the
+    walk and the statistics of :func:`_mha_bwd_dq_kernel`.  A head's q, dO, k
+    and v are slices of whole lane tiles of their blocks and its rotary part
+    a slice of ``q_rope``'s; each head is a chain of its own with its own
+    accumulators, dq's [Tq, D] in its lanes of ``acc_ref`` and dq_rope's
+    [Tq, r] in ``accr_ref[g]``, which leave side by side through the
+    transposes the forward's output takes."""
+    iq, jk = pl.program_id(1), pl.program_id(2)
+    tile, step = plan.tile_q, plan.step_k
+    heads, ropes = _head_lanes(plan), _rope_lanes(plan, variant.rope)
+    score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
+                              valid_len=valid_len)
+
+    @pl.when(jk == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        accr_ref[:] = jnp.zeros_like(accr_ref)
+        _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan)
+
+    @pl.when(_block_live(iq, jk, causal, plan, valid_len))
+    def _compute():
+        def q_tile(c, _):
+            start = pl.multiple_of(c * tile, tile)
+            row0 = iq * plan.block_q + c * tile
+
+            def visit(off, masked, lo, hi):
+                rows = pl.ds(start + off, tile - off)
+                tile_of = [(q_ref[rows, h], qr_ref[rows, r], do_ref[rows, h],
+                            _row_to_col(lse_ref[g:g + 1, rows]),
+                            _row_to_col(delta_ref[g:g + 1, rows]))
+                           for g, (h, r) in enumerate(zip(heads, ropes))]
+
+                def body(j, state):
+                    cols = pl.ds(pl.multiple_of(j * step, step), step)
+                    col0 = jk * plan.block_k + j * step
+                    kr = kr_ref[cols, :]                    # [Tk, r]
+                    new = []
+                    for (q, qr, do, lse, delta), h, (acc, accr) in zip(
+                            tile_of, heads, state):
+                        k = k_ref[cols, h]                  # [Tk, D]
+                        s = score(q, k, row0 + off, col0, masked,
+                                  rope=(qr, kr))
+                        p = jnp.exp(s - lse)                # [Tq, Tk]
+                        dp = jax.lax.dot_general(
+                            do, v_ref[cols, h], _NT,
+                            preferred_element_type=jnp.float32)
+                        ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
+                        new.append((
+                            acc + jnp.dot(
+                                ds, k, preferred_element_type=jnp.float32),
+                            accr + jnp.dot(
+                                ds, kr, preferred_element_type=jnp.float32)))
+                    return tuple(new)
+
+                state = _run(body, lo, hi, tuple(
+                    (acc_ref[rows, h], accr_ref[g, rows, :])
+                    for g, h in enumerate(heads)))
+                for g, (h, (acc, accr)) in enumerate(zip(heads, state)):
+                    acc_ref[rows, h] = acc
+                    accr_ref[g, rows, :] = accr
+
+            _k_walk(row0, jk, causal, plan, valid_len, visit)
+
+        jax.lax.fori_loop(0, plan.block_q // tile, q_tile, None)
+
+    @pl.when(jk == pl.num_programs(2) - 1)
+    def _flush():
+        dq_ref[:] = acc_ref[:].astype(dq_ref.dtype)
+
+        def q_tile(c, _):
+            rows = pl.ds(pl.multiple_of(c * tile, tile), tile)
+            parts = [jnp.transpose(accr_ref[g, rows, :])   # [r, Tq] a head
+                     for g in range(len(heads))]
+            dqr_ref[rows, :] = jnp.transpose(
+                jnp.concatenate(parts, axis=0)).astype(dqr_ref.dtype)
+
+        jax.lax.fori_loop(0, plan.block_q // tile, q_tile, None)
+
+
+def _mla_dkv_kernel(q_ref, k_ref, v_ref, qr_ref, kr_ref, do_ref, o_ref,
+                    lse_ref, dlse_ref, dk_ref, dv_ref, dkr_ref, dk_acc,
+                    dv_acc, dkr_acc, delta_ref, *, sm_scale: float,
+                    causal: bool, plan: TilePlan, valid_len,
+                    variant: _Variant):
+    """dK, dV and this grid row's part of dK_rope of a call with a second
+    score operand: the grid and the walk of :func:`_mha_bwd_dkv_kernel`
+    (score tiles transposed, [Tk, Tq]).  A head's k, v, q and dO are slices
+    of whole lane tiles, each head a chain of its own into its lanes of the
+    [Tk, lanes] accumulators; what the block's heads owe the one rotary key
+    gathers in one [Tk, r] float32 accumulator and leaves as this grid row's
+    partial, which the caller sums over a sequence's rows."""
+    jk, iq = pl.program_id(1), pl.program_id(2)
+    tile, step = plan.tile_k, plan.step_q
+    last = _steps(valid_len, step)
+    heads, ropes = _head_lanes(plan), _rope_lanes(plan, variant.rope)
+    score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
+                              valid_len=valid_len, transposed=True)
+
+    @pl.when(iq == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dkr_acc[:] = jnp.zeros_like(dkr_acc)
+
+    @pl.when(_block_live(iq, jk, causal, plan, valid_len))
+    def _compute():
+        _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan)
+
+        def k_tile(c, _):
+            start = pl.multiple_of(c * tile, tile)
+            col0 = jk * plan.block_k + c * tile
+
+            def visit(size, masked, lo, hi):
+                cols = pl.ds(start, size)
+                kr = kr_ref[cols, :]                        # [Tk, r]
+                kv = [(k_ref[cols, h], v_ref[cols, h]) for h in heads]
+
+                def body(j, state):
+                    rows = pl.ds(pl.multiple_of(j * step, step), step)
+                    row0 = iq * plan.block_q + j * step
+                    *per_head, dkr = state
+                    new = []
+                    for g, (h, r, (k, v), (dk, dv)) in enumerate(zip(
+                            heads, ropes, kv, per_head)):
+                        q, qr, do = (q_ref[rows, h], qr_ref[rows, r],
+                                     do_ref[rows, h])
+                        s = score(q, k, row0, col0, masked, rope=(qr, kr))
+                        p = jnp.exp(s - lse_ref[g:g + 1, rows])  # [Tk, Tq]
+                        dp = jax.lax.dot_general(
+                            v, do, _NT, preferred_element_type=jnp.float32)
+                        ds = (p * (dp - delta_ref[g:g + 1, rows])
+                              * sm_scale).astype(q.dtype)
+                        new.append((
+                            dk + jnp.dot(
+                                ds, q, preferred_element_type=jnp.float32),
+                            dv + jnp.dot(
+                                p.astype(do.dtype), do,
+                                preferred_element_type=jnp.float32)))
+                        dkr = dkr + jnp.dot(
+                            ds, qr, preferred_element_type=jnp.float32)
+                    return (*new, dkr)
+
+                *per_head, dkr = _run(body, lo, hi, (
+                    *((dk_acc[cols, h], dv_acc[cols, h]) for h in heads),
+                    dkr_acc[cols, :]))
+                for h, (dk, dv) in zip(heads, per_head):
+                    dk_acc[cols, h] = dk
+                    dv_acc[cols, h] = dv
+                dkr_acc[cols, :] = dkr
+
+            _q_walk(col0, iq, last, causal, plan, valid_len, visit)
+
+        jax.lax.fori_loop(0, plan.block_k // tile, k_tile, None)
+
+    @pl.when(iq == pl.num_programs(2) - 1)
+    def _flush():
+        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+        dkr_ref[:] = dkr_acc[:]
+
+
+@functools.partial(jax.jit, static_argnums=(9, 10, 11, 12, 13, 14),
+                   inline=True)
+def _mla_bwd(qb, kb, vb, qrb, krb, ob, lse, dob, dlse, sm_scale, causal,
+             plan, interpret, valid_len, variant):
+    """The two backward kernels of a call with a second score operand:
+    ``(dq, dk, dv, dq_rope, dk_rope)``, the last summed over a sequence's
+    grid rows in float32."""
+    n, s, width = qb.shape
+    n_col, g, r = width // plan.lanes, plan.heads_per_block, variant.rope
+    bq, bk = plan.block_q, plan.block_k
+    lse, dlse = lse.astype(jnp.float32), dlse.astype(jnp.float32)
+    delta_scratch = pltpu.VMEM((g, bq), jnp.float32)
+    kernel_args = dict(sm_scale=sm_scale, causal=causal, plan=plan,
+                       valid_len=valid_len, interpret=interpret)
+    streamed_k = _streamed_k(causal, plan, valid_len)
+    q_by_i = _operand_spec(bq, plan, n_col, _by_i)
+    kv_by_j = _operand_spec(bk, plan, n_col, streamed_k)
+    row_by_i = _row_stat_spec(plan, _by_i)
+    qr_by_i, kr_by_j = _rope_specs(variant, plan, n_col, _by_i, streamed_k)
+    dq, dqr = _kernel_call(
+        _mla_dq_kernel, None, **kernel_args, **_named(variant, "dq"),
+        grid=(n * n_col, s // bq, s // bk),
+        in_specs=[q_by_i, kv_by_j, kv_by_j, qr_by_i, kr_by_j, q_by_i, q_by_i,
+                  row_by_i, row_by_i],
+        out_specs=[q_by_i, qr_by_i],
+        out_shape=[_out_struct(qb.shape, qb.dtype, qb),
+                   _out_struct(qrb.shape, qrb.dtype, qrb)],
+        scratch_shapes=[pltpu.VMEM((bq, plan.lanes), jnp.float32),
+                        pltpu.VMEM((g, bq, r), jnp.float32), delta_scratch],
+    )(qb, kb, vb, qrb, krb, dob, ob, lse, dlse)
+
+    def streamed_q(row, i, j, lens):
+        first = (i * bk) // bq if causal else 0
+        return jnp.minimum(jnp.maximum(j, first),
+                           _last_live_q(i, plan, valid_len, 0))
+
+    q_by_j = _operand_spec(bq, plan, n_col, streamed_q)
+    kv_by_i = _operand_spec(bk, plan, n_col, _by_i)
+    row_by_j = _row_stat_spec(plan, streamed_q)
+    qr_by_j, kr_by_i = _rope_specs(variant, plan, n_col, streamed_q, _by_i)
+    part_by_i = pl.BlockSpec((None, bk, r), lambda row, i, j: (row, i, 0))
+    accumulator = pltpu.VMEM((bk, plan.lanes), jnp.float32)
+    dk, dv, dkr = _kernel_call(
+        _mla_dkv_kernel, None, **kernel_args, **_named(variant, "dkv"),
+        grid=(n * n_col, s // bk, s // bq),
+        in_specs=[q_by_j, kv_by_i, kv_by_i, qr_by_j, kr_by_i, q_by_j, q_by_j,
+                  row_by_j, row_by_j],
+        out_specs=[kv_by_i, kv_by_i, part_by_i],
+        out_shape=[_out_struct(kb.shape, kb.dtype, kb),
+                   _out_struct(vb.shape, vb.dtype, vb),
+                   _out_struct((n * n_col, s, r), jnp.float32, kb)],
+        scratch_shapes=[accumulator, accumulator,
+                        pltpu.VMEM((bk, r), jnp.float32), delta_scratch],
+    )(qb, kb, vb, qrb, krb, dob, ob, lse, dlse)
+    dkr = jnp.sum(dkr.reshape(n, n_col, s, r), axis=1).astype(krb.dtype)
+    return dq, dk, dv, dqr, dkr
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_mla(qb, kb, vb, qrb, krb, sm_scale, causal, plan, interpret,
+               valid_len, variant):
+    """:func:`_flash_lse` with a second score operand: ``q_rope`` [N, S,
+    n_col * G * r] and ``k_rope`` [N, S, r] in the kernels' layout."""
+    return _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret,
+                      valid_len, None, variant, (qrb, krb))
+
+
+def _flash_mla_fwd(qb, kb, vb, qrb, krb, sm_scale, causal, plan, interpret,
+                   valid_len, variant):
+    out, lse = _named_residuals(*_flash_fwd(
+        qb, kb, vb, sm_scale, causal, plan, interpret, valid_len, None,
+        variant, (qrb, krb)))
+    return (out, lse), (qb, kb, vb, qrb, krb, out, lse)
+
+
+def _flash_mla_bwd(sm_scale, causal, plan, interpret, valid_len, variant,
+                   res, cotangents):
+    qb, kb, vb, qrb, krb, ob, lse = res
+    return _mla_bwd(qb, kb, vb, qrb, krb, ob, lse, *cotangents, sm_scale,
+                    causal, plan, interpret, valid_len, variant)
+
+
+_flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
 
 
 def _kv_lens(kv_lens, batch: int, seq: int, causal: bool):
@@ -1305,16 +1675,52 @@ def _window(window, seq: int, causal: bool, kv_lens, block_diffusion):
     return None if window >= seq else int(window)
 
 
+def _rope_pair(q, k, q_rope, k_rope, kv_lens, block_diffusion, window):
+    """The width of a call's second score operand, 0 without the pair:
+    ``q_rope`` [B, S, H, r] on ``k_rope`` [B, S, 1, r], one key for every
+    head, checked against the call it came with."""
+    if q_rope is None and k_rope is None:
+        return 0
+    if q_rope is None or k_rope is None:
+        raise ValueError("q_rope and k_rope come as a pair")
+    for what, given in (("kv_lens", kv_lens is not None),
+                        ("window", window is not None),
+                        ("block_diffusion", block_diffusion is not None),
+                        ("grouped key/value heads",
+                         k.shape[2] != q.shape[2])):
+        if given:
+            raise ValueError(f"q_rope / k_rope with {what} is not built "
+                             "(ROADMAP Reach B2)")
+    r = q_rope.shape[-1]
+    if q_rope.shape != q.shape[:3] + (r,) or k_rope.shape != (
+            *k.shape[:2], 1, r):
+        raise ValueError(
+            f"q_rope {q_rope.shape} against q {q.shape}, k_rope "
+            f"{k_rope.shape} against k {k.shape}: a rotary part a query "
+            "head, and one rotary key for all heads")
+    return r
+
+
+def _default_scale(q, rope: int) -> float:
+    """1 / sqrt of the scores' width: q's and, with the pair, q_rope's."""
+    return (q.shape[-1] + rope) ** -0.5
+
+
 def _dense(q, k, v, causal, scale, kv_lens, block_diffusion=None,
-           window=None):
+           window=None, q_rope=None, k_rope=None):
     b, s = q.shape[:2]
+    rope = _rope_pair(q, k, q_rope, k_rope, kv_lens, block_diffusion, window)
     window = _window(window, s, causal, kv_lens, block_diffusion)
-    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    scale = _default_scale(q, rope) if scale is None else scale
     group = _group(q, k, v)
     if group > 1:
         k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
+                        preferred_element_type=jnp.float32)
+    if rope:
+        logits = logits + jnp.einsum("bqhr,bkr->bhqk", q_rope, k_rope[:, :, 0],
+                                     preferred_element_type=jnp.float32)
+    logits = logits * scale
     if block_diffusion is not None:
         mask = block_diffusion_mask(
             *_block_diffusion(block_diffusion, s, causal, kv_lens))
@@ -1340,19 +1746,23 @@ def _dense(q, k, v, causal, scale, kv_lens, block_diffusion=None,
 
 def dense_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, kv_lens=None,
-                    block_diffusion=None, window: Optional[int] = None):
+                    block_diffusion=None, window: Optional[int] = None,
+                    q_rope=None, k_rope=None):
     """Reference-math dense attention over [B, S, H, D] (fp32 softmax).
-    ``kv_lens``, ``block_diffusion``, ``window`` and fewer key/value heads
-    than query heads as in :func:`flash_attention`."""
-    out, _ = _dense(q, k, v, causal, scale, kv_lens, block_diffusion, window)
+    ``kv_lens``, ``block_diffusion``, ``window``, the pair ``q_rope`` /
+    ``k_rope`` and fewer key/value heads than query heads as in
+    :func:`flash_attention`."""
+    out, _ = _dense(q, k, v, causal, scale, kv_lens, block_diffusion, window,
+                    q_rope, k_rope)
     return out
 
 
 def dense_attention_with_lse(q, k, v, causal: bool = False,
-                             scale: Optional[float] = None):
+                             scale: Optional[float] = None, q_rope=None,
+                             k_rope=None):
     """Dense attention that also returns log-sum-exp [B, H, S] (the chunk
     statistic ring attention merges across hops)."""
-    return _dense(q, k, v, causal, scale, None)
+    return _dense(q, k, v, causal, scale, None, q_rope=q_rope, k_rope=k_rope)
 
 
 def _kernel_layout(plan: TilePlan, n: int, s_pad: int, d: int):
@@ -1381,17 +1791,21 @@ def _kernel_layout(plan: TilePlan, n: int, s_pad: int, d: int):
 
 
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret, kv_lens,
-           block_diffusion=None, window=None):
+           block_diffusion=None, window=None, q_rope=None, k_rope=None):
     """``(out, lse)`` of the kernels, or of the dense fallback off-TPU."""
     b, s, h, d = q.shape
+    rope = _rope_pair(q, k, q_rope, k_rope, kv_lens, block_diffusion, window)
     group = _group(q, k, v)
     window = _window(window, s, causal, kv_lens, block_diffusion)
     if interpret is None:
         if jax.default_backend() != "tpu":
             return _dense(q, k, v, causal, scale, kv_lens, block_diffusion,
-                          window)
+                          window, q_rope, k_rope)
         interpret = False
-    sm_scale = d ** -0.5 if scale is None else scale
+    sm_scale = _default_scale(q, rope) if scale is None else scale
+    if rope:
+        return _flash_rope_pair(q, k, v, q_rope, k_rope, causal, sm_scale,
+                                block_q, block_k, bool(interpret))
     if block_diffusion is not None:
         return _flash_block_diffusion(
             q, k, v, *_block_diffusion(block_diffusion, s, causal, kv_lens),
@@ -1421,6 +1835,29 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret, kv_lens,
     out = from_kernel(out)[:, :s]
     lse = lse.reshape(b, h, s_pad)[:, :, :s]
     return out, lse
+
+
+def _flash_rope_pair(q, k, v, q_rope, k_rope, causal, sm_scale, block_q,
+                     block_k, interpret):
+    """``(out, lse)`` of a call with a second score operand: the operands in
+    the model's own layout, heads side by side in the minor dimension (a
+    view), ``k_rope`` as its one [B, S, r] array."""
+    b, s, h, d = q.shape
+    r = q_rope.shape[-1]
+    plan = tile_plan(s, d, q.dtype.itemsize, causal, block_q, block_k,
+                     heads=h, rope=r)
+    s_pad = plan.seq_pad
+
+    def flat(x):
+        x = x.reshape(b, s, -1)
+        return x if s_pad == s else jnp.pad(
+            x, [(0, 0), (0, s_pad - s), (0, 0)])
+
+    out, lse = _flash_mla(*(flat(x) for x in (q, k, v, q_rope, k_rope)),
+                          sm_scale, causal, plan, interpret, s,
+                          _Variant(rope=r))
+    return (out[:, :s].reshape(b, s, h, d),
+            lse.reshape(b, h, s_pad)[:, :, :s])
 
 
 def _flash_block_diffusion(q, k, v, length, block, sm_scale, block_q,
@@ -1464,11 +1901,13 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
                              scale: Optional[float] = None,
                              block_q: Optional[int] = None,
                              block_k: Optional[int] = None,
-                             interpret: Optional[bool] = None):
+                             interpret: Optional[bool] = None, q_rope=None,
+                             k_rope=None):
     """Pallas attention over [B, S, H, D] returning ``(out, lse)`` with
     lse shaped [B, H, S].  Same dispatch rules as :func:`flash_attention`;
     off-TPU it falls back to :func:`dense_attention_with_lse`."""
-    return _flash(q, k, v, causal, scale, block_q, block_k, interpret, None)
+    return _flash(q, k, v, causal, scale, block_q, block_k, interpret, None,
+                  q_rope=q_rope, k_rope=k_rope)
 
 
 def flash_attention(q, k, v, causal: bool = False,
@@ -1476,7 +1915,8 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None, kv_lens=None,
-                    block_diffusion=None, window: Optional[int] = None):
+                    block_diffusion=None, window: Optional[int] = None,
+                    q_rope=None, k_rope=None):
     """Attention over q [batch, seq, heads, head_dim] and k, v [batch, seq,
     kv_heads, head_dim]; with fewer key/value heads than query heads, query
     head ``i`` reads key/value head ``i // (heads // kv_heads)``.
@@ -1499,7 +1939,14 @@ def flash_attention(q, k, v, causal: bool = False,
     ``window`` (causal calls only): query i sees the ``window`` keys ``i -
     window < j <= i``, its own among them; None, or a window of the whole
     sequence, is the causal call.
+
+    ``q_rope`` [batch, seq, heads, r] with ``k_rope`` [batch, seq, 1, r] (no
+    other mask than ``causal``, as many key/value heads as query heads): the
+    scores are ``scale x (q . k + q_rope . k_rope)``, the rotary key one for
+    all heads, and ``scale`` defaults to ``(head_dim + r) ** -0.5``; v and the
+    output stay ``head_dim`` wide.  Gradients reach both, ``k_rope``'s summed
+    over the heads.
     """
     out, _ = _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-                    kv_lens, block_diffusion, window)
+                    kv_lens, block_diffusion, window, q_rope, k_rope)
     return out

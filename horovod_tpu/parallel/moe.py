@@ -32,7 +32,9 @@ not) and is what ``models/sdar.py`` calls.  :func:`dispatch_experts` is the
 dropless layer alone, for a caller that routes for itself and brings each
 token's chosen experts and their weights: ``models/zaya.py``, whose router is
 a small MLP with a state carried down the layers, chooses under a balancing
-bias and gates by the unbiased probability, top-1 and not renormalised.
+bias and gates by the unbiased probability, top-1 and not renormalised; and
+``models/joyai.py``, whose scores are sigmoids: the top-8 of the scores plus
+a balancing bias, weighed by the scores without it, renormalised.
 ``routed_experts`` is ``route`` followed by that call.
 
 **Which product runs where**: on a TPU the three products, forward and

@@ -134,6 +134,18 @@ def test_flash_window_phase_tiny():
             for tag in tags} <= set(report)
 
 
+def test_flash_mla_phase_tiny():
+    """The flash kernels with a second score operand (interpreted) against
+    ``dense_attention`` a head at a time and against the kernels without the
+    pair, and the two times."""
+    report = chip_smoke.flash_mla(length=160, heads=2, repeats=1, chain=2,
+                                  interpret=True)
+    assert [c["name"] for c in report["checks"]] == [
+        "out", "dq", "dk", "dv", "dq_rope", "dk_rope", "concat192/out"]
+    assert all(c["ok"] for c in report["checks"])
+    assert {"fwd_ms", "fwd_bwd_ms"} <= set(report)
+
+
 def test_tied_head_phase_tiny():
     """The tied head's kernel (interpreted) against the ``jax.numpy`` product
     and statistics, alone and inside the whole head, at a table of whole

@@ -684,6 +684,86 @@ def test_a_laguna_step_copies_nothing_of_q_s_size_round_its_kernels(
     assert not copies, copies
 
 
+def test_a_joyai_step_copies_nothing_round_its_paired_kernels(topo,
+                                                              monkeypatch):
+    """The first two layers of ``joyai-mla-ep16-s16384`` at its widths (the
+    dense layer and an expert layer; 4 heads of 128 + 64 / 128, 896 dense
+    columns, 16 experts and 16,160 rows held) on a short sequence, a whole
+    step as ``families/joyai.py`` builds it: gradients, ``optax.adamw``
+    through ``DistributedOptimizer``, donated state, ``shard_map`` over one
+    described chip.  Each layer holds one call of each paired kernel by name
+    and no ``hvd_flash_relayout``; under the attention scope the step holds
+    **no copy and no transpose as large as q**, and outside the rotary turn
+    and the projections nothing as large as q_rope is broadcast, padded or
+    concatenated (``k_rope`` reaches the kernels as its one [B, S, 64] array,
+    never repeated a head); the paired kernels (``kv_b``, ``gate_up``,
+    ``shared_gate_up``) cross the step's boundary 2-D and nothing of their
+    size is copied."""
+    import optax
+    from jax import shard_map
+
+    import horovod_tpu as hvd
+    from benchmark.families.joyai import kernel_calls
+    from horovod_tpu.models import joyai
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(
+        joyai.JOYAI_LLM_FLASH, num_layers=2, num_heads_held=4,
+        dense_columns_held=896, num_experts_held=16, vocab_size_held=16160)
+    model, seq = joyai.JoyAI(cfg), 1024
+    tx = hvd.DistributedOptimizer(optax.adamw(2e-7), axis_name="hvd")
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("hvd",))
+
+    def train_step(variables, opt_state, ids):
+        rest = {k: v for k, v in variables.items() if k != "params"}
+        params = {"params": variables["params"]}
+        loss, grads = jax.value_and_grad(
+            lambda p: joyai.lm_loss(model, {**rest, **p}, ids))(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return ({**rest, **optax.apply_updates(params, updates)}, opt_state,
+                hvd.allreduce(loss, axis_name="hvd"))
+
+    ids = jax.ShapeDtypeStruct((1, seq), jnp.int32)
+    variables = jax.eval_shape(model.init, jax.random.key(0), ids)
+    state = (variables, jax.eval_shape(
+        lambda v: tx.init({"params": v["params"]}), variables))
+    paired = {"kv_b": (512, 2 * 512), "gate_up": (2048, 2 * 896),
+              "shared_gate_up": (2048, 2 * 768)}
+    seen = [leaf.shape for path, leaf in
+            jax.tree_util.tree_leaves_with_path(state)
+            if any(getattr(k, "key", None) in paired for k in path)]
+    assert sorted(seen) == sorted(
+        [paired["kv_b"]] * 8 + [paired["gate_up"]] * 4
+        + [paired["shared_gate_up"]] * 4)
+    text = jax.jit(
+        shard_map(train_step, mesh=mesh, in_specs=(P(), P(), P("hvd")),
+                  out_specs=(P(), P(), P())),
+        donate_argnums=(0, 1)).lower(
+            *_shapes_on(NamedSharding(mesh, P()), state),
+            _shapes_on(NamedSharding(mesh, P("hvd")), ids)).compile().as_text()
+    assert kernel_calls(text) == dict.fromkeys(
+        ("hvd_flash_mla_fwd", "hvd_flash_mla_dq", "hvd_flash_mla_dkv"), 2)
+    assert "hvd_flash_relayout" not in text
+    made = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                      r"(copy|transpose|broadcast|pad|concatenate)\(")
+    q, q_rope = seq * 4 * 128, seq * 4 * 64
+    round_the_kernels = [
+        line.strip()[:200] for line in text.splitlines()
+        for m in [made.match(line)]
+        if m and "/attn/" in line and (
+            math.prod(map(int, m.group(1).split(","))) >= q
+            if m.group(2) in ("copy", "transpose") else
+            math.prod(map(int, m.group(1).split(","))) >= q_rope
+            and not re.search(r"hvd_(rope|attn_proj|mla_latent)", line))]
+    assert not round_the_kernels, round_the_kernels
+    sizes = {math.prod(shape) for shape in paired.values()}
+    copies = [line.strip()[:160] for line in text.splitlines()
+              for m in [made.match(line)]
+              if m and m.group(2) == "copy"
+              and math.prod(map(int, m.group(1).split(","))) in sizes]
+    assert not copies, copies
+
+
 @pytest.mark.parametrize("codec", ["int8", "int4"])
 def test_codec_encode_decode_compiles(one_chip, codec):
     def roundtrip(flat):

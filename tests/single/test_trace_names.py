@@ -209,6 +209,54 @@ def test_a_laguna_step_names_what_its_layer_kinds_add():
     assert any("layer_1" in n and "hvd_moe_shared" in n for n in names)
 
 
+def test_a_joyai_step_names_what_latent_attention_adds():
+    """``models/joyai.py``'s scopes in the lowered step at the
+    configuration's rehearsal sizes: ``hvd_attn_proj`` holds the five latent
+    projections, ``hvd_mla_latent`` the two latent norms, ``hvd_rope`` the
+    rotary turn of q_rope and of the one k_rope, ``hvd_moe_route`` the
+    sigmoid router with ``parallel/moe.py``'s dispatch, ``hvd_moe_shared``
+    the shared expert, ``hvd_lm_head`` the final norm, the untied head and the
+    loss; both passes carry them, and the dense layer has none of the
+    mixture's."""
+    from benchmark.families import joyai
+    from horovod_tpu.models import joyai as model_joyai
+
+    cfg = run.load_json("configs", "joyai-llm-flash-ep16.json")
+    traffic = traffic_gen.resolve(
+        run.load_json("traffic", "joyai-causal-1x16384x1.json"),
+        rehearse=True)
+    mesh = common.hvd_mesh(jax.devices()[:1])
+    cell = joyai.setup(cfg, mesh, seed=3, rehearse=True)
+    (ids,) = traffic_gen.make_batches(traffic, joyai.inputs(cell, traffic),
+                                      mesh, seed=3)[0]
+    rest = {"balancing": cell["params"]["balancing"]}
+    names = _op_names(jax.jit(jax.grad(lambda p: model_joyai.lm_loss(
+        cell["model"], {**rest, "params": p}, ids))).lower(
+            cell["params"]["params"]).as_text(debug_info=True))
+
+    def under(scope, op, backward=False):
+        return any(re.search(rf"[/(]{scope}[/)]", n) and n.endswith(op)
+                   and ("transpose(" in n) == backward for n in names)
+
+    for backward in (False, True):
+        assert under("hvd_attn_proj", "dot_general", backward)
+        assert under("hvd_mla_latent", "mul", backward)
+        assert under("hvd_rope", "mul", backward)
+        assert under("hvd_moe_route", "", backward)
+        assert under("hvd_moe_experts", "", backward)
+        assert under("hvd_moe_shared", "dot_general", backward)
+        assert under("hvd_lm_head", "dot_general", backward)
+    assert under("hvd_moe_route", "logistic")
+    for kernel in ("q_a", "q_b_nope", "q_b_rope", "kv_a", "kv_b", "o_proj"):
+        assert any(f"/hvd_attn_proj/{kernel}/" in n for n in names), kernel
+    # The rotary tables are made under the turn's scope, not the products'.
+    assert not any("/hvd_attn_proj/" in n and n.endswith(("cos", "sin"))
+                   for n in names)
+    # Layer 0 is the dense one: nothing of the mixture's is in it.
+    assert not any("layer_0" in n and "hvd_moe" in n for n in names)
+    assert any("layer_1" in n and "hvd_moe_shared" in n for n in names)
+
+
 def test_eager_update_writes_the_spine_s_spans(hvd_single, tmp_path):
     path = _profile_eager_update(hvd_single, tmp_path)
     trace = trace_reduce.read_xplane(path, steps=1)

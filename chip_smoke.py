@@ -451,6 +451,18 @@ def grouped_products(tokens: int = 16384, d: int = 2048, f: int = 768,
         report[f"layer_fwd_bwd_ms/routed={share}"] = best_ms(
             dispatch, x, _choices(tokens, top_k, held, experts, routed), even,
             w_gate, w_up, w_down)
+    # The layer's backward starts from what its forward kept (and takes
+    # d weights from g . W_down^T): against plain reverse mode through the
+    # same buffer, at the timed size, half of it routed.
+    half = _choices(tokens, top_k, held, experts, buffer // 2)
+    plain = jax.jit(jax.grad(
+        lambda x, weights, *kernels: jnp.sum(moe._held_part(
+            buffer, x, moe._local(half, 0, held), weights, *kernels)[0].astype(
+                jnp.float32) ** 2), argnums=(0, 1, 2, 3, 4)))
+    for name, a, b in zip(("dx", "dweights", "dgate", "dup", "ddown"),
+                          dispatch(x, half, even, w_gate, w_up, w_down),
+                          plain(x, even, w_gate, w_up, w_down)):
+        _check(checks, f"kept_forward/{name}", a, b, TOL_BF16_BWD)
 
     def products(dot):
         """Gradients of the three products' sum with respect to the rows

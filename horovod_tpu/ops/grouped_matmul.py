@@ -346,3 +346,21 @@ def grouped_dot(rows, w, group_sizes, *, interpret=None):
     schedule = _schedule(group_sizes, rows.shape[0], _tile_rows(rows.shape[0]))
     return _grouped_dot(_vary_like(rows, w), _vary_like(w, rows), schedule,
                         interpret)
+
+
+def grouped_dot_grads(rows, w, group_sizes, g, *, interpret=None):
+    """``(d rows, dW)`` of ``grouped_dot(rows, w, group_sizes)`` under the
+    cotangent ``g`` [M, N], for a caller that kept the product's operands and
+    writes its own backward (``parallel/moe.py``): the two products that
+    ``grouped_dot``'s own reverse mode runs, and no forward product."""
+    if interpret is None:
+        if jax.default_backend() != "tpu":
+            def product(rows, w):
+                return lax.ragged_dot(rows, w.astype(rows.dtype), group_sizes)
+            return (*jax.linear_transpose(lambda r: product(r, w), rows)(g),
+                    *jax.linear_transpose(lambda k: product(rows, k), w)(g))
+        interpret = False
+    operands = (rows, w, g)
+    rows, w, g = (functools.reduce(_vary_like, operands, a) for a in operands)
+    schedule = _schedule(group_sizes, rows.shape[0], _tile_rows(rows.shape[0]))
+    return _grouped_dot_bwd(interpret, (rows, w, schedule), g)[:2]

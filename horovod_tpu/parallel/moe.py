@@ -26,6 +26,15 @@ buffer walks every row a router can send, in parts (a ``lax.cond``, taken
 while the step runs).  It has no exchange: what the other chips' experts
 would add is not there (ROADMAP Reach B1 keeps the all-to-all).
 
+**What is kept between forward and backward** (:func:`_dropless`): a step
+whose rows fit keeps the sorted choices, the groups' sizes, the rows'
+weights and the ``gate`` and ``up`` products (two ``[rows, f]`` arrays a
+layer), and its backward runs no sort and no product of the forward again;
+it gathers the ``[rows, d]`` rows again and takes ``d weights`` from the
+product it runs for ``d h``.  A step in parts keeps nothing of a buffer's
+size and its backward makes each part's forward again
+(:func:`forward_kept`).
+
 **Who routes.**  :func:`routed_experts` makes the choice itself
 (:func:`route`: a ``[d, experts]`` matrix, softmax, top-k, renormalised or
 not) and is what ``models/sdar.py`` calls.  :func:`dispatch_experts` is the
@@ -62,7 +71,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.collectives import axis_size, ensure_varying, vary_like
-from ..ops.grouped_matmul import TILE_ROWS, grouped_dot
+from ..ops.grouped_matmul import TILE_ROWS, grouped_dot, grouped_dot_grads
 
 
 def switch_moe(x, router_kernel, expert_fn: Callable, axis_name: str = "ep",
@@ -199,13 +208,35 @@ def row_buffer(tokens: int, top_k: int, held: int, experts: int,
 
 
 def _swiglu_rows(rows, group_sizes, w_gate, w_up, w_down):
-    """``down(silu(gate(x)) * up(x))`` of rows sorted by expert: three
-    grouped products, over the rows in a group and no others (the kernels
-    are read cast to the rows' dtype)."""
+    """``down(silu(gate(x)) * up(x))`` of rows sorted by expert, and the
+    ``gate(x)`` and ``up(x)`` it was made of: three grouped products, over
+    the rows in a group and no others (the kernels are read cast to the
+    rows' dtype)."""
     with jax.named_scope("hvd_moe_experts"):
         gate = grouped_dot(rows, w_gate, group_sizes)
         up = grouped_dot(rows, w_up, group_sizes)
-        return grouped_dot(jax.nn.silu(gate) * up, w_down, group_sizes)
+        return (grouped_dot(jax.nn.silu(gate) * up, w_down, group_sizes),
+                gate, up)
+
+
+def _swiglu_rows_grads(rows, group_sizes, gate, up, scale, g, w_gate, w_up,
+                       w_down):
+    """The reverse of ``scale x _swiglu_rows(rows, ...)`` under ``g``, the
+    cotangent of the weighted rows, from the ``gate`` and ``up`` the forward
+    made: ``(d rows, d scale, dW_gate, dW_up, dW_down)`` in six grouped
+    products, none of them the forward's.  ``down``'s result is not made
+    again for ``d scale = <out, g>``: that is ``<h, g . W_down^T>``, and
+    ``g . W_down^T`` is the product that ``d h = scale x`` it needs anyway."""
+    with jax.named_scope("hvd_moe_experts"):
+        h, h_vjp = jax.vjp(lambda gate, up: jax.nn.silu(gate) * up, gate, up)
+        weighted = (h.astype(jnp.float32) * scale[:, None]).astype(h.dtype)
+        back, dw_down = grouped_dot_grads(weighted, w_down, group_sizes, g)
+        back = back.astype(jnp.float32)
+        d_scale = jnp.sum(h.astype(jnp.float32) * back, axis=1)
+        d_gate, d_up = h_vjp((back * scale[:, None]).astype(h.dtype))
+        by_gate, dw_gate = grouped_dot_grads(rows, w_gate, group_sizes, d_gate)
+        by_up, dw_up = grouped_dot_grads(rows, w_up, group_sizes, d_up)
+        return by_gate + by_up, d_scale, dw_gate, dw_up, dw_down
 
 
 # Rows a trip of :func:`take_rows` / :func:`add_rows`: one of the kernels'
@@ -311,11 +342,22 @@ add_rows.defvjp(
                                       None))
 
 
+class _Kept(NamedTuple):
+    """What a forward through a buffer of [capacity] rows hands its
+    backward."""
+    order: jax.Array        # [capacity] int32: the choices, sorted by expert
+    sizes: jax.Array        # [held] int32: the rows routed to each expert
+    scale: jax.Array        # [capacity] float32: a row's weight, 0 past them
+    gate: jax.Array         # [capacity, f]
+    up: jax.Array           # [capacity, f]
+
+
 def _held_part(capacity: int, x, local, weights, w_gate, w_up, w_down):
     """The held experts' part of every token's sum through a row buffer of
-    ``capacity`` rows (at least as many as are routed here).  What is paid by
-    the row (the gather into the buffer, the sum back into the tokens, and
-    their transposes) walks the rows routed, as the products do."""
+    ``capacity`` rows (at least as many as are routed here), and what its
+    reverse (:func:`_held_grads`) starts from.  What is paid by the row (the
+    gather into the buffer, the sum back into the tokens, and their
+    transposes) walks the rows routed, as the products do."""
     tokens, top_k = local.shape
     held = w_gate.shape[0]
     with jax.named_scope("hvd_moe_route"):
@@ -329,14 +371,39 @@ def _held_part(capacity: int, x, local, weights, w_gate, w_up, w_down):
         # product is computed for them.
         single = min(top_k, held) == 1      # a token has one row at most
         rows = take_rows(x, token, routed, single)
-    out = _swiglu_rows(rows, sizes, w_gate, w_up, w_down)
+    out, gate, up = _swiglu_rows(rows, sizes, w_gate, w_up, w_down)
     with jax.named_scope("hvd_moe_route"):
         # Weighted in float32, summed back in the activations' dtype: a
         # token's sum has at most min(top_k, held) terms.
         scale = jnp.where(jnp.arange(capacity) < routed,
                           weights.reshape(-1)[order], 0.0)
         out = (out.astype(jnp.float32) * scale[:, None]).astype(x.dtype)
-        return add_rows(out, token, routed, tokens, single)
+        return (add_rows(out, token, routed, tokens, single),
+                _Kept(order, sizes, scale, gate, up))
+
+
+def _held_grads(x, weights, kept: _Kept, g, w_gate, w_up, w_down):
+    """``(dx, d weights, dW_gate, dW_up, dW_down)`` of :func:`_held_part`
+    under ``g`` [tokens, d], from what its forward kept: no sort, no count of
+    the groups and no product of the forward runs again.  The rows are
+    gathered again (a gather from ascending rows costs what its bytes do,
+    and the buffer they fill is the widest thing the forward makes)."""
+    top_k, held = weights.shape[1], w_gate.shape[0]
+    with jax.named_scope("hvd_moe_route"):
+        routed, token = jnp.sum(kept.sizes), kept.order // top_k
+        single = min(top_k, held) == 1
+        rows = take_rows(x, token, routed, single)
+        g = take_rows(g, token, routed, single)
+    d_rows, d_scale, *d_kernels = _swiglu_rows_grads(
+        rows, kept.sizes, kept.gate, kept.up, kept.scale, g, w_gate, w_up,
+        w_down)
+    with jax.named_scope("hvd_moe_route"):
+        live = jnp.arange(kept.order.shape[0]) < routed
+        d_weights = jnp.zeros((weights.size,), weights.dtype).at[
+            kept.order].add(jnp.where(live, d_scale, 0.0).astype(
+                weights.dtype))
+        return (add_rows(d_rows, token, routed, x.shape[0], single),
+                d_weights.reshape(weights.shape), *d_kernels)
 
 
 def _in_parts(parts: int, fn, summed: int, token_args, kernels):
@@ -354,32 +421,36 @@ def _in_parts(parts: int, fn, summed: int, token_args, kernels):
         mine = len(out) - summed
         return (tuple(t + o for t, o in zip(total, out[mine:])), out[:mine])
 
-    start = tuple(jnp.zeros_like(k) for k in kernels[len(kernels) - summed:])
+    start = tuple(_zeros(k.shape, k.dtype, *token_args, *kernels)
+                  for k in kernels[len(kernels) - summed:])
     total, per_token = lax.scan(body, start, tuple(map(split, token_args)))
     joined = tuple(a.reshape(-1, *a.shape[2:]) for a in per_token)
     return (*joined, *total) if summed or len(joined) > 1 else joined[0]
 
 
-def _fitting(rows: int, fn, summed: int, token_args, kernels):
-    """``fn(capacity, *token_args, *kernels)`` through a buffer of ``rows``
-    rows where the rows routed here fit it, chosen while the step runs
-    (``lax.cond``: the work done is the taken side's alone).  Where they do
-    not, every row a router can send is walked in the fewest parts that are
-    no larger than that buffer, so that a side that hardly ever runs is not
-    what the step's memory is sized by."""
-    local, tokens = token_args[1], token_args[0].shape[0]
-    held = kernels[0].shape[0]
-    worst = tokens * min(local.shape[1], held)
+def forward_kept(load_sum, capacity: int):
+    """Whether a layer's backward starts from what its forward made:
+    ``load_sum`` rows routed here fit a row buffer of ``capacity`` rows.
+    (Where they do not, the step walks every row a router can send in parts,
+    forward and backward, and keeps nothing of a buffer's size.)"""
+    return load_sum <= capacity
+
+
+def _sides(rows: int, local, held: int):
+    """``(worst, parts, fits)``: every row a router can send here; the fewest
+    parts no larger than a buffer of ``rows`` rows that they are walked in
+    where more are routed here than it holds, so that a side that hardly
+    ever runs is not what the step's memory is sized by; and whether the rows
+    routed here fit that buffer (:func:`forward_kept`, known while the step
+    runs).  ``parts`` and ``fits`` are None where the buffer holds every row
+    a router can send and nothing is chosen."""
+    tokens, top_k = local.shape
+    worst = tokens * min(top_k, held)
     if rows >= worst:
-        return fn(worst, *token_args, *kernels)
+        return worst, None, None
     parts = next(p for p in range(-(-worst // rows), tokens + 1)
                  if tokens % p == 0)
-    return lax.cond(
-        jnp.sum(local < held) <= rows, functools.partial(fn, rows),
-        lambda *a: _in_parts(parts, functools.partial(fn, worst // parts),
-                             summed, a[:len(token_args)],
-                             a[len(token_args):]),
-        *token_args, *kernels)
+    return worst, parts, forward_kept(jnp.sum(local < held), rows)
 
 
 # Inlined jits, as the flash kernels' (``ops/flash_attention.py``): a model
@@ -388,44 +459,87 @@ def _fitting(rows: int, fn, summed: int, token_args, kernels):
 # not once a block; ``inline`` leaves no call in the jaxpr, so an op keeps the
 # scope of the block that made it.
 @functools.partial(jax.jit, static_argnums=(0,), inline=True)
-def _forward(rows: int, x, local, weights, w_gate, w_up, w_down):
-    return _fitting(rows, _held_part, 0, (x, local, weights),
-                    (w_gate, w_up, w_down))
+def _forward(rows: int, x, local, weights, *kernels):
+    """``(y, kept)`` through a buffer of ``rows`` rows where the rows routed
+    here fit it, chosen while the step runs (``lax.cond``: the work done is
+    the taken side's alone); in parts where they do not, and then ``kept`` is
+    zeros, which cost a side that hardly ever runs nothing worth counting."""
+    worst, parts, fits = _sides(rows, local, kernels[0].shape[0])
+    if parts is None:
+        return _held_part(worst, x, local, weights, *kernels)
+    whole = functools.partial(_held_part, rows)
+
+    def in_parts(*args):
+        y = _in_parts(parts, lambda *a: _held_part(worst // parts, *a)[0], 0,
+                      args[:3], args[3:])
+        return y, jax.tree.map(
+            lambda k: ensure_varying(jnp.zeros(k.shape, k.dtype),
+                                     sorted(k.vma or ())),
+            jax.eval_shape(whole, *args)[1])
+
+    return lax.cond(fits, whole, in_parts, x, local, weights, *kernels)
 
 
 @functools.partial(jax.jit, static_argnums=(0,), inline=True)
-def _backward(rows: int, x, local, weights, g, *kernels):
-    def grads(capacity, x, local, weights, g, *kernels):
+def _backward(rows: int, x, local, weights, kept: _Kept, g, *kernels):
+    """The gradients of :func:`_forward`'s ``y`` under ``g``: from ``kept``
+    where the rows fit, and where they do not a part at a time, each part's
+    forward made again."""
+    worst, parts, fits = _sides(rows, local, kernels[0].shape[0])
+    if parts is None:
+        return _held_grads(x, weights, kept, g, *kernels)
+
+    def part_grads(x, local, weights, g, *kernels):
         _, vjp = jax.vjp(
-            lambda x, w, *k: _held_part(capacity, x, local, w, *k),
+            lambda x, w, *k: _held_part(worst // parts, x, local, w, *k)[0],
             x, weights, *kernels)
         return vjp(g)
 
-    return _fitting(rows, grads, len(kernels), (x, local, weights, g),
-                    kernels)
+    return lax.cond(
+        fits,
+        lambda x, local, weights, kept, g, *kernels: _held_grads(
+            x, weights, kept, g, *kernels),
+        lambda x, local, weights, kept, g, *kernels: _in_parts(
+            parts, part_grads, len(kernels), (x, local, weights, g), kernels),
+        x, local, weights, kept, g, *kernels)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _dropless(rows: int, x, local, weights, w_gate, w_up, w_down):
-    """``_held_part`` through a buffer of ``rows`` rows, or in parts over
-    all a router can send where more are routed here.  Forward and backward
-    each look at the rows themselves and nothing of a buffer's size is kept
-    between them: the backward recomputes the forward of the side it
-    takes."""
-    return _forward(rows, x, local, weights, w_gate, w_up, w_down)
+def _kept_between(rows: int, x, local, weights, w_gate, w_up, w_down):
+    return _forward(rows, x, local, weights, w_gate, w_up, w_down)[0]
 
 
-def _dropless_fwd(rows, *args):
-    return _dropless(rows, *args), args
+def _kept_between_fwd(rows, *args):
+    y, kept = _forward(rows, *args)
+    return y, (args, kept)
 
 
-def _dropless_bwd(rows, args, g):
-    x, local, weights, *kernels = args
-    dx, dweights, *dkernels = _backward(rows, x, local, weights, g, *kernels)
+def _kept_between_bwd(rows, saved, g):
+    (x, local, weights, *kernels), kept = saved
+    dx, dweights, *dkernels = _backward(rows, x, local, weights, kept, g,
+                                        *kernels)
     return (dx, None, dweights, *dkernels)
 
 
-_dropless.defvjp(_dropless_fwd, _dropless_bwd)
+_kept_between.defvjp(_kept_between_fwd, _kept_between_bwd)
+
+
+def _dropless(rows: int, x, local, weights, w_gate, w_up, w_down):
+    """``_held_part`` through a buffer of ``rows`` rows, or in parts over
+    all a router can send where more are routed here.  Forward and backward
+    each look at the rows themselves.  Between them a step whose rows fit
+    keeps the sorted choices, the groups' sizes, the rows' weights and the
+    ``gate`` and ``up`` products (:class:`_Kept`: two ``[rows, f]`` arrays
+    and a few ``[rows]`` ones), and its backward runs neither the sort nor a
+    product of the forward again; it gathers the rows again, which is the
+    one ``[rows, d]`` array it would otherwise keep.  A step in parts keeps
+    zeros in their place and its backward makes each part's forward again,
+    so its temporaries are a part's size."""
+    operands = (x, local, weights, w_gate, w_up, w_down)
+    # One type for all, cast outside the custom_vjp (``vary_like``): what is
+    # kept varies as they do, and so do both sides of each ``cond``.
+    return _kept_between(rows, *(functools.reduce(vary_like, operands, a)
+                                 for a in operands))
 
 
 def dispatch_experts(x, experts, weights, w_gate, w_up, w_down, *,

@@ -60,6 +60,8 @@ def test_grouped_products_phase_tiny():
     assert [c["name"] for c in report["checks"]] == [
         f"{case}/{n}" for case in ("routed_experts", "routed_experts_in_parts")
         for n in ("y", "dx", "drouter", "dgate", "dup", "ddown")] + [
+        f"kept_forward/{n}"
+        for n in ("dx", "dweights", "dgate", "dup", "ddown")] + [
         f"{dot}/{n}" for dot in ("hvd_grouped_dot",
                                  "hvd_grouped_dot_cast_first")
         for n in ("drows_tail_is_zero", "drows", "dgate", "dup", "ddown")]

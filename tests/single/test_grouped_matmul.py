@@ -200,3 +200,30 @@ def test_off_the_tpu_it_is_ragged_dot():
         gm.grouped_dot(rows, w.astype(jnp.bfloat16), sizes),
         jax.lax.ragged_dot(rows, w.astype(jnp.bfloat16).astype(jnp.float32),
                            sizes))
+
+
+@pytest.mark.parametrize("interpret", [True, None],
+                         ids=["kernels", "ragged_dot"])
+@pytest.mark.parametrize("w_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["an-empty-group",
+                                  "a-tail-that-starts-inside-a-tile"])
+def test_the_two_gradients_alone_are_reverse_mode_s(case, w_dtype, interpret):
+    """``grouped_dot_grads``: d rows and dW for a caller that kept the
+    operands, the bits that ``jax.vjp`` of ``grouped_dot`` gives, by the
+    kernels and by ``ragged_dot``'s transposes; and no forward product in
+    its jaxpr."""
+    rows, w, ct, sizes = _operands(case, jnp.bfloat16, w_dtype)
+    if interpret is None:       # ragged_dot takes the tail's NaN as given
+        rows, ct = (jnp.nan_to_num(a) for a in (rows, ct))
+    want = jax.vjp(lambda r, w: gm.grouped_dot(
+        r, w, sizes, interpret=interpret), rows, w)[1](ct)
+    got = gm.grouped_dot_grads(rows, w, sizes, ct, interpret=interpret)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    traced = str(jax.make_jaxpr(lambda *a: gm.grouped_dot_grads(
+        *a, interpret=interpret))(rows, w, sizes, ct))
+    assert (traced.count("pallas_call") if interpret
+            else traced.count("ragged_dot_general[")) == 2
+

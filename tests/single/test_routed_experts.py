@@ -103,11 +103,14 @@ def test_a_skewed_router_drops_nothing(capacity_factor, skewed, rows, fit):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5, err_msg=name)
 
 
+@pytest.mark.parametrize("chips", [1, 2])
 @pytest.mark.parametrize("skewed", [(1,), (1, 2)], ids=["fits", "in-parts"])
-def test_both_sides_inside_a_jitted_shard_map_step(skewed):
+def test_both_sides_inside_a_jitted_shard_map_step(skewed, chips):
     """As a model calls it: under ``jit``, ``shard_map`` over ``hvd`` with
     ``check_vma`` and ``value_and_grad``, the side chosen while the step
-    runs."""
+    runs.  What the forward keeps for the backward varies as its operands
+    do: the tokens over the chips, the kernels not, and their gradients are
+    the chips' sum."""
     from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
@@ -123,7 +126,7 @@ def test_both_sides_inside_a_jitted_shard_map_step(skewed):
             argnums=(0, 1, 2, 3, 4))(*a)
         return jax.lax.psum(value, "hvd"), grads
 
-    mesh = Mesh(np.asarray(jax.devices()[:1]), ("hvd",))
+    mesh = Mesh(np.asarray(jax.devices()[:chips]), ("hvd",))
     got = jax.jit(shard_map(
         step, mesh=mesh, in_specs=(P("hvd"), P(), P(), P(), P()),
         out_specs=(P(), (P("hvd"), P(), P(), P(), P()))))(x, router, *mine)
@@ -144,7 +147,7 @@ def test_the_buffer_s_tail_reaches_nothing(monkeypatch, request, skewed,
     by ``ragged_dot`` (what a CPU runs) and by the Pallas kernels (what a TPU
     runs; interpreted here).  Before PR 35 the last expert took the tail as
     rows of its own."""
-    from horovod_tpu.ops.grouped_matmul import grouped_dot
+    from horovod_tpu.ops.grouped_matmul import grouped_dot, grouped_dot_grads
 
     traced_anew = _traced_anew
     x, router, *kernels = _layer(skew=6.0, skewed=skewed)
@@ -153,6 +156,8 @@ def test_the_buffer_s_tail_reaches_nothing(monkeypatch, request, skewed,
     if products == "kernels":
         monkeypatch.setattr(moe, "grouped_dot", functools.partial(
             grouped_dot, interpret=True))
+        monkeypatch.setattr(moe, "grouped_dot_grads", functools.partial(
+            grouped_dot_grads, interpret=True))
         traced_anew()
 
     def value_and_grads(*a):
@@ -169,13 +174,166 @@ def test_the_buffer_s_tail_reaches_nothing(monkeypatch, request, skewed,
         return swiglu_rows(jnp.where(past[:, None], jnp.nan, rows),
                            group_sizes, *k)
 
+    swiglu_rows_grads = moe._swiglu_rows_grads
+
+    def poisoned_grads(rows, group_sizes, gate, up, scale, g, *k):
+        past = (jnp.arange(rows.shape[0]) >= jnp.sum(group_sizes))[:, None]
+        tails.append(-rows.shape[0])
+        return swiglu_rows_grads(
+            jnp.where(past, jnp.nan, rows), group_sizes, gate, up, scale,
+            jnp.where(past, jnp.nan, g), *k)
+
     monkeypatch.setattr(moe, "_swiglu_rows", poisoned)
+    monkeypatch.setattr(moe, "_swiglu_rows_grads", poisoned_grads)
     traced_anew()
     got = value_and_grads(x, router, *mine)
-    assert tails                                  # the hook was on the path
+    assert min(tails) < 0 < max(tails)            # both hooks were traced
     for name, a, b in zip(("y", "x", "router", "gate", "up", "down"),
                           jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def _plain_reverse_mode(x, local, weights, *kernels):
+    """The layer as it was before it kept anything: ``_held_part`` through a
+    buffer of every row a router can send, differentiated by ``jax.vjp``."""
+    worst = local.shape[0] * min(local.shape[1], kernels[0].shape[0])
+    return moe._held_part(worst, x, local, weights, *kernels)[0]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("entry", ["routed_experts", "dispatch_experts"])
+@pytest.mark.parametrize("top_k,held,capacity_factor,skewed,fit", [
+    (4, 4, 2.0, (1,), True), (4, 4, 2.0, (1, 2), False),
+    (1, 4, 2.0, (1,), False), (1, 4, 4.0, (1,), True),
+    (4, 1, 2.0, (0,), False)],
+    ids=["fits", "in-parts", "top-1-in-parts", "top-1-every-row-fits",
+         "one-expert-held-in-parts"])
+def test_the_kept_forward_gives_the_gradients_of_plain_reverse_mode(
+        top_k, held, capacity_factor, skewed, fit, entry, dtype):
+    """The backward that starts from the forward's sort, sizes, gate and up
+    (and takes ``d weights`` from ``g . W_down^T``) against ``jax.vjp`` of
+    ``_held_part``: tokens, weights or router, the three kernels; to
+    rounding in float32, within the file's bound in bfloat16; where the
+    rows fit, where they are walked in parts (nothing kept), and for a
+    layer whose tokens have one row each."""
+    x, router, *kernels = _layer(skew=6.0, skewed=skewed)
+    x = x.astype(dtype)
+    mine = _held(kernels, 0, held)
+    renormalize = top_k > 1     # top-1 renormalised weighs 1: no gradient
+    routing = moe.route(x, router, top_k, 0, held, renormalize)
+    rows = moe.row_buffer(TOKENS, top_k, held, EXPERTS, capacity_factor)
+    assert moe.forward_kept(int(routing.load.sum()), rows) == fit
+
+    if entry == "routed_experts":
+        def layer(x, router, *k):
+            return moe.routed_experts(x, router, *k, top_k=top_k,
+                                      capacity_factor=capacity_factor,
+                                      renormalize=renormalize)[0]
+
+        def plain(x, router, *k):
+            r = moe.route(x, router, top_k, 0, held, renormalize)
+            return _plain_reverse_mode(x, moe._local(r.experts, 0, held),
+                                       r.weights, *k)
+        second = router
+    else:
+        def layer(x, weights, *k):
+            return moe.dispatch_experts(
+                x, routing.experts, weights, *k, first_expert=0,
+                experts_total=EXPERTS, capacity_factor=capacity_factor)
+
+        def plain(x, weights, *k):
+            return _plain_reverse_mode(
+                x, moe._local(routing.experts, 0, held), weights, *k)
+        second = routing.weights
+
+    def value_and_grads(fn):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2, 3, 4))(x, second, *mine)
+
+    (got_y, got), (want_y, want) = value_and_grads(layer), value_and_grads(
+        plain)
+    np.testing.assert_allclose(got_y, want_y, rtol=1e-5)
+    for name, a, b in zip(("x", entry, "gate", "up", "down"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = (np.asarray(v, np.float32) for v in (a, b))
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5 * np.abs(
+                b).max(), err_msg=name)
+        else:
+            assert np.abs(a - b).max() < 0.05 * np.abs(b).max(), name
+
+
+def _count(jaxpr, primitives, side):
+    """How many equations of ``jaxpr`` are one of ``primitives``, under the
+    side ``side`` of every ``cond`` (1: the rows fit) and in every other
+    sub-jaxpr."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        found += eqn.primitive.name in primitives
+        for key, value in eqn.params.items():
+            subs = value if isinstance(value, (tuple, list)) else (value,)
+            if eqn.primitive.name == "cond" and key == "branches":
+                subs = (value[side],)
+            for sub in subs:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _count(sub, primitives, side)
+    return found
+
+
+@pytest.mark.parametrize("capacity_factor,fits,in_parts", [
+    (2.0, (9, 1), (12, 2)), (4.0, (9, 1), (9, 1))],
+    ids=["a-buffer-smaller-than-the-worst", "every-row-fits"])
+def test_a_fitting_backward_runs_no_product_and_no_sort_again(
+        capacity_factor, fits, in_parts):
+    """The traced forward and backward of one layer (off a TPU a grouped
+    product is a ``ragged_dot``): where the rows fit, three products and a
+    sort forward and six products backward, which were nine and a second
+    sort while the backward made its forward again; in parts, as before:
+    each part's forward is made again under ``jax.checkpoint``."""
+    x, router, *kernels = _layer()
+    mine = _held(kernels, 0, 4)
+    traced = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(moe.routed_experts(
+            *a, top_k=TOP_K, capacity_factor=capacity_factor)[0] ** 2),
+        argnums=(0, 1, 2, 3, 4)))(x, router, *mine)
+    products = {"ragged_dot", "ragged_dot_general"}
+    for side, (dots, sorts) in ((1, fits), (0, in_parts)):
+        assert _count(traced.jaxpr, products, side) == dots
+        assert _count(traced.jaxpr, {"sort"}, side) == sorts
+
+
+def test_the_kept_forward_under_a_block_s_checkpoint():
+    """As ``models/zaya.py`` builds its block: ``nn.remat`` with
+    ``save_only_these_names``, none of them the layer's, so that the block's
+    backward makes the layer's forward again and hands what it kept to the
+    layer's backward.  The same values and gradients as without."""
+    import flax.linen as nn
+    from horovod_tpu.ops.flash_attention import CHECKPOINT_NAMES
+
+    x, router, *kernels = _layer(seed=5)
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            mine = [self.param(name, lambda _, k=k: k[:4])
+                    for name, k in zip(("gate", "up", "down"), kernels)]
+            return x + moe.routed_experts(
+                x, self.param("router", lambda _: router), *mine,
+                top_k=TOP_K, capacity_factor=2.0)[0]
+
+    def value_and_grads(module):
+        params = module.init(jax.random.key(0), x)
+        return jax.jit(jax.value_and_grad(
+            lambda p, x: jnp.sum(module.apply(p, x) ** 2), argnums=(0, 1)))(
+                params, x)
+
+    kept = jax.checkpoint_policies.save_only_these_names(*CHECKPOINT_NAMES)
+    got = value_and_grads(nn.remat(Layer, policy=kept)())
+    want = value_and_grads(Layer())
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("held", [2, 4, 16])
@@ -363,6 +521,17 @@ def test_a_layer_whose_tokens_have_one_row_each(top_k, held):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5, err_msg=name)
 
 
+@pytest.mark.parametrize("load_sum,capacity,kept", [
+    (0, 36864, True), (16400, 36864, True), (36864, 36864, True),
+    (36865, 36864, False), (8192, 16384, True), (5, 384, True),
+    (1101, 1100, False)])
+def test_forward_kept(load_sum, capacity, kept):
+    # the rows routed fit the buffer: ``rows_walked``'s cases, and past them
+    assert moe.forward_kept(load_sum, capacity) is kept
+    if kept:        # and then a walk visits every one of them
+        assert load_sum <= moe.rows_walked(load_sum, capacity) <= capacity
+
+
 def test_rows_walked():
     # whole trips of WALK_ROWS up to the one that holds the last routed row
     assert moe.WALK_ROWS == 512
@@ -420,10 +589,12 @@ def test_no_pass_of_the_routing_walks_the_whole_buffer(trips_of_64,
     local = moe._local(routing.experts, 0, 4)
     rows = moe.row_buffer(TOKENS, TOP_K, 4, EXPERTS, 2.0)
     assert rows == 512 and rows not in (TOKENS, D, F)
-    args = (x, local, routing.weights) + ((x,) if backward else ()) + mine
+    args = (x, local, routing.weights)
+    if backward:
+        args += (moe._forward(rows, *args, *mine)[1], x)
     traced = jax.make_jaxpr(
         moe._backward if backward else moe._forward, static_argnums=0)(
-            rows, *args)
+            rows, *args, *mine)
     loops = str(traced).count("while[")
     assert loops >= (3 if backward else 2), loops
     assert _wide_passes_outside_loops(traced.jaxpr, rows, D) == []

@@ -353,6 +353,47 @@ def test_grouped_products_compile_at_an_expert_wider_than_the_budget(
     assert len(re.findall(r"hvd_moe_tgmm[\w.]* = ", text)) == 1
 
 
+def test_a_fitting_expert_layer_runs_no_product_of_its_forward_again(
+        one_chip, monkeypatch):
+    """``parallel/moe.py:routed_experts`` at ``sdar-moe-ep8-s4096``'s layer
+    (16,384 tokens, top-8 of 128, 16 held, a 36,864-row buffer), value and
+    gradients: where the rows fit, three products forward and, from what
+    the forward kept, three ``d rows`` and three ``dW`` backward; in parts
+    as before, each part's forward made again."""
+    from horovod_tpu.parallel import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def traced_anew():      # the passes' jit caches are keyed by shapes, not
+        moe._forward.clear_cache()      # by the backend they were traced for
+        moe._backward.clear_cache()
+
+    traced_anew()
+
+    def step(x, router, *kernels):
+        return jax.value_and_grad(lambda *a: jnp.sum(moe.routed_experts(
+            *a, top_k=8, capacity_factor=2.25)[0].astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2, 3, 4))(x, router, *kernels)
+
+    try:
+        text = _compiled_text(step, *_shapes_on(one_chip, (
+            jax.ShapeDtypeStruct((16384, 2048), jnp.bfloat16),
+            jax.ShapeDtypeStruct((2048, 128), jnp.float32),
+            jax.ShapeDtypeStruct((16, 2048, 768), jnp.float32),
+            jax.ShapeDtypeStruct((16, 2048, 768), jnp.float32),
+            jax.ShapeDtypeStruct((16, 768, 2048), jnp.float32))))
+    finally:
+        traced_anew()
+
+    def calls(kernel, side):
+        return len(re.findall(
+            rf"{kernel}[\w.]* = [^\n]*cond/branch_{side}_fun", text))
+
+    assert (calls("hvd_moe_gmm", 1), calls("hvd_moe_tgmm", 1)) == (6, 3)
+    assert (calls("hvd_moe_gmm", 0), calls("hvd_moe_tgmm", 0)) == (9, 3)
+    assert "ragged-dot" not in text
+
+
 HEADS = {"zaya": (2048, 131136), "jamba": (2560, 16384)}
 
 

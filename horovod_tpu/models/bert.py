@@ -77,8 +77,12 @@ class SelfAttention(nn.Module):
         head_dim = cfg.hidden_size // cfg.num_heads
         # One fused QKV projection: [B, S, H] @ [H, 3H] keeps the MXU at a
         # single large matmul instead of three small ones.
-        qkv = FlatDenseGeneral((3, cfg.num_heads, head_dim), dtype=cfg.dtype,
-                               name="qkv")(x)           # [B, S, 3 * H * D]
+        with jax.named_scope("hvd_attn_proj"):
+            qkv = FlatDenseGeneral((3, cfg.num_heads, head_dim),
+                                   dtype=cfg.dtype,
+                                   name="qkv")(x)       # [B, S, 3 * H * D]
+        # The kernels stay outside the products' scope: a kernel call's HLO
+        # instruction is named for its innermost scope, this module's.
         q, k, v = (part.reshape(*x.shape[:-1], cfg.num_heads, head_dim)
                    for part in jnp.split(qkv, 3, axis=-1))
         if cfg.sp_axis_name is not None:
@@ -105,9 +109,9 @@ class SelfAttention(nn.Module):
                 logits = jnp.where(mask[:, None, None, :], logits, big_neg)
             probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
             ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        out = FlatDenseGeneral(cfg.hidden_size, axis=(-2, -1), dtype=cfg.dtype,
-                               name="out")(ctx)
-        return out
+        with jax.named_scope("hvd_attn_proj"):
+            return FlatDenseGeneral(cfg.hidden_size, axis=(-2, -1),
+                                    dtype=cfg.dtype, name="out")(ctx)
 
 
 class TransformerLayer(nn.Module):
@@ -117,19 +121,24 @@ class TransformerLayer(nn.Module):
     def __call__(self, x, mask=None, deterministic: bool = True,
                  lengths=None):
         cfg = self.config
-        attn = SelfAttention(cfg, name="attention")(x, mask, deterministic,
-                                                    lengths)
-        attn = nn.Dropout(cfg.dropout_rate)(attn, deterministic=deterministic)
-        # Post-LN like original BERT; LN in fp32 for stability.
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_attn")(
-            (x + attn).astype(jnp.float32)).astype(cfg.dtype)
-        h = nn.Dense(cfg.intermediate_size, dtype=cfg.dtype, name="mlp_in")(x)
-        h = nn.gelu(h)
-        h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="mlp_out")(h)
-        h = nn.Dropout(cfg.dropout_rate)(h, deterministic=deterministic)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_mlp")(
-            (x + h).astype(jnp.float32)).astype(cfg.dtype)
-        return x
+        with jax.named_scope("hvd_block"):
+            with jax.named_scope("hvd_attn"):
+                attn = SelfAttention(cfg, name="attention")(
+                    x, mask, deterministic, lengths)
+            attn = nn.Dropout(cfg.dropout_rate)(attn,
+                                                deterministic=deterministic)
+            # Post-LN like original BERT; LN in fp32 for stability.
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_attn")(
+                (x + attn).astype(jnp.float32)).astype(cfg.dtype)
+            with jax.named_scope("hvd_mlp"):
+                h = nn.Dense(cfg.intermediate_size, dtype=cfg.dtype,
+                             name="mlp_in")(x)
+                h = nn.gelu(h)
+                h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
+                             name="mlp_out")(h)
+            h = nn.Dropout(cfg.dropout_rate)(h, deterministic=deterministic)
+            return nn.LayerNorm(dtype=jnp.float32, name="ln_mlp")(
+                (x + h).astype(jnp.float32)).astype(cfg.dtype)
 
 
 class BertEncoder(nn.Module):
@@ -147,23 +156,24 @@ class BertEncoder(nn.Module):
         kernels read ``lengths`` and the dense path the mask."""
         cfg = self.config
         seq_len = input_ids.shape[-1]
-        x = nn.Embed(cfg.padded_vocab_size, cfg.hidden_size,
-                     dtype=cfg.dtype, name="word_embeddings")(input_ids)
-        if cfg.sp_axis_name is not None:
-            # Sequence-parallel: this shard holds a contiguous chunk of the
-            # global sequence; position ids are global.
-            offset = jax.lax.axis_index(cfg.sp_axis_name) * seq_len
-        else:
-            offset = 0
-        pos = (offset + jnp.arange(seq_len))[None, :]
-        x = x + nn.Embed(cfg.max_position_embeddings, cfg.hidden_size,
-                         dtype=cfg.dtype, name="position_embeddings")(pos)
-        if token_type_ids is not None:
-            x = x + nn.Embed(cfg.type_vocab_size, cfg.hidden_size,
-                             dtype=cfg.dtype, name="token_type_embeddings")(
-                token_type_ids)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_embed")(
-            x.astype(jnp.float32)).astype(cfg.dtype)
+        with jax.named_scope("hvd_embed"):
+            x = nn.Embed(cfg.padded_vocab_size, cfg.hidden_size,
+                         dtype=cfg.dtype, name="word_embeddings")(input_ids)
+            if cfg.sp_axis_name is not None:
+                # Sequence-parallel: this shard holds a contiguous chunk of
+                # the global sequence; position ids are global.
+                offset = jax.lax.axis_index(cfg.sp_axis_name) * seq_len
+            else:
+                offset = 0
+            pos = (offset + jnp.arange(seq_len))[None, :]
+            x = x + nn.Embed(cfg.max_position_embeddings, cfg.hidden_size,
+                             dtype=cfg.dtype, name="position_embeddings")(pos)
+            if token_type_ids is not None:
+                x = x + nn.Embed(cfg.type_vocab_size, cfg.hidden_size,
+                                 dtype=cfg.dtype,
+                                 name="token_type_embeddings")(token_type_ids)
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_embed")(
+                x.astype(jnp.float32)).astype(cfg.dtype)
         for i in range(cfg.num_layers):
             x = TransformerLayer(cfg, name=f"layer_{i}")(
                 x, attention_mask, deterministic, lengths)
@@ -251,4 +261,5 @@ def pretraining_loss(mlm_logits, nsp_logits, mlm_labels, mlm_weights,
         masked_lm = mlm_loss(mlm_logits, mlm_labels, mlm_weights)
     with jax.named_scope("hvd_nsp_head"):
         next_sentence = nsp_loss(nsp_logits, nsp_labels)
-    return masked_lm + next_sentence
+    with jax.named_scope("hvd_mlm_head"):
+        return masked_lm + next_sentence

@@ -48,10 +48,14 @@ class CausalSelfAttention(nn.Module):
     def __call__(self, x, deterministic: bool = True):
         cfg = self.config
         head_dim = cfg.hidden_size // cfg.num_heads
-        qkv = FlatDenseGeneral((3, cfg.num_heads, head_dim), dtype=cfg.dtype,
-                               name="qkv")(x)           # [B, S, 3 * H * D]
+        with jax.named_scope("hvd_attn_proj"):
+            qkv = FlatDenseGeneral((3, cfg.num_heads, head_dim),
+                                   dtype=cfg.dtype,
+                                   name="qkv")(x)       # [B, S, 3 * H * D]
         q, k, v = (part.reshape(*x.shape[:-1], cfg.num_heads, head_dim)
                    for part in jnp.split(qkv, 3, axis=-1))
+        # The kernels stay outside the products' scope: a kernel call's HLO
+        # instruction is named for its innermost scope, this module's.
         if cfg.sp_axis_name is not None:
             from ..parallel.ring_attention import ring_attention
 
@@ -66,8 +70,9 @@ class CausalSelfAttention(nn.Module):
             from ..ops.flash_attention import dense_attention
 
             ctx = dense_attention(q, k, v, causal=True)
-        return FlatDenseGeneral(cfg.hidden_size, axis=(-2, -1),
-                                dtype=cfg.dtype, name="out")(ctx)
+        with jax.named_scope("hvd_attn_proj"):
+            return FlatDenseGeneral(cfg.hidden_size, axis=(-2, -1),
+                                    dtype=cfg.dtype, name="out")(ctx)
 
 
 class GPTBlock(nn.Module):
@@ -76,19 +81,24 @@ class GPTBlock(nn.Module):
     @nn.compact
     def __call__(self, x, deterministic: bool = True):
         cfg = self.config
-        # Pre-LN (GPT-2 style); LN in fp32.
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln_1")(
-            x.astype(jnp.float32)).astype(cfg.dtype)
-        x = x + nn.Dropout(cfg.dropout_rate)(
-            CausalSelfAttention(cfg, name="attn")(h, deterministic),
-            deterministic=deterministic)
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln_2")(
-            x.astype(jnp.float32)).astype(cfg.dtype)
-        m = nn.Dense(4 * cfg.hidden_size, dtype=cfg.dtype, name="mlp_in")(h)
-        m = nn.gelu(m)
-        m = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="mlp_out")(m)
-        return x + nn.Dropout(cfg.dropout_rate)(m,
-                                                deterministic=deterministic)
+        with jax.named_scope("hvd_block"):
+            # Pre-LN (GPT-2 style); LN in fp32.
+            h = nn.LayerNorm(dtype=jnp.float32, name="ln_1")(
+                x.astype(jnp.float32)).astype(cfg.dtype)
+            with jax.named_scope("hvd_attn"):
+                a = CausalSelfAttention(cfg, name="attn")(h, deterministic)
+            x = x + nn.Dropout(cfg.dropout_rate)(
+                a, deterministic=deterministic)
+            h = nn.LayerNorm(dtype=jnp.float32, name="ln_2")(
+                x.astype(jnp.float32)).astype(cfg.dtype)
+            with jax.named_scope("hvd_mlp"):
+                m = nn.Dense(4 * cfg.hidden_size, dtype=cfg.dtype,
+                             name="mlp_in")(h)
+                m = nn.gelu(m)
+                m = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
+                             name="mlp_out")(m)
+            return x + nn.Dropout(cfg.dropout_rate)(
+                m, deterministic=deterministic)
 
 
 class GPT(nn.Module):
@@ -100,27 +110,30 @@ class GPT(nn.Module):
     def __call__(self, input_ids, deterministic: bool = True):
         cfg = self.config
         seq_len = input_ids.shape[-1]
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                     name="wte")(input_ids)
-        if cfg.sp_axis_name is not None:
-            offset = jax.lax.axis_index(cfg.sp_axis_name) * seq_len
-        else:
-            offset = 0
-        pos = (offset + jnp.arange(seq_len))[None, :]
-        x = x + nn.Embed(cfg.max_seq_len, cfg.hidden_size, dtype=cfg.dtype,
-                         name="wpe")(pos)
+        with jax.named_scope("hvd_embed"):
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                         name="wte")(input_ids)
+            if cfg.sp_axis_name is not None:
+                offset = jax.lax.axis_index(cfg.sp_axis_name) * seq_len
+            else:
+                offset = 0
+            pos = (offset + jnp.arange(seq_len))[None, :]
+            x = x + nn.Embed(cfg.max_seq_len, cfg.hidden_size,
+                             dtype=cfg.dtype, name="wpe")(pos)
         block = GPTBlock
         if cfg.remat:
             block = nn.remat(GPTBlock, static_argnums=(2,))
         for i in range(cfg.num_layers):
             x = block(cfg, name=f"h_{i}")(x, deterministic)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(
-            x.astype(jnp.float32))
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-                          name="lm_head")(x)
-        return logits
+        with jax.named_scope("hvd_lm_head"):
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(
+                x.astype(jnp.float32))
+            return nn.Dense(cfg.vocab_size, use_bias=False,
+                            dtype=jnp.float32, name="lm_head")(x)
 
 
 def lm_loss(logits, input_ids):
     """Next-token cross entropy (shifted), mean over positions."""
-    return softmax_cross_entropy(logits[:, :-1], input_ids[:, 1:]).mean()
+    with jax.named_scope("hvd_lm_head"):
+        return softmax_cross_entropy(logits[:, :-1],
+                                     input_ids[:, 1:]).mean()

@@ -353,19 +353,21 @@ class JambaAttention(nn.Module):
         cfg = self.config
         batch, seq = h.shape[:2]
         heads, groups, d = cfg.heads_held, cfg.num_kv_heads, cfg.head_dim
-        q = FlatDenseGeneral((heads, d), dtype=cfg.dtype, use_bias=False,
-                             name="q_proj")(h)
-        # Whole on every chip: its gradient is this chip's heads' part.
-        kv = FlatDenseGeneral((2, groups, d), dtype=cfg.dtype,
-                              use_bias=False, name="kv_proj")(h)
+        with jax.named_scope("hvd_attn_proj"):
+            q = FlatDenseGeneral((heads, d), dtype=cfg.dtype, use_bias=False,
+                                 name="q_proj")(h)
+            # Whole on every chip: its gradient is this chip's heads' part.
+            kv = FlatDenseGeneral((2, groups, d), dtype=cfg.dtype,
+                                  use_bias=False, name="kv_proj")(h)
         k, v = kv[..., :groups * d], kv[..., groups * d:]
         attend = flash_attention if cfg.use_flash else dense_attention
         ctx = attend(q.reshape(batch, seq, heads, d),
                      k.reshape(batch, seq, groups, d),
                      v.reshape(batch, seq, groups, d), causal=True)
-        return RowParallel(cfg.hidden_size, cfg.num_heads * d,
-                           self.axis_name, cfg.dtype, name="o_proj")(
-                               ctx.reshape(batch, seq, heads * d))
+        with jax.named_scope("hvd_attn_proj"):
+            return RowParallel(cfg.hidden_size, cfg.num_heads * d,
+                               self.axis_name, cfg.dtype, name="o_proj")(
+                                   ctx.reshape(batch, seq, heads * d))
 
 
 class JambaMLP(nn.Module):
@@ -404,11 +406,18 @@ class JambaBlock(nn.Module):
             return (x.astype(jnp.float32)
                     + y.astype(jnp.float32)).astype(cfg.dtype)
 
-        mixer = (JambaAttention if self.attention else MambaMixer)(
-            cfg, self.axis_name, name="attn" if self.attention else "mamba")
-        x = add(x, mixer(norm("input_norm")(x)))
-        return add(x, JambaMLP(cfg, self.axis_name, name="mlp")(
-            norm("pre_ff_norm")(x)))
+        with jax.named_scope("hvd_block"):
+            h = norm("input_norm")(x)
+            if self.attention:
+                with jax.named_scope("hvd_attn"):
+                    y = JambaAttention(cfg, self.axis_name, name="attn")(h)
+            else:
+                y = MambaMixer(cfg, self.axis_name, name="mamba")(h)
+            x = add(x, y)
+            h = norm("pre_ff_norm")(x)
+            with jax.named_scope("hvd_mlp"):
+                y = JambaMLP(cfg, self.axis_name, name="mlp")(h)
+            return add(x, y)
 
 
 class Jamba(nn.Module):
@@ -439,12 +448,13 @@ class Jamba(nn.Module):
         self.final_norm = RMSNorm(cfg.rms_norm_eps, dtype=cfg.dtype)
 
     def hidden(self, ids):
-        if self.axis_name is None:
-            x = self.embed(ids)
-        else:
-            x = vocab_parallel_embedding(
-                ids, self.embed.embedding.astype(self.config.dtype),
-                self.axis_name)
+        with jax.named_scope("hvd_embed"):
+            if self.axis_name is None:
+                x = self.embed(ids)
+            else:
+                x = vocab_parallel_embedding(
+                    ids, self.embed.embedding.astype(self.config.dtype),
+                    self.axis_name)
         for layer in self.layers:
             x = layer(x)
         with jax.named_scope("hvd_lm_head"):
@@ -469,10 +479,10 @@ class Jamba(nn.Module):
         self._one_chip_s_rows("loss")
         x = self.hidden(ids)
         batch, seq = ids.shape
-        predicts = jnp.arange(seq) < seq - 1
-        weights = jnp.broadcast_to(predicts / (batch * (seq - 1.0)),
-                                   ids.shape)
         with jax.named_scope("hvd_lm_head"):
+            predicts = jnp.arange(seq) < seq - 1
+            weights = jnp.broadcast_to(predicts / (batch * (seq - 1.0)),
+                                       ids.shape)
             return tied_head_cross_entropy(
                 x.reshape(batch * seq, -1), self.embed.embedding,
                 jnp.roll(ids, -1, axis=1).reshape(-1),

@@ -344,12 +344,18 @@ class JoyAIBlock(nn.Module):
             return (x.astype(jnp.float32)
                     + y.astype(jnp.float32)).astype(cfg.dtype)
 
-        x = add(x, JoyAIAttention(cfg, self.axis_name, name="attn")(
-            norm("input_norm")(x)))
-        ff = (JoyAIMoE(cfg, self.axis_name, name="moe")
-              if cfg.sparse(self.layer)
-              else JoyAIMLP(cfg, self.axis_name, name="mlp"))
-        return add(x, ff(norm("post_attn_norm")(x)))
+        with jax.named_scope("hvd_block"):
+            h = norm("input_norm")(x)
+            with jax.named_scope("hvd_attn"):
+                y = JoyAIAttention(cfg, self.axis_name, name="attn")(h)
+            x = add(x, y)
+            h = norm("post_attn_norm")(x)
+            if cfg.sparse(self.layer):
+                y = JoyAIMoE(cfg, self.axis_name, name="moe")(h)
+            else:
+                with jax.named_scope("hvd_mlp"):
+                    y = JoyAIMLP(cfg, self.axis_name, name="mlp")(h)
+            return add(x, y)
 
 
 class JoyAI(nn.Module):
@@ -378,12 +384,13 @@ class JoyAI(nn.Module):
             (cfg.hidden_size, cfg.rows_held))
 
     def hidden(self, ids):
-        if self.axis_name is None:
-            x = self.embed(ids)
-        else:
-            x = vocab_parallel_embedding(
-                ids, self.embed.embedding.astype(self.config.dtype),
-                self.axis_name)
+        with jax.named_scope("hvd_embed"):
+            if self.axis_name is None:
+                x = self.embed(ids)
+            else:
+                x = vocab_parallel_embedding(
+                    ids, self.embed.embedding.astype(self.config.dtype),
+                    self.axis_name)
         for layer in self.layers:
             x = layer(x)
         with jax.named_scope("hvd_lm_head"):
@@ -406,8 +413,8 @@ class JoyAI(nn.Module):
         self._one_chip_s_rows("loss")
         logits = self.head(self.hidden(ids))
         batch, seq = ids.shape
-        weights = (jnp.arange(seq) < seq - 1) / (batch * (seq - 1.0))
         with jax.named_scope("hvd_lm_head"):
+            weights = (jnp.arange(seq) < seq - 1) / (batch * (seq - 1.0))
             nll = softmax_cross_entropy(logits, jnp.roll(ids, -1, axis=1))
             return jnp.sum(nll * weights.astype(jnp.float32))
 

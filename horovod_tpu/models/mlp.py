@@ -6,6 +6,7 @@ from __future__ import annotations
 from typing import Any, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from .losses import softmax_cross_entropy
@@ -25,4 +26,7 @@ class MLP(nn.Module):
 
 
 def xent_loss(logits, labels):
-    return softmax_cross_entropy(logits, labels).mean()
+    """A classifier's loss, under the classifier's scope (``hvd_head``, as
+    ``models/resnet.py`` names its pool and product)."""
+    with jax.named_scope("hvd_head"):
+        return softmax_cross_entropy(logits, labels).mean()

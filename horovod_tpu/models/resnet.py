@@ -21,6 +21,7 @@ import functools
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 ModuleDef = Any
@@ -37,17 +38,18 @@ class ResNetBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        residual = x
-        y = self.conv(self.filters, (3, 3), self.strides)(x)
-        y = self.norm()(y)
-        y = self.act(y)
-        y = self.conv(self.filters, (3, 3))(y)
-        y = self.norm(scale_init=nn.initializers.zeros_init())(y)
-        if residual.shape != y.shape:
-            residual = self.conv(self.filters, (1, 1), self.strides,
-                                 name="conv_proj")(residual)
-            residual = self.norm(name="norm_proj")(residual)
-        return self.act(residual + y)
+        with jax.named_scope("hvd_block"):
+            residual = x
+            y = self.conv(self.filters, (3, 3), self.strides)(x)
+            y = self.norm()(y)
+            y = self.act(y)
+            y = self.conv(self.filters, (3, 3))(y)
+            y = self.norm(scale_init=nn.initializers.zeros_init())(y)
+            if residual.shape != y.shape:
+                residual = self.conv(self.filters, (1, 1), self.strides,
+                                     name="conv_proj")(residual)
+                residual = self.norm(name="norm_proj")(residual)
+            return self.act(residual + y)
 
 
 class BottleneckResNetBlock(nn.Module):
@@ -63,23 +65,24 @@ class BottleneckResNetBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        residual = x
-        y = self.conv(self.filters, (1, 1))(x)
-        y = self.norm()(y)
-        y = self.act(y)
-        y = self.conv(self.filters, (3, 3), self.strides)(y)
-        y = self.norm()(y)
-        y = self.act(y)
-        y = self.conv(self.filters * 4, (1, 1))(y)
-        # Zero-init the last BN scale so blocks start as identity — the
-        # standard large-batch trick (He et al.; also used by the Horovod
-        # paper's training recipes).
-        y = self.norm(scale_init=nn.initializers.zeros_init())(y)
-        if residual.shape != y.shape:
-            residual = self.conv(self.filters * 4, (1, 1), self.strides,
-                                 name="conv_proj")(residual)
-            residual = self.norm(name="norm_proj")(residual)
-        return self.act(residual + y)
+        with jax.named_scope("hvd_block"):
+            residual = x
+            y = self.conv(self.filters, (1, 1))(x)
+            y = self.norm()(y)
+            y = self.act(y)
+            y = self.conv(self.filters, (3, 3), self.strides)(y)
+            y = self.norm()(y)
+            y = self.act(y)
+            y = self.conv(self.filters * 4, (1, 1))(y)
+            # Zero-init the last BN scale so blocks start as identity — the
+            # standard large-batch trick (He et al.; also used by the Horovod
+            # paper's training recipes).
+            y = self.norm(scale_init=nn.initializers.zeros_init())(y)
+            if residual.shape != y.shape:
+                residual = self.conv(self.filters * 4, (1, 1), self.strides,
+                                     name="conv_proj")(residual)
+                residual = self.norm(name="norm_proj")(residual)
+            return self.act(residual + y)
 
 
 class ResNet(nn.Module):
@@ -116,12 +119,14 @@ class ResNet(nn.Module):
             momentum=self.bn_momentum, epsilon=self.bn_epsilon,
             dtype=self.dtype, axis_name=self.bn_axis_name,
         )
-        x = jnp.asarray(x, self.dtype)
-        x = conv(self.num_filters, (7, 7), (2, 2),
-                 padding=[(3, 3), (3, 3)], name="conv_init")(x)
-        x = norm(name="bn_init")(x)
-        x = self.act(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
+        with jax.named_scope("hvd_stem"):
+            x = jnp.asarray(x, self.dtype)
+            x = conv(self.num_filters, (7, 7), (2, 2),
+                     padding=[(3, 3), (3, 3)], name="conv_init")(x)
+            x = norm(name="bn_init")(x)
+            x = self.act(x)
+            x = nn.max_pool(x, (3, 3), strides=(2, 2),
+                            padding=((1, 1), (1, 1)))
         for i, block_size in enumerate(self.stage_sizes):
             for j in range(block_size):
                 strides = (2, 2) if i > 0 and j == 0 else (1, 1)
@@ -129,11 +134,11 @@ class ResNet(nn.Module):
                     self.num_filters * 2 ** i, strides=strides,
                     conv=conv, norm=norm, act=self.act,
                 )(x)
-        x = jnp.mean(x, axis=(1, 2))
-        # Classifier head in fp32 for numerically stable softmax/loss.
-        x = nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(
-            x.astype(jnp.float32))
-        return x
+        with jax.named_scope("hvd_head"):
+            x = jnp.mean(x, axis=(1, 2))
+            # Classifier head in fp32 for numerically stable softmax/loss.
+            return nn.Dense(self.num_classes, dtype=jnp.float32,
+                            name="head")(x.astype(jnp.float32))
 
 
 ResNet18 = functools.partial(ResNet, stage_sizes=[2, 2, 2, 2],

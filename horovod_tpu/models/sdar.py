@@ -159,8 +159,10 @@ class SDARAttention(nn.Module):
             return nn.Dense(heads * d, use_bias=False, dtype=cfg.dtype,
                             name=name)(x)
 
-        q, k = proj("q_proj", cfg.num_heads), proj("k_proj", cfg.num_kv_heads)
-        v = proj("v_proj", cfg.num_kv_heads)
+        with jax.named_scope("hvd_attn_proj"):
+            q, k = (proj("q_proj", cfg.num_heads),
+                    proj("k_proj", cfg.num_kv_heads))
+            v = proj("v_proj", cfg.num_kv_heads)
         # The clean and the noised copy carry the same positions.
         positions = jnp.tile(jnp.arange(length), 2)
         q = HeadNormRope(cfg, name="q_norm")(q, positions)
@@ -170,8 +172,9 @@ class SDARAttention(nn.Module):
         attend = flash_attention if cfg.use_flash else dense_attention
         ctx = attend(*(t.reshape(*lead, -1, d) for t in (q, k, v)),
                      block_diffusion=(length, cfg.block_length))
-        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
-                        name="o_proj")(ctx.reshape(*lead, -1))
+        with jax.named_scope("hvd_attn_proj"):
+            return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                            name="o_proj")(ctx.reshape(*lead, -1))
 
 
 def _expert_init(key, shape, dtype=jnp.float32):
@@ -216,8 +219,12 @@ class SDARBlock(nn.Module):
         cfg = self.config
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, dtype=cfg.dtype,  # noqa: E731
                                     name=name)
-        x = x + SDARAttention(cfg, name="attn")(norm("input_norm")(x))
-        return x + SDARExperts(cfg, name="moe")(norm("post_attn_norm")(x))
+        with jax.named_scope("hvd_block"):
+            h = norm("input_norm")(x)
+            with jax.named_scope("hvd_attn"):
+                a = SDARAttention(cfg, name="attn")(h)
+            x = x + a
+            return x + SDARExperts(cfg, name="moe")(norm("post_attn_norm")(x))
 
 
 class SDAR(nn.Module):
@@ -231,15 +238,16 @@ class SDAR(nn.Module):
     def __call__(self, clean_ids, noised_ids):
         cfg = self.config
         length = clean_ids.shape[-1]
-        ids = jnp.concatenate([clean_ids, noised_ids], axis=-1)   # [B, 2L]
         # Unit-variance embeddings (torch's default): a token's own identity
         # is then the largest part of its hidden state at initialisation, as
         # it is in a trained model.  With rows of unit norm an untrained
         # attention's near-uniform averages make all positions alike and the
         # routers send them to the same few experts.
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                     embedding_init=nn.initializers.normal(stddev=1.0),
-                     name="embed")(ids)
+        with jax.named_scope("hvd_embed"):
+            ids = jnp.concatenate([clean_ids, noised_ids], -1)   # [B, 2L]
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                         embedding_init=nn.initializers.normal(stddev=1.0),
+                         name="embed")(ids)
         for i in range(cfg.num_layers):
             x = SDARBlock(cfg, name=f"layer_{i}")(x)
         with jax.named_scope("hvd_lm_head"):

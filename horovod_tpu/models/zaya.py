@@ -222,8 +222,9 @@ class CCA(nn.Module):
             return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
                             name=name)(x)
 
-        q0, k0 = proj("q_proj", h * d), proj("k_proj", g * d)
-        v_now, v_before = proj("v_proj", d), proj("v_shift_proj", d)
+        with jax.named_scope("hvd_attn_proj"):
+            q0, k0 = proj("q_proj", h * d), proj("k_proj", g * d)
+            v_now, v_before = proj("v_proj", d), proj("v_shift_proj", d)
         conv0 = self.param("conv0", _taps_init, (cfg.cca_time0, (h + g) * d))
         conv1 = self.param("conv1", _taps_init,
                            (cfg.cca_time1, h + g, d, d))
@@ -252,8 +253,9 @@ class CCA(nn.Module):
                        cfg.rope_theta, rotated).astype(cfg.dtype)
         attend = flash_attention if cfg.use_flash else dense_attention
         ctx = attend(q, k, v, causal=True)
-        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
-                        name="o_proj")(ctx.reshape(batch, seq, h * d))
+        with jax.named_scope("hvd_attn_proj"):
+            return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                            name="o_proj")(ctx.reshape(batch, seq, h * d))
 
 
 class ZayaRouter(nn.Module):
@@ -332,12 +334,15 @@ class ZayaBlock(nn.Module):
         cfg = self.config
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, dtype=cfg.dtype,  # noqa: E731
                                     name=name)
-        if not self.first:
-            r = ResidualScale(cfg.dtype, name="res_attn")(r, y)
-        y = CCA(cfg, name="attn")(norm("input_norm")(r))
-        r = ResidualScale(cfg.dtype, name="res_moe")(r, y)
-        y, s = ZayaExperts(cfg, name="moe")(norm("post_attn_norm")(r), s)
-        return r, y, s
+        with jax.named_scope("hvd_block"):
+            if not self.first:
+                r = ResidualScale(cfg.dtype, name="res_attn")(r, y)
+            h = norm("input_norm")(r)
+            with jax.named_scope("hvd_attn"):
+                y = CCA(cfg, name="attn")(h)
+            r = ResidualScale(cfg.dtype, name="res_moe")(r, y)
+            y, s = ZayaExperts(cfg, name="moe")(norm("post_attn_norm")(r), s)
+            return r, y, s
 
 
 class Zaya(nn.Module):
@@ -366,7 +371,8 @@ class Zaya(nn.Module):
         self.final_norm = RMSNorm(cfg.rms_norm_eps, dtype=cfg.dtype)
 
     def hidden(self, ids):
-        r, y, s = self.embed(ids), None, None
+        with jax.named_scope("hvd_embed"):
+            r, y, s = self.embed(ids), None, None
         for layer in self.layers:
             r, y, s = layer(r, y, s)
         with jax.named_scope("hvd_lm_head"):
@@ -389,10 +395,10 @@ class Zaya(nn.Module):
         token's negative log-likelihood."""
         x = self.hidden(ids)
         batch, seq = ids.shape
-        predicts = jnp.arange(seq) < seq - 1
-        weights = jnp.broadcast_to(predicts / (batch * (seq - 1.0)),
-                                   ids.shape)
         with jax.named_scope("hvd_lm_head"):
+            predicts = jnp.arange(seq) < seq - 1
+            weights = jnp.broadcast_to(predicts / (batch * (seq - 1.0)),
+                                       ids.shape)
             return tied_head_cross_entropy(
                 x.reshape(batch * seq, -1), self.embed.embedding,
                 jnp.roll(ids, -1, axis=1).reshape(-1),

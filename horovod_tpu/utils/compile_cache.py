@@ -22,14 +22,20 @@ def enable_compile_cache() -> str:
     is set in code.  Otherwise the cache goes to ``<checkout>/.jax_cache``,
     exported into the environment so that worker processes spawned later
     share it.  Touches no backend: a launcher parent may call it.
+
+    On both paths the key holds the program's debug information
+    (``jax_compilation_cache_include_metadata_in_key``): a program that
+    differs from a cached one in its ``hvd_*`` scopes alone is another entry,
+    because a trace's names are read out of the executable.
     """
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get(ENV_VAR)
     if path:
         return path
     path = os.path.join(_CHECKOUT, ".jax_cache")
     os.environ[ENV_VAR] = path
-    import jax
-
     # jax reads the variable when it is imported, which may have happened.
     jax.config.update("jax_compilation_cache_dir", path)
     return path

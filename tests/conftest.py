@@ -60,6 +60,7 @@ LONG_FILES = (
     "tests/benchmark/test_benchmark.py",
     "tests/single/test_chip_smoke.py",
     "tests/single/test_routed_experts.py",
+    "tests/single/test_trace_names.py",
     "tests/parallel/test_multiprocess.py",
     "tests/integration/test_matrix.py",
     "tests/parallel/test_grouped_atomic.py",

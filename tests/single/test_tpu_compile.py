@@ -194,6 +194,11 @@ def test_block_takes_the_kernels_without_relayout(one_chip, monkeypatch,
     returns = sorted(len(re.findall(r"\w+\[[\d,]+\]", _INSTRUCTION.match(
         line).group(1))) for line in calls)
     assert returns == [1, 2, 2], calls
+    # Each call's instruction is named for its innermost scope, the attention
+    # module's (``hvd_attn`` lies outside it, ``hvd_attn_proj`` round the
+    # products alone): what the benchmark's flash metrics select by.
+    assert all(re.match(rf"\s*%{scope}[\w.]* = ", line) for line in calls), \
+        calls
     activation = batch * seq * heads * head_dim
     relayouts = [
         (op, result, name) for op, result, name in under

@@ -257,6 +257,131 @@ def test_a_joyai_step_names_what_latent_attention_adds():
     assert any("layer_1" in n and "hvd_moe_shared" in n for n in names)
 
 
+# One cell a family, and the layers its step must show in both passes
+# (``hvd_embed`` and a head besides, checked for every family).
+FAMILY_CELLS = {
+    "gpt": ("gpt2m-1chip", {"hvd_block", "hvd_attn", "hvd_attn_proj",
+                            "hvd_mlp", "hvd_lm_head"}),
+    "bert": ("bert-large-s512", {"hvd_block", "hvd_attn", "hvd_attn_proj",
+                                 "hvd_mlp", "hvd_mlm_head", "hvd_nsp_head"}),
+    "resnet": ("resnet50-1chip", {"hvd_stem", "hvd_block", "hvd_head"}),
+    "sdar": ("sdar-moe-ep8-s4096", {
+        "hvd_block", "hvd_attn", "hvd_attn_proj", "hvd_moe_route",
+        "hvd_moe_experts", "hvd_lm_head"}),
+    "zaya": ("zaya1-moe-ep2-s16384", {
+        "hvd_block", "hvd_attn", "hvd_attn_proj", "hvd_cca_mix",
+        "hvd_moe_router", "hvd_moe_experts", "hvd_lm_head"}),
+    "jamba": ("jamba2-ssm-tp4-s16384", {
+        "hvd_block", "hvd_attn", "hvd_attn_proj", "hvd_mlp", "hvd_ssm_proj",
+        "hvd_ssm_mix", "hvd_ssm_scan", "hvd_lm_head"}),
+    "laguna": ("laguna-swa-ep32-s16384", {
+        "hvd_block", "hvd_attn", "hvd_attn_proj", "hvd_mlp", "hvd_rope",
+        "hvd_moe_shared", "hvd_lm_head"}),
+    "joyai": ("joyai-mla-ep16-s16384", {
+        "hvd_block", "hvd_attn", "hvd_attn_proj", "hvd_mlp",
+        "hvd_mla_latent", "hvd_moe_shared", "hvd_lm_head"}),
+}
+# Ops of a step's forward or backward that no scope of the program's can
+# name, each with its reason.
+UNNAMED_BY_DESIGN = (
+    # jax.checkpoint's own barrier round a checkpointed block: ``nn.remat``
+    # wraps the block from outside its ``__call__``, where ``hvd_block`` is.
+    re.compile(r"/remat2$"),
+)
+
+
+def _step_op_names(hvd, cell_name: str) -> list:
+    """Every ``op_name`` of the family's own compiled step
+    (``families/<family>.py:build``) at the configuration's rehearsal sizes
+    on one device: the ops inside a fusion keep theirs."""
+    entry = run.cell_entry(run.load_spec(), cell_name)
+    cfg = run.load_json("configs", entry["config"] + ".json")
+    traffic = traffic_gen.resolve(
+        run.load_json("traffic", entry["traffic"] + ".json"), rehearse=True)
+    family = run.load_family(cfg["family"])
+    mesh = common.hvd_mesh(jax.devices()[:1])
+    cell = family.setup(cfg, mesh, seed=3, rehearse=True)
+    cell["traffic"] = traffic
+    cell["batches"] = traffic_gen.make_batches(
+        traffic, family.inputs(cell, traffic), mesh, seed=3)
+    step, _ = family.build(cell)
+    return re.findall(r'op_name="([^"]+)"', step.as_text())
+
+
+@pytest.mark.parametrize("family", FAMILY_CELLS)
+def test_every_op_of_a_step_s_passes_lies_under_a_layer_scope(hvd_single,
+                                                              family):
+    """The rule ``benchmark/scope_ledger.py`` reads a step by: every op the
+    program's modules trace into a step's forward or backward has an
+    ``hvd_*`` segment in its ``op_name``, and the innermost one is its
+    layer.  A model added later fails this until its layers are named."""
+    from benchmark.scope_ledger import layer_of_scope as layer
+
+    cell_name, layers = FAMILY_CELLS[family]
+    names = _step_op_names(hvd_single, cell_name)
+    passes = [n for n in names if "jvp(" in n or "transpose(" in n]
+    assert len(passes) > 500, len(passes)
+    bare = sorted({n for n in passes if layer(n) is None
+                   and not any(rx.search(n) for rx in UNNAMED_BY_DESIGN)})
+    assert not bare, bare[:20]
+    found = {backward: {layer(n) for n in passes
+                        if ("transpose(" in n) == backward}
+             for backward in (False, True)}
+    embed_and_head = {"hvd_stem"} if family == "resnet" else {"hvd_embed"}
+    for backward in (False, True):
+        assert found[backward] >= layers | embed_and_head, (
+            backward, sorted((layers | embed_and_head) - found[backward]))
+    # The update is named, and outside the model's passes.
+    assert any(layer(n) == "hvd_update" for n in names)
+    assert not any(layer(n) == "hvd_update" for n in passes)
+
+
+@pytest.mark.parametrize("family,module", [("gpt", "attn"),
+                                           ("bert", "attention")])
+def test_a_plain_flash_call_is_named_for_its_attention_module(monkeypatch,
+                                                              family, module):
+    """A kernel call's HLO instruction is named for its innermost scope, and
+    GPT's ``%attn.N`` / BERT's ``%attention.N`` are what the accepted flash
+    metrics select by: ``hvd_attn`` lies outside the flax module and
+    ``hvd_attn_proj`` round the products alone, so the three kernels of a
+    block lowered for a TPU (here, without one) still end their ``op_name``
+    ``/<module>/pallas_call``, under ``hvd_block/hvd_attn``."""
+    import dataclasses
+
+    from horovod_tpu import models
+    from horovod_tpu.models import bert, gpt
+
+    # The models leave interpret=None, and the dispatch then asks which
+    # backend is attached; here that is the CPU, so steer it in the test.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = jax.ShapeDtypeStruct((1, 256, 128), jnp.bfloat16)
+    if family == "gpt":
+        block = gpt.GPTBlock(models.GPTConfig(
+            hidden_size=128, num_heads=2, max_seq_len=256, use_flash=True,
+            dtype=jnp.bfloat16))
+    else:
+        block = bert.TransformerLayer(dataclasses.replace(
+            models.BERT_LARGE, hidden_size=128, num_heads=2,
+            intermediate_size=256, use_flash=True))
+    params = jax.eval_shape(block.init, jax.random.PRNGKey(0), x)
+    lowered = jax.jit(jax.grad(lambda p, x: jnp.sum(
+        block.apply(p, x).astype(jnp.float32) ** 2))).trace(
+            params, x).lower(lowering_platforms=("tpu",)).as_text(
+                debug_info=True)
+    locations = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', lowered,
+                                re.M))
+    calls = [locations[ref] for ref in re.findall(
+        r'@tpu_custom_call\(.*loc\((#loc\d+)\)$', lowered, re.M)]
+    assert len(calls) == 3, calls     # forward, dq, dkv
+    for name in calls:
+        assert name.endswith(f"/hvd_block/hvd_attn/{module}/pallas_call"), \
+            name
+    products = [n for n in locations.values() if n.endswith("dot_general")
+                and f"/{module}/" in n]
+    assert products and all(f"/{module}/hvd_attn_proj/" in n
+                            for n in products), products
+
+
 def test_eager_update_writes_the_spine_s_spans(hvd_single, tmp_path):
     path = _profile_eager_update(hvd_single, tmp_path)
     trace = trace_reduce.read_xplane(path, steps=1)

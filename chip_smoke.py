@@ -35,12 +35,14 @@
                                      concatenated to 192, v padded), forward
                                      and backward timed; nothing else
     python chip_smoke.py --tied-head
-                                     one chip: ``ops/tied_head.py`` at a block
-                                     of ``zaya1-moe-ep2-s16384``'s and of
-                                     ``jamba2-ssm-tp4-s16384``'s head against
-                                     the ``jax.numpy`` product and statistics,
-                                     alone and inside the whole head's value
-                                     and gradients, both timed; nothing else
+                                     one chip: ``ops/tied_head.py`` at the
+                                     four blocked heads of the benchmark
+                                     (ZAYA's and Jamba's tied, Laguna's and
+                                     JoyAI's of their own) by the block of
+                                     tokens, against the ``jax.numpy`` product
+                                     and statistics, alone and inside the
+                                     whole head's value and gradients, both
+                                     timed; nothing else
 
 One chip: the device JAX found, a clean build of the C++ core and
 ``hvd.init()`` on it, the Pallas kernels alone against their references (at
@@ -957,22 +959,46 @@ def flash_mla(length: int = 16384, heads: int = 4, head_dim: int = 128,
     return report
 
 
-def tied_head(heads=((2048, 131136), (2560, 16384)), tokens: int = 16384,
-              block: int = 2048, repeats: int = 5, chain: int = 4,
-              interpret: bool = False) -> dict:
-    """``ops/tied_head.py`` alone and in its place (the defaults are the two
-    tied heads of the benchmark: ``zaya1-moe-ep2-s16384``'s 131,136 rows of
-    2,048 and ``jamba2-ssm-tp4-s16384``'s 16,384 held rows of 2,560, a block
-    of 2,048 tokens of 16,384, bfloat16).  A block's logits and log-sum-exp
-    by ``hvd_head_logits`` against the ``jax.numpy`` product and row
-    statistics as XLA compiles them alone, each timed as one of ``chain`` in
-    one compiled program; then value, ``dx`` and ``d table`` of
-    ``tied_head_cross_entropy`` over all the tokens with the kernel and with
+def _whole_logits_loss(tied: bool, x, matrix, labels, weights):
+    """The blocked head's loss with the float32 logits whole: the product in
+    ``x.dtype`` and ``softmax_cross_entropy``, the form ``laguna.Laguna.loss``
+    and ``joyai.JoyAI.loss`` had before ``losses.head_cross_entropy``."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import losses
+
+    table = matrix.astype(x.dtype)
+    logits = jnp.dot(x, table.T if tied else table,
+                     preferred_element_type=jnp.float32)
+    return jnp.sum(weights * losses.softmax_cross_entropy(logits, labels))
+
+
+# The four blocked heads of the benchmark, ``(d, rows, tied)``: the tied
+# tables of ``zaya1-moe-ep2-s16384`` and ``jamba2-ssm-tp4-s16384`` (``[V, d]``)
+# and the heads of their own of ``laguna-swa-ep32-s16384`` and
+# ``joyai-mla-ep16-s16384`` (``[d, V]``).
+HEADS = ((2048, 131136, True), (2560, 16384, True),
+         (3072, 12544, False), (2048, 16160, False))
+
+
+def tied_head(heads=HEADS, tokens: int = 16384,
+              blocks=(2048, 4096, 8192, 16384), repeats: int = 5,
+              chain: int = 4, interpret: bool = False) -> dict:
+    """``ops/tied_head.py`` alone and in its place, by the head and by the
+    block of tokens (the defaults: the four heads above, 16,384 tokens,
+    bfloat16).  A block's logits and log-sum-exp by ``hvd_head_logits``
+    against the ``jax.numpy`` product and row statistics as XLA compiles
+    them alone, each timed as one of ``chain`` in one compiled program; then
+    value, ``dx`` and the matrix's gradient of ``tied_head_cross_entropy`` /
+    ``head_cross_entropy`` over all the tokens with the kernel and with
     ``_block_nll``'s ``jax.numpy``, and both times: the second pair is the
     one that says what a step gains, since XLA schedules its own product
-    differently beside the two backward ones."""
-    from unittest import mock
-
+    differently beside the two backward ones.  Where all the tokens'
+    float32 logits take less than 2 GiB, the head with every logit alive
+    (:func:`_whole_logits_loss`) is compared and timed too.  ``blocks``: the
+    blocks of tokens a head is timed at, those left out whose float32 logits
+    would take 2 GiB or more (ZAYA's past 2,048); ``models/losses.py`` itself
+    takes the largest whose logits fit ``HEAD_BLOCK_BYTES``."""
     import jax
     import jax.numpy as jnp
 
@@ -997,45 +1023,63 @@ def tied_head(heads=((2048, 131136), (2560, 16384)), tokens: int = 16384,
             return kept, x
         return jax.jit(run)
 
-    def whole(head):
+    def whole(head, loss, rows, block):
         # A function of its own a side (jit's cache is keyed on it), traced
         # while ``_block_nll`` finds ``head`` in the kernel's place.
         def run(*args):
             with mock.patch.object(losses, "head_logits", head), \
-                    mock.patch.object(losses, "HEAD_BLOCK", block):
-                return jax.value_and_grad(losses.tied_head_cross_entropy,
-                                          argnums=(0, 1))(*args)
+                    mock.patch.object(losses, "HEAD_BLOCK_BYTES",
+                                      4 * rows * block):
+                return jax.value_and_grad(loss, argnums=(0, 1))(*args)
         return jax.jit(run)
 
     checks, report = [], {}
-    for d, rows in heads:
-        tag = f"d={d}/rows={rows}"
+
+    def compare(tag, got, want, tol):
+        # (loss, (dx, d matrix)) of two sides.
+        _check(checks, f"{tag}/loss", got[0], want[0], 1e-6)
+        _check(checks, f"{tag}/dx", got[1][0], want[1][0], TOL_BF16_FWD)
+        _check(checks, f"{tag}/dmatrix", got[1][1], want[1][1], tol)
+
+    for d, rows, tied in heads:
         ks = jax.random.split(jax.random.PRNGKey(rows), 4)
         x = jax.random.normal(ks[0], (tokens, d)).astype(jnp.bfloat16)
         table = jax.random.normal(ks[1], (rows, d)) / 20
         labels = jax.random.randint(ks[2], (tokens,), 0, rows)
         weights = jax.random.uniform(ks[3], (tokens,)) / tokens
-        xb, tb = x[:block], table.astype(jnp.bfloat16)
-        for name, a, b in zip(("logits", "lse"), jax.jit(kernel)(xb, tb),
-                              jax.jit(dense)(xb, tb)):
-            _check(checks, f"{tag}/{name}", a, b, 2e-6)
-        for form, fn in (("kernel", kernel), ("dense", dense)):
-            ms = _best_ms(repeats, chained(fn), xb, tb) / chain
-            report[f"{form}_ms/{tag}"] = round(ms, 3)
-            report[f"{form}_tflop_s/{tag}"] = round(
-                2 * block * d * rows / max(ms, 1e-3) / 1e9, 1)
-        sides = {"kernel": whole(kernel), "dense": whole(lambda *_: None)}
-        got, want = (sides[form](x, table, labels, weights)
-                     for form in ("kernel", "dense"))
-        _check(checks, f"{tag}/loss", got[0], want[0], 1e-6)
-        _check(checks, f"{tag}/dx", got[1][0], want[1][0], TOL_BF16_FWD)
-        _check(checks, f"{tag}/dtable", got[1][1], want[1][1], 1e-3)
-        del got, want
-        for form, fn in sides.items():
-            report[f"head_{form}_ms/{tag}"] = _best_ms(
-                repeats, fn, x, table, labels, weights)
-    report = emit("tied_head", checks=checks, tokens=tokens, block=block,
-                  chain=chain, **report)
+        matrix, loss = (table, losses.tied_head_cross_entropy) if tied else (
+            table.T, losses.head_cross_entropy)
+        tb = table.astype(jnp.bfloat16)
+        for block in (b for b in blocks if 4 * rows * b < 2 ** 31):
+            tag = f"d={d}/rows={rows}/block={block}"
+            xb = x[:block]
+            for name, a, b in zip(("logits", "lse"), jax.jit(kernel)(xb, tb),
+                                  jax.jit(dense)(xb, tb)):
+                _check(checks, f"{tag}/{name}", a, b, 2e-6)
+            for form, fn in (("kernel", kernel), ("dense", dense)):
+                ms = _best_ms(repeats, chained(fn), xb, tb) / chain
+                report[f"{form}_ms/{tag}"] = round(ms, 3)
+                report[f"{form}_tflop_s/{tag}"] = round(
+                    2 * block * d * rows / max(ms, 1e-3) / 1e9, 1)
+            sides = {"kernel": whole(kernel, loss, rows, block),
+                     "dense": whole(lambda *_: None, loss, rows, block)}
+            compare(tag, *(sides[form](x, matrix, labels, weights)
+                           for form in ("kernel", "dense")), 1e-3)
+            for form, fn in sides.items():
+                report[f"head_{form}_ms/{tag}"] = _best_ms(
+                    repeats, fn, x, matrix, labels, weights)
+        if 4 * rows * tokens < 2 ** 31:
+            # Every logit alive, as a model without the blocks makes them.
+            side = jax.jit(jax.value_and_grad(functools.partial(
+                _whole_logits_loss, tied), argnums=(0, 1)))
+            # (Its cast's transpose rounds ``d matrix`` to bfloat16.)
+            compare(f"d={d}/rows={rows}/whole",
+                    *(fn(x, matrix, labels, weights)
+                      for fn in (sides["kernel"], side)), TOL_BF16_FWD)
+            report[f"head_whole_ms/d={d}/rows={rows}"] = _best_ms(
+                repeats, side, x, matrix, labels, weights)
+    report = emit("tied_head", checks=checks, tokens=tokens, chain=chain,
+                  **report)
     _raise_on_failed("tied_head", checks)
     return report
 
@@ -1060,7 +1104,7 @@ def main(argv=None) -> int:
                     help="check and time the flash kernels with a second "
                          "score operand, and nothing else")
     ap.add_argument("--tied-head", action="store_true",
-                    help="check and time the tied head's logits kernel, "
+                    help="check and time the blocked heads' logits kernel, "
                          "and nothing else")
     ap.add_argument("--worker", action="store_true",
                     help="internal: one launch_np4 worker")

@@ -78,7 +78,7 @@ from ..parallel.moe import dispatch_experts, expert_load
 from ..parallel.tensor_parallel import vocab_parallel_embedding
 from .jamba import PairedDense, RowParallel
 from .laguna import EMBEDDING_STDDEV, mixture_sum
-from .losses import softmax_cross_entropy
+from .losses import head_cross_entropy
 from .sdar import RMSNorm, _expert_init
 from .zaya import balancing_bias
 
@@ -409,14 +409,22 @@ class JoyAI(nn.Module):
 
     def loss(self, ids):
         """Mean over the ``B x (S - 1)`` predicting positions of the next
-        token's negative log-likelihood over the rows held."""
+        token's negative log-likelihood over the rows held, through the
+        blocked head (``losses.head_cross_entropy``: :meth:`head`'s logits and
+        their row statistics from one kernel on a TPU, ``d logits`` made on
+        the way into the two backward products; no ``[B x S, V]`` array but
+        the float32 logits themselves)."""
         self._one_chip_s_rows("loss")
-        logits = self.head(self.hidden(ids))
+        x = self.hidden(ids)
         batch, seq = ids.shape
         with jax.named_scope("hvd_lm_head"):
-            weights = (jnp.arange(seq) < seq - 1) / (batch * (seq - 1.0))
-            nll = softmax_cross_entropy(logits, jnp.roll(ids, -1, axis=1))
-            return jnp.sum(nll * weights.astype(jnp.float32))
+            predicts = jnp.arange(seq) < seq - 1
+            weights = jnp.broadcast_to(predicts / (batch * (seq - 1.0)),
+                                       ids.shape)
+            return head_cross_entropy(
+                x.reshape(batch * seq, -1), self.lm_head,
+                jnp.roll(ids, -1, axis=1).reshape(-1),
+                weights.reshape(-1).astype(jnp.float32))
 
     def _one_chip_s_rows(self, what: str) -> None:
         if self.axis_name is not None:

@@ -1,9 +1,12 @@
 """The one softmax cross-entropy of the models' loss functions
-(``gpt.lm_loss``, ``bert.mlm_loss`` / ``nsp_loss``, ``mlp.xent_loss``), and
-the same under a head tied to the embedding, a block of tokens at a time
-(``zaya.lm_loss``, ``jamba.lm_loss``: :func:`tied_head_cross_entropy`, at the
-end; on a TPU a block's logits and their log-sum-exp are one kernel's,
-``ops/tied_head.py``).
+(``gpt.lm_loss``, ``bert.mlm_loss`` / ``nsp_loss``, ``mlp.xent_loss``,
+``sdar.block_diffusion_loss``), and the same with the head's product inside,
+a block of tokens at a time (at the end): :func:`tied_head_cross_entropy` for
+a head tied to the embedding ``[V, d]`` (``zaya.Zaya.loss``,
+``jamba.Jamba.loss``) and :func:`head_cross_entropy` for a head's own kernel
+``[d, V]`` (``laguna.Laguna.loss``, ``joyai.JoyAI.loss``), which is the first
+on the kernel turned; on a TPU a block's logits and their log-sum-exp are
+one kernel's, ``ops/tied_head.py``.
 
 ``log_softmax`` followed by ``take_along_axis`` writes a log-probability for
 every class though the loss reads one a row, and autodiff keeps that array
@@ -66,40 +69,45 @@ softmax_cross_entropy.defvjp(_forward, _backward)
 
 
 # ---------------------------------------------------------------------------
-# A tied head and its cross-entropy, a block of tokens at a time
+# A head and its cross-entropy, a block of tokens at a time
 # ---------------------------------------------------------------------------
 
-# Tokens a block.  The float32 logits of one block are ``HEAD_BLOCK x V x 4``
-# bytes (1.07 GB at 131,136 rows) and nothing else of that shape lives; a
-# block reads the embedding three times and the gradient's accumulator once
-# each way, so fewer, larger blocks cost fewer bytes.  The logits themselves
-# are written once (by the product that makes them, which on a TPU folds the
-# row statistics as it goes) and read once by each of the two backward
-# products, which make ``d logits`` of them on the way in.
-HEAD_BLOCK = 2048
+# What the float32 logits of one block may take, in bytes; nothing else of
+# that shape lives.  A block reads the head's matrix three times and the
+# gradient's accumulator once each way, so fewer, larger blocks cost fewer
+# bytes: 131,136 rows (ZAYA's tied table) make it 2,048 tokens of 16,384, and
+# a head of 12,544 to 16,384 rows takes all 16,384 tokens as one block, with
+# no scan (in their cells Laguna's and JoyAI's steps read 6.0 and 2.4 ms
+# shorter so than in blocks of 2,048, and compile to 1.5 and 0.5 GB less:
+# PERF.md section 6, PR 57).  The logits themselves are written once (by the
+# product that makes them, which on a TPU folds the row statistics as it
+# goes) and read once by each of the two backward products, which make
+# ``d logits`` of them on the way in.
+HEAD_BLOCK_BYTES = 1100 * 1000 * 1000
 
 
-def _head_blocks(tokens: int) -> int:
-    """The fewest blocks of at most ``HEAD_BLOCK`` tokens that divide
-    ``tokens`` evenly."""
-    return next(n for n in range(-(-tokens // HEAD_BLOCK), tokens + 1)
+def _head_blocks(tokens: int, rows: int) -> int:
+    """The fewest blocks that divide ``tokens`` evenly, each one's float32
+    logits over ``rows`` classes within ``HEAD_BLOCK_BYTES``."""
+    most = max(1, HEAD_BLOCK_BYTES // (4 * rows))
+    return next(n for n in range(-(-tokens // most), tokens + 1)
                 if tokens % n == 0)
 
 
-def _block_nll(x, embedding, labels):
-    """One block: float32 logits ``x . embedding^T`` (the product in
-    ``x.dtype``, accumulated in float32), each row's negative
+def _block_nll(x, table, labels):
+    """One block: float32 logits ``x . table^T`` (``table`` [V, d], the
+    product in ``x.dtype``, accumulated in float32), each row's negative
     log-likelihood, and what the gradient needs of the softmax, the row's
     log-sum-exp.  On a TPU the logits and the log-sum-exp are one kernel's
     (``hvd_head_logits``, ``ops/tied_head.py``): the product's tiles are
     folded into the rows' statistics before they leave VMEM.  Elsewhere the
     product is XLA's and the statistics a second pass over its logits."""
-    made = head_logits(x, embedding)
+    made = head_logits(x, table)
     if made is not None:
         logits, lse = made
         picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)
         return (lse - picked)[:, 0], logits, lse
-    logits = jax.lax.dot_general(x, embedding, (((1,), (1,)), ((), ())),
+    logits = jax.lax.dot_general(x, table, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     top = jnp.max(logits, axis=-1, keepdims=True)
     log_sum = jnp.log(jnp.sum(jnp.exp(logits - top), axis=-1, keepdims=True))
@@ -107,9 +115,9 @@ def _block_nll(x, embedding, labels):
     return (log_sum - (picked - top))[:, 0], logits, top + log_sum
 
 
-def _in_blocks(*per_token):
+def _in_blocks(rows: int, *per_token):
     """Each ``[T, ...]`` array as ``[blocks, T / blocks, ...]``."""
-    blocks = _head_blocks(per_token[0].shape[0])
+    blocks = _head_blocks(per_token[0].shape[0], rows)
     return tuple(a.reshape(blocks, a.shape[0] // blocks, *a.shape[1:])
                  for a in per_token)
 
@@ -118,9 +126,10 @@ def tied_head_cross_entropy(x, embedding, labels, weights):
     """``sum_t weights[t] x nll_t`` where ``nll_t`` is the negative
     log-likelihood of ``labels[t]`` under ``softmax(x[t] . embedding^T)``:
     a head tied to the embedding ``[V, d]`` and :func:`softmax_cross_entropy`
-    in one, over ``x`` [T, d], a block of ``HEAD_BLOCK`` tokens at a time (a
-    ``lax.scan``), so that no ``[T, V]`` array ever lives: at 16,384 tokens
-    and 131,136 rows the float32 logits would be 8.6 GB.
+    in one, over ``x`` [T, d], a block of tokens at a time (a ``lax.scan``;
+    a block's float32 logits take at most ``HEAD_BLOCK_BYTES``), so that no
+    ``[T, V]`` array ever lives: at 16,384 tokens and 131,136 rows the
+    float32 logits would be 8.6 GB.
 
     The products run in ``x.dtype`` (the embedding is cast to it once) and
     accumulate in float32; the logits of a block are float32.  ``weights``
@@ -150,6 +159,22 @@ def tied_head_cross_entropy(x, embedding, labels, weights):
                  _vary_like(weights, x))
 
 
+def head_cross_entropy(x, kernel, labels, weights):
+    """:func:`tied_head_cross_entropy` for a head of its own: ``nll_t`` under
+    ``softmax(x[t] . kernel)``, ``kernel`` [d, V] as ``laguna.Laguna`` and
+    ``joyai.JoyAI`` store their ``lm_head``.  It is the same function of the
+    kernel turned: the float32 master is cast to ``x.dtype`` and turned to
+    the ``[V, d]`` rows the blocks read in one pass a call, and ``d kernel``
+    is the float32 ``[V, d]`` sum turned back once (a layout of the product
+    that writes it, where there is one block), never rounded to ``x.dtype``.
+    The same blocks, the same passes, the same arithmetic.  What it
+    replaces: the whole float32 logits from XLA's product handed to
+    :func:`softmax_cross_entropy`, which reads them again for the sums of
+    the exponentials and hands the two backward products a float32
+    ``d logits``."""
+    return tied_head_cross_entropy(x, kernel.T, labels, weights)
+
+
 @jax.custom_vjp
 def _tied(x, embedding, labels, weights):
     table = embedding.astype(x.dtype)
@@ -159,7 +184,7 @@ def _tied(x, embedding, labels, weights):
         return total + jnp.sum(wb * _block_nll(xb, table, lb)[0]), None
 
     return jax.lax.scan(block, _vary_like(jnp.zeros((), jnp.float32), x),
-                        _in_blocks(x, labels, weights))[0]
+                        _in_blocks(table.shape[0], x, labels, weights))[0]
 
 
 def _tied_forward(x, embedding, labels, weights):
@@ -180,8 +205,8 @@ def _tied_forward(x, embedding, labels, weights):
 
     start = (_vary_like(jnp.zeros((), jnp.float32), x),
              _vary_like(jnp.zeros(embedding.shape, jnp.float32), x))
-    (total, d_table), dx = jax.lax.scan(block, start,
-                                        _in_blocks(x, labels, weights))
+    (total, d_table), dx = jax.lax.scan(
+        block, start, _in_blocks(table.shape[0], x, labels, weights))
     return total, (dx.reshape(x.shape), d_table.astype(embedding.dtype))
 
 

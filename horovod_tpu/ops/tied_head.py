@@ -1,9 +1,12 @@
-"""The logits of a tied head and their row statistics as one Pallas TPU
-kernel: ``x [T, d] . table [V, d]^T`` in float32 and each row's
+"""The logits of a language model's head and their row statistics as one
+Pallas TPU kernel: ``x [T, d] . table [V, d]^T`` in float32 and each row's
 log-sum-exp, from one pass.
 
-It is the first of the three products of a block of
-``models/losses.py:tied_head_cross_entropy``.  Left to XLA the product writes
+It is the first of the three products of a block of the blocked head in
+``models/losses.py``: ``tied_head_cross_entropy``, whose table is the
+embedding (ZAYA's, Jamba's), and ``head_cross_entropy``, whose table is a
+head's own kernel ``[d, V]`` cast and turned to ``[V, d]`` in one pass a step
+(Laguna's, JoyAI's).  Left to XLA the product writes
 a block's float32 logits (1.07 GB at 2,048 tokens x 131,136 rows) and a
 second pass reads them whole for the row maxima and the sums of the
 exponentials; here a tile of the logits is folded into a running maximum and
@@ -42,7 +45,7 @@ nothing.
 One call, named ``hvd_head_logits`` for a trace.  Off the TPU
 :func:`head_logits` returns None and the caller keeps its ``jax.numpy``; the
 kernel is unit-tested in interpret mode (``tests/single/test_tied_head.py``),
-compiled for a described v5e at both cells' shapes (``tests/single/
+compiled for a described v5e at the four cells' shapes (``tests/single/
 test_tpu_compile.py``) and held against the ``jax.numpy`` form on the chip by
 ``chip_smoke.py --tied-head``.
 """
@@ -160,7 +163,7 @@ def _head_logits(x, table, plan: HeadPlan, interpret: bool):
 
 
 def head_logits(x, table, *, interpret=None):
-    """``(logits, lse)`` of a tied head: ``x [T, d] . table [V, d]^T`` as
+    """``(logits, lse)`` of a head: ``x [T, d] . table [V, d]^T`` as
     float32 ``[T, V]`` (operands in their own dtype, the same for both,
     accumulated in float32; the transpose of what the kernel wrote, which
     XLA reads as a layout and does not copy) and float32 ``[T, 1]``, each

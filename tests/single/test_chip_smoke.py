@@ -149,19 +149,25 @@ def test_flash_mla_phase_tiny():
 
 
 def test_tied_head_phase_tiny():
-    """The tied head's kernel (interpreted) against the ``jax.numpy`` product
-    and statistics, alone and inside the whole head, at a table of whole
-    tiles and at one with a last tile in part, and the timing table's
-    keys."""
-    report = chip_smoke.tied_head(heads=((128, 1024), (256, 584)), tokens=256,
-                                  block=128, repeats=1, chain=2,
-                                  interpret=True)
-    tags = ["d=128/rows=1024", "d=256/rows=584"]
-    assert [c["name"] for c in report["checks"]] == [
-        f"{tag}/{n}" for tag in tags
-        for n in ("logits", "lse", "loss", "dx", "dtable")]
+    """The blocked head's kernel (interpreted) against the ``jax.numpy``
+    product and statistics, alone and inside the whole head, at a tied table
+    of whole tiles and at a head of its own with a last tile in part, by the
+    block, and the timing table's keys."""
+    report = chip_smoke.tied_head(
+        heads=((128, 1024, True), (256, 584, False)), tokens=256,
+        blocks=(128, 256), repeats=1, chain=2, interpret=True)
+    tags = [f"d={d}/rows={rows}/block={block}"
+            for d, rows in ((128, 1024), (256, 584)) for block in (128, 256)]
+    heads = ["d=128/rows=1024", "d=256/rows=584"]
+    names = [c["name"] for c in report["checks"]]
+    assert sorted(names) == sorted(
+        [f"{tag}/{n}" for tag in tags
+         for n in ("logits", "lse", "loss", "dx", "dmatrix")]
+        + [f"{head}/whole/{n}" for head in heads
+           for n in ("loss", "dx", "dmatrix")]), names
     assert all(c["ok"] for c in report["checks"]), report["checks"]
     assert {f"{form}_{unit}/{tag}" for form in ("kernel", "dense")
             for unit in ("ms", "tflop_s") for tag in tags} | {
                 f"head_{form}_ms/{tag}" for form in ("kernel", "dense")
-                for tag in tags} <= set(report)
+                for tag in tags} | {
+                    f"head_whole_ms/{head}" for head in heads} <= set(report)

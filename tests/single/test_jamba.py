@@ -10,6 +10,7 @@ the right."""
 
 import dataclasses
 import functools
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +49,9 @@ def _stirred(variables, seed=5):
         name = jax.tree_util.keystr(path)
         if leaf.ndim != 1 and not name.endswith("['A_log']"):
             return leaf
-        key = jax.random.fold_in(jax.random.key(seed), hash(name) % (1 << 30))
+        # (crc32, not ``hash``: a str's hash is drawn anew every process.)
+        key = jax.random.fold_in(jax.random.key(seed),
+                                 zlib.crc32(name.encode()) % (1 << 30))
         return leaf + 0.2 * jax.random.normal(key, leaf.shape)
 
     return jax.tree_util.tree_map_with_path(stir, variables)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from benchmark.families import joyai as family
 from benchmark.references import joyai as reference
 from horovod_tpu.models import joyai as jy
+from horovod_tpu.models import losses
 from horovod_tpu.models.sdar import rotary as half_split_rotary
 
 TINY = jy.JOYAI_TINY
@@ -276,3 +277,41 @@ def test_an_axis_sums_attention_and_the_dense_layer_and_refuses_the_rest():
         shard_map(lambda p, x: jy.JoyAIMoE(TINY, "tp").apply(
             {"params": p}, x), mesh=mesh, in_specs=(P(), P()),
             out_specs=P())(sparse["params"]["layer_1"]["moe"], x)
+
+
+@pytest.mark.parametrize("blocks", [1, 3], ids=["one-block", "three-blocks"])
+def test_the_loss_through_the_blocked_head_against_whole_logits(blocks,
+                                                                monkeypatch):
+    """``JoyAI.loss`` (``losses.head_cross_entropy`` on ``hidden``) against
+    the form it had, ``head`` and ``softmax_cross_entropy`` over the whole
+    float32 logits: the value and every parameter's gradient, at the family's
+    rehearsal sizes (2 x 48 tokens, 512 rows held), as one block and as
+    three."""
+    from benchmark import run
+
+    cfg = family._joyai_config(
+        run.load_json("configs", "joyai-llm-flash-ep16.json"), True)
+    monkeypatch.setattr(losses, "HEAD_BLOCK_BYTES",
+                        4 * cfg.rows_held * 96 // blocks)
+    assert losses._head_blocks(96, cfg.rows_held) == blocks
+    model = jy.JoyAI(cfg)
+    ids = jax.random.randint(jax.random.key(1), (2, 48), 0, cfg.rows_held)
+    v = _stirred(model, ids)
+
+    def whole(v):
+        nll = losses.softmax_cross_entropy(model.apply(v, ids),
+                                           jnp.roll(ids, -1, axis=1))
+        predicts = jnp.arange(48) < 47
+        return jnp.sum(nll * predicts / (2 * 47.0))
+
+    got, got_grads = jax.jit(jax.value_and_grad(
+        lambda v: jy.lm_loss(model, v, ids)))(v)
+    want, want_grads = jax.jit(jax.value_and_grad(whole))(v)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    assert got_grads["params"]["lm_head"].shape == (cfg.hidden_size,
+                                                    cfg.rows_held)
+    flat, _ = jax.tree_util.tree_flatten_with_path(want_grads)
+    for (path, b), a in zip(flat, jax.tree_util.tree_leaves(got_grads)):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-6 * float(jnp.max(jnp.abs(b))) + 1e-9,
+            err_msg=jax.tree_util.keystr(path))

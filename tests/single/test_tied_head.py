@@ -1,8 +1,9 @@
-"""``ops/tied_head.py``: the tied head's logits and their log-sum-exp from one
-kernel (``hvd_head_logits``), run by the Pallas interpreter, against the
+"""``ops/tied_head.py``: a blocked head's logits and their log-sum-exp from
+one kernel (``hvd_head_logits``), run by the Pallas interpreter, against the
 ``jax.numpy`` form of ``models/losses.py:_block_nll``; and
-``tied_head_cross_entropy`` with the kernel forced on against every logit
-alive."""
+``tied_head_cross_entropy`` and ``head_cross_entropy`` (the same blocks for a
+table ``[V, d]`` and for a head's own kernel ``[d, V]``) with the kernel
+forced on and off against every logit alive."""
 
 import functools
 
@@ -128,7 +129,7 @@ def test_the_head_s_gradients_with_the_kernel_on(tokens, rows, forced,
     """Value, d x and both gradients of the tied matrix (the gather's and
     the head's) against every logit alive: ``tests/single/test_zaya.py``'s
     reference, the logits and their statistics by the kernel."""
-    monkeypatch.setattr(losses, "HEAD_BLOCK", 128)
+    monkeypatch.setattr(losses, "HEAD_BLOCK_BYTES", 4 * rows * 128)
     keys = jax.random.split(jax.random.key(tokens + rows), 5)
     table = jax.random.normal(keys[0], (rows, 128)) / 4
     ids = jax.random.randint(keys[1], (tokens,), 0, rows)
@@ -157,12 +158,115 @@ def test_the_head_s_gradients_with_the_kernel_on(tokens, rows, forced,
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
 
 
+@pytest.mark.parametrize("tokens,rows,blocks", [
+    (16384, 131136, 8), (16384, 16384, 1), (16384, 12544, 1),
+    (16384, 16160, 1), (16384, 262144, 16), (8 * 1023, 50304, 2),
+    (100, 10 ** 9, 100)],
+    ids=["zaya", "jamba", "laguna", "joyai", "twice-zaya-s-rows",
+         "gpt2-s-tokens", "a-row-past-the-budget"])
+def test_a_block_is_as_many_tokens_as_its_logits_bytes_allow(tokens, rows,
+                                                             blocks):
+    """The fewest blocks that divide the tokens evenly with a block's
+    float32 logits inside ``HEAD_BLOCK_BYTES``: by the shapes alone."""
+    assert losses._head_blocks(tokens, rows) == blocks
+    assert tokens % blocks == 0
+    assert (4 * rows * (tokens // blocks) <= losses.HEAD_BLOCK_BYTES
+            or blocks == tokens)
+
+
+UNTIED = {"three-blocks": (384, 384), "a-last-tile-of-64": (256, 320),
+          "one-block": (128, 200)}
+
+
+def _untied_operands(tokens, rows, d=128):
+    keys = jax.random.split(jax.random.key(tokens * rows), 5)
+    kernel = jax.random.normal(keys[0], (d, rows)) / 4
+    hidden = jax.random.normal(keys[1], (tokens, d))
+    labels = jax.random.randint(keys[2], (tokens,), 0, rows).at[::7].set(
+        rows - 1)
+    weights = jax.random.uniform(keys[3], (tokens,)) / tokens
+    mix = jax.random.normal(keys[4], (d, d)) / 5
+    return kernel, hidden, labels, weights, mix
+
+
+@pytest.mark.parametrize("kernel_on", [True, False],
+                         ids=["kernel", "jax.numpy"])
+@pytest.mark.parametrize("tokens,rows", UNTIED.values(), ids=UNTIED.keys())
+def test_a_head_of_its_own_against_whole_logits(tokens, rows, kernel_on,
+                                                forced, monkeypatch):
+    """``head_cross_entropy``: value, ``dx`` (through ``mix``) and the
+    gradient of the kernel ``[d, V]`` against ``softmax_cross_entropy(x .
+    kernel)`` in float32, a block of 128 tokens at a time, at a ``V`` that is
+    no multiple of the vocabulary tile and a ``T`` of one block and of
+    several, with the logits and their statistics by the kernel and by
+    ``jax.numpy``."""
+    if not kernel_on:
+        monkeypatch.setattr(losses, "head_logits", lambda x, table: None)
+    monkeypatch.setattr(losses, "HEAD_BLOCK_BYTES", 4 * rows * 128)
+    assert losses._head_blocks(tokens, rows) == tokens // 128
+    kernel, hidden, labels, weights, mix = _untied_operands(tokens, rows)
+
+    def blocked(kernel, mix):
+        return 3.0 * losses.head_cross_entropy(hidden @ mix, kernel, labels,
+                                               weights)
+
+    def whole(kernel, mix):
+        return 3.0 * jnp.sum(weights * losses.softmax_cross_entropy(
+            jnp.dot(hidden @ mix, kernel), labels))
+
+    text = str(jax.make_jaxpr(jax.grad(blocked))(kernel, mix))
+    assert ("hvd_head_logits" in text) == kernel_on
+    got, got_grads = jax.value_and_grad(blocked, argnums=(0, 1))(kernel, mix)
+    want, want_grads = jax.value_and_grad(whole, argnums=(0, 1))(kernel, mix)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    assert float(blocked(kernel, mix)) == pytest.approx(float(want), rel=1e-6)
+    assert got_grads[0].shape == kernel.shape
+    assert got_grads[0].dtype == jnp.float32
+    for a, b in zip(got_grads, want_grads):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+def test_a_head_of_its_own_in_bfloat16_keeps_a_float32_gradient(forced,
+                                                               monkeypatch):
+    """The models' call: ``x`` in bfloat16, the kernel a float32 master cast
+    once.  ``dx`` comes back in bfloat16, ``d kernel`` float32 ``[d, V]`` and
+    not rounded to bfloat16 on the way (the whole-logits form's cast rounds
+    it), each within bfloat16's reach of the float32 reference."""
+    tokens, rows = 256, 320
+    monkeypatch.setattr(losses, "HEAD_BLOCK_BYTES", 4 * rows * 128)
+    kernel, hidden, labels, weights, _ = _untied_operands(tokens, rows)
+    x = hidden.astype(jnp.bfloat16)
+    dx, dkernel = jax.grad(losses.head_cross_entropy, argnums=(0, 1))(
+        x, kernel, labels, weights)
+    want_dx, want_dkernel = jax.grad(
+        lambda x, k: jnp.sum(weights * losses.softmax_cross_entropy(
+            jnp.dot(x, k), labels)), argnums=(0, 1))(
+                x.astype(jnp.float32), kernel)
+    assert dx.dtype == jnp.bfloat16 and dkernel.dtype == jnp.float32
+    assert dkernel.shape == kernel.shape
+    assert np.any(dkernel != dkernel.astype(jnp.bfloat16).astype(jnp.float32))
+    for got, want in ((dx, want_dx), (dkernel, want_dkernel)):
+        assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) < (
+            2e-2 * float(jnp.max(jnp.abs(want))))
+
+
+@pytest.mark.parametrize("loss", ["tied_head_cross_entropy",
+                                  "head_cross_entropy"])
+def test_forward_mode_raises(loss):
+    """Reverse mode only, as ``softmax_cross_entropy``."""
+    kernel, hidden, labels, weights, _ = _untied_operands(128, 200)
+    matrix = kernel.T if loss.startswith("tied") else kernel
+    with pytest.raises(TypeError, match="custom_vjp"):
+        jax.jvp(lambda x: getattr(losses, loss)(x, matrix, labels, weights),
+                (hidden,), (hidden,))
+
+
 def test_inside_a_jitted_shard_map_step_under_check_vma(forced, monkeypatch):
     from jax import shard_map
     from jax.experimental.pallas import tpu as pltpu
     from jax.sharding import Mesh, PartitionSpec as P
 
-    monkeypatch.setattr(losses, "HEAD_BLOCK", 128)
+    monkeypatch.setattr(losses, "HEAD_BLOCK_BYTES", 4 * 200 * 128)
     # Of the two interpreters only this one runs inside ``shard_map``.
     monkeypatch.setattr(losses, "head_logits", functools.partial(
         th.head_logits, interpret=pltpu.InterpretParams()))

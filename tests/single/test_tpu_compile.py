@@ -426,16 +426,10 @@ def test_the_tied_head_s_kernel_compiles_at_both_cells_shapes(one_chip, d,
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
-@pytest.mark.parametrize("d,rows", HEADS.values(), ids=HEADS.keys())
-def test_the_tied_head_s_step_reads_the_kernel_s_logits_as_they_are(
-        one_chip, monkeypatch, d, rows):
-    """Value and gradients of ``tied_head_cross_entropy`` over 16,384 tokens
-    at both cells' widths with the kernel on: the scan's body holds one
-    ``hvd_head_logits``, no pass of XLA's own over a block's logits for the
-    row statistics (no reduction to ``[2048]``), no ``copy`` or ``transpose``
-    of a block's logits or ``d logits`` (the kernel writes ``[V, T]``, the
-    layout the two backward products read), and those two products as they
-    were: ``dx`` and the accumulation into the float32 ``[V, d]``."""
+def _blocked_head_texts(one_chip, monkeypatch, loss, matrix_shape, d):
+    """The compiled value and gradients of a blocked head's ``loss`` over
+    16,384 tokens of width ``d``, with the kernel and with ``_block_nll``'s
+    ``jax.numpy``."""
     from horovod_tpu.models import losses
     from horovod_tpu.ops import tied_head as th
 
@@ -445,51 +439,134 @@ def test_the_tied_head_s_step_reads_the_kernel_s_logits_as_they_are(
             else lambda x, table: None)
         # A function of its own a side: jit's cache is keyed on it.
         return _compiled_text(
-            lambda *a: jax.value_and_grad(
-                losses.tied_head_cross_entropy, argnums=(0, 1))(*a),
+            lambda *a: jax.value_and_grad(loss, argnums=(0, 1))(*a),
             *_shapes_on(one_chip, (
                 jax.ShapeDtypeStruct((16384, d), jnp.bfloat16),
-                jax.ShapeDtypeStruct((rows, d), jnp.float32),
+                jax.ShapeDtypeStruct(matrix_shape, jnp.float32),
                 jax.ShapeDtypeStruct((16384,), jnp.int32),
                 jax.ShapeDtypeStruct((16384,), jnp.float32))))
 
-    def census(text):
-        """The instructions outside fusions' bodies that make an array of a
-        block's logits' size, by opcode, and the products' result types."""
-        made, products = [], []
-        for name, lines in _computations(text).items():
-            for line in lines:
-                m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(",
-                             line)
-                if not m:
-                    continue
-                result, op = m.groups()
-                if op == "convolution":
-                    products.append(result.split("{")[0])
-                elif ("fused" not in name and op not in (
-                        "parameter", "get-tuple-element", "bitcast", "tuple")
-                      and re.match(rf"\w+\[({rows},2048|2048,{rows})\]",
-                                   result)):
-                    made.append(op)
-        return sorted(made), sorted(products)
+    return compiled(True), compiled(False)
 
-    kernel, plain = compiled(True), compiled(False)
+
+def _logits_census(text, rows, block):
+    """The instructions outside fusions' bodies that make an array of a
+    block's logits' size, by opcode, and the products' result types."""
+    made, products = [], []
+    for name, lines in _computations(text).items():
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+            if not m:
+                continue
+            result, op = m.groups()
+            if op == "convolution":
+                products.append(result.split("{")[0])
+            elif ("fused" not in name and op not in (
+                    "parameter", "get-tuple-element", "bitcast", "tuple")
+                  and re.match(rf"\w+\[({rows},{block}|{block},{rows})\]",
+                               result)):
+                made.append(op)
+    return sorted(made), sorted(products)
+
+
+def _head_products_in_the_forward(text):
+    """The products a compiled step holds under ``hvd_lm_head`` (the
+    kernel's call and XLA's own), every one of them in the forward pass: the
+    blocked head's reverse mode is by hand, ``dx`` and the matrix's gradient
+    are made beside the loss and the backward scales them."""
+    names = [m.group(1) for line in text.splitlines()
+             if " convolution(" in line or "tpu_custom_call" in line
+             for m in [re.search(r'op_name="([^"]*/hvd_lm_head/[^"]*)"', line)]
+             if m]
+    assert not [n for n in names if "transpose(" in n], names
+    return names
+
+
+def _row_reductions(text, block):
+    """XLA's own reductions to one float32 a token of a block."""
+    return [line for line in text.splitlines()
+            if re.search(rf"= f32\[{block}\]\S* reduce\(", line)]
+
+
+@pytest.mark.parametrize("d,rows", HEADS.values(), ids=HEADS.keys())
+def test_the_tied_head_s_step_reads_the_kernel_s_logits_as_they_are(
+        one_chip, monkeypatch, d, rows):
+    """Value and gradients of ``tied_head_cross_entropy`` over 16,384 tokens
+    at both cells' widths with the kernel on, a block as many tokens as
+    ``HEAD_BLOCK_BYTES`` of float32 logits hold (2,048 of ZAYA's 131,136
+    rows in a scan of eight, all 16,384 of Jamba's 16,384 rows and no scan):
+    the program holds one ``hvd_head_logits``, no pass of XLA's own over a
+    block's logits for the row statistics (no reduction to one float32 a
+    token), no ``copy`` or ``transpose`` of a block's logits or ``d logits``
+    (the kernel writes ``[V, T]``, the layout the two backward products
+    read), and those two products as they were: ``dx`` and the accumulation
+    into the float32 ``[V, d]``."""
+    from horovod_tpu.models import losses
+
+    blocks = losses._head_blocks(16384, rows)
+    assert blocks == {131136: 8, 16384: 1}[rows]
+    block = 16384 // blocks
+    kernel, plain = _blocked_head_texts(
+        one_chip, monkeypatch, losses.tied_head_cross_entropy, (rows, d), d)
     assert len(re.findall(r"hvd_head_logits[\w.]* = ", kernel)) == 1
+    assert ("while(" in kernel) == (blocks > 1)
     assert "hvd_head_logits" not in plain
-    made, products = census(kernel)
-    plain_made, plain_products = census(plain)
+    made, products = _logits_census(kernel, rows, block)
+    plain_made, plain_products = _logits_census(plain, rows, block)
     assert "copy" not in made and "transpose" not in made, made
     # XLA's own logits' product is gone and the two backward ones stand.
-    logits = f"f32[2048,{rows}]"
+    logits = f"f32[{block},{rows}]"
     assert plain_products.count(logits) == 1 and logits not in products
     plain_products.remove(logits)
     assert products == plain_products
-    assert f"f32[{rows},{d}]" in products and f"f32[2048,{d}]" in products
+    assert f"f32[{rows},{d}]" in products and f"f32[{block},{d}]" in products
     # No reduction over a block's logits is XLA's any more.
-    reduces = [line for line in kernel.splitlines()
-               if re.search(r"= f32\[2048\]\S* reduce\(", line)]
-    assert reduces == [] and re.search(
-        r"= f32\[2048\]\S* reduce\(", plain), reduces
+    assert _row_reductions(kernel, block) == []
+    assert _row_reductions(plain, block)
+
+
+UNTIED_HEADS = {"laguna": (3072, 12544), "joyai": (2048, 16160)}
+
+
+@pytest.mark.parametrize("d,rows", UNTIED_HEADS.values(),
+                         ids=UNTIED_HEADS.keys())
+def test_a_head_of_its_own_takes_the_blocked_head_s_path(one_chip,
+                                                         monkeypatch, d, rows):
+    """Value and gradients of ``head_cross_entropy`` over 16,384 tokens at
+    ``laguna-swa-ep32-s16384``'s and ``joyai-mla-ep16-s16384``'s heads (a
+    float32 kernel ``[d, V]``, ``x`` in bfloat16) with the kernel on: all the
+    tokens are one block by their logits' bytes, so the program holds no loop
+    and ``hvd_head_logits`` once; **nothing else makes an array of the
+    logits' size**, in any dtype (no ``d logits`` array: it is made inside
+    the two backward products' fusions; no ``copy`` or ``transpose`` of the
+    logits); no reduction of XLA's own to one float32 a token (no pass for
+    the row maxima or the sums of exponentials); the kernel's gradient leaves
+    the product float32 in the parameter's own ``[d, V]``, and the kernel is
+    turned to the ``[V, d]`` rows the blocks read once, by the cast."""
+    from horovod_tpu.models import losses
+
+    blocks = losses._head_blocks(16384, rows)
+    block = 16384 // blocks
+    kernel, plain = _blocked_head_texts(
+        one_chip, monkeypatch, losses.head_cross_entropy, (d, rows), d)
+    assert len(re.findall(r"hvd_head_logits[\w.]* = ", kernel)) == 1
+    assert ("while(" in kernel) == (blocks > 1)
+    assert "hvd_head_logits" not in plain
+    made, products = _logits_census(kernel, rows, block)
+    assert made == [], made
+    assert f"f32[{block},{rows}]" not in products
+    assert f"f32[{block},{rows}]" in _logits_census(plain, rows, block)[1]
+    assert f"f32[{d},{rows}]" in products and f"f32[{block},{d}]" in products
+    assert _row_reductions(kernel, block) == []
+    assert _row_reductions(plain, block)
+    # The one pass over the kernel that is no product: float32 [d, V] in,
+    # bfloat16 rows of d out.
+    turned = [m.group(1) for name, lines in _computations(kernel).items()
+              if "fused" not in name for line in lines
+              for m in [re.search(
+                  rf"= bf16\[(?:{d},{rows}|{rows},{d})\]\S* (\w+)\(", line)]
+              if m and m.group(1) not in ("parameter", "bitcast")]
+    assert len(turned) == 1, turned
 
 
 def test_selective_scan_compiles_at_the_jamba_cell_s_shapes_in_shard_map(
@@ -714,6 +791,10 @@ def test_a_laguna_step_copies_nothing_of_q_s_size_round_its_kernels(
     for kernel in ("fwd", "dq", "dkv"):
         assert len(re.findall(rf"hvd_flash_swa_{kernel}[\w.]* = ", text)) == 1
         assert len(re.findall(rf"hvd_flash_{kernel}[\w.]* = ", text)) == 1
+    # The loss goes through the blocked head: its kernel once, one block.
+    assert len(re.findall(r"hvd_head_logits[\w.]* = ", text)) == 1
+    products = _head_products_in_the_forward(text)
+    assert len(products) == 3, products
     moved = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
                        r"(copy|transpose)\(")
     least = seq * 6 * 128           # the full layer's q, the smaller of two
@@ -790,6 +871,10 @@ def test_a_joyai_step_copies_nothing_round_its_paired_kernels(topo,
     assert kernel_calls(text) == dict.fromkeys(
         ("hvd_flash_mla_fwd", "hvd_flash_mla_dq", "hvd_flash_mla_dkv"), 2)
     assert "hvd_flash_relayout" not in text
+    # The loss goes through the blocked head: its kernel once, one block.
+    assert len(re.findall(r"hvd_head_logits[\w.]* = ", text)) == 1
+    products = _head_products_in_the_forward(text)
+    assert len(products) == 3, products
     made = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
                       r"(copy|transpose|broadcast|pad|concatenate)\(")
     q, q_rope = seq * 4 * 128, seq * 4 * 64

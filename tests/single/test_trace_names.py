@@ -169,8 +169,9 @@ def test_a_laguna_step_names_what_its_layer_kinds_add():
     rotary turn, ``hvd_attn_gate`` the gate's product, its sigmoid and the
     0/1 product that spreads it, ``hvd_moe_shared`` the shared expert beside
     ``parallel/moe.py``'s ``hvd_moe_route`` and ``hvd_moe_experts``,
-    ``hvd_lm_head`` the final norm, the untied head and the loss; both passes
-    carry them, and the dense layer has none of the mixture's."""
+    ``hvd_lm_head`` the final norm, the head of its own and the loss (through
+    ``losses.head_cross_entropy``); both passes carry them, and the dense
+    layer has none of the mixture's."""
     from benchmark.families import laguna
     from horovod_tpu.models import laguna as model_laguna
 
@@ -199,7 +200,11 @@ def test_a_laguna_step_names_what_its_layer_kinds_add():
         assert under("hvd_moe_route", "", backward)
         assert under("hvd_moe_experts", "", backward)
         assert under("hvd_moe_shared", "dot_general", backward)
-        assert under("hvd_lm_head", "dot_general", backward)
+        # The blocked head's three products lie in its scan's body, whose
+        # names are relative to it here; its reverse mode is by hand, and the
+        # backward scales what the forward made.
+        assert under("hvd_lm_head", "", backward)
+    assert under("hvd_lm_head", "while")
     assert under("hvd_attn_gate", "logistic")
     # The rotary tables are made under the turn's scope, not the products'.
     assert not any("/hvd_attn_proj/" in n and n.endswith(("cos", "sin"))
@@ -245,7 +250,11 @@ def test_a_joyai_step_names_what_latent_attention_adds():
         assert under("hvd_moe_route", "", backward)
         assert under("hvd_moe_experts", "", backward)
         assert under("hvd_moe_shared", "dot_general", backward)
-        assert under("hvd_lm_head", "dot_general", backward)
+        # The blocked head's three products lie in its scan's body, whose
+        # names are relative to it here; its reverse mode is by hand, and the
+        # backward scales what the forward made.
+        assert under("hvd_lm_head", "", backward)
+    assert under("hvd_lm_head", "while")
     assert under("hvd_moe_route", "logistic")
     for kernel in ("q_a", "q_b_nope", "q_b_rope", "kv_a", "kv_b", "o_proj"):
         assert any(f"/hvd_attn_proj/{kernel}/" in n for n in names), kernel

@@ -352,9 +352,10 @@ def _whole_logits_loss(x, table, ids, labels, weights):
 def test_the_blocked_head_against_whole_logits(tokens, block, monkeypatch):
     """Values, d x and both gradients of the tied matrix (the gather's and
     the head's blocks' products, which autodiff adds up)."""
-    monkeypatch.setattr(losses, "HEAD_BLOCK", block)
-    assert losses._head_blocks(tokens) == {(96, 32): 3, (96, 96): 1,
-                                           (60, 16): 4}[tokens, block]
+    # A block is as many tokens as its float32 logits may take bytes.
+    monkeypatch.setattr(losses, "HEAD_BLOCK_BYTES", 4 * 200 * block)
+    assert losses._head_blocks(tokens, 200) == {(96, 32): 3, (96, 96): 1,
+                                                (60, 16): 4}[tokens, block]
     keys = jax.random.split(jax.random.key(tokens + block), 5)
     table = jax.random.normal(keys[0], (200, 24))
     ids = jax.random.randint(keys[1], (tokens,), 0, 200)
@@ -390,7 +391,7 @@ def test_the_blocked_head_against_whole_logits(tokens, block, monkeypatch):
 def test_the_blocked_head_keeps_no_logits_of_every_token(monkeypatch):
     """No array of ``[tokens, rows]`` in the jaxpr of its value and
     gradient: a block's logits are the largest."""
-    monkeypatch.setattr(losses, "HEAD_BLOCK", 16)
+    monkeypatch.setattr(losses, "HEAD_BLOCK_BYTES", 4 * 200 * 16)
     tokens, rows, d = 64, 200, 24
     x, table = jnp.ones((tokens, d)), jnp.ones((rows, d))
     labels, weights = jnp.zeros((tokens,), jnp.int32), jnp.ones((tokens,))

@@ -34,6 +34,26 @@
                                      kernels without the pair (q and k
                                      concatenated to 192, v padded), forward
                                      and backward timed; nothing else
+    python chip_smoke.py --lightning
+                                     one chip: ``ops/lightning_attention.py``
+                                     at ``sala-sparse-linear-tp4-s16384``'s
+                                     shape (heads 24 to 31 of 32 with their
+                                     published slopes, 16,384 x 128) against
+                                     the quadratic form and the scan form,
+                                     values and the three gradients, forward
+                                     and backward timed by chunk size;
+                                     nothing else
+    python chip_smoke.py --flash-select
+                                     one chip: ``ops/flash_select.py`` at that
+                                     cell's sparse layer (8 query heads on 1
+                                     key/value head, 16,384 x 128, MiniCPM4's
+                                     selection constants): the selection
+                                     timed, the selected walk's kernels
+                                     against the masked dense softmax, values
+                                     and the three gradients, the walk's
+                                     counters, forward and backward timed by
+                                     tile sizes beside the plain causal
+                                     kernels; nothing else
     python chip_smoke.py --tied-head
                                      one chip: ``ops/tied_head.py`` at the
                                      four blocked heads of the benchmark
@@ -959,6 +979,183 @@ def flash_mla(length: int = 16384, heads: int = 4, head_dim: int = 128,
     return report
 
 
+def _chain_ms(fn, g, operands, chain: int, repeats: int) -> dict:
+    """``fwd_ms`` and ``fwd_bwd_ms`` of ``fn(*operands)`` (the result shaped
+    like its first operand): each pass one of ``chain`` in one compiled
+    program, the forward's result fed back as the first operand, the
+    backward's cotangents as the next operands."""
+    import jax
+
+    def fwd_chain(first, *rest):
+        for _ in range(chain):
+            first = fn(first, *rest)
+        return first
+
+    def bwd_chain(g, *args):
+        for _ in range(chain):
+            args = jax.vjp(fn, *args)[1](g)
+        return args
+
+    return {"fwd_ms": round(_best_ms(repeats, jax.jit(fwd_chain), *operands)
+                            / chain, 3),
+            "fwd_bwd_ms": round(_best_ms(repeats, jax.jit(bwd_chain), g,
+                                         *operands) / chain, 3)}
+
+
+def published_slopes(heads: int = 32, first: int = 24, held: int = 8,
+                     layer: int = 1, layers: int = 32):
+    """The slopes of MiniCPM-SALA's held lightning heads (``configs/
+    minicpm-sala-tp4.json``: ``assumed.slopes``): ``2^(-8 (h + 1) / heads) x
+    (1 - layer / (layers - 1) + 1e-5)``."""
+    import jax.numpy as jnp
+
+    h = jnp.arange(first, first + held, dtype=jnp.float32)
+    return 2.0 ** (-8.0 * (h + 1) / heads) * (1 - layer / (layers - 1) + 1e-5)
+
+
+def lightning(length: int = 16384, heads: int = 8, head_dim: int = 128,
+              chunks=(256, 128), repeats: int = 5, chain: int = 4,
+              check_rows: int = 2048, interpret: bool = False) -> dict:
+    """The lightning-attention kernels (the defaults are
+    ``sala-sparse-linear-tp4-s16384``'s: heads 24 to 31 of 32 with the
+    published slopes of layer 1, 16,384 rows of 128 in bfloat16): value, dq,
+    dk, dv of the first ``check_rows`` rows against the quadratic form
+    ``((Q K^T) * D) V`` in float32, and of the whole length against the
+    ``lax.scan`` form (the carried state crosses every block); then the
+    forward's time and the forward and backward's for each chunk size of
+    ``chunks``, each pass one of ``chain`` in one compiled program."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import lightning_attention as la
+
+    slopes = published_slopes(held=heads)
+    scale = head_dim ** -0.5
+    ks = jax.random.split(jax.random.PRNGKey(heads), 4)
+    q, k, v, g = (jax.random.normal(key, (1, length, heads, head_dim),
+                                    jnp.bfloat16) for key in ks)
+
+    def both(fn, g, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (out, *vjp(g))
+
+    def kernel(chunk):
+        return lambda q, k, v: la.lightning_attention(
+            q, k, v, slopes, scale, chunk=min(chunk, length),
+            interpret=interpret or None)
+
+    checks, times = [], {}
+    rows = min(check_rows, length)
+    head = [x[:, :rows] for x in (g, q, k, v)]
+    quadratic = jax.jit(functools.partial(both, lambda q, k, v: (
+        la.lightning_attention_quadratic(q, k, v, slopes, scale))))(
+            *(x.astype(jnp.float32) for x in head))
+    scan = jax.jit(functools.partial(both, lambda q, k, v: (
+        la.lightning_attention_scan(q, k, v, slopes, scale))))(g, q, k, v)
+    for chunk in chunks:
+        fn = kernel(chunk)
+        got_head = jax.jit(functools.partial(both, fn))(*head)
+        got = jax.jit(functools.partial(both, fn))(g, q, k, v)
+        for name, a, b, c, d in zip(("out", "dq", "dk", "dv"), got_head,
+                                    quadratic, got, scan):
+            tol = TOL_BF16_FWD if name == "out" else TOL_BF16_BWD
+            _check(checks, f"chunk{chunk}/quadratic/{name}", a, b, tol)
+            _check(checks, f"chunk{chunk}/scan/{name}", c, d, tol)
+        times[f"chunk{chunk}"] = _chain_ms(fn, g, (q, k, v), chain, repeats)
+    report = emit("lightning", checks=checks, length=length, heads=heads,
+                  head_dim=head_dim, chain=chain, times=times)
+    _raise_on_failed("lightning", checks)
+    return report
+
+
+# MiniCPM4's sparse_config (``configs/minicpm-sala-tp4.json``: ``assumed``).
+SPARSE = {"kernel_size": 32, "stride": 16, "block": 64, "topk": 64,
+          "init_blocks": 1, "local_blocks": 32}
+
+
+def flash_select(length: int = 16384, heads: int = 8, head_dim: int = 128,
+                 sparse=None, forms=((256, 256, 256, 256), (512, 256, 512, 256),
+                                     (512, 512, 512, 512),
+                                     (1024, 256, 1024, 256),
+                                     (1024, 512, 1024, 512),
+                                     (512, 256, 1024, 256),
+                                     (1024, 256, 512, 256)),
+                 repeats: int = 5, chain: int = 4, check_rows: int = 4096,
+                 interpret: bool = False) -> dict:
+    """The selected walk (the defaults are ``sala-sparse-linear-tp4-s16384``'s
+    sparse layer: 8 query heads on 1 key/value head, 16,384 x 128 in
+    bfloat16, MiniCPM4's selection constants): the selection's time alone;
+    value, dq, dk, dv of the kernels on the selection of drawn q and k
+    against ``dense_select`` (the masked dense softmax) over the first
+    ``check_rows`` rows, a head at a time; the walk's counters; then the
+    forward's time and the forward and backward's in each of ``forms``
+    (query tile, key step, key tile, query step), one of ``chain`` in one
+    compiled program, beside the plain causal kernels on the same operands."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_select as fs
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    sparse = dict(sparse or SPARSE)
+    scale = head_dim ** -0.5
+    ks = jax.random.split(jax.random.PRNGKey(heads), 4)
+    q, g = (jax.random.normal(key, (1, length, heads, head_dim),
+                              jnp.bfloat16) for key in ks[:2])
+    k, v = (jax.random.normal(key, (1, length, 1, head_dim), jnp.bfloat16)
+            for key in ks[2:])
+    choose = jax.jit(lambda q, k: fs.sparse_select(q, k, scale=scale,
+                                                   **sparse).bits)
+    bits = choose(q, k)
+    select = fs.Selection(bits, sparse["block"])
+
+    def both(fn, g, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (out, *vjp(g))
+
+    def walk(form, rows=length):
+        sizes = dict(zip(("tile_q", "step_k", "tile_k", "step_q"),
+                         (min(f, rows) for f in form)))
+        chosen = fs.Selection(bits[..., :rows], sparse["block"])
+        return lambda q, k, v: fs.flash_select(
+            q, k, v, chosen, scale, interpret=interpret or None, **sizes)[0]
+
+    checks, times = [], {}
+    rows = min(check_rows, length)
+    head = [x[:, :rows] for x in (g, q, k, v)]
+    dense = jax.jit(functools.partial(both, lambda q, k, v: fs.dense_select(
+        q, k, v, fs.Selection(bits[..., :rows], sparse["block"]), scale)[0]))
+    per_head = [dense(head[0][:, :, i:i + 1], head[1][:, :, i:i + 1],
+                      head[2], head[3]) for i in range(heads)]
+    want = (jnp.concatenate([p[0] for p in per_head], 2),
+            jnp.concatenate([p[1] for p in per_head], 2),
+            sum(p[2].astype(jnp.float32) for p in per_head),
+            sum(p[3].astype(jnp.float32) for p in per_head))
+    for form in forms:
+        name = "x".join(map(str, form))
+        got = jax.jit(functools.partial(both, walk(form, rows)))(*head)
+        for what, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            _check(checks, f"{name}/{what}", a, b,
+                   TOL_BF16_FWD if what == "out" else TOL_BF16_BWD)
+        times[name] = {
+            **_chain_ms(walk(form), g, (q, k, v), chain, repeats),
+            "counters": {n: float(c) for n, c in jax.jit(
+                lambda b, form=form: fs.walk_counters(
+                    fs.Selection(b, sparse["block"]), length,
+                    min(form[0], length), min(form[1], length)))(
+                        bits).items()}}
+    causal = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, scale=scale, interpret=interpret or None)
+    report = emit(
+        "flash_select", checks=checks, length=length, heads=heads,
+        head_dim=head_dim, chain=chain, times=times,
+        select_ms=_best_ms(repeats, choose, q, k),
+        plain_causal_fwd_bwd_ms=_chain_ms(causal, g, (q, k, v), chain,
+                                          repeats)["fwd_bwd_ms"])
+    _raise_on_failed("flash_select", checks)
+    return report
+
+
 def _whole_logits_loss(tied: bool, x, matrix, labels, weights):
     """The blocked head's loss with the float32 logits whole: the product in
     ``x.dtype`` and ``softmax_cross_entropy``, the form ``laguna.Laguna.loss``
@@ -1103,6 +1300,12 @@ def main(argv=None) -> int:
     ap.add_argument("--flash-mla", action="store_true",
                     help="check and time the flash kernels with a second "
                          "score operand, and nothing else")
+    ap.add_argument("--lightning", action="store_true",
+                    help="check and time the lightning-attention kernels, "
+                         "and nothing else")
+    ap.add_argument("--flash-select", action="store_true",
+                    help="check and time the selection and the selected "
+                         "walk's flash kernels, and nothing else")
     ap.add_argument("--tied-head", action="store_true",
                     help="check and time the blocked heads' logits kernel, "
                          "and nothing else")
@@ -1132,6 +1335,12 @@ def main(argv=None) -> int:
     elif args.flash_mla:
         info = device()
         flash_mla()
+    elif args.lightning:
+        info = device()
+        lightning()
+    elif args.flash_select:
+        info = device()
+        flash_select()
     elif args.tied_head:
         info = device()
         tied_head()

@@ -13,7 +13,11 @@ a top-k mixture of experts beside a shared one, an untied head; its loss is
 ``laguna.lm_loss``) and ``JoyAI`` (multi-head latent attention: a low-rank
 latent between the stream and the heads, scores of two products, one rotary
 key for all heads; experts chosen by bias-corrected sigmoid scores beside a
-shared one; its loss is ``joyai.lm_loss``)."""
+shared one; its loss is ``joyai.lm_loss``) and ``Sala`` (MiniCPM-SALA:
+lightning linear-attention layers, one decay a head as chunked products on the
+MXU, among block-selected softmax-attention layers whose visible keys the
+data chooses, output gates on both, in MiniCPM's scaled frame; its loss is
+``sala.lm_loss``)."""
 
 from .losses import softmax_cross_entropy  # noqa: F401
 from .mlp import MLP, xent_loss  # noqa: F401
@@ -46,3 +50,5 @@ from . import joyai  # noqa: F401
 from .joyai import (  # noqa: F401
     JoyAI, JoyAIConfig, JOYAI_LLM_FLASH, JOYAI_TINY,
 )
+from . import sala  # noqa: F401
+from .sala import Sala, SalaConfig, MINICPM_SALA, SALA_TINY  # noqa: F401

@@ -45,18 +45,22 @@ LONG_FILES = (
     "tests/benchmark/test_zaya_cell.py",
     "tests/single/test_jamba.py",
     "tests/single/test_tpu_compile.py",
+    "tests/benchmark/test_sala_cell.py",
     "tests/benchmark/test_joyai_cell.py",
     "tests/benchmark/test_laguna_cell.py",
     "tests/single/test_flash_attention.py",
     "tests/parallel/test_shm_plane_perf.py",
     "tests/single/test_laguna.py",
+    "tests/single/test_sala.py",
     "tests/single/test_joyai.py",
     "tests/single/test_flash_window.py",
     "tests/single/test_bert_reference.py",
     "tests/single/test_selective_scan.py",
     "tests/single/test_ops_jit_quantized_allreduce_bits.py",
     "tests/single/test_qk_norm_rope.py",
+    "tests/single/test_flash_select.py",
     "tests/single/test_flash_mla.py",
+    "tests/single/test_lightning_attention.py",
     "tests/benchmark/test_benchmark.py",
     "tests/single/test_chip_smoke.py",
     "tests/single/test_routed_experts.py",

@@ -148,6 +148,42 @@ def test_flash_mla_phase_tiny():
     assert {"fwd_ms", "fwd_bwd_ms"} <= set(report)
 
 
+def test_lightning_phase_tiny():
+    """The lightning-attention kernels (interpreted) against the quadratic
+    form and the scan form, by chunk size, and the timing table's keys."""
+    report = chip_smoke.lightning(length=256, heads=2, chunks=(128, 64),
+                                  repeats=1, chain=2, check_rows=128,
+                                  interpret=True)
+    assert [c["name"] for c in report["checks"]] == [
+        f"chunk{c}/{form}/{n}" for c in (128, 64)
+        for n in ("out", "dq", "dk", "dv") for form in ("quadratic", "scan")]
+    assert all(c["ok"] for c in report["checks"])
+    assert set(report["times"]) == {"chunk128", "chunk64"}
+    assert all({"fwd_ms", "fwd_bwd_ms"} <= set(t)
+               for t in report["times"].values())
+
+
+def test_flash_select_phase_tiny():
+    """The selected walk's kernels (interpreted) on the selection of drawn q
+    and k against the masked dense softmax a head at a time, by tile sizes,
+    with the walk's counters, and the selection's and plain causal times."""
+    sparse = {"kernel_size": 8, "stride": 4, "block": 16, "topk": 8,
+              "init_blocks": 1, "local_blocks": 2}
+    forms = ((128, 128, 128, 128), (256, 128, 128, 256))
+    report = chip_smoke.flash_select(length=512, heads=2, sparse=sparse,
+                                     forms=forms, repeats=1, chain=2,
+                                     check_rows=256, interpret=True)
+    names = ["x".join(map(str, f)) for f in forms]
+    assert [c["name"] for c in report["checks"]] == [
+        f"{name}/{n}" for name in names for n in ("out", "dq", "dk", "dv")]
+    assert all(c["ok"] for c in report["checks"])
+    assert set(report["times"]) == set(names)
+    for times in report["times"].values():
+        assert {"fwd_ms", "fwd_bwd_ms", "counters"} <= set(times)
+        assert times["counters"]["visited"] >= times["counters"]["chosen"] > 0
+    assert {"select_ms", "plain_causal_fwd_bwd_ms"} <= set(report)
+
+
 def test_tied_head_phase_tiny():
     """The blocked head's kernel (interpreted) against the ``jax.numpy``
     product and statistics, alone and inside the whole head, at a tied table

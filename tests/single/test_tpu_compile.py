@@ -895,6 +895,118 @@ def test_a_joyai_step_copies_nothing_round_its_paired_kernels(topo,
     assert not copies, copies
 
 
+def test_lightning_and_the_selected_walk_compile_at_the_sala_cell_s_shapes(
+        one_chip):
+    """``ops/lightning_attention.py`` and ``ops/flash_select.py`` at
+    ``sala-sparse-linear-tp4-s16384``'s shapes (8 heads of 128 over 16,384
+    rows; 8 query heads on 1 key/value head whose k and v lie whole in VMEM,
+    the walk's lists scalar-prefetched, a branch on a listed step's kind
+    inside the walk's loop): value and gradients, which only Mosaic can
+    refuse."""
+    from horovod_tpu.ops import flash_select as fs
+    from horovod_tpu.ops import lightning_attention as la
+
+    q = jax.ShapeDtypeStruct((1, 16384, 8, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 1, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    slopes = jax.ShapeDtypeStruct((8,), jnp.float32, sharding=one_chip)
+    bits = jax.ShapeDtypeStruct((1, 1, 8, 16384), jnp.int32,
+                                sharding=one_chip)
+
+    def lightning(q, k, v, a):
+        return jnp.sum(la.lightning_attention(
+            q, k, v, a, interpret=False).astype(jnp.float32) ** 2)
+
+    def selected(q, k, v, bits):
+        out, lse = fs.flash_select(q, k, v, fs.Selection(bits, 64),
+                                   interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2) + jnp.sum(lse)
+
+    text = _compiled_text(jax.grad(lightning, argnums=(0, 1, 2)), q, q, q,
+                          slopes)
+    for name in ("hvd_lightning_fwd", "hvd_lightning_dq", "hvd_lightning_dkv"):
+        assert len(re.findall(rf"{name}[\w.]* = ", text)) == 1, name
+    text = _compiled_text(jax.grad(selected, argnums=(0, 1, 2)), q, kv, kv,
+                          bits)
+    for name in ("hvd_flash_sel_fwd", "hvd_flash_sel_dq", "hvd_flash_sel_dkv"):
+        assert len(re.findall(rf"{name}[\w.]* = ", text)) == 1, name
+
+
+def test_a_sala_step_copies_nothing_of_q_s_size_round_its_kernels(
+        topo, monkeypatch):
+    """The first two layers of ``sala-sparse-linear-tp4-s16384`` at its
+    widths (the sparse layer and a lightning layer; 8 heads of each held,
+    4,096 SwiGLU columns, 18,362 rows) at its 16,384 tokens, which choose, a
+    whole step as ``families/sala.py`` builds it: gradients, ``optax.adamw``
+    through ``DistributedOptimizer``, donated state, the chosen bits handed
+    out, ``shard_map`` over one described chip.  The step holds one call of
+    each of the six kernels by name, the head norms' op four times each way,
+    no ``hvd_flash_relayout``, and under the attention scope **no copy and no
+    transpose as large as q**; the gate/up pair crosses the step's boundary
+    2-D and nothing of its size is copied."""
+    import optax
+    from jax import shard_map
+
+    import horovod_tpu as hvd
+    from benchmark.families.sala import _loss_and_choices, kernel_calls
+    from horovod_tpu.models import sala
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(
+        sala.MINICPM_SALA, num_layers=2, lightning_heads_held=8,
+        first_lightning_head=24, num_heads_held=8, num_kv_heads_held=1,
+        intermediate_size_held=4096, vocab_size_held=18362)
+    model, seq = sala.Sala(cfg), 16384
+    tx = hvd.DistributedOptimizer(optax.adamw(2e-7), axis_name="hvd")
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("hvd",))
+
+    def train_step(variables, opt_state, chosen, ids):
+        del chosen
+        (loss, chosen), grads = jax.value_and_grad(
+            lambda p: _loss_and_choices(model, [0], p, ids),
+            has_aux=True)(variables)
+        updates, opt_state = tx.update(grads, opt_state, variables)
+        return (optax.apply_updates(variables, updates), opt_state, chosen,
+                hvd.allreduce(loss, axis_name="hvd"))
+
+    ids = jax.ShapeDtypeStruct((1, seq), jnp.int32)
+    variables = jax.eval_shape(model.init, jax.random.key(0), ids)
+    state = (variables, jax.eval_shape(tx.init, variables))
+    chosen = jax.ShapeDtypeStruct((1, 1, 1, 8, seq), jnp.int32)
+    by_sequence = NamedSharding(mesh, P(None, "hvd"))
+    text = jax.jit(
+        shard_map(train_step, mesh=mesh,
+                  in_specs=(P(), P(), P(None, "hvd"), P("hvd")),
+                  out_specs=(P(), P(), P(None, "hvd"), P())),
+        donate_argnums=(0, 1, 2)).lower(
+            *_shapes_on(NamedSharding(mesh, P()), state),
+            _shapes_on(by_sequence, chosen),
+            _shapes_on(NamedSharding(mesh, P("hvd")), ids)).compile().as_text()
+    assert kernel_calls(text) == dict.fromkeys(
+        ("hvd_lightning_fwd", "hvd_lightning_dq", "hvd_lightning_dkv",
+         "hvd_flash_sel_fwd", "hvd_flash_sel_dq", "hvd_flash_sel_dkv"), 1)
+    for name in ("hvd_qk_norm_rope_fwd", "hvd_qk_norm_rope_bwd"):
+        assert len(re.findall(rf"{name}[\w.]* = ", text)) == 4, name
+    assert "hvd_flash_relayout" not in text
+    assert len(re.findall(r"hvd_head_logits[\w.]* = ", text)) == 1
+    made = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                      r"(copy|transpose)\(")
+    q = seq * 8 * 128
+    round_the_kernels = [
+        line.strip()[:200] for line in text.splitlines()
+        for m in [made.match(line)]
+        if m and "/attn/" in line
+        and math.prod(map(int, m.group(1).split(","))) >= q]
+    assert not round_the_kernels, round_the_kernels
+    pair = 4096 * 2 * 4096
+    copies = [line.strip()[:160] for line in text.splitlines()
+              for m in [made.match(line)]
+              if m and m.group(2) == "copy"
+              and math.prod(map(int, m.group(1).split(","))) == pair]
+    assert not copies, copies
+
+
 @pytest.mark.parametrize("codec", ["int8", "int4"])
 def test_codec_encode_decode_compiles(one_chip, codec):
     def roundtrip(flat):

@@ -266,6 +266,55 @@ def test_a_joyai_step_names_what_latent_attention_adds():
     assert any("layer_1" in n and "hvd_moe_shared" in n for n in names)
 
 
+def test_a_sala_step_names_what_both_mixers_add():
+    """``models/sala.py``'s scopes in the lowered step at the configuration's
+    rehearsal sizes: ``hvd_attn_proj`` holds both mixers' five projections,
+    ``hvd_lightning_prep`` the lightning layers' head norms, rotary turn and
+    slopes, ``hvd_qk_norm`` the sparse layer's head norms, ``hvd_attn_gate``
+    both gates and the lightning output norm, ``hvd_sparse_select`` the
+    selection (forward only: it passes no gradient), ``hvd_mlp`` the SwiGLU,
+    ``hvd_lm_head`` the final norm, the untied head and the loss."""
+    from benchmark.families import sala
+    from horovod_tpu.models import sala as model_sala
+
+    cfg = run.load_json("configs", "minicpm-sala-tp4.json")
+    traffic = traffic_gen.resolve(
+        run.load_json("traffic", "sala-causal-1x16384x1.json"),
+        rehearse=True)
+    mesh = common.hvd_mesh(jax.devices()[:1])
+    cell = sala.setup(cfg, mesh, seed=3, rehearse=True)
+    (ids,) = traffic_gen.make_batches(traffic, sala.inputs(cell, traffic),
+                                      mesh, seed=3)[0]
+    names = _op_names(jax.jit(jax.grad(lambda v: model_sala.lm_loss(
+        cell["model"], v, ids))).lower(cell["params"]).as_text(
+            debug_info=True))
+
+    def under(scope, op, backward=False):
+        return any(re.search(rf"[/(]{scope}[/)]", n) and n.endswith(op)
+                   and ("transpose(" in n) == backward for n in names)
+
+    for backward in (False, True):
+        assert under("hvd_attn_proj", "dot_general", backward)
+        assert under("hvd_lightning_prep", "mul", backward)
+        assert under("hvd_qk_norm", "mul", backward)
+        assert under("hvd_attn_gate", "logistic" if not backward else "mul",
+                     backward)
+        assert under("hvd_mlp", "dot_general", backward)
+        assert under("hvd_lm_head", "", backward)
+    # (The selection's tiles run in a ``lax.map``, whose body's names are
+    # relative to it here: the compressed keys' mean is made outside it.)
+    assert under("hvd_sparse_select", "div")
+    assert not under("hvd_sparse_select", "", backward=True)
+    for kernel in ("q_proj", "k_proj", "v_proj", "gate_proj", "o_proj"):
+        assert any(f"/hvd_attn_proj/{kernel}/" in n for n in names), kernel
+    # The sparse layer turns nothing and has no slopes; the lightning layers
+    # choose nothing.
+    assert not any("layer_0" in n and "hvd_lightning_prep" in n
+                   for n in names)
+    assert not any("layer_1" in n and "hvd_sparse_select" in n
+                   for n in names)
+
+
 # One cell a family, and the layers its step must show in both passes
 # (``hvd_embed`` and a head besides, checked for every family).
 FAMILY_CELLS = {
@@ -289,6 +338,9 @@ FAMILY_CELLS = {
     "joyai": ("joyai-mla-ep16-s16384", {
         "hvd_block", "hvd_attn", "hvd_attn_proj", "hvd_mlp",
         "hvd_mla_latent", "hvd_moe_shared", "hvd_lm_head"}),
+    "sala": ("sala-sparse-linear-tp4-s16384", {
+        "hvd_block", "hvd_attn", "hvd_attn_proj", "hvd_mlp",
+        "hvd_qk_norm_rope", "hvd_qk_norm", "hvd_attn_gate", "hvd_lm_head"}),
 }
 # Ops of a step's forward or backward that no scope of the program's can
 # name, each with its reason.

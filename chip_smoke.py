@@ -8,7 +8,10 @@
                                      its experts, and what its grouped
                                      products cost by ``ragged_dot``, by
                                      megablox ``gmm`` and by the repo's own
-                                     kernels; the same kernels at an expert
+                                     kernels; the layer's sum of its rows back
+                                     into the tokens alone, at the three
+                                     cells' sizes, beside the scatter-add it
+                                     replaced; the same kernels at an expert
                                      of [2048, 2048], wider than their VMEM
                                      budget, each call timed; nothing else
     python chip_smoke.py --qk-norm-rope
@@ -371,6 +374,91 @@ def _best_ms(repeats: int, fn, *args) -> float:
         jax.block_until_ready(fn(*args))
         times.append(time.perf_counter() - start)
     return round(1e3 * min(times), 3)
+
+
+# (rows of the buffer, tokens, d, top_k, held, experts) of a chip's expert
+# layer in the three cells whose tokens have more than one row.
+SUM_SHAPES = {"sdar-moe-ep8-s4096": (36864, 16384, 2048, 8, 16, 128),
+              "joyai-mla-ep16-s16384": (16384, 16384, 2048, 8, 16, 256),
+              "laguna-swa-ep32-s16384": (10240, 16384, 3072, 10, 8, 256)}
+
+
+def _chained_ms(repeats: int, chain: int, body, rows, *args) -> float:
+    """Milliseconds of one ``body(rows, *args)`` on the device: ``chain`` of
+    them in one compiled program, each fed one element of the one before,
+    less a chain of one (the dispatch, some 0.6 ms, is in neither)."""
+    import jax
+
+    def chained(trips):
+        def fn(rows, *args):
+            def trip(_, rows):
+                out = body(rows, *args)
+                return rows.at[0, 0].add(out[0, 0].astype(rows.dtype))
+            return jax.lax.fori_loop(0, trips, trip, rows)
+        return jax.jit(fn)
+
+    return round((_best_ms(repeats, chained(chain), rows, *args)
+                  - _best_ms(repeats, chained(1), rows, *args))
+                 / (chain - 1), 3)
+
+
+def sum_rows(shapes=None, repeats: int = 5, chain: int = 16,
+             interpret: bool = False) -> dict:
+    """The expert layer's sum of its rows back into the tokens, alone
+    (``parallel/moe.py:add_rows``), at each cell's (buffer, tokens, d) with a
+    quarter, a half and all of the buffer routed, rows and tokens as a layer
+    makes them (sorted by expert, a token's rows on ``top_k`` experts):
+    milliseconds on the device, one of ``chain`` in one program, best of
+    ``repeats``.  ``sum_rows_ms`` is what a TPU runs, from the order the layer
+    keeps: the gather into token order and ``hvd_moe_sum_rows``;
+    ``token_order_ms`` is that order's sort, made once a layer for both
+    passes; ``scatter_add_ms`` is the scatter-add in trips that it replaced
+    (130 ns a row).  The two sums are held against each other."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import grouped_matmul as gm
+    from horovod_tpu.parallel import moe
+
+    chained_ms = functools.partial(_chained_ms, repeats, chain)
+    report, checks = {}, []
+    for cell, (buffer, tokens, d, top_k, held, experts) in (
+            shapes or SUM_SHAPES).items():
+        rows = jax.random.normal(jax.random.PRNGKey(3), (buffer, d),
+                                 jnp.bfloat16)
+
+        def order_of(token, n):
+            return gm.token_order(token, n, interpret=interpret or None)
+
+        def by_kernel(rows, order):
+            return gm.sum_by_token(rows, order, tokens,
+                                   interpret=interpret or None)
+
+        def in_trips(rows, token, n):
+            return moe._scatter_add_rows(rows, token, n, tokens)
+
+        for share, routed in (("quarter", buffer // 4),
+                              ("half", buffer // 2), ("all", buffer)):
+            chosen = _choices(tokens, top_k, held, experts, routed)
+            token = (jnp.argsort(chosen.reshape(-1), stable=True)[:buffer]
+                     // top_k).astype(jnp.int32)
+            n = jnp.int32(routed)
+            order = jax.jit(order_of)(token, n)
+            case = f"{cell}/routed={share}"
+            report[f"token_order_ms/{case}"] = chained_ms(
+                # (the sort's keys hang on the chain's carry)
+                lambda rows, token, n: order_of(
+                    token + (rows[0, 0] != rows[0, 0]).astype(jnp.int32), n
+                ).rows[:, None], rows, token, n)
+            report[f"sum_rows_ms/{case}"] = chained_ms(by_kernel, rows, order)
+            report[f"scatter_add_ms/{case}"] = chained_ms(in_trips, rows,
+                                                          token, n)
+            _check(checks, f"sum_rows/{case}",
+                   jax.jit(by_kernel)(rows, order),
+                   jax.jit(in_trips)(rows, token, n), TOL_BF16_FWD)
+    report = emit("sum_rows", checks=checks, **report)
+    _raise_on_failed("sum_rows", checks)
+    return report
 
 
 def grouped_products(tokens: int = 16384, d: int = 2048, f: int = 768,
@@ -1325,6 +1413,7 @@ def main(argv=None) -> int:
     elif args.grouped_products:
         info = device()
         grouped_products()
+        sum_rows()
         wide_expert_products()
     elif args.qk_norm_rope:
         info = device()

@@ -34,6 +34,16 @@ native rate) and accumulates in float32.  ``w`` may be kept in another dtype
 in VMEM when its first visit fetches it, not in a pass of its own over all of
 ``w``, and dW leaves the accumulator in ``w``'s dtype.
 
+A fourth call, ``hvd_moe_sum_rows`` (:func:`sum_by_token`), is the way back
+from the rows to the tokens: the rows are put in token order
+(:func:`token_order`, one sort a layer), a tile of :data:`SUM_TOKENS` tokens
+is a group (and :data:`SUM_ROWS` rows a visit), and a group's sum by token is
+``onehot [tokens, rows] . rows [rows, d]`` over its visits, ``onehot`` made in
+VMEM from the rows' tokens: a 0 / 1 operand, so every product is exact, a
+float32 accumulator, one rounding, each token tile written once.  A
+scatter-add does the same sum as a serial read-modify-write at some 100
+cycles a row (PERF.md, PR 37, PR 59).
+
 Off the TPU :func:`grouped_dot` is ``jax.lax.ragged_dot`` on ``w`` cast; the
 kernels are unit-tested in interpret mode (``tests/single/
 test_grouped_matmul.py``) and held against a loop over the experts on the chip
@@ -43,6 +53,8 @@ by ``chip_smoke.py --grouped-products``.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -55,6 +67,20 @@ LANES = 128
 # Rows a visit: a 36,864-row buffer is 72 of them.  megablox's tiling at an
 # expert layer's sizes, read on a v5e (PERF.md, PR 34 and PR 35).
 TILE_ROWS = 512
+# :func:`sum_by_token`'s tiles: rows a visit, and tokens a group (the
+# one-hot's rows).  A visit's dot is the whole row tile by the whole token
+# tile whatever share of either is the other's, so small tiles waste less:
+# read on a v5e at the three expert cells' sizes, the kernel alone takes 0.32
+# / 0.22 / 0.25 ms at 128 x 128 and 0.44 / 0.33 / 0.43 at 512 x 256; a whole
+# step of ``sdar-moe-ep8-s4096`` reads the same at either (301.20 / 301.14 ms
+# against 301.25 / 301.19 at two seeds; PERF.md, PR 59).
+SUM_ROWS = 128
+SUM_TOKENS = 128
+# The prefixes of a buffer that :func:`sum_by_token`'s gather may cover: the
+# rows routed are rounded up to one of them.  Against one gather of the whole
+# buffer the switch is worth 5.2 of ``sdar-moe-ep8-s4096``'s 306.4 ms a step;
+# eight is the only count read (PERF.md, PR 59).
+SUM_SHARES = 8
 # What a call's blocks may take of VMEM by :func:`_gmm_bytes` /
 # :func:`_tgmm_bytes`, and what the calls ask Mosaic for.  Whole
 # [2048, 768] float32 matrices fit: no contraction is cut at SDAR's sizes.
@@ -364,3 +390,161 @@ def grouped_dot_grads(rows, w, group_sizes, g, *, interpret=None):
     rows, w, g = (functools.reduce(_vary_like, operands, a) for a in operands)
     schedule = _schedule(group_sizes, rows.shape[0], _tile_rows(rows.shape[0]))
     return _grouped_dot_bwd(interpret, (rows, w, schedule), g)[:2]
+
+
+# ---------------------------------------------------------------------------
+# The way back: rows summed into their tokens
+# ---------------------------------------------------------------------------
+
+_PAST = jnp.iinfo(jnp.int32).max        # the key of a row that is no token's
+
+
+class TokenOrder(NamedTuple):
+    """A row buffer's rows by their token (:func:`token_order`)."""
+    rows: jax.Array         # [C] int32: the rows, those of no token last
+    tokens: jax.Array       # [C] int32: their tokens, ascending; then _PAST
+
+
+def token_order(token, n, *, interpret=None):
+    """The first ``n`` rows of a buffer ordered by their token (token [C]
+    int32, n int32): one stable sort of C keys, which :func:`sum_by_token`
+    starts from and a layer makes once for both its passes.  The rows past
+    ``n`` keep their order behind the others.  None off the TPU unless
+    ``interpret`` is given: there the sum is a scatter-add in row order
+    (``parallel/moe.py:add_rows``) and asks for no order."""
+    if interpret is None and jax.default_backend() != "tpu":
+        return None
+    at = jnp.arange(token.shape[0], dtype=jnp.int32)
+    key = jnp.where(at < n, token.astype(jnp.int32), _PAST)
+    tokens, rows = lax.sort((key, _vary_like(at, key)), num_keys=1,
+                            is_stable=True)
+    return TokenOrder(rows, tokens)
+
+
+def _sum_kernel(offsets, groups, tiles, token_ref, rows_ref, out_ref, acc_ref,
+                *, tile: int, held: int, span: int):
+    """One visit of the sum: ``out[token tile] += onehot . rows[its rows in
+    this row tile]``, ``onehot[t, r] = (row r's token is the tile's t-th)``.
+    Grid (column tiles, visits), walked as ``_tgmm_kernel`` walks dW's: the
+    pseudo group's visits go on the last token tile and add nothing."""
+    v, last = pl.program_id(1), pl.num_programs(1) - 1
+    lo, hi, g, first_row = _rows_of(v, offsets, groups, tiles, tile)
+    g = jnp.minimum(g, held - 1)
+
+    @pl.when((v == 0) | (jnp.minimum(groups[jnp.maximum(v - 1, 0)],
+                                     held - 1) != g))
+    def _new_group():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(hi > lo)
+    def _visit():
+        # A row of another token tile has no 1 in this one-hot; one past
+        # them all, or past the buffer's end, may hold anything: zeros.
+        row = first_row + lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+        rows = rows_ref[...]
+        rows = jnp.where((row >= lo) & (row < hi), rows, jnp.zeros_like(rows))
+        nth = lax.broadcasted_iota(jnp.int32, (span, tile), 0)
+        onehot = (token_ref[...] - g * span == nth).astype(rows.dtype)
+        # float32 rows are summed as float32: the 0 / 1 operand is exact in
+        # any precision, the rows only at the highest.
+        acc_ref[...] += lax.dot_general(
+            onehot, rows, _NN, preferred_element_type=jnp.float32,
+            precision=(lax.Precision.HIGHEST if rows.dtype == jnp.float32
+                       else None))
+
+    @pl.when((v == last) | (jnp.minimum(groups[jnp.minimum(v + 1, last)],
+                                        held - 1) != g))
+    def _group_done():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _sum_bytes(tile, span, n, itemsize) -> int:
+    """VMEM of a visit of the sum: the row tile double-buffered and once
+    more masked, the one-hot, the output block double-buffered, the float32
+    accumulator."""
+    return ((3 * tile * n + span * tile + 2 * span * n) * itemsize
+            + span * n * 4)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3), inline=True)
+def _sum_rows(rows, tokens_of, tokens: int, interpret: bool):
+    """``[tokens, d]``: rows ``[M, d]`` in the order of their tokens
+    ``tokens_of`` [M] (ascending, ``_PAST`` for a row of none) summed by
+    token."""
+    m, d = rows.shape
+    tile, span = min(SUM_ROWS, m), min(SUM_TOKENS, tokens)
+    held = -(-tokens // span)
+    sizes = jnp.sum(jax.nn.one_hot(tokens_of // span, held, dtype=jnp.int32),
+                    axis=0)
+    offsets, groups, tiles = _schedule(sizes, m, tile)
+    itemsize = rows.dtype.itemsize
+    block = next((b for b in _divisors(d) if _sum_bytes(
+        tile, span, b, itemsize) <= _VMEM_BUDGET), None)
+    if block is None:
+        raise _plan_error("the sum by token", m, tokens, d)
+
+    def row_tile(v, offsets, tiles):
+        return _input_tile(v, offsets, tiles, tile, held)
+
+    out = pl.pallas_call(
+        functools.partial(_sum_kernel, tile=tile, held=held, span=span),
+        name="hvd_moe_sum_rows",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(d // block, groups.shape[0]),
+            in_specs=[
+                pl.BlockSpec((1, tile), lambda j, v, offsets, groups, tiles:
+                             (0, row_tile(v, offsets, tiles))),
+                pl.BlockSpec((tile, block), lambda j, v, offsets, groups,
+                             tiles: (row_tile(v, offsets, tiles), j))],
+            out_specs=pl.BlockSpec(
+                (span, block), lambda j, v, offsets, groups, tiles:
+                (jnp.minimum(groups[v], held - 1), j)),
+            scratch_shapes=[pltpu.VMEM((span, block), jnp.float32)]),
+        out_shape=_out_struct((held * span, d), rows.dtype, rows, tokens_of),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret)(offsets, groups, tiles, tokens_of[None, :], rows)
+    return out[:tokens]       # (the last token tile may be a partial one)
+
+
+def _covers(capacity: int) -> list:
+    """The lengths of a ``capacity``-row buffer's prefix that
+    :func:`sum_by_token` may gather: every :data:`SUM_SHARES`-th of it in
+    whole row tiles, and all of it."""
+    tile = min(SUM_ROWS, capacity)
+    return sorted({min(capacity,
+                       -(-capacity * i // (SUM_SHARES * tile)) * tile)
+                   for i in range(1, SUM_SHARES + 1)})
+
+
+def sum_by_token(rows, order: TokenOrder, tokens: int, *, interpret=None):
+    """``[tokens, d]``: row t is the sum of the buffer's rows ``rows`` [C, d]
+    whose token is t, for the rows that ``order`` (:func:`token_order`)
+    counts as some token's, and zeros where a token has none: what
+    ``zeros.at[token[:n]].add(rows[:n])`` is, summed in float32 and rounded
+    once to ``rows.dtype``, each token tile written once.  The rows are
+    gathered into token order (the cost that is left: XLA's gather takes some
+    30 ns a row of a large buffer on a v5e wherever the row lies) and summed
+    by ``hvd_moe_sum_rows``, which never fetches a row of no token.  So that
+    the gather follows the rows routed as well, and not the buffer, it covers
+    the shortest of a few prefixes of the order (:func:`_covers`) that holds
+    them all, chosen while the step runs (a ``lax.switch``: one side runs),
+    and what lies past it is zeros that nothing reads.  ``interpret`` as
+    :func:`grouped_dot`'s, None being the compiled kernel."""
+    capacity = rows.shape[0]
+
+    def gather(cover):
+        return lambda rows, at: jnp.pad(
+            rows.at[at[:cover]].get(mode="promise_in_bounds",
+                                    unique_indices=True),
+            ((0, capacity - cover), (0, 0)))
+
+    covers = _covers(capacity)
+    routed = jnp.sum(order.tokens != _PAST)
+    operands = (rows, *order)
+    rows, at, tokens_of = (functools.reduce(_vary_like, operands, a)
+                           for a in operands)
+    by_token = lax.switch(sum(routed > cover for cover in covers[:-1]),
+                          list(map(gather, covers)), rows, at)
+    return _sum_rows(by_token, tokens_of, tokens, interpret or False)

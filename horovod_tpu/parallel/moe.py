@@ -19,17 +19,21 @@ dropped: the rows routed here are sorted by expert into a row buffer of the
 caller's ``capacity_factor`` times what an even router sends here and
 multiplied in grouped matrix products, a group an expert.  The buffer's rows
 past the routed ones are in no group, and neither the products nor the
-gather into the buffer nor the sum back into the tokens visits them
-(:func:`take_rows`, :func:`add_rows`: trips of :data:`WALK_ROWS` rows over
-the routed prefix; :func:`rows_walked`).  A step whose rows do not fit the
-buffer walks every row a router can send, in parts (a ``lax.cond``, taken
-while the step runs).  It has no exchange: what the other chips' experts
-would add is not there (ROADMAP Reach B1 keeps the all-to-all).
+gather into the buffer nor the sum back into the tokens computes anything
+for them (:func:`take_rows`: trips of :data:`WALK_ROWS` rows over the routed
+prefix, :func:`rows_walked`; :func:`add_rows`: on a TPU a segment sum in
+token order, the rows gathered by their token and those routed summed run by
+run in one kernel call, a token tile written once; elsewhere a scatter-add in
+such trips).  A step whose rows do not fit the buffer walks every row a
+router can send, in parts (a ``lax.cond``, taken while the step runs).  It
+has no exchange: what the other chips' experts would add is not there
+(ROADMAP Reach B1 keeps the all-to-all).
 
 **What is kept between forward and backward** (:func:`_dropless`): a step
 whose rows fit keeps the sorted choices, the groups' sizes, the rows'
-weights and the ``gate`` and ``up`` products (two ``[rows, f]`` arrays a
-layer), and its backward runs no sort and no product of the forward again;
+weights, their order by token (on a TPU: what both sums back start from) and
+the ``gate`` and ``up`` products (two ``[rows, f]`` arrays a layer), and its
+backward runs no sort and no product of the forward again;
 it gathers the ``[rows, d]`` rows again and takes ``d weights`` from the
 product it runs for ``d h``.  A step in parts keeps nothing of a buffer's
 size and its backward makes each part's forward again
@@ -51,8 +55,10 @@ backward, are the Pallas kernels of ``ops/grouped_matmul.py``
 (:func:`~horovod_tpu.ops.grouped_matmul.grouped_dot`; ``hvd_moe_gmm`` /
 ``hvd_moe_tgmm`` in a trace): they walk the rows routed and nothing else, so
 their time follows the rows and not the buffer, and they read the float32
-kernels as they are, cast a group at a time in VMEM.  Elsewhere ``grouped_dot``
-is ``jax.lax.ragged_dot``.  The backend is what it looks at, as
+kernels as they are, cast a group at a time in VMEM; and the sum back is
+``hvd_moe_sum_rows`` (:func:`~horovod_tpu.ops.grouped_matmul.sum_by_token`).
+Elsewhere ``grouped_dot`` is ``jax.lax.ragged_dot`` and the sum a
+scatter-add.  The backend is what it looks at, as
 ``flash_attention`` does; no argument chooses.  (megablox's ``gmm``, which
 ships with jax and whose scheme the kernels follow, declares no ``vma`` on its
 outputs, so ``shard_map`` refuses it under ``check_vma``; ``ragged_dot`` on a
@@ -71,7 +77,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.collectives import axis_size, ensure_varying, vary_like
-from ..ops.grouped_matmul import TILE_ROWS, grouped_dot, grouped_dot_grads
+from ..ops.grouped_matmul import (TILE_ROWS, TokenOrder, grouped_dot,
+                                  grouped_dot_grads, sum_by_token,
+                                  token_order)
 
 
 def switch_moe(x, router_kernel, expert_fn: Callable, axis_name: str = "ep",
@@ -249,9 +257,10 @@ WALK_ROWS = TILE_ROWS
 
 
 def rows_walked(load_sum: int, capacity: int) -> int:
-    """The buffer's rows that a pass of :func:`take_rows` or :func:`add_rows`
-    visits when ``load_sum`` rows are routed into ``capacity``: whole trips
-    of :data:`WALK_ROWS`, up to the one that holds the last routed row."""
+    """The buffer's rows that a pass of :func:`take_rows` (and, off the TPU,
+    of :func:`add_rows`) visits when ``load_sum`` rows are routed into
+    ``capacity``: whole trips of :data:`WALK_ROWS`, up to the one that holds
+    the last routed row."""
     tile = min(WALK_ROWS, capacity)
     return min(capacity, -(-load_sum // tile) * tile)
 
@@ -301,23 +310,50 @@ def take_rows(x, token, n, single: bool = False):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def add_rows(rows, token, n, tokens: int, single: bool = False):
+def add_rows(rows, token, n, tokens: int, single: bool = False,
+             by_token: TokenOrder | None = None):
     """``[tokens, d]``: the sum of ``rows[i]`` into row ``token[i]`` for
-    i < ``n``, in ``rows.dtype`` and in row order, as
-    ``zeros.at[token[:n]].add(rows[:n])`` makes it.  Linear in ``rows``; its
-    transpose is :func:`take_rows`.  ``single``: no token has more than one
-    of the n rows (top-1, or one expert held), so its sum is that row and is
-    gathered: a scatter-add costs some 100 ns a row on a v5e whatever the
-    rows' bytes, a gather what its bytes cost (PERF.md, PR 37)."""
-    capacity = token.shape[0]
+    i < ``n``, what ``zeros.at[token[:n]].add(rows[:n])`` is.  Linear in
+    ``rows``; its transpose is :func:`take_rows`.
+
+    On a TPU it is a segment sum in token order (``ops/grouped_matmul.py:
+    sum_by_token``, ``hvd_moe_sum_rows`` in a trace): the n rows, gathered
+    into the order of their tokens, are summed run by run by a one-hot
+    product, in float32 with one rounding to ``rows.dtype``, and a tile of
+    tokens is written once, zeros where a token has no row.  ``by_token`` is
+    that order (``token_order(token, n)``) where the caller has it already;
+    the layer makes it once and keeps it for its backward.  A scatter-add
+    there is a serial read-modify-write of some 100 ns a row whatever the row
+    holds, 130 in a loop (PERF.md, PR 37, PR 59).  Off the TPU it is that
+    scatter-add, in ``rows.dtype`` and in row order, a trip's rows at a time.
+    A row that is not finite reaches every token of its tile of
+    ``SUM_TOKENS`` tokens on a TPU (``0 x inf`` in the one-hot product is
+    NaN) and its own token alone off it; the rows past ``n`` are masked and
+    reach nothing on either.
+
+    ``single``: no token has more than one of the n rows (top-1, or one
+    expert held), so its sum is that row and is gathered, on any backend."""
     if single:
+        capacity = token.shape[0]
         at = jnp.arange(capacity, dtype=jnp.int32)
         row_of = jnp.full((tokens,), capacity, jnp.int32).at[
             jnp.where(at < n, token, tokens)].set(at, mode="drop")
         mine = rows[jnp.minimum(row_of, capacity - 1)]
         return jnp.where((row_of < capacity)[:, None], mine,
                          jnp.zeros_like(mine))
-    tile, trips, place = _tiles(n, capacity)
+    if by_token is None:
+        by_token = token_order(token, n)
+    if by_token is None:
+        return _scatter_add_rows(rows, token, n, tokens)
+    return sum_by_token(rows, by_token, tokens)
+
+
+def _scatter_add_rows(rows, token, n, tokens: int):
+    """:func:`add_rows` off the TPU: ``zeros.at[token[:n]].add(rows[:n])`` in
+    ``rows.dtype`` and in row order, a trip's rows at a time.  (On a v5e it
+    cost 130 ns a row, 21 of ``sdar-moe-ep8-s4096``'s 314 ms a step;
+    ``chip_smoke.py --grouped-products`` still times it beside the sum.)"""
+    tile, trips, place = _tiles(n, token.shape[0])
 
     def trip(i, total):
         lo = place(i)
@@ -336,10 +372,10 @@ take_rows.defvjp(
                                  (token, n, x.shape[0])),
     lambda single, saved, g: (add_rows(g, *saved, single), None, None))
 add_rows.defvjp(
-    lambda rows, token, n, tokens, single: (
-        add_rows(rows, token, n, tokens, single), (token, n)),
+    lambda rows, token, n, tokens, single, by_token=None: (
+        add_rows(rows, token, n, tokens, single, by_token), (token, n)),
     lambda tokens, single, saved, g: (take_rows(g, *saved, single), None,
-                                      None))
+                                      None, None))
 
 
 class _Kept(NamedTuple):
@@ -350,6 +386,9 @@ class _Kept(NamedTuple):
     scale: jax.Array        # [capacity] float32: a row's weight, 0 past them
     gate: jax.Array         # [capacity, f]
     up: jax.Array           # [capacity, f]
+    # The rows by their token, for the two sums back into the tokens (None
+    # where ``add_rows`` asks for none: off the TPU, or a row a token).
+    by_token: TokenOrder | None
 
 
 def _held_part(capacity: int, x, local, weights, w_gate, w_up, w_down):
@@ -378,8 +417,9 @@ def _held_part(capacity: int, x, local, weights, w_gate, w_up, w_down):
         scale = jnp.where(jnp.arange(capacity) < routed,
                           weights.reshape(-1)[order], 0.0)
         out = (out.astype(jnp.float32) * scale[:, None]).astype(x.dtype)
-        return (add_rows(out, token, routed, tokens, single),
-                _Kept(order, sizes, scale, gate, up))
+        by_token = None if single else token_order(token, routed)
+        return (add_rows(out, token, routed, tokens, single, by_token),
+                _Kept(order, sizes, scale, gate, up, by_token))
 
 
 def _held_grads(x, weights, kept: _Kept, g, w_gate, w_up, w_down):
@@ -402,7 +442,8 @@ def _held_grads(x, weights, kept: _Kept, g, w_gate, w_up, w_down):
         d_weights = jnp.zeros((weights.size,), weights.dtype).at[
             kept.order].add(jnp.where(live, d_scale, 0.0).astype(
                 weights.dtype))
-        return (add_rows(d_rows, token, routed, x.shape[0], single),
+        return (add_rows(d_rows, token, routed, x.shape[0], single,
+                         kept.by_token),
                 d_weights.reshape(weights.shape), *d_kernels)
 
 

@@ -57,13 +57,13 @@ LONG_FILES = (
     "tests/single/test_bert_reference.py",
     "tests/single/test_selective_scan.py",
     "tests/single/test_ops_jit_quantized_allreduce_bits.py",
+    "tests/single/test_routed_experts.py",
     "tests/single/test_qk_norm_rope.py",
     "tests/single/test_flash_select.py",
     "tests/single/test_flash_mla.py",
     "tests/single/test_lightning_attention.py",
     "tests/benchmark/test_benchmark.py",
     "tests/single/test_chip_smoke.py",
-    "tests/single/test_routed_experts.py",
     "tests/single/test_trace_names.py",
     "tests/parallel/test_multiprocess.py",
     "tests/integration/test_matrix.py",

@@ -78,6 +78,19 @@ def test_grouped_products_phase_tiny():
             } <= set(report)
 
 
+def test_sum_rows_phase_tiny():
+    """The sum back into the tokens by the kernel (interpreted) against the
+    scatter-add in trips, and the timing table's keys."""
+    report = chip_smoke.sum_rows(shapes={"toy": (256, 128, 128, 4, 4, 16)},
+                                 repeats=1, chain=2, interpret=True)
+    assert [c["name"] for c in report["checks"]] == [
+        f"sum_rows/toy/routed={share}" for share in ("quarter", "half", "all")]
+    assert all(c["ok"] for c in report["checks"])
+    assert {f"{what}/toy/routed={share}"
+            for what in ("sum_rows_ms", "token_order_ms", "scatter_add_ms")
+            for share in ("quarter", "half", "all")} <= set(report)
+
+
 def test_kernels_phase_interpreted():
     # 160 pads to 256: the padding path.  One dtype and one mask here; the
     # chip runs the product.

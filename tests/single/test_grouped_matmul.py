@@ -227,3 +227,109 @@ def test_the_two_gradients_alone_are_reverse_mode_s(case, w_dtype, interpret):
     assert (traced.count("pallas_call") if interpret
             else traced.count("ragged_dot_general[")) == 2
 
+
+
+# ---------------------------------------------------------------------------
+# The way back: rows summed into their tokens (``hvd_moe_sum_rows``)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Row tiles of 128 and token tiles of 32, so that a small buffer is
+    several of each; nothing traced with them stays in the jit's cache."""
+    monkeypatch.setattr(gm, "SUM_ROWS", 128)
+    monkeypatch.setattr(gm, "SUM_TOKENS", 32)
+    gm._sum_rows.clear_cache()
+    yield
+    gm._sum_rows.clear_cache()
+
+
+def _spread(capacity, tokens, top_k=4):
+    """Tokens of a buffer as a layer makes them: at most ``top_k`` rows a
+    token, in no order, some tokens with no row."""
+    return (jax.random.permutation(jax.random.PRNGKey(capacity),
+                                   tokens * top_k)[:capacity] // top_k)
+
+
+# name: (capacity, tokens, n, the rows' tokens)
+SUMS = {
+    "no-row": (384, 96, 0, _spread),
+    "the-full-buffer": (384, 96, 384, _spread),
+    "an-edge-inside-a-row-tile": (384, 96, 200, _spread),
+    "a-buffer-of-no-whole-row-tiles": (300, 96, 290, _spread),
+    "tokens-of-no-whole-token-tiles": (384, 100, 333, _spread),
+    "fewer-tokens-than-a-token-tile": (256, 24, 90,
+                                       lambda c, t: _spread(c, t, 12)),
+    "every-token-with-all-its-rows": (384, 96, 384,
+                                      lambda c, t: jnp.arange(c) % t),
+    "every-row-on-one-token-tile": (384, 96, 300,
+                                    lambda c, t: 64 + jnp.arange(c) % 7),
+    "most-tokens-with-no-row": (384, 96, 384,
+                                lambda c, t: 3 * (jnp.arange(c) % 5) + 40),
+}
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("case", SUMS)
+def test_the_sum_by_token_against_a_scatter_add(small_tiles, case, dtype):
+    """``sum_by_token`` of the first n rows is ``zeros.at[token[:n]].add(
+    rows[:n])`` made in float32 and rounded once, whatever the rows past n
+    hold: zeros where a token has no row, a token's every row, the edges of
+    row tiles and of token tiles anywhere."""
+    capacity, tokens, n, draw = SUMS[case]
+    dtype = jnp.dtype(dtype)
+    token = draw(capacity, tokens).astype(jnp.int32)
+    rows = jax.random.normal(jax.random.PRNGKey(1), (capacity, 128)).astype(
+        dtype)
+    live = (jnp.arange(capacity) < n)[:, None]
+    order = gm.token_order(token, jnp.int32(n), interpret=True)
+    got = gm.sum_by_token(jnp.where(live, rows, jnp.nan), order, tokens,
+                          interpret=True)
+    want = jnp.zeros((tokens, 128), jnp.float32).at[token].add(
+        jnp.where(live, rows, 0).astype(jnp.float32)).astype(dtype)
+    assert got.dtype == dtype and got.shape == want.shape
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        rtol=2.0 ** -7 if dtype == jnp.bfloat16 else 1e-5, atol=1e-5)
+    # rows n.. keep their order behind the others, the first n ascend
+    by, tokens_of = np.asarray(order.rows), np.asarray(order.tokens)
+    assert sorted(by[:n]) == list(range(n))
+    assert list(by[n:]) == list(range(n, capacity))
+    assert list(tokens_of[:n]) == sorted(np.asarray(token)[:n])
+
+
+def test_off_the_tpu_no_order_is_made():
+    # there ``parallel/moe.py:add_rows`` is a scatter-add in row order
+    assert jax.default_backend() != "tpu"
+    assert gm.token_order(jnp.arange(8, dtype=jnp.int32), 8) is None
+    assert gm.token_order(jnp.arange(8, dtype=jnp.int32), 8,
+                          interpret=True) is not None
+
+
+def test_the_sum_inside_a_jitted_shard_map_step_under_check_vma(small_tiles):
+    """Each chip sums its own rows into its own tokens."""
+    from jax import shard_map
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    capacity, tokens, chips = 256, 64, 2
+    token = jnp.concatenate([_spread(capacity + i, tokens)
+                             for i in range(chips)]).astype(jnp.int32)
+    rows = jax.random.normal(jax.random.PRNGKey(2), (chips * capacity, 128))
+    n = jnp.asarray([200, 77], jnp.int32)
+
+    def step(rows, token, n):
+        interpret = pltpu.InterpretParams()
+        order = gm.token_order(token, n[0], interpret=interpret)
+        return gm.sum_by_token(rows, order, tokens, interpret=interpret)
+
+    mesh = Mesh(np.asarray(jax.devices()[:chips]), ("hvd",))
+    got = jax.jit(shard_map(step, mesh=mesh, in_specs=P("hvd"),
+                            out_specs=P("hvd")))(rows, token, n)
+    for i in range(chips):
+        mine = slice(i * capacity, i * capacity + int(n[i]))
+        np.testing.assert_allclose(
+            got[i * tokens:(i + 1) * tokens],
+            jnp.zeros((tokens, 128)).at[token[mine]].add(rows[mine]),
+            rtol=1e-6, atol=1e-6)

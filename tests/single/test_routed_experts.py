@@ -2,7 +2,9 @@
 SwiGLU experts against a plain loop over the experts, under a skewed router
 (nothing dropped), whether or not the rows routed fit its row buffer; the
 shares add up to the uncut layer; the two ops that walk the rows routed
-(``take_rows``, ``add_rows``) against whole-buffer indexing."""
+(``take_rows``, ``add_rows``) against whole-buffer indexing, ``add_rows`` as
+a CPU makes it (a scatter-add in a loop) and as a TPU does (a segment sum in
+token order, its kernel interpreted here)."""
 
 import functools
 
@@ -598,3 +600,170 @@ def test_no_pass_of_the_routing_walks_the_whole_buffer(trips_of_64,
     loops = str(traced).count("while[")
     assert loops >= (3 if backward else 2), loops
     assert _wide_passes_outside_loops(traced.jaxpr, rows, D) == []
+
+
+# ---------------------------------------------------------------------------
+# ``add_rows`` as a TPU makes it: a segment sum in token order
+# ---------------------------------------------------------------------------
+
+
+def _scatter_adds_into(jaxpr, shape) -> int:
+    """The ``scatter-add`` equations of ``jaxpr``, at any depth, whose result
+    has ``shape``."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        found += (eqn.primitive.name == "scatter-add"
+                  and eqn.outvars[0].aval.shape == shape)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _scatter_adds_into(sub, shape)
+    return found
+
+
+@pytest.fixture
+def sums_by_the_kernel(monkeypatch):
+    """What a TPU runs for ``add_rows``, interpreted: the token order and
+    ``hvd_moe_sum_rows`` (row tiles of 128, token tiles of 32, so that a small
+    buffer is several of each), and no pass traced that way left behind."""
+    from jax.experimental.pallas import tpu as pltpu
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    def anew():
+        _traced_anew()
+        gm._sum_rows.clear_cache()
+
+    interpret = pltpu.InterpretParams()     # scalar prefetch under shard_map
+    monkeypatch.setattr(gm, "SUM_ROWS", 128)
+    monkeypatch.setattr(gm, "SUM_TOKENS", 32)
+    monkeypatch.setattr(moe, "token_order", functools.partial(
+        gm.token_order, interpret=interpret))
+    monkeypatch.setattr(moe, "sum_by_token", functools.partial(
+        gm.sum_by_token, interpret=interpret))
+    anew()
+    yield
+    anew()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("capacity", [256, 300],
+                         ids=["whole-tiles", "no-whole-number-of-tiles"])
+@pytest.mark.parametrize("n", [0, 1, 100, 128, None],
+                         ids=["none", "one", "an-edge-inside-a-tile",
+                              "a-whole-number-of-tiles", "the-full-buffer"])
+def test_add_rows_by_the_kernel_is_the_sum_in_float32(sums_by_the_kernel, n,
+                                                      capacity, dtype):
+    """On a TPU ``add_rows`` is ``zeros.at[token[:n]].add(rows[:n])`` summed
+    in float32 and rounded once (off it, the same sum in ``rows.dtype`` and
+    in row order: never further from it than the activations' rounding), and
+    the rows past n reach nothing."""
+    x, rows, token = _rows_case(capacity, jnp.dtype(dtype))
+    n = capacity if n is None else n
+    live = (jnp.arange(capacity) < n)[:, None]
+    # (a jit of a function of this test's own: one of ``moe.add_rows`` itself
+    # would find the trace an earlier test left, the CPU's)
+    got = jax.jit(lambda rows, token, n: moe.add_rows(
+        rows, token, n, x.shape[0]))(
+            jnp.where(live, rows, jnp.nan), token, jnp.int32(n))
+    assert "pallas_call" in str(jax.make_jaxpr(lambda rows: moe.add_rows(
+        rows, token, jnp.int32(n), x.shape[0]))(rows))
+    want = jnp.zeros(x.shape, jnp.float32).at[token].add(
+        jnp.where(live, rows, 0).astype(jnp.float32))
+    assert got.dtype == rows.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want.astype(dtype), np.float32),
+                               rtol=2.0 ** -7 if dtype == "bfloat16" else 1e-6,
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("n", [0, 100, 300])
+def test_the_row_ops_stay_each_other_s_transpose_by_the_kernel(
+        sums_by_the_kernel, n):
+    """``take_rows``' cotangent is ``add_rows`` by the kernel (it sorts for
+    itself there: nobody kept an order), ``add_rows``' is ``take_rows``,
+    with or without an order handed in; both are what indexing's are."""
+    x, rows, token = _rows_case(300, jnp.float32)
+    n, live = jnp.int32(n), (jnp.arange(300) < n)[:, None]
+    _, take_vjp = jax.vjp(lambda x: moe.take_rows(x, token, n), x)
+    _, index_vjp = jax.vjp(lambda x: jnp.where(live, x[token], 0.0), x)
+    np.testing.assert_allclose(take_vjp(rows)[0], index_vjp(rows)[0],
+                               rtol=1e-6, atol=1e-6)
+    by_token = moe.token_order(token, n)
+    for order in (None, by_token):
+        summed, add_vjp = jax.vjp(lambda r: moe.add_rows(
+            r, token, n, x.shape[0], False, order), rows)
+        np.testing.assert_array_equal(summed, take_vjp(rows)[0])
+        np.testing.assert_array_equal(add_vjp(x)[0],
+                                      moe.take_rows(x, token, n))
+
+
+@pytest.mark.parametrize("chips", [1, 2])
+@pytest.mark.parametrize("skewed", [(1,), (1, 2)], ids=["fits", "in-parts"])
+def test_the_layer_by_the_kernel_inside_a_jitted_shard_map_step(
+        sums_by_the_kernel, skewed, chips):
+    """The layer as a TPU sums it back, under ``jit``, ``shard_map`` with
+    ``check_vma`` and ``value_and_grad``: where the rows fit (the order made
+    in the forward and kept) and in parts (a part's buffer, its order made
+    where it is needed), against the loop."""
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    x, router, *kernels = _layer(skew=6.0, skewed=skewed)
+    mine = _held(kernels, 0, 4)
+
+    def step(*a):
+        value, grads = jax.value_and_grad(lambda *a: jnp.sum(
+            moe.routed_experts(*a, top_k=TOP_K, capacity_factor=2.0)[0] ** 2),
+            argnums=(0, 1, 2, 3, 4))(*a)
+        return jax.lax.psum(value, "hvd"), grads
+
+    mesh = Mesh(np.asarray(jax.devices()[:chips]), ("hvd",))
+    sharded = shard_map(
+        step, mesh=mesh, in_specs=(P("hvd"), P(), P(), P(), P()),
+        out_specs=(P(), (P("hvd"), P(), P(), P(), P())))
+    # both passes' sums on each side of the cond, and no scatter-add
+    traced = jax.make_jaxpr(sharded)(x, router, *mine)
+    assert str(traced).count("hvd_moe_sum_rows") >= 3
+    assert _scatter_adds_into(traced.jaxpr, (TOKENS // chips, D)) == 0
+    got = jax.jit(sharded)(x, router, *mine)
+    want = jax.value_and_grad(lambda *a: jnp.sum(_loop(*a) ** 2),
+                              argnums=(0, 1, 2, 3, 4))(x, router, *mine)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for name, a, b in zip(("x", "router", "gate", "up", "down"), got[1],
+                          want[1]):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("top_k,sorts", [(TOP_K, (2, 0)), (1, (1, 0))],
+                         ids=["top-k", "top-1"])
+def test_the_token_order_is_made_once_a_layer(sums_by_the_kernel, top_k,
+                                              sorts):
+    """A fitting forward sorts twice (the choices by expert, the rows by
+    token) and its backward not at all: the order is kept; both sums are the
+    kernel's and no scatter-add of a ``[tokens, d]`` array is left.  A layer
+    whose tokens have one row each makes no order and calls no kernel."""
+    x, router, *kernels = _layer()
+    mine = _held(kernels, 0, 4)
+    routing = moe.route(x, router, top_k, 0, 4)
+    local = moe._local(routing.experts, 0, 4)
+    rows = TOKENS * min(top_k, 4)       # every row fits: no cond
+    args = (x, local, routing.weights)
+
+    def traced(fn, *a):     # through a new function each time: the traces
+        return jax.make_jaxpr(lambda *a: fn(rows, *a))(*a)  # of one are kept
+
+    kept = moe._forward(rows, *args, *mine)[1]
+    assert (kept.by_token is None) == (top_k == 1)
+    traces = (traced(moe._forward, *args, *mine),
+              traced(moe._backward, *args, kept, x, *mine))
+    with pytest.MonkeyPatch.context() as off_the_tpu:   # what a CPU traces
+        off_the_tpu.setattr(moe, "token_order", lambda token, n: None)
+        _traced_anew()
+        assert _scatter_adds_into(traced(moe._forward, *args, *mine).jaxpr,
+                                  x.shape) == (top_k > 1)
+        _traced_anew()
+    for trace, n_sorts in zip(traces, sorts):
+        assert _count(trace.jaxpr, {"sort"}, 1) == n_sorts
+        assert _count(trace.jaxpr, {"pallas_call"}, 1) == (top_k > 1)
+        assert _scatter_adds_into(trace.jaxpr, x.shape) == 0

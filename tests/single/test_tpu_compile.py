@@ -358,37 +358,43 @@ def test_grouped_products_compile_at_an_expert_wider_than_the_budget(
     assert len(re.findall(r"hvd_moe_tgmm[\w.]* = ", text)) == 1
 
 
+@pytest.fixture
+def expert_layer_as_on_a_tpu(monkeypatch):
+    """``parallel/moe.py`` traced as a TPU traces it (the kernels, not what a
+    CPU runs in their place), and no such trace left behind: the passes' jit
+    caches are keyed by shapes, not by the backend they were traced for."""
+    from horovod_tpu.parallel import moe
+
+    def traced_anew():
+        moe._forward.clear_cache()
+        moe._backward.clear_cache()
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    traced_anew()
+    yield moe
+    traced_anew()
+
+
 def test_a_fitting_expert_layer_runs_no_product_of_its_forward_again(
-        one_chip, monkeypatch):
+        one_chip, expert_layer_as_on_a_tpu):
     """``parallel/moe.py:routed_experts`` at ``sdar-moe-ep8-s4096``'s layer
     (16,384 tokens, top-8 of 128, 16 held, a 36,864-row buffer), value and
     gradients: where the rows fit, three products forward and, from what
     the forward kept, three ``d rows`` and three ``dW`` backward; in parts
     as before, each part's forward made again."""
-    from horovod_tpu.parallel import moe
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-    def traced_anew():      # the passes' jit caches are keyed by shapes, not
-        moe._forward.clear_cache()      # by the backend they were traced for
-        moe._backward.clear_cache()
-
-    traced_anew()
+    moe = expert_layer_as_on_a_tpu
 
     def step(x, router, *kernels):
         return jax.value_and_grad(lambda *a: jnp.sum(moe.routed_experts(
             *a, top_k=8, capacity_factor=2.25)[0].astype(jnp.float32) ** 2),
             argnums=(0, 1, 2, 3, 4))(x, router, *kernels)
 
-    try:
-        text = _compiled_text(step, *_shapes_on(one_chip, (
-            jax.ShapeDtypeStruct((16384, 2048), jnp.bfloat16),
-            jax.ShapeDtypeStruct((2048, 128), jnp.float32),
-            jax.ShapeDtypeStruct((16, 2048, 768), jnp.float32),
-            jax.ShapeDtypeStruct((16, 2048, 768), jnp.float32),
-            jax.ShapeDtypeStruct((16, 768, 2048), jnp.float32))))
-    finally:
-        traced_anew()
+    text = _compiled_text(step, *_shapes_on(one_chip, (
+        jax.ShapeDtypeStruct((16384, 2048), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2048, 128), jnp.float32),
+        jax.ShapeDtypeStruct((16, 2048, 768), jnp.float32),
+        jax.ShapeDtypeStruct((16, 2048, 768), jnp.float32),
+        jax.ShapeDtypeStruct((16, 768, 2048), jnp.float32))))
 
     def calls(kernel, side):
         return len(re.findall(
@@ -397,6 +403,110 @@ def test_a_fitting_expert_layer_runs_no_product_of_its_forward_again(
     assert (calls("hvd_moe_gmm", 1), calls("hvd_moe_tgmm", 1)) == (6, 3)
     assert (calls("hvd_moe_gmm", 0), calls("hvd_moe_tgmm", 0)) == (9, 3)
     assert "ragged-dot" not in text
+
+
+# (tokens, d, f, experts, held, top_k, capacity_factor) of a chip's expert
+# layer in the four cells that have one.
+EXPERT_LAYERS = {
+    "sdar-moe-ep8-s4096": (16384, 2048, 768, 128, 16, 8, 2.25),
+    "laguna-swa-ep32-s16384": (16384, 3072, 1024, 256, 8, 10, 2.0),
+    "joyai-mla-ep16-s16384": (16384, 2048, 768, 256, 16, 8, 2.0),
+    "zaya1-moe-ep2-s16384": (16384, 2048, 2048, 16, 8, 1, 2.0)}
+
+
+def _loops_scattering_into(text: str, shape: str) -> list:
+    """The ``while`` bodies of a compiled module (and what they call) that
+    hold a ``scatter`` whose result is ``shape``."""
+    bodies = dict(re.findall(r"\n(?:ENTRY )?%?([\w.-]+) [^\n]*\{\n(.*?)\n\}",
+                             text, flags=re.S))
+
+    def scatters(name, seen):
+        if name in seen or name not in bodies:
+            return False
+        seen.add(name)
+        body = bodies[name]
+        if re.search(rf"= {re.escape(shape)}[^\n]* scatter\(", body):
+            return True
+        return any(scatters(callee, seen) for callee in re.findall(
+            r"(?:calls|to_apply|body|condition|branch_computations=\{)"
+            r"=?%?([\w.-]+)", body))
+
+    return [body for body in re.findall(r"body=%?([\w.-]+)", text)
+            if scatters(body, set())]
+
+
+@pytest.mark.parametrize("rows,tokens,d,dtype", [
+    (36864, 16384, 2048, "bfloat16"), (10240, 16384, 3072, "bfloat16"),
+    (8192, 4096, 2048, "float32"), (1100, 300, 256, "bfloat16"),
+    (1100, 300, 256, "float32"), (96, 40, 128, "bfloat16")],
+    ids=["sdar-and-joyai-s-width", "laguna-s-width", "float32-rows",
+         "no-whole-number-of-either-tile", "the-same-in-float32",
+         "smaller-than-either-tile"])
+def test_the_sum_by_token_compiles(one_chip, rows, tokens, d, dtype):
+    """``ops/grouped_matmul.py:sum_by_token`` alone: a gather into token
+    order (one for each prefix of the buffer it may cover) and one
+    ``hvd_moe_sum_rows``, at the cells' widths, for float32 rows (summed at
+    the highest precision) and where the buffer's last row tile and the
+    tokens' last tile are partial."""
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    order = gm.TokenOrder(*_shapes_on(one_chip, (
+        jax.ShapeDtypeStruct((rows,), jnp.int32),) * 2))
+    text = _compiled_text(
+        lambda r, o: gm.sum_by_token(r, o, tokens, interpret=False),
+        jax.ShapeDtypeStruct((rows, d), jnp.dtype(dtype), sharding=one_chip),
+        order)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert len(re.findall(r"hvd_moe_sum_rows[\w.]* = ", text)) == 1
+    # (the schedule's ``jnp.repeat`` is a scatter of a few int32)
+    assert not re.search(rf"= \w+\[\d+,{d}\][^\n]* scatter\(", text)
+
+
+@pytest.mark.parametrize("cell", EXPERT_LAYERS)
+def test_the_expert_layer_sums_its_rows_back_by_one_kernel_a_pass(
+        one_chip, expert_layer_as_on_a_tpu, cell):
+    """``parallel/moe.py:dispatch_experts`` at each cell's layer, value and
+    gradients, compiled for the chip: the fitting side holds two
+    ``hvd_moe_sum_rows`` (the forward's sum of the weighted rows, the
+    backward's of ``d rows``), the grouped products' counts are what they
+    were, and no ``scatter`` into a ``[tokens, d]`` array is left inside a
+    ``while``.  ZAYA's top-1 layer gathers its sum and holds neither."""
+    moe = expert_layer_as_on_a_tpu
+    tokens, d, f, experts, held, top_k, factor = EXPERT_LAYERS[cell]
+
+    def step(x, chosen, weights, *kernels):
+        return jax.value_and_grad(lambda x, weights, *k: jnp.sum(
+            moe.dispatch_experts(
+                x, chosen, weights, *k, first_expert=0, experts_total=experts,
+                capacity_factor=factor).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2, 3, 4))(x, weights, *kernels)
+
+    text = _compiled_text(step, *_shapes_on(one_chip, (
+        jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((tokens, top_k), jnp.int32),
+        jax.ShapeDtypeStruct((tokens, top_k), jnp.float32),
+        jax.ShapeDtypeStruct((held, d, f), jnp.float32),
+        jax.ShapeDtypeStruct((held, d, f), jnp.float32),
+        jax.ShapeDtypeStruct((held, f, d), jnp.float32))))
+
+    def calls(kernel, *sides):
+        """The calls of ``kernel`` whose ``op_name`` goes through these sides
+        of the conds it lies in, outermost first."""
+        found = re.findall(rf"{kernel}[\w.]* = [^\n]*", text)
+        return sum(tuple(map(int, re.findall(r"cond/branch_(\d+)_fun", line))
+                         )[:len(sides)] == sides for line in found)
+
+    assert _loops_scattering_into(text, f"bf16[{tokens},{d}]") == []
+    if top_k == 1:          # no cond: the buffer holds every row there is
+        assert "cond/branch" not in text
+        assert calls("hvd_moe_sum_rows") == 0
+        assert (calls("hvd_moe_gmm"), calls("hvd_moe_tgmm")) == (6, 3)
+        return
+    fits, in_parts = 1, 0
+    assert calls("hvd_moe_sum_rows", fits) == 2
+    assert (calls("hvd_moe_gmm", fits), calls("hvd_moe_tgmm", fits)) == (6, 3)
+    assert (calls("hvd_moe_gmm", in_parts),
+            calls("hvd_moe_tgmm", in_parts)) == (9, 3)
 
 
 HEADS = {"zaya": (2048, 131136), "jamba": (2560, 16384)}

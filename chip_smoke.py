@@ -37,6 +37,17 @@
                                      kernels without the pair (q and k
                                      concatenated to 192, v padded), forward
                                      and backward timed; nothing else
+    python chip_smoke.py --flash-diff
+                                     one chip: differential attention's two
+                                     maps at ``phi4flash-sambay-tp2-s16384``'s
+                                     shape (10 query pairs on 5 values of 128,
+                                     q and k 64 wide, 16,384 rows), causal
+                                     and under the band of 512,
+                                     as one padded 128-lane call a map and as
+                                     four 64-wide calls, against
+                                     ``dense_attention``, values and the three
+                                     gradients, forward and backward timed,
+                                     and ``A1 - lambda A2``; nothing else
     python chip_smoke.py --lightning
                                      one chip: ``ops/lightning_attention.py``
                                      at ``sala-sparse-linear-tp4-s16384``'s
@@ -1067,6 +1078,138 @@ def flash_mla(length: int = 16384, heads: int = 4, head_dim: int = 128,
     return report
 
 
+def flash_diff(length: int = 16384, heads: int = 20, kv_heads: int = 10,
+               head_dim: int = 64, window: int = 512, layer: int = 17,
+               repeats: int = 5, chain: int = 4,
+               interpret: bool = False) -> dict:
+    """Differential attention's two softmax maps on the flash kernels (the
+    defaults are ``phi4flash-sambay-tp2-s16384``'s: 20 query heads on 10
+    key/value heads of 64 paired by neighbours, so 10 query pairs on 5 values
+    of 128, over 16,384 rows in bfloat16), causal and under the band, in two
+    forms: ``padded`` (what ``models/
+    phi4flash.py:two_maps`` runs: a map is one grouped call at the value's
+    128 lanes, q and k zero-padded) and ``four_calls`` (the kernels as they
+    stood: each map against the value's even and odd head, 64 wide, every
+    score made twice).  A value operand wider than q and k inside the
+    kernels is not built (PERF.md section 6, PR 60 says why).  Each form's
+    ``(A1, A2)`` and its dq, dk, dv of one drawn pair of cotangents against
+    ``dense_attention`` a pair at a time; the forward's time and the forward
+    and backward's, each pass one of ``chain`` in one compiled program; and
+    ``A1 - lambda A2`` at published layer ``layer``'s ``lambda_init``, the
+    subtraction in float32 of the maps as the kernels hand them out, against
+    the dense form's (``difference_error``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import phi4flash
+    from horovod_tpu.ops.flash_attention import (dense_attention,
+                                                 flash_attention)
+
+    cfg = phi4flash.Phi4FlashConfig(
+        num_heads=heads, num_kv_heads=kv_heads, head_dim=head_dim,
+        sliding_window=window, use_flash=True)
+    pairs, groups, d = heads // 2, kv_heads // 2, head_dim
+    ks = jax.random.split(jax.random.PRNGKey(heads), 5)
+    q = jax.random.normal(ks[0], (1, length, heads * d), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (1, length, kv_heads * d), jnp.bfloat16)
+            for key in ks[1:3])
+    g = tuple(jax.random.normal(key, (1, length, pairs, 2 * d), jnp.bfloat16)
+              for key in ks[3:5])
+    kernel = functools.partial(flash_attention, causal=True, scale=d ** -0.5,
+                               interpret=interpret or None)
+
+    def padded(band, q, k, v):
+        return phi4flash.two_maps(cfg, q, k, v, band,
+                                  interpret=interpret or None)
+
+    def by_pair(x, n):
+        x = x.reshape(1, length, n, 2, d)
+        return x[:, :, :, 0], x[:, :, :, 1]
+
+    def four_calls(band, q, k, v):
+        v_even, v_odd = by_pair(v, groups)
+        return tuple(jnp.concatenate(
+            [kernel(q_m, k_m, half, window=band) for half in (v_even, v_odd)],
+            axis=-1) for q_m, k_m in zip(by_pair(q, pairs),
+                                         by_pair(k, groups)))
+
+    def dense(band, q, k, v):
+        """A pair at a time: float32 operands at full precision, the dense
+        softmax (the kernels beside it keep the precision they run at)."""
+        wide = lambda x: x.astype(jnp.float32)  # noqa: E731
+        values = wide(v).reshape(1, length, groups, 2 * d)
+        zeros = [(0, 0)] * 3 + [(0, d)]
+        with jax.default_matmul_precision("highest"):
+            return tuple(jnp.concatenate([dense_attention(
+                jnp.pad(q_m[:, :, p:p + 1], zeros),
+                jnp.pad(k_m[:, :, r:r + 1], zeros), values[:, :, r:r + 1],
+                causal=True, window=band, scale=d ** -0.5)
+                for p in range(pairs) for r in [p // (pairs // groups)]],
+                axis=2) for q_m, k_m in zip(by_pair(wide(q), pairs),
+                                            by_pair(wide(k), groups)))
+
+    def both(fn, g, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (*out, *vjp(tuple(x.astype(o.dtype) for x, o in zip(g, out))))
+
+    start = phi4flash.lambda_init(layer)
+
+    def difference_error(maps, want) -> float:
+        got, want = (a1.astype(jnp.float32) - start * a2.astype(jnp.float32)
+                     for a1, a2 in (maps, want))
+        return float(jnp.linalg.norm((got - want).ravel())
+                     / jnp.linalg.norm(want.ravel()))
+
+    checks, times, errors = [], {}, {}
+    for mask, band in (("causal", None), ("band", window)):
+        want = jax.jit(functools.partial(both, functools.partial(
+            dense, band)))(g, q, k, v)
+        for form, fn in (("padded", padded), ("four_calls", four_calls)):
+            fn = functools.partial(fn, band)
+            got = jax.jit(functools.partial(both, fn))(g, q, k, v)
+            for name, a, b in zip(("a1", "a2", "dq", "dk", "dv"), got, want):
+                _check(checks, f"{mask}/{form}/{name}", a, b,
+                       TOL_BF16_FWD if name[0] == "a" else TOL_BF16_BWD)
+            errors[f"{mask}/{form}"] = difference_error(got[:2], want[:2])
+            if not interpret or form == "padded":
+                times[f"{mask}/{form}"] = _two_maps_ms(fn, g, (q, k, v),
+                                                       chain, repeats)
+    report = emit("flash_diff", checks=checks, length=length, heads=heads,
+                  kv_heads=kv_heads, head_dim=head_dim, window=window,
+                  chain=chain, lambda_init=start, times=times,
+                  difference_error=errors)
+    _raise_on_failed("flash_diff", checks)
+    return report
+
+
+def _two_maps_ms(fn, g, operands, chain: int, repeats: int) -> dict:
+    """``fwd_ms`` and ``fwd_bwd_ms`` of ``fn(q, k, v) -> (A1, A2)``: each pass
+    one of ``chain`` in one compiled program, a pass fed one element of each
+    result of the one before so that no kernel can be dropped or
+    overlapped."""
+    import jax
+
+    def fed(x, like):
+        return x.at[0, 0, 0].add(like.ravel()[0].astype(x.dtype))
+
+    def fwd_chain(q, k, v):
+        for _ in range(chain):
+            a1, a2 = fn(q, k, v)       # both read: neither map is dropped
+            q = fed(fed(q, a1), a2)
+        return q
+
+    def bwd_chain(g, q, k, v):
+        for _ in range(chain):
+            dq, dk, dv = jax.vjp(fn, q, k, v)[1](g)
+            q, k, v = fed(q, dq), fed(k, dk), fed(v, dv)
+        return q, k, v
+
+    return {"fwd_ms": round(_best_ms(repeats, jax.jit(fwd_chain), *operands)
+                            / chain, 3),
+            "fwd_bwd_ms": round(_best_ms(repeats, jax.jit(bwd_chain), g,
+                                         *operands) / chain, 3)}
+
+
 def _chain_ms(fn, g, operands, chain: int, repeats: int) -> dict:
     """``fwd_ms`` and ``fwd_bwd_ms`` of ``fn(*operands)`` (the result shaped
     like its first operand): each pass one of ``chain`` in one compiled
@@ -1388,6 +1531,9 @@ def main(argv=None) -> int:
     ap.add_argument("--flash-mla", action="store_true",
                     help="check and time the flash kernels with a second "
                          "score operand, and nothing else")
+    ap.add_argument("--flash-diff", action="store_true",
+                    help="check and time differential attention's two maps "
+                         "on the flash kernels, and nothing else")
     ap.add_argument("--lightning", action="store_true",
                     help="check and time the lightning-attention kernels, "
                          "and nothing else")
@@ -1424,6 +1570,9 @@ def main(argv=None) -> int:
     elif args.flash_mla:
         info = device()
         flash_mla()
+    elif args.flash_diff:
+        info = device()
+        flash_diff()
     elif args.lightning:
         info = device()
         lightning()

@@ -17,7 +17,11 @@ shared one; its loss is ``joyai.lm_loss``) and ``Sala`` (MiniCPM-SALA:
 lightning linear-attention layers, one decay a head as chunked products on the
 MXU, among block-selected softmax-attention layers whose visible keys the
 data chooses, output gates on both, in MiniCPM's scaled frame; its loss is
-``sala.lm_loss``)."""
+``sala.lm_loss``) and ``Phi4Flash`` (Phi-4-mini-flash's SambaY: Mamba-1 and
+banded differential-attention layers, then a cross-decoder whose gate layers
+read one scan's output and whose attention layers read one layer's keys and
+values, carried beside the residual stream; its loss is
+``phi4flash.lm_loss``)."""
 
 from .losses import softmax_cross_entropy  # noqa: F401
 from .mlp import MLP, xent_loss  # noqa: F401
@@ -52,3 +56,7 @@ from .joyai import (  # noqa: F401
 )
 from . import sala  # noqa: F401
 from .sala import Sala, SalaConfig, MINICPM_SALA, SALA_TINY  # noqa: F401
+from . import phi4flash  # noqa: F401
+from .phi4flash import (  # noqa: F401
+    Phi4Flash, Phi4FlashConfig, PHI4_MINI_FLASH, PHI4FLASH_TINY,
+)

@@ -29,6 +29,10 @@ key/value heads (20 on 1: multi-query), causal, no bias, **no positional
 encoding of any kind** (the recurrence carries the order), scale
 ``head_dim ** -0.5``.
 
+``mamba_norms`` False is Mamba-1 as published, without Jamba's three norms
+(``models/phi4flash.py`` runs this mixer so, and with ``memory=True`` takes
+the scan's output ``y`` beside the mixer's own).
+
 Everything between the projections and the kernels stays channel-minor ``[B,
 S, C]``: the convolution is ``mamba_d_conv`` shifted multiply-adds
 (``models/zaya.py:shift``), the scan is ``ops/selective_scan.py`` on that
@@ -116,6 +120,7 @@ class JambaConfig:
     mamba_dt_rank: int = 160
     mamba_conv_bias: bool = True
     mamba_proj_bias: bool = False
+    mamba_norms: bool = True         # Jamba's own three, on dt, B and C
     rms_norm_eps: float = 1e-6
     # What this chip holds of each layer's width; None: the whole.
     vocab_size_held: Optional[int] = None
@@ -289,8 +294,15 @@ class PairedDense(nn.Module):
 
 
 class MambaMixer(nn.Module):
+    """The Mamba-1 mixer of a configuration with ``JambaConfig``'s ``mamba_*``
+    keys (``models/phi4flash.py`` brings its own).  ``mamba_norms`` False
+    leaves ``dt``, ``B`` and ``C`` as ``x_proj`` made them (Mamba-1 as
+    published: no scale is created).  ``memory``: the mixer returns ``(out,
+    y)``, its scan's output ``y`` [B, S, held] (with the ``D u`` term, before
+    the gate) beside its own, for the layers that read it as their memory."""
     config: JambaConfig
     axis_name: Optional[str] = None
+    memory: bool = False
 
     @nn.compact
     def __call__(self, h):
@@ -307,7 +319,8 @@ class MambaMixer(nn.Module):
         a_log = self.param("A_log", _a_log_init, (held, n))
         d = self.param("D", nn.initializers.ones, (held,))
         scales = {k: self.param(f"{k}_norm", nn.initializers.ones, (width,))
-                  for k, width in (("dt", rank), ("b", n), ("c", n))}
+                  for k, width in (("dt", rank), ("b", n), ("c", n))
+                  } if cfg.mamba_norms else None
         dt_kernel = self.param("dt_proj", _uniform_init(rank ** -0.5),
                                (rank, held))
         dt_bias = self.param("dt_bias", _dt_bias_init, (held,))
@@ -322,9 +335,10 @@ class MambaMixer(nn.Module):
             # and the three norms want the whole sum.
             dbc = RowParallel(rank + 2 * n, cfg.d_inner, self.axis_name,
                               cfg.dtype, jnp.float32, name="x_proj")(u)
-            dt, b, c = (
-                _scaled(part, scales[k], cfg.rms_norm_eps) for k, part in
-                zip(("dt", "b", "c"), jnp.split(dbc, [rank, rank + n], -1)))
+            dt, b, c = jnp.split(dbc, [rank, rank + n], -1)
+            if scales:
+                dt, b, c = (_scaled(part, scales[k], cfg.rms_norm_eps)
+                            for k, part in (("dt", dt), ("b", b), ("c", c)))
             dt = jax.nn.softplus(jnp.dot(
                 dt.astype(cfg.dtype), dt_kernel.astype(cfg.dtype),
                 preferred_element_type=jnp.float32) + dt_bias)
@@ -336,11 +350,12 @@ class MambaMixer(nn.Module):
         self.sow("intermediates", "scan", {
             "x_proj": dbc, "operands": (u, dt, a, b, c, d), "y": y})
         with jax.named_scope("hvd_ssm_mix"):
-            y = (y.astype(jnp.float32)
-                 * jax.nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
+            gated = (y.astype(jnp.float32)
+                     * jax.nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
         with jax.named_scope("hvd_ssm_proj"):
-            return RowParallel(cfg.hidden_size, cfg.d_inner, self.axis_name,
-                               cfg.dtype, name="out_proj")(y)
+            out = RowParallel(cfg.hidden_size, cfg.d_inner, self.axis_name,
+                              cfg.dtype, name="out_proj")(gated)
+        return (out, y) if self.memory else out
 
 
 class JambaAttention(nn.Module):

@@ -46,12 +46,14 @@ LONG_FILES = (
     "tests/single/test_jamba.py",
     "tests/single/test_tpu_compile.py",
     "tests/benchmark/test_sala_cell.py",
+    "tests/benchmark/test_phi4flash_cell.py",
     "tests/benchmark/test_joyai_cell.py",
     "tests/benchmark/test_laguna_cell.py",
     "tests/single/test_flash_attention.py",
     "tests/parallel/test_shm_plane_perf.py",
     "tests/single/test_laguna.py",
     "tests/single/test_sala.py",
+    "tests/single/test_phi4flash.py",
     "tests/single/test_joyai.py",
     "tests/single/test_flash_window.py",
     "tests/single/test_bert_reference.py",
@@ -62,6 +64,7 @@ LONG_FILES = (
     "tests/single/test_flash_select.py",
     "tests/single/test_flash_mla.py",
     "tests/single/test_lightning_attention.py",
+    "tests/single/test_flash_diff.py",
     "tests/benchmark/test_benchmark.py",
     "tests/single/test_chip_smoke.py",
     "tests/single/test_trace_names.py",

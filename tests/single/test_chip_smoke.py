@@ -161,6 +161,26 @@ def test_flash_mla_phase_tiny():
     assert {"fwd_ms", "fwd_bwd_ms"} <= set(report)
 
 
+def test_flash_diff_phase_tiny():
+    """Differential attention's two maps on the flash kernels (interpreted),
+    as one padded call a map and as four calls, causal and banded, against
+    ``dense_attention``, with the difference's error and the padded form's
+    times."""
+    report = chip_smoke.flash_diff(length=128, heads=4, kv_heads=2,
+                                   head_dim=64, window=64, repeats=1,
+                                   chain=2, interpret=True)
+    assert [c["name"] for c in report["checks"]] == [
+        f"{mask}/{form}/{name}" for mask in ("causal", "band")
+        for form in ("padded", "four_calls")
+        for name in ("a1", "a2", "dq", "dk", "dv")]
+    assert all(c["ok"] for c in report["checks"])
+    assert set(report["times"]) == {"causal/padded", "band/padded"}
+    assert set(report["difference_error"]) == {
+        f"{mask}/{form}" for mask in ("causal", "band")
+        for form in ("padded", "four_calls")}
+    assert all(e < 1e-2 for e in report["difference_error"].values())
+
+
 def test_lightning_phase_tiny():
     """The lightning-attention kernels (interpreted) against the quadratic
     form and the scan form, by chunk size, and the timing table's keys."""

@@ -1146,3 +1146,31 @@ def test_flash_ring_attention_compiles_on_four_chips(topo, monkeypatch):
                                     NamedSharding(mesh, spec)))
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+def test_the_two_maps_compile_at_the_phi4flash_cell_s_shapes(one_chip,
+                                                             monkeypatch):
+    """``models/phi4flash.py:two_maps`` at ``phi4flash-sambay-tp2-s16384``'s
+    shapes (10 query pairs on 5 values of 128 lanes, q and k padded from 64,
+    16,384 rows), causal and under the band of 512, value and gradients: two
+    calls of each kernel a mask, by name, and no ``hvd_flash_relayout``."""
+    from horovod_tpu.models import phi4flash
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(phi4flash.PHI4_MINI_FLASH, num_heads_held=20,
+                              num_kv_heads_held=10)
+    q = jax.ShapeDtypeStruct((1, 16384, 1280), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 640), jnp.bfloat16,
+                              sharding=one_chip)
+    for window, band in ((None, ""), (512, "swa_")):
+        def loss(q, k, v, window=window):
+            a1, a2 = phi4flash.two_maps(cfg, q, k, v, window)
+            return jnp.sum((a1.astype(jnp.float32)
+                            - 0.8 * a2.astype(jnp.float32)) ** 2)
+
+        text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+        for kernel in ("fwd", "dq", "dkv"):
+            name = f"hvd_flash_{band}{kernel}"
+            assert len(re.findall(rf"{name}[\w.]* = ", text)) == 2, name
+        assert "hvd_flash_relayout" not in text

@@ -341,6 +341,10 @@ FAMILY_CELLS = {
     "sala": ("sala-sparse-linear-tp4-s16384", {
         "hvd_block", "hvd_attn", "hvd_attn_proj", "hvd_mlp",
         "hvd_qk_norm_rope", "hvd_qk_norm", "hvd_attn_gate", "hvd_lm_head"}),
+    "phi4flash": ("phi4flash-sambay-tp2-s16384", {
+        "hvd_block", "hvd_attn", "hvd_attn_proj", "hvd_attn_diff", "hvd_gmu",
+        "hvd_mlp", "hvd_ssm_proj", "hvd_ssm_mix", "hvd_ssm_scan",
+        "hvd_lm_head"}),
 }
 # Ops of a step's forward or backward that no scope of the program's can
 # name, each with its reason.

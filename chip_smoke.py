@@ -77,6 +77,15 @@
                                      and statistics, alone and inside the
                                      whole head's value and gradients, both
                                      timed; nothing else
+    python chip_smoke.py --embed-grad
+                                     one chip: ``ops/embedding.py`` at the
+                                     seven cells' held tables and ids a step:
+                                     the table's gradient by the segment sum
+                                     in id order against a float32
+                                     scatter-add, timed beside XLA's scatter
+                                     for the same lookup, and the forward's
+                                     two orders (cast the table then take,
+                                     take then cast); nothing else
 
 One chip: the device JAX found, a clean build of the C++ core and
 ``hvd.init()`` on it, the Pallas kernels alone against their references (at
@@ -394,18 +403,24 @@ SUM_SHAPES = {"sdar-moe-ep8-s4096": (36864, 16384, 2048, 8, 16, 128),
               "laguna-swa-ep32-s16384": (10240, 16384, 3072, 10, 8, 256)}
 
 
-def _chained_ms(repeats: int, chain: int, body, rows, *args) -> float:
+def _chained_ms(repeats: int, chain: int, body, rows, *args,
+                whole: bool = False) -> float:
     """Milliseconds of one ``body(rows, *args)`` on the device: ``chain`` of
     them in one compiled program, each fed one element of the one before,
-    less a chain of one (the dispatch, some 0.6 ms, is in neither)."""
+    less a chain of one (the dispatch, some 0.6 ms, is in neither).
+    ``whole``: the chain carries a body's whole result beside it, for a body
+    XLA could narrow to the element read (a gather)."""
     import jax
 
     def chained(trips):
         def fn(rows, *args):
-            def trip(_, rows):
-                out = body(rows, *args)
-                return rows.at[0, 0].add(out[0, 0].astype(rows.dtype))
-            return jax.lax.fori_loop(0, trips, trip, rows)
+            def trip(_, carry):
+                out = body(carry[0], *args)
+                fed = carry[0].at[0, 0].add(out[0, 0].astype(rows.dtype))
+                return (fed, out) if whole else (fed,)
+            kept = (jax.numpy.zeros_like(jax.eval_shape(body, rows, *args)),
+                    ) if whole else ()
+            return jax.lax.fori_loop(0, trips, trip, (rows, *kept))
         return jax.jit(fn)
 
     return round((_best_ms(repeats, chained(chain), rows, *args)
@@ -469,6 +484,83 @@ def sum_rows(shapes=None, repeats: int = 5, chain: int = 16,
                    jax.jit(in_trips)(rows, token, n), TOL_BF16_FWD)
     report = emit("sum_rows", checks=checks, **report)
     _raise_on_failed("sum_rows", checks)
+    return report
+
+
+# (rows of the table held, d, ids a step) of the seven cells whose embedding
+# is a held share of a vocabulary.
+EMBED_SHAPES = {"phi4flash-sambay-tp2-s16384": (100032, 2560, 16384),
+                "zaya1-moe-ep2-s16384": (131136, 2048, 16384),
+                "jamba2-ssm-tp4-s16384": (16384, 2560, 16384),
+                "sala-sparse-linear-tp4-s16384": (18362, 4096, 16384),
+                "laguna-swa-ep32-s16384": (12544, 3072, 16384),
+                "sdar-moe-ep8-s4096": (18992, 2048, 16384),
+                "joyai-mla-ep16-s16384": (16160, 2048, 16384)}
+# One rounding of a float32 sum to bfloat16 is 2 ** -9 of the value.
+TOL_ONE_ROUNDING = 2.0 ** -8
+
+
+def embed_grad(shapes=None, repeats: int = 5, chain: int = 8,
+               interpret: bool = False) -> dict:
+    """``ops/embedding.py:embed_lookup`` alone at each cell's (table rows, d,
+    ids a step), ids uniform over the table: its table gradient (a float32
+    table under bfloat16 cotangent rows, as the models hold them) against a
+    float32 scatter-add, its value against ``jnp.take`` of the table cast,
+    and milliseconds on the device, one of ``chain`` in one program, best of
+    ``repeats``.  ``embed_grad_ms`` is the sum as a TPU runs it (the sort of
+    the ids, the gather into id order, ``embed_grad_sum_rows``) and
+    ``sorted_scatter_ms`` XLA's own for the same ``jnp.take`` (what
+    ``nn.Embed`` compiles to: the sorted scatter where the ids are more than
+    an eighth of the table's rows, the plain one under it), both into a
+    bfloat16 gradient: the cast to the table's float32 fuses into the sum
+    with a tied head's gradient on either side.  ``cast_then_take_ms`` /
+    ``take_then_cast_ms`` are the forward's two orders over the float32
+    table, between which ``embed_lookup`` chooses by the table's bytes."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.embedding import embed_lookup
+
+    chained_ms = functools.partial(_chained_ms, repeats, chain)
+
+    def lookup(table, ids):
+        return embed_lookup(table, ids, jnp.bfloat16,
+                            interpret=interpret or None)
+
+    def cast_first(table, ids):
+        return jnp.take(table.astype(jnp.bfloat16), ids, axis=0)
+
+    def take_first(table, ids):
+        return jnp.take(table, ids, axis=0).astype(jnp.bfloat16)
+
+    def d_table(fn):
+        return lambda g, table, ids: jax.vjp(
+            lambda t: fn(t, ids), table)[1](g)[0]
+
+    report, checks = {}, []
+    for cell, (rows, d, m) in (shapes or EMBED_SHAPES).items():
+        ks = jax.random.split(jax.random.PRNGKey(rows), 3)
+        table = jax.random.normal(ks[0], (rows, d), jnp.float32) / 50
+        ids = jax.random.randint(ks[1], (m,), 0, rows)
+        g = jax.random.normal(ks[2], (m, d), jnp.bfloat16)
+        _check(checks, f"embed_grad/{cell}/value",
+               jax.jit(lookup)(table, ids), jax.jit(cast_first)(table, ids),
+               1e-9)
+        _check(checks, f"embed_grad/{cell}/d_table",
+               jax.jit(d_table(lookup))(g, table, ids),
+               jnp.zeros((rows, d), jnp.float32).at[ids].add(
+                   g.astype(jnp.float32)), TOL_ONE_ROUNDING)
+        held = table.astype(jnp.bfloat16)
+        report[f"embed_grad_ms/{cell}"] = chained_ms(
+            d_table(lookup), g, held, ids)
+        report[f"sorted_scatter_ms/{cell}"] = chained_ms(
+            d_table(cast_first), g, held, ids)
+        report[f"cast_then_take_ms/{cell}"] = chained_ms(
+            cast_first, table, ids, whole=True)
+        report[f"take_then_cast_ms/{cell}"] = chained_ms(
+            take_first, table, ids, whole=True)
+    report = emit("embed_grad", checks=checks, chain=chain, **report)
+    _raise_on_failed("embed_grad", checks)
     return report
 
 
@@ -1543,6 +1635,9 @@ def main(argv=None) -> int:
     ap.add_argument("--tied-head", action="store_true",
                     help="check and time the blocked heads' logits kernel, "
                          "and nothing else")
+    ap.add_argument("--embed-grad", action="store_true",
+                    help="check and time the embedding lookup's gradient, "
+                         "and nothing else")
     ap.add_argument("--worker", action="store_true",
                     help="internal: one launch_np4 worker")
     args = ap.parse_args(argv)
@@ -1582,6 +1677,9 @@ def main(argv=None) -> int:
     elif args.tied_head:
         info = device()
         tied_head()
+    elif args.embed_grad:
+        info = device()
+        embed_grad()
     else:
         info = device()
         native_core()

@@ -91,6 +91,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..ops.collectives import vary_like
+from ..ops.embedding import embed_lookup
 from ..ops.flash_attention import (
     CHECKPOINT_NAMES as FLASH_CHECKPOINT_NAMES, dense_attention,
     flash_attention)
@@ -465,7 +466,8 @@ class Jamba(nn.Module):
     def hidden(self, ids):
         with jax.named_scope("hvd_embed"):
             if self.axis_name is None:
-                x = self.embed(ids)
+                x = embed_lookup(self.embed.embedding, ids,
+                                 self.config.dtype)
             else:
                 x = vocab_parallel_embedding(
                     ids, self.embed.embedding.astype(self.config.dtype),

@@ -73,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..ops.embedding import embed_lookup
 from ..ops.flash_attention import dense_attention, flash_attention
 from ..parallel.moe import dispatch_experts, expert_load
 from ..parallel.tensor_parallel import vocab_parallel_embedding
@@ -386,7 +387,8 @@ class JoyAI(nn.Module):
     def hidden(self, ids):
         with jax.named_scope("hvd_embed"):
             if self.axis_name is None:
-                x = self.embed(ids)
+                x = embed_lookup(self.embed.embedding, ids,
+                                 self.config.dtype)
             else:
                 x = vocab_parallel_embedding(
                     ids, self.embed.embedding.astype(self.config.dtype),

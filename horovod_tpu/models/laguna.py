@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.embedding import embed_lookup
 from ..ops.flash_attention import dense_attention, flash_attention
 from ..parallel.moe import routed_experts
 from ..parallel.tensor_parallel import vocab_parallel_embedding
@@ -440,7 +441,8 @@ class Laguna(nn.Module):
     def hidden(self, ids):
         with jax.named_scope("hvd_embed"):
             if self.axis_name is None:
-                x = self.embed(ids)
+                x = embed_lookup(self.embed.embedding, ids,
+                                 self.config.dtype)
             else:
                 x = vocab_parallel_embedding(
                     ids, self.embed.embedding.astype(self.config.dtype),

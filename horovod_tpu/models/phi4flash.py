@@ -79,6 +79,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.embedding import embed_lookup
 from ..ops.flash_attention import dense_attention, flash_attention
 from ..parallel.tensor_parallel import vocab_parallel_embedding
 from .flat_dense import FlatDenseGeneral
@@ -386,7 +387,8 @@ class Phi4Flash(nn.Module):
     def hidden(self, ids):
         with jax.named_scope("hvd_embed"):
             if self.axis_name is None:
-                x = self.embed(ids)
+                x = embed_lookup(self.embed.embedding, ids,
+                                 self.config.dtype)
             else:
                 x = vocab_parallel_embedding(
                     ids, self.embed.embedding.astype(self.config.dtype),

@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import flash_select
+from ..ops.embedding import embed_lookup
 from ..ops.flash_attention import dense_attention, flash_attention
 from ..ops.lightning_attention import (
     lightning_attention, lightning_attention_scan)
@@ -434,7 +435,7 @@ class Sala(nn.Module):
         cfg = self.config
         with jax.named_scope("hvd_embed"):
             if self.axis_name is None:
-                x = self.embed(ids)
+                x = embed_lookup(self.embed.embedding, ids, cfg.dtype)
             else:
                 x = vocab_parallel_embedding(
                     ids, self.embed.embedding.astype(cfg.dtype),

@@ -30,6 +30,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.embedding import embed_lookup
 from ..ops.flash_attention import dense_attention, flash_attention
 from ..ops.qk_norm_rope import dense_qk_norm_rope, qk_norm_rope
 from ..parallel.moe import routed_experts
@@ -245,9 +246,10 @@ class SDAR(nn.Module):
         # routers send them to the same few experts.
         with jax.named_scope("hvd_embed"):
             ids = jnp.concatenate([clean_ids, noised_ids], -1)   # [B, 2L]
-            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                         embedding_init=nn.initializers.normal(stddev=1.0),
-                         name="embed")(ids)
+            embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                             embedding_init=nn.initializers.normal(stddev=1.0),
+                             name="embed")
+            x = embed_lookup(embed.embedding, ids, cfg.dtype)
         for i in range(cfg.num_layers):
             x = SDARBlock(cfg, name=f"layer_{i}")(x)
         with jax.named_scope("hvd_lm_head"):

@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.embedding import embed_lookup
 from ..ops.flash_attention import (
     CHECKPOINT_NAMES, dense_attention, flash_attention)
 from ..parallel.moe import dispatch_experts, expert_load
@@ -372,7 +373,8 @@ class Zaya(nn.Module):
 
     def hidden(self, ids):
         with jax.named_scope("hvd_embed"):
-            r, y, s = self.embed(ids), None, None
+            r = embed_lookup(self.embed.embedding, ids, self.config.dtype)
+        y = s = None
         for layer in self.layers:
             r, y, s = layer(r, y, s)
         with jax.named_scope("hvd_lm_head"):

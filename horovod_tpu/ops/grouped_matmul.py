@@ -42,7 +42,9 @@ is a group (and :data:`SUM_ROWS` rows a visit), and a group's sum by token is
 VMEM from the rows' tokens: a 0 / 1 operand, so every product is exact, a
 float32 accumulator, one rounding, each token tile written once.  A
 scatter-add does the same sum as a serial read-modify-write at some 100
-cycles a row (PERF.md, PR 37, PR 59).
+cycles a row (PERF.md, PR 37, PR 59).  :func:`sum_ordered_rows` is the same
+kernel under a caller's name, for rows that lie in their destinations' order
+already (``ops/embedding.py``: a table's gradient, its rows the destinations).
 
 Off the TPU :func:`grouped_dot` is ``jax.lax.ragged_dot`` on ``w`` cast; the
 kernels are unit-tested in interpret mode (``tests/single/
@@ -466,11 +468,12 @@ def _sum_bytes(tile, span, n, itemsize) -> int:
             + span * n * 4)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3), inline=True)
-def _sum_rows(rows, tokens_of, tokens: int, interpret: bool):
+@functools.partial(jax.jit, static_argnums=(2, 3, 4), inline=True)
+def _sum_rows(rows, tokens_of, tokens: int, interpret: bool,
+              name: str = "hvd_moe_sum_rows"):
     """``[tokens, d]``: rows ``[M, d]`` in the order of their tokens
     ``tokens_of`` [M] (ascending, ``_PAST`` for a row of none) summed by
-    token."""
+    token.  ``name`` is the kernel's in a trace."""
     m, d = rows.shape
     tile, span = min(SUM_ROWS, m), min(SUM_TOKENS, tokens)
     held = -(-tokens // span)
@@ -488,7 +491,7 @@ def _sum_rows(rows, tokens_of, tokens: int, interpret: bool):
 
     out = pl.pallas_call(
         functools.partial(_sum_kernel, tile=tile, held=held, span=span),
-        name="hvd_moe_sum_rows",
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(d // block, groups.shape[0]),
             in_specs=[
@@ -506,6 +509,17 @@ def _sum_rows(rows, tokens_of, tokens: int, interpret: bool):
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret)(offsets, groups, tiles, tokens_of[None, :], rows)
     return out[:tokens]       # (the last token tile may be a partial one)
+
+
+def sum_ordered_rows(rows, tokens_of, tokens: int, *, name: str,
+                     interpret: bool = False):
+    """:func:`sum_by_token` for rows ``[M, d]`` that lie in their tokens'
+    order already, every one of them some token's (``tokens_of`` [M]
+    ascending in ``[0, tokens)``; a row whose token lies past them is
+    dropped): the kernel alone, under ``name`` in a trace
+    (``ops/embedding.py``: a table's rows are the tokens)."""
+    return _sum_rows(_vary_like(rows, tokens_of), _vary_like(tokens_of, rows),
+                     tokens, interpret, name)
 
 
 def _covers(capacity: int) -> list:

@@ -91,6 +91,20 @@ def test_sum_rows_phase_tiny():
             for share in ("quarter", "half", "all")} <= set(report)
 
 
+def test_embed_grad_phase_tiny():
+    """The embedding lookup's gradient by the kernel (interpreted) against a
+    float32 scatter-add at a table with a partial last tile, and the timing
+    table's keys."""
+    report = chip_smoke.embed_grad(shapes={"toy": (300, 128, 160)}, repeats=1,
+                                   chain=2, interpret=True)
+    assert [c["name"] for c in report["checks"]] == [
+        "embed_grad/toy/value", "embed_grad/toy/d_table"]
+    assert all(c["ok"] for c in report["checks"])
+    assert {f"{what}/toy" for what in (
+        "embed_grad_ms", "sorted_scatter_ms", "cast_then_take_ms",
+        "take_then_cast_ms")} <= set(report)
+
+
 def test_kernels_phase_interpreted():
     # 160 pads to 256: the padding path.  One dtype and one mask here; the
     # chip runs the product.

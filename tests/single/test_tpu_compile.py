@@ -462,6 +462,46 @@ def test_the_sum_by_token_compiles(one_chip, rows, tokens, d, dtype):
     assert not re.search(rf"= \w+\[\d+,{d}\][^\n]* scatter\(", text)
 
 
+# (rows of the table held, d, ids a step): the claimed cell's, the widest
+# rows (and a table of no whole number of sublanes), the largest table.
+EMBEDDINGS = {"phi4flash": (100032, 2560, 16384),
+              "sala": (18362, 4096, 16384), "zaya": (131136, 2048, 16384)}
+
+
+@pytest.mark.parametrize("rows,d,ids", EMBEDDINGS.values(),
+                         ids=EMBEDDINGS.keys())
+def test_the_embedding_s_gradient_is_one_kernel_under_hvd_embed(one_chip,
+                                                                rows, d, ids):
+    """``ops/embedding.py:embed_lookup``'s table gradient as a model makes it
+    (a float32 table, bfloat16 rows, inside ``hvd_embed``), compiled for the
+    chip: one ``embed_grad_sum_rows`` and no ``scatter`` into an array of the
+    table's width (the schedule's ``jnp.repeat`` is one of a few int32).  The
+    kernel's ``op_name`` lies under ``hvd_embed`` and ``benchmark/
+    scope_ledger.py`` counts it there: that is why its name does not begin
+    with ``hvd_``, which would make it a layer of its own that no metric
+    reads (``phi_embed_ms`` and ``sala_embed_ms`` select ``^hvd_embed$``)."""
+    from benchmark.scope_ledger import layer_of_scope
+    from horovod_tpu.ops.embedding import KERNEL_NAME, embed_lookup
+
+    def d_table(table, tokens, g):
+        def loss(table):
+            with jax.named_scope("hvd_embed"):
+                x = embed_lookup(table, tokens, jnp.bfloat16, interpret=False)
+            return jnp.sum(x.astype(jnp.float32) * g)
+        return jax.grad(loss)(table)
+
+    text = _compiled_text(d_table, *_shapes_on(one_chip, (
+        jax.ShapeDtypeStruct((rows, d), jnp.float32),
+        jax.ShapeDtypeStruct((1, ids), jnp.int32),
+        jax.ShapeDtypeStruct((1, ids, d), jnp.float32))))
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]+)"', text)
+    assert len(kernels) == 1 and f"/{KERNEL_NAME}/" in kernels[0], kernels
+    assert "transpose(" in kernels[0]
+    assert layer_of_scope(kernels[0]) == "hvd_embed"
+    assert not re.search(rf"= \w+\[\d+,{d}\][^\n]* scatter\(", text)
+
+
 @pytest.mark.parametrize("cell", EXPERT_LAYERS)
 def test_the_expert_layer_sums_its_rows_back_by_one_kernel_a_pass(
         one_chip, expert_layer_as_on_a_tpu, cell):

@@ -6,6 +6,7 @@ nesting, never a time."""
 
 import ast
 import glob
+import inspect
 import os
 import re
 
@@ -20,6 +21,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 from benchmark import common, run, trace_reduce  # noqa: E402
 from benchmark import traffic as traffic_gen  # noqa: E402
 from benchmark.families import gpt  # noqa: E402
+from horovod_tpu.ops import embedding, grouped_matmul  # noqa: E402
 
 
 def _lowered_gpt_step(hvd, **tx_options) -> str:
@@ -559,8 +561,17 @@ def test_every_name_the_program_writes_into_a_trace_starts_with_hvd():
         for line, name in _names_written(tree):
             seen += 1
             if name is None or not re.match(r"^hvd_[a-z0-9_]+$", name):
-                bad.append(f"{os.path.relpath(path, REPO)}:{line}: {name!r}")
-    assert not bad, bad
+                bad.append((os.path.relpath(path, REPO), line, name))
+    # One kernel is named by its caller: ``_sum_rows`` is ``hvd_moe_sum_rows``
+    # for the expert layer and ``embed_grad_sum_rows`` for the embedding's
+    # gradient, which is plain on purpose: a kernel named ``hvd_*`` is a layer
+    # of its own (``benchmark/scope_ledger.py``), and this one belongs to
+    # ``hvd_embed``, whose metrics read it there (test_tpu_compile.py).
+    assert [(path, name) for path, _, name in bad] == [
+        ("horovod_tpu/ops/grouped_matmul.py", None)], bad
+    assert (inspect.signature(grouped_matmul._sum_rows).parameters["name"]
+            .default, embedding.KERNEL_NAME) == ("hvd_moe_sum_rows",
+                                                 "embed_grad_sum_rows")
     # optimizer.py 8, context.py 4, ops/device_plane.py 3, step_watch.py 2
     assert seen >= 14
     # The rule itself, on a module that breaks it three ways.

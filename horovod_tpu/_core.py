@@ -20,10 +20,7 @@ import numpy as np
 
 from .runtime import PROTOCOL_VERSION, CoreBackend, FusedResponse, TensorEntry
 from .utils.env import Config, get_bool
-from .utils.logging import get_logger
 from .wire import DataType, OpType, ReduceOp, wire_dtype
-
-log = get_logger()
 
 _CPP_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "cpp")
 _LIB_PATH = os.path.join(_CPP_DIR, "libhvd_tpu_core.so")
@@ -123,128 +120,57 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.hvd_barrier.restype = c.c_int
     lib.hvd_barrier.argtypes = [c.c_longlong, c.c_int]
     lib.hvd_free.argtypes = [c.c_void_p]
-    lib.hvd_add_process_set.restype = c.c_int
-    lib.hvd_add_process_set.argtypes = [c.POINTER(c.c_int), c.c_int]
-    try:
-        # Old-ABI tolerance: a stale .so predating QoS process-set weights
-        # loses the weighted registration path; add_process_set(weight=...)
-        # then falls back to the unweighted symbol (weight 1.0).
-        lib.hvd_add_process_set2.restype = c.c_int
-        lib.hvd_add_process_set2.argtypes = [
-            c.POINTER(c.c_int), c.c_int, c.c_double]
-    except AttributeError:
-        pass
+    lib.hvd_add_process_set2.restype = c.c_int
+    lib.hvd_add_process_set2.argtypes = [
+        c.POINTER(c.c_int), c.c_int, c.c_double]
     lib.hvd_remove_process_set.restype = c.c_int
     lib.hvd_remove_process_set.argtypes = [c.c_int]
     lib.hvd_process_set_ranks.restype = c.c_int
     lib.hvd_process_set_ranks.argtypes = [c.c_int, c.POINTER(c.c_int), c.c_int]
     lib.hvd_negotiation_stats.argtypes = [
         c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
-    lib.hvd_data_plane_stats.argtypes = [
-        c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
     lib.hvd_data_plane_stats2.argtypes = [
         c.POINTER(c.c_longlong), c.POINTER(c.c_longlong),
         c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
-    try:
-        # Old-ABI tolerance: a stale .so predating the v9 leader tree
-        # loses ctrl_plane_stats() (degrades to zeros), nothing else.
-        lib.hvd_ctrl_plane_stats.argtypes = [
-            c.POINTER(c.c_longlong), c.POINTER(c.c_longlong),
-            c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
-    except AttributeError:
-        pass
+    lib.hvd_ctrl_plane_stats.argtypes = [
+        c.POINTER(c.c_longlong), c.POINTER(c.c_longlong),
+        c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
     lib.hvd_start_timeline.argtypes = [c.c_char_p, c.c_int]
     lib.hvd_stop_timeline.argtypes = []
-    try:
-        # Old-ABI tolerance (same pattern as hvd_data_plane_stats2): a
-        # stale .so that survived a failed rebuild predates the metrics
-        # plane; metrics() then degrades to {} instead of raising.
-        lib.hvd_metrics_dump.restype = c.c_int
-        lib.hvd_metrics_dump.argtypes = [c.c_char_p, c.c_int]
-    except AttributeError:
-        pass
+    lib.hvd_metrics_dump.restype = c.c_int
+    lib.hvd_metrics_dump.argtypes = [c.c_char_p, c.c_int]
     lib.hvd_last_error.restype = c.c_char_p
-    try:
-        # Old-ABI tolerance: a stale .so predating the flight recorder
-        # degrades flight_record() to {} instead of raising.
-        lib.hvd_flight_record.restype = c.c_int
-        lib.hvd_flight_record.argtypes = [c.c_char_p, c.c_int]
-    except AttributeError:
-        pass
-    try:
-        # Old-ABI tolerance: a stale .so predating causal step tracing
-        # degrades step_trace() to {} instead of raising.
-        lib.hvd_step_trace.restype = c.c_int
-        lib.hvd_step_trace.argtypes = [c.c_char_p, c.c_int]
-    except AttributeError:
-        pass
-    try:
-        # Old-ABI tolerance: a stale .so predating the fleet-telemetry
-        # plane degrades fleet_history() to {} instead of raising.
-        lib.hvd_fleet_history.restype = c.c_int
-        lib.hvd_fleet_history.argtypes = [c.c_char_p, c.c_int]
-    except AttributeError:
-        pass
-    try:
-        # Old-ABI tolerance: a stale .so predating the fault-injection
-        # plane simply loses `horovodrun --fault-inject` pre-validation.
-        lib.hvd_fault_spec_check.restype = c.c_char_p
-        lib.hvd_fault_spec_check.argtypes = [c.c_char_p]
-    except AttributeError:
-        pass
-    try:
-        # Old-ABI tolerance: a stale .so predating the device-plane int8
-        # codec loses the native byte counters (data_plane_stats() falls
-        # back to the Python-side counters) and the qdev autotune poll.
-        lib.hvd_device_plane_note.restype = None
-        lib.hvd_device_plane_note.argtypes = [c.c_longlong, c.c_longlong]
-        lib.hvd_device_plane_stats.restype = None
-        lib.hvd_device_plane_stats.argtypes = [
-            c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
-        lib.hvd_autotune_qdev.restype = c.c_int
-        lib.hvd_autotune_qdev.argtypes = []
-    except AttributeError:
-        pass
-    try:
-        # Old-ABI tolerance: a stale .so predating the schedule coordinate
-        # loses only the qdev-schedule autotune poll.
-        lib.hvd_autotune_qsched.restype = c.c_int
-        lib.hvd_autotune_qsched.argtypes = []
-    except AttributeError:
-        pass
-    try:
-        # Old-ABI tolerance: a stale .so predating the data-plane
-        # coordinate loses only the plane autotune poll (and ignores the
-        # trailing data_plane init argument — cdecl, caller-cleaned).
-        lib.hvd_autotune_plane.restype = c.c_int
-        lib.hvd_autotune_plane.argtypes = []
-    except AttributeError:
-        pass
-    try:
-        # Old-ABI tolerance: a stale .so predating the elastic-migration
-        # plane loses the type-14 forensics and the generation gauge; the
-        # migration protocol itself is Python-side and keeps working.
-        lib.hvd_migrate_note.restype = None
-        lib.hvd_migrate_note.argtypes = [c.c_int, c.c_longlong, c.c_int]
-        lib.hvd_elastic_generation_set.restype = None
-        lib.hvd_elastic_generation_set.argtypes = [c.c_longlong]
-    except AttributeError:
-        pass
-    try:
-        # Old-ABI tolerance: a stale .so predating compiled-collective
-        # introspection loses the native gspmd byte counters
-        # (data_plane_stats() falls back to the Python-side inventory
-        # totals), the type-16 forensics and the step-trace plane tag.
-        lib.hvd_gspmd_plane_note.restype = None
-        lib.hvd_gspmd_plane_note.argtypes = [
-            c.c_longlong, c.c_longlong, c.c_longlong]
-        lib.hvd_gspmd_plane_stats.restype = None
-        lib.hvd_gspmd_plane_stats.argtypes = [
-            c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
-        lib.hvd_step_trace_note_plane.restype = None
-        lib.hvd_step_trace_note_plane.argtypes = [c.c_int]
-    except AttributeError:
-        pass
+    lib.hvd_flight_record.restype = c.c_int
+    lib.hvd_flight_record.argtypes = [c.c_char_p, c.c_int]
+    lib.hvd_step_trace.restype = c.c_int
+    lib.hvd_step_trace.argtypes = [c.c_char_p, c.c_int]
+    lib.hvd_fleet_history.restype = c.c_int
+    lib.hvd_fleet_history.argtypes = [c.c_char_p, c.c_int]
+    lib.hvd_fault_spec_check.restype = c.c_char_p
+    lib.hvd_fault_spec_check.argtypes = [c.c_char_p]
+    lib.hvd_device_plane_note.restype = None
+    lib.hvd_device_plane_note.argtypes = [c.c_longlong, c.c_longlong]
+    lib.hvd_device_plane_stats.restype = None
+    lib.hvd_device_plane_stats.argtypes = [
+        c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
+    lib.hvd_autotune_qdev.restype = c.c_int
+    lib.hvd_autotune_qdev.argtypes = []
+    lib.hvd_autotune_qsched.restype = c.c_int
+    lib.hvd_autotune_qsched.argtypes = []
+    lib.hvd_autotune_plane.restype = c.c_int
+    lib.hvd_autotune_plane.argtypes = []
+    lib.hvd_migrate_note.restype = None
+    lib.hvd_migrate_note.argtypes = [c.c_int, c.c_longlong, c.c_int]
+    lib.hvd_elastic_generation_set.restype = None
+    lib.hvd_elastic_generation_set.argtypes = [c.c_longlong]
+    lib.hvd_gspmd_plane_note.restype = None
+    lib.hvd_gspmd_plane_note.argtypes = [
+        c.c_longlong, c.c_longlong, c.c_longlong]
+    lib.hvd_gspmd_plane_stats.restype = None
+    lib.hvd_gspmd_plane_stats.argtypes = [
+        c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
+    lib.hvd_step_trace_note_plane.restype = None
+    lib.hvd_step_trace_note_plane.argtypes = [c.c_int]
 
 
 class NativeCoreError(RuntimeError):
@@ -255,13 +181,9 @@ def check_fault_spec(spec: str) -> str:
     """Validate a HOROVOD_FAULT_INJECT spec against the native parser.
 
     Returns "" when well-formed, else the same actionable message
-    hvd.init() would fail with.  An old .so without the entry point
-    validates nothing (returns "").
+    hvd.init() would fail with.
     """
-    lib = _load_library()
-    if not hasattr(lib, "hvd_fault_spec_check"):
-        return ""
-    msg = lib.hvd_fault_spec_check(spec.encode())
+    msg = _load_library().hvd_fault_spec_check(spec.encode())
     return msg.decode() if msg else ""
 
 
@@ -368,15 +290,14 @@ class NativeCore(CoreBackend):
             raise NativeCoreError(
                 f"native core init failed (rc={rc}, control protocol "
                 f"v{PROTOCOL_VERSION}): {self._last_error()}")
-        if hasattr(self._lib, "hvd_elastic_generation_set"):
-            # Publish the elastic generation the driver assigned us (0 for
-            # non-elastic jobs) as the hvd_elastic_generation gauge.
-            try:
-                gen = int(os.environ.get("HOROVOD_ELASTIC_GENERATION", "0"))
-            except ValueError:
-                gen = 0
-            self._lib.hvd_elastic_generation_set(gen)
-        if qdev >= 0 and hasattr(self._lib, "hvd_device_plane_note"):
+        # Publish the elastic generation the driver assigned us (0 for
+        # non-elastic jobs) as the hvd_elastic_generation gauge.
+        try:
+            gen = int(os.environ.get("HOROVOD_ELASTIC_GENERATION", "0"))
+        except ValueError:
+            gen = 0
+        self._lib.hvd_elastic_generation_set(gen)
+        if qdev >= 0:
             # Mirror quantized-collective byte deltas into the native
             # metrics registry (hvd.metrics() / Prometheus exposure).
             try:
@@ -387,26 +308,22 @@ class NativeCore(CoreBackend):
                 note = self._lib.hvd_device_plane_note
                 _qz.set_native_byte_sink(
                     lambda raw, enc: note(int(raw), int(enc)))
-        if hasattr(self._lib, "hvd_gspmd_plane_note"):
-            # Mirror each gspmd trace's HLO collective inventory into the
-            # native metrics registry (hvd.metrics() / Prometheus / flight
-            # type 16) — once per trace, never per step.
-            try:
-                from .ops import hlo_inspect as _hi
-            except Exception:
-                pass
-            else:
-                gnote = self._lib.hvd_gspmd_plane_note
-                _hi.set_native_sink(
-                    lambda ops, raw, wire: gnote(int(ops), int(raw),
-                                                 int(wire)))
+        # Mirror each gspmd trace's HLO collective inventory into the
+        # native metrics registry (hvd.metrics() / Prometheus / flight
+        # type 16) — once per trace, never per step.
+        try:
+            from .ops import hlo_inspect as _hi
+        except Exception:
+            pass
+        else:
+            gnote = self._lib.hvd_gspmd_plane_note
+            _hi.set_native_sink(
+                lambda ops, raw, wire: gnote(int(ops), int(raw), int(wire)))
 
     def step_trace_note_plane(self, plane: int) -> None:
         """Tag the step-trace ring with the data plane running the steps
-        (-1 unknown, 0 eager, 1 gspmd).  Silently a no-op on a stale .so
-        predating the entry point."""
-        if hasattr(self._lib, "hvd_step_trace_note_plane"):
-            self._lib.hvd_step_trace_note_plane(int(plane))
+        (-1 unknown, 0 eager, 1 gspmd)."""
+        self._lib.hvd_step_trace_note_plane(int(plane))
 
     def shutdown(self) -> None:
         if self._lib.hvd_is_initialized():
@@ -481,11 +398,7 @@ class NativeCore(CoreBackend):
     def add_process_set(self, ranks: Sequence[int],
                         weight: float = 1.0) -> int:
         arr = (ctypes.c_int * len(ranks))(*[int(r) for r in ranks])
-        if weight != 1.0 and hasattr(self._lib, "hvd_add_process_set2"):
-            psid = self._lib.hvd_add_process_set2(arr, len(ranks),
-                                                  float(weight))
-        else:
-            psid = self._lib.hvd_add_process_set(arr, len(ranks))
+        psid = self._lib.hvd_add_process_set2(arr, len(ranks), float(weight))
         if psid < 0:
             raise NativeCoreError("add_process_set failed")
         return psid
@@ -623,11 +536,7 @@ class NativeCore(CoreBackend):
         for this rank.  On the coordinator, ctrl_msgs_recv per cycle is the
         leader-tree (HOROVOD_CONTROL_TREE, protocol v9) acceptance metric:
         flat mode receives one frame per worker per cycle, tree mode one per
-        local child plus one aggregate per remote host.  An old .so without
-        the entry point returns zeros."""
-        if not hasattr(self._lib, "hvd_ctrl_plane_stats"):
-            return {"ctrl_msgs_sent": 0, "ctrl_msgs_recv": 0,
-                    "ctrl_bytes_sent": 0, "ctrl_bytes_recv": 0}
+        local child plus one aggregate per remote host."""
         msgs_sent = ctypes.c_longlong()
         msgs_recv = ctypes.c_longlong()
         bytes_sent = ctypes.c_longlong()
@@ -658,42 +567,22 @@ class NativeCore(CoreBackend):
         self._lib.hvd_data_plane_stats2(
             ctypes.byref(local), ctypes.byref(xhost),
             ctypes.byref(raw_local), ctypes.byref(raw_xhost))
-        dev_raw = dev_enc = 0
-        if hasattr(self._lib, "hvd_device_plane_stats"):
-            a = ctypes.c_longlong()
-            b = ctypes.c_longlong()
-            self._lib.hvd_device_plane_stats(ctypes.byref(a), ctypes.byref(b))
-            dev_raw, dev_enc = a.value, b.value
-        else:
-            # Stale .so: the Python-side counters hold the same totals
-            # (the native registry only ever sees forwarded deltas).
-            try:
-                from .ops import quantize as _qz
-                dev_raw, dev_enc = _qz.device_byte_counters()
-            except Exception:
-                pass
-        gspmd_raw = gspmd_wire = 0
-        if hasattr(self._lib, "hvd_gspmd_plane_stats"):
-            a = ctypes.c_longlong()
-            b = ctypes.c_longlong()
-            self._lib.hvd_gspmd_plane_stats(ctypes.byref(a), ctypes.byref(b))
-            gspmd_raw, gspmd_wire = a.value, b.value
-        else:
-            # Stale .so: the Python-side inventory counters hold the same
-            # totals (the native registry only ever sees forwarded notes).
-            try:
-                from .ops import hlo_inspect as _hi
-                gspmd_raw, gspmd_wire = _hi.gspmd_byte_counters()
-            except Exception:
-                pass
+        dev_raw = ctypes.c_longlong()
+        dev_enc = ctypes.c_longlong()
+        self._lib.hvd_device_plane_stats(ctypes.byref(dev_raw),
+                                         ctypes.byref(dev_enc))
+        gspmd_raw = ctypes.c_longlong()
+        gspmd_wire = ctypes.c_longlong()
+        self._lib.hvd_gspmd_plane_stats(ctypes.byref(gspmd_raw),
+                                        ctypes.byref(gspmd_wire))
         return {"data_sent_local": local.value,
                 "data_sent_xhost": xhost.value,
                 "data_raw_local": raw_local.value,
                 "data_raw_xhost": raw_xhost.value,
-                "device_raw": dev_raw,
-                "device_encoded": dev_enc,
-                "gspmd_raw": gspmd_raw,
-                "gspmd_wire": gspmd_wire}
+                "device_raw": dev_raw.value,
+                "device_encoded": dev_enc.value,
+                "gspmd_raw": gspmd_raw.value,
+                "gspmd_wire": gspmd_wire.value}
 
     def cycle_count(self) -> Optional[int]:
         """Cycles of the native background loop since init, counted whether
@@ -702,66 +591,39 @@ class NativeCore(CoreBackend):
         n = self._lib.hvd_cycle_count()
         return n if n >= 0 else None
 
-    _warned_no_metrics = False
-
-    def metrics(self) -> dict:
-        """Local metrics registry as a dict (counters + power-of-two-bucket
-        histograms); on the coordinator the dump also carries the cluster
-        view and the last straggler report.  An old .so without the entry
-        point degrades to {} with a one-time warning."""
-        if not hasattr(self._lib, "hvd_metrics_dump"):
-            if not NativeCore._warned_no_metrics:
-                NativeCore._warned_no_metrics = True
-                log.warning("native core predates the metrics plane "
-                            "(hvd_metrics_dump missing); metrics() returns {}")
-            return {}
+    @staticmethod
+    def _json_dump(dump) -> dict:
+        """Call a native ``(buf, cap) -> length`` JSON dump, growing the
+        buffer while it answers -2 (too small); {} when it has nothing."""
         cap = 1 << 16
         buf = ctypes.create_string_buffer(cap)
-        n = self._lib.hvd_metrics_dump(buf, cap)
-        while n == -2:  # buffer too small: grow and retry
+        n = dump(buf, cap)
+        while n == -2:
             cap *= 4
             buf = ctypes.create_string_buffer(cap)
-            n = self._lib.hvd_metrics_dump(buf, cap)
+            n = dump(buf, cap)
         if n <= 0:
             return {}
         return json.loads(buf.raw[:n].decode())
 
+    def metrics(self) -> dict:
+        """Local metrics registry as a dict (counters + power-of-two-bucket
+        histograms); on the coordinator the dump also carries the cluster
+        view and the last straggler report."""
+        return self._json_dump(self._lib.hvd_metrics_dump)
+
     def migrate_note(self, phase: int, nbytes: int,
                      source_rank: int = -1) -> None:
         """Record one elastic-migration phase natively: the migrate
-        counters, a type-14 flight event, and a MIGRATE timeline instant.
-        Silently a no-op on a stale .so predating the entry point."""
-        if hasattr(self._lib, "hvd_migrate_note"):
-            self._lib.hvd_migrate_note(int(phase), int(nbytes),
-                                       int(source_rank))
-
-    _warned_no_flight = False
+        counters, a type-14 flight event, and a MIGRATE timeline instant."""
+        self._lib.hvd_migrate_note(int(phase), int(nbytes), int(source_rank))
 
     def flight_record(self) -> dict:
         """Snapshot of this rank's flight-recorder ring (the always-on event
         black box): {"rank", "host", "slots", "dropped", "types", "events"}
         where events are [ts_us, seq, type, tid, a, b] rows, oldest first.
-        {} when the recorder is off (HOROVOD_FLIGHT_RECORDER=off) or the .so
-        predates it."""
-        if not hasattr(self._lib, "hvd_flight_record"):
-            if not NativeCore._warned_no_flight:
-                NativeCore._warned_no_flight = True
-                log.warning("native core predates the flight recorder "
-                            "(hvd_flight_record missing); flight_record() "
-                            "returns {}")
-            return {}
-        cap = 1 << 16
-        buf = ctypes.create_string_buffer(cap)
-        n = self._lib.hvd_flight_record(buf, cap)
-        while n == -2:  # buffer too small: grow and retry
-            cap *= 4
-            buf = ctypes.create_string_buffer(cap)
-            n = self._lib.hvd_flight_record(buf, cap)
-        if n <= 0:
-            return {}
-        return json.loads(buf.raw[:n].decode())
-
-    _warned_no_steptrace = False
+        {} when the recorder is off (HOROVOD_FLIGHT_RECORDER=off)."""
+        return self._json_dump(self._lib.hvd_flight_record)
 
     def step_trace(self) -> dict:
         """Snapshot of this rank's causal step-trace ring: {"schema",
@@ -769,26 +631,8 @@ class NativeCore(CoreBackend):
         [step, start_us, end_us, <5 phase us>] rows and fleet (rank 0
         only) carries per-step cross-rank sums with dominant_phase /
         dominant_rank attribution.  {} when tracing is off
-        (HOROVOD_STEP_TRACE=off) or the .so predates it."""
-        if not hasattr(self._lib, "hvd_step_trace"):
-            if not NativeCore._warned_no_steptrace:
-                NativeCore._warned_no_steptrace = True
-                log.warning("native core predates causal step tracing "
-                            "(hvd_step_trace missing); step_trace() "
-                            "returns {}")
-            return {}
-        cap = 1 << 16
-        buf = ctypes.create_string_buffer(cap)
-        n = self._lib.hvd_step_trace(buf, cap)
-        while n == -2:  # buffer too small: grow and retry
-            cap *= 4
-            buf = ctypes.create_string_buffer(cap)
-            n = self._lib.hvd_step_trace(buf, cap)
-        if n <= 0:
-            return {}
-        return json.loads(buf.raw[:n].decode())
-
-    _warned_no_fleet = False
+        (HOROVOD_STEP_TRACE=off)."""
+        return self._json_dump(self._lib.hvd_step_trace)
 
     def fleet_history(self) -> dict:
         """The coordinator's multi-resolution fleet history + anomaly log
@@ -796,25 +640,9 @@ class NativeCore(CoreBackend):
         where tiers are {"period_s", "samples"} rings of
         [ts_us, step_p99_us, neg_p99_us, goodput_ppm, wire_ratio_ppm,
         steps] rows and anomalies is the sentinel's log, newest last.
-        {} when the plane is off (HOROVOD_FLEET_TELEMETRY=off), on
-        non-coordinator ranks before any tick, or on a .so predating it."""
-        if not hasattr(self._lib, "hvd_fleet_history"):
-            if not NativeCore._warned_no_fleet:
-                NativeCore._warned_no_fleet = True
-                log.warning("native core predates the fleet-telemetry plane "
-                            "(hvd_fleet_history missing); fleet_history() "
-                            "returns {}")
-            return {}
-        cap = 1 << 16
-        buf = ctypes.create_string_buffer(cap)
-        n = self._lib.hvd_fleet_history(buf, cap)
-        while n == -2:  # buffer too small: grow and retry
-            cap *= 4
-            buf = ctypes.create_string_buffer(cap)
-            n = self._lib.hvd_fleet_history(buf, cap)
-        if n <= 0:
-            return {}
-        return json.loads(buf.raw[:n].decode())
+        {} when the plane is off (HOROVOD_FLEET_TELEMETRY=off) or on
+        non-coordinator ranks before any tick."""
+        return self._json_dump(self._lib.hvd_fleet_history)
 
     def start_timeline(self, path: str, mark_cycles: bool) -> None:
         self._lib.hvd_start_timeline(path.encode(), 1 if mark_cycles else 0)

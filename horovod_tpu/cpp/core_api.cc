@@ -845,33 +845,18 @@ int hvd_barrier(long long seq, int psid) {
 
 void hvd_free(void* p) { std::free(p); }
 
-int hvd_add_process_set(const int* ranks, int n) {
+// `weight` orders the coordinator's fused-response schedule (higher weight
+// first; the global set is pinned at 1.0).
+int hvd_add_process_set2(const int* ranks, int n, double weight) {
   if (g == nullptr) return -1;
   std::vector<int> v(ranks, ranks + n);
-  int id = g->controller->process_sets().Add(v);
+  int id = g->controller->process_sets().AddWeighted(v, weight);
   // Dedicated data channel (per-set socket mesh) so this set's collectives
   // can run on their own executor lane, concurrent with other sets'.
   Status s = g->controller->EstablishChannel(id);
   if (!s.ok()) {
     // EstablishChannel can fail after the channel sockets were inserted
     // (the shm handshake runs last): close them too.
-    g->controller->RemoveChannel(id);
-    g->controller->process_sets().Remove(id);
-    SetLastError("process set channel establishment failed: " + s.reason);
-    return -4;
-  }
-  return id;
-}
-
-// QoS variant: `weight` orders the coordinator's fused-response schedule
-// (higher weight first; the global set is pinned at 1.0).  The unweighted
-// export above keeps its ABI for older callers.
-int hvd_add_process_set2(const int* ranks, int n, double weight) {
-  if (g == nullptr) return -1;
-  std::vector<int> v(ranks, ranks + n);
-  int id = g->controller->process_sets().AddWeighted(v, weight);
-  Status s = g->controller->EstablishChannel(id);
-  if (!s.ok()) {
     g->controller->RemoveChannel(id);
     g->controller->process_sets().Remove(id);
     SetLastError("process set channel establishment failed: " + s.reason);
@@ -925,22 +910,10 @@ void hvd_ctrl_plane_stats(long long* msgs_sent, long long* msgs_recv,
 // Data-plane byte accounting split by locality (host plane only): bytes
 // sent to ranks sharing this rank's host key vs. bytes crossing hosts.
 // Lets tests assert the hierarchical composition actually shrinks
-// cross-host traffic instead of trusting the topology log.
-void hvd_data_plane_stats(long long* local, long long* xhost) {
-  *local = *xhost = 0;
-  if (g == nullptr) return;
-  auto* sc = dynamic_cast<SocketController*>(g->controller.get());
-  if (sc == nullptr) return;
-  int64_t l = 0, x = 0, rl = 0, rx = 0;
-  sc->DataPlaneStats(&l, &x, &rl, &rx);
-  *local = l;
-  *xhost = x;
-}
-
-// Extended form: `raw_*` are the fp32-equivalent payload bytes of the
-// same sends (wire == raw unless a compressed ring encoded them), so
-// raw/wire is the measured compression ratio.  The 2-arg export above
-// keeps its ABI for older callers.
+// cross-host traffic instead of trusting the topology log.  `raw_*` are
+// the fp32-equivalent payload bytes of the same sends (wire == raw unless
+// a compressed ring encoded them), so raw/wire is the measured compression
+// ratio.
 void hvd_data_plane_stats2(long long* local, long long* xhost,
                            long long* raw_local, long long* raw_xhost) {
   *local = *xhost = *raw_local = *raw_xhost = 0;

@@ -125,10 +125,8 @@ constexpr size_t kPolicyMaxLine = 65536;
 
 // Broadcasts at least this large take the pipelined chain instead of the
 // binomial tree.  A protocol constant: the algorithm choice must agree on
-// every rank, so only nbytes/m and the pipelining-enabled switch may gate
-// it — per-rank CHUNK SIZES may differ (the chain is a raw byte stream),
-// but HOROVOD_RING_CHUNK_BYTES=0 (pipelining off) selects different wire
-// protocols and must be uniform across ranks, as documented in socketio.h.
+// every rank, so only nbytes and m may gate it — per-rank CHUNK SIZES may
+// differ (the chain is a raw byte stream).
 constexpr int64_t kBroadcastChainBytes = 1 << 20;
 
 // Wall-clock seconds (system_clock): the abort-propagation latency spans
@@ -145,14 +143,19 @@ thread_local int64_t SocketController::current_seq_ = -1;
 
 SocketController::SocketController(const CoreConfig& cfg)
     : Controller(cfg), cache_(cfg.cache_capacity) {
-  // HOROVOD_RING_CHUNK_BYTES (0 disables pipelining; clamped to 1 GiB —
-  // the u32 chunk-frame length prefix cannot carry more).  Default lives
-  // on the member initializer in socket_controller.h.
+  // HOROVOD_RING_CHUNK_BYTES (clamped to 1 GiB — the u32 chunk-frame
+  // length prefix cannot carry more).  Default lives on the member
+  // initializer in socket_controller.h.
   if (const char* env = ::getenv("HOROVOD_RING_CHUNK_BYTES")) {
     char* end = nullptr;
     long long v = std::strtoll(env, &end, 10);
-    if (end && *end == '\0' && v >= 0) {
+    const bool parsed = end && *end == '\0';
+    if (parsed && v >= 1) {
       ring_chunk_bytes_ = std::min<long long>(v, 1LL << 30);
+    } else if (parsed && v == 0) {
+      HVD_LOG(WARNING) << "HOROVOD_RING_CHUNK_BYTES=0: the whole-segment "
+                       << "wire format is gone; using the default chunk of "
+                       << ring_chunk_bytes_ << " bytes";
     }
   }
   // HOROVOD_WIRE_COMPRESSION_MIN_BYTES: payload floor below which the
@@ -3141,8 +3144,7 @@ Status SocketController::ChunkedStep(
     } else if (fa == FaultAction::kTruncate) {
       // Frame a full first chunk but deliver only half its payload, then
       // cut: the peer dies mid-chunk, not at a frame boundary.
-      const int64_t cb = chunk_bytes > 0 ? chunk_bytes : (1 << 19);
-      const int64_t chunk = std::min<int64_t>(send_len, cb);
+      const int64_t chunk = std::min<int64_t>(send_len, chunk_bytes);
       uint32_t flen = static_cast<uint32_t>(hdr + chunk);
       socks[send_to].SendAll(&flen, 4);
       socks[send_to].SendAll(w.data().data(), w.data().size());
@@ -3251,75 +3253,28 @@ Status SocketController::RingAllreduce(std::vector<Socket>& socks, void* buf,
   const int next = members[(idx + 1) % m];
   const int prev = members[(idx - 1 + m) % m];
 
-  if (ring_chunk_bytes_ > 0) {
-    // Pipelined (Gloo segmented-ring) path: each hop streams the segment
-    // in element-aligned chunks straight from/into the user buffer —
-    // no full-segment copies — and reduces each received chunk while the
-    // kernel keeps moving later chunks, so compute overlaps the wire.
-    const int64_t chunkb =
-        std::max<int64_t>(item, ring_chunk_bytes_ / item * item);
-    // Phase 1: ring reduce-scatter with in-flight reduction.
-    std::vector<int64_t> offs(m + 1, 0);
-    for (int c = 0; c < m; ++c) offs[c + 1] = start(c + 1);
-    Status st = PipelinedReducePhase(socks, members, idx, idx, base, offs,
-                                     dtype, op, kTagReduceScatter, chunkb);
-    if (!st.ok()) return st;
-    // Phase 2: ring allgather, received straight into place (zero-copy in
-    // both directions).
-    for (int s = 0; s < m - 1; ++s) {
-      const int send_c = ((idx + 1 - s) % m + m) % m;
-      const int recv_c = ((idx - s) % m + m) % m;
-      Status st = ChunkedStep(socks, next, base + start(send_c) * item,
-                              len(send_c) * item, prev, len(recv_c) * item,
-                              base + start(recv_c) * item,
-                              kTagAllgatherPhase + s, chunkb, nullptr);
-      if (!st.ok()) return st;
-    }
-    return Status::OK();
-  }
-
-  // Legacy whole-segment path (HOROVOD_RING_CHUNK_BYTES=0).
-  // Phase 1: ring reduce-scatter.  After m-1 steps this rank holds the
-  // fully reduced chunk (idx+1)%m.
-  for (int s = 0; s < m - 1; ++s) {
-    const int send_c = ((idx - s) % m + m) % m;
-    const int recv_c = ((idx - s - 1) % m + m) % m;
-    Writer w;
-    PutFrameHeader(&w, current_seq_, kTagReduceScatter + s);
-    w.PutRaw(base + start(send_c) * item, len(send_c) * item);
-    std::string in;
-    Status st = ExchangeStep(socks, next, w.data(), prev, &in);
-    if (!st.ok()) return st;
-    Reader rd(in);
-    st = CheckFrameHeader(&rd, kTagReduceScatter + s, "ring reduce-scatter");
-    if (!st.ok()) return st;
-    if (static_cast<int64_t>(rd.remaining()) != len(recv_c) * item) {
-      aborted_ = true;
-      return Status::Error(StatusCode::ABORTED,
-                           "ring reduce-scatter chunk size mismatch");
-    }
-    ReduceInto(base + start(recv_c) * item, rd.cursor(), len(recv_c), dtype,
-               op);
-  }
-  // Phase 2: ring allgather of the reduced chunks.
+  // Pipelined (Gloo segmented-ring) path: each hop streams the segment
+  // in element-aligned chunks straight from/into the user buffer —
+  // no full-segment copies — and reduces each received chunk while the
+  // kernel keeps moving later chunks, so compute overlaps the wire.
+  const int64_t chunkb =
+      std::max<int64_t>(item, ring_chunk_bytes_ / item * item);
+  // Phase 1: ring reduce-scatter with in-flight reduction.
+  std::vector<int64_t> offs(m + 1, 0);
+  for (int c = 0; c < m; ++c) offs[c + 1] = start(c + 1);
+  Status st = PipelinedReducePhase(socks, members, idx, idx, base, offs,
+                                   dtype, op, kTagReduceScatter, chunkb);
+  if (!st.ok()) return st;
+  // Phase 2: ring allgather, received straight into place (zero-copy in
+  // both directions).
   for (int s = 0; s < m - 1; ++s) {
     const int send_c = ((idx + 1 - s) % m + m) % m;
     const int recv_c = ((idx - s) % m + m) % m;
-    Writer w;
-    PutFrameHeader(&w, current_seq_, kTagAllgatherPhase + s);
-    w.PutRaw(base + start(send_c) * item, len(send_c) * item);
-    std::string in;
-    Status st = ExchangeStep(socks, next, w.data(), prev, &in);
+    st = ChunkedStep(socks, next, base + start(send_c) * item,
+                     len(send_c) * item, prev, len(recv_c) * item,
+                     base + start(recv_c) * item, kTagAllgatherPhase + s,
+                     chunkb, nullptr);
     if (!st.ok()) return st;
-    Reader rd(in);
-    st = CheckFrameHeader(&rd, kTagAllgatherPhase + s, "ring allgather");
-    if (!st.ok()) return st;
-    if (static_cast<int64_t>(rd.remaining()) != len(recv_c) * item) {
-      aborted_ = true;
-      return Status::Error(StatusCode::ABORTED,
-                           "ring allgather chunk size mismatch");
-    }
-    std::memcpy(base + start(recv_c) * item, rd.cursor(), len(recv_c) * item);
   }
   return Status::OK();
 }
@@ -3362,11 +3317,8 @@ Status SocketController::CompressedRingAllreduce(
   auto len = [&](int c) { return start(c + 1) - start(c); };
   const int next = members[(idx + 1) % m];
   const int prev = members[(idx - 1 + m) % m];
-  // The compressed ring is always chunk-pipelined (the legacy
-  // whole-segment path predates it and stays raw); chunk boundaries are
-  // byte-level, the decode carry below handles partial int8 blocks.
-  const int64_t chunkb =
-      ring_chunk_bytes_ > 0 ? ring_chunk_bytes_ : (1 << 19);
+  // Chunk boundaries are byte-level; the decode carry below handles
+  // partial int8 blocks.
   const int64_t maxseg = chunk + (rem > 0 ? 1 : 0);
   std::vector<char> enc_send(
       static_cast<size_t>(WireEncodedBytes(codec, maxseg)));
@@ -3400,8 +3352,8 @@ Status SocketController::CompressedRingAllreduce(
     Status st = ChunkedStep(socks, next, enc_send.data(),
                             WireEncodedBytes(codec, selems), prev,
                             WireEncodedBytes(codec, relems), enc_recv.data(),
-                            kTagCompReduceScatter + s, chunkb, consume,
-                            /*raw_len=*/4 * selems);
+                            kTagCompReduceScatter + s, ring_chunk_bytes_,
+                            consume, /*raw_len=*/4 * selems);
     if (!st.ok()) return st;
   }
 
@@ -3432,8 +3384,8 @@ Status SocketController::CompressedRingAllreduce(
     Status st = ChunkedStep(socks, next, enc_send.data(),
                             WireEncodedBytes(codec, len(send_c)), prev,
                             WireEncodedBytes(codec, relems), enc_recv.data(),
-                            kTagCompAllgather + s, chunkb, consume,
-                            /*raw_len=*/4 * len(send_c));
+                            kTagCompAllgather + s, ring_chunk_bytes_,
+                            consume, /*raw_len=*/4 * len(send_c));
     if (!st.ok()) return st;
     // What we just received is exactly what we forward next hop
     // (send_c at step s+1 == recv_c at step s): swap, don't re-encode.
@@ -3514,17 +3466,14 @@ Status SocketController::ReduceScatterBuffer(
   // (m-1)/m of the buffer instead of the allreduce's 2(m-1)/m.  The
   // schedule runs in a shifted index space (vidx = idx-1) so this rank
   // finishes owning ITS slice (the standard ring leaves rank j with
-  // chunk j+1).  This op always uses the chunked wire format — it has no
-  // legacy framing, so per-rank HOROVOD_RING_CHUNK_BYTES (even 0) stays
-  // interoperable.
+  // chunk j+1).
   char* base = static_cast<char*>(buf);
   const int item = ItemSize(dtype);
   std::vector<int64_t> offs(m + 1, 0);
   for (int c = 0; c < m; ++c) offs[c + 1] = offs[c] + slice_counts[c];
   const int vidx = (idx - 1 + m) % m;
-  const int64_t want = ring_chunk_bytes_ > 0 ? ring_chunk_bytes_
-                                             : (int64_t{1} << 19);
-  const int64_t chunkb = std::max<int64_t>(item, want / item * item);
+  const int64_t chunkb =
+      std::max<int64_t>(item, ring_chunk_bytes_ / item * item);
   return PipelinedReducePhase(SocksFor(psid), members, idx, vidx, base,
                               offs, dtype, op, kTagReduceScatterOp, chunkb);
 }
@@ -3551,80 +3500,46 @@ Status SocketController::AllgatherBuffer(const void* in, int64_t nbytes,
   const int next = members[(idx + 1) % m];
   const int prev = members[(idx - 1 + m) % m];
 
-  if (ring_chunk_bytes_ > 0) {
-    // Pipelined path: a cheap size ring first (8-byte frames on the same
-    // schedule), then m-1 chunk-pipelined hops whose payloads stream
-    // straight between the output concatenation's block slots — zero
-    // block copies, reduce-free cousin of the pipelined ring allreduce.
-    //
-    // Tradeoff: the up-front size ring adds m-1 tiny serialized steps vs
-    // the legacy in-band path.  The ragged zero-copy layout needs every
-    // size before the output can be allocated, a payload-size switch
-    // would desync (nbytes legally differs per rank), and for small
-    // allgathers the negotiation round trip dominates those 8-byte hops
-    // anyway; large ones win back block-sized copies per hop.
-    std::vector<int64_t> sizes(m, 0);
-    sizes[idx] = nbytes;
-    for (int s2 = 0; s2 < m - 1; ++s2) {
-      const int send_b = ((idx - s2) % m + m) % m;
-      const int recv_b = ((idx - s2 - 1) % m + m) % m;
-      Writer w;
-      PutFrameHeader(&w, current_seq_, kTagAllgatherSize + s2);
-      w.PutI64(sizes[send_b]);
-      std::string in_frame;
-      st = ExchangeStep(socks, next, w.data(), prev, &in_frame);
-      if (!st.ok()) return st;
-      Reader rd(in_frame);
-      st = CheckFrameHeader(&rd, kTagAllgatherSize + s2, "allgather sizes");
-      if (!st.ok()) return st;
-      sizes[recv_b] = rd.GetI64();
-      if (!rd.ok() || sizes[recv_b] < 0) {
-        aborted_ = true;
-        return Status::Error(StatusCode::ABORTED,
-                             "allgather size ring desync");
-      }
-    }
-    std::vector<int64_t> offs(m + 1, 0);
-    for (int b = 0; b < m; ++b) offs[b + 1] = offs[b] + sizes[b];
-    out->resize(static_cast<size_t>(offs[m]));
-    char* base = out->empty() ? nullptr : &(*out)[0];
-    if (nbytes > 0) std::memcpy(base + offs[idx], in, nbytes);
-    for (int s2 = 0; s2 < m - 1; ++s2) {
-      const int send_b = ((idx - s2) % m + m) % m;
-      const int recv_b = ((idx - s2 - 1) % m + m) % m;
-      st = ChunkedStep(socks, next, base + offs[send_b], sizes[send_b],
-                       prev, sizes[recv_b], base + offs[recv_b],
-                       kTagAllgather + s2, ring_chunk_bytes_, nullptr);
-      if (!st.ok()) return st;
-    }
-    per_rank->assign(sizes.begin(), sizes.end());
-    return Status::OK();
-  }
-
-  // Legacy whole-block path (HOROVOD_RING_CHUNK_BYTES=0): per-rank sizes
-  // carried in-band; step s passes block (idx - s) along the ring.
-  std::vector<std::string> blocks(m);
-  blocks[idx].assign(static_cast<const char*>(in), nbytes);
-  for (int s = 0; s < m - 1; ++s) {
-    const int send_b = ((idx - s) % m + m) % m;
-    const int recv_b = ((idx - s - 1) % m + m) % m;
+  // A cheap size ring first (8-byte frames on the same schedule), then
+  // m-1 chunk-pipelined hops whose payloads stream straight between the
+  // output concatenation's block slots — zero block copies, reduce-free
+  // cousin of the pipelined ring allreduce.  The ragged zero-copy layout
+  // needs every size before the output can be allocated, and a
+  // payload-size switch would desync (nbytes legally differs per rank).
+  std::vector<int64_t> sizes(m, 0);
+  sizes[idx] = nbytes;
+  for (int s2 = 0; s2 < m - 1; ++s2) {
+    const int send_b = ((idx - s2) % m + m) % m;
+    const int recv_b = ((idx - s2 - 1) % m + m) % m;
     Writer w;
-    PutFrameHeader(&w, current_seq_, kTagAllgather + s);
-    w.PutRaw(blocks[send_b].data(), blocks[send_b].size());
-    std::string frame;
-    st = ExchangeStep(socks, next, w.data(), prev, &frame);
+    PutFrameHeader(&w, current_seq_, kTagAllgatherSize + s2);
+    w.PutI64(sizes[send_b]);
+    std::string in_frame;
+    st = ExchangeStep(socks, next, w.data(), prev, &in_frame);
     if (!st.ok()) return st;
-    Reader rd(frame);
-    st = CheckFrameHeader(&rd, kTagAllgather + s, "allgather");
+    Reader rd(in_frame);
+    st = CheckFrameHeader(&rd, kTagAllgatherSize + s2, "allgather sizes");
     if (!st.ok()) return st;
-    blocks[recv_b].assign(rd.cursor(), rd.remaining());
+    sizes[recv_b] = rd.GetI64();
+    if (!rd.ok() || sizes[recv_b] < 0) {
+      aborted_ = true;
+      return Status::Error(StatusCode::ABORTED, "allgather size ring desync");
+    }
   }
-  out->clear();
-  per_rank->clear();
-  for (int b = 0; b < m; ++b) {
-    per_rank->push_back(static_cast<int64_t>(blocks[b].size()));
-    out->append(blocks[b]);
+  std::vector<int64_t> offs(m + 1, 0);
+  for (int b = 0; b < m; ++b) offs[b + 1] = offs[b] + sizes[b];
+  out->resize(static_cast<size_t>(offs[m]));
+  char* base = out->empty() ? nullptr : &(*out)[0];
+  if (nbytes > 0) std::memcpy(base + offs[idx], in, nbytes);
+  for (int s2 = 0; s2 < m - 1; ++s2) {
+    const int send_b = ((idx - s2) % m + m) % m;
+    const int recv_b = ((idx - s2 - 1) % m + m) % m;
+    st = ChunkedStep(socks, next, base + offs[send_b], sizes[send_b], prev,
+                     sizes[recv_b], base + offs[recv_b], kTagAllgather + s2,
+                     ring_chunk_bytes_, nullptr);
+    if (!st.ok()) return st;
   }
+  per_rank->assign(sizes.begin(), sizes.end());
   return Status::OK();
 }
 
@@ -3657,7 +3572,7 @@ Status SocketController::BroadcastBuffer(void* buf, int64_t nbytes,
   // and serializes tree levels per whole buffer.  Payloads this large
   // are the broadcast_parameters case this path exists for; small
   // payloads keep the tree's fewer hop latencies.
-  if (ring_chunk_bytes_ > 0 && m > 2 && nbytes >= kBroadcastChainBytes) {
+  if (m > 2 && nbytes >= kBroadcastChainBytes) {
     char* base = static_cast<char*>(buf);
     const int src =
         vrank > 0 ? members[(root_idx + vrank - 1) % m] : -1;
@@ -3803,90 +3718,53 @@ Status SocketController::AlltoallBuffer(const void* in,
   std::vector<int64_t> offs(m + 1, 0);
   for (int j = 0; j < m; ++j) offs[j + 1] = offs[j] + splits[j];
 
-  if (ring_chunk_bytes_ > 0) {
-    // Pipelined path (same shape as the pipelined allgather): a pairwise
-    // row-count exchange first — the ragged output layout needs every
-    // count before it can be allocated — then chunk-pipelined pairwise
-    // hops that stream each peer's rows straight into the output
-    // concatenation's slot, with zero block copies.
-    std::vector<int64_t> rows_from(m, 0);
-    rows_from[idx] = splits[idx];
-    for (int d = 1; d < m; ++d) {
-      const int to_i = (idx + d) % m;
-      const int from_i = (idx - d + m) % m;
-      Writer w;
-      PutFrameHeader(&w, current_seq_, kTagAlltoallSize + d);
-      w.PutI64(splits[to_i]);
-      std::string frame;
-      st = ExchangeStep(socks, members[to_i], w.data(), members[from_i],
-                        &frame);
-      if (!st.ok()) return st;
-      Reader rd(frame);
-      st = CheckFrameHeader(&rd, kTagAlltoallSize + d, "alltoall sizes");
-      if (!st.ok()) return st;
-      rows_from[from_i] = rd.GetI64();
-      if (!rd.ok() || rows_from[from_i] < 0) {
-        aborted_ = true;
-        return Status::Error(StatusCode::ABORTED,
-                             "alltoall size exchange desync");
-      }
-    }
-    std::vector<int64_t> roffs(m + 1, 0);
-    for (int j = 0; j < m; ++j) roffs[j + 1] = roffs[j] + rows_from[j];
-    out->resize(static_cast<size_t>(roffs[m] * row_bytes));
-    char* obase = out->empty() ? nullptr : &(*out)[0];
-    if (splits[idx] > 0) {
-      std::memcpy(obase + roffs[idx] * row_bytes,
-                  base + offs[idx] * row_bytes, splits[idx] * row_bytes);
-    }
-    for (int d = 1; d < m; ++d) {
-      const int to_i = (idx + d) % m;
-      const int from_i = (idx - d + m) % m;
-      st = ChunkedStep(socks, members[to_i], base + offs[to_i] * row_bytes,
-                       splits[to_i] * row_bytes, members[from_i],
-                       rows_from[from_i] * row_bytes,
-                       obase + roffs[from_i] * row_bytes, kTagAlltoall + d,
-                       ring_chunk_bytes_, nullptr);
-      if (!st.ok()) return st;
-    }
-    recv_splits->assign(rows_from.begin(), rows_from.end());
-    return Status::OK();
-  }
-
-  // Legacy whole-block path (HOROVOD_RING_CHUNK_BYTES=0).
-  std::vector<std::string> recv_bufs(m);
-  std::vector<int64_t> rows_from(m, 0);
-  recv_bufs[idx].assign(base + offs[idx] * row_bytes,
-                        splits[idx] * row_bytes);
-  rows_from[idx] = splits[idx];
-  // Pairwise exchange: round d trades with the member d positions away in
+  // Same shape as the allgather: a pairwise row-count exchange first —
+  // the ragged output layout needs every count before it can be
+  // allocated — then chunk-pipelined pairwise hops that stream each
+  // peer's rows straight into the output concatenation's slot, with zero
+  // block copies.  Round d trades with the member d positions away in
   // each direction; the duplex step keeps the cycle deadlock-free.
+  std::vector<int64_t> rows_from(m, 0);
+  rows_from[idx] = splits[idx];
   for (int d = 1; d < m; ++d) {
     const int to_i = (idx + d) % m;
     const int from_i = (idx - d + m) % m;
     Writer w;
-    PutFrameHeader(&w, current_seq_, kTagAlltoall + d);
+    PutFrameHeader(&w, current_seq_, kTagAlltoallSize + d);
     w.PutI64(splits[to_i]);
-    w.PutRaw(base + offs[to_i] * row_bytes, splits[to_i] * row_bytes);
     std::string frame;
     st = ExchangeStep(socks, members[to_i], w.data(), members[from_i],
                       &frame);
     if (!st.ok()) return st;
     Reader rd(frame);
-    st = CheckFrameHeader(&rd, kTagAlltoall + d, "alltoall");
+    st = CheckFrameHeader(&rd, kTagAlltoallSize + d, "alltoall sizes");
     if (!st.ok()) return st;
-    int64_t rows = rd.GetI64();
-    if (static_cast<int64_t>(rd.remaining()) != rows * row_bytes) {
+    rows_from[from_i] = rd.GetI64();
+    if (!rd.ok() || rows_from[from_i] < 0) {
       aborted_ = true;
       return Status::Error(StatusCode::ABORTED,
-                           "alltoall payload size mismatch");
+                           "alltoall size exchange desync");
     }
-    recv_bufs[from_i].assign(rd.cursor(), rd.remaining());
-    rows_from[from_i] = rows;
   }
-  out->clear();
+  std::vector<int64_t> roffs(m + 1, 0);
+  for (int j = 0; j < m; ++j) roffs[j + 1] = roffs[j] + rows_from[j];
+  out->resize(static_cast<size_t>(roffs[m] * row_bytes));
+  char* obase = out->empty() ? nullptr : &(*out)[0];
+  if (splits[idx] > 0) {
+    std::memcpy(obase + roffs[idx] * row_bytes, base + offs[idx] * row_bytes,
+                splits[idx] * row_bytes);
+  }
+  for (int d = 1; d < m; ++d) {
+    const int to_i = (idx + d) % m;
+    const int from_i = (idx - d + m) % m;
+    st = ChunkedStep(socks, members[to_i], base + offs[to_i] * row_bytes,
+                     splits[to_i] * row_bytes, members[from_i],
+                     rows_from[from_i] * row_bytes,
+                     obase + roffs[from_i] * row_bytes, kTagAlltoall + d,
+                     ring_chunk_bytes_, nullptr);
+    if (!st.ok()) return st;
+  }
   recv_splits->assign(rows_from.begin(), rows_from.end());
-  for (int j = 0; j < m; ++j) out->append(recv_bufs[j]);
   return Status::OK();
 }
 

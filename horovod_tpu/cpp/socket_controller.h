@@ -627,8 +627,8 @@ class SocketController : public Controller {
   // -- wiring ---------------------------------------------------------------
   bool is_coordinator() const { return cfg_.rank == 0; }
 
-  // HOROVOD_RING_CHUNK_BYTES: ring-hop pipelining granularity (0 = legacy
-  // whole-segment frames).  512 KiB measured best on the loopback sweep
+  // HOROVOD_RING_CHUNK_BYTES: ring-hop pipelining granularity, >= 1.
+  // 512 KiB measured best on the loopback sweep
   // (128k/256k/512k x socket-buffer sizes); the ctor only overrides this
   // from the env.
   int64_t ring_chunk_bytes_ = 1 << 19;

@@ -162,9 +162,7 @@ bool DuplexExchange(Socket& send_sock, const std::string& out,
 //   offset (zero-copy).  Otherwise chunks land in an internal scratch and
 //   `on_chunk(offset, data, len)` is invoked as each completes, in order.
 // - The peer's chunk size is discovered per-frame, so the two ends may use
-//   different NONZERO HOROVOD_RING_CHUNK_BYTES settings.  0 (the legacy
-//   whole-segment protocol) is a different wire format and must be uniform
-//   across ranks.
+//   different HOROVOD_RING_CHUNK_BYTES settings.
 // - The two sockets may be the same object (2-member ring).
 struct ChunkExchangeError {
   enum Kind { kNone, kTransport, kHeaderMismatch, kBadLength };

@@ -6,6 +6,7 @@ real cluster (SURVEY.md §4): multi-device via
 on localhost (tests/parallel).
 """
 
+import json
 import os
 import sys
 
@@ -25,53 +26,22 @@ import pytest  # noqa: E402
 # Helper modules that hold assertions of tests split over several files.
 pytest.register_assert_rewrite("_jit_helpers")
 
-# The files one pytest-xdist worker needs minutes for, longest first.
+# Seconds each test file took in one whole run of the gate; tools/
+# gate_seconds.py rewrites the record from that run's junit file.
 # ``--dist loadfile`` hands whole files out in collection order, and the
-# alphabet puts most of these last: started when nothing is left to run beside
-# them, they were the gate's tail.  Started first, the short files fill in
-# behind them.  Every file over a minute, longest first (seconds a file:
-# PERF.md, "PR 50", and "PR 51" for its three; tests/single/
-# test_docs_name_files.py holds every name to a tracked file), but for the longest, tests/single/test_native_selftests.py:
-# its sanitizer builds, started beside five workers that are all compiling,
-# once lost ``make selftest`` to its limit (PERF.md, "PR 31").
-LONG_FILES = (
-    "tests/single/test_ops_jit_schedule_parity.py",
-    "tests/single/test_flash_gqa_block_diffusion.py",
-    "tests/benchmark/test_jamba_cell.py",
-    "tests/single/test_ring_attention.py",
-    "tests/benchmark/test_sdar_cell.py",
-    "tests/single/test_flash_attention_grads.py",
-    "tests/single/test_zaya.py",
-    "tests/benchmark/test_zaya_cell.py",
-    "tests/single/test_jamba.py",
-    "tests/single/test_tpu_compile.py",
-    "tests/benchmark/test_sala_cell.py",
-    "tests/benchmark/test_phi4flash_cell.py",
-    "tests/benchmark/test_joyai_cell.py",
-    "tests/benchmark/test_laguna_cell.py",
-    "tests/single/test_flash_attention.py",
-    "tests/parallel/test_shm_plane_perf.py",
-    "tests/single/test_laguna.py",
-    "tests/single/test_sala.py",
-    "tests/single/test_phi4flash.py",
-    "tests/single/test_joyai.py",
-    "tests/single/test_flash_window.py",
-    "tests/single/test_bert_reference.py",
-    "tests/single/test_selective_scan.py",
-    "tests/single/test_ops_jit_quantized_allreduce_bits.py",
-    "tests/single/test_routed_experts.py",
-    "tests/single/test_qk_norm_rope.py",
-    "tests/single/test_flash_select.py",
-    "tests/single/test_flash_mla.py",
-    "tests/single/test_lightning_attention.py",
-    "tests/single/test_flash_diff.py",
-    "tests/benchmark/test_benchmark.py",
-    "tests/single/test_chip_smoke.py",
-    "tests/single/test_trace_names.py",
-    "tests/parallel/test_multiprocess.py",
-    "tests/integration/test_matrix.py",
-    "tests/parallel/test_grouped_atomic.py",
-)
+# alphabet puts most of the long ones last: started when nothing is left to
+# run beside them, they were the gate's tail.  So files start longest first,
+# and a file the record does not name first of all: a new family's files start
+# at once and nobody edits a list (tests/single/test_docs_name_files.py holds
+# every name of the record to a tracked file).
+with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "file_seconds.json")) as _f:
+    FILE_SECONDS = json.load(_f)
+
+
+def start_order(nodeid):
+    """Sort key of a test: its file's place in the order files start in."""
+    return -FILE_SECONDS.get(nodeid.split("::")[0], float("inf"))
 
 
 # One assertion of an accepted benchmark test that an appended metric breaks:
@@ -89,9 +59,7 @@ APPENDED_AFTER = (
 
 
 def pytest_collection_modifyitems(items):
-    first = {path: i for i, path in enumerate(LONG_FILES)}
-    items.sort(key=lambda item: first.get(item.nodeid.split("::")[0],
-                                          len(first)))
+    items.sort(key=lambda item: start_order(item.nodeid))
     for item in items:
         if item.nodeid.split("[")[0] in APPENDED_AFTER:
             item.add_marker(pytest.mark.xfail(

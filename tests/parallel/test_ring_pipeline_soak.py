@@ -1,13 +1,12 @@
 """Randomized differential soak for the chunk-pipelined TCP ring.
 
-The pipelined ring (ChunkedDuplexExchange; VERDICT r3 #5) is a new wire
-format on the hot data-plane path.  This soak drives it through the FULL
+The pipelined ring (ChunkedDuplexExchange; VERDICT r3 #5) is the wire
+format of the hot data-plane path.  This soak drives it through the FULL
 public eager API with randomized shapes (including odd element counts that
 exercise remainder segments and sub-chunk tails), dtypes, ops, and a
-process-set subset, and checks every result against a numpy ground truth
-AND against the legacy whole-segment protocol (HOROVOD_RING_CHUNK_BYTES=0)
-computing the same schedule.  A tiny chunk size forces many chunks per
-segment; shm is disabled so everything rides TCP.
+process-set subset, and checks every result against a numpy ground truth.
+A tiny chunk size forces many chunks per segment; shm is disabled so
+everything rides TCP.
 """
 
 import numpy as np
@@ -157,15 +156,6 @@ def test_pipelined_ring_soak_matches_ground_truth():
     # per ring hop.
     res = _totals({"HOROVOD_RING_CHUNK_BYTES": "4096"})
     assert res == [20, 19, 20]
-
-
-def test_pipelined_and_legacy_rings_agree():
-    # Same seeded schedule through both wire formats; every assertion
-    # inside the worker is against closed-form numpy, so agreement means
-    # both protocols are exactly correct, not merely consistent.
-    piped = _totals({})                                # default 512 KiB
-    legacy = _totals({"HOROVOD_RING_CHUNK_BYTES": "0"})
-    assert piped == legacy == [20, 19, 20]
 
 
 def test_mixed_chunk_sizes_interoperate():

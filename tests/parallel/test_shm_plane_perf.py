@@ -1,117 +1,43 @@
-"""Host data plane perf smoke: shm vs TCP ring, pipelined vs legacy ring
-(VERDICT r2 #7: close the host-plane gap to wire speed on one host;
-VERDICT r3 #5: chunk-pipeline the cross-host TCP ring).
+"""Host data plane: which path a collective takes, counted, and one timing.
 
-Measured on the single-core sandbox (round 4, 4 MiB/rank np=4 allreduce,
-plane-to-plane): legacy whole-segment TCP ring 22-25 ms -> chunk-pipelined
-ring (HOROVOD_RING_CHUNK_BYTES=512 KiB default) 14-17 ms (~1.5-1.8x) ->
-shm 10.5 ms.  On loopback every byte is a CPU copy, so the pipelined
-ring's zero-copy send/recv + in-flight reduce is memory-bandwidth-bound
-there; on a real cross-host wire the same overlap hides the reduce+copy
-behind the transfer.  Assertions compare against the LEGACY ring with
-generous margins so single-core scheduler noise cannot flake them.
+On one host the shared-memory plane carries a collective; with
+``HOROVOD_SHM_DISABLE=1`` (every cross-host job) the chunk-pipelined TCP ring,
+the pipelined chain (broadcasts of 1 MiB and more among three or more
+members) or the binomial tree does.  The cases below read the path from what
+``HOROVOD_METRICS=1`` counts, ``ring_hop_us`` (one entry a chunk-exchange
+hop), ``shm_fence_us`` (one a shm barrier) and the data plane's bytes sent
+through sockets, and hold the results to closed forms.  No case of the
+tier-1 gate times anything: beside five busy pytest-xdist workers a ratio of
+two wall times inverts on a sound tree (ROADMAP.md, D2).  The one timing case
+is marked ``slow``.
 """
 
-import numpy as np
 import pytest
 
 from horovod_tpu.runner import run
 
+_TCP = {"HOROVOD_SHM_DISABLE": "1", "HOROVOD_METRICS": "1"}
 
-def _plane_worker():
-    import os
-    import time
 
-    import numpy as np
+def _counted(fn):
+    """Run ``fn`` and return (its value, ring hops, shm fences, data-plane
+    bytes this rank sent through sockets) counted across the call."""
     import horovod_tpu as hvd
     from horovod_tpu.context import HorovodContext
-    from horovod_tpu.wire import ReduceOp
 
-    hvd.init(build_mesh=False)
-    r = hvd.rank()
-    ctx = HorovodContext.instance()
-    x = np.full((4 << 20) // 4, float(r + 1), np.float32)  # 4 MiB
-    hvd.barrier()
-    for _ in range(2):
-        ctx.core.allreduce_buffer(x.copy(), 0, ReduceOp.SUM)
-    t0 = time.perf_counter()
-    iters = 8
-    for _ in range(iters):
-        out = ctx.core.allreduce_buffer(x.copy(), 0, ReduceOp.SUM)
-    dt = (time.perf_counter() - t0) / iters
-    np.testing.assert_allclose(out[:8], float(sum(range(1, hvd.size() + 1))))
-    hvd.barrier()
-    hvd.shutdown()
-    return {"rank": r, "ms": dt * 1e3,
-            "shm_disabled": os.environ.get("HOROVOD_SHM_DISABLE") == "1"}
+    core = HorovodContext.instance().core
+
+    def read():
+        h = hvd.metrics()["histograms"]
+        return (h["ring_hop_us"]["count"], h["shm_fence_us"]["count"],
+                core.data_plane_stats()["data_sent_local"])
+
+    before = read()
+    out = fn()
+    return (out,) + tuple(b - a for a, b in zip(before, read()))
 
 
-def _best_of(n, env=None, worker=None):
-    # Min-of-n worst-rank times: the single shared core makes any one run
-    # noisy; the minimum is the honest capability number.  Every run also
-    # re-checks whether HOROVOD_SHM_DISABLE actually reached the workers
-    # (inferred from the env itself, so shm-on sides pass env=None).
-    expect_shm_disabled = bool(env) and env.get("HOROVOD_SHM_DISABLE") == "1"
-    best = float("inf")
-    for _ in range(n):
-        res = run(worker or _plane_worker, np=4, env=env)
-        assert res[0]["shm_disabled"] == expect_shm_disabled
-        best = min(best, max(r["ms"] for r in res))
-    return best
-
-
-def _assert_faster(slow_env, fast_env, margin, worker=None, n=2, label="",
-                   attempts=3):
-    # Load-detect retry: a background-load burst on the shared core can
-    # invert any single comparison no matter how generous the margin.  When
-    # a round fails, re-measure from scratch (both sides, so a transient
-    # that slowed the FAST side doesn't survive either) before declaring a
-    # perf regression; only the final round asserts.
-    slow_ms = fast_ms = 0.0
-    for _ in range(attempts):
-        slow_ms = _best_of(n, env=slow_env, worker=worker)
-        fast_ms = _best_of(n, env=fast_env, worker=worker)
-        if slow_ms > margin * fast_ms:
-            return
-    assert slow_ms > margin * fast_ms, (
-        f"{label} not faster after {attempts} rounds: "
-        f"slow={slow_ms:.1f}ms fast={fast_ms:.1f}ms (margin {margin}x)")
-
-
-def test_shm_plane_beats_tcp_ring():
-    # vs the LEGACY whole-segment ring (stable ~2.1-2.4x margin on an idle
-    # box; the pipelined ring narrows this on loopback by design).  The
-    # round-5 verdict caught this flaking one-shot: a background-load burst
-    # measured the ratio at 1.14x against what was effectively a 1.15x
-    # gate, so it now rides the same re-measure-both-sides retry as the
-    # ring/chain comparisons instead of trusting any single round.
-    _assert_faster(
-        slow_env={"HOROVOD_SHM_DISABLE": "1",
-                  "HOROVOD_RING_CHUNK_BYTES": "0"},
-        fast_env=None,  # shm plane on
-        margin=1.6, label="shm plane")
-
-
-# Not in the tier-1 gate since PR 31: beside five busy pytest-xdist workers the
-# pipelined ring's extra threads starve first and the ratio inverts (8.9 ms
-# whole-segment against 15.6 ms pipelined after three rounds, PR 31's first run
-# of the final tree).  It runs wherever ``-m 'not slow'`` is not given; the case
-# below it holds, without a clock, that the pipelined ring is the path the
-# default takes.
-@pytest.mark.slow
-def test_pipelined_ring_beats_whole_segment_ring():
-    # VERDICT r3 #5: the chunk-pipelined ring (default) must beat the
-    # legacy whole-segment ring on the same TCP path.  Measured ~1.5-1.8x;
-    # min-of-3 runs + a 1.10x margin + load-detect retry absorb scheduler
-    # noise (the old min-of-2/1.15x gate still flaked under CI load).
-    _assert_faster(
-        slow_env={"HOROVOD_SHM_DISABLE": "1",
-                  "HOROVOD_RING_CHUNK_BYTES": "0"},
-        fast_env={"HOROVOD_SHM_DISABLE": "1"},
-        margin=1.10, n=3, label="pipelined ring")
-
-
-def _ring_hops_worker():
+def _allreduce_worker():
     import numpy as np
     import horovod_tpu as hvd
     from horovod_tpu.context import HorovodContext
@@ -121,106 +47,144 @@ def _ring_hops_worker():
     ctx = HorovodContext.instance()
     x = np.full((4 << 20) // 4, float(hvd.rank() + 1), np.float32)  # 4 MiB
     hvd.barrier()
-    hops = hvd.metrics()["histograms"]["ring_hop_us"]["count"]
-    out = ctx.core.allreduce_buffer(x.copy(), 0, ReduceOp.SUM)
-    hops = hvd.metrics()["histograms"]["ring_hop_us"]["count"] - hops
+    out, hops, fences, sent = _counted(
+        lambda: ctx.core.allreduce_buffer(x.copy(), 0, ReduceOp.SUM))
     np.testing.assert_array_equal(out, float(sum(range(1, hvd.size() + 1))))
     hvd.barrier()
     hvd.shutdown()
-    return hops
+    return {"hops": hops, "fences": fences, "sent": sent}
 
 
-def test_pipelined_ring_is_the_tcp_path_the_default_takes_np4():
-    # What the timing case above presupposes, counted instead of timed: with
-    # shm off an allreduce crosses the chunk-pipelined ring, 2 (n - 1)
-    # chunk-exchange hops on every rank, and HOROVOD_RING_CHUNK_BYTES=0 takes
-    # the whole-segment ring, which records none; the sums are the same.
-    env = {"HOROVOD_SHM_DISABLE": "1", "HOROVOD_METRICS": "1"}
-    assert run(_ring_hops_worker, np=4, env=env) == [6] * 4
-    assert run(_ring_hops_worker, np=4,
-               env=dict(env, HOROVOD_RING_CHUNK_BYTES="0")) == [0] * 4
+def test_shm_plane_moves_the_bytes_and_the_ring_records_no_hop_np4():
+    # Same host, default settings: the 4 MiB a rank are reduced through the
+    # shared region between barriers, whose frames are all a socket carries.
+    for r in run(_allreduce_worker, np=4, env={"HOROVOD_METRICS": "1"}):
+        assert r["hops"] == 0 and r["fences"] > 0, r
+        assert r["sent"] < 4096, r
+
+
+@pytest.mark.parametrize("chunk_bytes", [None, "0"])
+def test_pipelined_ring_is_the_tcp_path_the_default_takes_np4(chunk_bytes):
+    # With shm off an allreduce crosses the chunk-pipelined ring: 2 (n - 1)
+    # chunk-exchange hops on every rank that carry 2 (n - 1) / n of the
+    # buffer.  HOROVOD_RING_CHUNK_BYTES=0 once chose a whole-segment format;
+    # it is read as the default chunk now.
+    env = dict(_TCP)
+    if chunk_bytes is not None:
+        env["HOROVOD_RING_CHUNK_BYTES"] = chunk_bytes
+    for r in run(_allreduce_worker, np=4, env=env):
+        assert r["hops"] == 6 and r["fences"] == 0, r
+        assert 0 <= r["sent"] - 6 * (1 << 20) < 4096, r
 
 
 def _bcast_worker():
-    import os
-    import time
-
     import numpy as np
     import horovod_tpu as hvd
 
     hvd.init(build_mesh=False)
     r = hvd.rank()
-    # Non-uniform root payload, full-array compare: the timing loop is
-    # also the chain's correctness check at size.
-    n = (32 << 20) // 4  # 32 MiB
-    x = (np.arange(n) % 509 + 7.0 * r).astype(np.float32)
-    expect = (np.arange(n) % 509).astype(np.float32)
-    hvd.barrier()
-    hvd.broadcast(x.copy(), root_rank=0, name="warm")
-    t0 = time.perf_counter()
-    iters = 5
-    for i in range(iters):
-        out = hvd.broadcast(x.copy(), root_rank=0, name=f"b.{i}")
-    dt = (time.perf_counter() - t0) / iters
-    np.testing.assert_array_equal(np.asarray(out), expect)
+    sent = {}
+    for nbytes in (32 << 20, 64 << 10):
+        # Non-uniform root payload, full-array compare.
+        n = nbytes // 4
+        x = (np.arange(n) % 509 + 7.0 * r).astype(np.float32)
+        hvd.barrier()
+        out, hops, _, sent[nbytes] = _counted(
+            lambda: hvd.broadcast(x, root_rank=0, name=f"b.{nbytes}"))
+        assert hops == 0
+        np.testing.assert_array_equal(
+            np.asarray(out), (np.arange(n) % 509).astype(np.float32))
     hvd.barrier()
     hvd.shutdown()
-    return {"rank": r, "ms": dt * 1e3,
-            "shm_disabled": os.environ.get("HOROVOD_SHM_DISABLE") == "1"}
+    return sent
 
 
-def test_chain_broadcast_beats_binomial_tree():
+def test_large_broadcast_takes_the_chain_and_small_the_tree_np4():
     # Large broadcasts (the broadcast_parameters case) take the pipelined
-    # chain: every member sends N once vs the tree root's N*log2(m)
-    # egress.  Measured ~2.0x at 32 MiB np=4; 1.3x margin for noise.
-    _assert_faster(
-        slow_env={"HOROVOD_SHM_DISABLE": "1",
-                  "HOROVOD_RING_CHUNK_BYTES": "0"},
-        fast_env={"HOROVOD_SHM_DISABLE": "1"},
-        margin=1.3, worker=_bcast_worker, label="chain broadcast")
+    # chain: every member but the last forwards the payload once, where the
+    # tree's root sends it log2(m) times.  Small ones keep the tree's fewer
+    # hop latencies: rank 0 sends to ranks 2 and 1, rank 2 to rank 3.
+    big, small = 32 << 20, 64 << 10
+    res = run(_bcast_worker, np=4, env=_TCP)
+    for r, (chain, tree) in enumerate(zip([1, 1, 1, 0], [2, 0, 1, 0])):
+        assert 0 <= res[r][big] - chain * big < 4096, (r, res[r])
+        assert 0 <= res[r][small] - tree * small < 4096, (r, res[r])
 
 
 def _allgather_worker():
-    import os
-    import time
-
     import numpy as np
     import horovod_tpu as hvd
     from horovod_tpu.context import HorovodContext
 
     hvd.init(build_mesh=False)
-    r = hvd.rank()
+    r, size = hvd.rank(), hvd.size()
     ctx = HorovodContext.instance()
     n = (8 << 20) // 4
-    x = np.full(n, float(r), np.float32)  # 8 MiB/rank
+    # 8 MiB a rank, no two ranks' blocks alike at any offset.
+    x = (np.arange(n) % 509 + 1000.0 * r).astype(np.float32)
     hvd.barrier()
-    ctx.core.allgather_buffer(x, 0)
-    t0 = time.perf_counter()
-    iters = 5
-    for _ in range(iters):
-        out, counts = ctx.core.allgather_buffer(x, 0)
-    dt = (time.perf_counter() - t0) / iters
-    assert list(counts) == [n] * hvd.size()  # elements/rank
-    # The timing loop doubles as the at-size correctness check: each
-    # rank's slot must hold that rank's fill value at both block edges.
-    out = np.asarray(out).reshape(hvd.size(), n)
-    for rr in range(hvd.size()):
-        assert out[rr, 0] == float(rr) and out[rr, -1] == float(rr), out
+    (out, counts), hops, _, sent = _counted(
+        lambda: ctx.core.allgather_buffer(x, 0))
+    assert list(counts) == [n] * size  # elements/rank
+    out = np.asarray(out).reshape(size, n)
+    for rr in range(size):
+        np.testing.assert_array_equal(out[rr], x - 1000.0 * (r - rr))
     hvd.barrier()
     hvd.shutdown()
-    return {"rank": r, "ms": dt * 1e3,
-            "shm_disabled": os.environ.get("HOROVOD_SHM_DISABLE") == "1"}
+    return {"hops": hops, "sent": sent}
 
 
-def test_pipelined_allgather_beats_whole_block_ring():
-    # Pipelined allgather (size ring + chunked hops straight into the
-    # output concat) vs legacy whole-block string frames.  Measured
-    # ~1.55-1.75x at 8 MiB/rank np=4; 1.2x margin for noise.
-    _assert_faster(
-        slow_env={"HOROVOD_SHM_DISABLE": "1",
-                  "HOROVOD_RING_CHUNK_BYTES": "0"},
-        fast_env={"HOROVOD_SHM_DISABLE": "1"},
-        margin=1.2, worker=_allgather_worker, label="pipelined allgather")
+def test_allgather_crosses_n_minus_1_chunked_hops_np4():
+    # A size ring of 8-byte frames, then n - 1 chunk-pipelined hops that land
+    # each block straight in its slot of the output.
+    for r in run(_allgather_worker, np=4, env=_TCP):
+        assert r["hops"] == 3, r
+        assert 0 <= r["sent"] - 3 * (8 << 20) < 4096, r
+
+
+def _timed_allreduce_worker():
+    import time
+
+    import numpy as np
+    import horovod_tpu as hvd
+    from horovod_tpu.context import HorovodContext
+    from horovod_tpu.wire import ReduceOp
+
+    hvd.init(build_mesh=False)
+    ctx = HorovodContext.instance()
+    x = np.full((4 << 20) // 4, float(hvd.rank() + 1), np.float32)  # 4 MiB
+    hvd.barrier()
+    for _ in range(2):
+        ctx.core.allreduce_buffer(x.copy(), 0, ReduceOp.SUM)
+    t0 = time.perf_counter()
+    iters = 8
+    for _ in range(iters):
+        ctx.core.allreduce_buffer(x.copy(), 0, ReduceOp.SUM)
+    dt = (time.perf_counter() - t0) / iters
+    hvd.barrier()
+    hvd.shutdown()
+    return dt * 1e3
+
+
+# Outside the tier-1 gate (it runs wherever ``-m 'not slow'`` is not given):
+# on loopback every byte of the ring is a CPU copy, so busy neighbours move
+# the ratio more than the planes do.
+@pytest.mark.slow
+def test_shm_plane_beats_pipelined_tcp_ring():
+    # Min over runs of the slowest rank, both sides measured again in each
+    # round so that a burst of load that slowed one side does not survive.
+    def best_ms(env):
+        return min(max(run(_timed_allreduce_worker, np=4, env=env))
+                   for _ in range(2))
+
+    for _ in range(3):
+        ring_ms = best_ms({"HOROVOD_SHM_DISABLE": "1"})
+        shm_ms = best_ms(None)
+        if ring_ms > 1.1 * shm_ms:
+            return
+    raise AssertionError(
+        f"shm plane not faster after 3 rounds: ring={ring_ms:.1f}ms "
+        f"shm={shm_ms:.1f}ms (margin 1.1x)")
 
 
 def _shm_correctness_worker():

@@ -37,7 +37,7 @@ def _smap(fn, in_specs=P("hvd"), out_specs=P("hvd"), world=N_DEV):
                              out_specs=out_specs, check_vma=False))
 
 
-def _smap_eager(fn):
+def _smap_eager(fn, world=N_DEV):
     """``shard_map`` un-jitted: every primitive is compiled and dispatched by
     itself, about a minute a call at eight ranks.  Only for the cases that
     need it: the one whose subject is the un-jitted call, and int4's exact
@@ -47,5 +47,5 @@ def _smap_eager(fn):
     be 112 reads 112.00003; a primitive compiled alone receives the 7 as a
     run-time operand and divides.  ``max|x| / 127`` has the same rewrite and
     is exact at the parity payload's values (PERF.md section 7)."""
-    return shard_map(fn, mesh=_mesh(), in_specs=P("hvd"),
+    return shard_map(fn, mesh=_mesh(world), in_specs=P("hvd"),
                      out_specs=P("hvd"), check_vma=False)

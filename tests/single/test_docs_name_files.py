@@ -1,7 +1,7 @@
 """The documents send a reader only to files that exist: every
 ``python <path>.py`` command and every back-quoted ``*.py`` path that
 README.md, docs/*.md and the verify skill name is a tracked file, and so is
-every file ``tests/conftest.py`` starts first."""
+every file ``tests/file_seconds.json`` has seconds for."""
 
 import glob
 import os
@@ -50,12 +50,28 @@ def test_documents_name_tracked_files():
     assert not missing, missing
 
 
-def test_files_the_gate_starts_first_exist():
+def _conftest():
     sys.path.insert(0, os.path.join(REPO, "tests"))
     try:
         import conftest
     finally:
         sys.path.pop(0)
+    return conftest
+
+
+def test_files_the_gate_has_seconds_for_exist():
     tracked = _tracked()
-    assert [p for p in conftest.LONG_FILES if p not in tracked] == []
-    assert len(set(conftest.LONG_FILES)) == len(conftest.LONG_FILES)
+    assert [p for p in _conftest().FILE_SECONDS if p not in tracked] == []
+
+
+def test_a_file_without_seconds_starts_first_then_the_longest():
+    conftest = _conftest()
+    longest = max(conftest.FILE_SECONDS, key=conftest.FILE_SECONDS.get)
+    shortest = min(conftest.FILE_SECONDS, key=conftest.FILE_SECONDS.get)
+    new = "tests/single/test_a_new_family.py"
+    assert new not in conftest.FILE_SECONDS
+    cases = [f"{shortest}::test_b", f"{longest}::test_a[x::y]",
+             f"{new}::test_c", f"{shortest}::test_a"]
+    assert sorted(cases, key=conftest.start_order) == [
+        f"{new}::test_c", f"{longest}::test_a[x::y]",
+        f"{shortest}::test_b", f"{shortest}::test_a"]

@@ -1,7 +1,8 @@
 """Quantized allreduce: each codec's three ring schedules against the exact
 fp32 psum, one schedule a case and the psum made once a codec.  int8's three
-run compiled; int4's three are the slow cases of the lattice (``_smap_eager``
-says why)."""
+run compiled at eight ranks; int4's three run un-jitted (``_smap_eager`` says
+why) at four, the smallest world that has all three schedules: a bidi ring
+with two chunks and a 2 x 2 torus."""
 
 import numpy as np
 import pytest
@@ -16,6 +17,7 @@ from _jit_helpers import N_DEV, _smap, _smap_eager
 pytestmark = pytest.mark.usefixtures("hvd_single")
 
 QMAX = {"int8": 127.0, "int4": 7.0}
+WORLD = {"int8": N_DEV, "int4": 4}
 
 
 @pytest.fixture(scope="module", params=["int8", "int4"])
@@ -34,7 +36,7 @@ def payload(request):
     nblk = per // qz.WIRE_BLOCK
     rng = np.random.RandomState(42)
     k = rng.randint(-3, 4, size=nblk)        # per-block exponent, shared
-    sign = rng.choice([-1.0, 1.0], size=(N_DEV, nblk))
+    sign = rng.choice([-1.0, 1.0], size=(WORLD[codec], nblk))
     vals = (sign * qmax * np.exp2(k)[None, :]).astype(np.float32)
     x = jnp.asarray(np.repeat(vals, qz.WIRE_BLOCK, axis=1))
 
@@ -43,7 +45,7 @@ def payload(request):
 
     hvd.init()
     try:
-        expected = np.asarray(_smap(plain)(x))
+        expected = np.asarray(_smap(plain, world=WORLD[codec])(x))
     finally:
         hvd.shutdown()
     return codec, x, expected
@@ -60,7 +62,7 @@ def test_schedule_differential_parity_exact(payload, schedule):
 
     # Compiled, int4's scale is a multiply by a rounded 1/7 and not exact.
     run = _smap_eager if codec == "int4" else _smap
-    out = np.asarray(run(fn)(x))
+    out = np.asarray(run(fn, world=WORLD[codec])(x))
     np.testing.assert_array_equal(
         out, expected,
         err_msg=f"{codec}/{schedule} diverged from exact psum")

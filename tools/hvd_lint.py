@@ -68,21 +68,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # reality is itself a finding).
 # ---------------------------------------------------------------------------
 
-# Symbols whose Python binding deliberately tolerates an old .so that
-# predates them (declared inside try/except, callers hasattr-guard):
-# the checker allows conditional declaration but still verifies types.
-OLD_ABI_TOLERANT = {"hvd_metrics_dump", "hvd_data_plane_stats2",
-                    "hvd_fault_spec_check", "hvd_ctrl_plane_stats",
-                    "hvd_flight_record", "hvd_add_process_set2",
-                    "hvd_device_plane_note", "hvd_device_plane_stats",
-                    "hvd_autotune_qdev", "hvd_autotune_qsched",
-                    "hvd_autotune_plane",
-                    "hvd_migrate_note",
-                    "hvd_elastic_generation_set", "hvd_step_trace",
-                    "hvd_fleet_history",
-                    "hvd_gspmd_plane_note", "hvd_gspmd_plane_stats",
-                    "hvd_step_trace_note_plane"}
-
 # HOROVOD_* variables read directly by C++ getenv (not routed through
 # utils/env.py): plane/topology knobs consumed below the ctypes ABI, where
 # threading them through hvd_init would widen the init signature for no

@@ -11,8 +11,7 @@ combination of:
 - np:      1, 2, 3
 - fusion:  default threshold / disabled (HOROVOD_FUSION_THRESHOLD=0)
 - cache:   default capacity / disabled (HOROVOD_CACHE_CAPACITY=0)
-- plane:   shared-memory / pipelined TCP ring (HOROVOD_SHM_DISABLE=1) /
-           legacy whole-segment TCP ring (+HOROVOD_RING_CHUNK_BYTES=0),
+- plane:   shared-memory / pipelined TCP ring (HOROVOD_SHM_DISABLE=1),
            np>1 only / hierarchical (HOROVOD_HIERARCHICAL_ALLREDUCE=1 over
            two fake hosts via HOROVOD_HIER_FAKE_HOSTS=2), np>=3 only —
            smaller np degenerates to one rank per fake host
@@ -545,7 +544,7 @@ def combos(quick: bool):
     nps = [1, 2, 3]
     fusion = ["on", "off"]
     cache = ["on", "off"]
-    planes = ["shm", "tcp", "tcp0", "hier"]
+    planes = ["shm", "tcp", "hier"]
     wires = ["none", "bf16", "int8"]
     if quick:
         # One covering set instead of the full product (every axis value
@@ -556,7 +555,7 @@ def combos(quick: bool):
         # Same-host links: the coordinator must demote the codec (knob
         # harmless, results exact).
         yield ("jax", "native", 2, "off", "off", "tcp", "bf16", "off")
-        yield ("jax", "native", 3, "on", "off", "tcp0", "none", "off")
+        yield ("jax", "native", 3, "on", "off", "tcp", "none", "off")
         yield ("jax", "native", 3, "on", "on", "hier", "bf16", "off")
         yield ("jax", "native", 3, "on", "off", "hier", "int8", "off")
         # ctrl_tree axis: the one quick on-combo (2 fake hosts via hier)
@@ -730,7 +729,7 @@ def combos(quick: bool):
     # product would double the wall time for little marginal coverage).
     yield ("torch", "native", 2, "on", "on", "shm", "none", "off")
     yield ("torch", "native", 2, "off", "off", "tcp", "none", "off")
-    yield ("torch", "native", 2, "on", "off", "tcp0", "none", "off")
+    yield ("torch", "native", 2, "on", "off", "tcp", "none", "off")
     yield ("torch", "native", 3, "on", "on", "tcp", "none", "off")
     yield ("torch", "native", 3, "off", "on", "shm", "none", "off")
     yield ("torch", "native", 3, "on", "on", "hier", "none", "off")
@@ -876,9 +875,6 @@ def run_combo(core: str, np_: int, fusion: str, cache: str,
               fleet: str, dplane: str, hloinspect: str, script: str,
               timeout: float) -> tuple:
     env = dict(os.environ)
-    # The plane axis must own this knob: an ambient setting would
-    # silently collapse the pipelined-vs-legacy distinction.
-    env.pop("HOROVOD_RING_CHUNK_BYTES", None)
     env.pop("HOROVOD_HIERARCHICAL_ALLREDUCE", None)
     env.pop("HOROVOD_HIER_FAKE_HOSTS", None)
     # Same for the wire axis: ambient codec settings would skew both the
@@ -936,10 +932,8 @@ def run_combo(core: str, np_: int, fusion: str, cache: str,
         env["HOROVOD_FUSION_THRESHOLD"] = "0"
     if cache == "off":
         env["HOROVOD_CACHE_CAPACITY"] = "0"
-    if plane in ("tcp", "tcp0"):
+    if plane == "tcp":
         env["HOROVOD_SHM_DISABLE"] = "1"
-    if plane == "tcp0":
-        env["HOROVOD_RING_CHUNK_BYTES"] = "0"  # legacy whole-segment frames
     if plane == "hier":
         # Two fake hosts carved out of the rank space: block partition, so
         # np=3 gives hosts {0,1} + {2} — the smallest hierarchical topology.

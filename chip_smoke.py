@@ -1030,14 +1030,26 @@ def flash_window(length: int = 16384, heads=(9, 6), head_dim: int = 128,
     are a gigabyte; dk and dv are the heads' sum), then the forward's time
     and the forward and backward's together, best of ``repeats``.  A pass is
     timed as one of ``chain`` in one compiled program, each fed by the one
-    before, as ``qk_norm_rope`` does."""
+    before, as ``qk_norm_rope`` does.  Beside a banded call's times, its
+    schedule from the plan: the forward's grid steps a head and the keys a
+    row is computed against (``schedule/window=...``)."""
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.ops.flash_attention import (dense_attention,
-                                                 flash_attention)
+    from horovod_tpu.ops.flash_attention import (band_of, dense_attention,
+                                                 flash_attention, tile_plan)
 
     checks, report = [], {}
+    for window in windows:
+        if window is not None and window < length:
+            plan = tile_plan(length, head_dim, 2, True, heads=1,
+                             window=window)
+            band = band_of(plan.block_q, plan.step_k, plan.tile_q, window)
+            report[f"schedule/window={window}"] = {
+                "grid_steps_a_head": plan.seq_pad // band.block,
+                "keys_a_row": band.rows_visited, "block": band.block,
+                "step": band.step, "chains": band.chains,
+                "rows_beside": band.n_beside * band.beside}
     for h in heads:
         ks = jax.random.split(jax.random.PRNGKey(h), 4)
         q, g = (jax.random.normal(k, (1, length, h, head_dim), jnp.bfloat16)

@@ -80,19 +80,42 @@ others name their kernels ``hvd_flash_fwd`` / ``hvd_flash_dq`` /
 ``hvd_flash_dkv``.
 
 **A window** (``window=W`` on a causal call: query i sees the W keys ``i - W
-< j <= i``, its own among them) is a band under the diagonal, walked as the
-diagonal is: the forward's and dq's key walk starts at the band's far edge as
-it stops at the diagonal, dkv's query walk stops at the far edge, and a grid
-tile wholly outside the band is predicated off with its index maps clamped to
-the nearest live block, so it is neither computed nor fetched.  A banded
-call's resident tile is about as many rows as the window holds keys
-(:func:`tile_plan`, ``_band_tile``): every row of a tile pays for each step
-that any row of it sees, so under a band of 512 a row of a 256-row tile
-visits 768 keys, of a 512-row tile 896 and of a 1024-row tile 1152, while a
-larger tile gives the scheduler more independent work a step; at a window of
-512, the one window measured, 512 rows read fastest on a v5e (PERF.md, PR
-51).  Its kernels are named ``hvd_flash_swa_fwd`` / ``_dq`` / ``_dkv``; ``window=None``
-or a window of the whole sequence is the causal call, text for text.
+< j <= i``, its own among them) is a band under the diagonal, and a banded
+call has a schedule of its own (:func:`band_of`; ``_band_fwd_kernel``,
+``_band_dq_kernel``, ``_band_dkv_kernel``), which shares the score's
+arithmetic with the kernels above and nothing of their grid or walk.  Its
+grid has no streamed axis: a grid step holds a resident block (the plan's,
+2,048 rows at 16,384 x 128 in bf16) and, as operands of their own, the rows
+of the other operand that the band reaches beside it (the 512 rows before a
+query block under a band of 512; after a key block in dkv), so every grid
+step is live and a sub-tile's whole span is in VMEM at once.  The block is
+cut into sub-tiles of one step's rows (256); a sub-tile takes the diagonal's
+step and the ``(W - 2) // step + 1`` before it (after it, in dkv), W rounded
+up to steps and a step more keys a row whatever tile the row is in (768 at
+512 / 256).  Its statistics and accumulators are values from its first step
+to its last, stored once; the forward starts at the diagonal's step, which
+shows every row a key it sees, so the running maximum is a real score before
+a far-edge step hides a row's whole step, and it needs no scratch, no init
+and no flush.  The steps wholly inside the band take no mask; the diagonal's
+takes ``k <= q`` and a step the far edge crosses ``q - k < W``, both on the
+difference of local positions alone, built once a grid step
+(``_band_masks``).  Two sub-tiles take each step side by side and a block's
+loop trips are written out, so the scheduler has several independent chains
+in one basic block; the trips that reach beside the block are written once
+for the sequence's edge and once for its inside, so none holds a branch
+(``_band_walk``).  A grouped dkv takes the group's query heads along a third
+grid axis and gathers dk, dv in float32 scratch, read and written once a
+sub-tile and head.  **Read on a v5e** (PERF.md, PR 64; forward / forward and
+backward of one call, 9 query heads on 1 key/value head, 16,384 x 128 in
+bf16, window 512, by resident block x sub-tiles side by side): the causal
+square's walk cut by a far edge, which this replaced, 1.386 / 4.787 ms; 512 x
+2: 0.815 / 2.462, 512 x 1: 0.824 / 2.466, 1,024 x 1: 0.919 / 2.481, 1,024 x
+2: 0.793 / 2.329, 1,024 x 4: 0.699 / 2.165, 2,048 x 1: 0.959 / 2.576, 2,048 x
+2: 0.815 / 2.333 (0.795 / 2.274 again), 2,048 x 4: 0.670 / 2.100, and 2,048 x
+2 with the block's trips written out, the form kept: 0.689 / 2.080.  One
+window is measured.  The kernels are named ``hvd_flash_swa_fwd`` / ``_dq`` /
+``_dkv``; ``window=None`` or a window of the whole sequence is the causal
+call, text for text.
 
 **A second score operand** (``q_rope`` [B, S, H, r] with ``k_rope`` [B, S, 1,
 r]: multi-head latent attention, whose scores are ``q . k + q_rope . k_rope``
@@ -159,6 +182,9 @@ LANES = 128          # a vreg's lane count and the MXU's width
 # (see tile_plan).  Read on a v5e at 128 x 1024 x 64 in bf16 (PERF.md, PR 26).
 _MAX_TILE = 1024
 _MAX_STEP = 256
+# The sub-tiles a loop trip of a banded call's kernels takes side by side
+# (see _band_plan).
+_BAND_CHAINS = 2
 # What tile_plan lets the hungriest kernel (dkv) hold in VMEM, and what the
 # pallas_calls ask Mosaic for: the rest is headroom for the compiler's own
 # temporaries (spilled score tiles, relayouts).  A v5e / v6e core has
@@ -274,23 +300,6 @@ def _divisor(n: int, cap: int) -> int:
                 if n % d == 0)
 
 
-def _band_tile(tile: int, step: int, window: int) -> int:
-    """The resident tile of a call under a band of ``window`` keys: the
-    largest part of ``tile`` in whole ``step``s that divides it and holds no
-    more rows than the window holds keys (one step where nothing larger
-    does; the un-banded ``tile`` from a window of that many keys on).  Every
-    row of a tile pays for every step that any row of it sees, tile + window
-    keys less a step, so a tile of the window's size wastes under half of
-    them and a smaller one leaves the scheduler one dependent chain a step.
-    **One window is measured**: on a v5e at 9 query heads on 1 key/value
-    head, 16,384 x 128 in bf16, window 512 (PERF.md, PR 51), forward and
-    backward of one call read 5.18 ms at 256 rows, 4.77 at 512, 5.33 at 1024
-    (8.83 at 128-row tiles in 128-row steps); the rule gives that window its
-    512 rows and is unmeasured at any other."""
-    return next(t for t in range(tile // step * step, 0, -step)
-                if tile % t == 0 and (t <= window or t == step))
-
-
 def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
               block_q: Optional[int] = None,
               block_k: Optional[int] = None, heads: int = 1,
@@ -315,8 +324,7 @@ def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
     tile it walks past, so the causal diagonal crosses a tile in a whole
     number of steps.  ``causal`` does not change the sizes: the kernels'
     walks stop at the diagonal whatever they are.  Under a ``window`` the
-    resident tile holds at most as many rows as the window holds keys (whole
-    steps, one at least: ``_band_tile``).
+    three sizes are the band's own (:func:`_band_plan`).
 
     With a second score operand ``rope`` lanes wide a head (``q_rope`` /
     ``k_rope``) a grid step holds the two heads whose rotary parts fill a
@@ -343,13 +351,76 @@ def tile_plan(seq: int, head_dim: int, itemsize: int, causal: bool,
         block_k = min(block_k if block_k is not None else block_q, seq)
         lcm = math.lcm(block_q, block_k)
         seq_pad = -(-seq // lcm) * lcm
+    if window is not None:
+        return _band_plan(seq_pad, block_q, block_k, window, held, g,
+                          itemsize, lanes)
     tile_q, tile_k = _divisor(block_q, _MAX_TILE), _divisor(block_k, _MAX_TILE)
     step_q = math.gcd(_divisor(block_q, _MAX_STEP), tile_k)
     step_k = math.gcd(_divisor(block_k, _MAX_STEP), tile_q)
-    if window is not None:
-        tile_q = _band_tile(tile_q, step_k, window)
-        tile_k = _band_tile(tile_k, step_q, window)
     vmem = _vmem_estimate(block_q, block_k, max(tile_q, tile_k),
+                          max(step_q, step_k), held, g, itemsize)
+    return TilePlan(seq_pad, block_q, block_k, tile_q, tile_k, step_q,
+                    step_k, vmem, g, lanes)
+
+
+class Band(NamedTuple):
+    """The schedule of one kernel of a banded call (:func:`band_of`): its
+    resident ``block`` is cut into sub-tiles of ``step`` rows, each of which
+    takes ``steps`` steps of ``step`` rows of the other operand, the
+    diagonal's and the ``steps - 1`` on the band's side of it, ``chains``
+    sub-tiles side by side a loop trip.  The rows on the band's side of the
+    resident block reach the kernel as ``n_beside`` blocks of ``beside`` rows
+    each (one, a divisor of the block, where the band reaches no further
+    than a block; else whole blocks)."""
+    block: int
+    step: int
+    steps: int
+    chains: int
+    beside: int
+    n_beside: int
+
+    @property
+    def rows_visited(self) -> int:
+        """Rows of the other operand that a row of the block is computed
+        against: the window rounded up to steps, and a step for the
+        diagonal's."""
+        return self.steps * self.step
+
+
+def band_of(block: int, step: int, tile: int, window: int) -> Band:
+    """The band's schedule for a resident ``block`` walked in ``step``s, a
+    loop trip ``tile`` rows.  A sub-tile of ``step`` rows at row ``r0`` and
+    the step ``j`` steps away from its own meet in pairs ``j * step - step
+    < q - k < j * step + step``: some of them inside a band of ``window``
+    keys up to ``j = (window - 2) // step + 1``."""
+    far = (window - 2) // step + 1 if window > 1 else 0
+    reach = far * step
+    if reach <= block:
+        beside = next((d for d in range(reach, block + 1, step)
+                       if block % d == 0), 0) if reach else 0
+        n_beside = 1 if reach else 0
+    else:
+        beside, n_beside = block, -(-reach // block)
+    return Band(block, step, far + 1, tile // step, beside, n_beside)
+
+
+def _band_plan(seq_pad, block_q, block_k, window, held, g, itemsize,
+               lanes) -> TilePlan:
+    """:func:`tile_plan` under a ``window``: the grid blocks as without one
+    (a banded call's kernels have no streamed grid axis: a grid step holds a
+    resident block and the rows of the other operand that the band reaches
+    beside it, :func:`band_of`).  A step is a sub-tile's rows and a key
+    step's keys alike (at most _MAX_STEP), and ``tile_q`` / ``tile_k`` the
+    rows a loop trip takes side by side, ``_BAND_CHAINS`` sub-tiles where the
+    block holds as many."""
+    def sizes(block):
+        step = _divisor(block, _MAX_STEP)
+        return math.gcd(block // step, _BAND_CHAINS) * step, step
+
+    (tile_q, step_k), (tile_k, step_q) = sizes(block_q), sizes(block_k)
+    beside = max(band_of(block_q, step_k, tile_q, window).beside,
+                 band_of(block_k, step_q, tile_k, window).beside)
+    vmem = _vmem_estimate(block_q + beside, block_k, max(tile_q, tile_k),
                           max(step_q, step_k), held, g, itemsize)
     return TilePlan(seq_pad, block_q, block_k, tile_q, tile_k, step_q,
                     step_k, vmem, g, lanes)
@@ -385,37 +456,22 @@ def _last_live_k(iq, causal: bool, plan: TilePlan, valid_len):
     return last
 
 
-def _first_live_k(iq, plan: TilePlan, window: int):
-    """The first key block a query block needs: under a band, the one that
-    holds the oldest key its first row sees."""
-    return (jnp.maximum(iq * plan.block_q - window + 1, 0) // plan.block_k
-            if window else 0)
-
-
-def _last_live_q(jk, plan: TilePlan, valid_len, window: int):
+def _last_live_q(jk, plan: TilePlan, valid_len):
     """The last query block a key block needs (dkv): the one that holds the
-    end of the real sequence or, under a band, the newest query that sees
-    the block's last key."""
-    last = (valid_len - 1) // plan.block_q
-    if window:
-        last = jnp.minimum(
-            last, ((jk + 1) * plan.block_k + window - 2) // plan.block_q)
-    return last
+    end of the real sequence."""
+    del jk
+    return (valid_len - 1) // plan.block_q
 
 
-def _block_live(iq, jk, causal: bool, plan: TilePlan, valid_len,
-                window: int = 0):
+def _block_live(iq, jk, causal: bool, plan: TilePlan, valid_len):
     """Whether the (q-block iq, k-block jk) grid tile can contribute.  The
-    grid is sequential and cannot be shortened per row, so a dead tile
-    (above the causal diagonal, below a band's far edge, or wholly tail
-    padding) is still a grid step: its body is predicated off and its index
-    maps hold the block of the nearest live step, so it costs neither dots
-    nor copies."""
-    live = jnp.logical_and(jk <= _last_live_k(iq, causal, plan, valid_len),
+    grid of these kernels is the whole square, so a dead tile (above the
+    causal diagonal or wholly tail padding) is still a grid step: its body
+    is predicated off and its index maps hold the block of the nearest live
+    step, so it costs neither dots nor copies.  (A banded call has a grid of
+    its own, every step of it live: :func:`_band_fwd_kernel`.)"""
+    return jnp.logical_and(jk <= _last_live_k(iq, causal, plan, valid_len),
                            iq * plan.block_q < valid_len)
-    if window:
-        live = jnp.logical_and(live, jk >= _first_live_k(iq, plan, window))
-    return live
 
 
 def _steps(valid_len, step: int):
@@ -442,8 +498,7 @@ def _run(body, lo, hi, state):
             jax.lax.fori_loop(lo, hi, body, state))
 
 
-def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit,
-            window: int = 0):
+def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit):
     """fwd / dq: walk the key steps of key block ``jk`` that the query tile
     starting at row ``row0`` can see.  ``visit(off, masked, lo, hi)`` takes
     local steps [lo, hi) (``hi`` None: the one step ``lo``) with the tile's
@@ -453,26 +508,14 @@ def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit,
     part of a tile above the diagonal is never computed.  Without a causal
     mask the run ends with the real keys, and a step that holds the end of
     them is masked (a length read in the kernel may end inside a step or
-    not: the one masked step is then live only if it does).  Under a band
-    of ``window`` keys the walk starts at the step that holds the oldest key
-    the tile's first row sees; the steps from there to the first one that
-    the tile's last row sees whole are cut by the band's far edge and
-    masked, the rest as without a band."""
+    not: the one masked step is then live only if it does)."""
     step = plan.step_k
     n = plan.block_k // step
     first, last = jk * n, _steps(valid_len, step)
     if causal:
         on_diag = row0 // step
         until = jnp.minimum(on_diag, last)
-        lo = 0
-        if window:
-            live = jnp.maximum(row0 - window + 1, 0) // step
-            whole = jnp.clip(jnp.maximum(
-                row0 + plan.tile_q - window + step - 1, 0) // step,
-                live, until)
-            lo = jnp.clip(whole - first, 0, n)
-            visit(0, True, jnp.clip(live - first, 0, n), lo)
-        visit(0, False, lo, jnp.clip(until - first, 0, n))
+        visit(0, False, 0, jnp.clip(until - first, 0, n))
         _diag_steps(on_diag, plan.tile_q // step, first, n, last,
                     lambda d, j: visit(d * step, True, j, None))
     else:
@@ -483,16 +526,14 @@ def _k_walk(row0, jk, causal: bool, plan: TilePlan, valid_len, visit,
                     lambda d, j: visit(0, True, j, None))
 
 
-def _q_walk(col0, iq, last, causal: bool, plan: TilePlan, valid_len, visit,
-            window: int = 0):
+def _q_walk(col0, iq, last, causal: bool, plan: TilePlan, valid_len, visit):
     """dkv: walk the query steps of query block ``iq`` that see the key tile
     starting at column ``col0``, of the ``last`` steps with a real row.
     ``visit(size, masked, lo, hi)`` takes local steps [lo, hi) (``hi`` None:
     the one step ``lo``) with the tile's first ``size`` keys.  The steps
     after the diagonal see the tile whole, in one run up to the last real
-    row (the rows past it carry a zero dO) or, under a band, up to its far
-    edge, which cuts the last steps of the walk as the diagonal cuts the
-    first; of the static ``tile_k // step_q`` steps the diagonal crosses,
+    row (the rows past it carry a zero dO); of the static ``tile_k //
+    step_q`` steps the diagonal crosses,
     the d-th sees the tile's first ``(d + 1) * step_q`` keys only.  Without
     a causal mask every step sees the whole tile, and padded keys are masked
     in each."""
@@ -504,19 +545,7 @@ def _q_walk(col0, iq, last, causal: bool, plan: TilePlan, valid_len, visit,
         on_diag, count = col0 // step, tile // step
         _diag_steps(on_diag, count, first, n, last,
                     lambda d, j: visit((d + 1) * step, True, j, None))
-        lo = jnp.clip(on_diag + count - first, 0, hi)
-        if window:
-            # The query steps that see the tile whole end where a step's
-            # last row no longer sees the tile's first key; those from
-            # there to the last row that sees the tile's last key are cut
-            # by the band's far edge.
-            whole = jnp.clip(jnp.maximum(col0 + window - step, 0)
-                             // step + 1 - first, lo, hi)
-            visit(tile, False, lo, whole)
-            visit(tile, True, whole, jnp.clip(
-                (col0 + tile + window - 2) // step + 1 - first, whole, hi))
-        else:
-            visit(tile, False, lo, hi)
+        visit(tile, False, jnp.clip(on_diag + count - first, 0, hi), hi)
     elif isinstance(valid_len, int):
         visit(tile, valid_len < plan.seq_pad, 0, hi)
     else:
@@ -530,7 +559,7 @@ def _q_walk(col0, iq, last, causal: bool, plan: TilePlan, valid_len, visit,
 
 def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
             transposed: bool = False, bd: int = 0, strict=0,
-            own: bool = False, window: int = 0, rope=None):
+            own: bool = False, rope=None):
     """The float32 score tile q @ k^T * sm_scale ([Tq, Tk]; or its
     transpose k @ q^T), masked where the diagonal or the tail padding
     crosses it.  The dot takes its operands as they arrive.  ``rope``: the
@@ -559,13 +588,7 @@ def _scores(q, k, row0, col0, masked: bool, *, sm_scale, causal, valid_len,
             # Padding lives at the tail, so kpos > any real qpos: the
             # causal mask already excludes padded keys.
             qpos = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
-            seen = qpos >= kpos
-            if window:
-                # A row that no key of its first (far-edge) step is seen by
-                # takes the mask value for its maximum there; the next step
-                # holds a seen key and scales what that made by exp(-1e30).
-                seen = jnp.logical_and(seen, qpos - kpos < window)
-            s = jnp.where(seen, s, NEG_INF)
+            s = jnp.where(qpos >= kpos, s, NEG_INF)
         else:
             s = jnp.where(kpos < valid_len, s, NEG_INF)
     return s
@@ -662,10 +685,9 @@ def _mha_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, causal: bool,
     tile, step = plan.tile_q, plan.step_k
     heads = _head_lanes(plan)
     bd, strict = variant.bd, _strict(variant, variant.q_rows)
-    window = variant.window
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
                               valid_len=valid_len, transposed=True, bd=bd,
-                              strict=strict, window=window)
+                              strict=strict)
 
     @pl.when(jk == 0)
     def _init():
@@ -699,7 +721,7 @@ def _mha_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, causal: bool,
 
             _own_squares(0, plan.block_q // tile, tile, side, square)
 
-    @pl.when(_block_live(iq, jk, causal, plan, valid_len, window))
+    @pl.when(_block_live(iq, jk, causal, plan, valid_len))
     def _compute():
         def q_tile(c, _):
             start = pl.multiple_of(c * tile, tile)
@@ -743,7 +765,7 @@ def _mha_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, causal: bool,
                     l_ref[g:g + 1, rows] = l
                     acc_ref[h, rows] = acc
 
-            _k_walk(row0, jk, causal, plan, valid_len, visit, window)
+            _k_walk(row0, jk, causal, plan, valid_len, visit)
 
         jax.lax.fori_loop(0, plan.block_q // tile, q_tile, None)
 
@@ -833,14 +855,13 @@ def _by_i(r, i, j, lens):
     return i
 
 
-def _streamed_k(causal: bool, plan: TilePlan, valid_len, window: int = 0):
+def _streamed_k(causal: bool, plan: TilePlan, valid_len):
     """Key/value blocks streaming past query block ``i`` (fwd, dq): a dead
     grid tile holds the block of the nearest live one, so it costs no
     copy."""
     def block(r, i, j, lens):
-        j = jnp.minimum(j, _last_live_k(i, causal, plan,
-                                        _len_at(r, lens, valid_len)))
-        return jnp.maximum(j, _first_live_k(i, plan, window)) if window else j
+        return jnp.minimum(j, _last_live_k(i, causal, plan,
+                                           _len_at(r, lens, valid_len)))
 
     return block
 
@@ -848,13 +869,13 @@ def _streamed_k(causal: bool, plan: TilePlan, valid_len, window: int = 0):
 def _named(variant: _Variant, kernel: str) -> dict:
     """The kernel arguments a grouped or block-diffusion call adds: the
     variant, and a name by which a trace tells its three kernels apart
-    (``hvd_flash_fwd`` / ``_dq`` / ``_dkv``; ``hvd_flash_swa_fwd`` / ``_dq``
-    / ``_dkv`` under a band, ``hvd_flash_mla_fwd`` / ``_dq`` / ``_dkv`` with
-    a second score operand).  A plain call adds neither, so its kernels
-    compile to what they were."""
-    band = "swa_" if variant.window else "mla_" if variant.rope else ""
+    (``hvd_flash_fwd`` / ``_dq`` / ``_dkv``; ``hvd_flash_mla_fwd`` / ``_dq``
+    / ``_dkv`` with a second score operand; a banded call names its own:
+    ``_band_call``).  A plain call adds neither, so its kernels compile to
+    what they were."""
+    pair = "mla_" if variant.rope else ""
     return {} if variant == _PLAIN else {
-        "variant": variant, "name": f"hvd_flash_{band}{kernel}"}
+        "variant": variant, "name": f"hvd_flash_{pair}{kernel}"}
 
 
 def _kernel_call(kernel, lens, *, grid, in_specs, out_specs, out_shape,
@@ -910,13 +931,14 @@ def _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret, valid_len,
     padded; see :func:`_operand_spec`): out likewise + the rows' lse
     [BH / G, G, S].  ``rope``: ``(q_rope, k_rope)`` in the kernels' layout
     (:func:`_rope_specs`) where ``variant.rope``."""
+    if variant.window:
+        return _band_fwd(qb, kb, vb, sm_scale, plan, interpret, variant)
     n, s, width = qb.shape
     n_col, g = width // plan.lanes, plan.heads_per_block
     bq, bk = plan.block_q, plan.block_k
     q_spec = _operand_spec(bq, plan, n_col, _by_i)
     kv_spec = _operand_spec(bk, plan, kb.shape[2] // plan.lanes,
-                            _streamed_k(causal, plan, valid_len,
-                                        variant.window),
+                            _streamed_k(causal, plan, valid_len),
                             _kv_row_of(variant))
     # What the variant's mask or second operand adds to q, k and v.
     more_specs, more = (
@@ -941,25 +963,29 @@ def _flash_fwd(qb, kb, vb, sm_scale, causal, plan, interpret, valid_len,
     )(qb, kb, vb, *more)
 
 
-def _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan: TilePlan):
+def _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan: TilePlan,
+                rows: Optional[int] = None, at: int = 0):
     """delta_i = rowsum(dO_i * O_i) - dlse_i of each head of a query block,
     the standard backward residual, into ``delta_ref`` [G, block_q] as
     lane-dense rows like lse.  (An lse cotangent folds in here: both enter
     as ``ds = p * (dp - delta)``.)  It is made where it is used: a head's
     ``head_dim`` lanes of the model's layout are no dimension XLA could
-    reduce over without turning the whole product round first."""
+    reduce over without turning the whole product round first.  ``rows``:
+    how many the blocks hold (a query block's), and ``at``: where in
+    ``delta_ref`` theirs begin (a banded dkv's blocks, own and beside)."""
     step = plan.step_q
 
     def chunk(c, _):
         rows = pl.ds(pl.multiple_of(c * step, step), step)
+        into = pl.ds(pl.multiple_of(at + c * step, step), step) if at else rows
         prod = jnp.transpose(do_ref[rows, :].astype(jnp.float32)
                              * o_ref[rows, :].astype(jnp.float32))
         for g, h in enumerate(_head_lanes(plan)):           # [lanes, Tq]
-            delta_ref[g:g + 1, rows] = (
+            delta_ref[g:g + 1, into] = (
                 jnp.sum(prod[h], axis=0, keepdims=True)
                 - dlse_ref[g:g + 1, rows])
 
-    jax.lax.fori_loop(0, plan.block_q // step, chunk, None)
+    jax.lax.fori_loop(0, (rows or plan.block_q) // step, chunk, None)
 
 
 def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
@@ -990,10 +1016,8 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     tile, step = plan.tile_q, plan.step_k
     heads = _head_lanes(plan)
     bd, strict = variant.bd, _strict(variant, variant.q_rows)
-    window = variant.window
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
-                              valid_len=valid_len, bd=bd, strict=strict,
-                              window=window)
+                              valid_len=valid_len, bd=bd, strict=strict)
 
     def resident(rows):
         """Per head: a tile's q, dO with the other heads' lanes zeroed;
@@ -1037,7 +1061,7 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
 
             _own_squares(0, plan.block_q // tile, tile, side, square)
 
-    @pl.when(_block_live(iq, jk, causal, plan, valid_len, window))
+    @pl.when(_block_live(iq, jk, causal, plan, valid_len))
     def _compute():
         def q_tile(c, _):
             start = pl.multiple_of(c * tile, tile)
@@ -1055,7 +1079,7 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
 
                 acc_ref[rows, :] = _run(body, lo, hi, acc_ref[rows, :])
 
-            _k_walk(row0, jk, causal, plan, valid_len, visit, window)
+            _k_walk(row0, jk, causal, plan, valid_len, visit)
 
         jax.lax.fori_loop(0, plan.block_q // tile, q_tile, None)
 
@@ -1104,10 +1128,10 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     tile, step = plan.tile_k, plan.step_q
     last = _steps(valid_len, step)
     heads = _head_lanes(plan)
-    strict, window = _strict(variant, variant.kv_rows), variant.window
+    strict = _strict(variant, variant.kv_rows)
     score = functools.partial(_scores, sm_scale=sm_scale, causal=causal,
                               valid_len=valid_len, transposed=True, bd=bd,
-                              strict=strict, window=window)
+                              strict=strict)
 
     def gather(state, kv, rows, row0, col0, masked, **mask):
         """``(dk, dv)`` [Tk, lanes] + what the queries ``rows`` of the
@@ -1144,7 +1168,7 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
             dk_own_acc[:] = jnp.zeros_like(dk_own_acc)
             dv_own_acc[:] = jnp.zeros_like(dv_own_acc)
 
-    @pl.when(_block_live(iq, jk, causal, plan, valid_len, window))
+    @pl.when(_block_live(iq, jk, causal, plan, valid_len))
     def _compute():
         _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan)
 
@@ -1194,7 +1218,7 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
                 dk_acc[cols, :], dv_acc[cols, :] = _run(
                     body, lo, hi, (dk_acc[cols, :], dv_acc[cols, :]))
 
-            _q_walk(col0, iq, last, causal, plan, valid_len, visit, window)
+            _q_walk(col0, iq, last, causal, plan, valid_len, visit)
 
         jax.lax.fori_loop(0, plan.block_k // tile, k_tile, None)
 
@@ -1211,6 +1235,9 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
                    inline=True)
 def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
                interpret, valid_len, lens=None, variant=_PLAIN):
+    if variant.window:
+        return _band_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, plan,
+                         interpret, variant)
     n, s, width = qb.shape
     n_col, g = width // plan.lanes, plan.heads_per_block
     kv_col, group = kb.shape[2] // plan.lanes, variant.group
@@ -1231,8 +1258,7 @@ def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
     # dq: q-block fixed per outer step, k/v stream on the inner grid dim.
     q_by_i = _operand_spec(bq, plan, n_col, _by_i)
     kv_by_j = _operand_spec(bk, plan, kv_col,
-                            _streamed_k(causal, plan, valid_len,
-                                        variant.window),
+                            _streamed_k(causal, plan, valid_len),
                             _kv_row_of(variant))
     row_by_i = _row_stat_spec(plan, _by_i)
     own_specs, own_kv = _own_kv(variant, plan, kb, vb)
@@ -1256,8 +1282,7 @@ def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
     def streamed_q(r, i, j, lens):
         first = (i * bk) // bq if causal else 0
         return jnp.minimum(jnp.maximum(j if group == 1 else j % n_q, first),
-                           _last_live_q(i, plan, _len_at(r, lens, valid_len),
-                                        variant.window))
+                           _last_live_q(i, plan, _len_at(r, lens, valid_len)))
 
     q_row_of = None if group == 1 else (lambda r, j: r * group + j // n_q)
     k_row_of = (None if not variant.bd else
@@ -1282,6 +1307,404 @@ def _flash_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, causal, plan,
         + [accumulator, accumulator] * more,
     )(qb, kb, vb, dob, ob, lse, dlse, *own_kv)
     return dq, dk, dv, d_own
+
+
+# ---- A banded call's kernels: the band's own grid, blocks and walk --------
+
+_BAND_PARAMS = {
+    rank: pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary")[:rank],
+        vmem_limit_bytes=_VMEM_LIMIT) for rank in (2, 3)}
+
+
+def _band_masks(band: Band, window: int, k_axis: int):
+    """The mask of each of a sub-tile's ``band.steps`` steps, None where the
+    step lies wholly inside the band: booleans [step, step] with the keys
+    along ``k_axis``, true where the query sees the key.  With ``d`` a key's
+    local position less a query's, the pair of step ``j`` is ``q - k = j *
+    step - d`` apart: the diagonal's step (``j`` 0) is cut by ``d <= 0``, a
+    step that the far edge crosses by ``q - k < window`` (both, under a
+    window narrower than a step).  They depend on local positions alone, so
+    they are the same for every sub-tile and built once a grid step."""
+    shape = (band.step, band.step)
+    d = (jax.lax.broadcasted_iota(jnp.int32, shape, k_axis)
+         - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - k_axis))
+    masks = []
+    for j in range(band.steps):
+        seen = d <= 0 if j == 0 else None
+        if (j + 1) * band.step > window:
+            far = d > j * band.step - window
+            seen = far if seen is None else jnp.logical_and(seen, far)
+        masks.append(seen)
+    return masks
+
+
+def _band_rows(band: Band, t: int, before: bool):
+    """Where the step that starts ``t`` rows from the resident block's first
+    row lies: ``(which, rows)``, ``which`` None for the block of the
+    resident block's own positions, else the index of the block beside it
+    (``before`` it, the farthest first; or after it, the nearest first)."""
+    if 0 <= t < band.block:
+        return None, pl.ds(t, band.step)
+    at = t + band.n_beside * band.beside if before else t - band.block
+    return at // band.beside, pl.ds(at % band.beside, band.step)
+
+
+def _band_walk(band: Band, i, n_blocks, before: bool, sub_tiles):
+    """Take every sub-tile of the resident block ``i`` (of ``n_blocks``)
+    through its steps: ``sub_tiles(c0, reach)`` takes the ``band.chains``
+    sub-tiles from the ``c0``-th on, of whose steps those within ``reach``
+    rows beside the block exist.  Every trip is written out, its positions
+    static.  The trips that reach into the blocks beside the resident one
+    (the first where the band lies ``before`` the block, else the last) are
+    written once for each number of blocks that exist on that side, under
+    the condition on ``i`` that says so, and a step beyond them (before the
+    sequence's start, after its end) is left out of the text: no trip holds
+    a branch, and the trips that stay inside the block are one basic block
+    for the scheduler."""
+    chains = band.chains
+    trips = band.block // band.step // chains
+    peeled = min(trips, -(-(band.steps - 1) // chains))
+    edge = i if before else n_blocks - 1 - i
+    inside, beside = ((range(peeled, trips), range(peeled)) if before else
+                      (range(trips - peeled), range(trips - peeled, trips)))
+    for held in range(band.n_beside + 1 if peeled else 0):
+        last = held == band.n_beside
+
+        @pl.when(edge >= held if last else edge == held)
+        def _(held=held):
+            for trip in beside:
+                sub_tiles(trip * chains, held * band.block)
+
+    for trip in inside:
+        sub_tiles(trip * chains, 0)
+
+
+def _band_scores(q, k, mask, sm_scale: float, transposed: bool = False):
+    """:func:`_scores`' tile of one step of a banded walk, under the step's
+    mask of :func:`_band_masks` (None: wholly inside the band)."""
+    s = _scores(q, k, 0, 0, False, sm_scale=sm_scale, causal=True,
+                valid_len=None, transposed=transposed)
+    return s if mask is None else jnp.where(mask, s, NEG_INF)
+
+
+def _softmax_step(state, s, v):
+    """``(m, l, acc^T)`` of a sub-tile after the step whose transposed score
+    tile is ``s`` [Tk, Tq] and values ``v`` [Tk, D]; ``state`` None: its
+    first step."""
+    top = jnp.max(s, axis=0, keepdims=True)
+    m = top if state is None else jnp.maximum(state[0], top)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=0, keepdims=True)
+    acc = jax.lax.dot_general(v, p.astype(v.dtype), _TN,    # v^T @ p
+                              preferred_element_type=jnp.float32)
+    if state is not None:
+        alpha = jnp.exp(state[0] - m)
+        l, acc = state[1] * alpha + l, state[2] * alpha + acc
+    return m, l, acc
+
+
+def _band_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float,
+                     plan: TilePlan, variant: _Variant, band: Band):
+    """Forward of a banded call: grid (BH / G, n_q), every step live.  A
+    query block is resident with the key/value block of its own positions
+    and the keys the band reaches before it (``rest``: ``band.n_beside``
+    blocks of k, as many of v; then the outputs o, lse), so a sub-tile's
+    whole key span is here and its softmax begins and ends in this grid
+    step: m, l and acc^T are values from the diagonal's step, which shows
+    every row a key it sees (the running maximum is a real score before a
+    far-edge step hides a whole row's pairs), to the far edge's; no scratch,
+    nothing carried between grid steps.  The score tile is transposed as in
+    :func:`_mha_kernel`, and ``band.chains`` sub-tiles take each step side
+    by side, chains independent of each other by row."""
+    n = band.n_beside
+    k_by, v_by, (o_ref, lse_ref) = rest[:n], rest[n:2 * n], rest[2 * n:]
+    heads, step = _head_lanes(plan), band.step
+    masks = _band_masks(band, variant.window, 0)
+
+    def sub_tiles(c0, reach):
+        tiles = [_band_rows(band, (c0 + u) * step, True)[1]
+                 for u in range(band.chains)]
+        qs = [[q_ref[rows, h] for h in heads] for rows in tiles]
+        state = [[None] * len(heads) for _ in tiles]
+        for j in range(band.steps):
+            for u in range(band.chains):
+                t = (c0 + u - j) * step
+                if t < -reach:
+                    continue
+                which, cols = _band_rows(band, t, True)
+                k_at, v_at = ((k_ref, v_ref) if which is None
+                              else (k_by[which], v_by[which]))
+                for g, h in enumerate(heads):
+                    s = _band_scores(qs[u][g], k_at[cols, h], masks[j],
+                                     sm_scale, transposed=True)  # [Tk, Tq]
+                    state[u][g] = _softmax_step(state[u][g], s,
+                                                v_at[cols, h])
+        for rows, per_head in zip(tiles, state):
+            outs = []
+            for g, (m, l, acc) in enumerate(per_head):
+                outs.append(acc / l)                        # [D, Tq]
+                lse_ref[g:g + 1, rows] = m + jnp.log(l)
+            o_ref[rows, :] = jnp.transpose(
+                jnp.concatenate(outs, axis=0)).astype(o_ref.dtype)
+
+    _band_walk(band, pl.program_id(1), pl.num_programs(1), True, sub_tiles)
+
+
+def _band_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
+                    *rest, sm_scale: float, plan: TilePlan,
+                    variant: _Variant, band: Band):
+    """dQ of a banded call: the grid, the blocks and the walk of
+    :func:`_band_fwd_kernel` (``rest``: the blocks of k and of v beside the
+    own, then the output dq).  A sub-tile's dq [Tq, D] is a value through
+    its steps and stored once; its ``delta`` is a sum along the lanes of the
+    dO and O rows it holds, so no scratch at all."""
+    n = band.n_beside
+    k_by, v_by, (dq_ref,) = rest[:n], rest[n:2 * n], rest[2 * n:]
+    heads, step = _head_lanes(plan), band.step
+    masks = _band_masks(band, variant.window, 1)
+
+    def sub_tiles(c0, reach):
+        tiles = [_band_rows(band, (c0 + u) * step, True)[1]
+                 for u in range(band.chains)]
+        held, dqs = [], []
+        for rows in tiles:
+            do = do_ref[rows, :]
+            prod = do.astype(jnp.float32) * o_ref[rows, :].astype(jnp.float32)
+            held.append([
+                (q_ref[rows, h], do[:, h],
+                 _row_to_col(lse_ref[g:g + 1, rows]),
+                 jnp.sum(prod[:, h], axis=1, keepdims=True)
+                 - _row_to_col(dlse_ref[g:g + 1, rows]))
+                for g, h in enumerate(heads)])
+            dqs.append([None] * len(heads))
+        for j in range(band.steps):
+            for u in range(band.chains):
+                t = (c0 + u - j) * step
+                if t < -reach:
+                    continue
+                which, cols = _band_rows(band, t, True)
+                k_at, v_at = ((k_ref, v_ref) if which is None
+                              else (k_by[which], v_by[which]))
+                for g, (h, (q, do, lse, delta)) in enumerate(
+                        zip(heads, held[u])):
+                    k = k_at[cols, h]                       # [Tk, D]
+                    s = _band_scores(q, k, masks[j], sm_scale)  # [Tq, Tk]
+                    p = jnp.exp(s - lse)
+                    dp = jax.lax.dot_general(
+                        do, v_at[cols, h], _NT,
+                        preferred_element_type=jnp.float32)
+                    ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
+                    dq = jnp.dot(ds, k, preferred_element_type=jnp.float32)
+                    dqs[u][g] = dq if dqs[u][g] is None else dqs[u][g] + dq
+        for rows, per_head in zip(tiles, dqs):
+            dq_ref[rows, :] = jnp.concatenate(per_head, axis=1).astype(
+                dq_ref.dtype)
+
+    _band_walk(band, pl.program_id(1), pl.num_programs(1), True, sub_tiles)
+
+
+def _band_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
+                     *rest, sm_scale: float, plan: TilePlan,
+                     variant: _Variant, band: Band):
+    """dK/dV of a banded call: grid (BH_kv / G, n_kv, group), every step
+    live.  A key/value block is resident with the query head's q, dO, O and
+    statistics at its own positions and at those the band reaches after it
+    (``rest``: ``band.n_beside`` blocks each of q, dO, O, lse, dlse; then
+    the outputs dk, dv, the scratch ``delta`` over all those rows and, in a
+    grouped call, two float32 accumulators): the mirror of the forward's
+    walk, a sub-tile of keys through the query steps from the diagonal's to
+    the far edge's, score tiles transposed, dk and dv [Tk, D] values through
+    the steps.  The grid's last axis takes the query heads of the group one
+    after another; without a group a sub-tile's dk and dv leave as they are
+    finished, with one they gather in the accumulators, read and written
+    once a sub-tile and head."""
+    n, group = band.n_beside, variant.group
+    q_by, do_by, o_by, lse_by, dlse_by = (
+        rest[x * n:(x + 1) * n] for x in range(5))
+    dk_ref, dv_ref, delta_ref, *accs = rest[5 * n:]
+    heads, step = _head_lanes(plan), band.step
+    member = pl.program_id(2)
+    masks = _band_masks(band, variant.window, 0)
+    _delta_rows(do_ref, o_ref, dlse_ref, delta_ref, plan, band.block)
+    for d in range(n):
+        _delta_rows(do_by[d], o_by[d], dlse_by[d], delta_ref, plan,
+                    band.beside, band.block + d * band.beside)
+
+    def sub_tiles(c0, reach):
+        tiles = [_band_rows(band, (c0 + u) * step, False)[1]
+                 for u in range(band.chains)]
+        kvs = [[(k_ref[cols, h], v_ref[cols, h]) for h in heads]
+               for cols in tiles]
+        grads = [[None] * len(heads) for _ in tiles]
+        for j in range(band.steps):
+            for u in range(band.chains):
+                t = (c0 + u + j) * step
+                if t + step > band.block + reach:
+                    continue
+                which, rows = _band_rows(band, t, False)
+                q_at, do_at, lse_at = (
+                    (q_ref, do_ref, lse_ref) if which is None else
+                    (q_by[which], do_by[which], lse_by[which]))
+                # delta lies in one piece, the own block's rows first.
+                over = rows if which is None else pl.ds(t, step)
+                for g, (h, (k, v)) in enumerate(zip(heads, kvs[u])):
+                    q, do = q_at[rows, h], do_at[rows, h]   # [Tq, D]
+                    s = _band_scores(q, k, masks[j], sm_scale,
+                                     transposed=True)       # [Tk, Tq]
+                    p = jnp.exp(s - lse_at[g:g + 1, rows])
+                    dp = jax.lax.dot_general(
+                        v, do, _NT, preferred_element_type=jnp.float32)
+                    ds = (p * (dp - delta_ref[g:g + 1, over])
+                          * sm_scale).astype(q.dtype)
+                    dv = jnp.dot(p.astype(do.dtype), do,
+                                 preferred_element_type=jnp.float32)
+                    dk = jnp.dot(ds, q, preferred_element_type=jnp.float32)
+                    grads[u][g] = (dk, dv) if grads[u][g] is None else (
+                        grads[u][g][0] + dk, grads[u][g][1] + dv)
+        for cols, per_head in zip(tiles, grads):
+            for h, pair in zip(heads, per_head):
+                for x, out_ref, acc_ref in zip(pair, (dk_ref, dv_ref),
+                                               accs or (None, None)):
+                    if group > 1:
+                        x = jnp.where(member == 0, x, acc_ref[cols, h] + x)
+                        acc_ref[cols, h] = x
+                    # The block leaves when the grid row moves on: what the
+                    # last head of the group stored.
+                    out_ref[cols, h] = x.astype(out_ref.dtype)
+
+    _band_walk(band, pl.program_id(1), pl.num_programs(1), False, sub_tiles)
+
+
+def _own(i):
+    """The resident block's own positions."""
+    return i
+
+
+def _band_spec(rows: int, plan: TilePlan, n_col: int, seq_block,
+               row_of=None, stat: bool = False):
+    """A block of ``rows`` positions in a banded call's grid ``(r, i)`` or
+    ``(r, i, member)``: of an operand [N, S, n_col * lanes] as
+    :func:`_operand_spec` takes it or (``stat``) of a row statistic [BH / G,
+    G, S].  ``seq_block(i)`` names the block along the sequence,
+    ``row_of(r, *member)`` the operand's row where it is not the grid's."""
+    def index(r, i, *member):
+        row = r if row_of is None else row_of(r, *member)
+        return ((row, 0, seq_block(i)) if stat else
+                (row // n_col, seq_block(i), row % n_col))
+
+    return pl.BlockSpec((None, plan.heads_per_block, rows) if stat else
+                        (None, rows, plan.lanes), index)
+
+
+def _band_beside(band: Band, seq: int, before: bool):
+    """``seq_block`` of each block beside the resident one, in the order
+    :func:`_band_rows` counts them: the rows before block ``i`` (the
+    farthest first) or after it, in blocks of ``band.beside`` rows, held at
+    the sequence's first or last block where they do not exist (no step
+    reads them then)."""
+    per, n = band.block // max(band.beside, 1), band.n_beside
+    return [(lambda i, d=d: jnp.maximum(i * per - (n - d), 0)) if before else
+            (lambda i, d=d: jnp.minimum((i + 1) * per + d,
+                                        seq // band.beside - 1))
+            for d in range(n)]
+
+
+def _band_call(kernel, band: Band, plan, variant, sm_scale, interpret,
+               **call):
+    """The ``pallas_call`` of one of a banded call's kernels; ``call`` holds
+    its ``name``, by which a trace tells the three apart."""
+    return pl.pallas_call(
+        functools.partial(kernel, sm_scale=sm_scale, plan=plan,
+                          variant=variant, band=band),
+        interpret=interpret,
+        compiler_params=_BAND_PARAMS[len(call["grid"])], **call)
+
+
+def _band_query_side(plan: TilePlan, variant: _Variant, kv_col: int, seq):
+    """What the forward and dq share: their band, ``own`` (the spec of a
+    block at the resident query block's positions), that of k and v there,
+    and those of the key/value blocks the band reaches before it."""
+    band = band_of(plan.block_q, plan.step_k, plan.tile_q, variant.window)
+
+    def kv_row(r):
+        return r // variant.group
+
+    own = functools.partial(_band_spec, band.block, plan, seq_block=_own)
+    by = [_band_spec(band.beside, plan, kv_col, block, kv_row)
+          for block in _band_beside(band, seq, True)]
+    return band, own, own(kv_col, row_of=kv_row), by
+
+
+def _band_fwd(qb, kb, vb, sm_scale, plan: TilePlan, interpret,
+              variant: _Variant):
+    """:func:`_flash_fwd` of a banded call."""
+    n, s, width = qb.shape
+    n_col, kv_col = width // plan.lanes, kb.shape[2] // plan.lanes
+    band, own, kv_own, by = _band_query_side(plan, variant, kv_col, s)
+    return _band_call(
+        _band_fwd_kernel, band, plan, variant, sm_scale, interpret,
+        name="hvd_flash_swa_fwd",
+        grid=(n * n_col, s // band.block),
+        in_specs=[own(n_col), kv_own, kv_own, *by, *by],
+        out_specs=[own(n_col), own(n_col, stat=True)],
+        out_shape=[
+            _out_struct(qb.shape, qb.dtype, qb),
+            _out_struct((n * n_col, plan.heads_per_block, s), jnp.float32,
+                        qb)],
+    )(qb, kb, vb, *[kb] * len(by), *[vb] * len(by))
+
+
+def _band_bwd(qb, kb, vb, ob, lse, dob, dlse, sm_scale, plan: TilePlan,
+              interpret, variant: _Variant):
+    """:func:`_flash_bwd` of a banded call: dq on the forward's grid, dk and
+    dv on the key blocks' with the group's query heads along its last
+    axis."""
+    n, s, width = qb.shape
+    n_col, kv_col = width // plan.lanes, kb.shape[2] // plan.lanes
+    g, group = plan.heads_per_block, variant.group
+    lse, dlse = lse.astype(jnp.float32), dlse.astype(jnp.float32)
+
+    band, own, kv_own, by = _band_query_side(plan, variant, kv_col, s)
+    dq = _band_call(
+        _band_dq_kernel, band, plan, variant, sm_scale, interpret,
+        name="hvd_flash_swa_dq",
+        grid=(n * n_col, s // band.block),
+        in_specs=[own(n_col), kv_own, kv_own, own(n_col), own(n_col),
+                  own(n_col, stat=True), own(n_col, stat=True), *by, *by],
+        out_specs=own(n_col),
+        out_shape=_out_struct(qb.shape, qb.dtype, qb),
+    )(qb, kb, vb, dob, ob, lse, dlse, *[kb] * len(by), *[vb] * len(by))
+
+    def q_row(r, member):
+        return r * group + member
+
+    band = band_of(plan.block_k, plan.step_q, plan.tile_k, variant.window)
+    own = functools.partial(_band_spec, band.block, plan, seq_block=_own)
+    q_own, stat_own = own(n_col, row_of=q_row), own(n_col, row_of=q_row,
+                                                    stat=True)
+    after = _band_beside(band, s, False)
+    q_by = [_band_spec(band.beside, plan, n_col, block, q_row)
+            for block in after]
+    stat_by = [_band_spec(band.beside, plan, n_col, block, q_row, stat=True)
+               for block in after]
+    accumulator = pltpu.VMEM((band.block, plan.lanes), jnp.float32)
+    dk, dv = _band_call(
+        _band_dkv_kernel, band, plan, variant, sm_scale, interpret,
+        name="hvd_flash_swa_dkv",
+        grid=(kb.shape[0] * kv_col, s // band.block, group),
+        in_specs=[q_own, own(kv_col), own(kv_col), q_own, q_own, stat_own,
+                  stat_own, *q_by * 3, *stat_by * 2],
+        out_specs=[own(kv_col), own(kv_col)],
+        out_shape=[_out_struct(kb.shape, kb.dtype, kb),
+                   _out_struct(vb.shape, vb.dtype, vb)],
+        scratch_shapes=[
+            pltpu.VMEM((g, band.block + len(after) * band.beside),
+                       jnp.float32)] + [accumulator] * (2 * (group > 1)),
+    )(qb, kb, vb, dob, ob, lse, dlse, *[qb] * len(after),
+      *[dob] * len(after), *[ob] * len(after), *[lse] * len(after),
+      *[dlse] * len(after))
+    return dq, dk, dv, []
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
@@ -1557,7 +1980,7 @@ def _mla_bwd(qb, kb, vb, qrb, krb, ob, lse, dob, dlse, sm_scale, causal,
     def streamed_q(row, i, j, lens):
         first = (i * bk) // bq if causal else 0
         return jnp.minimum(jnp.maximum(j, first),
-                           _last_live_q(i, plan, valid_len, 0))
+                           _last_live_q(i, plan, valid_len))
 
     q_by_j = _operand_spec(bq, plan, n_col, streamed_q)
     kv_by_i = _operand_spec(bk, plan, n_col, _by_i)

@@ -150,8 +150,8 @@ def test_wide_expert_products_phase_tiny(monkeypatch):
 
 def test_flash_window_phase_tiny():
     """The banded flash kernels (interpreted) against ``dense_attention`` a
-    query head at a time, at two group sizes, with a band and without, and
-    the timing table's keys."""
+    query head at a time, at two group sizes, with a band and without, the
+    timing table's keys and the banded call's schedule beside them."""
     report = chip_smoke.flash_window(length=160, heads=(3, 2), head_dim=16,
                                      windows=(24, None), repeats=1, chain=2,
                                      interpret=True)
@@ -161,6 +161,12 @@ def test_flash_window_phase_tiny():
     assert all(c["ok"] for c in report["checks"])
     assert {f"{p}_ms/{tag}" for p in ("fwd", "fwd_bwd")
             for tag in tags} <= set(report)
+    # 160 rows pad to one block of 256 rows and one step: a band of 24 keys
+    # takes the diagonal's step and the one before it.
+    assert report["schedule/window=24"] == {
+        "grid_steps_a_head": 1, "keys_a_row": 512, "block": 256, "step": 256,
+        "chains": 1, "rows_beside": 256}
+    assert "schedule/window=None" not in report
 
 
 def test_flash_mla_phase_tiny():

@@ -71,6 +71,15 @@ def from_example(example: dict, shift_ns: float = 0.0) -> Trace:
                  (lo + shift_ns, hi + shift_ns), example["steps"])
 
 
+def examples_of(meta: dict) -> list:
+    """The examples of a metric file: its one ``example``, or the
+    ``examples`` of a name that several families share, each with the
+    ``cell`` it is of.  Empty where the file has neither."""
+    if "example" in meta:
+        return [meta["example"]]
+    return list(meta.get("examples", ()))
+
+
 # ---------------------------------------------------------------------------
 # Core: intervals
 # ---------------------------------------------------------------------------
@@ -331,12 +340,17 @@ def roofline_pct(trace: Trace, ctx: dict, pattern: str, least: str,
     time from the context's shapes and peaks: it returns a dict with
     ``seconds``, and lives beside the operations and bytes it counts.
     ``least_key`` (``"kernels.dq"``) is the path to the one kernel's dict
-    inside it, for a function that returns several."""
+    inside it, for a function that returns several.  ``{family}`` in
+    ``least`` stands for the configuration's ``family``: one metric over
+    the families that each count a least time of their own
+    (``"{family}_flops.experts_step_least"``)."""
     from benchmark import common
 
     took = op_time_ms(trace, ctx, pattern, **select)
     if not took:
         return None
+    if "{family}" in least:
+        least = least.replace("{family}", ctx["cfg"]["family"])
     bound = common.load_function(least)(ctx)
     for key in least_key.split(".") if least_key else ():
         bound = bound[key]

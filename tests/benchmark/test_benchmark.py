@@ -70,18 +70,21 @@ def test_data_file_loads_and_is_named_for_its_content(rel):
         assert callable(common.load_function(data["reducer"]))
         # What BENCHMARK.json states of a metric is stated there alone.
         assert set(data) <= {"name", "reducer", "params", "what",
-                             "pattern_note", "example"}
+                             "pattern_note", "example", "examples"}
         # A metric proves itself: what its reader returns for a few events,
-        # and a case in which there is nothing for it to read.
-        assert "example" in data, (
+        # and a case in which there is nothing for it to read; a metric
+        # that several families share, once for each cell that brought one.
+        assert ("example" in data) != bool(data.get("examples")), (
             f"{rel} has no \"example\" (benchmark/README.md, \"A per-layer "
             "metric\")")
-        example = data["example"]
-        assert {"window", "steps", "value", "nothing"} <= set(example) <= {
-            "what", "events", "host", "context", "window", "steps", "value",
-            "nothing"}
-        assert isinstance(example["value"], (int, float))
-        assert set(example["nothing"]) <= {"events", "host", "context"}
+        entry, = [m for m in _spec()["per_layer"] if m["name"] == data["name"]]
+        for cell, example in _examples(data["name"]):
+            assert cell is None or cell in entry["workloads"], (rel, cell)
+            assert {"window", "steps", "value", "nothing"} <= set(example) <= {
+                "what", "events", "host", "context", "window", "steps",
+                "value", "nothing", "cell", "was"}
+            assert isinstance(example["value"], (int, float))
+            assert set(example["nothing"]) <= {"events", "host", "context"}
 
 
 @pytest.mark.parametrize("group,key,folder", [
@@ -450,8 +453,22 @@ METRIC_FILES = sorted(os.path.splitext(os.path.basename(p))[0]
                       for p in DATA_FILES if p.startswith("layer_metrics/"))
 
 
+def _examples(name):
+    """``[(cell, example), ...]`` of a metric file: its one ``example`` (the
+    first cell of its entry's list reads it) or its ``examples``, each of the
+    cell it names."""
+    return [(e.get("cell"), e) for e in trace_reduce.examples_of(
+        _load(f"layer_metrics/{name}.json"))]
+
+
 def _example(name):
-    return _load(f"layer_metrics/{name}.json")["example"]
+    """The file's first example: the one that goes into the trace of all."""
+    return _examples(name)[0][1]
+
+
+EXAMPLES = [pytest.param(name, i, id=f"{name}:{cell}" if cell else name)
+            for name in METRIC_FILES
+            for i, (cell, _) in enumerate(_examples(name))]
 
 
 def _nothing(example):
@@ -490,17 +507,19 @@ def _all_examples_in_one_trace():
     return Trace(devices, host, (0.0, at), steps), ctx
 
 
-@pytest.mark.parametrize("name", METRIC_FILES)
-def test_metric_file_reads_its_own_example(name):
-    """``reducer``, ``params`` and ``example`` of one file held together,
+@pytest.mark.parametrize("name,which", EXAMPLES)
+def test_metric_file_reads_its_own_example(name, which):
+    """``reducer``, ``params`` and one example of one file held together,
     through the harness's own lookup: the reader returns the value the file
-    states for the file's events, and nothing for its ``nothing`` case.  A
-    new metric brings its file and its entry; no test is edited for it."""
+    states for the example's events, in the cell the example is of, and
+    nothing for its ``nothing`` case.  A new metric brings its file and its
+    entry; no test is edited for it."""
     spec = _spec()
     entry = next(m for m in spec["per_layer"] if m["name"] == name)
     alone = {**spec, "per_layer": [entry]}
-    cell = (entry.get("workloads") or [spec["workloads"][0]["name"]])[0]
-    example = _example(name)
+    cell, example = _examples(name)[which]
+    cell = cell or (entry.get("workloads")
+                    or [spec["workloads"][0]["name"]])[0]
     got = run.per_layer(alone, cell, trace_reduce.from_example(example),
                         example.get("context", {}))
     assert got == {name: {"value": pytest.approx(example["value"],

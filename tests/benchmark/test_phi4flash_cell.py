@@ -56,9 +56,12 @@ PHI_METRICS = (
     "phi_flash_swa_dq_roofline", "phi_flash_swa_dkv_roofline",
     "phi_attn_diff_ms", "phi_ssm_scan_ms", "phi_ssm_scan_fwd_roofline",
     "phi_ssm_scan_bwd_roofline", "phi_ssm_mix_ms", "phi_ssm_proj_ms",
-    "phi_gmu_ms", "phi_attn_proj_ms", "phi_mlp_ms", "phi_lm_head_ms",
-    "phi_embed_ms", "phi_block_rest_ms", "phi_unattributed_pct",
-    "phi_optimizer_update_ms")
+    "phi_gmu_ms")
+# What the cell reads under the names every family shares (PR 65 folded the
+# family's seven copies of them onto these).
+SHARED_METRICS = (
+    "attn_proj_ms", "mlp_ms", "lm_head_ms", "embed_ms", "block_rest_ms",
+    "step_unattributed_pct", "optimizer_update_ms")
 
 
 def _files(rehearse=False):
@@ -186,14 +189,26 @@ def test_layers_held_must_be_the_layers_counted():
 
 
 def test_the_metrics_stand_after_the_two_witnesses_in_the_order_asked():
+    """The family's own fifteen, in their order and of this cell alone; the
+    seven shared names list the cell; the cell reports those and what every
+    cell reports, and nothing else."""
     spec = run.load_spec()
     names = [m["name"] for m in spec["per_layer"]]
-    assert tuple(names[-len(PHI_METRICS):]) == PHI_METRICS
-    assert names.index("host_alive_gap_max_ms") < names.index(PHI_METRICS[0])
-    for m in spec["per_layer"][-len(PHI_METRICS):]:
-        assert (m["workloads"], m["moves"]) == ([CELL], "step_ms")
-    assert not any(CELL in m.get("workloads", ())
-                   for m in spec["per_layer"][:-len(PHI_METRICS)])
+    first = names.index(PHI_METRICS[0])
+    assert tuple(names[first:first + len(PHI_METRICS)]) == PHI_METRICS
+    assert names.index("host_alive_gap_max_ms") < first
+    by_name = {m["name"]: m for m in spec["per_layer"]}
+    for name in PHI_METRICS:
+        assert (by_name[name]["workloads"], by_name[name]["moves"]) == (
+            [CELL], "step_ms")
+    for name in SHARED_METRICS:
+        assert CELL in by_name[name]["workloads"], name
+        assert by_name[name]["moves"] == "step_ms"
+    assert {m["name"] for m in spec["per_layer"]
+            if CELL in m.get("workloads", ())} == {*PHI_METRICS,
+                                                   *SHARED_METRICS}
+    assert not any(n.startswith("phi_") for n in names
+                   if n not in PHI_METRICS)
 
 
 def test_parameter_count_of_one_chips_share():

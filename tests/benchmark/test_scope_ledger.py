@@ -23,8 +23,23 @@ WHILE = ("%while.1 = (s32[], f32[8]) while((s32[], f32[8]) %t), "
 BLOCK = "jit(step)/jvp(SDAR)/layer_0/hvd_block/"
 RECORDED = sorted(glob.glob(os.path.join(REPO, "benchmark", "testdata",
                                          "*.xplane.pb.gz")))
-EXAMPLES = sorted(glob.glob(os.path.join(REPO, "benchmark", "layer_metrics",
-                                         "*.json")))
+METRIC_FILES = sorted(glob.glob(os.path.join(REPO, "benchmark",
+                                             "layer_metrics", "*.json")))
+
+
+def _examples_of(path) -> list:
+    with open(path) as f:
+        return trace_reduce.examples_of(json.load(f))
+
+
+def _short(path) -> str:
+    return os.path.basename(path).split(".")[0]
+
+
+EXAMPLES = [pytest.param(path, i, id=f"{_short(path)}:{example['cell']}"
+                         if "cell" in example else _short(path))
+            for path in METRIC_FILES
+            for i, example in enumerate(_examples_of(path))]
 
 
 def _op(i, start, dur, scope="", line=SYNC_LINE, name=None):
@@ -84,14 +99,14 @@ def test_self_times_add_up_to_busy_time(case, spans, want):
     assert sum(want) == trace_reduce.busy_ns(events, window), case
 
 
-@pytest.mark.parametrize("path", RECORDED + EXAMPLES,
-                         ids=lambda p: os.path.basename(p).split(".")[0])
-def test_self_time_adds_up_on_every_recorded_trace_and_example(path):
+@pytest.mark.parametrize("path,which", [
+    pytest.param(path, None, id=_short(path)) for path in RECORDED
+] + EXAMPLES)
+def test_self_time_adds_up_on_every_recorded_trace_and_example(path, which):
     """Sum of self = ``busy_ns`` of the same events: on the two traces
     recorded on a TPU v5e and on every metric file's hand-made events."""
-    if path.endswith(".json"):
-        with open(path) as f:
-            trace = trace_reduce.from_example(json.load(f)["example"])
+    if which is not None:
+        trace = trace_reduce.from_example(_examples_of(path)[which])
     else:
         trace = trace_reduce.read_xplane(path, steps=2)
         assert trace.devices

@@ -94,7 +94,10 @@ def test_the_two_read_beside_each_other_part_the_host_from_what_is_beneath():
 
 @pytest.mark.parametrize("name", ["device_gap_max_ms",
                                   "host_alive_gap_max_ms"])
-def test_every_cell_reports_both_and_neither_has_a_list_of_cells(name):
+def test_every_cell_reports_both_in_their_order_and_neither_lists_cells(name):
+    """The pair's order, not its place: the families' metrics are appended
+    after it (the driver reads an entry put before the two as a change to
+    ``device_gap_max_ms``; PERF.md section 6, PR 47)."""
     spec = run.load_spec()
     entry, = [m for m in spec["per_layer"] if m["name"] == name]
     assert "workloads" not in entry
@@ -104,9 +107,9 @@ def test_every_cell_reports_both_and_neither_has_a_list_of_cells(name):
     for cell in spec["workloads"]:
         assert name in {m["name"] for m in run.metrics_of(
             spec, "per_layer", cell["name"])}
-    assert spec["per_layer"][-2:] == [
-        m for m in spec["per_layer"]
-        if m["name"] in ("device_gap_max_ms", "host_alive_gap_max_ms")]
+    assert [m["name"] for m in spec["per_layer"]
+            if m["name"].endswith("_gap_max_ms")] == [
+                "device_gap_max_ms", "host_alive_gap_max_ms"]
 
 
 def test_stall_hunt_rehearses_its_traced_windows(tmp_path):
